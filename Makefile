@@ -47,7 +47,7 @@ bench:  ## headline decode-throughput benchmark (one JSON line)
 # path is unstable on some hosts (wrong tokens, then a native crash) —
 # tiny smoke programs recompile in seconds anyway
 bench-smoke:  ## seconds-scale CPU bench: engine + HTTP + mixed + prefix + spec + overload + restart + coldstart + fused-paged + disagg arms
-	JAX_PLATFORMS=cpu BENCH_CHILD=1 BENCH_HTTP=1 BENCH_MIXED_ARM=1 \
+	JAX_PLATFORMS=cpu BENCH_HTTP=1 BENCH_MIXED_ARM=1 \
 	  BENCH_PREFIX_ARM=1 BENCH_TIER_ARMS=1 \
 	  BENCH_PAGED_ASYNC_ARM=1 BENCH_PAGED_FUSED_ARM=1 \
 	  BENCH_SPEC_ARM=1 \

@@ -1,31 +1,41 @@
-"""Bisect which construct in the v3 paged kernel crashes the real-TPU
-Mosaic lowering (hack/tpu_kernel_check.py: INTERNAL compile-helper crash;
-interpret mode passes). Each probe isolates one suspect:
+"""Bisect which construct in the v3 paged kernel the TPU's Mosaic lowering
+refuses (interpret mode passes all of them). Each probe isolates one
+suspect — the constructs that once crashed the chip's lowering of the v3
+kernel:
 
   p1  batched dot_general (batch dim = KvH) on VMEM values
   p2  dynamic leading-index read of a VMEM scratch buffer (buf[slot])
   p3  make_async_copy HBM.at[lay, pg] -> VMEM scratch, traced indices
   p4  fori_loop with traced (SMEM-scalar) bounds containing pl.when+DMA
   p5  3-D broadcasted_iota + 3-D flash-style elementwise chain
+
+The probes compile for a v5e that is described, not attached (the TPU's
+compiler ships with jaxlib), so this runs anywhere:
+
+    JAX_PLATFORMS=cpu python hack/v3_bisect.py
+
+The kernels themselves, at tinyllama's real shapes, are held by
+tests/test_chip_compile.py.
 """
 from __future__ import annotations
 
-import functools
 import os
 import sys
 
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental import topologies
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import SingleDeviceSharding
 
 
-def check(name, fn, *args):
+def check(name, fn, *shapes):
     try:
-        jax.jit(fn).lower(*args).compile()
+        jax.jit(fn).lower(*shapes).compile()
         print(f"OK   {name}", flush=True)
         return True
     except Exception as e:
@@ -36,6 +46,14 @@ def check(name, fn, *args):
 
 def main():
     KvH, Gp, ps, hd = 4, 8, 64, 128
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+
+    def arg(shape, dtype):
+        """A described-device argument: shapes compile, nothing runs."""
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    ok = True
 
     # p1: batched dot_general
     def k1(q_ref, k_ref, o_ref):
@@ -50,9 +68,9 @@ def main():
             out_shape=jax.ShapeDtypeStruct((KvH, Gp, ps), jnp.float32),
         )(q, k)
 
-    q = jnp.zeros((KvH, Gp, hd), jnp.bfloat16)
-    kk = jnp.zeros((KvH, ps, hd), jnp.bfloat16)
-    check("p1 batched dot_general", p1, q, kk)
+    ok &= check("p1 batched dot_general", p1,
+                arg((KvH, Gp, hd), jnp.bfloat16),
+                arg((KvH, ps, hd), jnp.bfloat16))
 
     # p2: dynamic leading-index scratch read
     def k2(i_ref, x_ref, o_ref, buf):
@@ -70,8 +88,8 @@ def main():
             out_shape=jax.ShapeDtypeStruct((ps, hd), jnp.float32),
         )(i, x)
 
-    check("p2 dynamic scratch read", p2, jnp.zeros((1,), jnp.int32),
-          jnp.zeros((ps, hd), jnp.float32))
+    ok &= check("p2 dynamic scratch read", p2, arg((1,), jnp.int32),
+                arg((ps, hd), jnp.float32))
 
     # p3: manual DMA from HBM with traced indices
     def k3(lay_ref, tbl_ref, hbm_ref, o_ref, buf, sem):
@@ -95,9 +113,9 @@ def main():
             out_shape=jax.ShapeDtypeStruct((KvH, ps, hd), jnp.float32),
         )(lay, tbl, pool)
 
-    pool = jnp.zeros((2, 5, KvH, ps, hd), jnp.int8)
-    check("p3 manual HBM DMA", p3, jnp.zeros((1,), jnp.int32),
-          jnp.zeros((4,), jnp.int32), pool)
+    pool = arg((2, 5, KvH, ps, hd), jnp.int8)
+    ok &= check("p3 manual HBM DMA", p3, arg((1,), jnp.int32),
+                arg((4,), jnp.int32), pool)
 
     # p4: dynamic fori_loop with pl.when + DMA inside
     def k4(len_ref, tbl_ref, hbm_ref, o_ref, buf, sem):
@@ -133,8 +151,8 @@ def main():
             out_shape=jax.ShapeDtypeStruct((ps, hd), jnp.float32),
         )(ln, tbl, pool)
 
-    check("p4 dynamic loop + DMA", p4, jnp.asarray([130], jnp.int32),
-          jnp.zeros((4,), jnp.int32), pool)
+    ok &= check("p4 dynamic loop + DMA", p4, arg((1,), jnp.int32),
+                arg((4,), jnp.int32), pool)
 
     # p5: 3-D iota + flash chain
     def k5(s_ref, o_ref, m_ref, l_ref):
@@ -157,8 +175,9 @@ def main():
                             pltpu.VMEM((KvH, Gp, 1), jnp.float32)],
         )(s)
 
-    check("p5 3-D iota+flash", p5, jnp.zeros((KvH, Gp, ps), jnp.float32))
+    ok &= check("p5 3-D iota+flash", p5, arg((KvH, Gp, ps), jnp.float32))
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

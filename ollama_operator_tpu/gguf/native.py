@@ -69,6 +69,15 @@ def load() -> Optional[ctypes.CDLL]:
         return _lib
 
 
+def path_used() -> str:
+    """Which dequant path this process took: "native", "numpy" (the
+    library could not be built or loaded), or "none" (nothing has been
+    dequantized yet, e.g. the transcode came from the cache)."""
+    if not _tried:
+        return "none"
+    return "native" if _lib is not None else "numpy"
+
+
 _NATIVE_MAP = {
     R.GGML_F16: "dq_f16",
     R.GGML_BF16: "dq_bf16",

@@ -45,7 +45,7 @@ from jax import lax
 
 from ..ops import quant as Q
 from ..ops.attention import (attend_hf, cached_attention, causal_mask,
-                             chunk_attention, shard_map_compat)
+                             chunk_attention, note_kernel)
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rope import apply_rope, rope_angles_cfg
 from .config import ModelConfig
@@ -745,23 +745,30 @@ def _paged_kernel_usable(cfg: ModelConfig, mesh, T: int, KvH: int, ps: int,
     reference path — the A/B control for the fused kernel's bandwidth
     win (bench paged_bw_ratio) and the parity suite's oracle."""
     import os
-    if os.environ.get("TPU_PAGED_FUSED", "1").lower() in ("0", "false"):
-        return False
     from ..ops.attention import resolve_kernels
     from ..ops.pallas.flash import _lane_ok
+    site = "paged_decode" if T == 1 else "paged_extend"
+
+    def gather(fell_back: bool = False) -> bool:
+        note_kernel(site, "gather_einsum", fell_back)
+        return False
+
+    if os.environ.get("TPU_PAGED_FUSED", "1").lower() in ("0", "false"):
+        return gather()
     mode = resolve_kernels(cfg.kernels)
     if mode not in ("pallas", "interpret") or T != 1:
-        return False
-    if cfg.n_heads % KvH or ps % 8 or not _lane_ok(hd, mode == "interpret"):
-        return False
+        return gather()
     if cfg.altern_sliding:
-        return False   # per-layer window rides the (traced) mask
+        return gather()   # per-layer window rides the (traced) mask
+    # from here on the kernel was wanted: giving way is a fallback
+    if cfg.n_heads % KvH or ps % 8 or not _lane_ok(hd, mode == "interpret"):
+        return gather(fell_back=True)
     if mesh is not None and mesh.size > 1:
         tp = mesh.shape.get("tp", 1)
         if _paged_dp_axes(cfg, mesh, KvH) is None and tp != mesh.size:
-            return False                   # engine enforces dp/tp meshes
+            return gather(fell_back=True)  # engine enforces dp/tp meshes
         if cfg.n_heads % tp or KvH % tp:
-            return False
+            return gather(fell_back=True)
     return True
 
 
@@ -799,23 +806,27 @@ def _paged_attend(cfg: ModelConfig, q, kp, vp, i, tables, lengths, mask,
                           if quant else pool_spec)
             qspec = P(None, None, "tp", None)
 
+            # manual over EVERY mesh axis (the others are size 1 here —
+            # _paged_kernel_usable): Mosaic refuses a kernel inside a
+            # region that leaves any axis to the partitioner
             def inner(q, kp, vp, i, tables, lengths):
                 return paged_decode_attention(
                     q, kp, vp, i, tables, lengths, scale, cfg.attn_softcap,
                     cfg.sliding_window, nblk=attn_blocks, interpret=interp)
 
-            out = shard_map_compat(
+            out = jax.shard_map(
                 inner, mesh=mesh,
                 in_specs=(qspec, pool_specs, pool_specs, P(), P(None, None),
                           P(None)),
-                out_specs=qspec,
-                axis_names={"tp"})(q, kp, vp, i, tables, lengths)
+                out_specs=qspec, check_vma=False)(
+                q, kp, vp, i, tables, lengths)
         else:
             out = paged_decode_attention(
                 q, kp, vp, i, tables, lengths, scale, cfg.attn_softcap,
                 cfg.sliding_window, nblk=attn_blocks, interpret=interp)
         if out is not None:
             return out
+        note_kernel("paged_decode", "gather_einsum", fell_back=True)
     tbl = tables[:, :attn_blocks]
     # gather fallback: the pool hd is 128-lane padded; pad q to match
     # (zeros are inert in the score dot) and slice the pad lanes back off
@@ -937,6 +948,7 @@ def _paged_write_attend_local(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
             cfg.sliding_window, nblk=attn_blocks, interpret=interp)
         if out is not None:
             return kp, vp, out
+        note_kernel("paged_decode", "gather_einsum", fell_back=True)
     out = _paged_attend(cfg, q, kp, vp, i, tables, lengths, mask, scale,
                         attn_blocks, None, False)
     return kp, vp, out
@@ -946,7 +958,8 @@ def _paged_write_attend_dp(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
                            lengths, positions, mask, scale,
                            attn_blocks: int, use_kernel: bool, interp: bool,
                            mesh, h_ax):
-    """dp/tp-manual wrapper around ``_paged_write_attend_local``: the pool
+    """Manual wrapper (over every mesh axis; only dp and tp are wider than
+    1 — _paged_dp_axes) around ``_paged_write_attend_local``: the pool
     PAGE axis is sharded over dp (each shard's local page 0 is its trash
     page) and tables/lengths/batch rows ride dp — so scatter AND attend
     stay device-local with no collectives, the same property the dense
@@ -965,13 +978,12 @@ def _paged_write_attend_dp(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
             cfg, q, k, v, kp, vp, i, tables, lengths, positions, mask,
             scale, attn_blocks, use_kernel, interp)
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, pool_specs, pool_specs, P(),
                   P("dp", None), P("dp"), P("dp", None),
                   P("dp", None, None, None)),
-        out_specs=(pool_specs, pool_specs, qspec),
-        axis_names={"dp", "tp"})(
+        out_specs=(pool_specs, pool_specs, qspec), check_vma=False)(
         q, k, v, kp, vp, i, tables, lengths, positions, mask)
 
 
@@ -996,11 +1008,11 @@ def paged_insert_dp(cfg: ModelConfig, k_pool, v_pool, ks, vs, table_rows,
     def inner(kp, vp, ks, vs, trow, n_valid):
         return paged_insert(cfg, kp, vp, ks, vs, trow[0], n_valid)
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(pool_specs, pool_specs, kvs, kvs, P("dp", None), P()),
         out_specs=(pool_specs, pool_specs),
-        axis_names={"dp", "tp"})(
+        axis_names={"dp", "tp"}, check_vma=False)(
         k_pool, v_pool, ks, vs, table_rows, n_valid)
 
 
@@ -1037,12 +1049,12 @@ def paged_extend_dp(params: Params, cfg: ModelConfig, tokens: jax.Array,
         logits = lax.psum(jnp.where(my == owner, logits, 0.0), "dp")
         return logits, kp, vp
 
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(None, None), pool_specs, pool_specs, P("dp", None),
                   P(None), P()),
         out_specs=(P(None, None, None), pool_specs, pool_specs),
-        axis_names={"dp"})(
+        axis_names={"dp"}, check_vma=False)(
         tokens, k_pool, v_pool, table_rows, lengths, owner)
 
 

@@ -110,6 +110,12 @@ def new_server_container(
         # and restored on wake (runtime/service.py warm snapshot; the
         # cache subpath is the same PVC the transcoded weights live on)
         env.append({"name": "TPU_WARM_SNAPSHOT", "value": "1"})
+        # the persistent XLA compile cache rides the same RW cache
+        # subpath, so a restarted pod skips the warm-up compiles; the
+        # server takes the place from JAX's own variable and sets none in
+        # code (runtime/compile_cache.py)
+        env.append({"name": "JAX_COMPILATION_CACHE_DIR",
+                    "value": f"{STORE_MOUNT}/{CACHE_SUBPATH}/xla-cache"})
     env.extend(extra_env or [])
 
     mounts = [{

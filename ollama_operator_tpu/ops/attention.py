@@ -8,8 +8,10 @@ large and avoids an HBM-resident K/V copy.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
-from typing import Optional
+from typing import Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +94,32 @@ def causal_mask(T: int, S: int, offset, dtype=jnp.float32,
 
 KERNEL_MODES = ("auto", "pallas", "xla", "interpret")
 
+# Which kernel each dispatcher picked while a program was traced. A
+# dispatcher that wanted a pallas kernel and took the next path because the
+# shapes do not tile says so here (fell_back=True); the engine reports it
+# once per program (runtime/engine.py), so a kernel that quietly gave way to
+# einsum on the chip is seen at start-up, not in a profile weeks later.
+_KERNEL_SINK: contextvars.ContextVar = contextvars.ContextVar(
+    "kernel_sink", default=None)
+
+
+@contextlib.contextmanager
+def record_kernels() -> Iterator[List[Tuple[str, str, bool]]]:
+    """Collect (site, kernel, fell_back) for every dispatch traced inside
+    the block, each once. Nothing is collected outside such a block."""
+    sink: List[Tuple[str, str, bool]] = []
+    token = _KERNEL_SINK.set(sink)
+    try:
+        yield sink
+    finally:
+        _KERNEL_SINK.reset(token)
+
+
+def note_kernel(site: str, kernel: str, fell_back: bool = False) -> None:
+    sink = _KERNEL_SINK.get()
+    if sink is not None and (site, kernel, fell_back) not in sink:
+        sink.append((site, kernel, fell_back))
+
 
 def _mesh_attn_axes(mesh, B: int, H: int, KvH: int):
     """(batch_axis, head_axis) for a dp/tp-manual ``shard_map`` around the
@@ -119,44 +147,6 @@ def _mesh_attn_axes(mesh, B: int, H: int, KvH: int):
     return ("dp" if dp > 1 else None), ("tp" if tp > 1 else None)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names):
-    """jax.shard_map across jax versions: the top-level API (with
-    axis_names/check_vma) landed after 0.4. The old experimental shard_map
-    cannot express partial-manual regions that use ``lax.axis_index`` (its
-    ``auto=`` lowering emits a PartitionId op GSPMD rejects), so the
-    fallback goes fully manual instead: axes outside ``axis_names`` are
-    unmentioned in the specs, so their values — including closed-over
-    params — replicate into the region. Same results, more per-device
-    memory; only the newer-jax path runs partial-manual."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
-def axis_size_compat(axis_name):
-    """Static mesh-axis size inside a shard_map region across jax versions:
-    ``lax.axis_size`` is newer; older jax exposes the same static int via
-    ``core.axis_frame``."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.core.axis_frame(axis_name)
-
-
-def pcast_varying_compat(x, axis_name):
-    """``lax.pcast(..., to="varying")`` where available. Older jax's
-    shard_map has no varying-type system (we run it with check_rep=False),
-    so the cast is a no-op there."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    return x
-
-
 def _sharded_kernel_call(mesh, q, KvH: int, tileable, inner, args,
                          with_pos: bool):
     """Run a pallas attention kernel inside a dp/tp-manual shard_map.
@@ -180,8 +170,11 @@ def _sharded_kernel_call(mesh, q, KvH: int, tileable, inner, args,
     qspec = P(b_ax, None, h_ax, None)
     kvspec = P(b_ax, h_ax, None, None)
     in_specs = (qspec, kvspec, kvspec) + ((P(b_ax),) if with_pos else ())
-    return shard_map_compat(inner, mesh=mesh, in_specs=in_specs,
-                            out_specs=qspec, axis_names={"dp", "tp"})(*args)
+    # manual over EVERY mesh axis (_mesh_attn_axes admits only meshes whose
+    # other axes are size 1): Mosaic refuses a kernel inside a region that
+    # leaves any axis to the partitioner
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=qspec, check_vma=False)(*args)
 
 
 def resolve_kernels(kernels: str) -> str:
@@ -229,7 +222,11 @@ def chunk_attention(cfg, q, k, v, mask, scale: float, mesh=None):
         else:
             out = inner(q, k, v)
         if out is not None:
+            note_kernel("prefill", "flash_prefill")
             return out
+        note_kernel("prefill", "einsum", fell_back=True)
+    else:
+        note_kernel("prefill", "einsum")
     return attend_hf(q, k, v, mask, scale, cfg.attn_softcap)
 
 
@@ -292,7 +289,13 @@ def cached_attention(cfg, q, k_cache, v_cache, mask, q_pos, scale: float,
         else:
             out = inner(q, k_cache, v_cache, q_pos[:, 0])
         if out is not None:
+            note_kernel("decode", "mha_decode_attention" if mha_kernel
+                        else "decode_attention")
             return out
+        note_kernel("decode", "einsum", fell_back=True)
+    else:
+        # xla mode, a T>1 continuation, or MHA by design (see above)
+        note_kernel("decode", "einsum")
     if attn_len is not None and attn_len < k_cache.shape[2]:
         k_cache = k_cache[:, :, :attn_len, :]
         v_cache = v_cache[:, :, :attn_len, :]

@@ -34,10 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the newer pallas API renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 from ..attention import NEG_INF, softcap_scores
 
 _BLOCKS = (512, 256, 128, 64, 32, 16, 8)
@@ -175,7 +171,7 @@ def flash_prefill(q, k, v, scale: float, softcap: float = 0.0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -287,7 +283,7 @@ def decode_attention(q, k_cache, v_cache, q_pos, scale: float,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KvH, Gp, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos.astype(jnp.int32), qg, k_cache, v_cache)
@@ -405,7 +401,7 @@ def mha_decode_attention(q, k_cache, v_cache, q_pos, scale: float,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos.astype(jnp.int32), q2, k_cache, v_cache)

@@ -51,16 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the newer pallas API renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-# older pallas has TPUMemorySpace with no HBM member; ANY is its
-# "stays in device memory, kernel DMAs slices itself" space
-_MemorySpace = getattr(pltpu, "MemorySpace",
-                       getattr(pltpu, "TPUMemorySpace", None))
-_HBM = getattr(_MemorySpace, "HBM", _MemorySpace.ANY)
-
-from ..attention import NEG_INF, softcap_scores
+from ..attention import NEG_INF, note_kernel, softcap_scores
 from .flash import _lane_ok
 
 
@@ -193,12 +184,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
             q, k_pool, v_pool, layer, tables, lengths, scale, softcap,
             sliding_window, nblk=nblk, interpret=interpret)
         if out is not None:
+            note_kernel("paged_decode", "paged_v4")
             return out
-    if os.environ.get("TPU_PAGED_V3", "1") == "1":
+    want_v3 = os.environ.get("TPU_PAGED_V3", "1") == "1"
+    if want_v3:
         out = paged_decode_attention_v3(
             q, k_pool, v_pool, layer, tables, lengths, scale, softcap,
             sliding_window, nblk=nblk, interpret=interpret)
         if out is not None:
+            note_kernel("paged_decode", "paged_v3")
             return out
     quant, quant4, k_arr, v_arr = _pool_arrs(k_pool, v_pool)
     B, T, H, hd_q = q.shape
@@ -269,13 +263,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KvH * Gp, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       lengths.astype(jnp.int32), tables.astype(jnp.int32),
       qg, *args[1:])
     out = out.reshape(B, KvH, Gp, hd)
+    # v3 asked for and refused by its tiling rules lands here
+    note_kernel("paged_decode", "paged_v2", fell_back=want_v3)
     return out[:, :, :G, :hd_q].reshape(B, 1, H, hd_q)
 
 
@@ -483,7 +479,7 @@ def paged_decode_attention_v4(q, k_pool, v_pool, layer, tables, lengths,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KvH, Gp, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.reshape(n_total, (1,)).astype(jnp.int32), slot, page, blk,
@@ -633,7 +629,7 @@ def paged_decode_attention_v3(q, k_pool, v_pool, layer, tables, lengths,
     # per-page latency at the cost of depth x page VMEM buffers.
     depth = max(2, int(os.environ.get("TPU_PAGED_DEPTH", "2") or "2"))
 
-    hbm = pl.BlockSpec(memory_space=_HBM)
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, KvH, Gp, hd), lambda b, *pref: (b, 0, 0, 0)),
         hbm, hbm,
@@ -671,7 +667,7 @@ def paged_decode_attention_v3(q, k_pool, v_pool, layer, tables, lengths,
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KvH, Gp, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),

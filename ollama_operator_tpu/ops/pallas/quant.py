@@ -27,10 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the newer pallas API renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
+from ..attention import note_kernel
 from ..quant import GROUP, qmm, qmm4
 
 _BLOCKS = (512, 256, 128, 64, 32)
@@ -86,7 +83,9 @@ def qmm_pallas(x: jax.Array, q: jax.Array, s: jax.Array,
     lanes_ok = interpret or (O % 128 == 0 and bo is not None and
                              bo % 128 == 0)
     if bk is None or bo is None or not lanes_ok:
+        note_kernel("matmul", "xla_int8", fell_back=True)
         return qmm(x, {"q": q, "s": s}, out_dtype=jnp.float32)
+    note_kernel("matmul", "qmm_pallas")
 
     Bp = max(8, B)
     if Bp != B:
@@ -105,7 +104,7 @@ def qmm_pallas(x: jax.Array, q: jax.Array, s: jax.Array,
         out_specs=pl.BlockSpec((Bp, bo), lambda oi, ki: (0, oi)),
         out_shape=jax.ShapeDtypeStruct((Bp, O), jnp.float32),
         scratch_shapes=[pltpu.VMEM((Bp, bo), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, q, s.astype(jnp.float32))
@@ -158,7 +157,9 @@ def qmm4_pallas(x: jax.Array, q4: jax.Array, s: jax.Array,
     lanes_ok = interpret or (O % 128 == 0 and bo is not None and
                              bo % 128 == 0)
     if bk is None or bo is None or not lanes_ok:
+        note_kernel("matmul", "xla_int4", fell_back=True)
         return qmm4(x, {"q4": q4, "s": s}, out_dtype=jnp.float32)
+    note_kernel("matmul", "qmm4_pallas")
 
     Bp = max(8, B)
     if Bp != B:
@@ -177,7 +178,7 @@ def qmm4_pallas(x: jax.Array, q4: jax.Array, s: jax.Array,
         out_specs=pl.BlockSpec((Bp, bo), lambda oi, ki: (0, oi)),
         out_shape=jax.ShapeDtypeStruct((Bp, O), jnp.float32),
         scratch_shapes=[pltpu.VMEM((Bp, bo), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, q4, s.astype(jnp.float32))

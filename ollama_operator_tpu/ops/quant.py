@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .attention import note_kernel
+
 GROUP = 32
 
 # matmul leaves worth quantizing (the big projections). tok_emb stays dense
@@ -300,6 +302,7 @@ def matmul(x: jax.Array, w: Any, out_dtype: Optional[Any] = None,
             y = qmm_pallas(x2, w["q"], w["s"],
                            interpret=(kernels == "interpret"))
         return y.reshape(*lead, -1).astype(out_dtype or x.dtype)
+    note_kernel("matmul", "xla_int4" if is_int4(w) else "xla_int8")
     return (qmm4 if is_int4(w) else qmm)(x, w, out_dtype)
 
 

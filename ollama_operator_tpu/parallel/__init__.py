@@ -1,2 +1,2 @@
-from .mesh import MeshPlan, make_mesh, set_mesh_compat  # noqa: F401
+from .mesh import MeshPlan, make_mesh  # noqa: F401
 from .sharding import params_pspec_tree, shard_params  # noqa: F401

@@ -30,7 +30,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models.config import ModelConfig
 from ..models.decoder import (Params, _attn_scale, _block_cached,
                               _block_chunk, _embed, _unembed)
-from ..ops.attention import shard_map_compat
 from ..ops.rope import rope_angles_cfg
 from .ring_attention import (ring_attention, sp_cache_write,
                              sp_decode_attention)
@@ -82,11 +81,11 @@ def prefill_chunk_sp(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     seq_spec = P(None, None, None, SP_AXIS, None)   # [L,B,KvH,T@sp,hd]
     emb_spec = None if inputs_embeds is None else P(None, SP_AXIS, None)
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(None, SP_AXIS), emb_spec),
         out_specs=(P(None, SP_AXIS, None), seq_spec, seq_spec),
-        axis_names={SP_AXIS})(tokens, inputs_embeds)
+        axis_names={SP_AXIS}, check_vma=False)(tokens, inputs_embeds)
 
 
 def forward_with_cache_sp(params: Params, cfg: ModelConfig,
@@ -140,8 +139,9 @@ def forward_with_cache_sp(params: Params, cfg: ModelConfig,
     if quant:
         cache_spec = {"q": cache_spec,
                       "s": P(None, None, None, SP_AXIS)}
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(None, None), cache_spec, cache_spec, P(None)),
         out_specs=(P(None, None, None), cache_spec, cache_spec),
-        axis_names={SP_AXIS})(tokens, k_cache, v_cache, lengths)
+        axis_names={SP_AXIS}, check_vma=False)(
+        tokens, k_cache, v_cache, lengths)

@@ -71,13 +71,3 @@ def make_mesh(plan: MeshPlan, devices: Optional[Sequence[jax.Device]] = None) ->
         raise ValueError(f"need {plan.n_devices} devices, have {len(devices)}")
     arr = np.array(devices[: plan.n_devices]).reshape(plan.dims)
     return Mesh(arr, AXES)
-
-
-def set_mesh_compat(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` context across jax versions. Older jax has no
-    set_mesh; there the Mesh object itself is the context manager that
-    installs the active mesh."""
-    try:
-        return jax.set_mesh(mesh)
-    except AttributeError:
-        return mesh

@@ -40,7 +40,6 @@ import dataclasses
 
 from ..models.config import ModelConfig
 from ..models.decoder import _attn_scale, Params, _block_cached, _embed, _unembed
-from ..ops.attention import pcast_varying_compat, shard_map_compat
 from ..ops.rope import rope_angles_cfg
 from .sharding import resolve_moe_impl
 
@@ -161,9 +160,10 @@ def forward_with_cache_pp(params: Params, cfg: ModelConfig,
                                [(i, (i + 1) % pp) for i in range(pp)])
             return act, kc, vc, out
 
-        act0 = pcast_varying_compat(jnp.zeros((b, T, D), dtype), PP_AXIS)
-        out0 = pcast_varying_compat(jnp.zeros((M, b, T, D), jnp.float32),
-                                    PP_AXIS)
+        act0 = lax.pcast(jnp.zeros((b, T, D), dtype), (PP_AXIS,),
+                         to="varying")
+        out0 = lax.pcast(jnp.zeros((M, b, T, D), jnp.float32), (PP_AXIS,),
+                         to="varying")
         act, kc, vc, out = lax.fori_loop(0, M + pp - 1, tick,
                                          (act0, kc, vc, out0))
         # replicate the last stage's bank to every device
@@ -171,7 +171,9 @@ def forward_with_cache_pp(params: Params, cfg: ModelConfig,
         return out, kc[None], vc[None]
 
     cache_spec = P(PP_AXIS, None, None, None, None, None)
-    out, kc5, vc5 = shard_map_compat(
+    # check_vma stays on: JAX 0.9's eager shard_map refuses a region that
+    # is manual over part of the mesh with the check off
+    out, kc5, vc5 = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(
             lambda _: P(PP_AXIS), stages), cache_spec, cache_spec,

@@ -39,8 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.attention import (NEG_INF, axis_size_compat, pcast_varying_compat,
-                             softcap_scores)
+from ..ops.attention import NEG_INF, softcap_scores
 
 _FP32 = jnp.float32
 
@@ -102,7 +101,7 @@ def ring_attention(q, k, v, scale: float, axis_name: str = "sp",
     in q.dtype — bitwise semantics of dense causal attention over the full
     sequence.
     """
-    sp = axis_size_compat(axis_name)
+    sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, Tc, H, hd = q.shape
     KvH = k.shape[1]
@@ -115,7 +114,7 @@ def ring_attention(q, k, v, scale: float, axis_name: str = "sp",
     # the accumulated carry is device-varying (per-chunk); mark the literal
     # init as such so both lax.cond branches type-check under check_vma
     carry = jax.tree.map(
-        lambda a: pcast_varying_compat(a, axis_name), carry)
+        lambda a: lax.pcast(a, (axis_name,), to="varying"), carry)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
     for step in range(sp):

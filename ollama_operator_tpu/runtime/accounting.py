@@ -59,17 +59,29 @@ ACCOUNTING_ENABLED = os.environ.get(
 # How many seconds of per-second aggregates /debug/utilization keeps.
 RING_SECONDS = int(os.environ.get("TPU_ACCOUNTING_RING_S", "120"))
 
-# Dense bf16 peak FLOPs/s per chip by TPU generation (public spec sheets).
-# Matched as substrings of jax's device_kind, most specific first.
-PEAK_FLOPS_BY_KIND: Tuple[Tuple[str, float], ...] = (
-    ("v6e", 918e12),
-    ("v6 lite", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5 lite", 197e12),
-    ("v5litepod", 197e12),
-    ("v4", 275e12),
+# Peaks per chip by TPU generation (public spec sheets): dense bf16 FLOP/s
+# and HBM bytes/s. Matched as substrings of jax's device_kind, most
+# specific first. The one table of device peaks in the repo.
+PEAKS_BY_KIND: Tuple[Tuple[str, float, float], ...] = (
+    ("v6e", 918e12, 1640e9),
+    ("v6 lite", 918e12, 1640e9),
+    ("v5p", 459e12, 2765e9),
+    ("v5e", 197e12, 819e9),
+    ("v5 lite", 197e12, 819e9),
+    ("v5litepod", 197e12, 819e9),
+    ("v4", 275e12, 1228e9),
 )
+
+
+def device_peaks(device_kind: str) -> Tuple[float, float]:
+    """(peak bf16 FLOP/s, peak HBM bytes/s) of one chip of this kind.
+    A device that is not in the table is an error, not a default."""
+    low = device_kind.lower()
+    for key, flops, hbm_bps in PEAKS_BY_KIND:
+        if key in low:
+            return flops, hbm_bps
+    raise KeyError(f"no peaks known for device kind {device_kind!r}; "
+                   f"add it to accounting.PEAKS_BY_KIND with its source")
 
 
 def detect_peak_flops() -> Tuple[float, str]:
@@ -92,11 +104,10 @@ def detect_peak_flops() -> Tuple[float, str]:
         kind = str(getattr(dev, "device_kind", "") or dev.platform)
     except Exception:
         return 0.0, "unknown"
-    low = kind.lower()
-    for key, peak in PEAK_FLOPS_BY_KIND:
-        if key in low:
-            return peak, kind
-    return 0.0, kind
+    try:
+        return device_peaks(kind)[0], kind
+    except KeyError:
+        return 0.0, kind
 
 
 # --- analytic FLOPs model ---------------------------------------------------

@@ -190,8 +190,9 @@ declare("TPU_WEIGHT_CACHE", "str", None, "server",
 declare("TPU_STORE_ONLY", "bool", 0, "server",
         "1 runs registry/store mode with no inference engine")
 declare("TPU_XLA_CACHE", "bool", 1, "server",
-        "0 disables the persistent XLA compilation cache beside the "
-        "weight cache")
+        "0 disables the persistent XLA compilation cache (kept where "
+        "JAX_COMPILATION_CACHE_DIR says, else at one fixed path in the "
+        "checkout)")
 declare("TPU_EXPECT_PLATFORM", "str", None, "server",
         "fail startup unless the JAX backend matches (tpu|cpu); set by "
         "the operator on TPU pods")

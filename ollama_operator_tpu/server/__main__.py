@@ -112,31 +112,26 @@ def main(argv=None):
     joined = False
     if not args.store_only:
         import jax
-        # honor an explicit JAX_PLATFORMS (e.g. cpu for kind/e2e pods) even
-        # where a sitecustomize force-sets the platform list programmatically
-        if os.environ.get("JAX_PLATFORMS"):
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
         # multi-host slice? join the jax.distributed world BEFORE touching
         # the backend (operator-rendered env; no-op single-host)
         from ..parallel.distributed import maybe_initialize
         joined = maybe_initialize()
         if args.cache and os.environ.get("TPU_XLA_CACHE", "1") != "0":
-            # persistent XLA compilation cache beside the weight cache: pod
-            # restarts skip the multi-program warm-up compiles.
+            # persistent XLA compilation cache for pods that keep a weight
+            # cache: restarts skip the multi-program warm-up compiles.
+            # Where it lives is compile_cache's to say, not --cache's.
             # TPU_XLA_CACHE=0 opts out: some CPU hosts miscompile on the
             # executable-deserialization path (wrong decode tokens), the
             # same instability that keeps the test-suite cache opt-in
-            xla_cache = os.path.join(args.cache, "xla-cache")
-            os.makedirs(xla_cache, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", xla_cache)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              1.0)
+            from ..runtime import compile_cache
+            print(f"compile cache: {compile_cache.enable()}",
+                  file=sys.stderr)
         if args.profile_port:
             jax.profiler.start_server(args.profile_port)
         devices = jax.devices()
-        # a TPU pod silently falling back to CPU (tunnel/driver hiccup)
-        # must crash loudly, not serve garbage at 1/100th speed: the
-        # operator sets TPU_EXPECT_PLATFORM=tpu on runtime: tpu pods
+        # a TPU pod that came up on the CPU must crash loudly, not serve
+        # at 1/100th speed: the operator sets TPU_EXPECT_PLATFORM=tpu on
+        # runtime: tpu pods
         expect = os.environ.get("TPU_EXPECT_PLATFORM")
         if expect and jax.default_backend() != expect:
             p.error(f"expected JAX platform {expect!r} but initialised "
@@ -266,6 +261,10 @@ def main(argv=None):
             pass
         control_plane.close()
     manager.shutdown()
+    if not args.store_only:
+        # the life-time peak of every device, in the pod's last log lines
+        from .app import device_memory
+        FLIGHT.record("device_memory", devices=device_memory())
     FLIGHT.dump("shutdown")
 
 

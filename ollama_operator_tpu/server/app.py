@@ -502,9 +502,11 @@ class ModelManager:
                 dt = np.float32
             # parse/transcode the new model (host memory) BEFORE tearing the
             # old one down: a corrupt pull must not leave the server empty
+            t_load = [time.perf_counter()]   # phase boundaries, in order
             cfg, params, tok_md = transcode_load(
                 gguf_path, cache_dir=self.cache_dir, dtype=dt,
                 digest=digest.replace("sha256:", "")[:24] or None)
+            t_load.append(time.perf_counter())
             adapter_path = layers.get(MT_ADAPTER)
             if adapter_path:
                 # Modelfile ADAPTER: merge W += (alpha/r)·BA host-side so
@@ -536,7 +538,14 @@ class ModelManager:
                 if engine_dtype == "int4":
                     from ..ops.quant import int4_mm_kernels
                     cfg = int4_mm_kernels(cfg, self.mesh)
-            params = jax.tree_util.tree_map(jnp.asarray, params)
+            t_load.append(time.perf_counter())
+            if self.mesh is None:
+                params = jax.tree_util.tree_map(jnp.asarray, params)
+                jax.block_until_ready(params)
+            # (on a mesh the engine places every leaf straight into its
+            # sharding from host memory: staging the tree here first
+            # would park a whole copy of the weights on device 0)
+            t_load.append(time.perf_counter())
             vision = None
             proj_path = layers.get(MT_PROJECTOR)
             if proj_path:
@@ -569,12 +578,38 @@ class ModelManager:
             # effective serving config, for /api/ps observability (the
             # auto-resolved dtype is otherwise invisible to clients)
             self.loaded.serving_dtype = engine_dtype
+            t_load.append(time.perf_counter())
+            self._record_load(name.short, engine_dtype, ecfg, t_load)
             # fresh deadline under this same lock: a stale expiry from the
             # previous model must never reap the one we just installed
             self._last_ka = self.default_keep_alive
             self.expires_at = (None if self.default_keep_alive is None
                                else time.monotonic() + self.default_keep_alive)
             return self.loaded
+
+    @staticmethod
+    def _record_load(model: str, serving_dtype: str, ecfg: EngineConfig,
+                     t_load) -> None:
+        """One flight-recorder event per model load: the serving config
+        the tri-state defaults resolved to, the seconds each phase took,
+        which dequant path ran, and the bytes every local device holds
+        now (GET /debug/events?kind=model_load). quantize_s spans all
+        host work between transcode and upload (adapter merge and
+        tokenizer build too). The warm plan's own event (kind=warm_plan)
+        sits just before it."""
+        from ..gguf import native
+        cache_dt = ecfg.cache_dtype
+        phases = [round(b - a, 3) for a, b in zip(t_load, t_load[1:])]
+        FLIGHT.record(
+            "model_load", model=model, serving_dtype=serving_dtype,
+            kv_dtype=(cache_dt if isinstance(cache_dt, str)
+                      else np.dtype(cache_dt).name),
+            paged=bool(ecfg.paged), max_slots=ecfg.max_slots,
+            page_size=ecfg.page_size, n_pages=ecfg.n_pages,
+            decode_chunk=ecfg.decode_chunk, max_seq_len=ecfg.max_seq_len,
+            transcode_s=phases[0], quantize_s=phases[1],
+            upload_s=phases[2], engine_warm_s=phases[3],
+            dequant=native.path_used(), devices=device_memory())
 
     def require_loaded(self, ref: str, keep_alive=None) -> LoadedModel:
         ka = self.default_keep_alive
@@ -2025,6 +2060,23 @@ def _hbm_bytes_in_use() -> float:
     if not stats:
         return 0.0
     return float(stats.get("bytes_in_use", 0.0))
+
+
+def device_memory() -> list:
+    """What every local device is and holds, from its memory_stats (all
+    zeros where the backend reports none, as the CPU does): the
+    per-device view the device-0 gauge above cannot give of a mesh."""
+    import jax
+    out = []
+    for d in jax.local_devices():
+        st = d.memory_stats() or {}
+        out.append({"id": d.id, "platform": d.platform,
+                    "kind": d.device_kind,
+                    "bytes_in_use": int(st.get("bytes_in_use", 0)),
+                    "peak_bytes_in_use": int(
+                        st.get("peak_bytes_in_use", 0)),
+                    "bytes_limit": int(st.get("bytes_limit", 0))})
+    return out
 
 
 def serve(manager: ModelManager, host: str = "0.0.0.0", port: int = 11434

@@ -21,6 +21,7 @@ from ollama_operator_tpu.runtime.accounting import (NULL_ACCOUNTING,
                                                     attn_span_flops,
                                                     decode_flops,
                                                     detect_peak_flops,
+                                                    device_peaks,
                                                     make_accounting,
                                                     per_token_flops,
                                                     prefill_flops,
@@ -114,6 +115,20 @@ def test_peak_flops_bad_override_falls_through(monkeypatch):
     monkeypatch.setenv("TPU_PEAK_FLOPS", "not-a-number")
     peak, kind = detect_peak_flops()
     assert kind != "override"
+
+
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", (197e12, 819e9)),     # what jax reports for a v5e
+    ("TPU v4", (275e12, 1228e9)),
+])
+def test_device_peaks_by_kind(kind, want):
+    assert device_peaks(kind) == want
+
+
+def test_device_peaks_unknown_device_is_an_error():
+    # an unlisted device must never read as a v5e
+    with pytest.raises(KeyError):
+        device_peaks("cpu")
 
 
 # -- goodput / occupancy accumulator -----------------------------------

@@ -1,0 +1,235 @@
+"""The serving kernels compile for the chip, at tinyllama's real shapes.
+
+Interpret mode on a CPU never meets Mosaic's tiling rules; these compiles
+do, for a v5e:2x2 that is described and not attached (the TPU's compiler
+ships with the installed jaxlib). Each case lowers one kernel the
+zero-config tinyllama server runs (int8 weights, int8 paged KV, 64 slots,
+128-token pages, head_dim 64 padded to the 128-lane tile) and asserts the
+compiled program really holds a Mosaic kernel (`tpu_custom_call`), not the
+einsum a dispatcher would quietly fall back to. Nothing runs: a compile
+that passes says nothing about results or times (chip_smoke.py does).
+
+One file on purpose: only one process may load the TPU's library, so these
+tests must land on one xdist worker, and the topology is described inside
+a fixture, never at import.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ollama_operator_tpu.models import decoder
+from ollama_operator_tpu.models.config import PRESETS
+from ollama_operator_tpu.ops.attention import chunk_attention
+from ollama_operator_tpu.ops.pallas.flash import (decode_attention,
+                                                  flash_prefill)
+from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention_v3
+from ollama_operator_tpu.ops.pallas.quant import qmm4_pallas, qmm_pallas
+from ollama_operator_tpu.parallel.mesh import AXES, MeshPlan
+from ollama_operator_tpu.runtime.engine import (EngineConfig,
+                                                resolve_serving_defaults)
+
+CFG = dataclasses.replace(PRESETS["tinyllama"], kernels="pallas")
+H, KVH, HD = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+SCALE = 1.0 / HD ** 0.5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it off here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def serving(one_chip):
+    """The EngineConfig a zero-config tinyllama start resolves on a TPU,
+    from the engine's own resolver (the backend probe is steered here, in
+    the test): slots, page size and pool pages are read, not guessed."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        ecfg = resolve_serving_defaults(
+            EngineConfig(max_slots=0, max_seq_len=4096, decode_chunk=0,
+                         cache_dtype=jnp.int8, paged=None, page_size=0,
+                         n_pages=None), CFG, None)
+    finally:
+        mp.undo()
+    assert (ecfg.paged, ecfg.max_slots, ecfg.page_size,
+            ecfg.decode_chunk) == (True, 64, 128, 32)
+    return ecfg
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _pool_shapes(ecfg, sharding, scale_sharding=None, n_pages=None):
+    """k/v pool ShapeDtypeStructs in the engine's own layout
+    (runtime/engine.py __init__): [L, P, KvH, ps, hd padded to 128] int8
+    codes and [L, P, KvH, ps padded to 128] f32 scales."""
+    ps = ecfg.page_size
+    pages = n_pages if n_pages is not None else ecfg.n_pages + 1
+    hd_pool = -(-HD // 128) * 128
+    sp_pool = -(-ps // 128) * 128
+    q = jax.ShapeDtypeStruct((CFG.n_layers, pages, KVH, ps, hd_pool),
+                             jnp.int8, sharding=sharding)
+    s = jax.ShapeDtypeStruct((CFG.n_layers, pages, KVH, sp_pool),
+                             jnp.float32,
+                             sharding=scale_sharding or sharding)
+    return {"q": q, "s": s}
+
+
+# (K, O): tinyllama's MLP projections, the widest matmuls it serves
+@pytest.mark.parametrize("K,O", [(2048, 5632), (5632, 2048)])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_dequant_matmul_compiles(one_chip, serving, K, O, bits):
+    B = serving.max_slots
+    x = jax.ShapeDtypeStruct((B, K), jnp.bfloat16, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((K // 32, O), jnp.float32, sharding=one_chip)
+    if bits == 8:
+        q = jax.ShapeDtypeStruct((K, O), jnp.int8, sharding=one_chip)
+        txt = _compiled_text(qmm_pallas, x, q, s)
+    else:
+        q = jax.ShapeDtypeStruct((K // 2, O), jnp.uint8, sharding=one_chip)
+        txt = _compiled_text(qmm4_pallas, x, q, s)
+    assert "tpu_custom_call" in txt
+
+
+def test_paged_decode_v3_compiles_on_engine_pool(one_chip, serving):
+    B, ps = serving.max_slots, serving.page_size
+    nblk = CFG.max_seq_len // ps
+    pool = _pool_shapes(serving, one_chip)
+    q = jax.ShapeDtypeStruct((B, 1, H, HD), jnp.bfloat16, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((B, nblk), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+
+    def fn(q, kp, vp, tables, lengths):
+        out = paged_decode_attention_v3(
+            q, kp, vp, jnp.int32(0), tables, lengths, SCALE, nblk=nblk)
+        assert out is not None, "v3 refused the engine's own pool layout"
+        return out
+
+    assert "tpu_custom_call" in _compiled_text(fn, q, pool, pool, tables,
+                                               lengths)
+
+
+@pytest.mark.parametrize("T", [64, 2048])   # smallest and largest bucket
+def test_flash_prefill_compiles(one_chip, T):
+    q = jax.ShapeDtypeStruct((1, T, H, HD), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, KVH, T, HD), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fn(q, k, v):
+        out = flash_prefill(q, k, v, SCALE)
+        assert out is not None, "flash_prefill refused a prefill bucket"
+        return out
+
+    assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv)
+
+
+def test_dense_decode_kernel_compiles(one_chip):
+    B, S = 8, CFG.max_seq_len          # the dense default: 8 slots
+    q = jax.ShapeDtypeStruct((B, 1, H, HD), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, KVH, S, HD), jnp.bfloat16,
+                              sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+
+    def fn(q, k, v, pos):
+        out = decode_attention(q, k, v, pos, SCALE)
+        assert out is not None, "decode_attention refused the dense cache"
+        return out
+
+    assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv, pos)
+
+
+@pytest.mark.parametrize("dp,tp", [(1, 4), (2, 2)])
+def test_paged_kernel_compiles_inside_manual_shard_map(topo, serving,
+                                                       dp, tp):
+    """On a mesh the paged kernel runs inside a manual shard_map — the
+    tp-manual attend (decoder._paged_attend) or the dp/tp-manual
+    write+attend (decoder._paged_write_attend_dp) — each device seeing
+    its own heads (and, under dp, its own sub-pool and table rows)."""
+    plan = MeshPlan(dp=dp, tp=tp)
+    mesh = Mesh(np.array(topo.devices).reshape(plan.dims), AXES)
+    B, ps = serving.max_slots, serving.page_size
+    nblk = CFG.max_seq_len // ps
+    pg = "dp" if dp > 1 else None
+
+    def sh(*spec):
+        return NamedSharding(mesh, P(*spec))
+
+    # under dp each shard owns its own sub-pool (own trash page)
+    pages = dp * (-(-serving.n_pages // dp) + 1)
+    pool = _pool_shapes(serving, sh(None, pg, "tp", None, None),
+                        sh(None, pg, "tp", None), n_pages=pages)
+    q = jax.ShapeDtypeStruct((B, 1, H, HD), jnp.bfloat16,
+                             sharding=sh(pg, None, "tp", None))
+    tables = jax.ShapeDtypeStruct((B, nblk), jnp.int32, sharding=sh(pg, None))
+    lengths = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sh(pg))
+    layer = jnp.int32(0)
+    if dp == 1:
+        def fn(q, kp, vp, tables, lengths):
+            return decoder._paged_attend(
+                CFG, q, kp, vp, layer, tables, lengths, None, SCALE, nblk,
+                mesh, True)
+        txt = _compiled_text(fn, q, pool, pool, tables, lengths)
+    else:
+        kv = jax.ShapeDtypeStruct((B, KVH, 1, HD), jnp.bfloat16,
+                                  sharding=sh("dp", "tp", None, None))
+        pos = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=sh("dp", None))
+        mask = jax.ShapeDtypeStruct((B, 1, 1, nblk * ps), jnp.float32,
+                                    sharding=sh("dp", None, None, None))
+
+        def fn(q, k, v, kp, vp, tables, lengths, pos, mask):
+            return decoder._paged_write_attend_dp(
+                CFG, q, k, v, kp, vp, layer, tables, lengths, pos, mask,
+                SCALE, nblk, True, False, mesh, "tp")
+        txt = _compiled_text(fn, q, kv, kv, pool, pool, tables, lengths,
+                             pos, mask)
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_prefill_compiles_inside_manual_shard_map(topo):
+    """The admit program of a tp=4 server: ops/attention.chunk_attention
+    wraps flash_prefill in a manual shard_map, 8 query heads and one KV
+    head to a device."""
+    mesh = Mesh(np.array(topo.devices).reshape(MeshPlan(tp=4).dims), AXES)
+    T = 512
+    q = jax.ShapeDtypeStruct(
+        (1, T, H, HD), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, None, "tp", None)))
+    kv = jax.ShapeDtypeStruct(
+        (1, KVH, T, HD), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, "tp", None, None)))
+
+    def fn(q, k, v):
+        return chunk_attention(CFG, q, k, v, None, SCALE, mesh=mesh)
+
+    assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv)

@@ -89,6 +89,9 @@ class ExecKubelet:
         # the "volume mount": PVC paths land in our tmp dir
         env["OLLAMA_MODELS"] = os.path.join(self.pvc, "models")
         env["TPU_WEIGHT_CACHE"] = os.path.join(self.pvc, "tpu-cache")
+        if "JAX_COMPILATION_CACHE_DIR" in env:
+            env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+                self.pvc, "tpu-cache", "xla-cache")
         env.update({
             "OLLAMA_HOST_BIND": "127.0.0.1",
             "OLLAMA_PORT": str(port),
@@ -182,6 +185,8 @@ class ExecKubelet:
                 # only the transcode/XLA cache is per-pod to avoid
                 # concurrent-write races
                 "TPU_WEIGHT_CACHE": os.path.join(self.pvc, f"cache-{i}"),
+                "JAX_COMPILATION_CACHE_DIR": os.path.join(
+                    self.pvc, f"cache-{i}", "xla-cache"),
             }
             for ic in tmpl.get("initContainers") or []:
                 p = self._run_container(ic, ports[i], extra)

@@ -105,6 +105,9 @@ class TestModelDeployment:
         assert env["TPU_EXPECT_PLATFORM"] == "tpu"
         assert env["TPU_TENSOR_PARALLEL"] == "4"
         assert env["TPU_PRELOAD_MODEL"] == "phi"
+        # the compile cache is placed from outside, beside the weights
+        assert env["JAX_COMPILATION_CACHE_DIR"] == \
+            env["TPU_WEIGHT_CACHE"] + "/xla-cache"
 
     def test_external_pvc_used_without_creating(self):
         m = model_obj(runtime="cpu",

@@ -9,7 +9,7 @@ import pytest
 
 from ollama_operator_tpu.models import config as cfglib
 from ollama_operator_tpu.models import decoder
-from ollama_operator_tpu.parallel import MeshPlan, make_mesh, set_mesh_compat
+from ollama_operator_tpu.parallel import MeshPlan, make_mesh
 from ollama_operator_tpu.parallel import pipeline as PL
 from ollama_operator_tpu.parallel.sharding import shard_params
 
@@ -86,7 +86,7 @@ def test_pp_tp_mesh_matches_dense():
     ref, _, _ = decoder.prefill_chunk(params, cfg, tokens)
 
     mesh = make_mesh(MeshPlan(pp=2, tp=4))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         sharded = shard_params(params, mesh, cfg)
         fn = jax.jit(lambda p, t: PL.prefill_chunk_pp(p, cfg, t, mesh))
         logits, _, _ = fn(sharded, tokens)
@@ -105,7 +105,7 @@ def test_pp_moe_ep_mesh_matches_dense():
     ref, _, _ = decoder.prefill_chunk(params, cfg, tokens)
 
     mesh = make_mesh(MeshPlan(pp=2, ep=2, tp=2))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         sharded = shard_params(params, mesh, cfg)
         fn = jax.jit(lambda p, t: PL.prefill_chunk_pp(p, cfg, t, mesh))
         logits, _, _ = fn(sharded, tokens)

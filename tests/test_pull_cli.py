@@ -1,4 +1,4 @@
-"""Puller init-container client (server/pull.py): the retry taxonomy.
+"""Puller init-container client (server/pull.py): the classes of retry.
 
 The init container must retry while the store is coming up (connection
 refused, 5xx) but exit non-zero immediately on a definitive 4xx so bad

@@ -13,7 +13,7 @@ from ollama_operator_tpu.models import decoder
 from ollama_operator_tpu.ops import quant as Q
 from ollama_operator_tpu.ops.pallas.quant import qmm_pallas
 from ollama_operator_tpu.parallel import (MeshPlan, make_mesh,
-                                           set_mesh_compat, shard_params)
+                                           shard_params)
 from ollama_operator_tpu.runtime.engine import Engine, EngineConfig, SlotOptions
 
 rng = np.random.default_rng(5)
@@ -109,7 +109,7 @@ def test_quantized_params_tp_sharded_matches_single_device():
     ref, _, _ = decoder.prefill_chunk(qparams, cfg, tokens)
 
     mesh = make_mesh(MeshPlan(tp=4))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         sharded = shard_params(qparams, mesh, cfg)
         fn = jax.jit(lambda p, t: decoder.prefill_chunk(p, cfg, t))
         out, _, _ = fn(sharded, tokens)
@@ -259,7 +259,7 @@ def test_int4_params_tp_sharded_matches_single_device():
     ref, _, _ = decoder.prefill_chunk(qparams, cfg, tokens)
 
     mesh = make_mesh(MeshPlan(tp=4))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         sharded = shard_params(qparams, mesh, cfg)
         fn = jax.jit(lambda p, t: decoder.prefill_chunk(p, cfg, t))
         out, _, _ = fn(sharded, tokens)

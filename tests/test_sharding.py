@@ -11,7 +11,7 @@ import numpy as np
 from ollama_operator_tpu.models import config as cfglib
 from ollama_operator_tpu.models import decoder
 from ollama_operator_tpu.parallel import (MeshPlan, make_mesh,
-                                           set_mesh_compat, shard_params)
+                                           shard_params)
 from ollama_operator_tpu.parallel.sharding import (
     kv_cache_pspec, params_sharding_tree)
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -34,7 +34,7 @@ def test_tp_sharded_prefill_matches_single_device():
     ref, ref_k, _ = decoder.prefill_chunk(params, cfg, tokens)
 
     mesh = make_mesh(MeshPlan(dp=1, sp=1, tp=4))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         sharded = shard_params(params, mesh)
         fn = jax.jit(lambda p, t: decoder.prefill_chunk(p, cfg, t))
         out, ks, _ = fn(sharded, tokens)
@@ -59,7 +59,7 @@ def test_dp_tp_sharded_decode_matches_single_device():
                                                    k_cache, v_cache, lengths)
 
     mesh = make_mesh(MeshPlan(dp=2, sp=1, tp=2))
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         p_sh = shard_params(params, mesh)
         cache_sh = NamedSharding(mesh, kv_cache_pspec())
         kc = jax.device_put(k_cache, cache_sh)
