@@ -1,0 +1,11 @@
+"""Device time of one decode step under the scopes that multiply by weights
+(``attn.qkv``, ``attn.out``, ``mlp``, ``lm_head``; dequantisation included):
+self time of the decode module's operations in the trace, over the steps of
+its complete runs (benchmark/trace_spans.py)."""
+from benchmark import trace_spans
+
+UNIT = "ms"
+
+
+def read(ctx):
+    return trace_spans.step_ms(ctx, "matmul")

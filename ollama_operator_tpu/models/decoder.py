@@ -48,6 +48,7 @@ from ..ops.attention import (attend_hf, cached_attention, causal_mask,
                              chunk_attention, note_kernel)
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rope import apply_rope, rope_angles_cfg
+from ..runtime.trace import device_scope
 from .config import ModelConfig
 
 Params = Dict[str, Any]
@@ -257,10 +258,20 @@ def _moe_mlp(cfg: ModelConfig, lp, x):
     """
     B, T, D = x.shape
     xf = x.reshape(B * T, D)
-    gates = _moe_gates(cfg, lp, xf)                          # [N, E] fp32
+    with device_scope("moe.route"):
+        gates = _moe_gates(cfg, lp, xf)                      # [N, E] fp32
+    with device_scope("moe.experts"):
+        y = _moe_experts(cfg, lp, xf, gates)
+    return y.astype(x.dtype).reshape(B, T, D)
+
+
+def _moe_experts(cfg: ModelConfig, lp, xf, gates):
+    """The experts' matmuls and the gated combine of ``_moe_mlp``:
+    [N, D] tokens and [N, E] gates to [N, D] fp32."""
+    N, D = xf.shape
     impl = cfg.moe_impl
     if impl == "auto":
-        impl = "einsum" if B * T <= 256 else "scan"
+        impl = "einsum" if N <= 256 else "scan"
     if impl == "einsum":
         h = jnp.einsum("nd,edf->enf", xf, lp["we_gate"])
         u = jnp.einsum("nd,edf->enf", xf, lp["we_up"])
@@ -271,7 +282,7 @@ def _moe_mlp(cfg: ModelConfig, lp, x):
             wg, wu, wd, g = ew                   # [D,F] [D,F] [F,D] [N]
             he = _act(cfg, xf @ wg) * (xf @ wu)
             return acc + g[:, None] * (he @ wd).astype(jnp.float32), None
-        acc0 = jnp.zeros((B * T, D), jnp.float32)
+        acc0 = jnp.zeros((N, D), jnp.float32)
         y, _ = lax.scan(body, acc0, (lp["we_gate"], lp["we_up"],
                                      lp["we_down"], gates.T))
     if "we_sh_gate" in lp:
@@ -282,9 +293,10 @@ def _moe_mlp(cfg: ModelConfig, lp, x):
         sg = jax.nn.sigmoid(
             (xf @ lp["sh_gate"]).astype(jnp.float32))      # [N, 1]
         y = y + sg * sh
-    return y.astype(x.dtype).reshape(B, T, D)
+    return y
 
 
+@device_scope("mlp")
 def _mlp(cfg: ModelConfig, lp, x):
     if cfg.n_experts:
         return _moe_mlp(cfg, lp, x)
@@ -331,6 +343,7 @@ def fuse_qkv_params(params: Params, cfg: ModelConfig) -> Params:
     return {**params, "layers": layers}
 
 
+@device_scope("attn.qkv")
 def _qkv(cfg: ModelConfig, lp, h, cos, sin):
     B, T, _ = h.shape
     if "wqkv" in lp:
@@ -362,6 +375,7 @@ def _qkv(cfg: ModelConfig, lp, h, cos, sin):
     return q, k, v
 
 
+@device_scope("attn.out")
 def _proj_out(cfg, lp, attn_out, B, T):
     o = _mm(cfg, attn_out.reshape(B, T, -1), lp["wo"])
     if "bo" in lp:
@@ -374,14 +388,22 @@ def _residual(cfg: ModelConfig, lp, x, h, attn):
     if cfg.post_norms:
         # gemma2 sandwich norms: attn/mlp OUTPUTS normed before the adds
         attn = _norm(cfg, attn, lp["post_attn_norm_w"])
+    # the residual adds carry their branch's scope: XLA fuses each into the
+    # matmul that feeds it and names the fusion after its root, the add
     if cfg.parallel_block:
-        return x + attn + _mlp(cfg, lp, h)
-    x = x + rm * attn
+        with device_scope("attn.out"):
+            x = x + attn
+        m = _mlp(cfg, lp, h)
+        with device_scope("mlp"):
+            return x + m
+    with device_scope("attn.out"):
+        x = x + rm * attn
     h2 = _norm(cfg, x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
     m = _mlp(cfg, lp, h2)
     if cfg.post_norms:
         m = _norm(cfg, m, lp["post_ffw_norm_w"])
-    return x + rm * m
+    with device_scope("mlp"):
+        return x + rm * m
 
 
 def _block_chunk(cfg: ModelConfig, lp, x, cos, sin, mask, scale,
@@ -395,14 +417,16 @@ def _block_chunk(cfg: ModelConfig, lp, x, cos, sin, mask, scale,
     q, k, v = _qkv(cfg, lp, h, cos, sin)
     k = k.transpose(0, 2, 1, 3)
     v = v.transpose(0, 2, 1, 3)
-    if attn_fn is not None:
-        attn = attn_fn(q, k, v)
-    elif cfg.altern_sliding:
-        # per-layer window rides the mask (traced); kernel dispatch needs
-        # a static window, so alternating archs stay on the einsum path
-        attn = attend_hf(q, k, v, mask, scale, cfg.attn_softcap)
-    else:
-        attn = chunk_attention(cfg, q, k, v, mask, scale, mesh=mesh)
+    with device_scope("attn.core"):
+        if attn_fn is not None:
+            attn = attn_fn(q, k, v)
+        elif cfg.altern_sliding:
+            # per-layer window rides the mask (traced); kernel dispatch
+            # needs a static window, so alternating archs stay on the
+            # einsum path
+            attn = attend_hf(q, k, v, mask, scale, cfg.attn_softcap)
+        else:
+            attn = chunk_attention(cfg, q, k, v, mask, scale, mesh=mesh)
     attn = _proj_out(cfg, lp, attn, B, T)
     return _residual(cfg, lp, x, h, attn), (k, v)
 
@@ -423,24 +447,30 @@ def _block_cached(cfg: ModelConfig, lp, x, cos, sin, k_cache, v_cache,
     q, k, v = _qkv(cfg, lp, h, cos, sin)
     k = k.transpose(0, 2, 1, 3)                       # [B, KvH, T, hd]
     v = v.transpose(0, 2, 1, 3)
-    if write_fn is None:
-        KvH = k.shape[1]
-        bidx = jnp.arange(B)[:, None, None]
-        hidx = jnp.arange(KvH)[None, :, None]
-        pidx = write_pos[:, None, :]
-        k_cache = k_cache.at[bidx, hidx, pidx].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[bidx, hidx, pidx].set(v.astype(v_cache.dtype))
-    else:
-        k_cache, v_cache = write_fn(k_cache, v_cache, k, v, write_pos)
-    if attn_fn is None:
-        attn = cached_attention(cfg, q, k_cache, v_cache, mask, write_pos,
-                                scale, attn_len=attn_len, mesh=mesh)
-    else:
-        attn = attn_fn(q, k_cache, v_cache, write_pos)
+    with device_scope("attn.kv_write"):
+        if write_fn is None:
+            KvH = k.shape[1]
+            bidx = jnp.arange(B)[:, None, None]
+            hidx = jnp.arange(KvH)[None, :, None]
+            pidx = write_pos[:, None, :]
+            k_cache = k_cache.at[bidx, hidx, pidx].set(
+                k.astype(k_cache.dtype))
+            v_cache = v_cache.at[bidx, hidx, pidx].set(
+                v.astype(v_cache.dtype))
+        else:
+            k_cache, v_cache = write_fn(k_cache, v_cache, k, v, write_pos)
+    with device_scope("attn.core"):
+        if attn_fn is None:
+            attn = cached_attention(cfg, q, k_cache, v_cache, mask,
+                                    write_pos, scale, attn_len=attn_len,
+                                    mesh=mesh)
+        else:
+            attn = attn_fn(q, k_cache, v_cache, write_pos)
     attn = _proj_out(cfg, lp, attn, B, T)
     return _residual(cfg, lp, x, h, attn), k_cache, v_cache
 
 
+@device_scope("embed")
 def _embed(cfg: ModelConfig, params: Params, tokens):
     x = params["tok_emb"][tokens]
     if cfg.emb_scale:
@@ -450,6 +480,7 @@ def _embed(cfg: ModelConfig, params: Params, tokens):
     return x
 
 
+@device_scope("lm_head")
 def _unembed(cfg: ModelConfig, params: Params, x):
     x = _norm(cfg, x, params["out_norm_w"], params.get("out_norm_b"))
     if not cfg.tie_embeddings and Q.is_quantized(params["lm_head"]):
@@ -588,30 +619,34 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         v = v.transpose(0, 2, 1, 3)
         if quant:
             from ..ops import quant_cache as QC
-            kq, ks = QC.quantize_kv(k)
-            vq, vs = QC.quantize_kv(v)
-            kc = {"q": kc["q"].at[i, bidx, hidx, pidx].set(kq),
-                  "s": kc["s"].at[i, bidx, hidx, pidx].set(ks)}
-            vc = {"q": vc["q"].at[i, bidx, hidx, pidx].set(vq),
-                  "s": vc["s"].at[i, bidx, hidx, pidx].set(vs)}
-            kwin = {"q": window(kc["q"], i, (1, B, KvH, A, hd)),
-                    "s": window(kc["s"], i, (1, B, KvH, A))}
-            vwin = {"q": window(vc["q"], i, (1, B, KvH, A, hd)),
-                    "s": window(vc["s"], i, (1, B, KvH, A))}
-            attn = QC.attend_hf_q(q, kwin, vwin, mask_l, scale,
-                                  cfg.attn_softcap, attn_len=A)
+            with device_scope("attn.kv_write"):
+                kq, ks = QC.quantize_kv(k)
+                vq, vs = QC.quantize_kv(v)
+                kc = {"q": kc["q"].at[i, bidx, hidx, pidx].set(kq),
+                      "s": kc["s"].at[i, bidx, hidx, pidx].set(ks)}
+                vc = {"q": vc["q"].at[i, bidx, hidx, pidx].set(vq),
+                      "s": vc["s"].at[i, bidx, hidx, pidx].set(vs)}
+            with device_scope("attn.core"):
+                kwin = {"q": window(kc["q"], i, (1, B, KvH, A, hd)),
+                        "s": window(kc["s"], i, (1, B, KvH, A))}
+                vwin = {"q": window(vc["q"], i, (1, B, KvH, A, hd)),
+                        "s": window(vc["s"], i, (1, B, KvH, A))}
+                attn = QC.attend_hf_q(q, kwin, vwin, mask_l, scale,
+                                      cfg.attn_softcap, attn_len=A)
         else:
-            kc = kc.at[i, bidx, hidx, pidx].set(k.astype(kc.dtype))
-            vc = vc.at[i, bidx, hidx, pidx].set(v.astype(vc.dtype))
-            kwin = window(kc, i, (1, B, KvH, A, hd))
-            vwin = window(vc, i, (1, B, KvH, A, hd))
-            if cfg.altern_sliding:
-                attn = attend_hf(q, kwin, vwin, mask_l, scale,
-                                 cfg.attn_softcap)
-            else:
-                attn = cached_attention(cfg, q, kwin, vwin, mask_l,
-                                        positions, scale, attn_len=A,
-                                        mesh=mesh)
+            with device_scope("attn.kv_write"):
+                kc = kc.at[i, bidx, hidx, pidx].set(k.astype(kc.dtype))
+                vc = vc.at[i, bidx, hidx, pidx].set(v.astype(vc.dtype))
+            with device_scope("attn.core"):
+                kwin = window(kc, i, (1, B, KvH, A, hd))
+                vwin = window(vc, i, (1, B, KvH, A, hd))
+                if cfg.altern_sliding:
+                    attn = attend_hf(q, kwin, vwin, mask_l, scale,
+                                     cfg.attn_softcap)
+                else:
+                    attn = cached_attention(cfg, q, kwin, vwin, mask_l,
+                                            positions, scale, attn_len=A,
+                                            mesh=mesh)
         attn = _proj_out(cfg, lp, attn, B, T)
         x = _residual(cfg, lp, x, h, attn)
         return (x, kc, vc), None
@@ -673,6 +708,7 @@ def _gather_pages(pool, i, tbl, ps: Optional[int] = None):
     return pages.transpose(0, 2, 1, 3).reshape(B, KvH, NA * psp)
 
 
+@device_scope("attn.kv_write")
 def paged_insert(cfg: ModelConfig, k_pool, v_pool, ks, vs, table_row,
                  n_valid):
     """Insert a fresh B=1 prefill chunk (ks/vs [L, 1, KvH, Tb, hd] from
@@ -789,6 +825,7 @@ def _paged_dp_axes(cfg: ModelConfig, mesh, KvH: int):
     return "dp", ("tp" if tp > 1 else None)
 
 
+@device_scope("attn.core")
 def _paged_attend(cfg: ModelConfig, q, kp, vp, i, tables, lengths, mask,
                   scale, attn_blocks: int, mesh, use_kernel: bool):
     """Attention for one layer of the paged forward: pallas kernel with
@@ -884,6 +921,7 @@ def _paged_scatter4(pool, i, codes, pg, off):
     return pool
 
 
+@device_scope("attn.kv_write")
 def _scatter_kv_pools(kp, vp, i, k, v, pg_w, off_w):
     """Quantize (int8/int4 pools) and scatter one layer's fresh K/V into
     the pools at (page, offset) per (row, position) — shared by the
@@ -943,9 +981,10 @@ def _paged_write_attend_local(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
     kp, vp = _scatter_kv_pools(kp, vp, i, k, v, pg_w, off_w)
     if use_kernel:
         from ..ops.pallas.paged import paged_decode_attention
-        out = paged_decode_attention(
-            q, kp, vp, i, tables, lengths, scale, cfg.attn_softcap,
-            cfg.sliding_window, nblk=attn_blocks, interpret=interp)
+        with device_scope("attn.core"):
+            out = paged_decode_attention(
+                q, kp, vp, i, tables, lengths, scale, cfg.attn_softcap,
+                cfg.sliding_window, nblk=attn_blocks, interpret=interp)
         if out is not None:
             return kp, vp, out
         note_kernel("paged_decode", "gather_einsum", fell_back=True)

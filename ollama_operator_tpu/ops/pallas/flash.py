@@ -157,6 +157,7 @@ def flash_prefill(q, k, v, scale: float, softcap: float = 0.0,
         window=sliding_window, bq=bq, bk=bk, nk=nk)
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -265,6 +266,7 @@ def decode_attention(q, k_cache, v_cache, q_pos, scale: float,
         window=sliding_window, bk=bk, nk=nk)
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, KvH, nk),
@@ -383,6 +385,7 @@ def mha_decode_attention(q, k_cache, v_cache, q_pos, scale: float,
         window=sliding_window, bk=bk, nk=nk)
     out = pl.pallas_call(
         kernel,
+        name="mha_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H // Ht, nk),

@@ -250,6 +250,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
 
     out = pl.pallas_call(
         kernel,
+        name="paged_v2",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, nblk),
@@ -467,6 +468,7 @@ def paged_decode_attention_v4(q, k_pool, v_pool, layer, tables, lengths,
         cdt=cdt, quant=quant, quant4=quant4)
     out = pl.pallas_call(
         kernel,
+        name="paged_v4",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(flat_n,),
@@ -658,6 +660,7 @@ def paged_decode_attention_v3(q, k_pool, v_pool, layer, tables, lengths,
         cdt=cdt, quant=quant, quant4=quant4, depth=depth)
     out = pl.pallas_call(
         kernel,
+        name="paged_v3",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),

@@ -95,6 +95,7 @@ def qmm_pallas(x: jax.Array, q: jax.Array, s: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_kernel, nk=nk, g=g, cdt=cdt),
+        name="qmm_pallas",
         grid=(O // bo, nk),
         in_specs=[
             pl.BlockSpec((Bp, bk), lambda oi, ki: (0, ki)),
@@ -169,6 +170,7 @@ def qmm4_pallas(x: jax.Array, q4: jax.Array, s: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_kernel4, nk=nk, g=g, cdt=cdt),
+        name="qmm4_pallas",
         grid=(O // bo, nk),
         in_specs=[
             pl.BlockSpec((Bp, bk), lambda oi, ki: (0, ki)),

@@ -225,7 +225,7 @@ class UtilizationAccounting:
         self.device_kind = device_kind or "unknown"
         self._base = per_token_flops(cfg) if cfg is not None else 0.0
         self._lock = threading.Lock()
-        self._t_start = time.monotonic()
+        self._t_start = time.perf_counter()
         self.useful_tokens: Dict[str, float] = {k: 0.0 for k in _KINDS}
         self.padded_tokens: Dict[str, float] = {k: 0.0 for k in _KINDS}
         self.model_flops = 0.0
@@ -312,10 +312,14 @@ class UtilizationAccounting:
         padded = float(max(0, bucket - n_new))
         self._bump("prefill", flops, float(n_new), padded, dur_s)
 
-    def _sync_phase(self, phase: str, dur_s: float) -> None:
+    def _sync_phase(self, phase: str, dur_s: float,
+                    now: Optional[float] = None) -> None:
         """Fold a blocked interval into the phase counters; the wall time
-        since the previous sync minus the blocked part is host overhead."""
-        now = time.monotonic()
+        since the previous sync minus the blocked part is host overhead.
+        ``now`` is the perf_counter() at which the interval ended, from
+        the span that timed it (runtime/trace.py); read here without it."""
+        if now is None:
+            now = time.perf_counter()
         with self._lock:
             host = max(0.0, (now - self._synced_wall) - dur_s)
             self._synced_wall = now
@@ -325,21 +329,21 @@ class UtilizationAccounting:
             METRICS.inc("tpu_model_breakdown_seconds_total", host,
                         '{phase="host"}')
 
-    def on_wait(self, dur_s: float) -> None:
+    def on_wait(self, dur_s: float, now: Optional[float] = None) -> None:
         with self._lock:
             self.wait_s += dur_s
-        self._sync_phase("dispatch_wait", dur_s)
+        self._sync_phase("dispatch_wait", dur_s, now)
 
-    def on_idle(self, dur_s: float) -> None:
+    def on_idle(self, dur_s: float, now: Optional[float] = None) -> None:
         with self._lock:
             self.idle_s += dur_s
-        self._sync_phase("idle", dur_s)
+        self._sync_phase("idle", dur_s, now)
 
     # -- reads ---------------------------------------------------------------
 
     def breakdown(self) -> Dict[str, float]:
         with self._lock:
-            wall = time.monotonic() - self._t_start
+            wall = time.perf_counter() - self._t_start
             wait, idle = self.wait_s, self.idle_s
         host = max(0.0, wall - wait - idle)
         return {"wall_s": round(wall, 3),
@@ -363,7 +367,7 @@ class UtilizationAccounting:
                     padded += cell[2]
                     busy += cell[3]
                     secs += 1
-            elapsed = min(window, max(1.0, time.monotonic() - self._t_start))
+            elapsed = min(window, max(1.0, time.perf_counter() - self._t_start))
             totals = {
                 "useful_tokens": dict(self.useful_tokens),
                 "padded_tokens": dict(self.padded_tokens),
@@ -418,10 +422,10 @@ class _NullAccounting:
     def on_prefill(self, *a: Any, **kw: Any) -> None:
         pass
 
-    def on_wait(self, dur_s: float) -> None:
+    def on_wait(self, dur_s: float, now: Optional[float] = None) -> None:
         pass
 
-    def on_idle(self, dur_s: float) -> None:
+    def on_idle(self, dur_s: float, now: Optional[float] = None) -> None:
         pass
 
     def breakdown(self) -> Dict[str, float]:

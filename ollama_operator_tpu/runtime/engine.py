@@ -40,7 +40,7 @@ from ..models import decoder
 from ..ops import sampling
 from ..ops.attention import record_kernels
 from .faults import FAULTS
-from .trace import FLIGHT
+from .trace import FLIGHT, device_scope, span
 from ..parallel.sharding import (kv_cache_pspec, params_sharding_tree,
                                  resolve_moe_impl)
 from ..server.metrics import GLOBAL as METRICS
@@ -814,33 +814,35 @@ class Engine:
             re-seeds to 2*tau here (llama.cpp's init) and absorbs the
             first token's surprise. Returns (tok, lengths, counts,
             last_tokens, pring, mu)."""
-            last = logits
-            allowed = unpack_mask(mask_row, cfg.vocab_size)
-            last = jnp.where((cflag == 1) & ~allowed, sampling.NEG_INF, last)
-            mu_row = 2.0 * sp_row.mirostat_tau
-            # position-folded key, SAME stream as the decode steps (which
-            # fold the pre-increment length: the token installed at index
-            # total-1 would have used fold_in(key, total-1) had it been
-            # decoded). Admission and decode drawing from one keystream is
-            # what makes a seeded stream resume bit-identically after a
-            # preemption or a restart replay — the re-prefill's first
-            # sample lands on exactly the fold the uninterrupted decode
-            # would have used at that position.
-            tok, mu_row = sampling.sample(
-                last[None], counts_row[None], sp_row,
-                jax.random.fold_in(key, total - 1)[None], mu_row)
-            tok = tok[0]
-            mu = mu.at[slot].set(mu_row[0])
-            rmod = jnp.maximum(rln, 1)
-            evict = ring_row[total % rmod]
-            counts_row = counts_row.at[evict].add(-1, mode="drop")
-            tok_entry = jnp.where(rln > 0, tok, jnp.int32(cfg.vocab_size))
-            ring_row = ring_row.at[total % rmod].set(tok_entry)
-            counts_row = counts_row.at[tok_entry].add(1, mode="drop")
-            pring = pring.at[slot].set(ring_row)
-            lengths = lengths.at[slot].set(total)
-            counts = counts.at[slot].set(counts_row)
-            last_tokens = last_tokens.at[slot].set(tok)
+            with device_scope("sample"):
+                last = logits
+                allowed = unpack_mask(mask_row, cfg.vocab_size)
+                last = jnp.where((cflag == 1) & ~allowed, sampling.NEG_INF,
+                                 last)
+                mu_row = 2.0 * sp_row.mirostat_tau
+                # position-folded key, SAME stream as the decode steps (which
+                # fold the pre-increment length: the token installed at index
+                # total-1 would have used fold_in(key, total-1) had it been
+                # decoded). Admission and decode drawing from one keystream is
+                # what makes a seeded stream resume bit-identically after a
+                # preemption or a restart replay — the re-prefill's first
+                # sample lands on exactly the fold the uninterrupted decode
+                # would have used at that position.
+                tok, mu_row = sampling.sample(
+                    last[None], counts_row[None], sp_row,
+                    jax.random.fold_in(key, total - 1)[None], mu_row)
+                tok = tok[0]
+                mu = mu.at[slot].set(mu_row[0])
+                rmod = jnp.maximum(rln, 1)
+                evict = ring_row[total % rmod]
+                counts_row = counts_row.at[evict].add(-1, mode="drop")
+                tok_entry = jnp.where(rln > 0, tok, jnp.int32(cfg.vocab_size))
+                ring_row = ring_row.at[total % rmod].set(tok_entry)
+                counts_row = counts_row.at[tok_entry].add(1, mode="drop")
+                pring = pring.at[slot].set(ring_row)
+                lengths = lengths.at[slot].set(total)
+                counts = counts.at[slot].set(counts_row)
+                last_tokens = last_tokens.at[slot].set(tok)
             return tok, lengths, counts, last_tokens, pring, mu
 
         def _insert_prefilled(k_cache, v_cache, lengths, counts,
@@ -885,18 +887,24 @@ class Engine:
                     cfg, k_cache, v_cache, ks, vs, table_row, n_valid)
             elif self.quant_cache:
                 from ..ops.quant_cache import quantize_kv
-                kq, ksc = quantize_kv(ks)          # [L,1,KvH,T,hd]
-                vq, vsc = quantize_kv(vs)
-                dus = jax.lax.dynamic_update_slice
-                k_cache = {"q": dus(k_cache["q"], kq, (0, slot, 0, 0, 0)),
-                           "s": dus(k_cache["s"], ksc, (0, slot, 0, 0))}
-                v_cache = {"q": dus(v_cache["q"], vq, (0, slot, 0, 0, 0)),
-                           "s": dus(v_cache["s"], vsc, (0, slot, 0, 0))}
+                with device_scope("attn.kv_write"):
+                    kq, ksc = quantize_kv(ks)          # [L,1,KvH,T,hd]
+                    vq, vsc = quantize_kv(vs)
+                    dus = jax.lax.dynamic_update_slice
+                    k_cache = {
+                        "q": dus(k_cache["q"], kq, (0, slot, 0, 0, 0)),
+                        "s": dus(k_cache["s"], ksc, (0, slot, 0, 0))}
+                    v_cache = {
+                        "q": dus(v_cache["q"], vq, (0, slot, 0, 0, 0)),
+                        "s": dus(v_cache["s"], vsc, (0, slot, 0, 0))}
             else:
-                k_cache = jax.lax.dynamic_update_slice(
-                    k_cache, ks.astype(k_cache.dtype), (0, slot, 0, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(
-                    v_cache, vs.astype(v_cache.dtype), (0, slot, 0, 0, 0))
+                with device_scope("attn.kv_write"):
+                    k_cache = jax.lax.dynamic_update_slice(
+                        k_cache, ks.astype(k_cache.dtype),
+                        (0, slot, 0, 0, 0))
+                    v_cache = jax.lax.dynamic_update_slice(
+                        v_cache, vs.astype(v_cache.dtype),
+                        (0, slot, 0, 0, 0))
             return (tok, *pin(k_cache, v_cache, lengths, counts,
                               last_tokens, pring, mu))
 
@@ -976,44 +984,46 @@ class Engine:
                 logits, k_cache, v_cache = step_impl(
                     params, tokens=last_tokens[:, None], k_cache=k_cache,
                     v_cache=v_cache, lengths=lengths, **kw)
-            step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
-            last = logits[:, 0]
-            # device-table slots read their mask straight off the
-            # precomputed grammar table (host rows for everyone else)
-            gdev = gstate >= 0
-            gi = jnp.clip(gstate, 0, gmask.shape[0] - 1)
-            eff_bits = jnp.where(gdev[:, None], gmask[gi], mask_bits)
-            allowed = unpack_mask(eff_bits, cfg.vocab_size)
-            last = jnp.where((constrained == 1)[:, None] & ~allowed,
-                             sampling.NEG_INF, last)
-            toks, mu_new = sampling.sample(last, counts, sp, step_keys,
-                                           mu)
-            # advance the device automaton by the sampled token; a -1
-            # transition (walk left the precomputed table) escapes to -2
-            ns = gtrans[gi, toks]
-            ns = jnp.where(ns < 0, jnp.int32(-2), ns)
-            gstate = jnp.where(gdev & (active == 1), ns, gstate)
-            mu = jnp.where(active == 1, mu_new, mu)
-            B = toks.shape[0]
-            bi = jnp.arange(B)
-            # penalty window: the NEW token's absolute position is
-            # lengths + 1 (last_tokens sits at lengths); evict whatever
-            # occupied that ring slot rln[i] tokens ago, then admit the
-            # new token. Per-slot rln picks each request's effective
-            # window inside the static-W ring via the modulus — inactive
-            # or rln==0 slots write the OOB sentinel.
-            rmod = jnp.maximum(rln, 1)
-            slot_pos = (lengths + 1) % rmod
-            evict = pring[bi, slot_pos]
-            evict = jnp.where(active == 1, evict, jnp.int32(cfg.vocab_size))
-            live = (active == 1) & (rln > 0)
-            new = jnp.where(live, toks, jnp.int32(cfg.vocab_size))
-            counts = counts.at[bi, evict].add(-1, mode="drop")
-            counts = counts.at[bi, new].add(1, mode="drop")
-            pring = jnp.where(live[:, None],
-                              pring.at[bi, slot_pos].set(toks), pring)
-            lengths = lengths + active
-            last_tokens = jnp.where(active == 1, toks, last_tokens)
+            with device_scope("sample"):
+                step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
+                last = logits[:, 0]
+                # device-table slots read their mask straight off the
+                # precomputed grammar table (host rows for everyone else)
+                gdev = gstate >= 0
+                gi = jnp.clip(gstate, 0, gmask.shape[0] - 1)
+                eff_bits = jnp.where(gdev[:, None], gmask[gi], mask_bits)
+                allowed = unpack_mask(eff_bits, cfg.vocab_size)
+                last = jnp.where((constrained == 1)[:, None] & ~allowed,
+                                 sampling.NEG_INF, last)
+                toks, mu_new = sampling.sample(last, counts, sp, step_keys,
+                                               mu)
+                # advance the device automaton by the sampled token; a -1
+                # transition (walk left the precomputed table) escapes to -2
+                ns = gtrans[gi, toks]
+                ns = jnp.where(ns < 0, jnp.int32(-2), ns)
+                gstate = jnp.where(gdev & (active == 1), ns, gstate)
+                mu = jnp.where(active == 1, mu_new, mu)
+                B = toks.shape[0]
+                bi = jnp.arange(B)
+                # penalty window: the NEW token's absolute position is
+                # lengths + 1 (last_tokens sits at lengths); evict whatever
+                # occupied that ring slot rln[i] tokens ago, then admit the
+                # new token. Per-slot rln picks each request's effective
+                # window inside the static-W ring via the modulus — inactive
+                # or rln==0 slots write the OOB sentinel.
+                rmod = jnp.maximum(rln, 1)
+                slot_pos = (lengths + 1) % rmod
+                evict = pring[bi, slot_pos]
+                evict = jnp.where(active == 1, evict,
+                                  jnp.int32(cfg.vocab_size))
+                live = (active == 1) & (rln > 0)
+                new = jnp.where(live, toks, jnp.int32(cfg.vocab_size))
+                counts = counts.at[bi, evict].add(-1, mode="drop")
+                counts = counts.at[bi, new].add(1, mode="drop")
+                pring = jnp.where(live[:, None],
+                                  pring.at[bi, slot_pos].set(toks), pring)
+                lengths = lengths + active
+                last_tokens = jnp.where(active == 1, toks, last_tokens)
             if slot_sh is not None:
                 gstate = jax.lax.with_sharding_constraint(gstate, slot_sh)
             return (toks, *pin(k_cache, v_cache, lengths, counts,
@@ -1103,19 +1113,20 @@ class Engine:
                 logits, k_cache, v_cache = step_impl(
                     params, tokens=tokens_in, k_cache=k_cache,
                     v_cache=v_cache, lengths=lengths, **kw)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             ok = (active == 1) & (is_greedy == 1)
             bi = jnp.arange(B)
-            step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
-            l0 = logits[:, 0]
             gdev = gstate >= 0
             gi = jnp.clip(gstate, 0, gmask.shape[0] - 1)
-            eff_bits = jnp.where(gdev[:, None], gmask[gi], mask_bits)
-            allowed = unpack_mask(eff_bits, V)
-            l0 = jnp.where((constrained == 1)[:, None] & ~allowed,
-                           sampling.NEG_INF, l0)
-            sampled0, mu_new = sampling.sample(l0, counts, sp, step_keys,
-                                               mu)
+            with device_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
+                l0 = logits[:, 0]
+                eff_bits = jnp.where(gdev[:, None], gmask[gi], mask_bits)
+                allowed = unpack_mask(eff_bits, V)
+                l0 = jnp.where((constrained == 1)[:, None] & ~allowed,
+                               sampling.NEG_INF, l0)
+                sampled0, mu_new = sampling.sample(l0, counts, sp,
+                                                   step_keys, mu)
             # greedy (accepting) slots never run mirostat; only the
             # sampled path's slots absorb the surprise update
             mu = jnp.where((active == 1) & ~ok, mu_new, mu)
@@ -1502,8 +1513,10 @@ class Engine:
         seed = (opts.seed if opts.seed >= 0
                 else (slot * 1000003 + seq_len * 7919 + 12345)
                 & 0x7FFFFFFF)
-        self.keys, key = self._install_key_fn(
-            self.keys, self._gr(np.int32(slot)), self._gr(np.int32(seed)))
+        with span("engine.install_key"):
+            self.keys, key = self._install_key_fn(
+                self.keys, self._gr(np.int32(slot)),
+                self._gr(np.int32(seed)))
         if mask_row is not None:
             return key, self._gr(self._pad_mask_row(mask_row)), \
                 self._gr(np.int32(1))
@@ -1523,13 +1536,19 @@ class Engine:
         self._host_lengths[slot] = n_total
         self._opts[slot] = opts
         self._repeat_n[slot] = self._resolve_rln(opts)
-        self._rln_dev = self._g(self._repeat_n, self._slot_sh)
         if self.paged:
             self._admit_seq += 1
             self._admit_order[slot] = self._admit_seq
-        self._rebuild_sp()
-        self._active_dev = self._g(self.active.astype(np.int32),
-                                   self._slot_sh)
+        self._upload_slot_state()
+
+    def _upload_slot_state(self):
+        """Re-stage the per-slot host state an admission changed: penalty
+        windows, batched sampling params, the active mask."""
+        with span("engine.upload"):
+            self._rln_dev = self._g(self._repeat_n, self._slot_sh)
+            self._rebuild_sp()
+            self._active_dev = self._g(self.active.astype(np.int32),
+                                       self._slot_sh)
 
     def admit(self, slot: int, prompt: np.ndarray,
               opts: SlotOptions = SlotOptions(),
@@ -1547,7 +1566,12 @@ class Engine:
         ``set_mask``.
         """
         FAULTS.check("engine.admit")
-        t0 = time.perf_counter()
+        with span("engine.admit") as sp:
+            tok = self._do_admit(slot, prompt, opts, embeds, mask_row)
+        self.dispatch_ms["admit"] = sp.dur * 1e3
+        return tok
+
+    def _do_admit(self, slot, prompt, opts, embeds, mask_row) -> int:
         assert not self.active[slot], f"slot {slot} busy"
         n = int(prompt.shape[0])
         if n >= self.max_seq:
@@ -1581,9 +1605,7 @@ class Engine:
                 cflag, self._gr(np.int32(self._resolve_rln(opts))),
                 table_row)
         self._commit_slot(slot, n, opts)
-        tok = int(tok)
-        self.dispatch_ms["admit"] = (time.perf_counter() - t0) * 1e3
-        return tok
+        return int(tok)
 
     def _grow_for_admit(self, slot: int, n: int):
         """Paged admission bookkeeping: drop any pages the slot still owns
@@ -1711,7 +1733,13 @@ class Engine:
         if opts_list is None:
             opts_list = [SlotOptions()] * m
         FAULTS.check("engine.admit")
-        t0 = time.perf_counter()
+        with span("engine.admit_many", m=m) as sp:
+            out = self._do_admit_many(slots, prompts, opts_list)
+        self.dispatch_ms["admit"] = sp.dur * 1e3
+        return out
+
+    def _do_admit_many(self, slots, prompts, opts_list) -> List[int]:
+        m = len(slots)
         ns = [int(np.asarray(p).shape[0]) for p in prompts]
         for s, n in zip(slots, ns):
             assert not self.active[s], f"slot {s} busy"
@@ -1746,13 +1774,15 @@ class Engine:
             key, _, _ = self._prep_slot(s, o, n, None)
             keys.append(key)
         gi = lambda a: self._gr(np.asarray(a, np.int32))  # noqa: E731
+        with span("engine.upload"):
+            sp_rows, keys_m = self._sp_many(opts_list), self._stack_keys(keys)
         (toks, self.k_cache, self.v_cache, self.lengths, self.counts,
          self.last_tokens, self.pring, self.mu) = \
             self._admit_many_exec(m, bucket)(
                 self.params, self.k_cache, self.v_cache, self.lengths,
                 self.counts, self.last_tokens, self.pring, self.mu,
                 self._gr(tokens), gi(list(slots)), gi(ns),
-                self._sp_many(opts_list), self._stack_keys(keys),
+                sp_rows, keys_m,
                 self._mask_ones,
                 gi([self._resolve_rln(o) for o in opts_list]), table_rows)
         for s, n, o in zip(slots, ns, opts_list):
@@ -1763,13 +1793,8 @@ class Engine:
             if self.paged:
                 self._admit_seq += 1
                 self._admit_order[s] = self._admit_seq
-        self._rln_dev = self._g(self._repeat_n, self._slot_sh)
-        self._rebuild_sp()
-        self._active_dev = self._g(self.active.astype(np.int32),
-                                   self._slot_sh)
-        out = [int(t) for t in self._fetch(toks)]
-        self.dispatch_ms["admit"] = (time.perf_counter() - t0) * 1e3
-        return out
+        self._upload_slot_state()
+        return [int(t) for t in self._fetch(toks)]
 
     @property
     def supports_extend(self) -> bool:
@@ -1837,7 +1862,12 @@ class Engine:
         # reuse or a chunked-prefill piece), and chaos drills must reach
         # the chunked path through it
         FAULTS.check("engine.admit")
-        t0 = time.perf_counter()
+        with span("engine.extend") as sp:
+            tok = self._do_extend(slot, full_ids, start, opts, mask_row)
+        self.dispatch_ms["extend"] = sp.dur * 1e3
+        return tok
+
+    def _do_extend(self, slot, full_ids, start, opts, mask_row) -> int:
         assert not self.active[slot], f"slot {slot} busy"
         full_ids = np.asarray(full_ids, np.int32)
         n_total = int(full_ids.shape[0])
@@ -1910,9 +1940,7 @@ class Engine:
          self.last_tokens, self.pring, self.mu) = \
             self._extend_exec(bucket, attn_a)(*args)
         self._commit_slot(slot, n_total, opts)
-        tok = int(tok)
-        self.dispatch_ms["extend"] = (time.perf_counter() - t0) * 1e3
-        return tok
+        return int(tok)
 
     def _attn_bucket(self, n: int) -> int:
         """Static attended-prefix length covering every active slot for the
@@ -3015,11 +3043,15 @@ class Engine:
         write into pages already mapped by prepare_decode, and the
         accept mask only moves ``lengths``."""
         FAULTS.check("engine.step")
-        t0 = time.perf_counter()
-        if drafts is not None:
-            # lint: allow(host-sync-hot-path): draft tokens are host ints
-            return self._spec_launch(np.asarray(drafts, np.int32),
-                                     retire, t0)
+        with span("engine.decode_n") as sp:
+            if drafts is not None:
+                # lint: allow(host-sync-hot-path): draft tokens are host ints
+                return self._spec_launch(np.asarray(drafts, np.int32),
+                                         retire, sp.t0)
+            return self._launch(n, retire, sp.t0)
+
+    def _launch(self, n: Optional[int], retire: Optional[int],
+                t0: float) -> DecodeHandle:
         n = n or self.ecfg.decode_chunk
         if self.paged and retire is not None:
             self._pt.retire_epoch(retire)
@@ -3160,6 +3192,10 @@ class Engine:
         """Free ``slot``. With ``park=True`` the KV cache and slot state
         are left in place so a later ``extend`` can reuse the prefix (the
         slot still counts as free and may be overwritten by any admit)."""
+        with span("engine.release", park=park):
+            self._do_release(slot, park)
+
+    def _do_release(self, slot: int, park: bool):
         self.clear_mask(slot)
         self.active[slot] = False
         self._opts.pop(slot, None)

@@ -38,7 +38,7 @@ from .engine import Engine, SlotOptions
 from .errors import BadRequest, DeadlineExceeded
 from .faults import FAULTS, InjectedFault
 from .paged import PagesExhausted
-from .trace import FLIGHT, TRACER
+from .trace import FLIGHT, TRACER, fold_stages, span
 
 
 class SchedulerBusy(RuntimeError):
@@ -370,6 +370,10 @@ class Scheduler:
         # waiting queue — they already hold a place in the line
         self._preempted: List[Request] = []
         self.n_preemptions = 0
+        # slot vacancy: (perf_counter() of the previous _step, slots its
+        # admission pass left free, whether a request still waited then) —
+        # the state that holds until the next iteration's admission pass
+        self._vacancy: Optional[tuple] = None
         # restart replay (stream-preserving recovery): _fail_running
         # moves replayable in-flight requests here instead of erroring
         # them; _supervised_restart re-admits them through the preempt
@@ -820,6 +824,9 @@ class Scheduler:
         self._avg_decode += 0.2 * (req.stats.n_generated - self._avg_decode)
         req.trace.event("finish", reason=reason, slot=slot,
                         n_generated=req.stats.n_generated)
+        if req.trace.t_http is None:
+            # no HTTP handler owns this request: nobody else will fold it
+            fold_stages(req.trace)
         with self._lock:
             self.finished.append(req.stats)
             if len(self.finished) > 512:
@@ -1129,7 +1136,6 @@ class Scheduler:
         the caller should stop admitting this pass."""
         if self._expired_at_admission(req):
             return True
-        t0 = time.perf_counter()
         try:
             mask_row = (req.constraint.mask_row()
                         if req.constraint is not None else None)
@@ -1176,19 +1182,26 @@ class Scheduler:
         except Exception as e:  # surfacing engine errors to the caller
             self._request_error(req, str(e))
             return True
-        dur = time.perf_counter() - t0
-        METRICS.inc("tpu_model_admission_stall_ms_total", dur * 1e3)
         kind = "extend" if reuse_len else "admit"
-        METRICS.observe("tpu_model_dispatch_seconds", dur,
-                        f'{{kind="{kind}"}}')
+        dur = self._note_prefill(kind)
         n_new = len(req.admit_ids) - reuse_len
         self.acct.on_prefill(dur, reuse_len, n_new,
                              self.engine.bucket_for(n_new))
-        self.acct.on_wait(dur)
         req.trace.event("prefill", kind=kind, dur_ms=round(dur * 1e3, 3),
                         n_tokens=n_new)
         self._post_admit(slot, req, first)
         return True
+
+    def _note_prefill(self, kind: str) -> float:
+        """Account one blocking admit/extend dispatch that just returned:
+        its seconds are the engine's own (its `engine.<kind>` span, kept in
+        dispatch_ms), so the scheduler reads no clock of its own here."""
+        dur = self.engine.dispatch_ms[kind] / 1e3
+        METRICS.inc("tpu_model_admission_stall_ms_total", dur * 1e3)
+        METRICS.observe("tpu_model_dispatch_seconds", dur,
+                        f'{{kind="{kind}"}}')
+        self.acct.on_wait(dur)
+        return dur
 
     def _start_chunked(self, slot: int, req: Request,
                        reuse_len: int) -> bool:
@@ -1201,7 +1214,6 @@ class Scheduler:
             return True
         ids = req.admit_ids
         end = reuse_len + self.prefill_chunk
-        t0 = time.perf_counter()
         try:
             try:
                 if reuse_len:
@@ -1238,16 +1250,12 @@ class Scheduler:
         except Exception as e:
             self._request_error(req, str(e))
             return True
-        dur = time.perf_counter() - t0
         METRICS.inc("tpu_model_prefill_chunks_total")
-        METRICS.inc("tpu_model_admission_stall_ms_total", dur * 1e3)
         kind = "extend" if reuse_len else "admit"
-        METRICS.observe("tpu_model_dispatch_seconds", dur,
-                        f'{{kind="{kind}"}}')
+        dur = self._note_prefill(kind)
         n_new = end - reuse_len
         self.acct.on_prefill(dur, reuse_len, n_new,
                              self.engine.bucket_for(n_new))
-        self.acct.on_wait(dur)
         req.trace.event("prefill_piece", kind=kind, done=end,
                         of=len(ids), dur_ms=round(dur * 1e3, 3))
         req.slot = slot
@@ -1297,7 +1305,6 @@ class Scheduler:
         start = job.done
         end = min(job.done + self.prefill_chunk, len(ids))
         final = end == len(ids)
-        t0 = time.perf_counter()
         try:
             if final:
                 mask_row = (req.constraint.mask_row()
@@ -1321,14 +1328,10 @@ class Scheduler:
         # any other engine failure propagates to the supervisor, which
         # errors every running request (this one included) exactly once
         # and restarts — _fail_running clears _prefilling
-        dur = time.perf_counter() - t0
         METRICS.inc("tpu_model_prefill_chunks_total")
-        METRICS.inc("tpu_model_admission_stall_ms_total", dur * 1e3)
-        METRICS.observe("tpu_model_dispatch_seconds", dur,
-                        '{kind="extend"}')
+        dur = self._note_prefill("extend")
         self.acct.on_prefill(dur, start, end - start,
                              self.engine.bucket_for(end - start))
-        self.acct.on_wait(dur)
         req.trace.event("prefill_piece", kind="extend", done=end,
                         of=len(ids), dur_ms=round(dur * 1e3, 3))
         if final:
@@ -1348,7 +1351,6 @@ class Scheduler:
             while len(items) >= 2:
                 m = 4 if len(items) >= 4 else 2
                 group, items = items[:m], items[m:]
-                t0 = time.perf_counter()
                 try:
                     toks = self.engine.admit_many(
                         [s for s, _ in group],
@@ -1362,17 +1364,12 @@ class Scheduler:
                     for s, r in group:
                         self._admit_one(s, r, 0)
                     continue
-                dur = time.perf_counter() - t0
-                METRICS.inc("tpu_model_admission_stall_ms_total",
-                            dur * 1e3)
-                METRICS.observe("tpu_model_dispatch_seconds", dur,
-                                '{kind="admit"}')
+                dur = self._note_prefill("admit")
                 # one batched dispatch: split its wall time evenly so the
                 # ring's busy_s doesn't count the dispatch m times
                 for _, r in group:
                     self.acct.on_prefill(dur / m, 0, len(r.admit_ids),
                                          bucket)
-                self.acct.on_wait(dur)
                 for (s, r), tok in zip(group, toks):
                     # batched admissions are always cold (a resumed
                     # request must not re-report its first admission's
@@ -1968,12 +1965,12 @@ class Scheduler:
         length (a parked/donated predecessor's length was already
         reset or is repaired at reuse). Folds per-slot drafted/accepted
         counts into the acceptance metrics."""
-        tw0 = time.perf_counter()
-        toks_n = self._watched(handle.wait)
+        with span("sched.wait") as sp:
+            toks_n = self._watched(handle.wait)
         # breakdown: only the time the scheduler actually BLOCKED here is
         # dispatch-wait (under async overlap the device may already be
         # done); `dur` below is the full launch→host device span
-        self.acct.on_wait(time.perf_counter() - tw0)
+        self.acct.on_wait(sp.dur, sp.t1)
         self._fence_ack = handle.epoch
         self._consecutive_failures = 0
         # dispatch latency: launch → tokens-on-host, per program kind.
@@ -2075,6 +2072,22 @@ class Scheduler:
         return {s: r for s, r in enumerate(self._running)
                 if r is not None and s not in self._prefilling}
 
+    def _count_vacancy(self, now: float):
+        """Slot-seconds since the previous iteration began: all of them
+        into tpu_model_slot_seconds_total, and into
+        tpu_model_slot_vacant_seconds_total those of the slots that
+        iteration's admission pass left free (a slot freed later, by its
+        fan-out, is admitted at the earliest by the next pass), by whether
+        a request still waited when the pass ended."""
+        if self._vacancy is None:
+            return
+        t_last, free, waiting = self._vacancy
+        dt = now - t_last
+        METRICS.inc("tpu_model_slot_seconds_total",
+                    self.engine.n_slots * dt)
+        METRICS.inc("tpu_model_slot_vacant_seconds_total", free * dt,
+                    '{queue="waiting"}' if waiting else '{queue="empty"}')
+
     def _step(self):
         if self._tasks:
             # exclusive tasks see a quiet pipeline: land any in-flight
@@ -2082,11 +2095,23 @@ class Scheduler:
             # decode reading the same buffers
             self._drain_pending()
             self._run_tasks()
-        self._shed_expired()
-        self._throttle_over_limit()
-        self._preempt_for_priority()
-        self._advance_prefill()
-        self._admit_waiting()
+        with span("sched.housekeep") as sp:
+            t_step = sp.t0
+            self._count_vacancy(t_step)
+            self._shed_expired()
+            self._throttle_over_limit()
+            self._preempt_for_priority()
+        if self._prefilling:
+            with span("sched.prefill"):
+                self._advance_prefill()
+        with span("sched.admit") as sp:
+            n_before = self.n_active
+            self._admit_waiting()
+            n_after = self.n_active
+            sp.set(n=n_after - n_before)
+        self._vacancy = (t_step, self.engine.n_slots - n_after,
+                         bool(self._preempted)
+                         or not self._admission.empty())
         if not self._decoding():
             self._drain_pending()
             # idle with pages still fenced (the last dispatch's frees):
@@ -2095,9 +2120,9 @@ class Scheduler:
             if self.engine.quarantined_pages:
                 self._quiesce("idle")
             if not self._prefilling:
-                t_idle = time.perf_counter()
-                self._wake.wait(timeout=0.05)
-                self.acct.on_idle(time.perf_counter() - t_idle)
+                with span("sched.idle") as sp:
+                    self._wake.wait(timeout=0.05)
+                self.acct.on_idle(sp.dur, sp.t1)
                 self._wake.clear()
             return
         # drop cancelled and over-deadline slots before paying for a
@@ -2140,9 +2165,10 @@ class Scheduler:
         # extend each slot's true tip), so pressure relief sizes for the
         # worst case the coming dispatch could need: spec_k+1 mapped
         # positions for a spec dispatch, decode_chunk for a chunked one
-        self._relieve_pressure(
-            max(self.engine.ecfg.decode_chunk, self.spec_k + 1)
-            if spec_usable else n_steps)
+        with span("sched.housekeep"):
+            self._relieve_pressure(
+                max(self.engine.ecfg.decode_chunk, self.spec_k + 1)
+                if spec_usable else n_steps)
         decoding = self._decoding()
         if not decoding:
             self._drain_pending()
@@ -2171,21 +2197,22 @@ class Scheduler:
             if spec_usable:
                 drafts, drafted = self._build_drafts(self.spec_k)
             if drafts is not None:
-                handle = self.engine.decode_n_launch(
-                    retire=(self._fence_ack if self.engine.paged
-                            else None),
-                    drafts=drafts)
+                with span("sched.launch"):
+                    handle = self.engine.decode_n_launch(
+                        retire=(self._fence_ack if self.engine.paged
+                                else None),
+                        drafts=drafts)
                 toks_n = self._wait_handle(handle, decoding,
                                            drafted)         # [k+1, B]
             else:
-                t0 = time.perf_counter()
-                toks_n = self._watched(
-                    lambda: self.engine.decode_n(n_steps))
+                with span("sched.wait") as sp:
+                    toks_n = self._watched(
+                        lambda: self.engine.decode_n(n_steps))
                 self._consecutive_failures = 0
-                dur = time.perf_counter() - t0
+                t0, dur = sp.t0, sp.dur
                 METRICS.observe("tpu_model_dispatch_seconds", dur,
                                 '{kind="decode"}')
-                self.acct.on_wait(dur)
+                self.acct.on_wait(dur, sp.t1)
                 if self.acct.enabled:
                     hl = self.engine._host_lengths
                     self.acct.on_decode(
@@ -2218,16 +2245,17 @@ class Scheduler:
                 tails = self._pending_tails(toks_prev, prev_snapshot)
             drafts, drafted = self._build_drafts(self.spec_k, tails)
             try:
-                if drafts is not None:
-                    handle = self.engine.decode_n_launch(
-                        retire=(self._fence_ack if self.engine.paged
-                                else None),
-                        drafts=drafts)
-                else:   # no slot found a match this round: full chunk
-                    handle = (self.engine.decode_n_launch(
-                                  retire=self._fence_ack)
-                              if self.engine.paged
-                              else self.engine.decode_n_launch())
+                with span("sched.launch"):
+                    if drafts is not None:
+                        handle = self.engine.decode_n_launch(
+                            retire=(self._fence_ack if self.engine.paged
+                                    else None),
+                            drafts=drafts)
+                    else:   # no slot found a match this round: full chunk
+                        handle = (self.engine.decode_n_launch(
+                                      retire=self._fence_ack)
+                                  if self.engine.paged
+                                  else self.engine.decode_n_launch())
             except Exception:
                 # dispatch N's tokens were already materialised —
                 # deliver them before the supervisor errors whoever is
@@ -2248,9 +2276,11 @@ class Scheduler:
         # retire= ack unfences pages freed behind dispatches we have
         # already materialised (paged mode; no-op dense).
         try:
-            handle = (self.engine.decode_n_launch(retire=self._fence_ack)
-                      if self.engine.paged
-                      else self.engine.decode_n_launch())
+            with span("sched.launch"):
+                handle = (self.engine.decode_n_launch(
+                              retire=self._fence_ack)
+                          if self.engine.paged
+                          else self.engine.decode_n_launch())
         except Exception:
             # dispatch N's tokens were already computed — deliver them
             # before the supervisor errors whoever is left
@@ -2265,6 +2295,10 @@ class Scheduler:
                          chunked=prev_drafted is None)
 
     def _fanout(self, toks_n, snapshot: dict, chunked: bool = True):
+        with span("sched.fanout"):
+            self._fanout_rows(toks_n, snapshot, chunked)
+
+    def _fanout_rows(self, toks_n, snapshot: dict, chunked: bool):
         """Deliver one dispatch's token rows [n, B] to the requests in
         ``snapshot`` (slot → request AT LAUNCH time). Under
         double-buffering a slot may have finished, been preempted, or

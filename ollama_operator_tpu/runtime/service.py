@@ -301,18 +301,6 @@ class LoadedModel:
             METRICS.gauge_fn("tpu_model_host_cache_pages",
                              lambda: (lm := wself()) is not None
                              and lm.engine.host_cache_pages or 0)
-        # per-program dispatch latency (launch → tokens on host), one
-        # labelled gauge per program kind: decode-chunk, one-shot admit,
-        # extend (prefix reuse / chunked-prefill pieces), spec verify —
-        # the number behind dispatch-dominated regressions like the
-        # BENCH_r05 623ms/spec-dispatch anomaly
-        for _kind in ("decode", "admit", "extend", "spec"):
-            METRICS.gauge_fn(
-                "tpu_model_dispatch_ms",
-                lambda k=_kind: (lm := wself()) is not None
-                and lm.engine.dispatch_ms.get(k, 0.0) or 0.0,
-                labels=f'{{program="{_kind}"}}')
-
         # utilization gauges (runtime/accounting.py): 60s-window MFU,
         # occupancy, goodput and waste read from the scheduler's
         # accounting snapshot; None (no peak known / idle) renders 0
@@ -913,9 +901,6 @@ class LoadedModel:
         if getattr(self.engine, "host_cache_enabled", False):
             METRICS.remove_gauge("tpu_model_host_cache_bytes")
             METRICS.remove_gauge("tpu_model_host_cache_pages")
-        for _kind in ("decode", "admit", "extend", "spec"):
-            METRICS.remove_gauge("tpu_model_dispatch_ms",
-                                 labels=f'{{program="{_kind}"}}')
         for _g in ("tpu_model_mfu", "tpu_model_occupancy",
                    "tpu_model_goodput_tokens_per_second",
                    "tpu_model_padding_waste_pct"):

@@ -48,7 +48,7 @@ from ..runtime.admission import TenantRateLimited, tenant_from_key
 from ..runtime.errors import BadRequest, DeadlineExceeded, FollowerLost
 from ..runtime.scheduler import SchedulerBroken, SchedulerBusy
 from ..runtime.service import LoadedModel
-from ..runtime.trace import FLIGHT, TRACER
+from ..runtime.trace import FLIGHT, TRACER, fold_stages, span
 from ..tokenizer import Tokenizer
 from .metrics import GLOBAL as METRICS
 from .modelfile import Modelfile, parse_modelfile, params_json
@@ -154,12 +154,12 @@ class _StreamCoalescer:
         self._parts.clear()
         self._ntok = 0
         self._t_last = time.monotonic() if now is None else now
-        self._chunk(self._make(text))
+        # stamps the request's timeline too ("http_flush", as ever)
+        with span("http.flush", self._trace, n_tokens=n_tok,
+                  chars=len(text)):
+            self._chunk(self._make(text))
         self.frames += 1
         METRICS.inc("tpu_model_stream_frames_total")
-        if self._trace is not None:
-            self._trace.event("http_flush", n_tokens=n_tok,
-                              chars=len(text))
 
 
 def _fmt_params(n: int) -> str:
@@ -1231,6 +1231,10 @@ class Handler(BaseHTTPRequestHandler):
         if path.startswith("/api/blobs/"):
             self._api_blob_upload(path[len("/api/blobs/"):])
             return
+        # open until _submit hands the request to the scheduler; a request
+        # that never gets there (another route, an error) records nothing
+        self._ingress = span("http.ingress").begin()
+        self._gen_trace = None
         try:
             body = self._json_body()
             route = {
@@ -1296,6 +1300,20 @@ class Handler(BaseHTTPRequestHandler):
             import traceback
             traceback.print_exc()
             self._send_error(f"internal: {e}", 500)
+        finally:
+            self._ingress.cancel()
+            if self._gen_trace is not None:
+                # the response has ended: its last http_flush is stamped
+                fold_stages(self._gen_trace)
+
+    def _submit(self, lm, prompt: str, **kw):
+        """``lm.generate_stream``: parse, template and tokenize are done
+        and the scheduler has the request, so `http.ingress` ends here; the
+        request's trace is kept for the stage fold when the response ends."""
+        gen = lm.generate_stream(prompt, **kw)
+        self._ingress.end()
+        self._gen_trace = getattr(gen, "trace", None)
+        return gen
 
     # -- Ollama endpoints ----------------------------------------------
     def _model_arg(self, body) -> str:
@@ -1345,12 +1363,11 @@ class Handler(BaseHTTPRequestHandler):
         text_prompt = prompt if raw else lm.render_prompt(
             prompt, system=body.get("system"),
             template=body.get("template"), suffix=body.get("suffix"))
-        gen = lm.generate_stream(text_prompt,
-                                 options=self._inject_tenant(
-                                     body.get("options")),
-                                 context=body.get("context"), raw=raw,
-                                 images=_decode_images(body.get("images")),
-                                 format=body.get("format"))
+        gen = self._submit(lm, text_prompt,
+                           options=self._inject_tenant(body.get("options")),
+                           context=body.get("context"), raw=raw,
+                           images=_decode_images(body.get("images")),
+                           format=body.get("format"))
         if stream:
             trace = getattr(gen, "trace", None)
             gen = self._pull_first(gen)
@@ -1415,11 +1432,10 @@ class Handler(BaseHTTPRequestHandler):
         images = []
         for m in messages:
             images.extend(m.get("images") or [])
-        gen = lm.generate_stream(prompt,
-                                 options=self._inject_tenant(
-                                     body.get("options")),
-                                 images=_decode_images(images),
-                                 format=body.get("format"))
+        gen = self._submit(lm, prompt,
+                           options=self._inject_tenant(body.get("options")),
+                           images=_decode_images(images),
+                           format=body.get("format"))
 
         def chat_message(final) -> Dict:
             """Assistant message for the completed generation: JSON tool
@@ -1825,9 +1841,8 @@ class Handler(BaseHTTPRequestHandler):
                        else None) or "json"
             elif rf.get("type") == "json_object":
                 fmt = "json"
-        gen = lm.generate_stream(prompt,
-                                 options=self._inject_tenant(options),
-                                 format=fmt)
+        gen = self._submit(lm, prompt,
+                           options=self._inject_tenant(options), format=fmt)
         if tools:
             # buffer and answer as one completion: tool invocations are
             # parsed from the full output
@@ -1953,8 +1968,11 @@ class Handler(BaseHTTPRequestHandler):
             options["temperature"] = body["temperature"]
         if body.get("stop"):
             options["stop"] = body["stop"]
-        final = lm.generate(body.get("prompt", ""),
-                            options=self._inject_tenant(options))
+        final = None
+        for _piece, f in self._submit(lm, body.get("prompt", ""),
+                                      options=self._inject_tenant(options)):
+            if f is not None:
+                final = f
         self._send_json({
             "id": f"cmpl-{int(time.time() * 1000)}",
             "object": "text_completion", "created": int(time.time()),

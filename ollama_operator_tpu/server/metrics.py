@@ -10,9 +10,10 @@ reference's (deploy/monitor.yaml).
 
 from __future__ import annotations
 
+import bisect
 import threading
 import time
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 
 class Histogram:
@@ -28,11 +29,7 @@ class Histogram:
     def observe(self, v: float):
         self.total += v
         self.n += 1
-        for i, b in enumerate(self.buckets):
-            if v <= b:
-                self.counts[i] += 1
-                return
-        self.counts[-1] += 1
+        self.counts[bisect.bisect_left(self.buckets, v)] += 1
 
     def render(self, name: str, labels: str = "") -> List[str]:
         out = []
@@ -57,12 +54,21 @@ class Metrics:
         self._gauges: Dict[Tuple[str, str], object] = {}
         self._hists: Dict[Tuple[str, str], Histogram] = {}
         self._help: Dict[str, str] = {}
+        self._buckets: Dict[str, Tuple[float, ...]] = {}
 
     def _key(self, name, labels):
         return (name, labels)
 
-    def describe(self, name: str, help_: str):
+    def describe(self, name: str, help_: str,
+                 buckets: Optional[Tuple[float, ...]] = None):
+        """HELP text of a family; ``buckets`` are the upper edges every
+        histogram of the family is created with (default: Histogram's)."""
         self._help[name] = help_
+        if buckets is not None:
+            self._buckets[name] = tuple(buckets)
+
+    def _new_hist(self, name: str) -> Histogram:
+        return Histogram(self._buckets.get(name, Histogram.DEFAULT_BUCKETS))
 
     def inc(self, name: str, value: float = 1.0, labels: str = ""):
         with self._lock:
@@ -86,7 +92,7 @@ class Metrics:
         with self._lock:
             k = self._key(name, labels)
             if k not in self._hists:
-                self._hists[k] = Histogram()
+                self._hists[k] = self._new_hist(name)
             self._hists[k].observe(v)
 
     def seed_histogram(self, name: str, labels: str = ""):
@@ -94,7 +100,9 @@ class Metrics:
         not absent — the histogram analog of the inc(name, 0.0)
         counter pre-seeds below."""
         with self._lock:
-            self._hists.setdefault(self._key(name, labels), Histogram())
+            k = self._key(name, labels)
+            if k not in self._hists:
+                self._hists[k] = self._new_hist(name)
 
     def hist_buckets(self, name: str,
                      labels: str = "") -> Tuple[Tuple[float, ...],
@@ -107,8 +115,7 @@ class Metrics:
         with self._lock:
             h = self._hists.get(self._key(name, labels))
             if h is None:
-                return (Histogram.DEFAULT_BUCKETS,
-                        (0,) * (len(Histogram.DEFAULT_BUCKETS) + 1))
+                h = self._new_hist(name)
             return h.buckets, tuple(h.counts)
 
     def hist_totals(self, name: str) -> Tuple[int, float]:
@@ -192,10 +199,6 @@ GLOBAL.describe("tpu_model_requests_shed_total",
 GLOBAL.describe("tpu_model_followers_lost_total",
                 "Multi-host follower connections lost (send failure or "
                 "missed heartbeat); the world is degraded afterwards")
-GLOBAL.describe("tpu_model_dispatch_ms",
-                "Last observed launch-to-tokens-on-host wall-clock per "
-                "device program kind (decode chunk, one-shot admit, "
-                "extend, speculative verify)")
 GLOBAL.describe("tpu_model_admission_stall_ms_total",
                 "Wall-clock milliseconds decode dispatches spent stalled "
                 "behind admission prefill work (one-shot, batched, and "
@@ -298,8 +301,8 @@ GLOBAL.describe("tpu_model_tenant_decode_tokens_total",
 GLOBAL.describe("tpu_model_dispatch_seconds",
                 "Device dispatch latency histogram by program kind "
                 "(kind=decode|admit|extend|spec): launch to tokens on "
-                "host — the distribution behind the last-value "
-                "tpu_model_dispatch_ms gauges")
+                "host (dispatches are double-buffered, so these overlap; "
+                "the engine's last values are in /api/ps dispatch)")
 GLOBAL.describe("tpu_model_metrics_gauge_errors_total",
                 "Gauge callables that raised during /metrics render; a "
                 "nonzero rate means a series is silently missing from "
@@ -487,6 +490,34 @@ GLOBAL.describe("tpu_model_disagg_pool_replicas",
                 "Replicas the gateway tracks per disagg pool "
                 "(pool=unified|prefill|decode); unified fleets read "
                 "everything under pool=\"unified\"")
+# log-spaced, 1 ms to 60 s in 50 steps of 24.6%: a percentile read from
+# bucket deltas lies within ~12% (the default buckets: a factor of two)
+STAGE_BUCKETS = tuple(round(1e-3 * 6e4 ** (i / 50.0), 6) for i in range(51))
+GLOBAL.describe("tpu_model_span_seconds",
+                "Host time inside each span of the closed vocabulary in "
+                "runtime/trace.py (span=http.ingress|http.flush|sched.*|"
+                "engine.*), nested spans included; while a profiler "
+                "session runs the same spans are TraceAnnotations on the "
+                "device planes' clock",
+                buckets=(1e-5, 1e-4, 1e-3, 0.01, 0.1, 1.0, 10.0))
+GLOBAL.describe("tpu_model_request_stage_seconds",
+                "A finished request's time by stage (stage=ingress: "
+                "handler start to queued; queue: to the start of its "
+                "first prefill dispatch; prefill: to the first token on "
+                "the host; first_flush: first token to its frame written; "
+                "decode: first token to finish), folded from its "
+                "RequestTrace when the response ends — off with "
+                "TPU_TRACE=0",
+                buckets=STAGE_BUCKETS)
+GLOBAL.describe("tpu_model_slot_vacant_seconds_total",
+                "Slot-seconds a decode slot stood free, split by whether "
+                "a request was waiting for admission meanwhile "
+                "(queue=waiting|empty); over "
+                "tpu_model_slot_seconds_total it is the share of "
+                "capacity admission left unused")
+GLOBAL.describe("tpu_model_slot_seconds_total",
+                "Slot-seconds the scheduler loop accounted: max_slots x "
+                "wall time of its iterations")
 # pre-seed the failure counters at 0: alert rules rate() over these, and
 # a series that first appears AT the first failure hides that failure
 # (the stall/chunk counters likewise: a mixed-load dashboard must read 0,
@@ -570,6 +601,10 @@ for _kind in ("decode", "prefill", "spec"):
     GLOBAL.inc("tpu_model_useful_tokens_total", 0.0, f'{{kind="{_kind}"}}')
     GLOBAL.inc("tpu_model_padded_tokens_total", 0.0, f'{{kind="{_kind}"}}')
 GLOBAL.inc("tpu_model_model_flops_total", 0.0)
+for _queue in ("waiting", "empty"):
+    GLOBAL.inc("tpu_model_slot_vacant_seconds_total", 0.0,
+               f'{{queue="{_queue}"}}')
+GLOBAL.inc("tpu_model_slot_seconds_total", 0.0)
 for _phase in ("dispatch_wait", "host", "idle"):
     GLOBAL.inc("tpu_model_breakdown_seconds_total", 0.0,
                f'{{phase="{_phase}"}}')

@@ -1,0 +1,404 @@
+"""The span vocabulary of runtime/trace.py: host spans (one closed table,
+each a histogram series and a TraceAnnotation), request stages folded from a
+RequestTrace, the slot-vacancy counters, and the device scopes every path of
+the model step carries into its lowered program."""
+
+import re
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from ollama_operator_tpu.models import config as cfglib
+from ollama_operator_tpu.runtime import trace as trace_mod
+from ollama_operator_tpu.runtime.engine import Engine, EngineConfig
+from ollama_operator_tpu.runtime.trace import (DEVICE_SCOPES, SPAN_TABLE,
+                                               SPANS, STAGES, TRACER,
+                                               RequestTrace, device_scope,
+                                               fold_stages, span)
+from ollama_operator_tpu.server.metrics import GLOBAL as METRICS
+from ollama_operator_tpu.server.metrics import STAGE_BUCKETS
+
+from test_scheduler import GREEDY, make_stack
+
+SPAN_NAMES = [row[0] for row in SPAN_TABLE]
+# the scopes a dense (no MoE) model's step must carry, on every path
+DENSE_SCOPES = [s for s in DEVICE_SCOPES if not s.startswith("moe.")]
+
+
+def _series(name, labels):
+    """(count, sum) of one histogram series of the global registry."""
+    text = METRICS.render()
+    out = []
+    for suffix in ("_count", "_sum"):
+        m = re.search(re.escape(name + suffix + labels) + r" (\S+)", text)
+        assert m, f"{name}{suffix}{labels} is not on /metrics"
+        out.append(float(m.group(1)))
+    return out
+
+
+# -- the closed tables -------------------------------------------------
+
+def test_an_unknown_span_or_scope_is_an_error():
+    with pytest.raises(KeyError):
+        span("sched.made_up")
+    with pytest.raises(KeyError):
+        device_scope("attention")
+    assert len(SPANS) == len(SPAN_TABLE)          # no name twice
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES)
+
+
+@pytest.mark.parametrize("name", SPAN_NAMES)
+def test_every_span_is_described_and_preseeded(name):
+    text = METRICS.render()
+    assert "# HELP tpu_model_span_seconds " in text
+    assert f'tpu_model_span_seconds_count{{span="{name}"}}' in text
+    assert SPANS[name] in ("HTTP server", "scheduler", "admission",
+                           "engine dispatch")
+    assert name.startswith(("http.", "sched.", "engine."))
+
+
+@pytest.mark.parametrize("stage", STAGES)
+def test_every_stage_is_described_and_preseeded(stage):
+    text = METRICS.render()
+    assert "# HELP tpu_model_request_stage_seconds " in text
+    assert (f'tpu_model_request_stage_seconds_count{{stage="{stage}"}}'
+            in text)
+
+
+def test_vacancy_counters_are_preseeded_and_the_gauge_is_gone():
+    text = METRICS.render()
+    for q in ("waiting", "empty"):
+        assert (f'tpu_model_slot_vacant_seconds_total{{queue="{q}"}}'
+                in text)
+    assert "tpu_model_slot_seconds_total " in text
+    assert "tpu_model_dispatch_ms" not in text
+
+
+def test_metrics_discipline_passes_on_the_shipped_tree():
+    from pathlib import Path
+
+    from tools.invariant_lint.core import LintConfig, run_passes
+    from tools.invariant_lint.passes.metrics_discipline import \
+        MetricsDisciplinePass
+    root = Path(__file__).resolve().parents[1]
+    findings = [f for f in run_passes(LintConfig(root=root),
+                                      [MetricsDisciplinePass()])
+                if not f.suppressed]
+    assert [str(f) for f in findings] == []
+
+
+def test_stage_buckets_step_by_at_most_a_quarter_from_1ms_to_60s():
+    assert STAGE_BUCKETS[0] == pytest.approx(1e-3)
+    assert STAGE_BUCKETS[-1] == pytest.approx(60.0)
+    steps = [b / a for a, b in zip(STAGE_BUCKETS, STAGE_BUCKETS[1:])]
+    assert max(steps) <= 1.25
+    bounds, counts = METRICS.hist_buckets("tpu_model_request_stage_seconds",
+                                          '{stage="queue"}')
+    assert bounds == STAGE_BUCKETS and len(counts) == len(bounds) + 1
+
+
+def test_the_benchmark_reads_the_programs_vocabulary():
+    from benchmark import trace_spans
+    assert trace_spans.SCOPES == DEVICE_SCOPES
+    assert all(n.startswith(trace_spans.SPAN_PREFIXES) for n in SPANS)
+    grouped = [s for ss in trace_spans.GROUPS.values() for s in ss]
+    assert set(grouped) <= set(DEVICE_SCOPES)
+    assert len(grouped) == len(set(grouped))
+
+
+# -- the span primitive ------------------------------------------------
+
+def test_a_span_observes_its_duration_once():
+    n0, s0 = _series("tpu_model_span_seconds", '{span="sched.fanout"}')
+    with span("sched.fanout") as sp:
+        time.sleep(0.01)
+    assert sp.dur >= 0.01 and sp.t0 > 0
+    sp.end()                                      # idempotent
+    n1, s1 = _series("tpu_model_span_seconds", '{span="sched.fanout"}')
+    assert n1 == n0 + 1
+    assert s1 - s0 == pytest.approx(sp.dur)
+
+
+def test_nested_spans_give_the_parents_self_time():
+    with span("sched.admit") as outer:
+        time.sleep(0.005)
+        with span("engine.admit") as inner:
+            time.sleep(0.02)
+            with span("engine.install_key") as leaf:
+                time.sleep(0.005)
+    assert inner.dur >= 0.025 and outer.dur >= inner.dur
+    assert outer.self_s == pytest.approx(outer.dur - inner.dur)
+    assert inner.self_s == pytest.approx(inner.dur - leaf.dur)
+    assert leaf.self_s == pytest.approx(leaf.dur)
+    # siblings both come off the parent
+    with span("sched.housekeep") as p:
+        with span("engine.release") as a:
+            pass
+        with span("engine.upload") as b:
+            pass
+    assert p.self_s == pytest.approx(p.dur - a.dur - b.dur)
+
+
+def test_spans_nest_per_thread():
+    seen = {}
+
+    def other():
+        with span("http.flush") as sp:
+            time.sleep(0.01)
+        seen["flush"] = sp
+
+    with span("sched.wait") as outer:
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    assert outer.self_s == pytest.approx(outer.dur)   # not its child
+    assert seen["flush"].self_s == pytest.approx(seen["flush"].dur)
+
+
+def test_a_cancelled_span_records_nothing():
+    n0, _ = _series("tpu_model_span_seconds", '{span="http.ingress"}')
+    sp = span("http.ingress").begin()
+    sp.cancel()
+    sp.end()
+    n1, _ = _series("tpu_model_span_seconds", '{span="http.ingress"}')
+    assert n1 == n0
+    with span("sched.idle") as after:            # the stack is clean again
+        pass
+    assert after.self_s == pytest.approx(after.dur)
+
+
+def test_a_span_with_rid_stamps_the_requests_trace():
+    tr = TRACER.begin("span-rid-1")
+    with span("http.flush", tr, n_tokens=3, chars=7):
+        pass
+    with span("http.flush", "span-rid-1", n_tokens=1, chars=1):
+        pass                                      # by id, through TRACER
+    evs = [(n, f) for _t, n, f in tr.events]
+    assert [n for n, _ in evs] == ["http_flush", "http_flush"]
+    assert evs[0][1]["n_tokens"] == 3 and evs[0][1]["chars"] == 7
+    assert "dur_ms" in evs[0][1]
+
+
+def test_a_span_is_a_trace_annotation_while_a_session_runs(tmp_path):
+    """The host plane of a profiler session holds the span, its nesting and
+    its fields, at the host tracer level the benchmark uses."""
+    from benchmark import trace_spans
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with span("sched.admit") as sp:
+            sp.set(n=2)
+            with span("engine.admit"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    planes = trace_spans.read_planes(trace_spans.find_trace(str(tmp_path)))
+    lines = trace_spans.host_spans(planes)
+    assert len(lines) == 1
+    (evs,) = lines.values()
+    assert sorted(n for _s, _e, n in evs) == ["engine.admit", "sched.admit"]
+    flat = trace_spans.innermost(evs)
+    assert [n for _s, _e, n in flat] == ["sched.admit", "engine.admit",
+                                         "sched.admit"]
+
+
+# -- request stages ----------------------------------------------------
+
+def _stage_counts():
+    return {st: _series("tpu_model_request_stage_seconds",
+                        f'{{stage="{st}"}}')[0] for st in STAGES}
+
+
+def test_fold_stages_worked_by_hand():
+    tr = RequestTrace("fold-1")
+    t0 = tr._t0
+    tr.t_http = t0 - 0.004                        # handler began 4 ms early
+    tr.event_at(t0 + 0.001, "queued")
+    tr.event_at(t0 + 0.500, "prefill", kind="admit", dur_ms=200.0)
+    tr.event_at(t0 + 0.501, "admitted")
+    tr.event_at(t0 + 0.502, "first_token")
+    tr.event_at(t0 + 0.503, "http_flush", dur_ms=2.0)
+    tr.event_at(t0 + 1.700, "http_flush", dur_ms=1.0)
+    tr.event_at(t0 + 2.502, "finish")
+    got = fold_stages(tr)
+    assert got == pytest.approx({"ingress": 0.005, "queue": 0.299,
+                                 "prefill": 0.202, "first_flush": 0.003,
+                                 "decode": 2.0})
+    assert fold_stages(tr) == {}                  # once
+
+
+def test_stage_durations_sum_to_ttft_and_finish_on_a_scheduler_run():
+    cfg, params, eng, sched = make_stack(slots=1)
+    try:
+        before = _stage_counts()
+        ingress = span("http.ingress").begin()    # as the handler does
+        time.sleep(0.003)
+        r = sched.submit(np.array([1, 2, 3, 4], np.int32), GREEDY,
+                         max_tokens=6)
+        ingress.end()
+        assert len(list(r.tokens())) == 6
+        tr = TRACER.get(r.id)
+        assert tr.t_http == ingress.t0
+        assert not tr.folded                      # the handler's to fold
+        got = fold_stages(tr)
+        at = {}
+        for t, name, _f in tr.events:
+            at.setdefault(name, t)
+        http = tr.t_http - tr._t0
+        assert set(got) == {"ingress", "queue", "prefill", "decode"}
+        assert got["ingress"] >= 0.003
+        assert got["ingress"] + got["queue"] + got["prefill"] == \
+            pytest.approx(at["first_token"] - http)
+        assert sum(got.values()) == pytest.approx(at["finish"] - http)
+        after = _stage_counts()
+        for st in got:
+            assert after[st] == before[st] + 1
+        assert after["first_flush"] == before["first_flush"]
+    finally:
+        sched.shutdown()
+
+
+def test_a_request_no_handler_owns_folds_at_finish():
+    cfg, params, eng, sched = make_stack(slots=1)
+    try:
+        before = _stage_counts()
+        r = sched.submit(np.array([5, 6, 7], np.int32), GREEDY,
+                         max_tokens=3)
+        assert len(list(r.tokens())) == 3
+        tr = TRACER.get(r.id)
+        deadline = time.time() + 5
+        while not tr.folded and time.time() < deadline:
+            time.sleep(0.01)
+        assert tr.t_http is None and tr.folded
+        after = _stage_counts()
+        assert after["queue"] == before["queue"] + 1
+        assert after["decode"] == before["decode"] + 1
+        assert after["ingress"] == before["ingress"]
+    finally:
+        sched.shutdown()
+
+
+def test_trace_off_leaves_stage_histograms_untouched(monkeypatch):
+    monkeypatch.setattr(trace_mod, "TRACE_ENABLED", False)
+    cfg, params, eng, sched = make_stack(slots=1)
+    try:
+        before = _stage_counts()
+        v0 = METRICS.get("tpu_model_slot_seconds_total")
+        r = sched.submit(np.array([1, 2, 3], np.int32), GREEDY,
+                         max_tokens=4)
+        assert len(list(r.tokens())) == 4
+        assert r.trace is trace_mod.NULL_TRACE
+        assert fold_stages(r.trace) == {}
+        time.sleep(0.12)
+        assert _stage_counts() == before
+        # the vacancy counters do not depend on the kill switch
+        assert METRICS.get("tpu_model_slot_seconds_total") > v0
+    finally:
+        sched.shutdown()
+
+
+# -- slot vacancy ------------------------------------------------------
+
+def test_vacancy_counters_add_up_to_slots_times_wall():
+    n_slots = 3
+    cfg, params, eng, sched = make_stack(slots=n_slots)
+    w, e = '{queue="waiting"}', '{queue="empty"}'
+    try:
+        time.sleep(0.15)                          # let the loop start
+        t0 = time.perf_counter()
+        s0 = METRICS.get("tpu_model_slot_seconds_total")
+        v0 = (METRICS.get("tpu_model_slot_vacant_seconds_total", w)
+              + METRICS.get("tpu_model_slot_vacant_seconds_total", e))
+        e0 = METRICS.get("tpu_model_slot_vacant_seconds_total", e)
+        r = sched.submit(np.array([1, 2, 3], np.int32), GREEDY,
+                         max_tokens=8)
+        assert len(list(r.tokens())) == 8
+        time.sleep(0.6)                           # idle: every slot vacant
+        wall = time.perf_counter() - t0
+        s1 = METRICS.get("tpu_model_slot_seconds_total")
+        v1 = (METRICS.get("tpu_model_slot_vacant_seconds_total", w)
+              + METRICS.get("tpu_model_slot_vacant_seconds_total", e))
+        e1 = METRICS.get("tpu_model_slot_vacant_seconds_total", e)
+        # the loop's iterations tile the wall time (one is 50 ms at most)
+        assert s1 - s0 == pytest.approx(n_slots * wall, abs=n_slots * 0.12)
+        assert 0.0 < v1 - v0 <= s1 - s0 + 1e-9
+        # nothing waited while the scheduler idled: that vacancy is "empty"
+        assert e1 - e0 >= n_slots * 0.4
+    finally:
+        sched.shutdown()
+
+
+# -- device scopes in every path's lowered program ---------------------
+
+def _lowered_texts(monkeypatch, **ecfg_kw):
+    """Lowered text (with locations) of the admit, decode and extend
+    programs a tiny engine compiles when it serves one request."""
+    texts = {}
+    orig = Engine._compile
+
+    def spy(self, kind, key, jit_fn, *args):
+        texts.setdefault(kind, jit_fn.lower(*args).as_text(debug_info=True))
+        return orig(self, kind, key, jit_fn, *args)
+
+    monkeypatch.setattr(Engine, "_compile", spy)
+    from ollama_operator_tpu.models import decoder
+    cfg = cfglib.PRESETS["tiny"]
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jax.numpy.float32)
+    kw = dict(max_slots=2, max_seq_len=64, cache_dtype=jax.numpy.float32,
+              min_prefill_bucket=16)
+    kw.update(ecfg_kw)
+    eng = Engine(cfg, params, ecfg=EngineConfig(**kw))
+    ids = np.arange(1, 21, dtype=np.int32)
+    eng.admit(0, ids[:10])
+    eng.decode_n(2)
+    eng.release(0, park=True)
+    eng.extend(0, ids, 10)
+    eng.release(0)
+    return texts
+
+
+PATHS = {"dense": {}, "paged": dict(paged=True, page_size=16, n_pages=8),
+         "paged_int8": dict(paged=True, page_size=16, n_pages=8,
+                            cache_dtype=jax.numpy.int8)}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    mp = pytest.MonkeyPatch()
+    try:
+        return {path: _lowered_texts(mp, **kw) for path, kw in PATHS.items()}
+    finally:
+        mp.undo()
+
+
+@pytest.mark.parametrize("program", ["admit", "decode", "extend"])
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_lowered_program_carries_every_scope(lowered, path, program):
+    text = lowered[path][program]
+    found = {s for s in DEVICE_SCOPES
+             if re.search(r'[/"]' + re.escape(s) + r'[/"]', text)}
+    assert found >= set(DENSE_SCOPES), (
+        f"{path}/{program} lacks {sorted(set(DENSE_SCOPES) - found)}")
+    # and no dotted scope that the table does not hold
+    dotted = set(re.findall(r'[/"]((?:attn|moe)\.[a-z_]+)[/"]', text))
+    assert dotted <= set(DEVICE_SCOPES)
+
+
+def test_moe_scopes_nest_under_mlp():
+    import dataclasses
+
+    from ollama_operator_tpu.models import decoder
+    cfg = dataclasses.replace(cfglib.PRESETS["tiny"], n_experts=4,
+                              n_experts_used=2)
+    params = decoder.init_params(cfg, jax.random.PRNGKey(1),
+                                 dtype=jax.numpy.float32)
+    toks = jax.numpy.zeros((1, 8), jax.numpy.int32)
+    text = jax.jit(lambda p, t: decoder.prefill_chunk(p, cfg, t)[0]).lower(
+        params, toks).as_text(debug_info=True)
+    assert "mlp/moe.route/" in text and "mlp/moe.experts/" in text
