@@ -275,12 +275,6 @@ def measure(jax, *, model: str, dtype: str, slots: int, steps: int,
         mesh = make_mesh(MeshPlan.for_devices(len(devs), tp=tp))
         log(f"mesh: {dict(mesh.shape)}")
 
-    if dtype == "int4":
-        # shared routing with the server loader (ops/quant.int4_mm_kernels)
-        # so the bench can never measure a different matmul path than the
-        # server ships
-        from ollama_operator_tpu.ops.quant import int4_mm_kernels
-        cfg = int4_mm_kernels(cfg, mesh)
     eng = Engine(cfg, params, mesh=mesh,
                  ecfg=EngineConfig(
                      max_slots=slots, max_seq_len=seq, decode_chunk=chunk,
@@ -489,9 +483,6 @@ def measure_spec(jax, *, model: str, dtype: str, slots: int, steps: int,
         f"k={spec_k}")
     params, param_bytes, dtype = _bench_params(
         jax, cfg, model, dtype, on_cpu, params_cache)
-    if dtype == "int4":
-        from ollama_operator_tpu.ops.quant import int4_mm_kernels
-        cfg = int4_mm_kernels(cfg, None)
     eng = Engine(cfg, params,
                  ecfg=EngineConfig(max_slots=slots, max_seq_len=seq,
                                    decode_chunk=chunk,
@@ -724,9 +715,6 @@ def measure_http(jax, *, model: str, dtype: str, slots: int, steps: int,
         f"steps={steps} paged={paged}")
     params, param_bytes, dtype = _bench_params(
         jax, cfg, model, dtype, on_cpu, params_cache)
-    if dtype == "int4":
-        from ollama_operator_tpu.ops.quant import int4_mm_kernels
-        cfg = int4_mm_kernels(cfg, None)
 
     tok = _bench_tokenizer(cfg.vocab_size)
     name = ModelName.parse("bench").short
@@ -885,9 +873,6 @@ def measure_mixed(jax, *, model: str, dtype: str, slots: int, steps: int,
         f"slots={slots} steps={steps} seq={seq}")
     params, param_bytes, dtype = _bench_params(
         jax, cfg, model, dtype, on_cpu, params_cache)
-    if dtype == "int4":
-        from ollama_operator_tpu.ops.quant import int4_mm_kernels
-        cfg = int4_mm_kernels(cfg, None)
     # the model config caps the servable context (Engine takes the min),
     # so size the decode chunk and prefill piece to the REAL context —
     # at smoke scale (tiny model, 128 ctx) the defaults would leave no
@@ -1197,9 +1182,6 @@ def measure_prefix(jax, *, model: str, dtype: str, slots: int, steps: int,
         f"slots={slots} seq={seq}")
     params, param_bytes, dtype = _bench_params(
         jax, cfg, model, dtype, on_cpu, params_cache)
-    if dtype == "int4":
-        from ollama_operator_tpu.ops.quant import int4_mm_kernels
-        cfg = int4_mm_kernels(cfg, None)
     serve_seq = min(seq, cfg.max_seq_len)
     # page size small enough that the shared prefix spans several pages
     # even at smoke scale (radix nodes are page-granular)

@@ -108,13 +108,16 @@ class ModelConfig:
                                        # token, scaled by a sigmoid gate
     moe_impl: str = "auto"             # auto|einsum|scan (models/decoder.py)
     kernels: str = "auto"              # attention impl: auto|pallas|xla|interpret
-    mm_kernels: str = "auto"           # quantized-matmul impl. "auto" = XLA
-                                       # (the grouped einsum measured faster
-                                       # than the fused kernel for int8 on
-                                       # v5e); the int4 loader sets "pallas"
-                                       # on single-device TPU — only the
-                                       # kernel reads packed bytes once, the
-                                       # XLA int4 path reads them twice
+    mm_kernels: str = "auto"           # quantized-matmul impl:
+                                       # auto|pallas|xla|interpret. "auto"
+                                       # is resolved by the engine
+                                       # (ops/quant.resolve_mm_kernels):
+                                       # "pallas" on a single-device TPU,
+                                       # where ops/quant.matmul routes by
+                                       # row count (int8 <= 16 rows: the
+                                       # grouped XLA form; above, and int4
+                                       # throughout: the fused kernel),
+                                       # "xla" on meshes and other backends
 
     @property
     def q_dim(self) -> int:

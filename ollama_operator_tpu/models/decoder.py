@@ -56,20 +56,50 @@ Params = Dict[str, Any]
 
 def _mm(cfg: ModelConfig, x, w, out_dtype=None):
     """Linear against a dense array or a quantized dict leaf
-    (ops/quant.py). The XLA grouped path wins on v5e for int8 full-model
-    decode (the fused pallas kernel measured slower: 137 vs 147 tok/s on
-    phi), so "auto" resolves to XLA here; cfg.mm_kernels overrides just
-    the matmul choice (the int4 loader sets it to "pallas" on
-    single-device TPU, where the kernel's read-each-byte-once is the
-    whole bandwidth win), and an explicit kernels="pallas"/"interpret"
-    config still routes everything through kernels."""
+    (ops/quant.py). cfg.mm_kernels picks the quantized matmul's path:
+    the engine's constructor resolves "auto" (ops/quant.resolve_mm_kernels:
+    "pallas" on a single-device TPU, "xla" elsewhere), and under "pallas"
+    ops/quant.matmul routes by row count — int8 up to 16 rows keeps the
+    grouped XLA form (one stream measured 147 tok/s on it against the fused
+    kernel's 137 on phi: N = 1, not a batch), above that the fused kernel,
+    int4 the fused kernel throughout. An "auto" that nobody resolved (a
+    forward pass outside any engine) is XLA, and an explicit
+    kernels="pallas"/"interpret" config still routes everything through
+    kernels."""
+    return Q.matmul(x, w, out_dtype, kernels=_mm_mode(cfg))
+
+
+def _mm_mode(cfg: ModelConfig) -> str:
     if cfg.kernels in ("pallas", "interpret"):
-        mode = cfg.kernels
-    elif cfg.mm_kernels in ("pallas", "interpret"):
-        mode = cfg.mm_kernels
-    else:
-        mode = "xla"
-    return Q.matmul(x, w, out_dtype, kernels=mode)
+        return cfg.kernels
+    if cfg.mm_kernels in ("pallas", "interpret"):
+        return cfg.mm_kernels
+    return "xla"
+
+
+def _scan_layers(cfg: ModelConfig, body, init, layers: Params):
+    """``lax.scan`` of ``body(carry, (lp, i))`` over the stacked layers.
+
+    A scan hands its body a slice of every stacked leaf. An XLA consumer
+    fuses that slice into its own read; a pallas_call cannot, so the slice
+    is a copy: for starcoder2's w_up 51 us to copy where the fused matmul
+    then needs 43 to read it, and the copies of one decode step outlasted
+    its matmuls (my chip run, PR 25). So where the quantized matmuls go to
+    the fused kernel, their leaves stay out of the scan's slices: every
+    step's ``lp`` carries the whole stack and this layer's index
+    (``{"q": [L, K, O], "s": [L, K/g, O], "layer": i}``), and the kernel
+    reads its layer where it lies (ops/quant.matmul)."""
+    in_place = ({k: v for k, v in layers.items() if Q.is_quantized(v)}
+                if _mm_mode(cfg) != "xla" else {})
+    sliced = {k: v for k, v in layers.items() if k not in in_place}
+
+    def step(carry, layer_in):
+        lp, i = layer_in
+        lp = {**lp, **{k: {**v, "layer": i} for k, v in in_place.items()}}
+        return body(carry, (lp, i))
+
+    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    return lax.scan(step, init, (sliced, jnp.arange(n, dtype=jnp.int32)))
 
 
 # --------------------------------------------------------------------------
@@ -542,15 +572,14 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                      scale, mesh=mesh)
             return x, (k, v)
 
-        x, (ks, vs) = lax.scan(
-            body_a, x, (params["layers"], jnp.arange(cfg.n_layers)))
+        x, (ks, vs) = _scan_layers(cfg, body_a, x, params["layers"])
     else:
-        def body(x, lp):
-            x, (k, v) = _block_chunk(cfg, lp, x, cos, sin, mask, scale,
-                                     mesh=mesh)
+        def body(x, layer_in):
+            x, (k, v) = _block_chunk(cfg, layer_in[0], x, cos, sin, mask,
+                                     scale, mesh=mesh)
             return x, (k, v)
 
-        x, (ks, vs) = lax.scan(body, x, params["layers"])
+        x, (ks, vs) = _scan_layers(cfg, body, x, params["layers"])
     logits = _unembed(cfg, params, x)
     return logits, ks, vs
 
@@ -651,9 +680,8 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         x = _residual(cfg, lp, x, h, attn)
         return (x, kc, vc), None
 
-    (x, k_cache, v_cache), _ = lax.scan(
-        body, (x, k_cache, v_cache),
-        (params["layers"], jnp.arange(cfg.n_layers)))
+    (x, k_cache, v_cache), _ = _scan_layers(
+        cfg, body, (x, k_cache, v_cache), params["layers"])
     logits = _unembed(cfg, params, x)
     return logits, k_cache, v_cache
 
@@ -1177,8 +1205,7 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
         x = _residual(cfg, lp, x, h, attn)
         return (x, kp, vp), None
 
-    (x, k_pool, v_pool), _ = lax.scan(
-        body, (x, k_pool, v_pool),
-        (params["layers"], jnp.arange(cfg.n_layers)))
+    (x, k_pool, v_pool), _ = _scan_layers(
+        cfg, body, (x, k_pool, v_pool), params["layers"])
     logits = _unembed(cfg, params, x)
     return logits, k_pool, v_pool

@@ -24,7 +24,8 @@ where byte j holds row j in its low nibble and row j+16 in its high nibble
 that is a multiple of the group unpacks with a sublane-granular concat —
 no cross-tile shuffles — which is what the pallas kernel wants.
 
-Matmul paths:
+Matmul paths (``matmul`` routes between them by row count, weight type
+and the ``kernels`` it is given):
 - ``qmm`` / ``qmm4``: pure-XLA grouped partial einsums — correct on any
   backend and under GSPMD (the convert fuses into the dot's operand
   stream). The int4 decode form runs two half-group dots over the same
@@ -32,12 +33,13 @@ Matmul paths:
   (~0.63 B/weight with the f32 group scales; 70B int4 ≈ 43 GB) is
   unconditional, the *bandwidth* win needs the kernel below.
 - ``ops/pallas/quant.py``: fused dequant-matmul kernels (int8 and int4);
-  the int4 kernel reads each packed byte once, i.e. half int8's weight
-  traffic.
+  no dequantized weight reaches HBM at any row count, and the int4 kernel
+  reads each packed byte once, i.e. half int8's weight traffic.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, Dict, Optional
 
@@ -217,10 +219,7 @@ def qmm4(x: jax.Array, qw: Dict[str, Any],
     G = s.shape[0]
     g = K // G
     h = g // 2
-    N = 1
-    for d in x.shape[:-1]:
-        N *= d
-    if N > 16:
+    if _rows(x) > GROUPED_MAX_ROWS:
         # dequantize in f32, cast the product once — the decode form and
         # the pallas kernel apply f32 scales post-dot, so prefill must not
         # see scale values rounded through bf16's 8-bit mantissa
@@ -241,38 +240,32 @@ def qmm4(x: jax.Array, qw: Dict[str, Any],
     return y.astype(out_dtype or x.dtype)
 
 
-def qmm(x: jax.Array, qw: Dict[str, Any],
-        out_dtype: Optional[Any] = None) -> jax.Array:
-    """x [..., K] @ dequant(qw [K, O]) with group-wise scales.
+def _rows(x) -> int:
+    return int(np.prod(x.shape[:-1], dtype=np.int64))
 
-    Two formulations, picked by the (static) token count N = prod(lead):
 
-    - **decode** (N small): grouped partial, keeping the scale multiply
-      outside the inner dot so the int8→bf16 convert fuses into the dot's
-      read stream and the weight is read once at 1 byte/element:
+def layer_of(stack: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a stacked leaf (``layer`` None: it is no stack).
+    XLA fuses this slice into its consumer's read, as it does a scan's."""
+    if layer is None:
+        return stack
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
 
-          y[.., o] = Σ_G s[G, o] · Σ_{k∈G} x[.., k] · q[k, o]
 
-      The [N, K/g, O] fp32 partial is tiny for decode batches.
-    - **prefill** (N large): that partial scales as N × weight-bytes×4 —
-      gigabytes per matmul at N=128+ — so dequantize the weight to one
-      [K, O] transient instead and run a single dense dot; prefill is
-      MXU-bound, the extra weight-write bandwidth is noise there.
-    """
+def qmm_grouped(x: jax.Array, qw: Dict[str, Any],
+                out_dtype: Optional[Any] = None) -> jax.Array:
+    """The few-rows XLA form: grouped partial, the scale multiply kept
+    outside the inner dot so the int8→bf16 convert fuses into the dot's
+    read stream and the weight is read once at 1 byte/element:
+
+        y[.., o] = Σ_G s[G, o] · Σ_{k∈G} x[.., k] · q[k, o]
+
+    Its [N, K/g, O] f32 partial is N × the weight's bytes × 4/g: tiny at a
+    handful of rows, 302 MB for one 3072x12288 matrix at 64."""
     q, s = qw["q"], qw["s"]
     K, O = q.shape
     G = s.shape[0]
     g = K // G
-    N = 1
-    for d in x.shape[:-1]:
-        N *= d
-    if N > 16:
-        # f32 scales (same reasoning as qmm4's batch form)
-        w = (q.reshape(G, g, O).astype(jnp.float32)
-             * s[:, None, :]).reshape(K, O).astype(x.dtype)
-        y = jnp.einsum("...k,ko->...o", x, w,
-                       preferred_element_type=jnp.float32)
-        return y.astype(out_dtype or x.dtype)
     xr = x.reshape(*x.shape[:-1], G, g)
     qr = q.reshape(G, g, O)
     partial = jnp.einsum("...Gg,Ggo->...Go", xr, qr.astype(x.dtype),
@@ -281,29 +274,80 @@ def qmm(x: jax.Array, qw: Dict[str, Any],
     return y.astype(out_dtype or x.dtype)
 
 
+def qmm_dense(x: jax.Array, qw: Dict[str, Any],
+              out_dtype: Optional[Any] = None) -> jax.Array:
+    """The many-rows XLA form: dequantize the weight to one [K, O]
+    transient (f32 scales: the grouped form and the pallas kernel apply
+    them in f32, so this one must not round them through bf16) and run a
+    single dense dot. The transient goes through HBM: ~12 bytes moved per
+    weight element where the codes are one. That is noise once the dot is
+    MXU-bound (a thousand rows and more) and is the whole cost below it,
+    which is where batched decode (32-64 rows) ran until the fused kernel
+    took those row counts on a single-device TPU (``matmul``)."""
+    q, s = qw["q"], qw["s"]
+    K, O = q.shape
+    G = s.shape[0]
+    w = (q.reshape(G, K // G, O).astype(jnp.float32)
+         * s[:, None, :]).reshape(K, O).astype(x.dtype)
+    y = jnp.einsum("...k,ko->...o", x, w,
+                   preferred_element_type=jnp.float32)
+    return y.astype(out_dtype or x.dtype)
+
+
+# the grouped form's f32 partial grows with the rows; above this many the
+# XLA path dequantizes the whole weight instead (and the fused kernel, where
+# it can run, takes over: hack/qmm_microbench.py has the table)
+GROUPED_MAX_ROWS = 16
+
+
+def qmm(x: jax.Array, qw: Dict[str, Any],
+        out_dtype: Optional[Any] = None) -> jax.Array:
+    """x [..., K] @ dequant(qw [K, O]) with group-wise scales, in XLA:
+    ``qmm_grouped`` up to GROUPED_MAX_ROWS rows (N = prod(lead), static),
+    ``qmm_dense`` above."""
+    form = qmm_grouped if _rows(x) <= GROUPED_MAX_ROWS else qmm_dense
+    return form(x, qw, out_dtype)
+
+
 def matmul(x: jax.Array, w: Any, out_dtype: Optional[Any] = None,
            kernels: str = "xla") -> jax.Array:
     """Unified linear: dense jnp array or quantized dict weight.
 
-    ``kernels`` follows ops/attention.resolve_kernels semantics — "pallas"
-    routes 2D-reshapeable quantized matmuls through the fused kernel.
+    The quantized matmul is one algorithm (dequantize, dot, accumulate)
+    that wants the dequantized tile in a different place at different row
+    counts N, so the form is chosen from what the inputs show:
+
+    1. int8, N <= GROUPED_MAX_ROWS: the grouped XLA form, which reads the
+       weight once already (measured best at one stream).
+    2. ``kernels`` "pallas" (or "interpret") otherwise: the fused kernel
+       (ops/pallas/quant.py), int8 tiles dequantized in VMEM. int4 takes
+       it at every N: only the kernel reads packed bytes once.
+    3. ``kernels`` "xla" (a GSPMD mesh, where pallas_call is opaque; CPU;
+       an explicit choice): the XLA forms at every N.
+
+    1 and 3 are routes, recorded as the kernel they are; only a shape that
+    was meant for the fused kernel and does not tile is a fallback.
     """
     if not is_quantized(w):
         y = x @ w
         return y.astype(out_dtype) if out_dtype is not None else y
-    if kernels in ("pallas", "interpret"):
+    int4 = is_int4(w)
+    codes = "q4" if int4 else "q"
+    # a stack of layers and the index of this one (models/decoder.py
+    # _scan_layers): the fused kernel reads its layer where it lies
+    layer = w.get("layer")
+    if kernels in ("pallas", "interpret") and (
+            int4 or _rows(x) > GROUPED_MAX_ROWS):
         from .pallas.quant import qmm4_pallas, qmm_pallas
         lead = x.shape[:-1]
         x2 = x.reshape(-1, x.shape[-1])
-        if is_int4(w):
-            y = qmm4_pallas(x2, w["q4"], w["s"],
-                            interpret=(kernels == "interpret"))
-        else:
-            y = qmm_pallas(x2, w["q"], w["s"],
-                           interpret=(kernels == "interpret"))
+        y = (qmm4_pallas if int4 else qmm_pallas)(
+            x2, w[codes], w["s"], interpret=(kernels == "interpret"),
+            layer=layer)
         return y.reshape(*lead, -1).astype(out_dtype or x.dtype)
-    note_kernel("matmul", "xla_int4" if is_int4(w) else "xla_int8")
-    return (qmm4 if is_int4(w) else qmm)(x, w, out_dtype)
+    note_kernel("matmul", "xla_int4" if int4 else "xla_int8")
+    w = {k: layer_of(w[k], layer) for k in (codes, "s")}
+    return (qmm4 if int4 else qmm)(x, w, out_dtype)
 
 
 def quantize_params(params: Dict[str, Any], group: int = GROUP,
@@ -341,28 +385,30 @@ def quantize_params(params: Dict[str, Any], group: int = GROUP,
     return out
 
 
-def int4_mm_kernels(cfg, mesh) -> Any:
-    """The ``mm_kernels`` value an int4 load should serve with: the fused
-    pallas kernel on a single-device TPU (the only matmul path that reads
-    each packed byte once), the portable XLA einsum under GSPMD meshes —
-    and an explicitly-set ``mm_kernels`` (config) or ``kernels=xla``
-    (config or OLLAMA_TPU_KERNELS) stays the escape hatch if the kernel
-    miscompiles — the matmul hatch works independently of the attention
-    switch. One helper so the server loader and bench.py can never drift
-    onto different matmul paths (they feed the same BASELINE numbers).
-    Returns the cfg, possibly replaced."""
-    import dataclasses
-
-    import jax
-
+def resolve_mm_kernels(cfg, mesh) -> Any:
+    """Resolve ``cfg.mm_kernels == "auto"`` for the place the model runs:
+    "pallas" on a single-device TPU (``matmul`` then routes by row count
+    and weight type), "xla" wherever the fused kernel cannot run — a mesh
+    of more than one device (pallas_call is opaque to GSPMD), another
+    backend — or is switched off (``kernels=xla`` in the config or
+    OLLAMA_TPU_KERNELS: the escape hatch if a kernel miscompiles). An
+    explicit ``mm_kernels`` always stands. The ONE place "auto" is decided,
+    for int8 and int4 alike: the engine's constructor calls it, so every
+    way of building an engine (the server's loader, bench.py, the
+    benchmark's child and its probe) serves the same matmul path. Returns
+    the cfg, possibly replaced."""
     from .attention import resolve_kernels
     if cfg.mm_kernels != "auto":
         return cfg
-    if (jax.default_backend() == "tpu"
-            and (mesh is None or mesh.size == 1)
-            and resolve_kernels(cfg.kernels) != "xla"):
-        return dataclasses.replace(cfg, mm_kernels="pallas")
-    return cfg
+    fused = (jax.default_backend() == "tpu"
+             and (mesh is None or mesh.size == 1)
+             and resolve_kernels(cfg.kernels) != "xla")
+    return dataclasses.replace(cfg, mm_kernels="pallas" if fused else "xla")
+
+
+# the name the loaders and the benchmark's child import (when only int4
+# resolved to the kernel)
+int4_mm_kernels = resolve_mm_kernels
 
 
 def quantized_bytes(params: Dict[str, Any]) -> int:

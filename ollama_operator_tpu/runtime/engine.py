@@ -39,6 +39,7 @@ from ..models.config import ModelConfig
 from ..models import decoder
 from ..ops import sampling
 from ..ops.attention import record_kernels
+from ..ops.quant import resolve_mm_kernels
 from .faults import FAULTS
 from .trace import FLIGHT, device_scope, span
 from ..parallel.sharding import (kv_cache_pspec, params_sharding_tree,
@@ -397,6 +398,9 @@ class Engine:
         if cfg.n_experts:
             cfg = dataclasses.replace(
                 cfg, moe_impl=resolve_moe_impl(cfg, mesh))
+        # the quantized matmuls' path hangs on the mesh and the backend,
+        # both known here and nowhere below
+        cfg = resolve_mm_kernels(cfg, mesh)
         self.cfg = cfg
         self.ecfg = ecfg
         self.mesh = mesh

@@ -535,9 +535,6 @@ class ModelManager:
                 from ..ops.quant import quantize_params
                 params = quantize_params(
                     params, bits=4 if engine_dtype == "int4" else 8)
-                if engine_dtype == "int4":
-                    from ..ops.quant import int4_mm_kernels
-                    cfg = int4_mm_kernels(cfg, self.mesh)
             t_load.append(time.perf_counter())
             if self.mesh is None:
                 params = jax.tree_util.tree_map(jnp.asarray, params)

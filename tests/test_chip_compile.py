@@ -122,6 +122,44 @@ def test_dequant_matmul_compiles(one_chip, serving, K, O, bits):
     assert "tpu_custom_call" in txt
 
 
+def _qmm_stack_shapes(one_chip, N, K, O, bits, layers=2):
+    """x and a stack of ``layers`` quantized [K, O] weights, as the
+    decoder's layer scan hands them to the fused kernel."""
+    x = jax.ShapeDtypeStruct((N, K), jnp.bfloat16, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((layers, K // 32, O), jnp.float32,
+                             sharding=one_chip)
+    q = jax.ShapeDtypeStruct((layers, K * bits // 8, O),
+                             jnp.int8 if bits == 8 else jnp.uint8,
+                             sharding=one_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return x, q, s, layer
+
+
+# starcoder2-3b's and phi-2's widest matmuls (w_up, w_down, phi-2's head)
+# at the rows a saturated decode step has (32, 64 slots), at admit_many's
+# 4 x 256, and at the longest prefill bucket: the kernel's row axis keeps
+# every one inside the default 16 MiB of VMEM (no vmem_limit_bytes is set)
+@pytest.mark.parametrize("K,O", [(3072, 12288), (12288, 3072),
+                                 (2560, 51200)])
+@pytest.mark.parametrize("N", [32, 64, 256, 1024, 4096])
+def test_int8_fused_matmul_compiles_at_served_rows(one_chip, N, K, O):
+    txt = _compiled_text(
+        lambda x, q, s, l: qmm_pallas(x, q, s, layer=l),
+        *_qmm_stack_shapes(one_chip, N, K, O, 8))
+    assert "tpu_custom_call" in txt
+
+
+# Mistral-7B's MLP at the admit bucket PR 23 could not compile
+# (`_admit_exec(2048)`: all rows in one block, 16.31M of 16.00M)
+@pytest.mark.parametrize("K,O", [(4096, 14336), (14336, 4096)])
+@pytest.mark.parametrize("N", [2048, 4096])
+def test_int4_fused_matmul_compiles_at_long_admit(one_chip, N, K, O):
+    txt = _compiled_text(
+        lambda x, q, s, l: qmm4_pallas(x, q, s, layer=l),
+        *_qmm_stack_shapes(one_chip, N, K, O, 4))
+    assert "tpu_custom_call" in txt
+
+
 def test_paged_decode_v3_compiles_on_engine_pool(one_chip, serving):
     B, ps = serving.max_slots, serving.page_size
     nblk = CFG.max_seq_len // ps

@@ -333,3 +333,195 @@ def test_int4_mm_kernels_interpret_matches_xla():
     got, _, _ = decoder.prefill_chunk(qparams, cfg_k, tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the int8 matmul's routes by row count (ops/quant.matmul)
+# ---------------------------------------------------------------------------
+
+from ollama_operator_tpu.ops.attention import record_kernels  # noqa: E402
+
+_RK, _RO = 256, 384
+
+
+def _route_case(N, dtype=jnp.float32, K=_RK, O=_RO, group=32):
+    r = np.random.default_rng([N, K, O])
+    x = jnp.asarray(r.standard_normal((N, K)), dtype)
+    w = r.standard_normal((K, O)).astype(np.float32)
+    qw = jax.tree_util.tree_map(jnp.asarray, Q.quantize_groupwise(w, group))
+    want = np.asarray(x, np.float32) @ np.asarray(
+        Q.dequantize_groupwise(qw), np.float32)
+    return x, qw, want
+
+
+# regime 1 up to 16 rows, regime 2 above, at every row count the warm plan
+# compiles programs for (one block of rows up to 512, equal blocks above)
+@pytest.mark.parametrize("N,kernel", [
+    (1, "xla_int8"), (16, "xla_int8"), (17, "qmm_pallas"),
+    (32, "qmm_pallas"), (64, "qmm_pallas"), (256, "qmm_pallas"),
+    (1024, "qmm_pallas")])
+def test_int8_matmul_routes_by_rows(N, kernel):
+    x, qw, want = _route_case(N)
+    with record_kernels() as picked:
+        got = Q.matmul(x, qw, jnp.float32, kernels="interpret")
+    assert picked == [("matmul", kernel, False)]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("N", [1, 16, 17, 32, 64, 256, 1024])
+def test_int8_matmul_xla_stays_xla(N):
+    """Regime 3: an explicit (or resolved) "xla" is XLA at every N, and
+    is a route, not a fallback."""
+    x, qw, want = _route_case(N)
+    with record_kernels() as picked:
+        got = Q.matmul(x, qw, jnp.float32, kernels="xla")
+    assert picked == [("matmul", "xla_int8", False)]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("N", [17, 64])
+def test_int8_matmul_bf16_operands_match_dense_form(N):
+    """The fused kernel's arithmetic is the dense XLA form's: f32 scales,
+    bf16 operands, f32 accumulation."""
+    x, qw, _ = _route_case(N, jnp.bfloat16)
+    ref = Q.qmm_dense(x, qw, jnp.float32)
+    got = Q.matmul(x, qw, jnp.float32, kernels="interpret")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+
+
+def test_int8_matmul_untileable_shape_is_the_only_fallback():
+    x, qw, want = _route_case(24, K=48, O=40, group=16)
+    with record_kernels() as picked:
+        got = Q.matmul(x, qw, jnp.float32, kernels="interpret")
+    assert picked == [("matmul", "xla_int8", True)]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("N,bm,nm", [
+    (1, 16, 1), (17, 32, 1), (64, 64, 1), (192, 192, 1), (512, 512, 1),
+    (768, 384, 2), (1024, 512, 2), (4096, 512, 8), (16384, 512, 32)])
+def test_fused_kernel_row_blocks(N, bm, nm):
+    from ollama_operator_tpu.ops.pallas.quant import _row_blocks
+    assert _row_blocks(N) == (bm, nm)
+
+
+def _resolved(monkeypatch, backend, mesh, **kw):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.delenv("OLLAMA_TPU_KERNELS", raising=False)
+    return Q.resolve_mm_kernels(tiny(**kw), mesh).mm_kernels
+
+
+@pytest.mark.parametrize("backend,mesh_shape,kw,want", [
+    ("tpu", None, {}, "pallas"),
+    ("tpu", (1,), {}, "pallas"),
+    ("tpu", (2,), {}, "xla"),               # pallas_call is opaque to GSPMD
+    ("cpu", None, {}, "xla"),
+    ("tpu", None, {"kernels": "xla"}, "xla"),          # the escape hatch
+    ("tpu", None, {"mm_kernels": "xla"}, "xla"),       # explicit stands
+    ("cpu", None, {"mm_kernels": "pallas"}, "pallas"),
+    ("tpu", (2,), {"mm_kernels": "interpret"}, "interpret")])
+def test_resolve_mm_kernels(monkeypatch, backend, mesh_shape, kw, want):
+    mesh = None
+    if mesh_shape is not None:
+        mesh = jax.sharding.Mesh(
+            np.asarray(jax.devices()[:mesh_shape[0]]), ("tp",))
+    assert _resolved(monkeypatch, backend, mesh, **kw) == want
+
+
+def test_int4_mm_kernels_still_imports_and_returns_a_config(monkeypatch):
+    """The benchmark's child imports this name (benchmark/server_child.py)."""
+    from ollama_operator_tpu.ops.quant import int4_mm_kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    out = int4_mm_kernels(tiny(), None)
+    assert isinstance(out, cfglib.ModelConfig)
+    assert out.mm_kernels == "pallas"
+    assert int4_mm_kernels(out, None) is out
+
+
+@pytest.mark.parametrize("mesh_devices", [0, 2])
+def test_engine_resolves_mm_kernels_auto(mesh_devices):
+    """The engine's constructor is where "auto" is decided: on this CPU
+    backend, and on a mesh of two devices, the programs stay on XLA and no
+    program reports a fallback."""
+    cfg = tiny(max_seq_len=64)
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jnp.float32)
+    qparams = Q.quantize_params(
+        jax.tree_util.tree_map(np.asarray, params))
+    mesh = None
+    if mesh_devices:
+        mesh = make_mesh(MeshPlan(tp=mesh_devices))
+    qparams = jax.tree_util.tree_map(jnp.asarray, qparams)
+    eng = Engine(cfg, qparams, mesh=mesh,
+                 ecfg=EngineConfig(max_slots=2, max_seq_len=64,
+                                   cache_dtype=jnp.float32,
+                                   min_prefill_bucket=32))
+    assert eng.cfg.mm_kernels == "xla"
+    eng.admit(0, np.arange(3, 23, dtype=np.int32))     # 32 rows: N > 16
+    picks = {p for ps in eng.program_kernels.values() for p in ps}
+    assert "matmul=xla_int8" in picks
+    assert not any("pallas" in p for p in picks if p.startswith("matmul"))
+
+
+def test_engine_explicit_interpret_serves_fused_kernel_above_16_rows():
+    """An engine whose matmuls are routed to the kernel: the admit program
+    (32 rows) takes the fused kernel, the decode program (2 rows) the
+    grouped XLA form, neither as a fallback."""
+    from ollama_operator_tpu.runtime.trace import FLIGHT
+    cfg = tiny(max_seq_len=64, mm_kernels="interpret", dim=128, ffn_dim=256)
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jnp.float32)
+    qparams = jax.tree_util.tree_map(jnp.asarray, Q.quantize_params(
+        jax.tree_util.tree_map(np.asarray, params)))
+    before = len([e for e in FLIGHT.snapshot()
+                  if e["kind"] == "kernel_fallback"])
+    eng = Engine(cfg, qparams, mesh=None,
+                 ecfg=EngineConfig(max_slots=2, max_seq_len=64,
+                                   cache_dtype=jnp.float32,
+                                   min_prefill_bucket=32))
+    eng.admit(0, np.arange(3, 23, dtype=np.int32))
+    eng.decode_n(2)
+    by_kind = eng.kernels_by_kind()
+    admit = [k for k in by_kind if k.startswith("admit")]
+    assert admit and all("matmul=qmm_pallas" in by_kind[k] for k in admit)
+    assert "matmul=xla_int8" in by_kind["decode"]
+    assert "matmul=qmm_pallas" not in by_kind["decode"]
+    after = len([e for e in FLIGHT.snapshot()
+                 if e["kind"] == "kernel_fallback"])
+    assert after == before
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_fused_matmuls_read_their_layer_of_the_stack_in_place(bits):
+    """Under a kernel mode the decoder's layer scan leaves the quantized
+    stacks unsliced and hands each step its index (decoder._scan_layers);
+    prefill (18 rows) and a cached step must match the XLA path, which
+    slices."""
+    import dataclasses
+    cfg = tiny(dim=128, ffn_dim=256)
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    qparams = jax.tree_util.tree_map(
+        jnp.asarray, Q.quantize_params(jax.tree_util.tree_map(
+            np.asarray, params), bits=bits))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 9), 0,
+                                cfg.vocab_size)
+    cfg_k = dataclasses.replace(cfg, mm_kernels="interpret")
+    ref, ks, vs = decoder.prefill_chunk(qparams, cfg, tokens)
+    with record_kernels() as picked:
+        got, _, _ = decoder.prefill_chunk(qparams, cfg_k, tokens)
+    kernel = "qmm_pallas" if bits == 8 else "qmm4_pallas"
+    assert ("matmul", kernel, False) in picked
+    assert not any(fb for _s, _k, fb in picked)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    S = 16
+    pad = ((0, 0), (0, 0), (0, 0), (0, S - ks.shape[3]), (0, 0))
+    kc, vc = jnp.pad(ks, pad), jnp.pad(vs, pad)
+    step = tokens[:, :1]
+    lengths = jnp.full((2,), 9, jnp.int32)
+    want = decoder.forward_with_cache(qparams, cfg, step, kc, vc, lengths)[0]
+    have = decoder.forward_with_cache(qparams, cfg_k, step, kc, vc,
+                                      lengths)[0]
+    np.testing.assert_allclose(np.asarray(have), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
