@@ -79,7 +79,7 @@ def find_cell(name: str) -> types.SimpleNamespace:
     return types.SimpleNamespace(
         name=name, chips=int(entry["chips"]),
         conf_path=os.path.join(REPO, conf_entry["file"]),
-        conf=load_json(os.path.join(REPO, conf_entry["file"])),
+        conf=work.load_conf(os.path.join(REPO, conf_entry["file"])),
         mix_name=entry["traffic"],
         mix=traffic_gen.load_mix(entry["traffic"]),
         numbers=(load_json(numbers_path)
@@ -199,14 +199,29 @@ async def repeat_check(gen: loadgen.LoadGen, chunk: int) -> bool:
     return same
 
 
+def numbers_compared(probe: dict, repeat_ok: bool, resolution_ok: bool,
+                     failed: int, short_share: float) -> dict:
+    """Every number ``correct`` compared, beside its limit, under a short
+    plain name: the probe's readings as the child said them, then what the
+    window showed."""
+    out = dict(probe)
+    out["resolution_differs"] = {"value": int(not resolution_ok), "limit": 0}
+    out["repeat_texts_differ"] = {"value": int(not repeat_ok), "limit": 0}
+    out["failed_requests"] = {"value": failed, "limit": 0}
+    out["short_frames_share"] = {"value": short_share,
+                                 "limit": stats.MAX_SHORT_SHARE}
+    return out
+
+
 async def run_cell(args, cell, trace_dir: str) -> int:
     child = await Child.start(cell, args.seed, trace_dir, args.rehearse)
     try:
         ready = await child.until("ready", READY_TIMEOUT_S)
         gen = loadgen.LoadGen(ready["port"], ready["model"], args.seed,
                               ready["prompt_overhead_tokens"])
+        repeat_ok = await repeat_check(gen, ready["decode_chunk"])
         correct = (bool(ready["probe_ok"]) and bool(ready["resolution_ok"])
-                   and await repeat_check(gen, ready["decode_chunk"]))
+                   and repeat_ok)
 
         mix, seconds = cell.mix, float(args.seconds)
         rate = cell.numbers.get("rate_rps")
@@ -307,6 +322,12 @@ async def run_cell(args, cell, trace_dir: str) -> int:
     if args.trace:
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+    result["compared"] = numbers_compared(
+        child.seen["probe_done"]["compared"], repeat_ok,
+        bool(ready["resolution_ok"]), len(failed), frames["short_share"])
+    for name, pair in result["compared"].items():
+        sys.stderr.write(f"compared {name}: {pair['value']} "
+                         f"(limit {pair['limit']})\n")
     if args.rehearse:
         say(rehearsal=True, note="CPU, toy widths: not a result",
             would_print=result)

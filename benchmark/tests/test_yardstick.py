@@ -147,6 +147,120 @@ def test_phi_2_work_by_hand():
     assert work.kv_bytes_per_token(c, "int8") == 2 * 32 * 32 * 84 == 172_032
 
 
+def test_a_configuration_with_no_work_file_gives_the_dense_formulas():
+    """The parent's arithmetic, written out: nothing moves for a file that
+    names no ``work``."""
+    for name in ("starcoder2-3b", "phi-2"):
+        c = conf(name)
+        assert "work" not in c and work.own(c, "kv_bytes_per_token") is None
+        L, H, hd = (c["num_hidden_layers"], c["num_attention_heads"],
+                    c["head_dim"])
+        total = work.matmul_params(c)
+        assert work.matmul_flops_per_token(c) == 2.0 * total
+        assert work.attn_flops_per_pair(c) == 4 * L * H * hd
+        assert work.weight_bytes_step(c, 37.5, "int8") == work.weight_bytes(
+            c, "int8")
+        kvb = 2 * L * c["num_key_value_heads"] * (hd * 1.0 + 4.0)
+        step = work.decode_step(c, 50.2, 8_800.5, "int8", "int8")
+        assert step["flops"] == 2.0 * total * 50.2 + 4 * L * H * hd * 8_800.5
+        assert step["bytes"] == (work.weight_bytes(c, "int8")
+                                 + (8_800.5 + 50.2) * kvb
+                                 + 50.2 * c["vocab_size"] * 4.0)
+        pre = work.prefill(c, 777, "int8", "int8")
+        assert pre["flops"] == (2.0 * total * 777
+                                + 4 * L * H * hd * 777 * 778 / 2.0)
+        assert pre["bytes"] == (work.weight_bytes(c, "int8") + 777 * kvb
+                                + c["vocab_size"] * 4.0)
+
+
+def test_routed_work_by_hand():
+    """One chip's share of a routed layer: 128 experts of which 32 are held,
+    4 a token, one shared expert, grouped-query attention (32 heads over 8
+    key/value heads of 128). The fixture's work file, fed these keys."""
+    c = work.load_conf(os.path.join(HERE, "fixtures", "routed.json"))
+    c.update(hidden_size=4096, num_hidden_layers=6, num_attention_heads=32,
+             num_key_value_heads=8, head_dim=128, moe_intermediate_size=2048,
+             n_routed_experts=128, experts_held=32, num_experts_per_tok=4,
+             shared_intermediate_size=2048, vocab_size=32768)
+    attn = 2 * 4096 * 32 * 128 + 2 * 4096 * 8 * 128     # q, o; k, v
+    assert attn == 41_943_040
+    expert = 3 * 4096 * 2048
+    shared = expert + 4096                      # and its hidden x 1 gate
+    router = 4096 * 128
+    assert expert == 25_165_824
+    layer = attn + shared + router + 32 * expert
+    assert layer == 872_943_616 == work.layer_matmul_params(c)
+    head = 4096 * 32768
+    assert work.matmul_params(c) == 6 * layer + head
+
+    # expected distinct held experts: 32 x (1 - (1 - 4/128)^batch)
+    own = work.load_module(os.path.join(HERE, "fixtures", "routed.work.py"))
+    assert own.distinct_experts(c, 1) == pytest.approx(1.0)
+    assert own.distinct_experts(c, 64) == pytest.approx(
+        32 * (1 - (31 / 32) ** 64)) == pytest.approx(27.8058, abs=1e-3)
+    assert own.distinct_experts(c, 4096) == pytest.approx(32.0)
+    for batch in (1, 64, 4096):
+        touched = own.distinct_experts(c, batch)
+        assert work.weight_bytes_step(c, batch, "bfloat16") == pytest.approx(
+            2.0 * (6 * (attn + shared + router + touched * expert) + head))
+    # at batch 1 a step reads one held expert a layer, not 32
+    assert work.weight_bytes_step(c, 1, "bfloat16") == pytest.approx(
+        2.0 * (6 * (attn + shared + router + expert) + head))
+
+    # a token meets 4 x 32/128 = 1 held expert a layer
+    assert work.matmul_flops_per_token(c) == 2.0 * (
+        6 * (attn + shared + router + 1.0 * expert) + head)
+    # the cache and a query against it are grouped-query attention's, which
+    # the work file leaves to work.py: 2 x 8 heads x (128 codes + a scale)
+    assert own_names(own) == {"layer_matmul_params", "weight_bytes_step",
+                              "matmul_flops_per_token"}
+    assert work.kv_bytes_per_token(c, "int8") == 6 * 2 * 8 * 132 == 12_672
+    assert work.kv_bytes_per_token(c, "bfloat16") == 6 * 2 * 8 * 256
+    assert work.attn_flops_per_pair(c) == 4 * 6 * 32 * 128
+    step = work.decode_step(c, 64, 100_000, "bfloat16", "int8")
+    assert step["flops"] == (work.matmul_flops_per_token(c) * 64
+                             + 4 * 6 * 32 * 128 * 100_000)
+    assert step["bytes"] == (work.weight_bytes_step(c, 64, "bfloat16")
+                             + (100_000 + 64) * 12_672 + 64 * 32768 * 4.0)
+
+
+def own_names(module):
+    """Which of ``work.py``'s five askable functions a work file defines."""
+    return {n for n in ("layer_matmul_params", "weight_bytes_step",
+                        "matmul_flops_per_token", "kv_bytes_per_token",
+                        "attn_flops_per_pair") if hasattr(module, n)}
+
+
+def test_the_fixtures_own_sizes_by_hand():
+    c = work.load_conf(os.path.join(HERE, "fixtures", "routed.json"))
+    attn = 512 * 8 * 64 * 2 + 2 * 512 * 2 * 64
+    layer = attn + (3 * 512 * 128 + 512) + 512 * 64 + 64 * 3 * 512 * 128
+    assert work.layer_matmul_params(c) == layer
+    assert work.kv_bytes_per_token(c, "int8") == 2 * 4 * 2 * (64 + 4)
+    assert work.attn_flops_per_pair(c) == 4 * 4 * 8 * 64
+
+
+def test_a_work_file_is_asked_for_each_function_it_defines(tmp_path):
+    """The cache and the attention too, for a layer that is not grouped-query
+    (the fixture defines neither): whatever a work file defines is what
+    ``decode_step`` and ``prefill`` count, the rest stays ``work.py``'s."""
+    (tmp_path / "odd.work.py").write_text(
+        "def kv_bytes_per_token(conf, kv):\n"
+        "    return conf['num_hidden_layers'] * {'int8': 324}[kv]\n"
+        "def attn_flops_per_pair(conf):\n"
+        "    return conf['num_hidden_layers'] * 1000\n")
+    c = dict(conf("starcoder2-3b"), work="odd.work.py", _dir=str(tmp_path))
+    dense = conf("starcoder2-3b")
+    assert work.kv_bytes_per_token(c, "int8") == 30 * 324
+    assert work.attn_flops_per_pair(c) == 30_000
+    assert work.matmul_flops_per_token(c) == work.matmul_flops_per_token(dense)
+    step = work.decode_step(c, 10, 5_000, "int8", "int8")
+    assert step["flops"] == (work.matmul_flops_per_token(dense) * 10
+                             + 30_000 * 5_000)
+    assert step["bytes"] == (work.weight_bytes(dense, "int8")
+                             + 5_010 * 30 * 324 + 10 * 49152 * 4.0)
+
+
 def test_least_seconds_names_its_bound_and_unknown_devices_are_an_error():
     peaks = work.load_peaks(os.path.join(BENCH, "peaks.json"), "TPU v5 lite")
     assert peaks["hbm_bytes_per_s"] == 819e9
