@@ -106,7 +106,32 @@ class ModelConfig:
     n_shared_ffn: int = 0              # qwen2moe: a SHARED gated expert
                                        # of this ffn width runs for every
                                        # token, scaled by a sigmoid gate
+    shared_gate: bool = True           # the shared expert's per-token
+                                       # sigmoid gate (qwen2moe sh_gate);
+                                       # False = added whole (granite)
+    # the chip's share of an expert-parallel layer: the router scores all
+    # n_experts and keeps n_experts_used; this chip holds the
+    # n_experts_held experts from expert_first on and adds only what they
+    # give. Gates of kept experts held elsewhere are neither renormalised
+    # nor replaced. 0 = holds them all.
+    n_experts_held: int = 0
+    expert_first: int = 0
     moe_impl: str = "auto"             # auto|einsum|scan (models/decoder.py)
+    # hybrid stacks (granitemoehybrid): one letter a layer, "m" a Mamba-2
+    # mixer, "A" attention; "" = every layer attends. A string, so the
+    # config stays hashable and arrives whole from JSON.
+    layer_kinds: str = ""
+    # Mamba-2 mixer sizes (one group of B/C): d_inner = ssm_heads *
+    # ssm_head_dim; the state a slot carries is [ssm_heads, ssm_head_dim,
+    # ssm_state] float32 a layer plus ssm_conv - 1 columns of the
+    # convolution's d_inner + 2 * ssm_state inputs
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256               # prefill block (mamba_chunk_size)
+    rope: bool = True                  # False = no positional embedding
+                                       # (position_embedding_type "nope")
     kernels: str = "auto"              # attention impl: auto|pallas|xla|interpret
     mm_kernels: str = "auto"           # quantized-matmul impl:
                                        # auto|pallas|xla|interpret. "auto"
@@ -128,6 +153,35 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return self.layer_kinds.count("m")
+
+    @property
+    def n_attn_layers(self) -> int:
+        return (self.layer_kinds.count("A") if self.layer_kinds
+                else self.n_layers)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_state
+
+    @property
+    def ssm_state_bytes(self) -> int:
+        """Recurrent state one sequence carries, all layers, float32."""
+        return 4 * self.n_ssm_layers * (
+            self.ssm_inner * self.ssm_state
+            + (self.ssm_conv - 1) * self.ssm_conv_dim)
+
+    @property
     def rotary_dim(self) -> int:
         rd = int(self.head_dim * self.rotary_pct)
         return rd - rd % 2
@@ -139,8 +193,14 @@ class ModelConfig:
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         mlp = 3 * d * f if self.mlp_type == "gated" else 2 * d * f
         if self.n_experts:
-            mlp = self.n_experts * mlp + d * self.n_experts
+            mlp = (self.experts_held * mlp + d * self.n_experts
+                   + 3 * d * self.n_shared_ffn)
         emb = v * d * (1 if self.tie_embeddings else 2)
+        if self.layer_kinds:
+            ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_state
+                        + self.ssm_heads) + self.ssm_inner * d)
+            return (self.n_attn_layers * attn + self.n_ssm_layers * ssm
+                    + l * mlp + emb)
         return l * (attn + mlp) + emb
 
     def validate(self) -> "ModelConfig":
@@ -168,6 +228,19 @@ class ModelConfig:
         if self.n_experts:
             assert self.mlp_type == "gated", "MoE is gated-MLP only"
             assert 0 < self.n_experts_used <= self.n_experts
+            assert (0 <= self.expert_first
+                    and self.expert_first + self.experts_held
+                    <= self.n_experts), "held experts lie past the router"
+        if self.layer_kinds:
+            assert len(self.layer_kinds) == self.n_layers, (
+                f"layer_kinds names {len(self.layer_kinds)} layers, "
+                f"n_layers is {self.n_layers}")
+            assert set(self.layer_kinds) <= {"m", "A"}, self.layer_kinds
+            assert "A" in self.layer_kinds, "no attention layer to cache"
+            assert self.ssm_heads > 0 and self.ssm_conv >= 2
+            assert not (self.parallel_block or self.post_norms
+                        or self.altern_sliding or self.sliding_window), (
+                "hybrid stacks run the plain pre-norm block")
         if self.rope_local_theta:
             assert self.altern_sliding, (
                 "rope_local_theta pairs with per-layer (altern_sliding) "
@@ -320,6 +393,36 @@ PRESETS = {
                          n_layers=56, n_heads=48, n_kv_heads=8, head_dim=128,
                          ffn_dim=16384, n_experts=8, n_experts_used=2,
                          rope_theta=1000000.0, max_seq_len=65536),
+    # granite-4.0-h-small (granitemoehybrid), ONE CHIP'S SHARE of a stated
+    # deployment: each layer's 72 routed experts divided over 2 chips
+    # (this is chip 0: experts 0-35, vocabulary rows 0-50,175 of 100,352)
+    # and the first period of the 40 layers, m m m m m A m m m m (the
+    # other three would lie on further chips as pipeline stages). Every
+    # width is the published one: hidden 4096, Mamba-2 128 heads of 64
+    # with state 128 and convolution 4, GQA 32/8 at head_dim 128 without
+    # rotary embedding, router 72 / 10 a token, expert width 768, shared
+    # expert 1536. benchmark/configs/granite-4.0-h-small.json states the
+    # cut beside the published numbers.
+    "granite-4.0-h-small": _mk(
+        arch="granitehybrid", vocab_size=50176, dim=4096, n_layers=10,
+        n_heads=32, n_kv_heads=8, head_dim=128, ffn_dim=768,
+        n_experts=72, n_experts_used=10, n_experts_held=36, expert_first=0,
+        n_shared_ffn=1536, shared_gate=False, layer_kinds="mmmmmAmmmm",
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_conv=4,
+        ssm_chunk=256, rope=False, emb_multiplier=12.0,
+        residual_multiplier=0.22, logit_scale=16.0,
+        attn_scale_mult=0.0078125, tie_embeddings=True, norm_eps=1e-5,
+        max_seq_len=131072),
+    # the same shape at toy widths (tests, --rehearse)
+    "tiny-hybrid": _mk(
+        arch="granitehybrid", vocab_size=256, dim=64, n_layers=10,
+        n_heads=4, n_kv_heads=2, head_dim=16, ffn_dim=32, n_experts=8,
+        n_experts_used=3, n_experts_held=4, expert_first=0,
+        n_shared_ffn=48, shared_gate=False, layer_kinds="mmmmmAmmmm",
+        ssm_heads=8, ssm_head_dim=16, ssm_state=16, ssm_conv=4,
+        ssm_chunk=16, rope=False, emb_multiplier=12.0,
+        residual_multiplier=0.22, logit_scale=16.0,
+        attn_scale_mult=0.0625, tie_embeddings=True, max_seq_len=256),
     "dolphin-mixtral": _mk(arch="llama", vocab_size=32002, dim=4096,
                            n_layers=32, n_heads=32, n_kv_heads=8,
                            head_dim=128, ffn_dim=14336, n_experts=8,

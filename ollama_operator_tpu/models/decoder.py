@@ -32,6 +32,14 @@ Params pytree layout (all leaves jnp arrays; layer leaves stacked on axis 0):
     MoE archs (cfg.n_experts > 0, mixtral family) replace w_gate/w_up/w_down:
     router [L, D, E]
     we_gate [L, E, D, F]  we_up [L, E, D, F]  we_down [L, E, F, D]
+    (E = the experts this chip holds, cfg.experts_held; router keeps all)
+    Hybrid stacks (cfg.layer_kinds, "m" Mamba-2 / "A" attention a layer):
+    the leaves above that every layer has keep their leading L; the two
+    mixers' leaves are stacked over their OWN layers only, still flat in
+    this dict: wq/wk/wv/wo [La, ...] and
+    ssm_in [Lm, D, 2*di + 2*N + H]  ssm_conv_w [Lm, K, C]  ssm_conv_b [Lm, C]
+    ssm_dt_bias / ssm_a_log / ssm_d [Lm, H]  ssm_norm_w [Lm, di]
+    ssm_out [Lm, di, D]     (di = H * P, C = di + 2 * N)
 """
 
 from __future__ import annotations
@@ -114,16 +122,32 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     def w(k, shape, scale=0.02):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
+    La = cfg.n_attn_layers          # L unless the stack is a hybrid one
     layers: Dict[str, jax.Array] = {
         "attn_norm_w": jnp.ones((L, D), dtype),
-        "wq": w(next(keys), (L, D, cfg.q_dim)),
-        "wk": w(next(keys), (L, D, cfg.kv_dim)),
-        "wv": w(next(keys), (L, D, cfg.kv_dim)),
-        "wo": w(next(keys), (L, cfg.q_dim, D)),
+        "wq": w(next(keys), (La, D, cfg.q_dim)),
+        "wk": w(next(keys), (La, D, cfg.kv_dim)),
+        "wv": w(next(keys), (La, D, cfg.kv_dim)),
+        "wo": w(next(keys), (La, cfg.q_dim, D)),
     }
+    if cfg.layer_kinds:
+        Lm, H, di = cfg.n_ssm_layers, cfg.ssm_heads, cfg.ssm_inner
+        C = cfg.ssm_conv_dim
+        layers["ssm_in"] = w(next(keys), (Lm, D, di + C + H))
+        layers["ssm_conv_w"] = w(next(keys), (Lm, cfg.ssm_conv, C), 0.2)
+        layers["ssm_conv_b"] = w(next(keys), (Lm, C))
+        layers["ssm_dt_bias"] = w(next(keys), (Lm, H))
+        # A = -exp(a_log): decays from 1 to 16 a unit of dt, as Mamba-2
+        # draws them
+        layers["ssm_a_log"] = jnp.broadcast_to(
+            jnp.log(1.0 + jnp.arange(H, dtype=jnp.float32) % 16),
+            (Lm, H)).astype(dtype)
+        layers["ssm_d"] = jnp.ones((Lm, H), dtype)
+        layers["ssm_norm_w"] = jnp.ones((Lm, di), dtype)
+        layers["ssm_out"] = w(next(keys), (Lm, di, D))
     if cfg.n_experts:
-        E = cfg.n_experts
-        layers["router"] = w(next(keys), (L, D, E))
+        E = cfg.experts_held
+        layers["router"] = w(next(keys), (L, D, cfg.n_experts))
         layers["we_gate"] = w(next(keys), (L, E, D, F))
         layers["we_up"] = w(next(keys), (L, E, D, F))
         layers["we_down"] = w(next(keys), (L, E, F, D))
@@ -132,7 +156,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             layers["we_sh_gate"] = w(next(keys), (L, D, Fs))
             layers["we_sh_up"] = w(next(keys), (L, D, Fs))
             layers["we_sh_down"] = w(next(keys), (L, Fs, D))
-            layers["sh_gate"] = w(next(keys), (L, D, 1))
+            if cfg.shared_gate:
+                layers["sh_gate"] = w(next(keys), (L, D, 1))
     else:
         layers["w_up"] = w(next(keys), (L, D, F))
         layers["w_down"] = w(next(keys), (L, F, D))
@@ -299,6 +324,12 @@ def _moe_experts(cfg: ModelConfig, lp, xf, gates):
     """The experts' matmuls and the gated combine of ``_moe_mlp``:
     [N, D] tokens and [N, E] gates to [N, D] fp32."""
     N, D = xf.shape
+    if cfg.experts_held != cfg.n_experts:
+        # this chip's share of an expert-parallel layer: the gates of the
+        # experts it holds, as the router over all of them gave them; what
+        # a token's other kept experts would add is another chip's to add
+        gates = lax.slice_in_dim(gates, cfg.expert_first,
+                                 cfg.expert_first + cfg.experts_held, axis=1)
     impl = cfg.moe_impl
     if impl == "auto":
         impl = "einsum" if N <= 256 else "scan"
@@ -317,12 +348,15 @@ def _moe_experts(cfg: ModelConfig, lp, xf, gates):
                                      lp["we_down"], gates.T))
     if "we_sh_gate" in lp:
         # qwen2moe shared expert: a gated MLP every token runs, its
-        # output scaled by a per-token sigmoid gate (shared_expert_gate)
+        # output scaled by a per-token sigmoid gate (shared_expert_gate);
+        # granite's is added whole (no sh_gate leaf)
         hs = _act(cfg, xf @ lp["we_sh_gate"]) * (xf @ lp["we_sh_up"])
         sh = (hs @ lp["we_sh_down"]).astype(jnp.float32)
-        sg = jax.nn.sigmoid(
-            (xf @ lp["sh_gate"]).astype(jnp.float32))      # [N, 1]
-        y = y + sg * sh
+        if "sh_gate" in lp:
+            sg = jax.nn.sigmoid(
+                (xf @ lp["sh_gate"]).astype(jnp.float32))  # [N, 1]
+            sh = sg * sh
+        y = y + sh
     return y
 
 
@@ -400,8 +434,9 @@ def _qkv(cfg: ModelConfig, lp, h, cos, sin):
                      cfg.norm_weight_offset)
         k = rms_norm(k, lp["k_norm_w"], cfg.norm_eps,
                      cfg.norm_weight_offset)
-    q = apply_rope(q, cos, sin, cfg.rotary_dim)
-    k = apply_rope(k, cos, sin, cfg.rotary_dim)
+    if cfg.rope:
+        q = apply_rope(q, cos, sin, cfg.rotary_dim)
+        k = apply_rope(k, cos, sin, cfg.rotary_dim)
     return q, k, v
 
 
@@ -546,7 +581,16 @@ def prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
             between text embeddings); replaces the tok_emb lookup.
     Returns (logits [B, T, V] fp32, k [L, B, KvH, T, hd], v [...]) — K/V
     head-first, matching the cache layout.
+
+    A hybrid stack (cfg.layer_kinds) returns trees in their place:
+    ({"kv": k [La, ...], "ssm": [Lm, B, H, P, N]}, {"kv": v, "conv":
+    [Lm, B, K-1, C]}), the recurrent state as position ``n_valid - 1``
+    left it (``n_valid`` scalar or [B]; None = every position is real),
+    and, told ``n_valid``, logits [B, 1, V] of that position alone.
     """
+    if cfg.layer_kinds:
+        return _hybrid_prefill(params, cfg, tokens, n_valid, inputs_embeds,
+                               mesh)
     B, T = tokens.shape
     scale = _attn_scale(cfg)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -588,7 +632,8 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
                        k_cache: jax.Array, v_cache: jax.Array,
                        lengths: jax.Array,
                        attn_len: Optional[int] = None,
-                       mesh=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                       mesh=None, n_valid: Optional[jax.Array] = None
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Extend sequences that already have ``lengths`` cached tokens.
 
     tokens   [B, T] — T=1 is the decode step; T>1 is chunked prefill
@@ -600,10 +645,17 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
              engine buckets this to the live prefix instead of streaming
              all S slots every step. Requires max(lengths) + T <= attn_len
              (new K/V land below it); None = S.
+    n_valid  [B] int32, hybrid stacks only — how many of a row's T new
+             positions are real: the recurrent state (which rides in the
+             two cache trees, ``join_state``) advances over those alone.
+             A decode step passes the active mask; an extend its tail's
+             length (and gets logits [B, 1, V] of the tail's last real
+             position alone); None = all T.
     Returns (logits [B, T, V], k_cache, v_cache).
     """
     from ..ops.quant_cache import is_quantized_cache
     B, T = tokens.shape
+    k_cache, v_cache, ssm, conv = split_state(k_cache, v_cache)
     kc_arr = k_cache["q"] if is_quantized_cache(k_cache) else k_cache
     L, _, _, S, _ = kc_arr.shape
     A = S if attn_len is None else min(attn_len, S)
@@ -637,12 +689,9 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return lax.dynamic_slice(c, (i,) + (0,) * (len(sizes) - 1),
                                  (1,) + sizes[1:])[0]
 
-    def body(carry, layer_in):
-        x, kc, vc = carry
-        lp, i = layer_in
-        mask_l = _layer_mask(cfg, i, mask, m_full)
-        cos_i, sin_i = _layer_rope(cfg, i, cos, sin, cos_l, sin_l)
-        h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
+    def attend(lp, h, kc, vc, i, mask_l, cos_i, sin_i):
+        """One layer's attention mixer against layer ``i`` of the cache:
+        project, write the new keys and values, attend, project out."""
         q, k, v = _qkv(cfg, lp, h, cos_i, sin_i)
         k = k.transpose(0, 2, 1, 3)                   # [B, KvH, T, hd]
         v = v.transpose(0, 2, 1, 3)
@@ -676,7 +725,24 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
                     attn = cached_attention(cfg, q, kwin, vwin, mask_l,
                                             positions, scale, attn_len=A,
                                             mesh=mesh)
-        attn = _proj_out(cfg, lp, attn, B, T)
+        return _proj_out(cfg, lp, attn, B, T), kc, vc
+
+    if cfg.layer_kinds:
+        x, k_cache, v_cache, ssm, conv = _hybrid_layers(
+            params, cfg, x, k_cache, v_cache, ssm, conv, _valid_rows(
+                n_valid, B, T),
+            lambda ap, h, kc, vc, row: attend(ap, h, kc, vc, row, mask,
+                                              None, None))
+        logits = _unembed(cfg, params, _last_real(x, n_valid))
+        return (logits, *join_state(k_cache, v_cache, ssm, conv))
+
+    def body(carry, layer_in):
+        x, kc, vc = carry
+        lp, i = layer_in
+        mask_l = _layer_mask(cfg, i, mask, m_full)
+        cos_i, sin_i = _layer_rope(cfg, i, cos, sin, cos_l, sin_l)
+        h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
+        attn, kc, vc = attend(lp, h, kc, vc, i, mask_l, cos_i, sin_i)
         x = _residual(cfg, lp, x, h, attn)
         return (x, kc, vc), None
 
@@ -684,6 +750,275 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         cfg, body, (x, k_cache, v_cache), params["layers"])
     logits = _unembed(cfg, params, x)
     return logits, k_cache, v_cache
+
+
+# --------------------------------------------------------------------------
+# hybrid stacks: Mamba-2 mixers beside attention in one scan
+# --------------------------------------------------------------------------
+#
+# granitemoehybrid: every layer is  h = x + rm * mixer(norm(x));
+# h + rm * moe(norm(h)), and the mixer is a Mamba-2 block ("m") or
+# attention without rotary embedding ("A") by cfg.layer_kinds. The layers
+# run as ONE lax.scan whose body traces the shared half (norms, router,
+# experts, residuals) once and picks the mixer with lax.cond; each mixer's
+# weights are stacked over their own layers only and a layer finds its row
+# through the static map of ``_hybrid_rows``.
+#
+# What a sequence carries besides keys and values is, per Mamba layer, the
+# state S [H, P, N] float32 and the last K-1 inputs of the causal
+# convolution [K-1, C] float32. It cannot be cut back to a prefix, only
+# advanced, so every entry point says how many of a row's positions are
+# real (``n_valid``): a padded prefill position, a slot that sits inactive
+# in a decode batch, leaves both exactly as they were. It travels inside
+# the two cache trees (``join_state``), so the engine's programs hand it
+# on as they hand on the cache.
+
+_ATTN_STACK = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wqkv",
+               "bqkv", "q_norm_w", "k_norm_w")
+
+
+def split_state(k_cache, v_cache):
+    """(k_cache, v_cache, ssm, conv): the recurrent state taken out of the
+    two cache trees; (k_cache, v_cache, None, None) for trees without."""
+    if not (isinstance(k_cache, dict) and "ssm" in k_cache):
+        return k_cache, v_cache, None, None
+    kc = {k: v for k, v in k_cache.items() if k != "ssm"}
+    vc = {k: v for k, v in v_cache.items() if k != "conv"}
+    if "kv" in kc:
+        kc, vc = kc["kv"], vc["kv"]
+    return kc, vc, k_cache["ssm"], v_cache["conv"]
+
+
+def join_state(k_cache, v_cache, ssm, conv):
+    """Inverse of ``split_state``: {"ssm"} beside the keys' leaves,
+    {"conv"} beside the values' (a plain array cache goes under "kv")."""
+    if ssm is None:
+        return k_cache, v_cache
+    if not isinstance(k_cache, dict):
+        k_cache, v_cache = {"kv": k_cache}, {"kv": v_cache}
+    return {**k_cache, "ssm": ssm}, {**v_cache, "conv": conv}
+
+
+def empty_state(cfg: ModelConfig, B: int):
+    """(ssm [Lm, B, H, P, N], conv [Lm, B, K-1, C]) float32 zeros: what a
+    sequence carries before its first position."""
+    Lm = cfg.n_ssm_layers
+    return (jnp.zeros((Lm, B, cfg.ssm_heads, cfg.ssm_head_dim,
+                       cfg.ssm_state), jnp.float32),
+            jnp.zeros((Lm, B, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
+                      jnp.float32))
+
+
+def _hybrid_rows(cfg: ModelConfig):
+    """(is_attn [L] bool, row [L] int32): each layer's kind and its row in
+    its own mixer's stack."""
+    rows, n = [], {"A": 0, "m": 0}
+    for c in cfg.layer_kinds:
+        rows.append(n[c])
+        n[c] += 1
+    return (jnp.asarray([c == "A" for c in cfg.layer_kinds]),
+            jnp.asarray(rows, jnp.int32))
+
+
+def _valid_rows(n_valid, B: int, T: int):
+    """n_valid (None, scalar or [B]) -> [B] int32."""
+    if n_valid is None:
+        return jnp.full((B,), T, jnp.int32)
+    return jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), (B,))
+
+
+def _last_real(x, n_valid):
+    """x [B, T, D] -> [B, 1, D] at each row's last real position, where the
+    caller said how many are real (``n_valid``): an admission samples from
+    that position alone, and the head over a whole padded bucket is the
+    largest array of a prefill ([4, 4096, 50176] float32 is 3 GB beside
+    9.5 GB of weights). Without ``n_valid`` every position is kept."""
+    if n_valid is None or x.shape[1] == 1:
+        return x
+    at = jnp.maximum(_valid_rows(n_valid, *x.shape[:2]) - 1, 0)
+    return jnp.take_along_axis(x, at[:, None, None], axis=1)
+
+
+def _ssm_scan(cfg: ModelConfig, S0, x, dt, a, Bm, Cm):
+    """The selective state update over T positions from state S0, exact:
+    S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t.
+
+    S0 [B, H, P, N]; x [B, T, H, P]; dt [B, T, H] (0 where a position is
+    not real: then S_t = S_{t-1} exactly); a [H] < 0; Bm, Cm [B, T, N].
+    All float32. One position is the recurrence as written (a pass over
+    the state on the vector unit). More go block by block (cfg.ssm_chunk,
+    Mamba-2's SSD form): inside a block every pair of positions through
+    the decays' running sums, between blocks the state. Returns
+    (y [B, T, H, P], S_T)."""
+    B, T, H, P = x.shape
+    hi = lax.Precision.HIGHEST
+    if T == 1:
+        dA = jnp.exp(dt[:, 0] * a)                              # [B, H]
+        dBx = (dt[:, 0, :, None] * x[:, 0])[..., None] \
+            * Bm[:, 0, None, None, :]
+        S1 = S0 * dA[:, :, None, None] + dBx
+        y = (S1 * Cm[:, 0, None, None, :]).sum(-1)              # [B, H, P]
+        return y[:, None], S1
+    Q = min(cfg.ssm_chunk, T)
+    pad = -T % Q
+    if pad:
+        # dt = 0 there: the state passes through, the outputs are cut off
+        x, dt, Bm, Cm = (jnp.pad(v, [(0, 0), (0, pad)]
+                                 + [(0, 0)] * (v.ndim - 2))
+                         for v in (x, dt, Bm, Cm))
+    nC = (T + pad) // Q
+
+    def blocks(v):                       # [B, nC*Q, ...] -> [nC, B, Q, ...]
+        return jnp.moveaxis(v.reshape(B, nC, Q, *v.shape[2:]), 1, 0)
+
+    tri = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def block(S, xs):
+        xb, dtb, Bb, Cb = xs
+        cum = jnp.cumsum(dtb * a, axis=1)                       # [B, Q, H]
+        # decay from position s to position t >= s of the block
+        seg = cum[:, :, None, :] - cum[:, None, :, :]           # [B, t, s, H]
+        seg = jnp.exp(jnp.where(tri[None, :, :, None], seg, -jnp.inf))
+        cb = jnp.einsum("btn,bsn->bts", Cb, Bb, precision=hi)
+        w = cb[..., None] * seg * dtb[:, None, :, :]            # [B, t, s, H]
+        y = jnp.einsum("btsh,bshp->bthp", w, xb, precision=hi)
+        y = y + jnp.einsum("btn,bhpn->bthp", Cb, S, precision=hi) \
+            * jnp.exp(cum)[..., None]
+        rest = jnp.exp(cum[:, -1:, :] - cum) * dtb              # [B, Q, H]
+        S = S * jnp.exp(cum[:, -1])[:, :, None, None] + jnp.einsum(
+            "bsh,bshp,bsn->bhpn", rest, xb, Bb, precision=hi)
+        return S, y
+
+    S, ys = lax.scan(block, S0, tuple(blocks(v) for v in (x, dt, Bm, Cm)))
+    y = jnp.moveaxis(ys, 0, 1).reshape(B, nC * Q, H, P)
+    return y[:, :T], S
+
+
+def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
+    """Mamba-2 mixer of one layer. u [B, T, D] (normed); ssm [Lm, B, H, P,
+    N] and conv [Lm, B, K-1, C] float32, of which this layer reads and
+    writes row ``row``; n_valid [B]: positions >= n_valid[b] change
+    neither. Returns (out [B, T, D], ssm, conv)."""
+    B, T, _ = u.shape
+    H, P, N, K = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
+    di, C = cfg.ssm_inner, cfg.ssm_conv_dim
+    f32 = jnp.float32
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]           # [B, T]
+    with device_scope("ssm.in_proj"):
+        zxd = _mm(cfg, u, sp["ssm_in"])
+        z, xbc, dt = zxd[..., :di], zxd[..., di:di + C], zxd[..., di + C:]
+    with device_scope("ssm.conv"):
+        prev = lax.dynamic_index_in_dim(conv, row, 0, keepdims=False)
+        cat = jnp.concatenate([prev, xbc.astype(f32)], axis=1)  # [B,T+K-1,C]
+        w = sp["ssm_conv_w"].astype(f32)
+        xc = sp["ssm_conv_b"].astype(f32)
+        for j in range(K):
+            xc = xc + w[j] * cat[:, j:j + T]
+        xc = jax.nn.silu(xc)
+        # the K-1 inputs before position n_valid: what the next call's
+        # first positions look back on
+        prev = jax.vmap(lambda c, n: lax.dynamic_slice_in_dim(c, n, K - 1, 0)
+                        )(cat, n_valid)
+        conv = lax.dynamic_update_index_in_dim(conv, prev, row, 0)
+    with device_scope("ssm.scan"):
+        x = xc[..., :di].reshape(B, T, H, P)
+        Bm, Cm = xc[..., di:di + N], xc[..., di + N:]
+        dt = jax.nn.softplus(dt.astype(f32) + sp["ssm_dt_bias"].astype(f32))
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        a = -jnp.exp(sp["ssm_a_log"].astype(f32))
+        S0 = lax.dynamic_index_in_dim(ssm, row, 0, keepdims=False)
+        y, S1 = _ssm_scan(cfg, S0, x, dt, a, Bm, Cm)
+        # dt = 0 already leaves S where it was up to rounding of 1 * S + 0;
+        # a row with nothing real keeps its very bits
+        S1 = jnp.where((n_valid > 0)[:, None, None, None], S1, S0)
+        ssm = lax.dynamic_update_index_in_dim(ssm, S1, row, 0)
+        y = y + sp["ssm_d"].astype(f32)[:, None] * x
+    with device_scope("ssm.gate_norm"):
+        y = y.reshape(B, T, di) * jax.nn.silu(z.astype(f32))
+        y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * sp["ssm_norm_w"].astype(f32)
+    with device_scope("ssm.out"):
+        out = _mm(cfg, y.astype(u.dtype), sp["ssm_out"])
+    return out, ssm, conv
+
+
+def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, ssm, conv,
+                   n_valid, attend):
+    """The layer scan of a hybrid stack. ``attend(ap, h, kc, vc, row) ->
+    (out, kc, vc)`` is the caller's attention mixer against row ``row`` of
+    its keys and values (a fresh chunk's or the cache's). Everything a
+    layer may write rides the carry, and each mixer hands the other's
+    through untouched."""
+    layers = params["layers"]
+    attn_stack = {k: v for k, v in layers.items() if k in _ATTN_STACK}
+    ssm_stack = {k: v for k, v in layers.items() if k.startswith("ssm_")}
+    shared = {k: v for k, v in layers.items()
+              if k not in attn_stack and k not in ssm_stack}
+
+    def take(stack, row):
+        return jax.tree_util.tree_map(
+            lambda w: lax.dynamic_index_in_dim(w, row, 0, keepdims=False),
+            stack)
+
+    def body(carry, layer_in):
+        x, kc, vc, ssm, conv = carry
+        lp, is_attn, row = layer_in
+        h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
+
+        def attn_mixer(h, kc, vc, ssm, conv):
+            out, kc, vc = attend(take(attn_stack, row), h, kc, vc, row)
+            return out, kc, vc, ssm, conv
+
+        def ssm_mixer(h, kc, vc, ssm, conv):
+            out, ssm, conv = _ssm_mixer(cfg, take(ssm_stack, row), h, ssm,
+                                        conv, row, n_valid)
+            return out, kc, vc, ssm, conv
+
+        # the Mamba mixer is the TRUE branch on purpose: with the branches
+        # the other way round the TPU compiler hands the whole state through
+        # the attention layer's branch by a copy (1.24 GB at 32 slots,
+        # 2.9 ms of a 24.8 ms decode step: my chip run, PR 29), this way
+        # round both branches update or pass their buffers in place
+        out, kc, vc, ssm, conv = lax.cond(~is_attn, ssm_mixer, attn_mixer,
+                                          h, kc, vc, ssm, conv)
+        x = _residual(cfg, lp, x, h, out)
+        return (x, kc, vc, ssm, conv), None
+
+    (x, kc, vc, ssm, conv), _ = lax.scan(
+        body, (x, kc, vc, ssm, conv), (shared, *_hybrid_rows(cfg)))
+    return x, kc, vc, ssm, conv
+
+
+def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
+                    inputs_embeds, mesh):
+    """``prefill_chunk`` of a hybrid stack: a fresh chunk from the empty
+    state. Keys and values of the attention layers come back [La, B, KvH,
+    T, hd]; the state as each row's position n_valid - 1 left it."""
+    B, T = tokens.shape
+    scale = _attn_scale(cfg)
+    mask = jnp.broadcast_to(causal_mask(T, T, 0), (B, 1, T, T))
+    if inputs_embeds is not None:
+        x = inputs_embeds.astype(params["tok_emb"].dtype)
+    else:
+        x = _embed(cfg, params, tokens)
+
+    def attend(ap, h, kc, vc, row):
+        q, k, v = _qkv(cfg, ap, h, None, None)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        with device_scope("attn.core"):
+            attn = chunk_attention(cfg, q, k, v, mask, scale, mesh=mesh)
+        kc = lax.dynamic_update_index_in_dim(kc, k, row, 0)
+        vc = lax.dynamic_update_index_in_dim(vc, v, row, 0)
+        return _proj_out(cfg, ap, attn, B, T), kc, vc
+
+    kv0 = jnp.zeros((cfg.n_attn_layers, B, cfg.n_kv_heads, T, cfg.head_dim),
+                    x.dtype)
+    x, ks, vs, ssm, conv = _hybrid_layers(
+        params, cfg, x, kv0, kv0, *empty_state(cfg, B),
+        _valid_rows(n_valid, B, T), attend)
+    logits = _unembed(cfg, params, _last_real(x, n_valid))
+    return logits, {"kv": ks, "ssm": ssm}, {"kv": vs, "conv": conv}
 
 
 # --------------------------------------------------------------------------

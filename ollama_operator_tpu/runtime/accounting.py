@@ -124,7 +124,7 @@ def detect_peak_flops() -> Tuple[float, str]:
 
 def _layer_split(cfg: ModelConfig) -> Tuple[int, int]:
     """(full_attention_layers, sliding_window_layers)."""
-    L = cfg.n_layers
+    L = cfg.n_attn_layers       # a hybrid stack's Mamba layers attend nothing
     if cfg.sliding_window <= 0:
         return L, 0
     if cfg.altern_sliding:
@@ -161,10 +161,22 @@ def per_token_flops(cfg: ModelConfig) -> float:
     mlp_mult = 6 if cfg.mlp_type == "gated" else 4
     mlp = mlp_mult * d * f
     if cfg.n_experts:
-        mlp = cfg.n_experts_used * mlp + 2 * d * cfg.n_experts
+        # of the experts a token keeps, the share this chip holds
+        # (expected: the router spreads its picks over all of them)
+        kept_here = cfg.n_experts_used * cfg.experts_held / cfg.n_experts
+        mlp = kept_here * mlp + 2 * d * cfg.n_experts
         if cfg.n_shared_ffn:
             mlp += 6 * d * cfg.n_shared_ffn
     head = 2 * d * v
+    if cfg.layer_kinds:
+        # a Mamba-2 mixer: in- and out-projection, the convolution's K
+        # taps, and the state update with its read-out (S = a S + x (x) B,
+        # y = S C: about 6 operations an element of [H, P, N])
+        di, n = cfg.ssm_inner, cfg.ssm_state
+        ssm = (2 * d * (di + cfg.ssm_conv_dim + cfg.ssm_heads) + 2 * di * d
+               + 2 * cfg.ssm_conv * cfg.ssm_conv_dim + 6 * di * n)
+        return float(cfg.n_attn_layers * proj + cfg.n_ssm_layers * ssm
+                     + L * mlp + head)
     return float(L * (proj + mlp) + head)
 
 

@@ -144,7 +144,9 @@ def resolve_serving_defaults(ecfg: "EngineConfig", cfg: ModelConfig,
     # metric); MHA keeps 32 (its paged step is ~3x GQA's, 64 would double
     # streaming latency on an unmeasured combination)
     slots = ecfg.max_slots or ((64 if on_tpu and gqa else 32)
-                               if paged else 8)
+                               if paged else
+                               _recurrent_slots(cfg) if on_tpu
+                               and cfg.layer_kinds else 8)
     n_pages = ecfg.n_pages
     if paged and n_pages is None and ecfg.max_slots == 0:
         serve_seq = min(ecfg.max_seq_len, cfg.max_seq_len)
@@ -161,6 +163,24 @@ def resolve_serving_defaults(ecfg: "EngineConfig", cfg: ModelConfig,
     return dataclasses.replace(ecfg, paged=paged, max_slots=slots,
                                n_pages=n_pages, decode_chunk=chunk,
                                page_size=ps, min_prefill_bucket=minb)
+
+
+def _recurrent_slots(cfg: ModelConfig) -> int:
+    """Slots of a hybrid stack's contiguous cache on the TPU, from the
+    model alone. Its decode step is bound by the weights of the experts it
+    holds, so the batch is what fills them: the smallest power of two that
+    gives every expert four tokens a step (8 slots x 10 picks over
+    granite's 72 is 1.1 a step: a latency test, not a serving batch), while
+    the slots' recurrent state (what grows with the batch here: 38.7 MB a
+    slot against 8 MB of int8 keys and values at 4096 positions) stays
+    under an eighth of a v5e chip's 16 GB."""
+    want = 4 * cfg.n_experts / cfg.n_experts_used if cfg.n_experts else 8
+    slots = 8
+    while slots < min(want, 64):
+        slots *= 2
+    while slots > 8 and slots * cfg.ssm_state_bytes > (2 << 30):
+        slots //= 2
+    return slots
 
 
 def resolve_paged_default(cfg: ModelConfig, mesh) -> bool:
@@ -186,7 +206,7 @@ def resolve_paged_default(cfg: ModelConfig, mesh) -> bool:
     if (cfg.n_kv_heads >= cfg.n_heads
             and os.environ.get("TPU_PAGED_V3", "1") != "1"):
         return False
-    if cfg.n_experts:
+    if cfg.n_experts or cfg.layer_kinds:
         return False
     if mesh is None:
         return True
@@ -406,8 +426,24 @@ class Engine:
         self.mesh = mesh
         B, S = ecfg.max_slots, min(ecfg.max_seq_len, cfg.max_seq_len)
         self.n_slots, self.max_seq = B, S
-        L, KvH, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        # layers that keep keys and values: all, but for a hybrid stack
+        L, KvH, hd = cfg.n_attn_layers, cfg.n_kv_heads, cfg.head_dim
         V = cfg.vocab_size
+        # a hybrid stack's slots carry a recurrent state beside their keys
+        # and values (models/decoder.py, hybrid section); it rides inside
+        # the two cache trees, so every program hands it on with them
+        self.recurrent = bool(cfg.layer_kinds)
+        if self.recurrent:
+            if ecfg.paged:
+                raise ValueError(
+                    "a model with recurrent layers serves from the "
+                    "contiguous cache: its state lives with the slot and "
+                    "a page pool has no place for it; set paged=False")
+            if mesh is not None and mesh.size > 1:
+                raise ValueError(
+                    "a model with recurrent layers serves on one device: "
+                    f"no sharding of its state is defined; got a mesh of "
+                    f"{dict(mesh.shape)}")
 
         cache_dtype = resolve_cache_dtype(ecfg.cache_dtype)
         if cache_dtype is not ecfg.cache_dtype:
@@ -622,6 +658,9 @@ class Engine:
             cache_shape = (L, B, KvH, S, hd)  # head-first: (S, hd) tiles
             self.k_cache = zeros(cache_shape, ecfg.cache_dtype, cache_sh)
             self.v_cache = zeros(cache_shape, ecfg.cache_dtype, cache_sh)
+        if self.recurrent:
+            self.k_cache, self.v_cache = decoder.join_state(
+                self.k_cache, self.v_cache, *decoder.empty_state(cfg, B))
         self.lengths = zeros((B,), jnp.int32, slot_sh)
         self.counts = zeros((B, V), jnp.int32, slot_sh2)
         # penalty ring: the last repeat_last_n token ids per slot (sentinel
@@ -805,6 +844,20 @@ class Engine:
 
         W = max(1, self.ecfg.repeat_last_n)
 
+        def real(n):
+            """How many positions of a row are real, for the forward pass
+            of a stack whose recurrent state must not see the padding."""
+            return {"n_valid": n} if self.recurrent else {}
+
+        def last_row(logits, n):
+            """The [V] logits an admission samples from: row n - 1 of the
+            chunk's [1, T, V]; a forward pass told ``real(n)`` hands back
+            that row alone."""
+            if self.recurrent:
+                return logits[0, 0]
+            return jax.lax.dynamic_index_in_dim(
+                logits[0], n - 1, axis=0, keepdims=False)
+
         def _sample_install(lengths, counts, last_tokens, pring, mu, logits,
                             ring_row, counts_row, slot, total, sp_row, key,
                             mask_row, cflag, rln):
@@ -858,8 +911,17 @@ class Engine:
             positions carry id == vocab_size, which the scatter-add drops —
             image tokens never enter the counts), sample, and install
             chunk K/V + slot state."""
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], n_valid - 1, axis=0, keepdims=False)
+            k_cache, v_cache, ssm, conv = decoder.split_state(k_cache,
+                                                              v_cache)
+            if ssm is not None:
+                # the state the prompt ends in goes over whatever the
+                # slot's last tenant left: release zeroes nothing
+                ssm = jax.lax.dynamic_update_slice(
+                    ssm, ks["ssm"], (0, slot, 0, 0, 0))
+                conv = jax.lax.dynamic_update_slice(
+                    conv, vs["conv"], (0, slot, 0, 0))
+                ks, vs = ks["kv"], vs["kv"]
+            last = last_row(logits, n_valid)
             # ring of the last rln prompt tokens: absolute positions
             # n_valid-rln .. n_valid-1 land in slots pos % rln (each slot
             # exactly once — no scatter duplicates); ring capacity is the
@@ -909,6 +971,8 @@ class Engine:
                     v_cache = jax.lax.dynamic_update_slice(
                         v_cache, vs.astype(v_cache.dtype),
                         (0, slot, 0, 0, 0))
+            k_cache, v_cache = decoder.join_state(k_cache, v_cache, ssm,
+                                                  conv)
             return (tok, *pin(k_cache, v_cache, lengths, counts,
                               last_tokens, pring, mu))
 
@@ -918,7 +982,8 @@ class Engine:
             """Prefill a padded B=1 chunk AND insert it into the slot state
             — one device program, one host round-trip per admission.
             ``table_row`` [NBLK] — the slot's block table (paged mode)."""
-            logits, ks, vs = prefill_impl(params, tokens=tokens)
+            logits, ks, vs = prefill_impl(params, tokens=tokens,
+                                          **real(n_valid))
             return _insert_prefilled(k_cache, v_cache, lengths, counts,
                                      last_tokens, pring, mu, logits, ks, vs,
                                      tokens, slot, n_valid, sp_row, key,
@@ -934,14 +999,20 @@ class Engine:
                             last_tokens, pring, mu, tokens, slots,
                             n_valids, sp_rows, keys_m, mask_row, rlns,
                             table_rows=None):
-                logits, ks, vs = prefill_impl(params, tokens=tokens)
+                logits, ks, vs = prefill_impl(params, tokens=tokens,
+                                              **real(n_valids))
                 toks = []
                 for i in range(m):
+                    # row i of what the prefill made (K/V are trees where
+                    # the stack carries a recurrent state beside them)
+                    logits_i = logits[i:i + 1]
+                    ks_i, vs_i = jax.tree_util.tree_map(
+                        lambda a: a[:, i:i + 1], (ks, vs))
                     (tok, k_cache, v_cache, lengths, counts, last_tokens,
                      pring, mu) = _insert_prefilled(
                         k_cache, v_cache, lengths, counts, last_tokens,
-                        pring, mu, logits[i:i + 1], ks[:, i:i + 1],
-                        vs[:, i:i + 1], tokens[i:i + 1], slots[i],
+                        pring, mu, logits_i, ks_i, vs_i,
+                        tokens[i:i + 1], slots[i],
                         n_valids[i],
                         jax.tree_util.tree_map(lambda a: a[i:i + 1],
                                                sp_rows),
@@ -962,7 +1033,8 @@ class Engine:
             id == vocab_size at image positions (dropped by the scatter).
             The embedding lookup never sees ``tokens``."""
             logits, ks, vs = prefill_impl(params, tokens=tokens,
-                                          inputs_embeds=embeds)
+                                          inputs_embeds=embeds,
+                                          **real(n_valid))
             return _insert_prefilled(k_cache, v_cache, lengths, counts,
                                      last_tokens, pring, mu, logits, ks, vs,
                                      tokens, slot, n_valid, sp_row, key,
@@ -985,9 +1057,12 @@ class Engine:
                 kw = {"attn_len": attn_len} if (attn_len is not None
                                                 and self._bucketed_attn) \
                     else {}
+                # a slot parked between prefill pieces, or freed, sits in
+                # every step's batch: its keys may be written over, its
+                # recurrent state may not
                 logits, k_cache, v_cache = step_impl(
                     params, tokens=last_tokens[:, None], k_cache=k_cache,
-                    v_cache=v_cache, lengths=lengths, **kw)
+                    v_cache=v_cache, lengths=lengths, **kw, **real(active))
             with device_scope("sample"):
                 step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
                 last = logits[:, 0]
@@ -1270,6 +1345,8 @@ class Engine:
                         mask_row, cflag, rln):
                 dsl = jax.lax.dynamic_slice
                 dus = jax.lax.dynamic_update_slice
+                k_cache, v_cache, ssm, conv = decoder.split_state(
+                    k_cache, v_cache)
                 if self.quant_cache:
                     Lq, _, KvH, _S, hd = k_cache["q"].shape
                     def slice5(c):
@@ -1289,13 +1366,28 @@ class Engine:
                     def write5(c, cs):
                         return dus(c, cs, (0, slot, 0, 0, 0))
                 kc_s, vc_s = slice5(k_cache), slice5(v_cache)
+                if ssm is not None:
+                    # the slot's state, read where the last piece left it
+                    # and advanced over the tail's real positions only
+                    kc_s, vc_s = decoder.join_state(
+                        kc_s, vc_s,
+                        dsl(ssm, (0, slot, 0, 0, 0),
+                            (ssm.shape[0], 1) + ssm.shape[2:]),
+                        dsl(conv, (0, slot, 0, 0),
+                            (conv.shape[0], 1) + conv.shape[2:]))
                 logits, kc_s, vc_s = fwd(
                     params, cfg, tokens, kc_s, vc_s, start[None],
-                    mesh=self.mesh)
+                    mesh=self.mesh, **real(n_new[None]))
+                if ssm is not None:
+                    kc_s, vc_s, ssm_s, conv_s = decoder.split_state(kc_s,
+                                                                    vc_s)
+                    ssm = dus(ssm, ssm_s, (0, slot, 0, 0, 0))
+                    conv = dus(conv, conv_s, (0, slot, 0, 0))
                 k_cache = write5(k_cache, kc_s)
                 v_cache = write5(v_cache, vc_s)
-                last = jax.lax.dynamic_index_in_dim(
-                    logits[0], n_new - 1, axis=0, keepdims=False)
+                k_cache, v_cache = decoder.join_state(k_cache, v_cache,
+                                                      ssm, conv)
+                last = last_row(logits, n_new)
                 (tok, lengths, counts, last_tokens, pring,
                  mu) = _sample_install(
                     lengths, counts, last_tokens, pring, mu, last,
@@ -1877,6 +1969,12 @@ class Engine:
         n_total = int(full_ids.shape[0])
         n_new = n_total - start
         assert 0 < n_new, f"nothing to prefill (start={start})"
+        if self.recurrent and start != self._host_lengths[slot]:
+            raise ValueError(
+                f"slot {slot} holds the state of "
+                f"{int(self._host_lengths[slot])} positions and a "
+                f"recurrent state cannot be cut back to {start}: prefill "
+                "from the start instead")
         if n_total >= self.max_seq:
             raise ValueError(f"prompt too long: {n_total} >= {self.max_seq}")
         bucket = self.bucket_for(n_new)
@@ -2256,7 +2354,8 @@ class Engine:
     def _spec_warm_k(self) -> int:
         """The draft length whose verify programs this engine warms: 0
         when speculation is off or this mesh cannot run it."""
-        if self.sp_size > 1 or (self.paged and self._paged_dp > 1):
+        if (self.sp_size > 1 or (self.paged and self._paged_dp > 1)
+                or self.recurrent):
             return 0
         return int(os.environ.get("TPU_SPEC_DECODE", "0") or "0")
 
@@ -3136,6 +3235,9 @@ class Engine:
         stream like ``retire`` does."""
         assert self.sp_size == 1, \
             "speculative decode: bucketed caches only (no sp meshes)"
+        assert not self.recurrent, (
+            "speculative decode rolls rejected drafts back by length; a "
+            "recurrent state has no such rollback")
         assert not (self.paged and self._paged_dp > 1), \
             "speculative decode: the paged dp-manual region is T=1 only"
         k = int(drafts.shape[1])  # lint: allow(host-sync-hot-path): shape read of a host array
@@ -3223,7 +3325,19 @@ class Engine:
     def slot_length(self, slot: int) -> int:
         return int(self._fetch(self.lengths)[slot])
 
+    def state_position(self, slot: int) -> int:
+        """Positions the slot's recurrent state has run over: the host's
+        mirror of its length, every launched step counted (no sync)."""
+        return int(self._host_lengths[slot])
+
     @property
     def kv_bytes(self) -> int:
         leaves = jax.tree_util.tree_leaves((self.k_cache, self.v_cache))
         return sum(l.size * l.dtype.itemsize for l in leaves)
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the slots' recurrent state (0 for a stack that has
+        none); part of ``kv_bytes``, whose trees it rides in."""
+        _, _, ssm, conv = decoder.split_state(self.k_cache, self.v_cache)
+        return 0 if ssm is None else ssm.nbytes + conv.nbytes

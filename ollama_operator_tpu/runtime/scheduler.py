@@ -291,6 +291,12 @@ class Scheduler:
         # enabled only when the acceptance rate holds (docs give
         # guidance).
         self.spec_k = int(os.environ.get("TPU_SPEC_DECODE", "0") or "0")
+        if self.spec_k and getattr(engine, "recurrent", False):
+            # a rejected draft rolls back by length alone; a recurrent
+            # state that has run over it cannot: refused, not served wrong
+            FLIGHT.record("spec_refused", cause="recurrent_state",
+                          k=self.spec_k)
+            self.spec_k = 0
         # drafted/accepted running totals back the /api/ps acceptance-
         # rate block (counters also exported via metrics)
         self.spec_drafted = 0
@@ -865,11 +871,19 @@ class Scheduler:
             return None, 0
         ids = req.admit_ids
         best, best_m = None, 0
+        # a recurrent state stands where its sequence ended and cannot be
+        # cut back: such a slot is reused only whole, and only if the
+        # engine stopped where the parked sequence does (a stream that
+        # ended mid-chunk left its slot some steps further on)
+        whole = getattr(self.engine, "recurrent", False)
         for slot, parked in self._parked.items():
             k = min(len(parked), len(ids) - 1)
             m = 0
             while m < k and parked[m] == ids[m]:
                 m += 1
+            if whole and not (m == len(parked)
+                              == self.engine.state_position(slot)):
+                continue
             if m > best_m:
                 best, best_m = slot, m
         if best is None or best_m < self.min_prefix_reuse:

@@ -226,6 +226,12 @@ class LoadedModel:
         self.control_plane = control_plane
         self.follower = follower
         self._unloaded = False   # set under dispatch_lock on multi-host
+        # an engine's compiled closures refer back to it, so an engine
+        # nobody holds any more (a model just unloaded) keeps its caches on
+        # the device until the cycle collector runs: run it before asking
+        # for this one's
+        import gc
+        gc.collect()
         self.engine = Engine(cfg, params, mesh=mesh, ecfg=self.ecfg)
         if control_plane is not None:
             # multi-host leader: every device-dispatching engine call is
@@ -290,6 +296,11 @@ class LoadedModel:
             METRICS.gauge_fn("tpu_model_radix_pages",
                              lambda: (lm := wself()) is not None
                              and lm.engine.radix_pages or 0)
+        if getattr(self.engine, "recurrent", False):
+            # what the slots of a hybrid stack hold beside keys and values
+            METRICS.gauge_fn("tpu_model_recurrent_state_bytes",
+                             lambda: (lm := wself()) is not None
+                             and lm.engine.state_bytes or 0)
         if getattr(self.engine, "host_cache_enabled", False):
             # tier-1 host-arena occupancy: bytes and whole KV pages the
             # spilled radix subtrees hold in pinned host RAM (the spill /
@@ -898,6 +909,8 @@ class LoadedModel:
         if getattr(self.engine, "radix_enabled", False):
             METRICS.remove_gauge("tpu_model_radix_nodes")
             METRICS.remove_gauge("tpu_model_radix_pages")
+        if getattr(self.engine, "recurrent", False):
+            METRICS.remove_gauge("tpu_model_recurrent_state_bytes")
         if getattr(self.engine, "host_cache_enabled", False):
             METRICS.remove_gauge("tpu_model_host_cache_bytes")
             METRICS.remove_gauge("tpu_model_host_cache_pages")

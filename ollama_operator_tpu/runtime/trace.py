@@ -309,8 +309,9 @@ _SPAN_EVENT = {"http.flush": "http_flush"}
 # Compile-time only; the profiler shows them in each device operation's
 # `tf_op` stat (benchmark/trace_spans.py reads that).
 DEVICE_SCOPES = ("embed", "attn.qkv", "attn.kv_write", "attn.core",
-                 "attn.out", "mlp", "moe.route", "moe.experts", "lm_head",
-                 "sample")
+                 "attn.out", "mlp", "moe.route", "moe.experts", "ssm.in_proj",
+                 "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out",
+                 "lm_head", "sample")
 
 # Request stages folded into tpu_model_request_stage_seconds{stage=...}
 STAGES = ("ingress", "queue", "prefill", "first_flush", "decode")

@@ -660,7 +660,19 @@ class ModelManager:
                                              is not None else None),
                             "paged": (bool(lm.engine.paged)
                                       if getattr(lm, "engine", None)
-                                      is not None else False)},
+                                      is not None else False),
+                            # routed experts: all the router scores, and
+                            # those this chip holds (0/0 = a dense MLP)
+                            # (an embedding model's config has neither)
+                            "experts": {
+                                "all": getattr(lm.cfg, "n_experts", 0),
+                                "held": getattr(lm.cfg, "experts_held", 0)},
+                            # hybrid stacks: bytes of the slots' recurrent
+                            # state (0 = keys and values only)
+                            "recurrent_state_bytes": (
+                                int(getattr(lm.engine, "state_bytes", 0))
+                                if getattr(lm, "engine", None)
+                                is not None else 0)},
                 "expires_at": expires,
                 "size_vram": 0,
                 # crash-only serving status: supervised restarts on THIS

@@ -247,6 +247,10 @@ GLOBAL.describe("tpu_model_host_cache_bytes",
 GLOBAL.describe("tpu_model_host_cache_pages",
                 "Spilled KV pages resident in the tier-1 host arena "
                 "(live gauge)")
+GLOBAL.describe("tpu_model_recurrent_state_bytes",
+                "Device bytes of the slots' recurrent state, for a model "
+                "with state-space layers (live gauge; absent for a model "
+                "that keeps keys and values only)")
 GLOBAL.describe("tpu_model_async_fallback_total",
                 "Decode dispatches that fell back to synchronous while "
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "
