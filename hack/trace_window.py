@@ -1,0 +1,65 @@
+"""One ``--trace 1`` window of a benchmark cell, with the trace's device time
+by module and scope kept: ``benchmark/run.py`` removes its trace directory
+when it ends, and no per-layer metric splits the admit or extend modules by
+part (PERF.md section 7, "prefill by part").
+
+    python hack/trace_window.py <tree> <tag> <run.py arguments ...>
+
+runs ``<tree>/benchmark/run.py`` with the arguments (give ``--trace 1``), and
+before the trace is removed writes what ``python -m benchmark.trace_spans``
+prints for it (per module: seconds by scope and the costliest operations; the
+idle by host span) to ``chiprun_out/<tag>.trace.json``; the run's result line
+goes to ``chiprun_out/<tag>.result.json``. The exit code is the run's. The
+builder's tool for a chip call, not part of the benchmark."""
+import contextlib
+import io
+import json
+import os
+import runpy
+import shutil
+import sys
+
+tree, tag, argv = os.path.abspath(sys.argv[1]), sys.argv[2], sys.argv[3:]
+out_dir = os.path.join(os.getcwd(), "chiprun_out")
+os.makedirs(out_dir, exist_ok=True)
+os.chdir(tree)
+sys.path.insert(0, tree)
+rmtree = shutil.rmtree
+
+
+def keep_then_remove(path, *a, **kw):
+    if os.path.basename(path).startswith("bench-trace-"):
+        from benchmark import trace_spans
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            trace_spans.main(["trace_spans", path])
+        with open(os.path.join(out_dir, tag + ".trace.json"), "w") as f:
+            f.write(buf.getvalue())
+    return rmtree(path, *a, **kw)
+
+
+shutil.rmtree = keep_then_remove
+sys.argv = [os.path.join(tree, "benchmark", "run.py")] + argv
+buf = io.StringIO()
+
+
+class Tee(io.TextIOBase):
+    def write(self, s):
+        buf.write(s)
+        return sys.__stdout__.write(s)
+
+    def flush(self):
+        sys.__stdout__.flush()
+
+
+code = 0
+with contextlib.redirect_stdout(Tee()):
+    try:
+        runpy.run_path(sys.argv[0], run_name="__main__")
+    except SystemExit as e:
+        code = e.code if isinstance(e.code, int) else int(bool(e.code))
+lines = [ln for ln in buf.getvalue().splitlines() if ln.startswith("{")]
+if lines:
+    with open(os.path.join(out_dir, tag + ".result.json"), "w") as f:
+        json.dump(json.loads(lines[-1]), f, indent=1)
+sys.exit(code)

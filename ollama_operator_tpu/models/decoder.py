@@ -44,6 +44,8 @@ Params pytree layout (all leaves jnp arrays; layer leaves stacked on axis 0):
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -1047,13 +1049,52 @@ def _pad_hd(x, hd_pool: int):
 
 
 def _paged_scatter(pool, i, vals, pg, off):
-    """Write ``vals`` [B, KvH, T(, hd)] into layer ``i`` of a page pool at
-    (page ``pg``, offset ``off``) per (row, position); pg/off [B, T]."""
-    KvH = vals.shape[1]
-    pgx = pg[:, None, :]                      # [B, 1, T]
-    hx = jnp.arange(KvH)[None, :, None]       # [1, KvH, 1]
-    offx = off[:, None, :]
-    return pool.at[i, pgx, hx, offx].set(vals)
+    """The writer's XLA form: ``vals`` [B, T, KvH(, hd)] into layer ``i``
+    of a page pool at (page ``pg``, offset ``off``) per (row, position);
+    pg/off [B, T]. One scatter index per (row, HEAD, position): XLA wants
+    a scatter's window minor-most, and a window over the heads (``pool.at[i,
+    pg, :, off]``) makes it re-lay the pool out around every write (PERF.md,
+    PR 30). The chip runs ``_paged_write``'s kernel instead."""
+    hx = jnp.arange(vals.shape[2])[None, None, :]
+    return pool.at[i, pg[:, :, None], hx, off[:, :, None]].set(vals)
+
+
+def _paged_write(cfg: ModelConfig, pools, i, rows, pg, off, mesh=None):
+    """Write one layer's new ``rows`` (per pool [B, T, KvH(, hd)], already
+    of the pool's type and width) into ``pools`` at (page, offset) per
+    (row, position). Where ``resolve_kernels`` says pallas or interpret the
+    kernel addresses the pool by (row, position) and moves every head's
+    row in one window (ops/pallas/kv_write.py); else the XLA scatter, one
+    index per (row, head, position). Both put the same bytes at the same
+    addresses. ``mesh`` is given outside a manual region only: on a tp
+    mesh the kernel runs manual over every axis, each device writing its
+    own heads, as ``_paged_attend`` runs the attention kernel."""
+    from ..ops.attention import resolve_kernels
+    mode = resolve_kernels(cfg.kernels)
+    if mode in ("pallas", "interpret"):
+        from ..ops.pallas.kv_write import paged_kv_write
+        write = functools.partial(paged_kv_write,
+                                  interpret=mode == "interpret")
+        out = None
+        if mesh is None or mesh.size == 1:
+            out = write(pools, i, pg, off, rows)
+        elif (mesh.shape.get("tp", 1) == mesh.size
+              and pools[0].shape[2] % mesh.size == 0):
+            from jax.sharding import PartitionSpec as P
+            heads = lambda xs: tuple(                  # noqa: E731
+                P(*(None, None, "tp") + (None,) * (x.ndim - 3)) for x in xs)
+            out = jax.shard_map(
+                write, mesh=mesh,
+                in_specs=(heads(pools), P(), P(None, None), P(None, None),
+                          heads(rows)),
+                out_specs=heads(pools), check_vma=False)(
+                tuple(pools), i, pg, off, tuple(rows))
+        if out is not None:
+            note_kernel("paged_write", "paged_kv_write")
+            return out
+    note_kernel("paged_write", "xla_scatter", fell_back=mode != "xla")
+    return tuple(_paged_scatter(p, i, r, pg, off)
+                 for p, r in zip(pools, rows))
 
 
 def _gather_pages(pool, i, tbl, ps: Optional[int] = None):
@@ -1071,32 +1112,57 @@ def _gather_pages(pool, i, tbl, ps: Optional[int] = None):
     return pages.transpose(0, 2, 1, 3).reshape(B, KvH, NA * psp)
 
 
+def _insert_pages(pool, vals, table_row, n_valid, ps: int, stride: int = 1):
+    """Write an admission's ``vals`` [L, KvH, R(, hd)] into the pool a PAGE
+    at a time: row r holds position ``r * stride`` (2 for nibble-packed
+    codes), and since an admission starts at offset 0 of its first page,
+    page j of ``table_row`` takes rows [j * ps/stride, (j+1) * ps/stride)
+    of every head: one scatter index per (layer, page). The window is the
+    WHOLE page of every head, the pool's minor dims as they lie (a window
+    over part of a page, or of a scale pool's padded lanes, has XLA re-lay
+    the pool out: tests/test_chip_compile.py); rows the chunk does not
+    reach, and positions at or past ``n_valid``, keep what the pool held
+    (the windows are gathered first). A page wholly past ``n_valid`` is
+    the trash page."""
+    L, KvH, R = vals.shape[:3]
+    rows = pool.shape[3]                      # stored rows a page, padded
+    pr = ps // stride                         # of which the page uses
+    npg = -(-R // pr)
+    t = jnp.arange(npg * pr, dtype=jnp.int32) * stride
+    live = (t < jnp.minimum(n_valid, R * stride)).reshape(npg, pr)
+    vals = jnp.pad(vals, [(0, 0), (0, 0), (0, npg * pr - R)]
+                   + [(0, 0)] * (vals.ndim - 3))
+    vals = jnp.moveaxis(vals.reshape(L, KvH, npg, pr, *vals.shape[3:]), 2, 1)
+    if rows > pr:                             # a scale pool's padded lanes
+        vals = jnp.pad(vals, [(0, 0)] * 3 + [(0, rows - pr)])
+        live = jnp.pad(live, [(0, 0), (0, rows - pr)])
+    j = jnp.arange(npg, dtype=jnp.int32)
+    nblk = table_row.shape[0]
+    page = jnp.where((j * ps < n_valid) & (j < nblk),
+                     table_row[jnp.minimum(j, nblk - 1)],
+                     jnp.int32(TRASH_PAGE))
+    window = pool.at[jnp.arange(L)[:, None], page[None, :]]
+    live = live.reshape((1, npg, 1, rows) + (1,) * (vals.ndim - 4))
+    return window.set(jnp.where(live, vals, window.get()))
+
+
 @device_scope("attn.kv_write")
 def paged_insert(cfg: ModelConfig, k_pool, v_pool, ks, vs, table_row,
                  n_valid):
     """Insert a fresh B=1 prefill chunk (ks/vs [L, 1, KvH, Tb, hd] from
-    ``prefill_chunk``) into pool pages listed by ``table_row`` [NBLK].
-    Positions >= n_valid scatter their garbage to the trash page, so
-    admissions allocate pages only for real tokens."""
+    ``prefill_chunk``) into pool pages listed by ``table_row`` [NBLK], one
+    window per page (``_insert_pages``): ceil(Tb / ps) updates a tensor
+    where the scatter it replaces had L x KvH x Tb indices. Positions >=
+    n_valid leave the pool as it was and pages wholly past it are the
+    trash page, so admissions allocate pages only for real tokens."""
     quant = isinstance(k_pool, dict)
     quant4 = quant and "q4" in k_pool
     arr = (k_pool["q4"] if quant4 else k_pool["q"]) if quant else k_pool
     L, P, KvH, ps, hd = arr.shape
     if quant4:
         ps *= 2                               # packed pool: 2 positions/byte
-    Tb = ks.shape[3]
-    t = jnp.arange(Tb, dtype=jnp.int32)
-    pg_row = jnp.where(t < n_valid, table_row[t // ps],
-                       jnp.int32(TRASH_PAGE))
-    off = t % ps
-    lx = jnp.arange(L)[:, None, None]
-    hx = jnp.arange(KvH)[None, :, None]
-    pgx = pg_row[None, None, :]
-    offx = off[None, None, :]
-
-    def put(pool, vals):                      # vals [L, KvH, Tb(, hd)]
-        return pool.at[lx, pgx, hx, offx].set(vals)
-
+    put = functools.partial(_insert_pages, table_row=table_row,
+                            n_valid=n_valid, ps=ps)
     if quant4:
         from ..ops import quant_cache as QC
         kq, ksc = QC.quantize_kv4(ks)     # codes [-7,7] over the TRUE hd
@@ -1106,19 +1172,11 @@ def paged_insert(cfg: ModelConfig, k_pool, v_pool, ks, vs, table_row,
         # A pair straddling n_valid writes its garbage high nibble one
         # position past the slot's length — beyond-length entries are
         # never attended and the next decode write overwrites the nibble.
-        pg4 = pg_row[0::2]                    # pair page = even member's
-        off4 = (off[0::2]) // 2               # packed byte row in the page
-        pgx4 = pg4[None, None, :]
-        offx4 = off4[None, None, :]
-
-        def put4(pool, vals):                 # vals [L, KvH, Tb//2, hd]
-            return pool.at[lx, pgx4, hx, offx4].set(vals)
-
-        k_pool = {"q4": put4(k_pool["q4"],
-                             QC.pack_kv4(_pad_hd(kq[:, 0], hd))),
+        k_pool = {"q4": put(k_pool["q4"],
+                            QC.pack_kv4(_pad_hd(kq[:, 0], hd)), stride=2),
                   "s": put(k_pool["s"], ksc[:, 0])}
-        v_pool = {"q4": put4(v_pool["q4"],
-                             QC.pack_kv4(_pad_hd(vq[:, 0], hd))),
+        v_pool = {"q4": put(v_pool["q4"],
+                            QC.pack_kv4(_pad_hd(vq[:, 0], hd)), stride=2),
                   "s": put(v_pool["s"], vsc[:, 0])}
     elif quant:
         from ..ops import quant_cache as QC
@@ -1260,10 +1318,11 @@ def _paged_attend(cfg: ModelConfig, q, kp, vp, i, tables, lengths, mask,
 
 def _paged_scatter4(pool, i, codes, pg, off):
     """int4 twin of ``_paged_scatter``: merge per-position codes [-7, 7]
-    ([B, KvH, T, hd]) into the nibble-packed pool at byte row off//2 —
+    ([B, T, KvH, hd]) into the nibble-packed pool at byte row off//2 —
     read-modify-write, one parity class at a time (even offsets share no
     byte with other even offsets, so each pass is conflict-free, and the
     odd pass reads the even pass's merged bytes through the dataflow)."""
+    codes = codes.transpose(0, 2, 1, 3)                # [B, KvH, T, hd]
     KvH = codes.shape[1]
     hx = jnp.arange(KvH)[None, :, None]
     nib = (codes + 8).astype(jnp.uint8) & 0xF          # code + INT4_BIAS
@@ -1285,11 +1344,14 @@ def _paged_scatter4(pool, i, codes, pg, off):
 
 
 @device_scope("attn.kv_write")
-def _scatter_kv_pools(kp, vp, i, k, v, pg_w, off_w):
-    """Quantize (int8/int4 pools) and scatter one layer's fresh K/V into
-    the pools at (page, offset) per (row, position) — shared by the
-    dp-manual region and the single-shard paged forward so the write
-    layout can never drift between them."""
+def _scatter_kv_pools(cfg: ModelConfig, kp, vp, i, k, v, pg_w, off_w,
+                      mesh=None):
+    """Quantize (int8/int4 pools) and write one layer's fresh K/V
+    ([B, T, KvH, hd], as ``_qkv`` leaves them) into the pools at (page,
+    offset) per (row, position) — shared by the dp-manual region and the
+    single-shard paged forward so the write layout can never drift
+    between them. Codes and scales of both pools go through ONE
+    ``_paged_write``; int4 codes keep their read-modify-write by parity."""
     quant = isinstance(kp, dict)
     quant4 = quant and "q4" in kp
     arr = (kp["q4"] if quant4 else kp["q"]) if quant else kp
@@ -1298,29 +1360,26 @@ def _scatter_kv_pools(kp, vp, i, k, v, pg_w, off_w):
         from ..ops import quant_cache as QC
         kq, ksc = QC.quantize_kv4(k)
         vq, vsc = QC.quantize_kv4(v)
+        ks_pool, vs_pool = _paged_write(cfg, (kp["s"], vp["s"]), i,
+                                        (ksc, vsc), pg_w, off_w, mesh)
         kp = {"q4": _paged_scatter4(kp["q4"], i, _pad_hd(kq, hd_pool),
-                                    pg_w, off_w),
-              "s": _paged_scatter(kp["s"], i, ksc, pg_w, off_w)}
+                                    pg_w, off_w), "s": ks_pool}
         vp = {"q4": _paged_scatter4(vp["q4"], i, _pad_hd(vq, hd_pool),
-                                    pg_w, off_w),
-              "s": _paged_scatter(vp["s"], i, vsc, pg_w, off_w)}
+                                    pg_w, off_w), "s": vs_pool}
         return kp, vp
     if quant:
         from ..ops import quant_cache as QC
         kq, ksc = QC.quantize_kv(k)       # quantize over the TRUE hd,
         vq, vsc = QC.quantize_kv(v)       # then pad codes with zeros
-        kp = {"q": _paged_scatter(kp["q"], i, _pad_hd(kq, hd_pool),
-                                  pg_w, off_w),
-              "s": _paged_scatter(kp["s"], i, ksc, pg_w, off_w)}
-        vp = {"q": _paged_scatter(vp["q"], i, _pad_hd(vq, hd_pool),
-                                  pg_w, off_w),
-              "s": _paged_scatter(vp["s"], i, vsc, pg_w, off_w)}
-    else:
-        kp = _paged_scatter(kp, i, _pad_hd(k.astype(arr.dtype), hd_pool),
-                            pg_w, off_w)
-        vp = _paged_scatter(vp, i, _pad_hd(v.astype(arr.dtype), hd_pool),
-                            pg_w, off_w)
-    return kp, vp
+        kq_pool, ks_pool, vq_pool, vs_pool = _paged_write(
+            cfg, (kp["q"], kp["s"], vp["q"], vp["s"]), i,
+            (_pad_hd(kq, hd_pool), ksc, _pad_hd(vq, hd_pool), vsc),
+            pg_w, off_w, mesh)
+        return {"q": kq_pool, "s": ks_pool}, {"q": vq_pool, "s": vs_pool}
+    return _paged_write(
+        cfg, (kp, vp), i,
+        (_pad_hd(k.astype(arr.dtype), hd_pool),
+         _pad_hd(v.astype(arr.dtype), hd_pool)), pg_w, off_w, mesh)
 
 
 def _paged_write_attend_local(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
@@ -1341,7 +1400,7 @@ def _paged_write_attend_local(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
     pg_w = jnp.where(blk_w < NBLK, tables[bi, jnp.minimum(blk_w, NBLK - 1)],
                      jnp.int32(TRASH_PAGE))
     off_w = positions % ps
-    kp, vp = _scatter_kv_pools(kp, vp, i, k, v, pg_w, off_w)
+    kp, vp = _scatter_kv_pools(cfg, kp, vp, i, k, v, pg_w, off_w)
     if use_kernel:
         from ..ops.pallas.paged import paged_decode_attention
         with device_scope("attn.core"):
@@ -1372,8 +1431,7 @@ def _paged_write_attend_dp(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
     pool_spec = P(None, "dp", h_ax, None, None)
     pool_specs = ({qkey: pool_spec, "s": P(None, "dp", h_ax, None)}
                   if quant else pool_spec)
-    qspec = P("dp", None, h_ax, None)
-    kvspec = P("dp", h_ax, None, None)
+    qspec = P("dp", None, h_ax, None)       # q, and k/v [B, T, KvH, hd]
 
     def inner(q, k, v, kp, vp, i, tables, lengths, positions, mask):
         return _paged_write_attend_local(
@@ -1382,7 +1440,7 @@ def _paged_write_attend_dp(cfg: ModelConfig, q, k, v, kp, vp, i, tables,
 
     return jax.shard_map(
         inner, mesh=mesh,
-        in_specs=(qspec, kvspec, kvspec, pool_specs, pool_specs, P(),
+        in_specs=(qspec, qspec, qspec, pool_specs, pool_specs, P(),
                   P("dp", None), P("dp"), P("dp", None),
                   P("dp", None, None, None)),
         out_specs=(pool_specs, pool_specs, qspec), check_vma=False)(
@@ -1443,9 +1501,13 @@ def paged_extend_dp(params: Params, cfg: ModelConfig, tokens: jax.Array,
     pool_specs = ({qkey: pool_spec, "s": P(None, "dp", None, None)}
                   if quant else pool_spec)
 
+    # tp stays with the partitioner in this region, and Mosaic refuses a
+    # kernel there: the tail's rows go through the writer's XLA form
+    cfg_in = dataclasses.replace(cfg, kernels="xla")
+
     def inner(tokens, kp, vp, trow, lengths, owner):
         logits, kp, vp = forward_with_cache_paged(
-            params, cfg, tokens, kp, vp, trow, lengths, attn_blocks,
+            params, cfg_in, tokens, kp, vp, trow, lengths, attn_blocks,
             mesh=None)
         my = lax.axis_index("dp")
         logits = lax.psum(jnp.where(my == owner, logits, 0.0), "dp")
@@ -1520,9 +1582,7 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
         lp, i = layer_in
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
         cos_i, sin_i = _layer_rope(cfg, i, cos, sin, cos_l, sin_l)
-        q, k, v = _qkv(cfg, lp, h, cos_i, sin_i)
-        k = k.transpose(0, 2, 1, 3)           # [B, KvH, T, hd]
-        v = v.transpose(0, 2, 1, 3)
+        q, k, v = _qkv(cfg, lp, h, cos_i, sin_i)   # k, v [B, T, KvH, hd]
         mask_l = _layer_mask(cfg, i, mask, m_full)
         if dp_axes is not None:
             # dp mesh: pool page axis is dp-sharded with per-shard local
@@ -1532,7 +1592,8 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
                 mask_l, scale, attn_blocks, use_kernel, interp, mesh,
                 dp_axes[1])
         else:
-            kp, vp = _scatter_kv_pools(kp, vp, i, k, v, pg_w, off_w)
+            kp, vp = _scatter_kv_pools(cfg, kp, vp, i, k, v, pg_w, off_w,
+                                       mesh)
             attn = _paged_attend(cfg, q, kp, vp, i, tables, lengths,
                                  mask_l, scale, attn_blocks, mesh,
                                  use_kernel)

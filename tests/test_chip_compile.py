@@ -239,8 +239,8 @@ def test_paged_kernel_compiles_inside_manual_shard_map(topo, serving,
                 mesh, True)
         txt = _compiled_text(fn, q, pool, pool, tables, lengths)
     else:
-        kv = jax.ShapeDtypeStruct((B, KVH, 1, HD), jnp.bfloat16,
-                                  sharding=sh("dp", "tp", None, None))
+        kv = jax.ShapeDtypeStruct((B, 1, KVH, HD), jnp.bfloat16,
+                                  sharding=sh("dp", None, "tp", None))
         pos = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=sh("dp", None))
         mask = jax.ShapeDtypeStruct((B, 1, 1, nblk * ps), jnp.float32,
                                     sharding=sh("dp", None, None, None))
@@ -252,6 +252,101 @@ def test_paged_kernel_compiles_inside_manual_shard_map(topo, serving,
         txt = _compiled_text(fn, q, kv, kv, pool, pool, tables, lengths,
                              pos, mask)
     assert "tpu_custom_call" in txt
+
+
+def test_paged_write_kernel_compiles_on_a_tp_mesh(topo, serving):
+    """The step's K/V write of a tp=4 server: decoder._paged_write runs
+    the writer's kernel manual over every axis, one KV head to a device."""
+    mesh = Mesh(np.array(topo.devices).reshape(MeshPlan(tp=4).dims), AXES)
+    B = serving.max_slots
+
+    def sh(*spec):
+        return NamedSharding(mesh, P(*spec))
+
+    pool = _pool_shapes(serving, sh(None, None, "tp", None, None),
+                        sh(None, None, "tp", None))
+    kv = jax.ShapeDtypeStruct((B, 1, KVH, HD), jnp.bfloat16,
+                              sharding=sh(None, None, "tp", None))
+    at = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=sh(None, None))
+
+    def fn(kp, vp, k, v, pg, off):
+        return decoder._scatter_kv_pools(CFG, kp, vp, jnp.int32(0), k, v,
+                                         pg, off, mesh)
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        pool, pool, kv, kv, at, at).compile()
+    assert "paged_kv_write" in compiled.as_text()
+
+
+# the benchmark's two paged cells at their resolved engines: (preset,
+# slots, pages + the trash page, page size); pools [L, P, KvH, ps, 128]
+CELL_POOLS = {"phi-2": ("phi", 32, 160, 64),
+              "starcoder2-3b": ("starcoder2", 64, 768, 128)}
+
+
+def _cell_pool(one_chip, name):
+    preset, slots, pages, ps = CELL_POOLS[name]
+    cfg = dataclasses.replace(PRESETS[preset], kernels="pallas")
+    q = jax.ShapeDtypeStruct((cfg.n_layers, pages, cfg.n_kv_heads, ps, 128),
+                             jnp.int8, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((cfg.n_layers, pages, cfg.n_kv_heads, 128),
+                             jnp.float32, sharding=one_chip)
+    return cfg, slots, ps, {"q": q, "s": s}, q.size
+
+
+@pytest.mark.parametrize("name", sorted(CELL_POOLS))
+def test_decode_write_in_the_layer_scan_copies_no_pool(one_chip, name):
+    """The decode program's writer as the step runs it: inside the layer
+    scan, the pools donated, the v3 attention reading them in the same
+    layer. The kernel is there, and the program's temporaries are far
+    below one code pool: nothing re-lays a pool out or copies it (a
+    windowed XLA scatter compiles to two pool copies a layer here)."""
+    cfg, B, ps, pool, pool_bytes = _cell_pool(one_chip, name)
+    nblk = 2048 // ps
+    L, KvH, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    q = sds((B, 1, cfg.n_heads, hd), jnp.bfloat16)
+    kv = sds((B, 1, KvH, hd), jnp.bfloat16)
+    tables = sds((B, nblk), jnp.int32)
+    lengths = sds((B,), jnp.int32)
+
+    def fn(kp, vp, q, k, v, tables, lengths):
+        pg = tables[jnp.arange(B), lengths // ps][:, None]
+        off = (lengths % ps)[:, None]
+
+        def layer(carry, i):
+            kp, vp, acc = carry
+            kp, vp = decoder._scatter_kv_pools(cfg, kp, vp, i, k, v, pg, off)
+            out = decoder._paged_attend(cfg, q, kp, vp, i, tables, lengths,
+                                        None, 1.0, nblk, None, True)
+            return (kp, vp, acc + out), None
+        return jax.lax.scan(layer, (kp, vp, jnp.zeros_like(q)),
+                            jnp.arange(L, dtype=jnp.int32))[0]
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        pool, pool, q, kv, kv, tables, lengths).compile()
+    txt = compiled.as_text()
+    assert "paged_kv_write" in txt and "paged_v3" in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 16
+
+
+@pytest.mark.parametrize("bucket", [64, 256])
+@pytest.mark.parametrize("name", sorted(CELL_POOLS))
+def test_admit_insert_copies_no_pool(one_chip, name, bucket):
+    """The admit program's writer (decoder.paged_insert, one window a page)
+    on the donated pools: temporaries far below one code pool."""
+    cfg, _B, ps, pool, pool_bytes = _cell_pool(one_chip, name)
+    fresh = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 1, cfg.n_kv_heads, bucket, cfg.head_dim),
+        jnp.bfloat16, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((2048 // ps,), jnp.int32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def fn(kp, vp, ks, vs, row, n_valid):
+        return decoder.paged_insert(cfg, kp, vp, ks, vs, row, n_valid)
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        pool, pool, fresh, fresh, row, n).compile()
+    # the chunk's own pages, read and written: 128 MiB at phi-2's 256 bucket
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
 
 
 def test_flash_prefill_compiles_inside_manual_shard_map(topo):
