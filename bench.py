@@ -366,13 +366,6 @@ def measure(jax, *, model: str, dtype: str, slots: int, steps: int,
     mid_ctx = plens.astype(np.int64) + chunk + n_steps // 2
     kv_bytes = int(np.sum(np.minimum(mid_ctx, eng.max_seq))
                    * cfg.n_layers * 2 * cfg.kv_dim * kv_item)
-    # the unfused reference path (TPU_PAGED_FUSED=0) materialises the
-    # gathered KV window, then re-reads it for scores and mix: ~3x the
-    # KV traffic of the fused kernel's single streaming pass
-    paged_fused = paged and os.environ.get(
-        "TPU_PAGED_FUSED", "1").lower() not in ("0", "false")
-    if paged and not paged_fused:
-        kv_bytes *= 3
     bytes_per_step = param_bytes + kv_bytes
     # per-chip: params and KV are sharded over the mesh, so each chip
     # streams ~1/n_devices of the aggregate bytes
@@ -415,18 +408,9 @@ def measure(jax, *, model: str, dtype: str, slots: int, steps: int,
     if paged:
         rec["page_size"] = page_size
         rec["n_pages"] = n_pages or eng._pt.n_pages
-        rec["paged_fused"] = paged_fused
         # recompiles landed in the MEASURED window (warmup compiles are
-        # not recompiles) — the fused-kernel arm must hold this at 0
+        # not recompiles)
         rec["recompiles"] = int(rc_measured)
-        depth = os.environ.get("TPU_PAGED_DEPTH")
-        if depth:
-            rec["paged_depth"] = int(depth)
-        # ambient kernel routing (capture-scoped env is recorded via
-        # rec["env"]; pinned runs set these in the process environment)
-        for var in ("TPU_PAGED_V4", "TPU_PAGED_V3"):
-            if os.environ.get(var):
-                rec[var.lower()] = os.environ[var]
     # per-chip bytes vs the HBM rate of the device that ran (the peaks
     # table, by device_kind; an unlisted device is an error, not a v5e).
     # A CPU has no HBM: not measured there.
@@ -3249,14 +3233,12 @@ def main() -> None:
             # reported as paged_async_itl_ratio in the summary
             plan.append({**smoke, "mixed_arm": True, "paged": True})
         if os.environ.get("BENCH_PAGED_FUSED_ARM", "") == "1":
-            # fused paged-attention A/B (ISSUE 16): the fused kernel vs
-            # the gather+einsum reference (TPU_PAGED_FUSED=0), plus the
-            # int4-vs-int8 KV-pool pair on the same paged config — the
-            # summary's paged_bw_ratio (bandwidth-normalised speedup)
-            # must exceed 1 and the fused arm must hold recompiles at 0
+            # the paged kernel's capture plus the int4-vs-int8 KV-pool
+            # pair on the same paged config (the kernel's A/B against
+            # gather+einsum went with its switch in PR 31: a CPU's time
+            # ratio said nothing about the chip)
             fused = {**smoke, "paged": True, "mixed": True}
             plan += [fused,
-                     {**fused, "env": {"TPU_PAGED_FUSED": "0"}},
                      {**fused, "env": {"BENCH_KV_DTYPE": "int4"}},
                      {**fused, "env": {"BENCH_KV_DTYPE": "int8"}}]
         if os.environ.get("BENCH_PREFIX_ARM", "") == "1":
@@ -3338,45 +3320,26 @@ def main() -> None:
             # preemption) is a regression in what `kubectl apply` serves
             dict(model="tinyllama", dtype="int8", slots=64, page_size=128,
                  n_pages=192, **ab),
-            # GQA short-ctx flagship A/B: v3 (default) then the v2 revert
+            # GQA short-ctx flagship
             dict(model="tinyllama", dtype="int8", slots=32, **ab),
-            dict(model="tinyllama", dtype="int8", slots=32,
-                 env={"TPU_PAGED_V3": "0"}, **ab),
-            # fused-kernel A/B (ISSUE 16): the gather+einsum reference
-            # re-enabled — paired with the fused arm above for the
-            # summary's paged_bw_ratio (bandwidth-normalised speedup)
-            dict(model="tinyllama", dtype="int8", slots=32,
-                 env={"TPU_PAGED_FUSED": "0"}, **ab),
             # int4 KV pool vs the int8 flagship: half the KV stream per
             # step on the same config — capacity AND bandwidth headroom
             dict(model="tinyllama", dtype="int8", slots=32,
                  env={"BENCH_KV_DTYPE": "int4"}, **ab),
-            # long-ctx A/B: the regime the v3 live-page pipeline targets
+            # long context: the regime the live-page walk targets
             dict(model="tinyllama", dtype="int8", slots=32, steps=128,
                  seq=2048, prompt_len=1024, paged=True, mixed=True),
-            dict(model="tinyllama", dtype="int8", slots=32, steps=128,
-                 seq=2048, prompt_len=1024, paged=True, mixed=True,
-                 env={"TPU_PAGED_V3": "0"}),
             # dense GQA baseline (paged-vs-dense aggregate ratio)
             dict(model="tinyllama", dtype="int8", slots=8, steps=64,
                  seq=1024, prompt_len=128, paged=False, mixed=False),
-            # MHA paged A/B (phi, KvH=32): v3 made MHA page by default;
-            # the v2 arm tracks the old per-head-dot gap
+            # MHA paged (phi, KvH=32)
             dict(model="phi", dtype="int8", slots=32, steps=128, seq=1024,
                  prompt_len=128, paged=True, mixed=True),
-            dict(model="phi", dtype="int8", slots=32, steps=128, seq=1024,
-                 prompt_len=128, paged=True, mixed=True,
-                 env={"TPU_PAGED_V3": "0"}),
             # the headline config measured THROUGH /api/generate (the
             # surface the metric names) — delta vs capture 1 = HTTP +
             # scheduler + tokenize overhead
             dict(model="phi", dtype="int8", slots=8, steps=64, seq=1024,
                  prompt_len=128, paged=False, mixed=False, http=True),
-            # MHA decode-kernel A/B vs capture 1 (same config, kernel
-            # on): keeps the einsum bail measurement-backed
-            dict(model="phi", dtype="int8", slots=8, steps=64, seq=1024,
-                 prompt_len=128, paged=False, mixed=False,
-                 env={"TPU_MHA_KERNEL": "1"}),
             # speculative-decoding envelope BEFORE the int4 arm so the
             # (phi, int8) params cache survives into it (the int4 entry
             # evicts the single-model cache)
@@ -3442,9 +3405,9 @@ def main() -> None:
                 f"{len(plan) - i} captures")
             break
         t_cap = time.monotonic()
-        # capture-scoped env (e.g. TPU_MHA_KERNEL=1): kernel routing reads
-        # the environment at trace time — set before the engine compiles,
-        # restore even on failure so captures stay independent
+        # capture-scoped env (e.g. BENCH_KV_DTYPE=int4): set before the
+        # engine is built, restored even on failure so captures stay
+        # independent
         cap_env = cap.get("env") or {}
         saved_env = {k: os.environ.get(k) for k in cap_env}
         os.environ.update(cap_env)
@@ -3638,30 +3601,9 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
             disagg_pages = c.get("kv_transfer_pages")
             disagg_errors = c.get("client_error_frames")
             break
-    # fused paged-attention A/B (ISSUE 16): pair the TPU_PAGED_FUSED=0
-    # reference with the fused capture of the same config — the ratio is
-    # tokens-per-HBM-byte (tok_s x bytes/step, the steps cancel), i.e.
-    # how much further the fused kernel stretches the memory bus. The
-    # acceptance bar is > 1 with ZERO recompiles in the fused arm.
-    paged_bw_ratio = paged_fused_recompiles = None
     kv_int4_tok_s_ratio = kv_int4_bytes_ratio = None
     engine_caps = [c for c in captures
                    if "mode" not in c and "surface" not in c]
-    for off in engine_caps:
-        if not off.get("paged") or off.get("paged_fused") is not False:
-            continue
-        on = next((c for c in engine_caps
-                   if c.get("paged_fused")
-                   and c["model"] == off["model"]
-                   and c["slots"] == off["slots"]
-                   and c.get("kv_dtype") == off.get("kv_dtype")), None)
-        if on and on.get("tok_s") and off.get("tok_s") \
-                and on.get("bytes_per_step_gb"):
-            paged_bw_ratio = round(
-                (off["bytes_per_step_gb"] / on["bytes_per_step_gb"])
-                * (on["tok_s"] / off["tok_s"]), 3)
-            paged_fused_recompiles = on.get("recompiles")
-            break
     # int4 KV pool vs the int8 arm of the same shape: tok/s parity at
     # roughly half the KV stream (capacity is the headline, bandwidth
     # headroom the rider)
@@ -3669,7 +3611,7 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
         if c.get("kv_dtype") != "int4" or not c.get("paged"):
             continue
         i8 = next((d for d in engine_caps
-                   if d.get("kv_dtype") == "int8" and d.get("paged_fused")
+                   if d.get("kv_dtype") == "int8" and d.get("paged")
                    and d["model"] == c["model"]
                    and d["slots"] == c["slots"]), None)
         if i8 and i8.get("tok_s") and i8.get("bytes_per_step_gb"):
@@ -3737,8 +3679,6 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
         "disagg_handoffs_transferred": disagg_handoffs,
         "disagg_kv_transfer_pages": disagg_pages,
         "disagg_client_error_frames": disagg_errors,
-        "paged_bw_ratio": paged_bw_ratio,
-        "paged_fused_recompiles": paged_fused_recompiles,
         "kv_int4_tok_s_ratio": kv_int4_tok_s_ratio,
         "kv_int4_bytes_ratio": kv_int4_bytes_ratio,
         "async_fallbacks": async_fallbacks,

@@ -70,7 +70,6 @@ SERVING_ENV = ("TPU_ENGINE_DTYPE", "TPU_KV_DTYPE", "TPU_PAGED",
                "TPU_DECODE_CHUNK", "TPU_MAX_SEQ_LEN", "TPU_TENSOR_PARALLEL",
                "TPU_SEQUENCE_PARALLEL", "TPU_EXPERT_PARALLEL",
                "TPU_DATA_PARALLEL", "TPU_WARM_BUCKETS", "TPU_XLA_CACHE",
-               "TPU_PAGED_V3", "TPU_PAGED_V4", "TPU_PAGED_FUSED",
                "TPU_PREFIX_CACHE", "TPU_SPEC_DECODE", "OLLAMA_TPU_KERNELS",
                "TPU_MIN_PREFILL_BUCKET", "TPU_PREFILL_CHUNK")
 

@@ -9,12 +9,14 @@ lowered text to argv[2]/<config>.<kind>.txt:
     JAX_PLATFORMS=cpu python hack/lower_cells.py /root/scratch/tree out_a
     (the same for a copy of the change, through the SAME path: file names
     are part of the kernels' debug locations)
-    cmp out_a/<file> out_b/<file>
+    JAX_PLATFORMS=cpu python hack/cmp_lowered.py out_a out_b
 
 Texts that differ only inside the serialized Mosaic bodies of the
 ``tpu_custom_call``s differ in debug locations (line numbers of callers):
-PR 29 parsed both bodies and compared them printed without locations. The
-builder's check before a chip run, not a golden file."""
+``hack/cmp_lowered.py`` parses both bodies and compares them printed
+without locations. The builder's check before a chip run, not a golden
+file. Names after the two directories choose the configurations (default:
+the two dense cells; ``granite-4.0-h-small`` lowers too)."""
 import os
 import sys
 repo, out = sys.argv[1], sys.argv[2]

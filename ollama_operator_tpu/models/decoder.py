@@ -379,53 +379,14 @@ def _mlp(cfg: ModelConfig, lp, x):
     return d
 
 
-def fuse_qkv_params(params: Params, cfg: ModelConfig) -> Params:
-    """Concatenate wq|wk|wv (and their biases) along the output axis into
-    one ``wqkv`` leaf, so the attention input projection is ONE matmul
-    instead of three. At decode batch sizes each dispatched matmul pays a
-    fixed latency floor regardless of its byte count (r4 microbench,
-    v5e-1: mistral-shaped GQA qkv 70.6 µs separate vs 20.2 µs fused —
-    3.49×; the GQA k/v projections are tiny and each eat a full floor).
-    Valid for dense and quantized (int8/int4) leaves — every output
-    column of the grouped qmm is independent, so the fused result is
-    bitwise identical to the separate matmuls. The engine applies this
-    only on meshes without a sharded tp/sp axis (a fused column split
-    would straddle the q/kv shard boundaries)."""
-    layers = dict(params["layers"])
-    if "wq" not in layers:
-        return params
-
-    def cat(leaves):
-        if isinstance(leaves[0], dict):
-            return {k: jnp.concatenate([l[k] for l in leaves], axis=-1)
-                    for k in leaves[0]}
-        return jnp.concatenate(leaves, axis=-1)
-
-    layers["wqkv"] = cat([layers.pop("wq"), layers.pop("wk"),
-                          layers.pop("wv")])
-    if "bq" in layers:
-        layers["bqkv"] = cat([layers.pop("bq"), layers.pop("bk"),
-                              layers.pop("bv")])
-    return {**params, "layers": layers}
-
-
 @device_scope("attn.qkv")
 def _qkv(cfg: ModelConfig, lp, h, cos, sin):
     B, T, _ = h.shape
-    if "wqkv" in lp:
-        y = _mm(cfg, h, lp["wqkv"])
-        if "bqkv" in lp:
-            y = y + lp["bqkv"]
-        qd, kvd = cfg.q_dim, cfg.kv_dim
-        q = y[..., :qd]
-        k = y[..., qd:qd + kvd]
-        v = y[..., qd + kvd:]
-    else:
-        q = _mm(cfg, h, lp["wq"])
-        k = _mm(cfg, h, lp["wk"])
-        v = _mm(cfg, h, lp["wv"])
-        if "bq" in lp:
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    q = _mm(cfg, h, lp["wq"])
+    k = _mm(cfg, h, lp["wk"])
+    v = _mm(cfg, h, lp["wv"])
+    if "bq" in lp:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
     q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
@@ -775,8 +736,8 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
 # the two cache trees (``join_state``), so the engine's programs hand it
 # on as they hand on the cache.
 
-_ATTN_STACK = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo", "wqkv",
-               "bqkv", "q_norm_w", "k_norm_w")
+_ATTN_STACK = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+               "q_norm_w", "k_norm_w")
 
 
 def split_state(k_cache, v_cache):
@@ -1192,35 +1153,35 @@ def paged_insert(cfg: ModelConfig, k_pool, v_pool, ks, vs, table_row,
     return k_pool, v_pool
 
 
-def _paged_kernel_usable(cfg: ModelConfig, mesh, T: int, KvH: int, ps: int,
-                         hd: int) -> bool:
-    """Route T=1 paged decode through the pallas kernel? Unlike the dense
-    path there is no MHA bail-out: the gather fallback copies every
-    attended page per step, so the kernel's direct-DMA path wins for MHA
-    too (the dense einsum the old measurement favoured is not available
-    on a paged pool). TPU_PAGED_FUSED=0 forces the gather+einsum
-    reference path — the A/B control for the fused kernel's bandwidth
-    win (bench paged_bw_ratio) and the parity suite's oracle."""
-    import os
+def _paged_kernel_usable(cfg: ModelConfig, mesh, T: int, k_pool) -> bool:
+    """Route this paged forward's attention through the pallas kernel? The
+    one place the route is chosen and recorded, before the layer scan is
+    traced. The policy is here: the resolved kernel mode, T == 1 (a T > 1
+    extend is gather + einsum by design), no per-layer window, a mesh the
+    kernel's manual region covers. The shapes are
+    ``ops/pallas/paged.paged_decode_tileable``'s to judge. There is no MHA
+    bail-out as on the dense cache: gather + einsum copies every attended
+    page each step, and phi-2 (KvH = 32) is served by the kernel in every
+    line of the ledger (PRs 25-30). What the kernel was wanted for and
+    cannot take is flagged ``kernel_fallback``."""
     from ..ops.attention import resolve_kernels
-    from ..ops.pallas.flash import _lane_ok
+    from ..ops.pallas.paged import paged_decode_tileable
     site = "paged_decode" if T == 1 else "paged_extend"
 
     def gather(fell_back: bool = False) -> bool:
         note_kernel(site, "gather_einsum", fell_back)
         return False
 
-    if os.environ.get("TPU_PAGED_FUSED", "1").lower() in ("0", "false"):
-        return gather()
     mode = resolve_kernels(cfg.kernels)
     if mode not in ("pallas", "interpret") or T != 1:
         return gather()
     if cfg.altern_sliding:
         return gather()   # per-layer window rides the (traced) mask
     # from here on the kernel was wanted: giving way is a fallback
-    if cfg.n_heads % KvH or ps % 8 or not _lane_ok(hd, mode == "interpret"):
+    if not paged_decode_tileable(cfg.n_heads, k_pool, mode == "interpret"):
         return gather(fell_back=True)
     if mesh is not None and mesh.size > 1:
+        KvH = cfg.n_kv_heads
         tp = mesh.shape.get("tp", 1)
         if _paged_dp_axes(cfg, mesh, KvH) is None and tp != mesh.size:
             return gather(fell_back=True)  # engine enforces dp/tp meshes
@@ -1559,7 +1520,7 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
     # out-of-table blocks (a slot over-running max_seq) redirect to the
     # trash page — never clamp into the slot's LAST live page, which
     # would corrupt resident prefix K/V
-    use_kernel = _paged_kernel_usable(cfg, mesh, T, KvH, ps, hd)
+    use_kernel = _paged_kernel_usable(cfg, mesh, T, k_pool)
     dp_axes = _paged_dp_axes(cfg, mesh, KvH)
     if dp_axes is None:
         # single-shard write indices, computed once outside the scan (the

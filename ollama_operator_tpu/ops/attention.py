@@ -177,19 +177,27 @@ def _sharded_kernel_call(mesh, q, KvH: int, tileable, inner, args,
                          out_specs=qspec, check_vma=False)(*args)
 
 
+def _kernels_override() -> str:
+    """``OLLAMA_TPU_KERNELS``, checked: the ONE read of the environment
+    under ``ops/`` and ``models/`` (tools/invariant_lint holds that). A
+    read at trace time is no part of a jit cache key, so nothing else here
+    may depend on one."""
+    env = os.environ.get("OLLAMA_TPU_KERNELS", "")
+    if env and env not in KERNEL_MODES:
+        raise ValueError(
+            f"OLLAMA_TPU_KERNELS={env!r}; expected one of {KERNEL_MODES}")
+    return env
+
+
 def resolve_kernels(kernels: str) -> str:
     """Trace-time kernel choice. ``auto`` → pallas on TPU backends, XLA
     elsewhere. The OLLAMA_TPU_KERNELS env var overrides only the ``auto``
     choice — an explicit config always wins. (On >1-device meshes the
     dispatchers below run the kernels inside a dp/tp-manual shard_map;
     there is no multi-device XLA fallback anymore.)"""
-    env = os.environ.get("OLLAMA_TPU_KERNELS", "")
-    if env:
-        if env not in KERNEL_MODES:
-            raise ValueError(
-                f"OLLAMA_TPU_KERNELS={env!r}; expected one of {KERNEL_MODES}")
-        if kernels == "auto":
-            kernels = env
+    env = _kernels_override()
+    if kernels == "auto" and env:
+        kernels = env
     if kernels == "auto":
         kernels = "pallas" if jax.default_backend() == "tpu" else "xla"
     return kernels
@@ -244,42 +252,28 @@ def cached_attention(cfg, q, k_cache, v_cache, mask, q_pos, scale: float,
     DMAs within it. On a >1-device ``mesh`` the kernel runs inside a
     dp/tp-manual shard_map (see chunk_attention)."""
     mode = resolve_kernels(cfg.kernels)
-    # MHA (G == 1) maps badly onto the GQA decode kernel's (B, KvH, nk)
-    # grid — B×KvH tiny 8-row programs lose to one big XLA einsum
-    # (measured on v5e: phi 128 vs 147 tok/s) — so "auto"-resolved pallas
-    # skips it; an explicit pallas choice (config or OLLAMA_TPU_KERNELS)
-    # still forces it. TPU_MHA_KERNEL=1 instead routes MHA through the
-    # head-tiled mha_decode kernel (grid (B, H/8, nk) — pallas/flash.py);
-    # it stays opt-in until a chip capture shows it beating the einsum
-    # (bench.py measures both).
+    # MHA (G == 1) maps badly onto this kernel's (B, KvH, nk) grid: B×KvH
+    # programs of 8 rows, 7 of them padding. So a pallas that "auto" chose
+    # by backend leaves MHA to the einsum; an explicit pallas (config or
+    # OLLAMA_TPU_KERNELS) still forces the kernel. Unmeasured since the
+    # cells came: the ledger's one MHA model (phi-2) is paged in every
+    # line, and no cell serves MHA from the dense cache.
     explicit_pallas = (cfg.kernels == "pallas"
-                       or os.environ.get("OLLAMA_TPU_KERNELS") == "pallas")
+                       or _kernels_override() == "pallas")
     is_mha = q.shape[2] == k_cache.shape[1]
-    mha_kernel = is_mha and os.environ.get("TPU_MHA_KERNEL", "") == "1"
-    gqa_ok = (not is_mha) or explicit_pallas or mha_kernel
     if (mode in ("pallas", "interpret") and q.shape[1] == 1
-            and (gqa_ok or mode == "interpret")):
-        from .pallas import (decode_attention, decode_tileable,
-                             mha_decode_attention, mha_decode_tileable)
+            and (not is_mha or explicit_pallas or mode == "interpret")):
+        from .pallas import decode_attention, decode_tileable
         interp = mode == "interpret"
         hd, S = q.shape[3], k_cache.shape[2]
 
-        if mha_kernel:
-            def inner(q, k_cache, v_cache, pos):
-                return mha_decode_attention(
-                    q, k_cache, v_cache, pos, scale, cfg.attn_softcap,
-                    cfg.sliding_window, interpret=interp)
+        def inner(q, k_cache, v_cache, pos):
+            return decode_attention(
+                q, k_cache, v_cache, pos, scale, cfg.attn_softcap,
+                cfg.sliding_window, interpret=interp)
 
-            def tileable(h, kvh):
-                return mha_decode_tileable(S, h, kvh, hd, interp)
-        else:
-            def inner(q, k_cache, v_cache, pos):
-                return decode_attention(
-                    q, k_cache, v_cache, pos, scale, cfg.attn_softcap,
-                    cfg.sliding_window, interpret=interp)
-
-            def tileable(h, kvh):
-                return decode_tileable(S, h, kvh, hd, interp)
+        def tileable(h, kvh):
+            return decode_tileable(S, h, kvh, hd, interp)
 
         if mesh is not None and mesh.size > 1:
             out = _sharded_kernel_call(
@@ -289,8 +283,7 @@ def cached_attention(cfg, q, k_cache, v_cache, mask, q_pos, scale: float,
         else:
             out = inner(q, k_cache, v_cache, q_pos[:, 0])
         if out is not None:
-            note_kernel("decode", "mha_decode_attention" if mha_kernel
-                        else "decode_attention")
+            note_kernel("decode", "decode_attention")
             return out
         note_kernel("decode", "einsum", fell_back=True)
     else:

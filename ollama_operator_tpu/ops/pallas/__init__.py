@@ -14,5 +14,4 @@ accumulation.
 """
 
 from .flash import (decode_attention, decode_tileable,  # noqa: F401
-                    flash_prefill, mha_decode_attention,
-                    mha_decode_tileable, prefill_tileable)
+                    flash_prefill, prefill_tileable)

@@ -291,121 +291,3 @@ def decode_attention(q, k_cache, v_cache, q_pos, scale: float,
     )(q_pos.astype(jnp.int32), qg, k_cache, v_cache)
     return out[:, :, :G, :].reshape(B, 1, H, hd)
 
-
-# ---------------------------------------------------------------------------
-# MHA decode: head-tiled grid (no GQA grouping axis to tile on)
-# ---------------------------------------------------------------------------
-
-def _mha_decode_kernel(qpos_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *,
-                       scale: float, softcap: float, window: int,
-                       bk: int, nk: int):
-    """Grid (B, H//Ht, nk): each program advances Ht whole heads one key
-    block. MHA has G == 1, so the GQA kernel's (B, KvH, nk) grid degrades
-    to B×H tiny programs whose matmul rows are 7/8 padding; tiling HEADS
-    instead makes each DMA Ht pages wide and the per-head dot an
-    elementwise-mul + lane reduction (VPU) — decode is bandwidth-bound,
-    the MXU was idle either way (round-2 VERDICT weak #3)."""
-    b, ki = pl.program_id(0), pl.program_id(2)
-    qp = qpos_ref[b]
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    k_start = ki * bk
-    needed = k_start <= qp
-    if window:
-        needed = jnp.logical_and(needed, k_start + bk - 1 > qp - window)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)               # [Ht, hd]
-        kb = k_ref[0].astype(jnp.float32)              # [Ht, bk, hd]
-        s = jnp.sum(q[:, None, :] * kb, axis=-1) * scale   # [Ht, bk]
-        s = softcap_scores(s, softcap)
-        Ht = s.shape[0]
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (Ht, bk), 1)
-        ok = k_pos <= qp
-        if window:
-            ok = jnp.logical_and(ok, k_pos > qp - window)
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_ref[:]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(m_cur > NEG_INF / 2, jnp.exp(s - m_cur), 0.0)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        vb = v_ref[0].astype(jnp.float32)              # [Ht, bk, hd]
-        acc_ref[:] = acc_ref[:] * alpha + jnp.sum(
-            p[:, :, None] * vb, axis=1)
-        m_ref[:] = m_cur
-
-    @pl.when(ki == nk - 1)
-    def _done():
-        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = out.astype(o_ref.dtype)
-
-
-def mha_decode_tileable(S: int, H: int, KvH: int, hd: int, interpret: bool,
-                        block_k: int = 512, head_tile: int = 8) -> bool:
-    """True iff mha_decode_attention will NOT bail for these shapes."""
-    return (KvH == H and H % head_tile == 0 and _lane_ok(hd, interpret)
-            and _pick_block(S, block_k) is not None)
-
-
-def mha_decode_attention(q, k_cache, v_cache, q_pos, scale: float,
-                         softcap: float = 0.0, sliding_window: int = 0, *,
-                         block_k: int = 512, head_tile: int = 8,
-                         interpret: bool = False):
-    """Single-token MHA attention against the head-first slot KV cache.
-
-    q [B, 1, H, hd]; k_cache/v_cache [B, H, S, hd] (KvH == H); q_pos [B].
-    Grid (B, H//head_tile, nk) — see _mha_decode_kernel. Returns
-    [B, 1, H, hd] (q.dtype) or None when the shapes don't tile.
-    """
-    B, T, H, hd = q.shape
-    KvH, S = k_cache.shape[1], k_cache.shape[2]
-    if T != 1 or not mha_decode_tileable(S, H, KvH, hd, interpret,
-                                         block_k, head_tile):
-        return None
-    bk = _pick_block(S, block_k)
-    Ht = head_tile
-    nk = S // bk
-    q2 = q.reshape(B, H, hd)
-
-    def kv_index(b, hi, ki, qpos_ref):
-        last = qpos_ref[b] // bk
-        return (b, hi, jnp.minimum(ki, last), 0)
-
-    kernel = functools.partial(
-        _mha_decode_kernel, scale=scale, softcap=softcap,
-        window=sliding_window, bk=bk, nk=nk)
-    out = pl.pallas_call(
-        kernel,
-        name="mha_decode_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H // Ht, nk),
-            in_specs=[
-                pl.BlockSpec((1, Ht, hd),
-                             lambda b, hi, ki, qpos_ref: (b, hi, 0)),
-                pl.BlockSpec((1, Ht, bk, hd), kv_index),
-                pl.BlockSpec((1, Ht, bk, hd), kv_index),
-            ],
-            out_specs=pl.BlockSpec((1, Ht, hd),
-                                   lambda b, hi, ki, qpos_ref: (b, hi, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((Ht, hd), jnp.float32),
-                pltpu.VMEM((Ht, 1), jnp.float32),
-                pltpu.VMEM((Ht, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q_pos.astype(jnp.int32), q2, k_cache, v_cache)
-    return out.reshape(B, 1, H, hd)

@@ -25,10 +25,6 @@ _LAYER_SPECS: Dict[str, P] = {
     "wq": P(None, None, "tp"),
     "wk": P(None, None, "tp"),
     "wv": P(None, None, "tp"),
-    # fused qkv (engine-side, only on meshes without a sharded tp axis —
-    # a tp split would straddle the q/kv column boundary)
-    "wqkv": P(None, None, None),
-    "bqkv": P(None, None),
     "wo": P(None, "tp", None),
     "bq": P(None, "tp"),
     "bk": P(None, "tp"),

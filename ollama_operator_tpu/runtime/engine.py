@@ -186,25 +186,21 @@ def _recurrent_slots(cfg: ModelConfig) -> int:
 def resolve_paged_default(cfg: ModelConfig, mesh) -> bool:
     """The serving default for an unset paged flag, per model and mesh.
 
-    Data-driven (BASELINE.md r3+r4, v5e): GQA models page (r3: paged-32
-    measured 1.90-2.04x the dense-8 aggregate on tinyllama). MHA models
-    page too SINCE the v3 live-page async-DMA kernel — the r4
-    same-window A/B measured phi (KvH=32) paged-32 at 934.5 tok/s
-    vs ~570 dense-8 (the r3 grid kernel was per-head-dot-bound at
-    190 ms/step, which is why MHA used to stay dense); with the kernel
-    explicitly reverted (TPU_PAGED_V3=0) MHA keeps the dense default.
-    Off for MoE (untested combination), for meshes the pool can't shard
-    (sp; dp without a valid dp-manual layout), and off the TPU backend
-    entirely (the measurement is v5e's; a 1-core CPU dev/kind pod gets
-    4x the per-step compute from a 32-slot batch). An explicit --paged /
-    TPU_PAGED=0|1 always wins."""
-    import os
-
+    What the ledger's cells decide: GQA and MHA models on one v5e chip
+    page. Both dense cells run the paged pool at this default and the
+    ``paged_v3`` kernel (ops/pallas/paged.py): starcoder2-3b (GQA, 2 kv
+    heads, 64 slots) at 2,629.0 and phi-2 (MHA, 32 kv heads, 32 slots) at
+    1,457.2 ``out_tok_s`` (ledger, PR 30). Neither cell has a dense-cache
+    twin, so the ledger does not say by how much paging wins.
+    What no cell decides ("unmeasured"): MoE stays dense; a stack with
+    recurrent layers has no paged form (``Engine`` refuses one); meshes
+    page only where the pool can be sharded (tp, or dp with a valid
+    dp-manual layout; not sp) and none has a cell. Off the TPU backend the
+    default is dense: a CPU dev/kind pod pays per-step compute for every
+    slot of a 32-slot batch. An explicit --paged / TPU_PAGED=0|1 always
+    wins."""
     import jax
     if jax.default_backend() != "tpu":
-        return False
-    if (cfg.n_kv_heads >= cfg.n_heads
-            and os.environ.get("TPU_PAGED_V3", "1") != "1"):
         return False
     if cfg.n_experts or cfg.layer_kinds:
         return False
@@ -516,20 +512,6 @@ class Engine:
             self._repl_sh = None
         self._cache_sh, self._slot_sh = cache_sh, slot_sh
         self._slot_sh2 = slot_sh2
-        # fused single-matmul QKV (models/decoder.fuse_qkv_params).
-        # Opt-in (TPU_FUSED_QKV=1): isolated jit-call microbenches showed
-        # 3.5x on GQA projections, but the on-chip serving A/B measured
-        # -3.7% — inside the one compiled decode program XLA already
-        # schedules the three dots back-to-back, so there is no per-op
-        # dispatch floor to save (BASELINE.md r4). Kept for experiments
-        # and hosts where dispatch-bound serving paths exist.
-        import os as _os
-        if (_os.environ.get("TPU_FUSED_QKV", "0") == "1"
-                and (mesh is None
-                     or all(sz == 1 for ax, sz in dict(mesh.shape).items()
-                            if ax != "dp"))):
-            from ..models.decoder import fuse_qkv_params
-            params = fuse_qkv_params(params, cfg)
         if mesh is not None:
             self._param_sh = params_sharding_tree(params, mesh, cfg)
             params = jax.tree_util.tree_map(self._g, params,
@@ -597,7 +579,7 @@ class Engine:
                            if self.quant4 else pool_shape)
                 cache_sh = {qkey: pool_sh, "s": s_sh}
                 # scale arrays lane-padded to the 128 tile like the codes'
-                # head dim: the v3 kernel DMAs [KvH, ps] f32 slices per
+                # head dim: the paged kernel DMAs [KvH, ps] f32 slices per
                 # page, and Mosaic requires the DMA'd minor dim to be a
                 # multiple of 128 lanes (ps=64 default crashes the real
                 # lowering). Writers scatter at off < ps; readers slice
@@ -624,7 +606,7 @@ class Engine:
             # stitch pages the slot's shard cannot read).
             # TPU_PREFIX_CACHE=0 falls back to the parked-slot path.
             if (dp == 1
-                    and _os.environ.get("TPU_PREFIX_CACHE", "1").lower()
+                    and os.environ.get("TPU_PREFIX_CACHE", "1").lower()
                     not in ("0", "false")):
                 from .radix import RadixCache
                 self._radix = RadixCache(ps)

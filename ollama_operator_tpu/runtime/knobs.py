@@ -81,9 +81,6 @@ declare("TPU_DECODE_CHUNK", "int", 0, "engine",
 declare("TPU_MIN_PREFILL_BUCKET", "int", 0, "engine",
         "floor for the padded prefill bucket ladder; 0 = engine-config "
         "default")
-declare("TPU_FUSED_QKV", "bool", 0, "engine",
-        "1 fuses the QKV projections into one matmul on single-device "
-        "meshes")
 declare("TPU_SPEC_DECODE", "int", 0, "engine",
         "speculative-decoding draft length k; 0 disables")
 declare("TPU_WARM_SNAPSHOT_EXECS", "bool", None, "engine",
@@ -94,29 +91,13 @@ declare("TPU_WARM_SNAPSHOT_EXECS", "bool", None, "engine",
 
 declare("TPU_PAGED", "bool", None, "paged",
         "1 forces the paged KV cache, 0 forces dense; unset = per-model "
-        "default (paged for GQA)")
+        "default (paged for GQA and MHA on a TPU)")
 declare("TPU_PAGE_SIZE", "int", 0, "paged",
         "KV pool page size in tokens; 0 = backend default (128 paged TPU, "
         "else 64)")
 declare("TPU_N_PAGES", "int", 0, "paged",
         "KV pool page count; 0 = dense-equivalent "
         "max_slots*max_seq_len/page_size")
-declare("TPU_PAGED_V3", "bool", 1, "paged",
-        "0 disables the v3 double-buffered paged attention kernel "
-        "(falls back to v2)")
-declare("TPU_PAGED_V4", "bool", 0, "paged",
-        "1 opts in to the v4 epoch-fenced paged kernel variant")
-declare("TPU_PAGED_DEPTH", "int", 2, "paged",
-        "paged kernel pipeline depth (double-buffering stages)")
-declare("TPU_PAGED_FUSED", "bool", 1, "paged",
-        "0 disables the fused paged-attention pallas kernels entirely "
-        "(gather+einsum reference path; A/B control and parity oracle)")
-
-# -- ops / kernels ----------------------------------------------------------
-
-declare("TPU_MHA_KERNEL", "bool", 0, "ops",
-        "1 routes MHA decode through the head-tiled pallas kernel instead "
-        "of the XLA einsum")
 
 # -- scheduler --------------------------------------------------------------
 

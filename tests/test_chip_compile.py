@@ -28,7 +28,7 @@ from ollama_operator_tpu.models.config import PRESETS
 from ollama_operator_tpu.ops.attention import chunk_attention
 from ollama_operator_tpu.ops.pallas.flash import (decode_attention,
                                                   flash_prefill)
-from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention_v3
+from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention
 from ollama_operator_tpu.ops.pallas.quant import qmm4_pallas, qmm_pallas
 from ollama_operator_tpu.parallel.mesh import AXES, MeshPlan
 from ollama_operator_tpu.runtime.engine import (EngineConfig,
@@ -169,9 +169,9 @@ def test_paged_decode_v3_compiles_on_engine_pool(one_chip, serving):
     lengths = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
 
     def fn(q, kp, vp, tables, lengths):
-        out = paged_decode_attention_v3(
+        out = paged_decode_attention(
             q, kp, vp, jnp.int32(0), tables, lengths, SCALE, nblk=nblk)
-        assert out is not None, "v3 refused the engine's own pool layout"
+        assert out is not None, "refused the engine's own pool layout"
         return out
 
     assert "tpu_custom_call" in _compiled_text(fn, q, pool, pool, tables,
@@ -327,6 +327,54 @@ def test_decode_write_in_the_layer_scan_copies_no_pool(one_chip, name):
     txt = compiled.as_text()
     assert "paged_kv_write" in txt and "paged_v3" in txt
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 16
+
+
+# pools Mosaic's manual copies cannot take (ops/pallas/paged.
+# paged_decode_tileable): an int4 pool of 32-token pages (16 stored rows,
+# under int8's 32-row tile) and int8 pages whose scale lanes nobody padded
+# to 128. Until PR 31 the v2 grid kernel served both on a chip.
+REFUSED_POOLS = {
+    "int4-32-token-pages": ("q4", 32, 128),
+    "unpadded-scale-lanes": ("q", 64, 64),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(REFUSED_POOLS))
+def test_refused_pool_decodes_through_the_flagged_fallback(one_chip, shape):
+    """A decode step over a pool the kernel refuses still compiles for the
+    chip: the route is gather + einsum, chosen once before the layer scan
+    and recorded as a fallback, and no Mosaic attention is in the program."""
+    from ollama_operator_tpu.ops.attention import record_kernels
+    key, ps, sp = REFUSED_POOLS[shape]
+    cfg = dataclasses.replace(PRESETS["starcoder2"], kernels="pallas")
+    B, pages, nblk = 8, 33, 4
+    L, KvH, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    sds = lambda shp, dt: jax.ShapeDtypeStruct(       # noqa: E731
+        shp, dt, sharding=one_chip)
+    rows = ps // 2 if key == "q4" else ps
+    pool = {key: sds((L, pages, KvH, rows, 128), jnp.int8),
+            "s": sds((L, pages, KvH, sp), jnp.float32)}
+    q = sds((B, 1, cfg.n_heads, hd), jnp.bfloat16)
+    tables = sds((B, nblk), jnp.int32)
+    lengths = sds((B,), jnp.int32)
+
+    def fn(kp, vp, q, tables, lengths):
+        use_kernel = decoder._paged_kernel_usable(cfg, None, 1, kp)
+        k_pos = jnp.arange(nblk * ps, dtype=jnp.int32)[None, None, :]
+        mask = decoder._causal_window_mask(k_pos, lengths[:, None, None],
+                                           cfg.sliding_window)
+
+        def layer(acc, i):
+            out = decoder._paged_attend(cfg, q, kp, vp, i, tables, lengths,
+                                        mask, 1.0, nblk, None, use_kernel)
+            return acc + out, None
+        return jax.lax.scan(layer, jnp.zeros_like(q),
+                            jnp.arange(L, dtype=jnp.int32))[0]
+    with record_kernels() as picked:
+        txt = jax.jit(fn).lower(pool, pool, q, tables,
+                                lengths).compile().as_text()
+    assert picked == [("paged_decode", "gather_einsum", True)]
+    assert "paged_v3" not in txt and "tpu_custom_call" not in txt
 
 
 @pytest.mark.parametrize("bucket", [64, 256])

@@ -249,11 +249,10 @@ def test_per_request_repeat_last_n():
     assert len(eng._admit_execs) == 1
 
 
-def test_resolve_paged_default(monkeypatch):
-    """Serving default (data-driven per BASELINE r3+r4): paged for GQA on
-    TPU, paged for MHA since the v3 live-page kernel (dense again when
-    v3 is explicitly reverted), dense for MoE/CPU/incompatible meshes;
-    explicit flags resolve in the server before the engine is built."""
+def test_resolve_paged_default():
+    """Serving default: paged for GQA and MHA on TPU (the ledger's two
+    paged cells), dense for MoE/CPU/incompatible meshes; explicit flags
+    resolve in the server before the engine is built."""
     from unittest import mock
 
     import dataclasses
@@ -267,11 +266,7 @@ def test_resolve_paged_default(monkeypatch):
     with mock.patch("jax.default_backend", return_value="tpu"):
         assert resolve_paged_default(gqa, None) is True
         mha = dataclasses.replace(gqa, n_kv_heads=gqa.n_heads)
-        assert resolve_paged_default(mha, None) is True   # v3 default
-        monkeypatch.setenv("TPU_PAGED_V3", "0")           # v2 revert
-        assert resolve_paged_default(mha, None) is False
-        assert resolve_paged_default(gqa, None) is True
-        monkeypatch.delenv("TPU_PAGED_V3")
+        assert resolve_paged_default(mha, None) is True
         moe = dataclasses.replace(gqa, n_experts=4)
         assert resolve_paged_default(moe, None) is False
         assert resolve_paged_default(
@@ -401,38 +396,41 @@ def test_resolve_engine_dtype():
     assert resolve_kv_dtype_default("cpu") == "float32"
 
 
-def test_fused_qkv_matches_separate(monkeypatch):
-    """Engine-side fused single-matmul QKV (models/decoder.fuse_qkv_params)
-    must decode bitwise-identically to the separate projections — every
-    output column of the (q)mm is independent, so fusion is pure op-count
-    reduction. Covers biases (attn_bias) and GQA."""
+def test_deleted_kernel_switches_change_nothing(monkeypatch):
+    """The six variables that chose a kernel at trace time are gone (PR
+    31): set to what used to turn every other path on, a paged engine
+    traces the same kernels and decodes the same tokens as with none."""
     import dataclasses
 
     import numpy as np
 
     from ollama_operator_tpu.runtime.engine import Engine, SlotOptions
-    cfg = dataclasses.replace(cfglib.PRESETS["tiny"], attn_bias=True,
-                              kernels="xla")
+    cfg = dataclasses.replace(cfglib.PRESETS["tiny"], kernels="interpret")
     params = decoder.init_params(cfg, jax.random.PRNGKey(3), jnp.float32)
     prompt = np.array([5, 6, 7, 8, 9, 2], np.int32)
     g = SlotOptions(temperature=0.0, repeat_penalty=1.0)
+    gone = {"TPU_PAGED_V3": "0", "TPU_PAGED_V4": "1", "TPU_PAGED_DEPTH": "4",
+            "TPU_PAGED_FUSED": "0", "TPU_MHA_KERNEL": "1",
+            "TPU_FUSED_QKV": "1"}
 
     def run():
         eng = Engine(cfg, params,
                      ecfg=EngineConfig(max_slots=2, max_seq_len=64,
-                                       cache_dtype=jnp.float32,
-                                       min_prefill_bucket=16))
+                                       cache_dtype=jnp.int8, paged=True,
+                                       page_size=8, min_prefill_bucket=16))
         toks = [eng.admit(0, prompt, g)]
-        toks += [int(eng.decode()[0]) for _ in range(6)]
-        return toks, "wqkv" in eng.params["layers"]
+        toks += [int(t) for t in eng.decode_n(6)[:, 0]]
+        assert set(eng.params["layers"]) >= {"wq", "wk", "wv"}
+        return toks, eng.kernels_by_kind()
 
-    monkeypatch.setenv("TPU_FUSED_QKV", "0")
-    ref, fused0 = run()
-    assert not fused0
-    monkeypatch.setenv("TPU_FUSED_QKV", "1")
-    got, fused1 = run()
-    assert fused1, "fusion did not engage on a single-device engine"
-    assert got == ref, (got, ref)
+    for name in gone:
+        monkeypatch.delenv(name, raising=False)
+    ref, ref_kinds = run()
+    assert "paged_decode=paged_v3" in ref_kinds["decode"]
+    for name, value in gone.items():
+        monkeypatch.setenv(name, value)
+    got, kinds = run()
+    assert (got, kinds) == (ref, ref_kinds)
 
 
 def test_mirostat_mu_threads_through_decode_chunks():

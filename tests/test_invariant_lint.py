@@ -20,7 +20,8 @@ from tools.invariant_lint.passes import (DeterminismPass,
                                          FaultCatalogPass,
                                          FollowerPurityPass, HostSyncPass,
                                          KnobRegistryPass, LockOrderPass,
-                                         MetricsDisciplinePass)
+                                         MetricsDisciplinePass,
+                                         TraceEnvPass)
 
 REPO = Path(__file__).resolve().parents[1]
 FIX = REPO / "tests" / "fixtures" / "lint"
@@ -39,6 +40,9 @@ def fixture_config(case, **overrides):
         determinism_modules=("pkg/engine.py",),
         exception_scopes=("pkg",),
         faults_module="pkg/faults.py",
+        trace_env_scopes=("pkg/ops", "pkg/models"),
+        trace_env_resolvers=(("pkg/ops/attention.py",
+                              "_kernels_override"),),
     )
     defaults.update(overrides)
     return LintConfig(**defaults)
@@ -81,6 +85,45 @@ def test_knob_registry_read_sites_are_finding_anchors():
     read = [f for f in fs if "TPU_FIX_B" in f.message][0]
     assert read.path == "pkg/mod.py"
     assert read.line == 8
+
+
+# -- trace-env --------------------------------------------------------------
+
+def test_trace_env_fixture_flags_reads_in_traced_packages():
+    """A ``TPU_*`` read under ops/ chooses a kernel at trace time, where
+    it is no cache key: each read is a finding, whatever its spelling
+    (``os.environ.get``, a bare-imported ``getenv``, a subscript), in both
+    scoped packages, and a suppression needs its reason."""
+    fs = run_one("traceenv", TraceEnvPass())
+    live = unsuppressed(fs)
+    assert [(f.path, f.line) for f in live] == [
+        ("pkg/models/decoder.py", 8),     # a namesake is not the resolver
+        ("pkg/ops/paged.py", 9),          # os.environ.get("TPU_FIX_KERNEL")
+        ("pkg/ops/paged.py", 11),         # a bare-imported getenv(...)
+    ]
+    assert all("not a jit cache key" in f.message for f in live)
+    supp = [f for f in fs if f.suppressed]
+    assert [(f.path, f.suppress_reason) for f in supp] == [
+        ("pkg/ops/paged.py", "fixture exercises suppression")]
+
+
+def test_trace_env_passes_the_resolver_and_code_outside_the_scope():
+    """The one sanctioned read (the resolver of OLLAMA_TPU_KERNELS) and
+    reads outside ops/ and models/ are not this pass's findings; in the
+    shipped tree the resolver exists, is the only read there, and is
+    what the rule names."""
+    fs = run_one("traceenv", TraceEnvPass())
+    assert not [f for f in fs if f.path in ("pkg/ops/attention.py",
+                                            "pkg/runtime/engine.py")]
+    cfg = LintConfig(root=REPO)
+    assert not run_passes(cfg, [TraceEnvPass()])
+    (mod, fn), = cfg.trace_env_resolvers
+    src = (REPO / mod).read_text()
+    assert f"def {fn}(" in src and src.count("os.environ") == 1
+    # with no resolver sanctioned, that one read is what is left
+    bare = run_passes(LintConfig(root=REPO, trace_env_resolvers=()),
+                      [TraceEnvPass()])
+    assert [f.path for f in bare] == [mod]
 
 
 # -- metrics-discipline -----------------------------------------------------
@@ -227,7 +270,7 @@ def test_json_schema_and_renderers():
 
 def test_pass_ids_unique_and_kebab():
     ids = [p.id for p in ALL_PASSES]
-    assert len(ids) == len(set(ids)) == 8
+    assert len(ids) == len(set(ids)) == 9
     for pid in ids:
         assert pid == pid.lower() and " " not in pid
 

@@ -178,11 +178,33 @@ def play_kubelet(fake, stop):
         stop.wait(0.05)
 
 
+def _until(cond, what, guard=120.0):
+    """Wait on the condition itself. ``guard`` only stops a hang: it is
+    far above anything a healthy run needs and is not what the test
+    measures."""
+    deadline = time.monotonic() + guard
+    while time.monotonic() < deadline:
+        got = cond()
+        if got:
+            return got
+        time.sleep(0.02)
+    raise AssertionError(what)
+
+
+@pytest.fixture()
+def fast_poll(monkeypatch):
+    """The reconciler's steady-state requeue, shrunk. A Model reaches
+    Available over three POLL results, and the manager backs consecutive
+    POLLs off (5 s, 7.5 s, 11.25 s: 24 s on an idle host), so a clock of
+    30 s measured the host's load, not the watch path. Below the
+    manager's POLL_BACKOFF_FLOOR nothing backs off."""
+    import ollama_operator_tpu.operator.reconciler as r
+    monkeypatch.setattr(r, "POLL", r.Result(requeue_after=0.1))
+
+
 class TestManagerEndToEnd:
-    def test_watch_to_available(self, fake):
+    def test_watch_to_available(self, fake, fast_poll):
         mgr = Manager(fake, namespace="default", server_image="img:t")
-        # shrink poll delays so the test runs fast
-        import ollama_operator_tpu.operator.reconciler as r
         stop = threading.Event()
         kubelet = threading.Thread(target=play_kubelet, args=(fake, stop),
                                    daemon=True)
@@ -190,14 +212,9 @@ class TestManagerEndToEnd:
         mgr.start(workers=2, serve_health=False)
         try:
             fake.create(model_obj("e2e"))
-            deadline = time.time() + 30
-            while time.time() < deadline:
-                m = fake.get(API_VERSION, KIND, "default", "e2e")
-                if m and is_condition_true(m, "Available"):
-                    break
-                time.sleep(0.1)
-            else:
-                raise AssertionError("model never became Available")
+            _until(lambda: is_condition_true(
+                fake.get(API_VERSION, KIND, "default", "e2e") or {},
+                "Available"), "model never became Available")
             dep = fake.get("apps/v1", "Deployment", "default",
                            "ollama-model-e2e")
             assert dep is not None
@@ -207,7 +224,7 @@ class TestManagerEndToEnd:
             stop.set()
             mgr.stop()
 
-    def test_workload_drift_heals(self, fake):
+    def test_workload_drift_heals(self, fake, fast_poll):
         mgr = Manager(fake, namespace="default", server_image="img:t")
         stop = threading.Event()
         kubelet = threading.Thread(target=play_kubelet, args=(fake, stop),
@@ -216,25 +233,18 @@ class TestManagerEndToEnd:
         mgr.start(workers=2, serve_health=False)
         try:
             fake.create(model_obj("drift"))
-            deadline = time.time() + 30
-            while time.time() < deadline:
-                m = fake.get(API_VERSION, KIND, "default", "drift")
-                if m and is_condition_true(m, "Available"):
-                    break
-                time.sleep(0.1)
+            _until(lambda: is_condition_true(
+                fake.get(API_VERSION, KIND, "default", "drift") or {},
+                "Available"), "model never became Available")
             # sabotage the deployment: wrong replica count
             dep = fake.get("apps/v1", "Deployment", "default",
                            "ollama-model-drift")
             dep["spec"]["replicas"] = 7
             fake.update(dep)  # owned-workload watch maps back to the Model
-            deadline = time.time() + 30
-            while time.time() < deadline:
-                dep = fake.get("apps/v1", "Deployment", "default",
-                               "ollama-model-drift")
-                if dep["spec"]["replicas"] == 1:
-                    break
-                time.sleep(0.1)
-            assert dep["spec"]["replicas"] == 1
+            _until(lambda: fake.get(
+                "apps/v1", "Deployment", "default",
+                "ollama-model-drift")["spec"]["replicas"] == 1,
+                "the owned Deployment's drift was never healed")
         finally:
             stop.set()
             mgr.stop()

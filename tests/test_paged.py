@@ -235,9 +235,8 @@ def test_scheduler_paged_full_flow_no_pressure(params):
     ("interpret", jnp.int8),
 ])
 def test_paged_engine_mha_matches_dense(kernels, cache_dtype):
-    """MHA pools (G=1) route through the VPU paged kernel branch
-    (_paged_kernel_mha — no per-head dots); greedy output must match the
-    dense engine. KvH=8 keeps the sublane-alignment gate satisfied."""
+    """MHA pools (G=1) take the same kernel, the head its batch dimension;
+    greedy output must match the dense engine."""
     mha_cfg = dataclasses.replace(BASE, n_heads=8, n_kv_heads=8,
                                   kernels=kernels)
     mha_xla = dataclasses.replace(mha_cfg, kernels="xla")
@@ -250,58 +249,78 @@ def test_paged_engine_mha_matches_dense(kernels, cache_dtype):
 
 
 # ---------------------------------------------------------------------------
-# v3 live-page async-DMA kernel (VERDICT r3 next-step #1)
+# the paged decode kernel (ops/pallas/paged.py) against gather + einsum
 # ---------------------------------------------------------------------------
 
-def _rand_pool(key, L, P, KvH, ps, hd, quant):
-    k1, k2 = jax.random.split(key)
-    kf = jax.random.normal(k1, (L, P, KvH, ps, hd), jnp.float32)
-    vf = jax.random.normal(k2, (L, P, KvH, ps, hd), jnp.float32)
-    if not quant:
-        return kf, vf
+def _rand_pool(key, L, P, KvH, ps, hd, quant, hd_pool=None, sp=None):
+    """A k/v pool pair as the engine lays them out: ``quant`` False (float32
+    pages), True / 8 ({"q","s"} int8 codes) or 4 ({"q4","s"} nibble-packed).
+    ``hd_pool`` pads the head dim with zero lanes and ``sp`` the scale
+    pools' lanes, as the engine pads both to 128."""
     from ollama_operator_tpu.ops import quant_cache as QC
 
-    def q(pool):
-        qq, ss = QC.quantize_kv(pool)     # per-position scales [...,ps]
-        return {"q": qq, "s": ss}
-    return q(kf), q(vf)
+    def pad(x, axis_len):
+        d = axis_len - x.shape[-1]
+        return x if d <= 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, d)])
+
+    def one(k):
+        f = jax.random.normal(k, (L, P, KvH, ps, hd), jnp.float32)
+        if not quant:
+            return pad(f, hd_pool or hd)
+        if quant == 4:
+            codes, ss = QC.quantize_kv4(f)
+            return {"q4": QC.pack_kv4(pad(codes, hd_pool or hd)),
+                    "s": pad(ss, sp or ps)}
+        codes, ss = QC.quantize_kv(f)         # per-position scales [...,ps]
+        return {"q": pad(codes, hd_pool or hd), "s": pad(ss, sp or ps)}
+    k1, k2 = jax.random.split(key)
+    return one(k1), one(k2)
+
+
+def _gather_einsum(q, kp, vp, layer, tables, lengths, scale, nblk,
+                   window=0, cfg=XLA):
+    """The reference: the pages gathered into a contiguous view and the
+    masked einsum over it (``_gather_pages`` + ``attend_hf`` /
+    ``attend_hf_q`` / ``attend_hf_q4``), as ``_paged_attend`` serves a step
+    the kernel does not take."""
+    arr = (kp.get("q4", kp.get("q")) if isinstance(kp, dict) else kp)
+    ps = arr.shape[3] * (2 if isinstance(kp, dict) and "q4" in kp else 1)
+    k_pos = jnp.arange(nblk * ps, dtype=jnp.int32)[None, None, :]
+    mask = decoder._causal_window_mask(k_pos, lengths[:, None, None], window)
+    return decoder._paged_attend(cfg, q, kp, vp, layer[0], tables, lengths,
+                                 mask, scale, nblk, None, False)
+
+
+def _mixed_batch(B=4):
+    tables = jnp.asarray(
+        np.random.default_rng(0).permutation(np.arange(1, 2 * B + 1))
+        .reshape(B, 2), jnp.int32)
+    return tables, jnp.asarray([1], jnp.int32)
 
 
 @pytest.mark.parametrize("quant", [False, True])
 @pytest.mark.parametrize("kvh,h", [(2, 8), (4, 4)])   # GQA and MHA
-def test_paged_v3_matches_v2_direct(quant, kvh, h, monkeypatch):
+def test_paged_v3_matches_v2_direct(quant, kvh, h):
     """Kernel-level parity: the dynamic live-page walk + KvH-batched dots
-    must reproduce the v2 grid kernel bit-for-bit-ish on mixed lengths,
-    both pool dtypes, GQA and MHA."""
-    from ollama_operator_tpu.ops.pallas.paged import (
-        paged_decode_attention, paged_decode_attention_v3)
-    # the dispatcher routes to v3/v4 by default — the REFERENCE must be
-    # the v2 grid kernel, not a self-comparison
-    monkeypatch.setenv("TPU_PAGED_V3", "0")
-    monkeypatch.setenv("TPU_PAGED_V4", "0")
+    reproduce gather + einsum on mixed lengths, both pool dtypes, GQA and
+    MHA. (The name is kept from when the reference was the v2 grid kernel,
+    so the case keeps its id in the driver's count.)"""
+    from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention
     L, P, ps, hd, B = 2, 9, 8, 128, 4
-    key = jax.random.key(0)
-    kp, vp = _rand_pool(key, L, P, kvh, ps, hd, quant)
+    kp, vp = _rand_pool(jax.random.key(0), L, P, kvh, ps, hd, quant)
     q = jax.random.normal(jax.random.key(1), (B, 1, h, hd), jnp.float32)
-    tables = jnp.asarray(
-        np.random.default_rng(0).permutation(np.arange(1, 9))
-        .reshape(B, 2), jnp.int32)
+    tables, layer = _mixed_batch(B)
     lengths = jnp.asarray([0, 3, 8, 15], jnp.int32)
-    layer = jnp.asarray([1], jnp.int32)
-    ref = paged_decode_attention(q, kp, vp, layer, tables, lengths,
+    ref = _gather_einsum(q, kp, vp, layer, tables, lengths, 0.35, 2)
+    got = paged_decode_attention(q, kp, vp, layer, tables, lengths,
                                  scale=0.35, nblk=2, interpret=True)
-    got = paged_decode_attention_v3(q, kp, vp, layer, tables, lengths,
-                                    scale=0.35, nblk=2, interpret=True)
-    assert ref is not None and got is not None
+    assert got is not None
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_v3_sliding_window_matches_v2(monkeypatch):
-    from ollama_operator_tpu.ops.pallas.paged import (
-        paged_decode_attention, paged_decode_attention_v3)
-    monkeypatch.setenv("TPU_PAGED_V3", "0")
-    monkeypatch.setenv("TPU_PAGED_V4", "0")
+def test_paged_v3_sliding_window_matches_v2():
+    from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention
     L, P, KvH, ps, hd, B, H = 1, 9, 2, 8, 128, 4, 4
     kp, vp = _rand_pool(jax.random.key(2), L, P, KvH, ps, hd, False)
     q = jax.random.normal(jax.random.key(3), (B, 1, H, hd), jnp.float32)
@@ -309,21 +328,19 @@ def test_paged_v3_sliding_window_matches_v2(monkeypatch):
     lengths = jnp.asarray([2, 9, 12, 15], jnp.int32)
     layer = jnp.asarray([0], jnp.int32)
     for win in (4, 11):
-        ref = paged_decode_attention(q, kp, vp, layer, tables, lengths,
+        ref = _gather_einsum(q, kp, vp, layer, tables, lengths, 0.3, 2,
+                             window=win)
+        got = paged_decode_attention(q, kp, vp, layer, tables, lengths,
                                      scale=0.3, sliding_window=win,
                                      nblk=2, interpret=True)
-        got = paged_decode_attention_v3(q, kp, vp, layer, tables, lengths,
-                                        scale=0.3, sliding_window=win,
-                                        nblk=2, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5, err_msg=f"win={win}")
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8])
-def test_paged_v3_engine_matches_dense(params, cache_dtype, monkeypatch):
-    """End-to-end: the engine's greedy decode through the v3 kernel equals
-    the dense-cache reference (same invariant the v2 kernel pins)."""
-    monkeypatch.setenv("TPU_PAGED_V3", "1")
+def test_paged_v3_engine_matches_dense(params, cache_dtype):
+    """End-to-end: the engine's greedy decode through the kernel equals
+    the dense-cache reference."""
     dense = dataclasses.replace(DENSE, cache_dtype=cache_dtype)
     paged = dataclasses.replace(PAGED, cache_dtype=cache_dtype)
     ref = _greedy_run(XLA, dense, params)
@@ -331,73 +348,124 @@ def test_paged_v3_engine_matches_dense(params, cache_dtype, monkeypatch):
     assert got == ref, (got, ref)
 
 
-# ---------------------------------------------------------------------------
-# v4 compacted flat-grid kernel (round 5: the B=32 walk-serialization floor)
-# ---------------------------------------------------------------------------
+# what the cells serve and no case above holds ------------------------------
+
+@pytest.mark.parametrize("kvh,h", [(2, 8), (4, 4)])   # GQA and MHA
+def test_paged_kernel_nibble_packed_pool_matches_reference(kvh, h):
+    """A {"q4","s"} pool: pages land packed two positions a byte and
+    unpack after the copy; same answers as the unpacked einsum."""
+    from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention
+    L, P, ps, hd, B = 2, 9, 16, 128, 4
+    kp, vp = _rand_pool(jax.random.key(4), L, P, kvh, ps, hd, 4)
+    assert kp["q4"].shape == (L, P, kvh, ps // 2, hd)
+    q = jax.random.normal(jax.random.key(5), (B, 1, h, hd), jnp.float32)
+    tables, layer = _mixed_batch(B)
+    lengths = jnp.asarray([0, 7, 16, 31], jnp.int32)
+    ref = _gather_einsum(q, kp, vp, layer, tables, lengths, 0.35, 2)
+    got = paged_decode_attention(q, kp, vp, layer, tables, lengths,
+                                 scale=0.35, nblk=2, interpret=True)
+    assert got is not None
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
 
 @pytest.mark.parametrize("quant", [False, True])
-@pytest.mark.parametrize("kvh,h", [(2, 8), (4, 4)])   # GQA and MHA
-def test_paged_v4_matches_v2_direct(quant, kvh, h, monkeypatch):
-    """Kernel-level parity for the flat-grid formulation: the slot-sorted
-    live-page list (cumsum + searchsorted construction, dead tail frozen)
-    must reproduce the v2 grid kernel on mixed lengths, both pool dtypes,
-    GQA and MHA."""
-    from ollama_operator_tpu.ops.pallas.paged import (
-        paged_decode_attention, paged_decode_attention_v4)
-    # pin the reference to the v2 grid kernel (the dispatcher would
-    # otherwise hand back v3 — or v4 itself under TPU_PAGED_V4=1)
-    monkeypatch.setenv("TPU_PAGED_V3", "0")
-    monkeypatch.setenv("TPU_PAGED_V4", "0")
-    L, P, ps, hd, B = 2, 9, 8, 128, 4
-    key = jax.random.key(0)
-    kp, vp = _rand_pool(key, L, P, kvh, ps, hd, quant)
-    q = jax.random.normal(jax.random.key(1), (B, 1, h, hd), jnp.float32)
-    tables = jnp.asarray(
-        np.random.default_rng(0).permutation(np.arange(1, 9))
-        .reshape(B, 2), jnp.int32)
+def test_paged_kernel_head_dim_80_on_a_128_lane_pool(quant):
+    """phi-2's geometry: MHA queries of head_dim 80 over a pool whose head
+    dim (and scale lanes) the engine padded to 128. The kernel pads q with
+    zero lanes and slices them back off, as the reference does."""
+    from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention
+    L, P, KvH, ps, hd, B = 2, 9, 4, 8, 80, 4
+    kp, vp = _rand_pool(jax.random.key(6), L, P, KvH, ps, hd, quant,
+                        hd_pool=128, sp=128)
+    q = jax.random.normal(jax.random.key(7), (B, 1, KvH, hd), jnp.float32)
+    tables, layer = _mixed_batch(B)
     lengths = jnp.asarray([0, 3, 8, 15], jnp.int32)
-    layer = jnp.asarray([1], jnp.int32)
-    ref = paged_decode_attention(q, kp, vp, layer, tables, lengths,
-                                 scale=0.35, nblk=2, interpret=True)
-    got = paged_decode_attention_v4(q, kp, vp, layer, tables, lengths,
-                                    scale=0.35, nblk=2, interpret=True)
-    assert ref is not None and got is not None
+    ref = _gather_einsum(q, kp, vp, layer, tables, lengths, 0.11, 2)
+    got = paged_decode_attention(q, kp, vp, layer, tables, lengths,
+                                 scale=0.11, nblk=2, interpret=True)
+    assert got is not None and got.shape == (B, 1, KvH, hd)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_v4_sliding_window_matches_v2(monkeypatch):
-    from ollama_operator_tpu.ops.pallas.paged import (
-        paged_decode_attention, paged_decode_attention_v4)
-    monkeypatch.setenv("TPU_PAGED_V3", "0")
-    monkeypatch.setenv("TPU_PAGED_V4", "0")
-    L, P, KvH, ps, hd, B, H = 1, 9, 2, 8, 128, 4, 4
-    kp, vp = _rand_pool(jax.random.key(2), L, P, KvH, ps, hd, False)
-    q = jax.random.normal(jax.random.key(3), (B, 1, H, hd), jnp.float32)
-    tables = jnp.asarray(np.arange(1, 9).reshape(B, 2), jnp.int32)
-    lengths = jnp.asarray([2, 9, 12, 15], jnp.int32)
+def test_paged_kernel_window_over_an_int8_pool():
+    """starcoder2's geometry in small: a sliding window over quantized
+    pages, the walk starting at the window's first page."""
+    from ollama_operator_tpu.ops.pallas.paged import paged_decode_attention
+    L, P, KvH, ps, hd, B, H = 1, 13, 2, 8, 128, 4, 8
+    kp, vp = _rand_pool(jax.random.key(8), L, P, KvH, ps, hd, True, sp=128)
+    q = jax.random.normal(jax.random.key(9), (B, 1, H, hd), jnp.float32)
+    tables = jnp.asarray(np.arange(1, 13).reshape(B, 3), jnp.int32)
+    lengths = jnp.asarray([2, 9, 17, 23], jnp.int32)
     layer = jnp.asarray([0], jnp.int32)
     for win in (4, 11):
-        ref = paged_decode_attention(q, kp, vp, layer, tables, lengths,
+        ref = _gather_einsum(q, kp, vp, layer, tables, lengths, 0.3, 3,
+                             window=win)
+        got = paged_decode_attention(q, kp, vp, layer, tables, lengths,
                                      scale=0.3, sliding_window=win,
-                                     nblk=2, interpret=True)
-        got = paged_decode_attention_v4(q, kp, vp, layer, tables, lengths,
-                                        scale=0.3, sliding_window=win,
-                                        nblk=2, interpret=True)
+                                     nblk=3, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5, err_msg=f"win={win}")
 
 
-@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.int8])
-def test_paged_v4_engine_matches_dense(params, cache_dtype, monkeypatch):
-    """End-to-end: the engine's greedy decode through the v4 kernel equals
-    the dense-cache reference (same invariant v2/v3 pin)."""
-    monkeypatch.setenv("TPU_PAGED_V4", "1")
-    dense = dataclasses.replace(DENSE, cache_dtype=cache_dtype)
-    paged = dataclasses.replace(PAGED, cache_dtype=cache_dtype)
-    ref = _greedy_run(XLA, dense, params)
-    got = _greedy_run(INTERP, paged, params)
-    assert got == ref, (got, ref)
+# shapes Mosaic's copies cannot take, as a chip would meet them (the mode is
+# "pallas": the refusal comes before anything is lowered, so this runs here)
+_REFUSED = {
+    # int8 pages whose scale lanes nobody padded to 128 (a hand-built pool)
+    "unpadded-scale-lanes": dict(quant=True, ps=64, sp=None),
+    # nibble-packed pages under 64 tokens: 16 stored rows, under int8's 32
+    "int4-32-token-pages": dict(quant=4, ps=32, sp=128),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_REFUSED))
+def test_refused_shape_is_served_by_gather_einsum_and_flagged(shape):
+    from ollama_operator_tpu.ops.attention import record_kernels
+    from ollama_operator_tpu.ops.pallas.paged import (
+        paged_decode_attention, paged_decode_tileable)
+    spec = _REFUSED[shape]
+    cfg = dataclasses.replace(BASE, kernels="pallas", n_heads=8,
+                              n_kv_heads=2)
+    L, P, KvH, ps, hd, B, H = 1, 9, 2, spec["ps"], 128, 4, 8
+    kp, vp = _rand_pool(jax.random.key(10), L, P, KvH, ps, hd,
+                        spec["quant"], sp=spec["sp"])
+    q = jax.random.normal(jax.random.key(11), (B, 1, H, hd), jnp.float32)
+    tables, layer = _mixed_batch(B)
+    lengths = jnp.asarray([0, ps - 1, ps, 2 * ps - 1], jnp.int32)
+    assert not paged_decode_tileable(H, kp, False)
+    assert paged_decode_tileable(H, kp, True)      # the interpreter takes it
+    assert paged_decode_attention(q, kp, vp, layer, tables, lengths,
+                                  scale=0.3, nblk=2) is None
+    ref = _gather_einsum(q, kp, vp, layer, tables, lengths, 0.3, 2)
+    k_pos = jnp.arange(2 * ps, dtype=jnp.int32)[None, None, :]
+    mask = decoder._causal_window_mask(k_pos, lengths[:, None, None], 0)
+    with record_kernels() as picked:
+        # the route as forward_with_cache_paged takes it ...
+        assert not decoder._paged_kernel_usable(cfg, None, 1, kp)
+        # ... and the net under a caller that asked for the kernel anyway
+        got = decoder._paged_attend(cfg, q, kp, vp, layer[0], tables,
+                                    lengths, mask, 0.3, 2, None, True)
+    assert picked == [("paged_decode", "gather_einsum", True)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_engine_reports_the_fallback_for_pages_the_kernel_refuses(params):
+    """4-token pages (not a sublane multiple) under a kernel mode: the
+    engine serves the same tokens through gather + einsum, and says so in
+    ``kernels_by_kind()`` and a ``kernel_fallback`` event."""
+    from ollama_operator_tpu.runtime.trace import FLIGHT
+    small = dataclasses.replace(PAGED, page_size=4)
+    eng = Engine(INTERP, params, ecfg=small)
+    toks = [eng.admit(0, PROMPT, GREEDY)]
+    toks += [int(x) for x in eng.decode_n(4)[:, 0]]
+    assert "paged_decode=gather_einsum" in eng.kernels_by_kind()["decode"]
+    assert any(e["kind"] == "kernel_fallback" and e.get("site") ==
+               "paged_decode" for e in FLIGHT.snapshot())
+    ref_eng = Engine(XLA, params, ecfg=small)
+    ref = [ref_eng.admit(0, PROMPT, GREEDY)]
+    ref += [int(x) for x in ref_eng.decode_n(4)[:, 0]]
+    assert toks == ref
 
 
 @pytest.mark.chaos

@@ -1,9 +1,11 @@
-"""TPU_PAGED_FUSED A/B: the fused paged-attention pallas kernels
-(interpret mode on CPU) against the gather+einsum reference path the
-knob re-enables, bit-for-bit at the token level — greedy and seeded,
-cold and with a radix stitch, across attention tail buckets — plus the
-int4 nibble-packed KV pool riding the same A/B (both arms share one
-codec, so the reference path stays a parity oracle for the lossy dtype).
+"""The paged-attention pallas kernel (interpret mode on CPU) against the
+gather+einsum path with everything else equal, bit-for-bit at the token
+level — greedy and seeded, cold and with a radix stitch, across attention
+tail buckets — plus the int4 nibble-packed KV pool riding the same A/B
+(both arms share one codec, so gather+einsum stays a parity oracle for
+the lossy dtype). The reference arm is reached by substituting the one
+function that chooses the route (``decoder._paged_kernel_usable``): no
+variable selects it.
 """
 
 import dataclasses
@@ -35,11 +37,26 @@ def params():
     return decoder.init_params(BASE, jax.random.key(0), jnp.float32)
 
 
-def _arm(params, monkeypatch, fused, cache_dtype, warm):
+def _never_the_kernel(cfg, mesh, T, k_pool):
+    """``_paged_kernel_usable`` answering no, and recording the route as
+    the real one does."""
+    from ollama_operator_tpu.ops.attention import note_kernel
+    note_kernel("paged_decode" if T == 1 else "paged_extend",
+                "gather_einsum")
+    return False
+
+
+def _arm(params, monkeypatch, fused, cache_dtype, warm, kinds=None):
     """One serving arm. Probes land in different attention tail buckets
     (8-token prompt → 16 bucket, 24-token radix prefix → 32 bucket) and
     the 8-token budgets walk generation across a bucket boundary."""
-    monkeypatch.setenv("TPU_PAGED_FUSED", "1" if fused else "0")
+    with monkeypatch.context() as mp:
+        if not fused:
+            mp.setattr(decoder, "_paged_kernel_usable", _never_the_kernel)
+        return _serve(params, cache_dtype, warm, kinds)
+
+
+def _serve(params, cache_dtype, warm, kinds):
     ecfg = dataclasses.replace(PAGED, cache_dtype=cache_dtype)
     eng = Engine(INTERP, params, ecfg=ecfg)
     sched = Scheduler(eng)
@@ -61,6 +78,8 @@ def _arm(params, monkeypatch, fused, cache_dtype, warm):
             assert r.error is None
         if warm:
             assert any(r.stats.n_reused >= 16 for r in reqs)
+        if kinds is not None:
+            kinds.update(eng.kernels_by_kind())
         return outs
     finally:
         sched.shutdown()
@@ -77,15 +96,16 @@ def test_fused_streams_match_reference(params, monkeypatch, cache_dtype,
 
 
 def test_fused_knob_routes_the_kernel(params, monkeypatch):
-    """The env knob actually flips the route (guards a future refactor
-    that would compare the fused path against itself)."""
-    from ollama_operator_tpu.models.decoder import _paged_kernel_usable
-    monkeypatch.setenv("TPU_PAGED_FUSED", "1")
-    assert _paged_kernel_usable(INTERP, None, 1, INTERP.n_kv_heads, 8,
-                                INTERP.head_dim)
-    monkeypatch.setenv("TPU_PAGED_FUSED", "0")
-    assert not _paged_kernel_usable(INTERP, None, 1, INTERP.n_kv_heads, 8,
-                                    INTERP.head_dim)
+    """The substitution actually flips the route (guards a refactor that
+    would compare the kernel's path against itself): each arm's engine
+    says which attention its decode programs traced."""
+    on, off = {}, {}
+    _arm(params, monkeypatch, True, jnp.float32, False, kinds=on)
+    _arm(params, monkeypatch, False, jnp.float32, False, kinds=off)
+    assert "paged_decode=paged_v3" in on["decode"]
+    assert "paged_decode=gather_einsum" not in on["decode"]
+    assert "paged_decode=gather_einsum" in off["decode"]
+    assert "paged_decode=paged_v3" not in off["decode"]
 
 
 # --- int4 KV pool ------------------------------------------------------------

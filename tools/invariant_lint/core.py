@@ -106,6 +106,12 @@ class LintConfig:
     knobs_module: str = "ollama_operator_tpu/runtime/knobs.py"
     docs_roots: Tuple[str, ...] = ("docs/en", "docs/zh-CN")
     knob_prefix: str = "TPU_"
+    # trace-env: packages jax.jit traces, and the (module, function)
+    # pairs that alone may read the environment there
+    trace_env_scopes: Tuple[str, ...] = ("ollama_operator_tpu/ops",
+                                         "ollama_operator_tpu/models")
+    trace_env_resolvers: Tuple[Tuple[str, str], ...] = (
+        ("ollama_operator_tpu/ops/attention.py", "_kernels_override"),)
     # metric registry module holding describe() + pre-seed calls
     metrics_module: str = "ollama_operator_tpu/server/metrics.py"
     metric_prefix: str = "tpu_model_"

@@ -12,9 +12,11 @@ from .host_sync import HostSyncPass
 from .knob_registry import KnobRegistryPass
 from .lock_order import LockOrderPass
 from .metrics_discipline import MetricsDisciplinePass
+from .trace_env import TraceEnvPass
 
 ALL_PASSES = [
     KnobRegistryPass(),
+    TraceEnvPass(),
     MetricsDisciplinePass(),
     FaultCatalogPass(),
     HostSyncPass(),
@@ -27,4 +29,4 @@ ALL_PASSES = [
 __all__ = ["ALL_PASSES", "KnobRegistryPass", "MetricsDisciplinePass",
            "FaultCatalogPass", "HostSyncPass", "LockOrderPass",
            "FollowerPurityPass", "DeterminismPass",
-           "ExceptionHygienePass"]
+           "ExceptionHygienePass", "TraceEnvPass"]
