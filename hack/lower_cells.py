@@ -16,11 +16,16 @@ Texts that differ only inside the serialized Mosaic bodies of the
 ``hack/cmp_lowered.py`` parses both bodies and compares them printed
 without locations. The builder's check before a chip run, not a golden
 file. Names after the two directories choose the configurations (default:
-the two dense cells; ``granite-4.0-h-small`` lowers too)."""
+the two dense cells; ``granite-4.0-h-small`` lowers too). ``--stub-sample``
+among them lowers every program with ``ops/sampling.sample`` replaced by
+an argmax: where two trees' texts are equal under it, they differ in the
+sampler alone."""
 import os
 import sys
 repo, out = sys.argv[1], sys.argv[2]
-names = sys.argv[3:] or ["starcoder2-3b", "phi-2"]
+stub = "--stub-sample" in sys.argv[3:]
+names = [a for a in sys.argv[3:] if a != "--stub-sample"] or [
+    "starcoder2-3b", "phi-2"]
 sys.path.insert(0, repo)
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.makedirs(out, exist_ok=True)
@@ -36,6 +41,11 @@ def spy(self, kind, key, jit_fn, *args):
     texts[f"{kind}.{key}"] = t
     return None
 E.Engine._compile = spy
+if stub:
+    def _argmax(logits, counts, sp, key, mu=None, **_kw):
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return tok if mu is None else (tok, mu)
+    E.sampling.sample = _argmax
 
 for name in names:
     texts.clear()
@@ -52,6 +62,8 @@ for name in names:
     eng._admit_exec(256)
     eng._admit_many_exec(2, 128)
     eng._extend_exec(128, 512)
+    if not eng.recurrent:
+        eng._spec_exec(4, 512)
     for k, t in texts.items():
         open(os.path.join(out, f"{name}.{k}.txt"), "w").write(t)
         print(" ", k, len(t), flush=True)

@@ -82,8 +82,24 @@ _LN2 = 0.6931471805599453
 _MIROSTAT_M = 100   # v1's zipf-fit window (llama.cpp default)
 
 
+def needs_candidates(temperature, live=None):
+    """Whether a step has to build the candidate set: some slot whose
+    token the caller keeps (``live`` [B], nonzero or True; every slot where
+    omitted) is not greedy, by the very test the candidate path ends in
+    (``temperature <= 0``, so a NaN samples as it always did). The one
+    predicate of ``sample``'s branch and, over the host's numpy mirror of
+    the same two arrays, of the engine's
+    ``tpu_model_decode_steps_total{sampler=...}`` count, so the two cannot
+    drift. A vacant slot carries the default options (temperature 0.8),
+    hence ``live``."""
+    hot = ~(temperature <= 0.0)
+    if live is not None:
+        hot = hot & (live != 0)
+    return hot.any()
+
+
 def sample(logits, token_counts, sp: SamplingParams, key, mu=None,
-           n_candidates: int = N_CANDIDATES):
+           live=None):
     """logits [B, V] f32 → tokens [B] i32, or (tokens, mu') when ``mu``
     ([B] f32, the mirostat surprise-budget state) is given.
 
@@ -97,21 +113,53 @@ def sample(logits, token_counts, sp: SamplingParams, key, mu=None,
     PRNG key (shared across the batch) or a [B] array of per-slot keys
     (each request carries its own seed, per the Ollama API `seed` option).
 
-    The filters run in a compressed top-``n_candidates`` space: ONE
+    A step none of whose ``live`` slots samples (``needs_candidates``)
+    takes the argmax of the penalised logits and skips the candidate path:
+    that path ends in ``where(temperature <= 0, argmax, ...)`` and leaves
+    a greedy slot's ``mu`` alone, so the result is the same bits. The
+    choice is a ``lax.cond`` on the device, not a program key; under
+    ``vmap`` it would turn into a select and run both sides, so batch
+    through the leading axis instead. What a non-live slot gets back is
+    unspecified (its caller masks it).
+    """
+    logits = apply_penalties(logits, token_counts, sp)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # module-level branches over explicit operands: an eager caller's
+    # next call finds them traced
+    return jax.lax.cond(needs_candidates(sp.temperature, live),
+                        _candidates, _argmax_only,
+                        logits, greedy, sp, key, mu)
+
+
+def sample_candidates(logits, token_counts, sp: SamplingParams, key,
+                      mu=None):
+    """``sample`` with the candidate path taken whatever the batch holds:
+    the reference the tests hold ``sample``'s two branches to."""
+    logits = apply_penalties(logits, token_counts, sp)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return _candidates(logits, greedy, sp, key, mu)
+
+
+def _argmax_only(logits, greedy, sp, key, mu):
+    return greedy if mu is None else (greedy, mu)
+
+
+def _candidates(logits, greedy, sp: SamplingParams, key, mu):
+    """The sampled path over penalised ``logits`` [B, V] (``greedy`` is
+    their argmax, which temperature <= 0 slots keep).
+
+    The filters run in a compressed top-``N_CANDIDATES`` space: ONE
     ``lax.top_k`` replaces the full [B, V] sorts the masks would
     otherwise need (a large share of the decode step at 50k+ vocabs), and
     since candidates come out sorted the top-p cumsum needs no further
     sort (typical_p re-orders by entropy deviation — its argsort runs
     over [B, C], not [B, V]). ``top_k`` is effectively capped at
-    n_candidates, and top-p/typical mass beyond the top-1024 logits is
+    N_CANDIDATES, and top-p/typical mass beyond the top-1024 logits is
     treated as zero — both far outside any practical sampling
     configuration (Ollama defaults: top_k=40).
     """
-    logits = apply_penalties(logits, token_counts, sp)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     B, V = logits.shape
-    C = min(V, n_candidates)
+    C = min(V, N_CANDIDATES)
     vals, cand = jax.lax.top_k(logits, C)           # [B, C], sorted desc
     t = jnp.maximum(sp.temperature, 1e-6)[:, None]
     scaled = vals / t

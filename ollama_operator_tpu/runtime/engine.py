@@ -360,11 +360,14 @@ class DecodeHandle:
     never wait) keep bit-identical host lengths."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "budgets",
-                 "accepted", "t_done")
+                 "accepted", "t_done", "sampler")
 
     def __init__(self, engine: "Engine", toks, t0: float, epoch: int = 0,
-                 budgets: Optional[np.ndarray] = None):
+                 budgets: Optional[np.ndarray] = None,
+                 sampler: str = "argmax"):
         self._engine = engine
+        # the costlier sampler any of its steps took (_count_sampler_steps)
+        self.sampler = sampler
         self._toks = toks
         self._t0 = t0
         self._out: Optional[np.ndarray] = None
@@ -1057,7 +1060,7 @@ class Engine:
                 last = jnp.where((constrained == 1)[:, None] & ~allowed,
                                  sampling.NEG_INF, last)
                 toks, mu_new = sampling.sample(last, counts, sp, step_keys,
-                                               mu)
+                                               mu, live=active)
                 # advance the device automaton by the sampled token; a -1
                 # transition (walk left the precomputed table) escapes to -2
                 ns = gtrans[gi, toks]
@@ -1187,7 +1190,8 @@ class Engine:
                 l0 = jnp.where((constrained == 1)[:, None] & ~allowed,
                                sampling.NEG_INF, l0)
                 sampled0, mu_new = sampling.sample(l0, counts, sp,
-                                                   step_keys, mu)
+                                                   step_keys, mu,
+                                                   live=active)
             # greedy (accepting) slots never run mirostat; only the
             # sampled path's slots absorb the surprise update
             mu = jnp.where((active == 1) & ~ok, mu_new, mu)
@@ -2136,7 +2140,31 @@ class Engine:
             self._rln_dev, self._gstate, self._gmask_dev,
             self._gtrans_dev, self._tables_dev())
         self._host_lengths[self.active] += 1
+        self._count_sampler_steps(1)
         return self._fetch(toks)
+
+    def _count_sampler_steps(self, n: int,
+                             budgets: Optional[np.ndarray] = None) -> str:
+        """Count a launched dispatch's ``n`` decode steps in
+        ``tpu_model_decode_steps_total{sampler=...}`` by the branch
+        ``sampling.sample`` takes in each: the device's predicate over the
+        host's mirror of its two arrays, the active slots' temperatures
+        and, past the first step, the slots whose budget runs on. (A slot
+        the device grammar froze mid-chunk is the one thing the mirror
+        cannot see.) Returns the first step's label: the costlier, since
+        the later steps' live slots are among the first's."""
+        temps = np.zeros((self.n_slots,), np.float32)
+        for s, o in self._opts.items():
+            temps[s] = o.temperature
+        first = sampling.needs_candidates(temps, self.active)
+        rest = first if budgets is None else sampling.needs_candidates(
+            temps, self.active & (budgets > 1))
+        for steps, needed in ((1, first), (n - 1, rest)):
+            if steps:
+                METRICS.inc("tpu_model_decode_steps_total", float(steps),
+                            '{sampler="%s"}' % (
+                                "candidates" if needed else "argmax"))
+        return "candidates" if first else "argmax"
 
     def _note_compile(self, kind: str, key: Any) -> None:
         """Called from every executable-cache miss. While warm_buckets is
@@ -3131,9 +3159,12 @@ class Engine:
         with span("engine.decode_n") as sp:
             if drafts is not None:
                 # lint: allow(host-sync-hot-path): draft tokens are host ints
-                return self._spec_launch(np.asarray(drafts, np.int32),
-                                         retire, sp.t0)
-            return self._launch(n, retire, sp.t0)
+                handle = self._spec_launch(np.asarray(drafts, np.int32),
+                                           retire, sp.t0)
+            else:
+                handle = self._launch(n, retire, sp.t0)
+            sp.set(sampler=handle.sampler)
+            return handle
 
     def _launch(self, n: Optional[int], retire: Optional[int],
                 t0: float) -> DecodeHandle:
@@ -3160,7 +3191,8 @@ class Engine:
         # epoch untouched, so later frees aren't fenced behind a program
         # that never existed
         epoch = self._pt.advance_epoch() if self.paged else 0
-        return DecodeHandle(self, toks_n, t0, epoch)
+        return DecodeHandle(self, toks_n, t0, epoch,
+                            sampler=self._count_sampler_steps(n, budgets))
 
     def _spec_exec(self, k: int, attn_len: int):
         key = (k, attn_len)
@@ -3250,7 +3282,8 @@ class Engine:
                            np.where(flags == 1, n, 1), 0).astype(np.int32)
         self._host_lengths[self.active] += budgets[self.active]
         epoch = self._pt.advance_epoch() if self.paged else 0
-        return DecodeHandle(self, toks, t0, epoch, budgets=budgets)
+        return DecodeHandle(self, toks, t0, epoch, budgets=budgets,
+                            sampler=self._count_sampler_steps(1))
 
     def spec_ack(self, rollback: np.ndarray) -> None:
         """Reconcile host lengths after a speculative dispatch

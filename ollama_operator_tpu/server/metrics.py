@@ -364,6 +364,13 @@ GLOBAL.describe("tpu_model_padded_tokens_total",
                 "batch slots x steps, prefill bucket positions past the "
                 "prompt chunk, rejected speculative drafts — the waste "
                 "half of the goodput split")
+GLOBAL.describe("tpu_model_decode_steps_total",
+                "Decode steps launched, by the sampler each took on the "
+                "device (sampler=argmax|candidates): argmax where no "
+                "live slot's temperature is above zero, so the step "
+                "skips the top-1024 candidate sort of the vocabulary "
+                "(ops/sampling.needs_candidates, evaluated over the "
+                "host's mirror of the slots active at launch)")
 GLOBAL.describe("tpu_model_model_flops_total",
                 "Analytic model FLOPs issued for active slots (matmul "
                 "terms only, MFU convention of Chowdhery et al.); rate() "
@@ -604,6 +611,9 @@ for _kind in ("decode", "admit", "admit_many", "extend", "spec"):
 for _kind in ("decode", "prefill", "spec"):
     GLOBAL.inc("tpu_model_useful_tokens_total", 0.0, f'{{kind="{_kind}"}}')
     GLOBAL.inc("tpu_model_padded_tokens_total", 0.0, f'{{kind="{_kind}"}}')
+for _sampler in ("argmax", "candidates"):
+    GLOBAL.inc("tpu_model_decode_steps_total", 0.0,
+               f'{{sampler="{_sampler}"}}')
 GLOBAL.inc("tpu_model_model_flops_total", 0.0)
 for _queue in ("waiting", "empty"):
     GLOBAL.inc("tpu_model_slot_vacant_seconds_total", 0.0,

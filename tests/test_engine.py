@@ -476,3 +476,97 @@ def test_mirostat_generation_stays_in_vocab():
     for _ in range(3):
         toks.extend(int(t) for t in eng.decode_n(2)[:, 2])
     assert all(0 <= t < cfg.vocab_size for t in toks)
+
+
+# -- the sampler's branch, seen from the engine (ops/sampling.sample) -------
+
+def _steps(sampler):
+    from ollama_operator_tpu.server.metrics import GLOBAL as METRICS
+    return METRICS.get("tpu_model_decode_steps_total",
+                       '{sampler="%s"}' % sampler)
+
+
+def _counted(fn):
+    """(what ``fn`` returns, argmax steps it counted, candidates steps)."""
+    a0, c0 = _steps("argmax"), _steps("candidates")
+    out = fn()
+    return out, _steps("argmax") - a0, _steps("candidates") - c0
+
+
+def test_greedy_chunk_beside_vacant_slots_is_an_argmax_chunk():
+    """Two greedy requests in a four-slot engine: the vacant slots carry
+    the default options (temperature 0.8) on the device, and still every
+    step of the chunk counts, and computes, as an argmax step: decode_n
+    yields what single steps yield and what the bare decoder yields."""
+    cfg = cfglib.PRESETS["tiny"]
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0), dtype=F32)
+    p0 = np.array([5, 9, 2, 11, 7], np.int32)
+    p2 = np.array([9, 2, 6], np.int32)
+
+    e1 = make_engine(cfg, params)
+    e1.admit(0, p0, GREEDY), e1.admit(2, p2, GREEDY)
+    assert float(np.asarray(e1.sp.temperature)[1]) == \
+        np.float32(SlotOptions().temperature) > 0      # a vacant slot
+    singles, a, c = _counted(
+        lambda: np.stack([e1.decode() for _ in range(6)]))
+    assert (a, c) == (6, 0)
+
+    e2 = make_engine(cfg, params)
+    firsts = [e2.admit(0, p0, GREEDY), e2.admit(2, p2, GREEDY)]
+    handle, a, c = _counted(lambda: e2.decode_n_launch(6))
+    assert (a, c) == (6, 0) and handle.sampler == "argmax"
+    chunk = handle.wait()
+    np.testing.assert_array_equal(chunk[:, [0, 2]], singles[:, [0, 2]])
+    for slot, first, prompt in ((0, firsts[0], p0), (2, firsts[1], p2)):
+        assert [first] + [int(t) for t in chunk[:, slot]] == \
+            greedy_reference(params, cfg, prompt, 7)
+
+
+def test_sampling_slot_takes_the_candidates_and_keeps_its_stream(
+        monkeypatch):
+    """A seeded temperature-0.8 request beside a greedy one: the chunks
+    count under candidates while it lives, both slots' tokens are those
+    of an engine whose every step is forced down the candidate path (the
+    sampler as it was), and once it is released the greedy slot's chunks
+    are argmax chunks again, its stale device options notwithstanding."""
+    from ollama_operator_tpu.ops import sampling
+    cfg = cfglib.PRESETS["tiny"]
+    params = decoder.init_params(cfg, jax.random.PRNGKey(2), dtype=F32)
+    warm = SlotOptions(temperature=0.8, seed=42)
+    prompts = (np.array([3, 1, 4, 1, 5], np.int32),
+               np.array([2, 7, 1, 8], np.int32))
+
+    def run():
+        eng = make_engine(cfg, params)
+        out = [eng.admit(1, prompts[0], warm),
+               eng.admit(3, prompts[1], GREEDY)]
+        chunk, a, c = _counted(lambda: eng.decode_n(5))
+        out += [int(t) for t in chunk[:, [1, 3]].ravel()]
+        mixed = (a, c)
+        eng.release(1)
+        chunk, a, c = _counted(lambda: eng.decode_n(4))
+        return out + [int(t) for t in chunk[:, 3]], mixed, (a, c)
+
+    got, mixed, after = run()
+    assert mixed == (0, 5) and after == (4, 0)
+    monkeypatch.setattr(
+        sampling, "sample",
+        lambda logits, counts, sp, key, mu=None, live=None:
+        sampling.sample_candidates(logits, counts, sp, key, mu))
+    assert run()[0] == got
+
+
+def test_host_masked_sampling_slot_counts_its_one_step():
+    """A host-masked constrained slot has a budget of one step a chunk:
+    where it is the only sampling slot the chunk's first step needs the
+    candidates and the rest, in which it is frozen, do not."""
+    cfg = cfglib.PRESETS["tiny"]
+    params = decoder.init_params(cfg, jax.random.PRNGKey(3), dtype=F32)
+    eng = make_engine(cfg, params)
+    eng.admit(0, np.array([5, 9, 2], np.int32), GREEDY)
+    eng.admit(1, np.array([4, 1, 8], np.int32),
+              SlotOptions(temperature=0.8, seed=7))
+    eng.set_mask(1, np.full((eng.mask_words,), 0xFFFFFFFF, np.uint32))
+    handle, a, c = _counted(lambda: eng.decode_n_launch(4))
+    assert (a, c) == (3, 1) and handle.sampler == "candidates"
+    handle.wait()

@@ -334,7 +334,9 @@ def test_utilization_metric_families_preseeded():
               + [f'tpu_model_padded_tokens_total{{kind="{k}"}}'
                  for k in ("decode", "prefill", "spec")]
               + [f'tpu_model_breakdown_seconds_total{{phase="{p}"}}'
-                 for p in ("dispatch_wait", "host", "idle")])
+                 for p in ("dispatch_wait", "host", "idle")]
+              + [f'tpu_model_decode_steps_total{{sampler="{s}"}}'
+                 for s in ("argmax", "candidates")])
     for s in series:
         assert re.search(rf"^{re.escape(s)} [0-9.]+$", text, re.M), \
             f"{s} not pre-seeded"

@@ -5,6 +5,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ollama_operator_tpu.ops import sampling
 
@@ -198,3 +199,134 @@ def test_merge_options_clamps_invalid_mirostat():
     assert so.mirostat == 0        # llama.cpp: non-1/2 reads as off
     so, _, _ = merge_options({}, {"mirostat": 2, "mirostat_tau": 3.0})
     assert so.mirostat == 2 and so.mirostat_tau == 3.0
+
+
+# -- the argmax branch: a step with no live sampling slot skips the
+# candidate path, and returns what that path would have -------------------
+
+def _batch(B=6, V=3000, seed=0):
+    k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
+    logits = jax.random.normal(k1, (B, V), jnp.float32) * 4.0
+    counts = jax.random.randint(k2, (B, V), 0, 3).astype(jnp.int32) \
+        * (jax.random.uniform(k3, (B, V)) < 0.02)
+    keys = jax.vmap(jax.random.fold_in)(
+        jnp.broadcast_to(jax.random.key(seed + 1), (B,)), jnp.arange(B))
+    mu = jnp.linspace(6.0, 12.0, B).astype(jnp.float32)
+    return logits, counts.astype(jnp.int32), keys, mu
+
+
+def _with(sp, **rows):
+    """``sp`` with single entries overwritten: name={slot: value}."""
+    out = {}
+    for name, per_slot in rows.items():
+        a = getattr(sp, name)
+        for slot, v in per_slot.items():
+            a = a.at[slot].set(v)
+        out[name] = a
+    return dataclasses.replace(sp, **out)
+
+
+def _same_bits(got, want):
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("case", [
+    "no-penalties", "penalties", "grammar-masked-row", "mirostat-1",
+    "mirostat-2", "shared-key"])
+def test_all_greedy_batch_equals_candidate_path(case):
+    logits, counts, keys, mu = _batch()
+    B, V = logits.shape
+    sp = mk_sp(B, temperature=0.0, repeat_penalty=1.0)
+    if case == "penalties":
+        sp = mk_sp(B, temperature=0.0, repeat_penalty=1.3,
+                   presence_penalty=0.4, frequency_penalty=0.2)
+    elif case == "grammar-masked-row":
+        # the engine masks before it calls: all of a row but 5 tokens
+        allowed = jnp.zeros((V,), bool).at[jnp.array([3, 70, 71, 900,
+                                                      2999])].set(True)
+        logits = logits.at[2].set(
+            jnp.where(allowed, logits[2], sampling.NEG_INF))
+    elif case.startswith("mirostat"):
+        sp = _with(sp, mirostat={1: int(case[-1]), 4: int(case[-1])})
+    elif case == "shared-key":
+        keys = jax.random.key(11)
+    got = jax.jit(sampling.sample)(logits, counts, sp, keys, mu)
+    want = jax.jit(sampling.sample_candidates)(logits, counts, sp, keys, mu)
+    _same_bits(got, want)
+    # and the mu-less form
+    np.testing.assert_array_equal(
+        np.asarray(sampling.sample(logits, counts, sp, keys)),
+        np.asarray(want[0]))
+    if case == "grammar-masked-row":
+        assert int(got[0][2]) in (3, 70, 71, 900, 2999)
+
+
+@pytest.mark.parametrize("case", [
+    "one-sampling-slot", "one-mirostat-slot", "live-mask-given",
+    "shared-key"])
+def test_mixed_batch_equals_candidate_path(case):
+    logits, counts, keys, mu = _batch(seed=3)
+    B = logits.shape[0]
+    sp = mk_sp(B, temperature=0.0, repeat_penalty=1.1)
+    sp = _with(sp, temperature={4: 0.8})
+    live = None
+    if case == "one-mirostat-slot":
+        sp = _with(sp, mirostat={4: 2})
+    elif case == "live-mask-given":
+        live = jnp.ones((B,), jnp.int32).at[0].set(0)
+    elif case == "shared-key":
+        keys = jax.random.key(5)
+    got = jax.jit(sampling.sample)(logits, counts, sp, keys, mu, live=live)
+    want = jax.jit(sampling.sample_candidates)(logits, counts, sp, keys, mu)
+    _same_bits(got, want)
+    if case == "one-mirostat-slot":
+        assert float(got[1][4]) != float(mu[4])     # the branch really ran
+
+
+def test_non_live_sampling_slot_does_not_bring_the_candidates_back():
+    """Vacant slots hold the default options (temperature 0.8): beside
+    live greedy slots the step is an argmax step, and the live slots'
+    tokens and mu are the candidate path's."""
+    logits, counts, keys, mu = _batch(seed=5)
+    B = logits.shape[0]
+    sp = _with(mk_sp(B), temperature={0: 0.0, 1: 0.0, 3: 0.0})
+    live = jnp.array([1, 1, 0, 1, 0, 0], jnp.int32)
+    assert not bool(sampling.needs_candidates(sp.temperature, live))
+    assert bool(sampling.needs_candidates(sp.temperature))
+    assert bool(sampling.needs_candidates(
+        sp.temperature, live.at[2].set(1)))      # a live sampling slot
+    assert not bool(sampling.needs_candidates(
+        sp.temperature, jnp.zeros((B,), jnp.int32)))
+    toks, mu2 = jax.jit(sampling.sample)(logits, counts, sp, keys, mu,
+                                         live=live)
+    want_t, want_mu = sampling.sample_candidates(logits, counts, sp, keys,
+                                                 mu)
+    on = np.asarray(live) == 1
+    np.testing.assert_array_equal(np.asarray(toks)[on],
+                                  np.asarray(want_t)[on])
+    np.testing.assert_array_equal(np.asarray(mu2)[on],
+                                  np.asarray(want_mu)[on])
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_needs_candidates_numpy_agrees_with_jnp(seed):
+    rng = np.random.default_rng(seed)
+    for _ in range(25):
+        B = int(rng.integers(1, 65))
+        temps = np.where(rng.random(B) < 0.7, 0.0,
+                         rng.random(B) * 2 - 0.5).astype(np.float32)
+        if rng.random() < 0.2:       # not greedy by the path's own test
+            temps[rng.integers(B)] = np.nan
+        for live in (None, rng.random(B) < rng.random(),
+                     (rng.random(B) < 0.5).astype(np.int32)):
+            host = sampling.needs_candidates(temps, live)
+            dev = sampling.needs_candidates(
+                jnp.asarray(temps),
+                None if live is None else jnp.asarray(live))
+            assert isinstance(host, (bool, np.bool_))
+            assert bool(host) == bool(dev)
+            want = any(not t <= 0 and (live is None or live[i])
+                       for i, t in enumerate(temps))
+            assert bool(host) == want

@@ -407,3 +407,145 @@ def test_moe_scopes_nest_under_mlp():
     text = jax.jit(lambda p, t: decoder.prefill_chunk(p, cfg, t)[0]).lower(
         params, toks).as_text(debug_info=True)
     assert "mlp/moe.route/" in text and "mlp/moe.experts/" in text
+
+
+# -- the candidate sort sits in a branch of the sampler's conditional ------
+
+CELL_CONFIGS = ("starcoder2-3b", "phi-2", "granite-4.0-h-small")
+SORTS = ("chlo.top_k", "stablehlo.sort")
+BRANCHING = ("stablehlo.case", "stablehlo.if")
+
+
+def _cell_decode_module(name):
+    """The 4-step decode program of a benchmark cell's configuration at
+    its rehearsal size, on the kind of engine the cell resolves to (an
+    int8 paged pool; the contiguous int8 cache for the recurrent stack),
+    lowered with locations and never run."""
+    import os
+
+    from benchmark import server_child as sc
+    from ollama_operator_tpu.models import decoder
+    conf = sc.load_conf(os.path.join(os.path.dirname(sc.__file__), "configs",
+                                     name + ".json"), True)
+    cfg = sc.model_config(conf, True)
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jax.numpy.float32)
+    paged = (dict(paged=True, page_size=16, n_pages=None)
+             if not cfg.layer_kinds else {})
+    ecfg = EngineConfig(max_slots=4, max_seq_len=cfg.max_seq_len,
+                        decode_chunk=4, cache_dtype=jax.numpy.int8,
+                        min_prefill_bucket=64, **paged)
+    got = []
+    mp = pytest.MonkeyPatch()
+    mp.setattr(Engine, "_compile",
+               lambda self, kind, key, jit_fn, *args: got.append(
+                   jit_fn.lower(*args).compiler_ir(dialect="stablehlo")))
+    try:
+        eng = Engine(cfg, params, ecfg=ecfg)
+        assert eng.paged == (not eng.recurrent)
+        eng._decode_n_exec(4, eng.max_seq)
+    finally:
+        mp.undo()
+    return got[0]
+
+
+def _reached_outside_branches(module, names):
+    """(every operation of ``names``, those a run reaches without entering
+    a branch of a conditional): an operation counts as inside where an
+    ancestor is a stablehlo.case / if, or where its function is called
+    from inside one only."""
+    from jax._src.lib.mlir import ir
+    found, calls = [], []
+
+    def place(op):
+        """(inside a conditional's branch?, the enclosing function)."""
+        inside, op = False, op.parent
+        while op.name != "func.func":
+            inside = inside or op.name in BRANCHING
+            op = op.parent
+        return inside, ir.StringAttr(op.attributes["sym_name"]).value
+
+    def visit(op):
+        op = op.operation
+        if op.name in names:
+            found.append((op, *place(op)))
+        elif op.name == "func.call":
+            calls.append((ir.FlatSymbolRefAttr(
+                op.attributes["callee"]).value, *place(op)))
+        return ir.WalkResult.ADVANCE
+
+    module.operation.walk(visit)
+    open_funcs, grew = {"main"}, True
+    while grew:
+        grew = False
+        for callee, inside, caller in calls:
+            if (not inside and caller in open_funcs
+                    and callee not in open_funcs):
+                open_funcs.add(callee)
+                grew = True
+    return ([op for op, _i, _f in found],
+            [op for op, inside, fn in found
+             if not inside and fn in open_funcs])
+
+
+@pytest.mark.parametrize("name", CELL_CONFIGS)
+def test_the_candidate_sort_runs_only_inside_the_samplers_branch(name):
+    """Every decode step of a greedy batch used to sort the vocabulary and
+    throw the result away: in each cell's decode program the top-k and the
+    typical-p argsort are reached through a conditional's branch and not
+    from the scan body, and they keep the ``sample`` scope the benchmark's
+    readers bill them to."""
+    sorts, outside = (
+        [op for op in ops if "/ops/sampling.py" in str(op.location)]
+        for ops in _reached_outside_branches(_cell_decode_module(name),
+                                             SORTS))      # not the router's
+    assert {op.name for op in sorts} == set(SORTS)
+    assert not outside, [str(op.location) for op in outside]
+    # (the argsort's own function names its operations from its own root)
+    for op in sorts:
+        assert op.name != "chlo.top_k" or re.match(
+            r'loc\("(.*/)?sample/cond/branch_1_fun/top_k"',
+            str(op.location)), str(op.location)
+
+
+def test_a_sort_outside_the_branch_is_found():
+    """The walker above, on a program that sorts in the scan body as the
+    sampler used to."""
+    def step(carry, x):
+        kept = jax.lax.cond(x.sum() > 0, lambda: jax.lax.top_k(x, 2)[0],
+                            lambda: x[:2])
+        return carry + jax.numpy.sort(x)[0], kept
+
+    module = jax.jit(lambda xs: jax.lax.scan(step, 0.0, xs)).lower(
+        jax.numpy.ones((3, 8))).compiler_ir(dialect="stablehlo")
+    sorts, outside = _reached_outside_branches(module, SORTS)
+    assert sorted(op.name for op in sorts) == sorted(SORTS)
+    assert [op.name for op in outside] == ["stablehlo.sort"]
+
+
+@pytest.mark.parametrize("steps,want", [
+    (None, None),                    # the parent: no such counter
+    ((0, 0), None),                  # no decode step in the window
+    ((960, 0), 100.0), ((96, 32), 75.0), ((0, 64), 0.0)])
+def test_sample_argmax_share_reads_the_engines_own_count(steps, want):
+    """The benchmark's reader over two scrapes of the real registry's
+    text: the window's argmax steps over all its decode steps; nothing,
+    and no raise, where the program has no such counter."""
+    import types
+
+    from benchmark import prom, run
+    from ollama_operator_tpu.server.metrics import Metrics
+    reg = Metrics()
+    reg.inc("tpu_model_generated_tokens_total", 5.0)
+    if steps is not None:
+        reg.inc("tpu_model_decode_steps_total", 7.0, '{sampler="argmax"}')
+        reg.inc("tpu_model_decode_steps_total", 3.0,
+                '{sampler="candidates"}')
+    before = prom.parse(reg.render())
+    for sampler, n in zip(("argmax", "candidates"), steps or ()):
+        reg.inc("tpu_model_decode_steps_total", float(n),
+                '{sampler="%s"}' % sampler)
+    ctx = types.SimpleNamespace(before=before,
+                                after=prom.parse(reg.render()))
+    got = run.layer_reader("sample_argmax_share").read(ctx)
+    assert got == (None if want is None else pytest.approx(want))
