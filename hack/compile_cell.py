@@ -1,0 +1,79 @@
+"""Does a cell fit the chip? Compiles, for a described v5e (no chip; nothing
+runs; weights are shapes only), the programs a benchmark cell's engine builds
+at its zero-config resolution, the program that makes its weights and its
+reference's ``forward_chosen`` / ``forward_rounded``, from the tree given as
+argv[1], and prints each one's ``memory_analysis()``:
+
+    JAX_PLATFORMS=cpu python hack/compile_cell.py /root/repo lfm2-8b-a1b \
+        [decode] [admit] [admit_many] [extend] [ref]
+
+What the TPU compiler refuses it raises here, at no chip time. It counts one
+program at a time, not what else the process keeps on the device (the
+probe's two engines hold a cache each). ~4 min for a 16-layer routed model."""
+import os
+import sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+repo, name = sys.argv[1], sys.argv[2]
+sys.path.insert(0, repo)
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+jax.config.update("jax_enable_compilation_cache", False)
+jax.default_backend = lambda: "tpu"
+from benchmark import server_child as sc
+from ollama_operator_tpu.runtime import engine as E
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = SingleDeviceSharding(topo.devices[0])
+def sds(a):
+    if hasattr(a, "shape") and hasattr(a, "dtype"):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+    return a
+GB = 1e9
+def spy(self, kind, key, jit_fn, *args):
+    args = jax.tree_util.tree_map(sds, args)
+    c = jit_fn.lower(*args).compile()
+    m = c.memory_analysis()
+    print(f"{kind}.{key}: args {m.argument_size_in_bytes/GB:.3f} out {m.output_size_in_bytes/GB:.3f} "
+          f"alias {m.alias_size_in_bytes/GB:.3f} temp {m.temp_size_in_bytes/GB:.3f} "
+          f"peak~ {(m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes)/GB:.3f} GB", flush=True)
+    return None
+E.Engine._compile = spy
+conf = sc.load_conf(os.path.join(repo, "benchmark", "configs", name + ".json"), False)
+cfg = sc.model_config(conf, False)
+dtype, ecfg = sc.resolve(cfg, "tpu", False)
+print(name, dtype, ecfg, flush=True)
+bits = {"int8": 8, "int4": 4}.get(dtype, 0)
+params = jax.eval_shape(sc.weights_program(cfg, bits, jnp.bfloat16, tuple(conf.get("omit_leaves", ()))), jax.random.key(0))
+print("weights GB", sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params)) / GB)
+# the weights program itself
+wp = jax.jit(sc.weights_program(cfg, bits, jnp.bfloat16, ())).lower(
+    jax.ShapeDtypeStruct((), jax.random.key(0, impl="rbg").dtype, sharding=one)).compile()
+m = wp.memory_analysis()
+print("weights_program temp", m.temp_size_in_bytes/GB, "out",
+      m.output_size_in_bytes/GB, flush=True)
+eng = E.Engine(cfg, params, mesh=None, ecfg=ecfg)
+what = sys.argv[3:] or ["decode", "admit", "admit_many", "extend", "ref"]
+if "decode" in what:
+    eng._decode_n_exec(ecfg.decode_chunk, 512)
+    eng._decode_n_exec(ecfg.decode_chunk, 4096)
+if "admit" in what:
+    eng._admit_exec(256)
+    eng._admit_exec(4096)
+if "admit_many" in what:
+    eng._admit_many_exec(4, 256)
+if "extend" in what:
+    eng._extend_exec(256, 4096)
+if "ref" in what:
+    ref = sc.load_reference(conf)
+    T = 257
+    k = conf["num_experts_per_tok"]
+    Lr = conf["num_hidden_layers"] - conf.get("num_dense_layers", 0)
+    p = jax.tree_util.tree_map(sds, params)
+    t = jax.ShapeDtypeStruct((T,), jnp.int32, sharding=one)
+    ch = {"moe.route": jax.ShapeDtypeStruct((Lr, T, k), jnp.int32, sharding=one)}
+    c = jax.jit(lambda p, t, c: ref.forward_chosen(p, conf, t, c)).lower(p, t, ch).compile()
+    m = c.memory_analysis()
+    print(f"reference.forward_chosen: args {m.argument_size_in_bytes/GB:.3f} temp {m.temp_size_in_bytes/GB:.3f} out {m.output_size_in_bytes/GB:.3f}", flush=True)
+    c = jax.jit(lambda p, t: ref.forward_rounded(p, conf, t, jnp.float8_e4m3fn)).lower(p, t).compile()
+    m = c.memory_analysis()
+    print(f"reference.forward_rounded: args {m.argument_size_in_bytes/GB:.3f} temp {m.temp_size_in_bytes/GB:.3f}", flush=True)

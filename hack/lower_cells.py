@@ -16,7 +16,8 @@ Texts that differ only inside the serialized Mosaic bodies of the
 ``hack/cmp_lowered.py`` parses both bodies and compares them printed
 without locations. The builder's check before a chip run, not a golden
 file. Names after the two directories choose the configurations (default:
-the two dense cells; ``granite-4.0-h-small`` lowers too). ``--stub-sample``
+the two dense cells; ``granite-4.0-h-small`` and ``lfm2-8b-a1b`` lower by
+name too, as any file of benchmark/configs does). ``--stub-sample``
 among them lowers every program with ``ops/sampling.sample`` replaced by
 an argmax: where two trees' texts are equal under it, they differ in the
 sampler alone."""

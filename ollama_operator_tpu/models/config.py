@@ -117,9 +117,25 @@ class ModelConfig:
     n_experts_held: int = 0
     expert_first: int = 0
     moe_impl: str = "auto"             # auto|einsum|scan (models/decoder.py)
-    # hybrid stacks (granitemoehybrid): one letter a layer, "m" a Mamba-2
-    # mixer, "A" attention; "" = every layer attends. A string, so the
-    # config stays hashable and arrives whole from JSON.
+    # the router's form (models/decoder._moe_gates). "softmax": the two
+    # forms moe_renorm tells apart. "sigmoid" (lfm2_moe): scores
+    # s = sigmoid(logits) in float32; the kept are the top-k of s + b, b
+    # the router_bias leaf ([E] float32 a layer) where moe_select_bias,
+    # which takes part in the SELECTION only; gates are s of the kept,
+    # divided by their sum + 1e-6 where moe_renorm, times moe_scale
+    moe_score: str = "softmax"
+    moe_select_bias: bool = False
+    moe_scale: float = 1.0             # routed_scaling_factor
+    # leading layers whose feed-forward is one dense gated MLP of width
+    # dense_ffn_dim in a stack whose other layers are routed (lfm2_moe
+    # num_dense_layers / intermediate_size); hybrid stacks only
+    n_dense_layers: int = 0
+    dense_ffn_dim: int = 0
+    # hybrid stacks: one letter a layer, "m" a Mamba-2 mixer
+    # (granitemoehybrid), "c" a gated short convolution (lfm2), "A"
+    # attention; "" = every layer attends. A stack has ONE recurrent kind
+    # beside attention (no published stack mixes "m" and "c"). A string,
+    # so the config stays hashable and arrives whole from JSON.
     layer_kinds: str = ""
     # Mamba-2 mixer sizes (one group of B/C): d_inner = ssm_heads *
     # ssm_head_dim; the state a slot carries is [ssm_heads, ssm_head_dim,
@@ -130,6 +146,11 @@ class ModelConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256               # prefill block (mamba_chunk_size)
+    # gated short convolution ("c"): [B, C, v] = split3(h W_in); u = B * v;
+    # depthwise causal convolution of conv_kernel taps over u, no bias, no
+    # activation; out = (C * conv) W_out. A slot carries the last
+    # conv_kernel - 1 values of u, [conv_kernel - 1, dim] float32 a layer
+    conv_kernel: int = 3               # lfm2 conv_L_cache
     rope: bool = True                  # False = no positional embedding
                                        # (position_embedding_type "nope")
     kernels: str = "auto"              # attention impl: auto|pallas|xla|interpret
@@ -161,6 +182,14 @@ class ModelConfig:
         return self.layer_kinds.count("m")
 
     @property
+    def n_conv_layers(self) -> int:
+        return self.layer_kinds.count("c")
+
+    @property
+    def n_routed_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.n_experts else 0
+
+    @property
     def n_attn_layers(self) -> int:
         return (self.layer_kinds.count("A") if self.layer_kinds
                 else self.n_layers)
@@ -176,10 +205,13 @@ class ModelConfig:
 
     @property
     def ssm_state_bytes(self) -> int:
-        """Recurrent state one sequence carries, all layers, float32."""
-        return 4 * self.n_ssm_layers * (
+        """Recurrent state one sequence carries, all layers, float32: a
+        Mamba layer's state and convolution inputs, a short convolution's
+        inputs alone."""
+        return 4 * (self.n_ssm_layers * (
             self.ssm_inner * self.ssm_state
             + (self.ssm_conv - 1) * self.ssm_conv_dim)
+            + self.n_conv_layers * (self.conv_kernel - 1) * self.dim)
 
     @property
     def rotary_dim(self) -> int:
@@ -199,8 +231,12 @@ class ModelConfig:
         if self.layer_kinds:
             ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_state
                         + self.ssm_heads) + self.ssm_inner * d)
+            conv = d * 3 * d + d * d
+            dense = 3 * d * self.dense_ffn_dim
             return (self.n_attn_layers * attn + self.n_ssm_layers * ssm
-                    + l * mlp + emb)
+                    + self.n_conv_layers * conv
+                    + self.n_dense_layers * dense
+                    + (l - self.n_dense_layers) * mlp + emb)
         return l * (attn + mlp) + emb
 
     def validate(self) -> "ModelConfig":
@@ -225,6 +261,11 @@ class ModelConfig:
         assert self.kernels in ("auto", "pallas", "xla", "interpret")
         assert self.mm_kernels in ("auto", "pallas", "xla", "interpret")
         assert self.moe_impl in ("auto", "einsum", "scan")
+        assert self.moe_score in ("softmax", "sigmoid")
+        if self.moe_select_bias or self.moe_scale != 1.0:
+            assert self.moe_score == "sigmoid", (
+                "a selection bias and a scaling factor belong to the "
+                "sigmoid router")
         if self.n_experts:
             assert self.mlp_type == "gated", "MoE is gated-MLP only"
             assert 0 < self.n_experts_used <= self.n_experts
@@ -235,12 +276,23 @@ class ModelConfig:
             assert len(self.layer_kinds) == self.n_layers, (
                 f"layer_kinds names {len(self.layer_kinds)} layers, "
                 f"n_layers is {self.n_layers}")
-            assert set(self.layer_kinds) <= {"m", "A"}, self.layer_kinds
+            assert set(self.layer_kinds) <= {"m", "c", "A"}, self.layer_kinds
             assert "A" in self.layer_kinds, "no attention layer to cache"
-            assert self.ssm_heads > 0 and self.ssm_conv >= 2
+            assert not ("m" in self.layer_kinds and "c" in self.layer_kinds), (
+                "one recurrent kind a stack")
+            if "m" in self.layer_kinds:
+                assert self.ssm_heads > 0 and self.ssm_conv >= 2
+            if "c" in self.layer_kinds:
+                assert self.conv_kernel >= 2
             assert not (self.parallel_block or self.post_norms
                         or self.altern_sliding or self.sliding_window), (
                 "hybrid stacks run the plain pre-norm block")
+        if self.n_dense_layers:
+            assert self.layer_kinds and self.n_experts, (
+                "leading dense layers stand before a hybrid stack's "
+                "routed ones")
+            assert 0 < self.n_dense_layers < self.n_layers
+            assert self.dense_ffn_dim > 0
         if self.rope_local_theta:
             assert self.altern_sliding, (
                 "rope_local_theta pairs with per-layer (altern_sliding) "
@@ -423,6 +475,33 @@ PRESETS = {
         ssm_chunk=16, rope=False, emb_multiplier=12.0,
         residual_multiplier=0.22, logit_scale=16.0,
         attn_scale_mult=0.0625, tie_embeddings=True, max_seq_len=256),
+    # LFM2-8B-A1B (lfm2_moe), cut in DEPTH alone: the first four whole
+    # periods of the 24 layers, c c A c c c A c c c A c c c A c (layers
+    # 0-15: both leading dense layers, 14 of the 22 routed ones; layers
+    # 16-23 would lie on a second chip as a pipeline stage). Every width,
+    # all 32 experts and the whole vocabulary are the published ones:
+    # hidden 2048, short convolution of 3 taps, GQA 32/8 at head_dim 64
+    # with q/k norms before rotary at theta 1e6, dense width 7168, expert
+    # width 1792, sigmoid router 32 / 4 a token with a selection bias.
+    # benchmark/configs/lfm2-8b-a1b.json states the cut.
+    "lfm2-8b-a1b": _mk(
+        arch="lfm2moe", vocab_size=65536, dim=2048, n_layers=16,
+        n_heads=32, n_kv_heads=8, head_dim=64, ffn_dim=1792,
+        n_experts=32, n_experts_used=4, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=1.0,
+        n_dense_layers=2, dense_ffn_dim=7168,
+        layer_kinds="ccAcccAcccAcccAc", conv_kernel=3, qk_norm=True,
+        rope_theta=1000000.0, tie_embeddings=True, norm_eps=1e-5,
+        max_seq_len=128000),
+    # the same shape at toy widths (tests, --rehearse): two periods
+    "tiny-lfm2": _mk(
+        arch="lfm2moe", vocab_size=256, dim=64, n_layers=8, n_heads=4,
+        n_kv_heads=2, head_dim=16, ffn_dim=32, n_experts=8,
+        n_experts_used=3, moe_score="sigmoid", moe_select_bias=True,
+        moe_renorm=True, moe_scale=1.0, n_dense_layers=2,
+        dense_ffn_dim=96, layer_kinds="ccAcccAc", conv_kernel=3,
+        qk_norm=True, rope_theta=1000000.0, tie_embeddings=True,
+        max_seq_len=256),
     "dolphin-mixtral": _mk(arch="llama", vocab_size=32002, dim=4096,
                            n_layers=32, n_heads=32, n_kv_heads=8,
                            head_dim=128, ffn_dim=14336, n_experts=8,

@@ -40,6 +40,12 @@ Params pytree layout (all leaves jnp arrays; layer leaves stacked on axis 0):
     ssm_in [Lm, D, 2*di + 2*N + H]  ssm_conv_w [Lm, K, C]  ssm_conv_b [Lm, C]
     ssm_dt_bias / ssm_a_log / ssm_d [Lm, H]  ssm_norm_w [Lm, di]
     ssm_out [Lm, di, D]     (di = H * P, C = di + 2 * N)
+    or, where the recurrent mixer is a gated short convolution ("c", lfm2),
+    conv_in [Lc, D, 3D]  conv_w [Lc, K, D]  conv_out [Lc, D, D]
+    cfg.n_dense_layers leading layers of such a stack have one dense MLP,
+    w_gate/w_up [Ld, D, Fd]  w_down [Ld, Fd, D], and the router's leaves
+    are stacked over the Lr = L - Ld layers after them (router_bias
+    [Lr, E] float32 where cfg.moe_select_bias)
 """
 
 from __future__ import annotations
@@ -132,7 +138,12 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         "wv": w(next(keys), (La, D, cfg.kv_dim)),
         "wo": w(next(keys), (La, cfg.q_dim, D)),
     }
-    if cfg.layer_kinds:
+    if cfg.n_conv_layers:
+        Lc = cfg.n_conv_layers
+        layers["conv_in"] = w(next(keys), (Lc, D, 3 * D))
+        layers["conv_w"] = w(next(keys), (Lc, cfg.conv_kernel, D), 0.4)
+        layers["conv_out"] = w(next(keys), (Lc, D, D))
+    if cfg.n_ssm_layers:
         Lm, H, di = cfg.n_ssm_layers, cfg.ssm_heads, cfg.ssm_inner
         C = cfg.ssm_conv_dim
         layers["ssm_in"] = w(next(keys), (Lm, D, di + C + H))
@@ -147,19 +158,31 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         layers["ssm_d"] = jnp.ones((Lm, H), dtype)
         layers["ssm_norm_w"] = jnp.ones((Lm, di), dtype)
         layers["ssm_out"] = w(next(keys), (Lm, di, D))
+    if cfg.n_dense_layers:
+        # the leading dense layers' MLP; the routed leaves below are stacked
+        # over the layers after them
+        Ld, Fd = cfg.n_dense_layers, cfg.dense_ffn_dim
+        layers["w_gate"] = w(next(keys), (Ld, D, Fd))
+        layers["w_up"] = w(next(keys), (Ld, D, Fd))
+        layers["w_down"] = w(next(keys), (Ld, Fd, D))
     if cfg.n_experts:
-        E = cfg.experts_held
-        layers["router"] = w(next(keys), (L, D, cfg.n_experts))
-        layers["we_gate"] = w(next(keys), (L, E, D, F))
-        layers["we_up"] = w(next(keys), (L, E, D, F))
-        layers["we_down"] = w(next(keys), (L, E, F, D))
+        E, Lr = cfg.experts_held, cfg.n_routed_layers
+        layers["router"] = w(next(keys), (Lr, D, cfg.n_experts))
+        if cfg.moe_select_bias:
+            # wide enough beside sigmoid scores around 1/2 that it changes
+            # some kept sets; float32 whatever the weights' type
+            layers["router_bias"] = jax.random.normal(
+                next(keys), (Lr, cfg.n_experts), jnp.float32) * 0.1
+        layers["we_gate"] = w(next(keys), (Lr, E, D, F))
+        layers["we_up"] = w(next(keys), (Lr, E, D, F))
+        layers["we_down"] = w(next(keys), (Lr, E, F, D))
         if cfg.n_shared_ffn:
             Fs = cfg.n_shared_ffn
-            layers["we_sh_gate"] = w(next(keys), (L, D, Fs))
-            layers["we_sh_up"] = w(next(keys), (L, D, Fs))
-            layers["we_sh_down"] = w(next(keys), (L, Fs, D))
+            layers["we_sh_gate"] = w(next(keys), (Lr, D, Fs))
+            layers["we_sh_up"] = w(next(keys), (Lr, D, Fs))
+            layers["we_sh_down"] = w(next(keys), (Lr, Fs, D))
             if cfg.shared_gate:
-                layers["sh_gate"] = w(next(keys), (L, D, 1))
+                layers["sh_gate"] = w(next(keys), (Lr, D, 1))
     else:
         layers["w_up"] = w(next(keys), (L, D, F))
         layers["w_down"] = w(next(keys), (L, F, D))
@@ -180,8 +203,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         layers["b_up"] = jnp.zeros((L, F), dtype)
         layers["b_down"] = jnp.zeros((L, D), dtype)
     if cfg.qk_norm:
-        layers["q_norm_w"] = jnp.ones((L, cfg.head_dim), dtype)
-        layers["k_norm_w"] = jnp.ones((L, cfg.head_dim), dtype)
+        layers["q_norm_w"] = jnp.ones((La, cfg.head_dim), dtype)
+        layers["k_norm_w"] = jnp.ones((La, cfg.head_dim), dtype)
     if cfg.post_norms:
         layers["post_attn_norm_w"] = jnp.ones((L, D), dtype)
         layers["post_ffw_norm_w"] = jnp.ones((L, D), dtype)
@@ -275,15 +298,27 @@ def _act(cfg: ModelConfig, x):
 
 
 def _moe_gates(cfg: ModelConfig, lp, xf):
-    """Router: top-k softmax gates scattered to a dense [N, E] fp32 matrix
-    (zeros for unselected experts). With ``moe_renorm`` (mixtral,
-    qwen3moe) the softmax runs over the SELECTED logits — equal to the
-    full softmax renormalised over the top-k; without it (qwen2moe,
+    """Router: top-k gates scattered to a dense [N, E] fp32 matrix (zeros
+    for unselected experts). ``moe_score`` "softmax": with ``moe_renorm``
+    (mixtral, qwen3moe) the softmax runs over the SELECTED logits — equal
+    to the full softmax renormalised over the top-k; without it (qwen2moe,
     norm_topk_prob=false) the full-softmax probabilities are kept
-    un-renormalised."""
+    un-renormalised. "sigmoid" (lfm2_moe): scores s = sigmoid(logits); the
+    kept are the top-k of s + router_bias, a bias that takes part in the
+    selection ONLY; the gates are the kept experts' s (never s + b), over
+    their sum + 1e-6 with ``moe_renorm``, times ``moe_scale``."""
     logits = jnp.einsum("nd,de->ne", xf, lp["router"],
                         preferred_element_type=jnp.float32)  # [N, E] fp32
-    if cfg.moe_renorm:
+    if cfg.moe_score == "sigmoid":
+        score = jax.nn.sigmoid(logits)
+        pick = (score + lp["router_bias"].astype(jnp.float32)
+                if cfg.moe_select_bias else score)
+        topi = lax.top_k(pick, cfg.n_experts_used)[1]
+        topw = jnp.take_along_axis(score, topi, axis=1)
+        if cfg.moe_renorm:
+            topw = topw / (topw.sum(axis=-1, keepdims=True) + 1e-6)
+        topw = topw * cfg.moe_scale
+    elif cfg.moe_renorm:
         topw, topi = lax.top_k(logits, cfg.n_experts_used)  # [N, k]
         topw = jax.nn.softmax(topw, axis=-1)
     else:
@@ -294,8 +329,10 @@ def _moe_gates(cfg: ModelConfig, lp, xf):
     return gates.at[jnp.arange(N)[:, None], topi].set(topw)
 
 
-def _moe_mlp(cfg: ModelConfig, lp, x):
+def _moe_mlp(cfg: ModelConfig, lp, x, tap=None):
     """Sparse-MoE gated MLP (mixtral family), exact (no token dropping).
+    ``tap``: a list the router's gates [N, E] are appended to, for a
+    caller that counts what was routed where (``_expert_load``).
 
     Every expert computes over all tokens and the combine applies the gate
     (zero for unselected) — on TPU decode this costs nothing extra where it
@@ -317,6 +354,8 @@ def _moe_mlp(cfg: ModelConfig, lp, x):
     xf = x.reshape(B * T, D)
     with device_scope("moe.route"):
         gates = _moe_gates(cfg, lp, xf)                      # [N, E] fp32
+    if tap is not None:
+        tap.append(gates)
     with device_scope("moe.experts"):
         y = _moe_experts(cfg, lp, xf, gates)
     return y.astype(x.dtype).reshape(B, T, D)
@@ -362,10 +401,17 @@ def _moe_experts(cfg: ModelConfig, lp, xf, gates):
     return y
 
 
+def _expert_load(gates, live):
+    """[E] int32: per expert of the router, how many of the rows that
+    ``live`` [B] marks kept it. gates [B * T, E], zero where not kept."""
+    kept = (gates > 0).reshape(live.shape[0], -1, gates.shape[-1])
+    return (kept & (live != 0)[:, None, None]).sum((0, 1), dtype=jnp.int32)
+
+
 @device_scope("mlp")
-def _mlp(cfg: ModelConfig, lp, x):
+def _mlp(cfg: ModelConfig, lp, x, tap=None):
     if cfg.n_experts:
-        return _moe_mlp(cfg, lp, x)
+        return _moe_mlp(cfg, lp, x, tap)
     if cfg.mlp_type == "gated":
         g = _act(cfg, _mm(cfg, x, lp["w_gate"]))
         u = _mm(cfg, x, lp["w_up"])
@@ -411,7 +457,7 @@ def _proj_out(cfg, lp, attn_out, B, T):
     return o
 
 
-def _residual(cfg: ModelConfig, lp, x, h, attn):
+def _residual(cfg: ModelConfig, lp, x, h, attn, tap=None):
     rm = cfg.residual_multiplier or 1.0   # granite: scaled residual adds
     if cfg.post_norms:
         # gemma2 sandwich norms: attn/mlp OUTPUTS normed before the adds
@@ -421,13 +467,13 @@ def _residual(cfg: ModelConfig, lp, x, h, attn):
     if cfg.parallel_block:
         with device_scope("attn.out"):
             x = x + attn
-        m = _mlp(cfg, lp, h)
+        m = _mlp(cfg, lp, h, tap)
         with device_scope("mlp"):
             return x + m
     with device_scope("attn.out"):
         x = x + rm * attn
     h2 = _norm(cfg, x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
-    m = _mlp(cfg, lp, h2)
+    m = _mlp(cfg, lp, h2, tap)
     if cfg.post_norms:
         m = _norm(cfg, m, lp["post_ffw_norm_w"])
     with device_scope("mlp"):
@@ -595,7 +641,8 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
                        k_cache: jax.Array, v_cache: jax.Array,
                        lengths: jax.Array,
                        attn_len: Optional[int] = None,
-                       mesh=None, n_valid: Optional[jax.Array] = None
+                       mesh=None, n_valid: Optional[jax.Array] = None,
+                       route_live: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Extend sequences that already have ``lengths`` cached tokens.
 
@@ -614,6 +661,9 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
              A decode step passes the active mask; an extend its tail's
              length (and gets logits [B, 1, V] of the tail's last real
              position alone); None = all T.
+    route_live [B], routed models only — where given, a fourth value
+             comes back: [E] int32, per expert of the router the number of
+             rows with route_live != 0 that kept it, over all layers.
     Returns (logits [B, T, V], k_cache, v_cache).
     """
     from ..ops.quant_cache import is_quantized_cache
@@ -690,81 +740,115 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                             mesh=mesh)
         return _proj_out(cfg, lp, attn, B, T), kc, vc
 
+    load = (None if route_live is None
+            else jnp.zeros((cfg.n_experts,), jnp.int32))
     if cfg.layer_kinds:
-        x, k_cache, v_cache, ssm, conv = _hybrid_layers(
+        x, k_cache, v_cache, ssm, conv, load = _hybrid_layers(
             params, cfg, x, k_cache, v_cache, ssm, conv, _valid_rows(
                 n_valid, B, T),
             lambda ap, h, kc, vc, row: attend(ap, h, kc, vc, row, mask,
-                                              None, None))
+                                              cos, sin), route_live, load)
         logits = _unembed(cfg, params, _last_real(x, n_valid))
-        return (logits, *join_state(k_cache, v_cache, ssm, conv))
+        return (logits, *join_state(k_cache, v_cache, ssm, conv),
+                *_given(load))
 
     def body(carry, layer_in):
-        x, kc, vc = carry
+        x, kc, vc, load = carry
         lp, i = layer_in
         mask_l = _layer_mask(cfg, i, mask, m_full)
         cos_i, sin_i = _layer_rope(cfg, i, cos, sin, cos_l, sin_l)
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
         attn, kc, vc = attend(lp, h, kc, vc, i, mask_l, cos_i, sin_i)
-        x = _residual(cfg, lp, x, h, attn)
-        return (x, kc, vc), None
+        x, load = _residual_counting(cfg, lp, x, h, attn, route_live, load)
+        return (x, kc, vc, load), None
 
-    (x, k_cache, v_cache), _ = _scan_layers(
-        cfg, body, (x, k_cache, v_cache), params["layers"])
+    (x, k_cache, v_cache, load), _ = _scan_layers(
+        cfg, body, (x, k_cache, v_cache, load), params["layers"])
     logits = _unembed(cfg, params, x)
-    return logits, k_cache, v_cache
+    return (logits, k_cache, v_cache, *_given(load))
+
+
+def _given(load):
+    """The trailing value of a forward pass asked for the experts' load."""
+    return () if load is None else (load,)
+
+
+def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load):
+    """``_residual``, and ``load`` [E] advanced by what this layer's router
+    kept for the ``live`` rows (``load`` None, or a layer without a router:
+    handed back as it came)."""
+    if load is None:
+        return _residual(cfg, lp, x, h, attn), None
+    tap = []
+    x = _residual(cfg, lp, x, h, attn, tap)
+    return x, (load + _expert_load(tap[0], live) if tap else load)
 
 
 # --------------------------------------------------------------------------
-# hybrid stacks: Mamba-2 mixers beside attention in one scan
+# hybrid stacks: a recurrent mixer beside attention in one scan
 # --------------------------------------------------------------------------
 #
-# granitemoehybrid: every layer is  h = x + rm * mixer(norm(x));
-# h + rm * moe(norm(h)), and the mixer is a Mamba-2 block ("m") or
-# attention without rotary embedding ("A") by cfg.layer_kinds. The layers
-# run as ONE lax.scan whose body traces the shared half (norms, router,
-# experts, residuals) once and picks the mixer with lax.cond; each mixer's
-# weights are stacked over their own layers only and a layer finds its row
-# through the static map of ``_hybrid_rows``.
+# Every layer is  h = x + rm * mixer(norm(x));  h + rm * ffn(norm(h)), and
+# the mixer is attention ("A") or the stack's recurrent one by
+# cfg.layer_kinds: a Mamba-2 block ("m", granitemoehybrid, whose attention
+# has no rotary embedding) or a gated short convolution ("c", lfm2, whose
+# attention norms q and k and rotates them). The layers run as ONE lax.scan
+# whose body traces the shared half (norms, router, experts, residuals)
+# once and picks the mixer with lax.cond; each mixer's weights are stacked
+# over their own layers only and a layer finds its row through the static
+# map of ``_hybrid_rows``. Leading layers with a dense MLP where the rest
+# are routed (cfg.n_dense_layers) run as a short scan of their own before
+# it, the row map running on across both, so that neither scan carries
+# weights it does not use.
 #
 # What a sequence carries besides keys and values is, per Mamba layer, the
 # state S [H, P, N] float32 and the last K-1 inputs of the causal
-# convolution [K-1, C] float32. It cannot be cut back to a prefix, only
-# advanced, so every entry point says how many of a row's positions are
-# real (``n_valid``): a padded prefill position, a slot that sits inactive
-# in a decode batch, leaves both exactly as they were. It travels inside
-# the two cache trees (``join_state``), so the engine's programs hand it
-# on as they hand on the cache.
+# convolution [K-1, C] float32; per short-convolution layer the last K-1
+# inputs alone [K-1, D]. It cannot be cut back to a prefix, only advanced,
+# so every entry point says how many of a row's positions are real
+# (``n_valid``): a padded prefill position, a slot that sits inactive in a
+# decode batch, leaves it exactly as it was. It travels inside the two
+# cache trees (``join_state``), so the engine's programs hand it on as they
+# hand on the cache.
 
 _ATTN_STACK = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
                "q_norm_w", "k_norm_w")
+_LAYER_NORMS = ("attn_norm_w", "attn_norm_b", "mlp_norm_w", "mlp_norm_b")
+_DENSE_FFN = ("w_gate", "w_up", "w_down", "b_up", "b_down")
 
 
 def split_state(k_cache, v_cache):
     """(k_cache, v_cache, ssm, conv): the recurrent state taken out of the
-    two cache trees; (k_cache, v_cache, None, None) for trees without."""
-    if not (isinstance(k_cache, dict) and "ssm" in k_cache):
+    two cache trees; (k_cache, v_cache, None, None) for trees without, and
+    ``ssm`` None for a stack whose state is a convolution's alone."""
+    if not (isinstance(v_cache, dict) and "conv" in v_cache):
         return k_cache, v_cache, None, None
     kc = {k: v for k, v in k_cache.items() if k != "ssm"}
     vc = {k: v for k, v in v_cache.items() if k != "conv"}
     if "kv" in kc:
         kc, vc = kc["kv"], vc["kv"]
-    return kc, vc, k_cache["ssm"], v_cache["conv"]
+    return kc, vc, k_cache.get("ssm"), v_cache["conv"]
 
 
 def join_state(k_cache, v_cache, ssm, conv):
     """Inverse of ``split_state``: {"ssm"} beside the keys' leaves,
     {"conv"} beside the values' (a plain array cache goes under "kv")."""
-    if ssm is None:
+    if conv is None:
         return k_cache, v_cache
     if not isinstance(k_cache, dict):
         k_cache, v_cache = {"kv": k_cache}, {"kv": v_cache}
-    return {**k_cache, "ssm": ssm}, {**v_cache, "conv": conv}
+    if ssm is not None:
+        k_cache = {**k_cache, "ssm": ssm}
+    return k_cache, {**v_cache, "conv": conv}
 
 
 def empty_state(cfg: ModelConfig, B: int):
     """(ssm [Lm, B, H, P, N], conv [Lm, B, K-1, C]) float32 zeros: what a
-    sequence carries before its first position."""
+    sequence carries before its first position; (None, conv [Lc, B, K-1,
+    D]) for a stack of short convolutions."""
+    if cfg.n_conv_layers:
+        return None, jnp.zeros((cfg.n_conv_layers, B, cfg.conv_kernel - 1,
+                                cfg.dim), jnp.float32)
     Lm = cfg.n_ssm_layers
     return (jnp.zeros((Lm, B, cfg.ssm_heads, cfg.ssm_head_dim,
                        cfg.ssm_state), jnp.float32),
@@ -773,14 +857,13 @@ def empty_state(cfg: ModelConfig, B: int):
 
 
 def _hybrid_rows(cfg: ModelConfig):
-    """(is_attn [L] bool, row [L] int32): each layer's kind and its row in
-    its own mixer's stack."""
-    rows, n = [], {"A": 0, "m": 0}
+    """(is_attn [L] bool, row [L]): each layer's kind and its row in its
+    own mixer's stack; host lists, for the scans to cut."""
+    rows, n = [], {"A": 0, "m": 0, "c": 0}
     for c in cfg.layer_kinds:
         rows.append(n[c])
         n[c] += 1
-    return (jnp.asarray([c == "A" for c in cfg.layer_kinds]),
-            jnp.asarray(rows, jnp.int32))
+    return [c == "A" for c in cfg.layer_kinds], rows
 
 
 def _valid_rows(n_valid, B: int, T: int):
@@ -857,13 +940,55 @@ def _ssm_scan(cfg: ModelConfig, S0, x, dt, a, Bm, Cm):
     return y[:, :T], S
 
 
+def _causal_conv(conv, row, new, w, n_valid, bias=None, act=None):
+    """Depthwise causal convolution of one layer over [the K-1 inputs its
+    sequence carries, the T new ones]. conv [Lr, B, K-1, C] float32, of
+    which this layer reads and writes row ``row``; new [B, T, C]; w [K, C]
+    (tap j weighs the input K-1-j positions back); n_valid [B]: the K-1
+    inputs kept are those before position n_valid[b], so positions at or
+    past it leave the state as it was. Returns (out [B, T, C] float32 =
+    act(bias + sum_j w[j] * in[t-K+1+j]), conv)."""
+    K, T, f32 = w.shape[0], new.shape[1], jnp.float32
+    prev = lax.dynamic_index_in_dim(conv, row, 0, keepdims=False)
+    cat = jnp.concatenate([prev, new.astype(f32)], axis=1)      # [B,T+K-1,C]
+    w = w.astype(f32)
+    out = None if bias is None else bias.astype(f32)
+    for j in range(K):
+        tap = w[j] * cat[:, j:j + T]
+        out = tap if out is None else out + tap
+    if act is not None:
+        out = act(out)
+    # the K-1 inputs before position n_valid: what the next call's
+    # first positions look back on
+    prev = jax.vmap(lambda c, n: lax.dynamic_slice_in_dim(c, n, K - 1, 0)
+                    )(cat, n_valid)
+    return out, lax.dynamic_update_index_in_dim(conv, prev, row, 0)
+
+
+def _conv_mixer(cfg: ModelConfig, cp, u, conv, row, n_valid):
+    """Gated short convolution of one layer (lfm2): [B, C, v] = split3(u
+    W_in); out = (C * causal_conv(B * v)) W_out, no bias, no activation.
+    u [B, T, D] (normed); conv [Lc, B, K-1, D] float32, row ``row`` this
+    layer's. Returns (out [B, T, D], conv)."""
+    D = cfg.dim
+    with device_scope("conv.in_proj"):
+        bcv = _mm(cfg, u, cp["conv_in"])
+        gate_b, gate_c, v = bcv[..., :D], bcv[..., D:2 * D], bcv[..., 2 * D:]
+    with device_scope("conv.conv"):
+        c, conv = _causal_conv(conv, row, gate_b * v, cp["conv_w"], n_valid)
+        y = (gate_c.astype(jnp.float32) * c).astype(u.dtype)
+    with device_scope("conv.out"):
+        out = _mm(cfg, y, cp["conv_out"])
+    return out, conv
+
+
 def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
     """Mamba-2 mixer of one layer. u [B, T, D] (normed); ssm [Lm, B, H, P,
     N] and conv [Lm, B, K-1, C] float32, of which this layer reads and
     writes row ``row``; n_valid [B]: positions >= n_valid[b] change
     neither. Returns (out [B, T, D], ssm, conv)."""
     B, T, _ = u.shape
-    H, P, N, K = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
+    H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     di, C = cfg.ssm_inner, cfg.ssm_conv_dim
     f32 = jnp.float32
     valid = jnp.arange(T)[None, :] < n_valid[:, None]           # [B, T]
@@ -871,18 +996,8 @@ def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
         zxd = _mm(cfg, u, sp["ssm_in"])
         z, xbc, dt = zxd[..., :di], zxd[..., di:di + C], zxd[..., di + C:]
     with device_scope("ssm.conv"):
-        prev = lax.dynamic_index_in_dim(conv, row, 0, keepdims=False)
-        cat = jnp.concatenate([prev, xbc.astype(f32)], axis=1)  # [B,T+K-1,C]
-        w = sp["ssm_conv_w"].astype(f32)
-        xc = sp["ssm_conv_b"].astype(f32)
-        for j in range(K):
-            xc = xc + w[j] * cat[:, j:j + T]
-        xc = jax.nn.silu(xc)
-        # the K-1 inputs before position n_valid: what the next call's
-        # first positions look back on
-        prev = jax.vmap(lambda c, n: lax.dynamic_slice_in_dim(c, n, K - 1, 0)
-                        )(cat, n_valid)
-        conv = lax.dynamic_update_index_in_dim(conv, prev, row, 0)
+        xc, conv = _causal_conv(conv, row, xbc, sp["ssm_conv_w"], n_valid,
+                                sp["ssm_conv_b"], jax.nn.silu)
     with device_scope("ssm.scan"):
         x = xc[..., :di].reshape(B, T, H, P)
         Bm, Cm = xc[..., di:di + N], xc[..., di + N:]
@@ -906,25 +1021,28 @@ def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
 
 
 def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, ssm, conv,
-                   n_valid, attend):
-    """The layer scan of a hybrid stack. ``attend(ap, h, kc, vc, row) ->
+                   n_valid, attend, live=None, load=None):
+    """The layer scans of a hybrid stack. ``attend(ap, h, kc, vc, row) ->
     (out, kc, vc)`` is the caller's attention mixer against row ``row`` of
     its keys and values (a fresh chunk's or the cache's). Everything a
     layer may write rides the carry, and each mixer hands the other's
-    through untouched."""
+    through untouched. ``load`` [E] int32 (or None) is advanced by what
+    each router kept for the ``live`` rows (``_residual_counting``)."""
     layers = params["layers"]
+    # the recurrent mixer's leaves, by their names' prefix
+    prefix = "ssm_" if cfg.n_ssm_layers else "conv_"
     attn_stack = {k: v for k, v in layers.items() if k in _ATTN_STACK}
-    ssm_stack = {k: v for k, v in layers.items() if k.startswith("ssm_")}
+    rec_stack = {k: v for k, v in layers.items() if k.startswith(prefix)}
     shared = {k: v for k, v in layers.items()
-              if k not in attn_stack and k not in ssm_stack}
+              if k not in attn_stack and k not in rec_stack}
 
     def take(stack, row):
         return jax.tree_util.tree_map(
             lambda w: lax.dynamic_index_in_dim(w, row, 0, keepdims=False),
             stack)
 
-    def body(carry, layer_in):
-        x, kc, vc, ssm, conv = carry
+    def body(cfg, carry, layer_in):
+        x, kc, vc, ssm, conv, load = carry
         lp, is_attn, row = layer_in
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
 
@@ -932,24 +1050,49 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, ssm, conv,
             out, kc, vc = attend(take(attn_stack, row), h, kc, vc, row)
             return out, kc, vc, ssm, conv
 
-        def ssm_mixer(h, kc, vc, ssm, conv):
-            out, ssm, conv = _ssm_mixer(cfg, take(ssm_stack, row), h, ssm,
-                                        conv, row, n_valid)
+        def rec_mixer(h, kc, vc, ssm, conv):
+            rp = take(rec_stack, row)
+            if ssm is None:
+                out, conv = _conv_mixer(cfg, rp, h, conv, row, n_valid)
+            else:
+                out, ssm, conv = _ssm_mixer(cfg, rp, h, ssm, conv, row,
+                                            n_valid)
             return out, kc, vc, ssm, conv
 
-        # the Mamba mixer is the TRUE branch on purpose: with the branches
-        # the other way round the TPU compiler hands the whole state through
-        # the attention layer's branch by a copy (1.24 GB at 32 slots,
-        # 2.9 ms of a 24.8 ms decode step: my chip run, PR 29), this way
-        # round both branches update or pass their buffers in place
-        out, kc, vc, ssm, conv = lax.cond(~is_attn, ssm_mixer, attn_mixer,
+        # the recurrent mixer is the TRUE branch on purpose: with the
+        # branches the other way round the TPU compiler hands the whole
+        # state through the attention layer's branch by a copy (1.24 GB of
+        # Mamba state at 32 slots, 2.9 ms of a 24.8 ms decode step: my chip
+        # run, PR 29), this way round both branches update or pass their
+        # buffers in place
+        out, kc, vc, ssm, conv = lax.cond(~is_attn, rec_mixer, attn_mixer,
                                           h, kc, vc, ssm, conv)
-        x = _residual(cfg, lp, x, h, out)
-        return (x, kc, vc, ssm, conv), None
+        x, load = _residual_counting(cfg, lp, x, h, out, live, load)
+        return (x, kc, vc, ssm, conv, load), None
 
-    (x, kc, vc, ssm, conv), _ = lax.scan(
-        body, (x, kc, vc, ssm, conv), (shared, *_hybrid_rows(cfg)))
-    return x, kc, vc, ssm, conv
+    is_attn, rows = _hybrid_rows(cfg)
+    L, Ld = cfg.n_layers, cfg.n_dense_layers
+    spans = [(0, L, cfg, shared)]
+    if Ld:
+        # layers 0..Ld-1 with their dense MLP, then the routed ones: each
+        # scan is handed its own feed-forward's leaves and its layers' norms
+        norms = {k: v for k, v in shared.items() if k in _LAYER_NORMS}
+        dense = {k: v for k, v in shared.items() if k in _DENSE_FFN}
+        routed = {k: v for k, v in shared.items()
+                  if k not in norms and k not in dense}
+        dense_cfg = dataclasses.replace(cfg, n_experts=0,
+                                        ffn_dim=cfg.dense_ffn_dim)
+        spans = [(0, Ld, dense_cfg,
+                  {**{k: v[:Ld] for k, v in norms.items()}, **dense}),
+                 (Ld, L, cfg,
+                  {**{k: v[Ld:] for k, v in norms.items()}, **routed})]
+    carry = (x, kc, vc, ssm, conv, load)
+    for lo, hi, cfg_l, xs in spans:
+        carry, _ = lax.scan(
+            functools.partial(body, cfg_l), carry,
+            (xs, jnp.asarray(is_attn[lo:hi]),
+             jnp.asarray(rows[lo:hi], jnp.int32)))
+    return carry
 
 
 def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
@@ -960,13 +1103,17 @@ def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
     B, T = tokens.shape
     scale = _attn_scale(cfg)
     mask = jnp.broadcast_to(causal_mask(T, T, 0), (B, 1, T, T))
+    cos = sin = None
+    if cfg.rope:
+        cos, sin = rope_angles_cfg(jnp.broadcast_to(
+            jnp.arange(T, dtype=jnp.int32), (B, T)), cfg)
     if inputs_embeds is not None:
         x = inputs_embeds.astype(params["tok_emb"].dtype)
     else:
         x = _embed(cfg, params, tokens)
 
     def attend(ap, h, kc, vc, row):
-        q, k, v = _qkv(cfg, ap, h, None, None)
+        q, k, v = _qkv(cfg, ap, h, cos, sin)
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
         with device_scope("attn.core"):
@@ -977,11 +1124,11 @@ def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
 
     kv0 = jnp.zeros((cfg.n_attn_layers, B, cfg.n_kv_heads, T, cfg.head_dim),
                     x.dtype)
-    x, ks, vs, ssm, conv = _hybrid_layers(
+    x, ks, vs, ssm, conv, _ = _hybrid_layers(
         params, cfg, x, kv0, kv0, *empty_state(cfg, B),
         _valid_rows(n_valid, B, T), attend)
     logits = _unembed(cfg, params, _last_real(x, n_valid))
-    return logits, {"kv": ks, "ssm": ssm}, {"kv": vs, "conv": conv}
+    return (logits, *join_state({"kv": ks}, {"kv": vs}, ssm, conv))
 
 
 # --------------------------------------------------------------------------
@@ -1486,8 +1633,9 @@ def paged_extend_dp(params: Params, cfg: ModelConfig, tokens: jax.Array,
 def forward_with_cache_paged(params: Params, cfg: ModelConfig,
                              tokens: jax.Array, k_pool, v_pool,
                              tables: jax.Array, lengths: jax.Array,
-                             attn_blocks: int, mesh=None):
-    """Paged twin of ``forward_with_cache``.
+                             attn_blocks: int, mesh=None,
+                             route_live: Optional[jax.Array] = None):
+    """Paged twin of ``forward_with_cache`` (``route_live`` as there).
 
     tokens   [B, T] — T=1 decode (pallas kernel path), T>1 extend tails
              (gathered einsum path; B=1 there).
@@ -1538,8 +1686,11 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
         from ..ops.attention import resolve_kernels
         interp = resolve_kernels(cfg.kernels) == "interpret"
 
+    load = (None if route_live is None
+            else jnp.zeros((cfg.n_experts,), jnp.int32))
+
     def body(carry, layer_in):
-        x, kp, vp = carry
+        x, kp, vp, load = carry
         lp, i = layer_in
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
         cos_i, sin_i = _layer_rope(cfg, i, cos, sin, cos_l, sin_l)
@@ -1559,10 +1710,10 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
                                  mask_l, scale, attn_blocks, mesh,
                                  use_kernel)
         attn = _proj_out(cfg, lp, attn, B, T)
-        x = _residual(cfg, lp, x, h, attn)
-        return (x, kp, vp), None
+        x, load = _residual_counting(cfg, lp, x, h, attn, route_live, load)
+        return (x, kp, vp, load), None
 
-    (x, k_pool, v_pool), _ = _scan_layers(
-        cfg, body, (x, k_pool, v_pool), params["layers"])
+    (x, k_pool, v_pool, load), _ = _scan_layers(
+        cfg, body, (x, k_pool, v_pool, load), params["layers"])
     logits = _unembed(cfg, params, x)
-    return logits, k_pool, v_pool
+    return (logits, k_pool, v_pool, *_given(load))

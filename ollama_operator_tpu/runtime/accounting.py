@@ -175,8 +175,14 @@ def per_token_flops(cfg: ModelConfig) -> float:
         di, n = cfg.ssm_inner, cfg.ssm_state
         ssm = (2 * d * (di + cfg.ssm_conv_dim + cfg.ssm_heads) + 2 * di * d
                + 2 * cfg.ssm_conv * cfg.ssm_conv_dim + 6 * di * n)
+        # a gated short convolution: in-projection to 3 d, two gating
+        # products, K taps, out-projection
+        conv = 2 * d * 3 * d + 2 * d * d + 2 * (cfg.conv_kernel + 2) * d
+        dense = 6 * d * cfg.dense_ffn_dim
         return float(cfg.n_attn_layers * proj + cfg.n_ssm_layers * ssm
-                     + L * mlp + head)
+                     + cfg.n_conv_layers * conv
+                     + cfg.n_dense_layers * dense
+                     + (L - cfg.n_dense_layers) * mlp + head)
     return float(L * (proj + mlp) + head)
 
 

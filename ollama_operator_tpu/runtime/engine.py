@@ -44,7 +44,7 @@ from .faults import FAULTS
 from .trace import FLIGHT, device_scope, span
 from ..parallel.sharding import (kv_cache_pspec, params_sharding_tree,
                                  resolve_moe_impl)
-from ..server.metrics import GLOBAL as METRICS
+from ..server.metrics import GLOBAL as METRICS, seed_expert_tokens
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,9 +171,10 @@ def _recurrent_slots(cfg: ModelConfig) -> int:
     holds, so the batch is what fills them: the smallest power of two that
     gives every expert four tokens a step (8 slots x 10 picks over
     granite's 72 is 1.1 a step: a latency test, not a serving batch), while
-    the slots' recurrent state (what grows with the batch here: 38.7 MB a
-    slot against 8 MB of int8 keys and values at 4096 positions) stays
-    under an eighth of a v5e chip's 16 GB."""
+    the slots' recurrent state (what grows with the batch here: granite's
+    38.7 MB a slot against 8 MB of int8 keys and values at 4096 positions;
+    a short-convolution stack's is 196 KB) stays under an eighth of a v5e
+    chip's 16 GB."""
     want = 4 * cfg.n_experts / cfg.n_experts_used if cfg.n_experts else 8
     slots = 8
     while slots < min(want, 64):
@@ -360,12 +361,14 @@ class DecodeHandle:
     never wait) keep bit-identical host lengths."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "budgets",
-                 "accepted", "t_done", "sampler")
+                 "accepted", "t_done", "sampler", "_load")
 
     def __init__(self, engine: "Engine", toks, t0: float, epoch: int = 0,
                  budgets: Optional[np.ndarray] = None,
-                 sampler: str = "argmax"):
+                 sampler: str = "argmax", load=None):
         self._engine = engine
+        # a routed model's chunk: [E] picks per expert, still on the device
+        self._load = load
         # the costlier sampler any of its steps took (_count_sampler_steps)
         self.sampler = sampler
         self._toks = toks
@@ -401,6 +404,12 @@ class DecodeHandle:
                     (self.t_done - self._t0) * 1e3)
             self._out = toks
             self._toks = None
+            if self._load is not None:
+                # beside the tokens, once a chunk: the program has ended,
+                # so this fetch waits for nothing
+                self._engine._count_expert_tokens(
+                    self._engine._fetch(self._load))
+                self._load = None
         return self._out
 
 
@@ -432,6 +441,8 @@ class Engine:
         # and values (models/decoder.py, hybrid section); it rides inside
         # the two cache trees, so every program hands it on with them
         self.recurrent = bool(cfg.layer_kinds)
+        if cfg.n_experts:
+            seed_expert_tokens(cfg.n_experts)
         if self.recurrent:
             if ecfg.paged:
                 raise ValueError(
@@ -828,11 +839,34 @@ class Engine:
             self._bucketed_attn = True
 
         W = max(1, self.ecfg.repeat_last_n)
+        # a routed model's decode steps bring out what their routers kept
+        # (tpu_model_moe_expert_tokens_total); the sequence-parallel
+        # forward has no such output
+        count_routes = bool(cfg.n_experts) and self.sp_size == 1
+
+        def slot_rows(state, slot):
+            """One slot's rows [L, 1, ...] of each leaf [L, B, ...] of the
+            recurrent state (ssm, conv); ssm is None for a stack whose
+            state is a convolution's alone."""
+            return jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_slice(
+                    a, (0, slot) + (0,) * (a.ndim - 2),
+                    (a.shape[0], 1) + a.shape[2:]), state)
+
+        def put_rows(state, rows, slot):
+            return jax.tree_util.tree_map(
+                lambda a, r: jax.lax.dynamic_update_slice(
+                    a, r, (0, slot) + (0,) * (a.ndim - 2)), state, rows)
 
         def real(n):
             """How many positions of a row are real, for the forward pass
             of a stack whose recurrent state must not see the padding."""
             return {"n_valid": n} if self.recurrent else {}
+
+        def routes(active):
+            """Asks a routed model's decode step for its routers' picks
+            over the active slots."""
+            return {"route_live": active} if count_routes else {}
 
         def last_row(logits, n):
             """The [V] logits an admission samples from: row n - 1 of the
@@ -898,13 +932,11 @@ class Engine:
             chunk K/V + slot state."""
             k_cache, v_cache, ssm, conv = decoder.split_state(k_cache,
                                                               v_cache)
-            if ssm is not None:
+            if conv is not None:
                 # the state the prompt ends in goes over whatever the
                 # slot's last tenant left: release zeroes nothing
-                ssm = jax.lax.dynamic_update_slice(
-                    ssm, ks["ssm"], (0, slot, 0, 0, 0))
-                conv = jax.lax.dynamic_update_slice(
-                    conv, vs["conv"], (0, slot, 0, 0))
+                ssm, conv = put_rows((ssm, conv),
+                                     (ks.get("ssm"), vs["conv"]), slot)
                 ks, vs = ks["kv"], vs["kv"]
             last = last_row(logits, n_valid)
             # ring of the last rln prompt tokens: absolute positions
@@ -1035,9 +1067,11 @@ class Engine:
             if self.paged:
                 ps = self.ecfg.page_size
                 nblk = -(-(attn_len or self.max_seq) // ps)
-                logits, k_cache, v_cache = decoder.forward_with_cache_paged(
-                    params, cfg, last_tokens[:, None], k_cache, v_cache,
-                    tables, lengths, nblk, mesh=self.mesh)
+                logits, k_cache, v_cache, *load = \
+                    decoder.forward_with_cache_paged(
+                        params, cfg, last_tokens[:, None], k_cache, v_cache,
+                        tables, lengths, nblk, mesh=self.mesh,
+                        **routes(active))
             else:
                 kw = {"attn_len": attn_len} if (attn_len is not None
                                                 and self._bucketed_attn) \
@@ -1045,9 +1079,10 @@ class Engine:
                 # a slot parked between prefill pieces, or freed, sits in
                 # every step's batch: its keys may be written over, its
                 # recurrent state may not
-                logits, k_cache, v_cache = step_impl(
+                logits, k_cache, v_cache, *load = step_impl(
                     params, tokens=last_tokens[:, None], k_cache=k_cache,
-                    v_cache=v_cache, lengths=lengths, **kw, **real(active))
+                    v_cache=v_cache, lengths=lengths, **kw, **real(active),
+                    **routes(active))
             with device_scope("sample"):
                 step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
                 last = logits[:, 0]
@@ -1091,13 +1126,14 @@ class Engine:
             if slot_sh is not None:
                 gstate = jax.lax.with_sharding_constraint(gstate, slot_sh)
             return (toks, *pin(k_cache, v_cache, lengths, counts,
-                               last_tokens, pring, mu), gstate)
+                               last_tokens, pring, mu), gstate,
+                    load[0] if load else None)
 
         def _decode(params, k_cache, v_cache, lengths, counts, last_tokens,
                     pring, mu, sp, keys, active, mask_bits, constrained,
                     rln, gstate, gmask, gtrans, tables=None):
             (toks, k_cache, v_cache, lengths, counts, last_tokens,
-             pring, mu, gstate) = _decode_body(
+             pring, mu, gstate, _load) = _decode_body(
                  params, k_cache, v_cache, lengths, counts, last_tokens,
                  pring, mu, sp, keys, active, mask_bits, constrained, rln,
                  gstate, gmask, gtrans, tables=tables)
@@ -1123,28 +1159,36 @@ class Engine:
             rest of the batch keeps the full chunk (round-1 weak #5: one
             format:"json" request used to collapse everyone to n=1).
             Device-table grammar slots (gstate >= 0) keep the full chunk:
-            their mask refreshes on device from gmask/gtrans."""
+            their mask refreshes on device from gmask/gtrans.
+
+            The last value is a routed model's [E] int32, how many of the
+            chunk's (step, active slot) pairs each expert of the router
+            was kept for, over all layers (None for a model without a
+            router): the host takes it beside the tokens, once a chunk."""
             def step(carry, t):
                 (k_cache, v_cache, lengths, counts, last_tokens,
-                 pring, mu, gstate) = carry
+                 pring, mu, gstate, load) = carry
                 act = active if budgets is None else active * (t < budgets)
                 (toks, k_cache, v_cache, lengths, counts, last_tokens,
-                 pring, mu, gstate) = _decode_body(
+                 pring, mu, gstate, picks) = _decode_body(
                      params, k_cache, v_cache, lengths, counts,
                      last_tokens, pring, mu, sp, keys, act, mask_bits,
                      constrained, rln, gstate, gmask, gtrans,
                      attn_len=attn_len, tables=tables)
+                if picks is not None:
+                    load = load + picks
                 return (k_cache, v_cache, lengths, counts, last_tokens,
-                        pring, mu, gstate), toks
+                        pring, mu, gstate, load), toks
 
             carry = (k_cache, v_cache, lengths, counts, last_tokens, pring,
-                     mu, gstate)
+                     mu, gstate, jnp.zeros((cfg.n_experts,), jnp.int32)
+                     if count_routes else None)
             carry, toks_n = jax.lax.scan(
                 step, carry, jnp.arange(n, dtype=jnp.int32))
             (k_cache, v_cache, lengths, counts, last_tokens, pring,
-             mu, gstate) = carry
+             mu, gstate, load) = carry
             return (toks_n, k_cache, v_cache, lengths, counts, last_tokens,
-                    pring, mu, keys, gstate)
+                    pring, mu, keys, gstate, load)
 
         def _spec_verify(params, k_cache, v_cache, lengths, counts,
                          last_tokens, pring, mu, sp, keys, active,
@@ -1352,23 +1396,18 @@ class Engine:
                     def write5(c, cs):
                         return dus(c, cs, (0, slot, 0, 0, 0))
                 kc_s, vc_s = slice5(k_cache), slice5(v_cache)
-                if ssm is not None:
+                if conv is not None:
                     # the slot's state, read where the last piece left it
                     # and advanced over the tail's real positions only
                     kc_s, vc_s = decoder.join_state(
-                        kc_s, vc_s,
-                        dsl(ssm, (0, slot, 0, 0, 0),
-                            (ssm.shape[0], 1) + ssm.shape[2:]),
-                        dsl(conv, (0, slot, 0, 0),
-                            (conv.shape[0], 1) + conv.shape[2:]))
+                        kc_s, vc_s, *slot_rows((ssm, conv), slot))
                 logits, kc_s, vc_s = fwd(
                     params, cfg, tokens, kc_s, vc_s, start[None],
                     mesh=self.mesh, **real(n_new[None]))
-                if ssm is not None:
+                if conv is not None:
                     kc_s, vc_s, ssm_s, conv_s = decoder.split_state(kc_s,
                                                                     vc_s)
-                    ssm = dus(ssm, ssm_s, (0, slot, 0, 0, 0))
-                    conv = dus(conv, conv_s, (0, slot, 0, 0))
+                    ssm, conv = put_rows((ssm, conv), (ssm_s, conv_s), slot)
                 k_cache = write5(k_cache, kc_s)
                 v_cache = write5(v_cache, vc_s)
                 k_cache, v_cache = decoder.join_state(k_cache, v_cache,
@@ -1431,7 +1470,10 @@ class Engine:
             toksn_sh = NamedSharding(self.mesh, P(None, b_ax))
             tok_outs = (repl_sh,) + state_outs
             dec_outs = (slot_sh,) + state_outs + (slot_sh, slot_sh)
-            decn_outs = (toksn_sh,) + state_outs + (slot_sh, slot_sh)
+            # the last is a routed model's picks per expert (None, an
+            # empty node, for a model without a router)
+            decn_outs = (toksn_sh,) + state_outs + (
+                slot_sh, slot_sh, repl_sh if count_routes else None)
         else:
             tok_outs = dec_outs = decn_outs = None
         self._admit_fn = _jit(_admit, (1, 2, 3, 4, 5, 6, 7),
@@ -2165,6 +2207,15 @@ class Engine:
                             '{sampler="%s"}' % (
                                 "candidates" if needed else "argmax"))
         return "candidates" if first else "argmax"
+
+    @staticmethod
+    def _count_expert_tokens(load: np.ndarray) -> None:
+        """A decode chunk's picks per expert of the router, over all
+        layers, into ``tpu_model_moe_expert_tokens_total{expert=...}``."""
+        for e, n in enumerate(load):
+            if n:
+                METRICS.inc("tpu_model_moe_expert_tokens_total", float(n),
+                            f'{{expert="{e}"}}')
 
     def _note_compile(self, kind: str, key: Any) -> None:
         """Called from every executable-cache miss. While warm_buckets is
@@ -3179,7 +3230,7 @@ class Engine:
         budgets = self.step_budgets(n)
         (toks_n, self.k_cache, self.v_cache, self.lengths, self.counts,
          self.last_tokens, self.pring, self.mu, self.keys,
-         self._gstate) = exe(
+         self._gstate, load) = exe(
             self.params, self.k_cache, self.v_cache, self.lengths,
             self.counts, self.last_tokens, self.pring, self.mu, self.sp,
             self.keys, self._active_dev, self.mask_bits, self._constr_dev,
@@ -3192,7 +3243,8 @@ class Engine:
         # that never existed
         epoch = self._pt.advance_epoch() if self.paged else 0
         return DecodeHandle(self, toks_n, t0, epoch,
-                            sampler=self._count_sampler_steps(n, budgets))
+                            sampler=self._count_sampler_steps(n, budgets),
+                            load=load)
 
     def _spec_exec(self, k: int, attn_len: int):
         key = (k, attn_len)
@@ -3354,5 +3406,5 @@ class Engine:
     def state_bytes(self) -> int:
         """Bytes of the slots' recurrent state (0 for a stack that has
         none); part of ``kv_bytes``, whose trees it rides in."""
-        _, _, ssm, conv = decoder.split_state(self.k_cache, self.v_cache)
-        return 0 if ssm is None else ssm.nbytes + conv.nbytes
+        state = decoder.split_state(self.k_cache, self.v_cache)[2:]
+        return sum(a.nbytes for a in state if a is not None)

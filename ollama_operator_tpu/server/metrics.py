@@ -249,8 +249,8 @@ GLOBAL.describe("tpu_model_host_cache_pages",
                 "(live gauge)")
 GLOBAL.describe("tpu_model_recurrent_state_bytes",
                 "Device bytes of the slots' recurrent state, for a model "
-                "with state-space layers (live gauge; absent for a model "
-                "that keeps keys and values only)")
+                "with state-space or short-convolution layers (live gauge; "
+                "absent for a model that keeps keys and values only)")
 GLOBAL.describe("tpu_model_async_fallback_total",
                 "Decode dispatches that fell back to synchronous while "
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "
@@ -371,6 +371,14 @@ GLOBAL.describe("tpu_model_decode_steps_total",
                 "skips the top-1024 candidate sort of the vocabulary "
                 "(ops/sampling.needs_candidates, evaluated over the "
                 "host's mirror of the slots active at launch)")
+GLOBAL.describe("tpu_model_moe_expert_tokens_total",
+                "Tokens the routers of a model with experts kept each "
+                "expert for in decode steps, summed over the routed layers "
+                "(expert=0..router width-1, of all the router's outputs, "
+                "held on this chip or not): counted on the device, taken "
+                "to the host once a decode chunk beside the tokens; "
+                "seeded when the engine of such a model is built "
+                "(seed_expert_tokens), absent for a model without a router")
 GLOBAL.describe("tpu_model_model_flops_total",
                 "Analytic model FLOPs issued for active slots (matmul "
                 "terms only, MFU convention of Chowdhery et al.); rate() "
@@ -615,6 +623,16 @@ for _sampler in ("argmax", "candidates"):
     GLOBAL.inc("tpu_model_decode_steps_total", 0.0,
                f'{{sampler="{_sampler}"}}')
 GLOBAL.inc("tpu_model_model_flops_total", 0.0)
+
+
+def seed_expert_tokens(n_experts: int) -> None:
+    """One series at 0 for each output of a model's router: the label
+    values are the model's, so the engine seeds them when it is built."""
+    for _e in range(n_experts):
+        GLOBAL.inc("tpu_model_moe_expert_tokens_total", 0.0,
+                   f'{{expert="{_e}"}}')
+
+
 for _queue in ("waiting", "empty"):
     GLOBAL.inc("tpu_model_slot_vacant_seconds_total", 0.0,
                f'{{queue="{_queue}"}}')

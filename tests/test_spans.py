@@ -26,7 +26,7 @@ from test_scheduler import GREEDY, make_stack
 SPAN_NAMES = [row[0] for row in SPAN_TABLE]
 # the scopes a dense (no MoE) model's step must carry, on every path
 DENSE_SCOPES = [s for s in DEVICE_SCOPES
-                if not s.startswith(("moe.", "ssm."))]
+                if not s.startswith(("moe.", "ssm.", "conv."))]
 
 
 def _series(name, labels):
@@ -102,12 +102,12 @@ def test_stage_buckets_step_by_at_most_a_quarter_from_1ms_to_60s():
 
 
 def test_the_benchmark_reads_the_programs_vocabulary():
-    from benchmark import ssm_spans, trace_spans
-    # the accepted reader knows the scopes the dense cells carry; the
-    # hybrid stack's are read by the reader that came with them
-    assert set(trace_spans.SCOPES) | set(ssm_spans.SCOPES) \
-        == set(DEVICE_SCOPES)
-    assert not set(trace_spans.SCOPES) & set(ssm_spans.SCOPES)
+    from benchmark import conv_spans, ssm_spans, trace_spans
+    # the accepted reader knows the scopes the dense cells carry; each
+    # hybrid stack's mixer is read by the reader that came with it
+    lists = (trace_spans.SCOPES, ssm_spans.SCOPES, conv_spans.SCOPES)
+    assert set().union(*lists) == set(DEVICE_SCOPES)
+    assert sum(map(len, lists)) == len(DEVICE_SCOPES)     # no scope twice
     assert all(n.startswith(trace_spans.SPAN_PREFIXES) for n in SPANS)
     grouped = [s for ss in trace_spans.GROUPS.values() for s in ss]
     assert set(grouped) <= set(DEVICE_SCOPES)
