@@ -9,8 +9,13 @@ runs ``<tree>/benchmark/run.py`` with the arguments (give ``--trace 1``), and
 before the trace is removed writes what ``python -m benchmark.trace_spans``
 prints for it (per module: seconds by scope and the costliest operations; the
 idle by host span) to ``chiprun_out/<tag>.trace.json``; the run's result line
-goes to ``chiprun_out/<tag>.result.json``. The exit code is the run's. The
-builder's tool for a chip call, not part of the benchmark."""
+goes to ``chiprun_out/<tag>.result.json``; and ``TIMELINE_S`` seconds from the
+middle of the trace, laid out on one clock, go to
+``chiprun_out/<tag>.timeline.json``: every run of a device module and every
+span of the scheduler's thread as ``[start ms, end ms, name]``, so that one
+cycle of the loop can be read beside what the device did meanwhile. The exit
+code is the run's. The builder's tool for a chip call, not part of the
+benchmark."""
 import contextlib
 import io
 import json
@@ -25,6 +30,36 @@ os.makedirs(out_dir, exist_ok=True)
 os.chdir(tree)
 sys.path.insert(0, tree)
 rmtree = shutil.rmtree
+TIMELINE_S = 1.5
+
+
+def timeline(trace_spans, path):
+    """The middle TIMELINE_S of the trace: device module runs and the
+    scheduler thread's spans (nested ones too), in ms from the cut."""
+    found = trace_spans.find_trace(path)
+    if found is None:       # a --trace 0 run
+        return None
+    planes = trace_spans.read_planes(found)
+    red = trace_spans.reduce_planes(planes)
+    if red is None or red["scheduler_line"] is None:
+        return None
+    dev = next(p for p in planes
+               if p["name"].startswith(trace_spans.DEVICE_PREFIX)
+               and any(ln["name"] == trace_spans.OPS_LINE and ln["events"]
+                       for ln in p["lines"]))
+    mods = sorted(
+        (s, e, trace_spans.module_name(dev["meta"].get(mid, ("?", ""))[0]))
+        for ln in dev["lines"] if ln["name"] == trace_spans.MODULES_LINE
+        for s, e, mid in ln["events"])
+    spans = sorted(trace_spans.host_spans(planes)[red["scheduler_line"]],
+                   key=lambda ev: (ev[0], -ev[1]))
+    mid = (mods[0][0] + mods[-1][1]) // 2
+    a, b = mid - int(TIMELINE_S * 5e11), mid + int(TIMELINE_S * 5e11)
+
+    def cut(rows):
+        return [[round((s - a) * 1e-9, 3), round((e - a) * 1e-9, 3), n]
+                for s, e, n in rows if e > a and s < b]
+    return {"seconds": TIMELINE_S, "modules": cut(mods), "spans": cut(spans)}
 
 
 def keep_then_remove(path, *a, **kw):
@@ -35,6 +70,10 @@ def keep_then_remove(path, *a, **kw):
             trace_spans.main(["trace_spans", path])
         with open(os.path.join(out_dir, tag + ".trace.json"), "w") as f:
             f.write(buf.getvalue())
+        tl = timeline(trace_spans, path)
+        if tl is not None:
+            with open(os.path.join(out_dir, tag + ".timeline.json"), "w") as f:
+                json.dump(tl, f)
     return rmtree(path, *a, **kw)
 
 

@@ -28,7 +28,7 @@ import os
 import sys
 import time
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -361,7 +361,7 @@ class DecodeHandle:
     never wait) keep bit-identical host lengths."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "budgets",
-                 "accepted", "t_done", "sampler", "_load")
+                 "accepted", "t_done", "t_begin", "sampler", "_load")
 
     def __init__(self, engine: "Engine", toks, t0: float, epoch: int = 0,
                  budgets: Optional[np.ndarray] = None,
@@ -381,6 +381,10 @@ class DecodeHandle:
         # t_launch this makes the async launch→materialize overlap
         # visible to the tracing layer (runtime/trace.py)
         self.t_done: Optional[float] = None
+        # the later of the launch and the previous dispatch's t_done: what
+        # lies between it and t_done is this dispatch's own time, also
+        # when it was launched behind one that still ran (_landed)
+        self.t_begin: Optional[float] = None
 
     @property
     def t_launch(self) -> float:
@@ -390,18 +394,14 @@ class DecodeHandle:
     def wait(self) -> np.ndarray:
         if self._out is None:
             toks = self._engine._fetch(self._toks)
-            self.t_done = time.perf_counter()
+            kind = "spec" if self.budgets is not None else "decode"
+            self.t_begin, self.t_done = self._engine._landed(kind, self._t0)
             if self.budgets is not None:
                 # [B, k+1] sentinel-padded: valid entries per row are the
                 # accepted draft prefix + bonus token, in order
                 self.accepted = (
                     toks < self._engine.cfg.vocab_size).sum(axis=1)
                 toks = toks.T
-                self._engine.dispatch_ms["spec"] = (
-                    (self.t_done - self._t0) * 1e3)
-            else:
-                self._engine.dispatch_ms["decode"] = (
-                    (self.t_done - self._t0) * 1e3)
             self._out = toks
             self._toks = None
             if self._load is not None:
@@ -410,6 +410,44 @@ class DecodeHandle:
                 self._engine._count_expert_tokens(
                     self._engine._fetch(self._load))
                 self._load = None
+        return self._out
+
+
+class AdmitHandle:
+    """An admission launched and not awaited: what DecodeHandle is to
+    ``decode_n``. The prefill program is dispatched and the slot already
+    live on the host (``_commit_slot`` ran), so a decode chunk launched
+    next carries it; the first sampled token(s) are still on the device.
+    ``wait()`` fetches them, one per slot, in the order of ``slots``.
+    Followers replay the launch and never wait."""
+
+    __slots__ = ("_engine", "_toks", "_t0", "_out", "kind", "slots",
+                 "t_done", "t_begin")
+
+    def __init__(self, engine: "Engine", toks, t0: float, kind: str,
+                 slots: Sequence[int]):
+        self._engine = engine
+        self._toks = toks
+        self._t0 = t0
+        self._out: Optional[List[int]] = None
+        # the key of Engine.dispatch_ms this admission reports under
+        self.kind = kind
+        self.slots = tuple(slots)
+        self.t_done: Optional[float] = None
+        self.t_begin: Optional[float] = None
+
+    @property
+    def t_launch(self) -> float:
+        """perf_counter() when the launch began (host staging included)."""
+        return self._t0
+
+    def wait(self) -> List[int]:
+        if self._out is None:
+            toks = self._engine._fetch(self._toks)
+            self.t_begin, self.t_done = self._engine._landed(self.kind,
+                                                             self._t0)
+            self._out = [int(t) for t in toks.reshape(-1)]
+            self._toks = None
         return self._out
 
 
@@ -713,6 +751,9 @@ class Engine:
         # setup.
         self.dispatch_ms = {"decode": 0.0, "admit": 0.0, "extend": 0.0,
                             "spec": 0.0}
+        # perf_counter() when the newest dispatch's tokens reached the host
+        # (_landed): a dispatch launched before then began no earlier
+        self._t_landed = 0.0
         # mid-serving recompile detector: warm_buckets registers every
         # AOT-warmed executable signature; an executable-cache miss
         # outside warming is an XLA compile inside a timed dispatch —
@@ -779,6 +820,21 @@ class Engine:
                             out_shardings=self._repl_sh)(0)
             self._dummy_key_val = k
         return k
+
+    def _landed(self, kind: str, t_launch: float) -> Tuple[float, float]:
+        """A handle's tokens just reached the host: (t_begin, t_done) of
+        the dispatch, kept in ``dispatch_ms[kind]``. Device programs run
+        in launch order, so one launched while its predecessor still ran
+        began when that one's tokens landed, not at its own launch: the
+        interval is what THIS dispatch took (host staging included where
+        the device stood empty), however deep the queue was. Handles are
+        waited in launch order; one waited out of order (an awaited
+        admission with a chunk in flight) takes the other's remainder."""
+        t_done = time.perf_counter()
+        t_begin = max(t_launch, self._t_landed)
+        self._t_landed = t_done
+        self.dispatch_ms[kind] = (t_done - t_begin) * 1e3
+        return t_begin, t_done
 
     @staticmethod
     def _fetch(x) -> np.ndarray:
@@ -1689,13 +1745,25 @@ class Engine:
         requests); the caller then keeps per-step masks flowing via
         ``set_mask``.
         """
+        return self.admit_launch(slot, prompt, opts, embeds,
+                                 mask_row).wait()[0]
+
+    def admit_launch(self, slot: int, prompt: np.ndarray,
+                     opts: SlotOptions = SlotOptions(),
+                     embeds: Optional[np.ndarray] = None,
+                     mask_row: Optional[np.ndarray] = None) -> AdmitHandle:
+        """``admit`` without its wait: the same program with the same
+        arguments is dispatched and the slot is live when this returns;
+        the handle's ``wait()`` yields the first token. Pool exhaustion
+        and an armed ``engine.admit`` fault raise here, before any
+        dispatch; a device error surfaces at ``wait()``."""
         FAULTS.check("engine.admit")
         with span("engine.admit") as sp:
-            tok = self._do_admit(slot, prompt, opts, embeds, mask_row)
-        self.dispatch_ms["admit"] = sp.dur * 1e3
-        return tok
+            return self._do_admit(slot, prompt, opts, embeds, mask_row,
+                                  sp.t0)
 
-    def _do_admit(self, slot, prompt, opts, embeds, mask_row) -> int:
+    def _do_admit(self, slot, prompt, opts, embeds, mask_row,
+                  t0: float) -> AdmitHandle:
         assert not self.active[slot], f"slot {slot} busy"
         n = int(prompt.shape[0])
         if n >= self.max_seq:
@@ -1729,7 +1797,7 @@ class Engine:
                 cflag, self._gr(np.int32(self._resolve_rln(opts))),
                 table_row)
         self._commit_slot(slot, n, opts)
-        return int(tok)
+        return AdmitHandle(self, tok, t0, "admit", (slot,))
 
     def _grow_for_admit(self, slot: int, n: int):
         """Paged admission bookkeeping: drop any pages the slot still owns
@@ -1851,6 +1919,14 @@ class Engine:
         independent of its batch mates. Grammar-constrained and
         multimodal requests take the single-admit path (the caller
         routes them there)."""
+        return self.admit_many_launch(slots, prompts, opts_list).wait()
+
+    def admit_many_launch(self, slots: Sequence[int],
+                          prompts: Sequence[Any],
+                          opts_list: Optional[Sequence[SlotOptions]] = None
+                          ) -> AdmitHandle:
+        """``admit_many`` without its wait (see ``admit_launch``): the
+        handle's ``wait()`` yields each slot's first token, in order."""
         m = len(slots)
         assert m == len(prompts) >= 2, "admit_many wants >= 2 prompts"
         assert self.supports_admit_many, "unsupported engine mode"
@@ -1858,11 +1934,10 @@ class Engine:
             opts_list = [SlotOptions()] * m
         FAULTS.check("engine.admit")
         with span("engine.admit_many", m=m) as sp:
-            out = self._do_admit_many(slots, prompts, opts_list)
-        self.dispatch_ms["admit"] = sp.dur * 1e3
-        return out
+            return self._do_admit_many(slots, prompts, opts_list, sp.t0)
 
-    def _do_admit_many(self, slots, prompts, opts_list) -> List[int]:
+    def _do_admit_many(self, slots, prompts, opts_list,
+                       t0: float) -> AdmitHandle:
         m = len(slots)
         ns = [int(np.asarray(p).shape[0]) for p in prompts]
         for s, n in zip(slots, ns):
@@ -1918,7 +1993,7 @@ class Engine:
                 self._admit_seq += 1
                 self._admit_order[s] = self._admit_seq
         self._upload_slot_state()
-        return [int(t) for t in self._fetch(toks)]
+        return AdmitHandle(self, toks, t0, "admit", slots)
 
     @property
     def supports_extend(self) -> bool:
@@ -1982,16 +2057,23 @@ class Engine:
         ids share that prefix — stale entries at positions >= start are
         never attended: masking is position-based and the tail overwrites
         them)."""
+        return self.extend_launch(slot, full_ids, start, opts,
+                                  mask_row).wait()[0]
+
+    def extend_launch(self, slot: int, full_ids: np.ndarray, start: int,
+                      opts: SlotOptions = SlotOptions(),
+                      mask_row: Optional[np.ndarray] = None) -> AdmitHandle:
+        """``extend`` without its wait (see ``admit_launch``)."""
         # same fault point as admit(): an extend IS an admission (prefix
         # reuse or a chunked-prefill piece), and chaos drills must reach
         # the chunked path through it
         FAULTS.check("engine.admit")
         with span("engine.extend") as sp:
-            tok = self._do_extend(slot, full_ids, start, opts, mask_row)
-        self.dispatch_ms["extend"] = sp.dur * 1e3
-        return tok
+            return self._do_extend(slot, full_ids, start, opts, mask_row,
+                                   sp.t0)
 
-    def _do_extend(self, slot, full_ids, start, opts, mask_row) -> int:
+    def _do_extend(self, slot, full_ids, start, opts, mask_row,
+                   t0: float) -> AdmitHandle:
         assert not self.active[slot], f"slot {slot} busy"
         full_ids = np.asarray(full_ids, np.int32)
         n_total = int(full_ids.shape[0])
@@ -2070,7 +2152,7 @@ class Engine:
          self.last_tokens, self.pring, self.mu) = \
             self._extend_exec(bucket, attn_a)(*args)
         self._commit_slot(slot, n_total, opts)
-        return int(tok)
+        return AdmitHandle(self, tok, t0, "extend", (slot,))
 
     def _attn_bucket(self, n: int) -> int:
         """Static attended-prefix length covering every active slot for the

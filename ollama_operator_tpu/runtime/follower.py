@@ -240,6 +240,10 @@ class MirroredEngine:
     state (page tables). Everything else delegates transparently."""
 
     MIRRORED = ("admit", "admit_many", "extend", "decode", "decode_n",
+                # the launched forms of the three admissions: followers
+                # replay launches and never wait, so a handle the leader
+                # collects later keeps every host's call stream the same
+                "admit_launch", "admit_many_launch", "extend_launch",
                 # decode_n_launch is the ONE decode dispatch surface —
                 # its drafts= kwarg covers fused speculative dispatches
                 # (the standalone decode_spec op is gone); spec_ack

@@ -41,6 +41,10 @@ from .paged import PagesExhausted
 from .trace import FLIGHT, TRACER, fold_stages, span
 
 
+# tpu_model_admissions_total's label values (Scheduler._own)
+_ADMIT_MODE = {m: f'{{mode="{m}"}}' for m in ("launched", "awaited")}
+
+
 class SchedulerBusy(RuntimeError):
     """Raised by submit() when the waiting queue is full (backpressure).
     ``retry_after_s`` rides into the HTTP 503's Retry-After header —
@@ -344,6 +348,12 @@ class Scheduler:
         # double-buffering — drafted counts feed the acceptance metrics
         # when the handle materialises
         self._pending = None
+        # the admissions this pass launched and has not collected, oldest
+        # first: (AdmitHandle, [(slot, request, reuse_len)]). Their slots
+        # have owners already (_own), so the chunk launched next carries
+        # them; _land collects the first tokens behind that launch. Empty
+        # whenever _step returns.
+        self._launched: List[tuple] = []
         # device-grammar escape bookkeeping: slot → request whose
         # ALREADY-LAUNCHED next dispatch ran with the slot frozen
         # (its automaton escaped the device table mid-chunk); that
@@ -630,6 +640,7 @@ class Scheduler:
         # an in-flight dispatch's tokens die with the loop; its owners
         # are still in _running and drain below
         self._pending = None
+        self._launched.clear()
         self._prefilling.clear()
         # unfence anything the dropped dispatch was holding: the engine
         # may outlive this scheduler (model swap builds a fresh one), and
@@ -1063,11 +1074,23 @@ class Scheduler:
             self.finished.append(req.stats)
         req.out.put(("error", msg))
 
-    def _post_admit(self, slot: int, req: Request, first: int):
-        """Shared admission tail (one-shot, batched, and the final
-        chunked piece): stats, slot ownership, grammar gate, first-token
-        emit."""
+    def _own(self, slot: int, req: Request, mode: str):
+        """The slot has its owner: the next decode launch counts it in
+        _decoding(). ``mode`` says whether the loop waited for the
+        admission's token before going on ("awaited") or launched it and
+        collects the token behind the next chunk's launch ("launched")."""
         req.slot = slot
+        self._running[slot] = req
+        METRICS.inc("tpu_model_admissions_total", 1.0, _ADMIT_MODE[mode])
+
+    def _post_admit(self, slot: int, req: Request, first: int,
+                    launched: bool = False):
+        """Shared admission tail (one-shot, batched, and the final
+        chunked piece), run once the first token is on the host: stats,
+        slot ownership (a launched admission has it already), grammar
+        gate, first-token emit."""
+        if not launched:
+            self._own(slot, req, "awaited")
         if req.stats.t_admitted == 0:
             # first admission only — a preempted request re-admitting
             # must not re-count its prompt in throughput stats (nor
@@ -1108,7 +1131,6 @@ class Scheduler:
                 METRICS.inc("tpu_model_tier_miss_tokens_total", float(n),
                             f'{{tier="{tier}"}}')
         req._tier_stitch = None
-        self._running[slot] = req
         # grammar check before emitting (see _fanout)
         if (req.constraint is not None
                 and first not in req.eog_ids
@@ -1144,24 +1166,43 @@ class Scheduler:
             self._shed(req)
         return True
 
-    def _admit_one(self, slot: int, req: Request, reuse_len: int) -> bool:
-        """One blocking admission (fresh or prefix-reusing). Returns
-        False when the paged pool ran dry and the request was requeued —
-        the caller should stop admitting this pass."""
+    def _launches(self, req: Optional[Request] = None) -> bool:
+        """Whether an admission is launched and its first token collected
+        behind the next chunk's launch, or awaited where it is made.
+        Launched wherever nothing needs the host between a prefill and the
+        first decode step. Awaited: a synchronous loop; a pass while
+        drafts are built (they extend each slot's true tip, first token
+        included); a constrained request (its first token advances the
+        automaton, and the mask of its first decode step follows from
+        that)."""
+        return (self.async_dispatch and self.spec_k == 0
+                and (req is None or req.constraint is None))
+
+    def _admit_one(self, slot: int, req: Request, reuse_len: int,
+                   tries: int = 3) -> bool:
+        """One admission (fresh or prefix-reusing), launched or awaited
+        (_launches). Returns False when the paged pool ran dry and the
+        request was requeued — the caller should stop admitting this
+        pass. ``tries``: a try that finds the pool dry reclaims pages
+        (unfences, else evicts) and a request that came cold is tried
+        again where it stands: at most once after each."""
         if self._expired_at_admission(req):
             return True
+        launch = self._launches(req)
+        cold = not reuse_len
+        eng = self.engine
         try:
             mask_row = (req.constraint.mask_row()
                         if req.constraint is not None else None)
+            admit = eng.admit_launch if launch else eng.admit
             try:
                 if reuse_len:
-                    first = self.engine.extend(slot, req.admit_ids,
-                                               reuse_len, req.opts,
-                                               mask_row=mask_row)
+                    out = (eng.extend_launch if launch else eng.extend)(
+                        slot, req.admit_ids, reuse_len, req.opts,
+                        mask_row=mask_row)
                 else:
-                    first = self.engine.admit(slot, req.admit_ids,
-                                              req.opts, embeds=req.embeds,
-                                              mask_row=mask_row)
+                    out = admit(slot, req.admit_ids, req.opts,
+                                embeds=req.embeds, mask_row=mask_row)
             except PagesExhausted:
                 if not (reuse_len and self._use_radix):
                     raise
@@ -1170,17 +1211,22 @@ class Scheduler:
                 # a genuinely dry pool raises again and requeues below
                 reuse_len = 0
                 req._tier_stitch = None
-                first = self.engine.admit(slot, req.admit_ids, req.opts,
-                                          embeds=req.embeds,
-                                          mask_row=mask_row)
+                out = admit(slot, req.admit_ids, req.opts,
+                            embeds=req.embeds, mask_row=mask_row)
             req.stats.n_reused = reuse_len
         except PagesExhausted as e:
             # paged pool dry: under async dispatch first drain the
             # pipeline and unfence quarantined pages (they may merely be
-            # fenced behind the in-flight dispatch, not truly gone), then
-            # evict cached pages; either way retry this request next
-            # pass — with nothing to reclaim it waits for a finisher
-            # (unless it can never fit at all)
+            # fenced behind the in-flight dispatch, not truly gone), else
+            # evict cached pages (nothing is in flight then: they are
+            # free at once). A request that came cold is then tried again
+            # where it stands: the pass has paid for the stall, and a
+            # request sent to the next pass waits a whole cycle beside
+            # pages that are free. One that came with a prefix goes to
+            # the head of the next pass, which stitches it again (trying
+            # it here would admit it cold and forfeit the hit); so does
+            # one for which nothing was reclaimed or the tries are used
+            # up: it waits for a finisher (unless it can never fit at all)
             if not self.engine.admissible(len(req.admit_ids)):
                 self._request_error(
                     req, f"prompt needs more KV pages than the pool "
@@ -1189,12 +1235,20 @@ class Scheduler:
             if self._pending is not None or self.engine.quarantined_pages:
                 self._drain_pending()
                 self._quiesce("pool_dry_admit")
+                reclaimed = True
             else:
-                self._evict_one_parked(self._pages_for(len(req.admit_ids)))
+                reclaimed = self._evict_one_parked(
+                    self._pages_for(len(req.admit_ids)))
+            if cold and reclaimed and tries > 1:
+                return self._admit_one(slot, req, 0, tries - 1)
             self._preempted.insert(0, req)
             return False
         except Exception as e:  # surfacing engine errors to the caller
             self._request_error(req, str(e))
+            return True
+        if launch:
+            self._own(slot, req, "launched")
+            self._launched.append((out, [(slot, req, reuse_len)]))
             return True
         kind = "extend" if reuse_len else "admit"
         dur = self._note_prefill(kind)
@@ -1203,19 +1257,71 @@ class Scheduler:
                              self.engine.bucket_for(n_new))
         req.trace.event("prefill", kind=kind, dur_ms=round(dur * 1e3, 3),
                         n_tokens=n_new)
-        self._post_admit(slot, req, first)
+        self._post_admit(slot, req, out)
         return True
 
-    def _note_prefill(self, kind: str) -> float:
-        """Account one blocking admit/extend dispatch that just returned:
-        its seconds are the engine's own (its `engine.<kind>` span, kept in
-        dispatch_ms), so the scheduler reads no clock of its own here."""
+    def _note_prefill(self, kind: str,
+                      blocked: Optional[float] = None) -> float:
+        """Account one admit/extend dispatch whose token just reached the
+        host: its seconds are the engine's own (dispatch_ms: what the
+        dispatch took, Engine._landed), so the scheduler reads no clock of
+        its own here. ``blocked`` is how long this thread stood waiting
+        for it: all of it for an awaited admission, the collect's wait for
+        a launched one."""
         dur = self.engine.dispatch_ms[kind] / 1e3
-        METRICS.inc("tpu_model_admission_stall_ms_total", dur * 1e3)
+        if blocked is None:
+            blocked = dur
+        METRICS.inc("tpu_model_admission_stall_ms_total", blocked * 1e3)
         METRICS.observe("tpu_model_dispatch_seconds", dur,
                         f'{{kind="{kind}"}}')
-        self.acct.on_wait(dur)
+        self.acct.on_wait(blocked)
         return dur
+
+    def _collect_launched(self):
+        """The first tokens of the admissions this pass launched, oldest
+        first, and for each the tail an awaited admission runs where it
+        is made. A request that was cancelled, preempted or timed out
+        since its launch has left its slot: its token is dropped by the
+        rule _fanout_rows drops rows by, the owner's identity. A device
+        error surfaces here and errors the admission's own requests; a
+        wedged device (WatchdogTimeout) goes to the supervisor, for which
+        what is still in _launched is one more pending dispatch whose
+        owners stand in _running."""
+        while self._launched:
+            # popped BEFORE waiting, as _drain_pending pops: a failed
+            # fetch must never be tried again
+            handle, items = self._launched.pop(0)
+            try:
+                with span("sched.collect", m=len(items)) as sp:
+                    toks = self._watched(handle.wait)
+            except WatchdogTimeout:
+                raise
+            except Exception as e:  # noqa: BLE001 — the owners' error frame
+                for slot, req, _ in items:
+                    if self._running[slot] is req:
+                        # the frame first: if the release raises too, the
+                        # supervisor must not find this owner again
+                        self._running[slot] = None
+                        req.slot = None
+                        self._request_error(req, str(e))
+                        self.engine.release(slot)
+                continue
+            dur = self._note_prefill(handle.kind, sp.dur)
+            m = len(items)
+            for (slot, req, reuse_len), tok in zip(items, toks):
+                n_new = len(req.admit_ids) - reuse_len
+                # a batched dispatch's time is split evenly, so the ring's
+                # busy_s doesn't count the dispatch m times
+                self.acct.on_prefill(dur / m, reuse_len, n_new,
+                                     self.engine.bucket_for(n_new))
+                # from the launch, so that the request's queue stage ends
+                # and its prefill stage begins there (trace.fold_stages)
+                req.trace.event(
+                    "prefill", kind=handle.kind, n_tokens=n_new,
+                    dur_ms=round((handle.t_done - handle.t_launch) * 1e3, 3),
+                    **({"batched": m} if m > 1 else {}))
+                if self._running[slot] is req:
+                    self._post_admit(slot, req, tok, launched=True)
 
     def _start_chunked(self, slot: int, req: Request,
                        reuse_len: int) -> bool:
@@ -1362,11 +1468,14 @@ class Scheduler:
             # may have burned this batch's remaining budget
             items = [(s, r) for s, r in items
                      if not self._expired_at_admission(r)]
+            launch = self._launches()    # no batched request is constrained
+            admit_many = (self.engine.admit_many_launch if launch
+                          else self.engine.admit_many)
             while len(items) >= 2:
                 m = 4 if len(items) >= 4 else 2
                 group, items = items[:m], items[m:]
                 try:
-                    toks = self.engine.admit_many(
+                    out = admit_many(
                         [s for s, _ in group],
                         [r.admit_ids for _, r in group],
                         [r.opts for _, r in group])
@@ -1378,17 +1487,24 @@ class Scheduler:
                     for s, r in group:
                         self._admit_one(s, r, 0)
                     continue
+                # batched admissions are always cold (a resumed request
+                # must not re-report its first admission's reuse as a
+                # fresh cache hit)
+                for _, r in group:
+                    r.stats.n_reused = 0
+                if launch:
+                    for s, r in group:
+                        self._own(s, r, "launched")
+                    self._launched.append(
+                        (out, [(s, r, 0) for s, r in group]))
+                    continue
                 dur = self._note_prefill("admit")
                 # one batched dispatch: split its wall time evenly so the
                 # ring's busy_s doesn't count the dispatch m times
                 for _, r in group:
                     self.acct.on_prefill(dur / m, 0, len(r.admit_ids),
                                          bucket)
-                for (s, r), tok in zip(group, toks):
-                    # batched admissions are always cold (a resumed
-                    # request must not re-report its first admission's
-                    # reuse as a fresh cache hit)
-                    r.stats.n_reused = 0
+                for (s, r), tok in zip(group, out):
                     r.trace.event("prefill", kind="admit", batched=m,
                                   dur_ms=round(dur * 1e3, 3),
                                   n_tokens=len(r.admit_ids))
@@ -1592,6 +1708,7 @@ class Scheduler:
         FLIGHT.record("fail_running", error=message[:200],
                       n_running=self.n_active)
         self._pending = None
+        self._launched.clear()
         self._prefilling.clear()
         budget = replay_token_budget()
         max_streams = replay_max_streams() if replay else 0
@@ -1696,9 +1813,7 @@ class Scheduler:
                 # priority-aware sacrifice: lowest class first, newest
                 # admission within a class — a best_effort straggler
                 # yields its pages before any high request does
-                slot = max(non_mm,
-                           key=lambda s: (self._running[s].rank,
-                                          self._running[s].stats.t_admitted))
+                slot = max(non_mm, key=self._newest_lowest)
                 self._preempt_slot(slot, cause="pool_pressure")
             else:
                 slot = cand[0]
@@ -1711,6 +1826,13 @@ class Scheduler:
                 with self._lock:
                     self.finished.append(req.stats)
                 req.out.put(("error", req.error))
+
+    def _newest_lowest(self, slot: int) -> tuple:
+        """Preemption order: the lowest class first, within it the newest
+        admission. One launched and not yet collected carries no stamp of
+        its own (_post_admit sets it): it is the newest there is."""
+        req = self._running[slot]
+        return req.rank, req.stats.t_admitted or float("inf")
 
     def _preempt_slot(self, slot: int, cause: str,
                       resume_delay: float = 0.0) -> Request:
@@ -1762,8 +1884,7 @@ class Scheduler:
                 and r.embeds is None and r.rank > want]
         if not cand:
             return
-        slot = max(cand, key=lambda s: (self._running[s].rank,
-                                        self._running[s].stats.t_admitted))
+        slot = max(cand, key=self._newest_lowest)
         self._preempt_slot(slot, cause="priority")
 
     def _throttle_over_limit(self):
@@ -1987,12 +2108,15 @@ class Scheduler:
         self.acct.on_wait(sp.dur, sp.t1)
         self._fence_ack = handle.epoch
         self._consecutive_failures = 0
-        # dispatch latency: launch → tokens-on-host, per program kind.
-        # The handle stamped both ends, so the span event's launch-time
-        # anchor makes async overlap visible (a launch far before its
-        # materialize = host work hidden behind device compute).
+        # dispatch latency, per program kind: what THIS dispatch took,
+        # from the later of its launch and its predecessor's tokens
+        # reaching the host (Engine._landed) to its own; a chunk launched
+        # behind one that still ran does not count that one's remainder.
+        # The trace event below is anchored at the launch, which makes
+        # async overlap visible (a launch far before its materialize =
+        # host work hidden behind device compute).
         kind = "spec" if handle.budgets is not None else "decode"
-        dur = ((handle.t_done - handle.t_launch)
+        dur = ((handle.t_done - handle.t_begin)
                if handle.t_done is not None else 0.0)
         METRICS.observe("tpu_model_dispatch_seconds", dur,
                         f'{{kind="{kind}"}}')
@@ -2069,15 +2193,30 @@ class Scheduler:
         return tails
 
     def _drain_pending(self):
-        """Materialise and fan out the in-flight async dispatch, if any.
-        Pops BEFORE waiting: if the fetch itself fails (poisoned device
-        state) the supervisor must error the owners, never re-deliver."""
-        if self._pending is None:
-            return
-        handle, snapshot, drafted = self._pending
-        self._pending = None
-        toks_n = self._wait_handle(handle, snapshot, drafted)
-        self._fanout(toks_n, snapshot, chunked=drafted is None)
+        """Materialise and fan out the in-flight async dispatch, if any,
+        and collect the admissions launched behind it. Pops BEFORE
+        waiting: if the fetch itself fails (poisoned device state) the
+        supervisor must error the owners, never re-deliver."""
+        prev, self._pending = self._pending, None
+        self._land(prev)
+
+    def _land(self, prev):
+        """Bring to the host, in the order the device runs them, the
+        dispatch ``prev`` (a _pending triple, or None) and the admissions
+        launched behind it; then fan ``prev`` out. The first tokens are
+        collected BEFORE the fan-out: a first token is not held behind a
+        chunk's worth of queue puts."""
+        toks_n = None
+        if prev is not None:
+            handle, snapshot, drafted = prev
+            toks_n = self._wait_handle(handle, snapshot, drafted)
+        try:
+            self._collect_launched()
+        finally:
+            # whatever the collect raised, prev's tokens are on the host:
+            # deliver them before the supervisor errors whoever is left
+            if prev is not None:
+                self._fanout(toks_n, snapshot, chunked=drafted is None)
 
     def _decoding(self) -> dict:
         """slot → request for every slot the NEXT decode dispatch will
@@ -2284,9 +2423,12 @@ class Scheduler:
                              chunked=prev_drafted is None)
             return
         # double-buffered async dispatch: launch dispatch N+1 FIRST,
-        # then materialise and fan out dispatch N — detokenise/queue
-        # work on the host overlaps device compute. Device programs stay
-        # ordered through their donated-state data dependencies. The
+        # then materialise dispatch N, collect the first tokens of the
+        # admissions this pass launched between the two, and fan N out —
+        # the pass's host work and the detokenise/queue work overlap
+        # device compute, and the device's queue holds the chunk in
+        # flight, the pass's prefills and the next chunk. Device programs
+        # stay ordered through their donated-state data dependencies. The
         # retire= ack unfences pages freed behind dispatches we have
         # already materialised (paged mode; no-op dense).
         try:
@@ -2301,12 +2443,7 @@ class Scheduler:
             self._drain_pending()
             raise
         prev, self._pending = self._pending, (handle, decoding, None)
-        if prev is not None:
-            prev_handle, prev_snapshot, prev_drafted = prev
-            toks_n = self._wait_handle(prev_handle, prev_snapshot,
-                                       prev_drafted)
-            self._fanout(toks_n, prev_snapshot,
-                         chunked=prev_drafted is None)
+        self._land(prev)
 
     def _fanout(self, toks_n, snapshot: dict, chunked: bool = True):
         with span("sched.fanout"):

@@ -280,17 +280,21 @@ SPAN_TABLE = (
      "engine.decode_n_launch as called from _step"),
     ("sched.wait", "engine dispatch",
      "blocked on a dispatch: DecodeHandle.wait or the synchronous decode_n"),
+    ("sched.collect", "engine dispatch",
+     "blocked on a launched admission's first token(s): AdmitHandle.wait "
+     "(field m: requests of the dispatch)"),
     ("sched.fanout", "scheduler",
      "_fanout: grammar walk, per-request queue put"),
     ("sched.idle", "scheduler", "the _wake.wait of an idle scheduler"),
     ("engine.decode_n", "engine dispatch",
      "host time to launch one decode chunk (spec launch included)"),
     ("engine.admit", "engine dispatch",
-     "one-shot prefill + insert, to the first token on the host"),
+     "one-shot prefill + insert, to the program's dispatch (the wait for "
+     "the first token lies outside: sched.collect where it is launched)"),
     ("engine.admit_many", "engine dispatch",
-     "batched prefill of same-bucket prompts, to their first tokens"),
+     "batched prefill of same-bucket prompts, to the program's dispatch"),
     ("engine.extend", "engine dispatch",
-     "prefix-reusing or chunked-prefill piece, to its token on the host"),
+     "prefix-reusing or chunked-prefill piece, to the program's dispatch"),
     ("engine.release", "engine dispatch", "slot release / park program"),
     ("engine.install_key", "engine dispatch", "per-slot PRNG key install"),
     ("engine.upload", "engine dispatch",

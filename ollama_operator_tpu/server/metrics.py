@@ -200,10 +200,20 @@ GLOBAL.describe("tpu_model_followers_lost_total",
                 "Multi-host follower connections lost (send failure or "
                 "missed heartbeat); the world is degraded afterwards")
 GLOBAL.describe("tpu_model_admission_stall_ms_total",
-                "Wall-clock milliseconds decode dispatches spent stalled "
-                "behind admission prefill work (one-shot, batched, and "
-                "per chunked-prefill piece); divide by "
-                "tpu_model_prefill_chunks_total for ms/piece")
+                "Wall-clock milliseconds the scheduler thread stood "
+                "blocked on admission prefill work: the whole dispatch of "
+                "an awaited admission (one-shot, batched, a "
+                "chunked-prefill piece), the collect's wait of a launched "
+                "one; divide by tpu_model_prefill_chunks_total for "
+                "ms/piece")
+GLOBAL.describe("tpu_model_admissions_total",
+                "Requests admitted into a slot, by how the loop took the "
+                "first token (mode=launched|awaited): launched = the "
+                "prefill was dispatched without a host sync and its token "
+                "collected behind the next decode chunk's launch; awaited "
+                "= the loop waited for it where the admission was made (a "
+                "synchronous loop, speculation on, a constrained request, "
+                "the last piece of a chunked prefill)")
 GLOBAL.describe("tpu_model_prefill_chunks_total",
                 "Chunked-prefill pieces dispatched (stall-free admission "
                 "of long prompts, one bucket-sized piece per scheduler "
@@ -304,9 +314,12 @@ GLOBAL.describe("tpu_model_tenant_decode_tokens_total",
                 "series behind WDRR fairness dashboards")
 GLOBAL.describe("tpu_model_dispatch_seconds",
                 "Device dispatch latency histogram by program kind "
-                "(kind=decode|admit|extend|spec): launch to tokens on "
-                "host (dispatches are double-buffered, so these overlap; "
-                "the engine's last values are in /api/ps dispatch)")
+                "(kind=decode|admit|extend|spec): what the dispatch "
+                "took, from the later of its launch and its predecessor's "
+                "tokens reaching the host to its own tokens on the host "
+                "(a dispatch queued behind one that still runs does not "
+                "count that one's remainder; the engine's last values "
+                "are in /api/ps dispatch)")
 GLOBAL.describe("tpu_model_metrics_gauge_errors_total",
                 "Gauge callables that raised during /metrics render; a "
                 "nonzero rate means a series is silently missing from "
@@ -622,6 +635,8 @@ for _kind in ("decode", "prefill", "spec"):
 for _sampler in ("argmax", "candidates"):
     GLOBAL.inc("tpu_model_decode_steps_total", 0.0,
                f'{{sampler="{_sampler}"}}')
+for _mode in ("launched", "awaited"):
+    GLOBAL.inc("tpu_model_admissions_total", 0.0, f'{{mode="{_mode}"}}')
 GLOBAL.inc("tpu_model_model_flops_total", 0.0)
 
 

@@ -223,7 +223,16 @@ def test_tier_metric_preseeds_cover_the_matrix():
     """metrics.py pre-seeds the tiered-KV hit/miss matrix (tier 0/1/2),
     the spill counter, and the restitch histogram so dashboards read 0,
     not absent, on engines that never spill."""
-    rendered = METRICS.render()
+    # a second, private load of the module: a registry that nothing but
+    # the seeding code has touched, so the 0 below is the pre-seed's
+    # whatever restitching test this worker ran before
+    import importlib.util
+    from ollama_operator_tpu.server import metrics as served
+    spec = importlib.util.spec_from_file_location("_fresh_metrics",
+                                                  served.__file__)
+    fresh = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fresh)
+    rendered = fresh.GLOBAL.render()
     for fam in ("tpu_model_tier_hit_tokens_total",
                 "tpu_model_tier_miss_tokens_total"):
         for tier in ("0", "1", "2"):

@@ -93,6 +93,54 @@ def test_mirrored_engine_broadcasts_before_execute():
     assert len(events) == 2
 
 
+def test_a_launched_admission_is_mirrored_and_its_wait_is_not():
+    """The leader's scheduler launches an admission through the mirror and
+    collects its token later: the launch rides the call stream (followers
+    replay it and never wait), the handle's wait does not, and a real
+    leader/follower pair ends with the same host state and token."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ollama_operator_tpu.models import config as cfglib
+    from ollama_operator_tpu.models import decoder
+    from ollama_operator_tpu.runtime.engine import Engine, EngineConfig
+
+    for name in ("admit_launch", "admit_many_launch", "extend_launch"):
+        assert name in F.MirroredEngine.MIRRORED
+    cfg = cfglib.PRESETS["tiny"]
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jnp.float32)
+    ecfg = EngineConfig(max_slots=2, max_seq_len=64,
+                        cache_dtype=jnp.float32, min_prefill_bucket=16)
+    leader, follower = Engine(cfg, params, ecfg=ecfg), \
+        Engine(cfg, params, ecfg=ecfg)
+    calls = []
+
+    class ReplayCP:
+        dispatch_lock = threading.RLock()
+
+        def broadcast(self, msg):
+            _, name, a, kw = msg
+            calls.append(name)
+            getattr(follower, name)(*a, **kw)     # replayed, never waited
+
+    me = F.MirroredEngine(leader, ReplayCP())
+    ids = np.arange(1, 10, dtype=np.int32)
+    handle = me.admit_launch(0, ids)
+    chunk = me.decode_n_launch(4)
+    first = handle.wait()                         # the leader alone waits
+    toks = chunk.wait()
+    assert calls == ["admit_launch", "decode_n_launch"]
+    assert follower.active[0] and (follower._host_lengths
+                                   == leader._host_lengths).all()
+    # the follower's device state is the leader's: its own next chunk
+    # gives what the leader's gives
+    np.testing.assert_array_equal(np.asarray(follower.decode_n(2))[:, 0],
+                                  np.asarray(leader.decode_n(2))[:, 0])
+    assert len(first) == 1 and toks.shape == (4, 2)
+
+
 def test_control_address_resolution():
     assert F.control_address({"TPU_DIST_CONTROL": "sts-0.svc:8477"}) == \
         ("sts-0.svc", 8477)

@@ -338,6 +338,104 @@ def test_vacancy_counters_add_up_to_slots_times_wall():
         sched.shutdown()
 
 
+# -- admissions by mode, and what a dispatch took -----------------------
+
+def _admissions():
+    return {m: METRICS.get("tpu_model_admissions_total", f'{{mode="{m}"}}')
+            for m in ("launched", "awaited")}
+
+
+@pytest.mark.parametrize("mode", ["launched", "awaited"])
+def test_admissions_counter_is_described_and_preseeded(mode):
+    text = METRICS.render()
+    assert "# HELP tpu_model_admissions_total " in text
+    assert re.search(
+        rf'^tpu_model_admissions_total\{{mode="{mode}"\}} [0-9.]+$', text,
+        re.M), f"mode={mode} absent from an idle scrape"
+
+
+@pytest.mark.parametrize("async_dispatch, mode", [(True, "launched"),
+                                                  (False, "awaited")])
+def test_admission_modes_add_up_to_the_requests_admitted(async_dispatch,
+                                                         mode):
+    """Five requests over two slots: each is admitted once, and counted
+    once, under the form its loop takes."""
+    cfg, params, eng, sched = make_stack(slots=2,
+                                         async_dispatch=async_dispatch)
+    try:
+        before = _admissions()
+        reqs = [sched.submit(np.array([i + 1, i + 2, i + 3], np.int32),
+                             GREEDY, max_tokens=4) for i in range(5)]
+        assert all(len(list(r.tokens())) == 4 for r in reqs)
+        moved = {m: v - before[m] for m, v in _admissions().items()}
+        assert sum(moved.values()) == len(reqs)
+        assert moved[mode] == len(reqs)
+    finally:
+        sched.shutdown()
+
+
+def test_a_chunk_launched_behind_another_does_not_count_its_predecessor():
+    """tpu_model_dispatch_seconds{kind="decode"} is what a dispatch took:
+    from the later of its launch and its predecessor's tokens reaching the
+    host. Two chunks launched back to back: the second's observation
+    starts where the first landed, not at its own launch."""
+    cfg, params, eng, sched = make_stack(slots=2)
+    sched._stop.set()
+    sched._wake.set()
+    sched._thread.join(timeout=5)
+    lab = '{kind="decode"}'
+    try:
+        eng.admit(0, np.array([1, 2, 3, 4], np.int32), GREEDY)
+        eng.decode_n(4)                           # compiled before the pair
+        n0, s0 = _series("tpu_model_dispatch_seconds", lab)
+        h1 = eng.decode_n_launch(4)
+        h2 = eng.decode_n_launch(4)
+        time.sleep(0.05)                          # both run out meanwhile
+        sched._wait_handle(h1)
+        time.sleep(0.02)
+        sched._wait_handle(h2)
+        n1, s1 = _series("tpu_model_dispatch_seconds", lab)
+        assert n1 == n0 + 2
+        assert h1.t_begin == pytest.approx(h1.t_launch)   # nothing before it
+        assert h2.t_launch < h1.t_done == h2.t_begin < h2.t_done
+        assert s1 - s0 == pytest.approx(
+            (h1.t_done - h1.t_launch) + (h2.t_done - h1.t_done), abs=1e-6)
+        # launch to tokens would have counted the first chunk's 50 ms again
+        assert h2.t_done - h2.t_begin < (h2.t_done - h2.t_launch) - 0.04
+        assert eng.dispatch_ms["decode"] == pytest.approx(
+            (h2.t_done - h2.t_begin) * 1e3)
+    finally:
+        sched.shutdown()
+        eng.release(0)
+
+
+@pytest.mark.parametrize("admitted,want", [
+    (None, None),                    # the parent: no such counter
+    ((0, 0), None),                  # nobody admitted in the window
+    ((106, 0), 100.0), ((30, 10), 75.0), ((0, 8), 0.0)])
+def test_admit_launched_share_reads_the_schedulers_own_count(admitted, want):
+    """The benchmark's reader over two scrapes of the real registry's
+    text: the window's launched admissions over all its admissions;
+    nothing, and no raise, where the program has no such counter."""
+    import types
+
+    from benchmark import prom, run
+    from ollama_operator_tpu.server.metrics import Metrics
+    reg = Metrics()
+    reg.inc("tpu_model_generated_tokens_total", 5.0)
+    if admitted is not None:
+        reg.inc("tpu_model_admissions_total", 7.0, '{mode="launched"}')
+        reg.inc("tpu_model_admissions_total", 3.0, '{mode="awaited"}')
+    before = prom.parse(reg.render())
+    for mode, n in zip(("launched", "awaited"), admitted or ()):
+        reg.inc("tpu_model_admissions_total", float(n),
+                '{mode="%s"}' % mode)
+    ctx = types.SimpleNamespace(before=before,
+                                after=prom.parse(reg.render()))
+    got = run.layer_reader("admit_launched_share").read(ctx)
+    assert got == (None if want is None else pytest.approx(want))
+
+
 # -- device scopes in every path's lowered program ---------------------
 
 def _lowered_texts(monkeypatch, **ecfg_kw):
