@@ -67,7 +67,8 @@ if "ref" in what:
     ref = sc.load_reference(conf)
     T = 257
     k = conf["num_experts_per_tok"]
-    Lr = conf["num_hidden_layers"] - conf.get("num_dense_layers", 0)
+    Lr = conf["num_hidden_layers"] - conf.get(
+        "num_dense_layers", conf.get("first_k_dense_replace", 0))
     p = jax.tree_util.tree_map(sds, params)
     t = jax.ShapeDtypeStruct((T,), jnp.int32, sharding=one)
     ch = {"moe.route": jax.ShapeDtypeStruct((Lr, T, k), jnp.int32, sharding=one)}

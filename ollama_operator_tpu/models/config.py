@@ -133,10 +133,17 @@ class ModelConfig:
     dense_ffn_dim: int = 0
     # hybrid stacks: one letter a layer, "m" a Mamba-2 mixer
     # (granitemoehybrid), "c" a gated short convolution (lfm2), "A"
-    # attention; "" = every layer attends. A stack has ONE recurrent kind
-    # beside attention (no published stack mixes "m" and "c"). A string,
+    # attention over every earlier position, "w" attention over the last
+    # sliding_window positions (exaone_moe), whose keys and values are a
+    # ring of that many positions a slot; "" = every layer attends. A
+    # stack has ONE kind beside "A" (no published stack mixes two of "m",
+    # "c" and "w"). "A" and "w" share one stack of projections. A string,
     # so the config stays hashable and arrives whole from JSON.
     layer_kinds: str = ""
+    # the attention kinds of a hybrid stack whose q and k rotate, where
+    # cfg.rope: "" = both; "w" = the window layers alone, the full layers
+    # without positional embedding (the EXAONE-4 hybrid convention)
+    rope_kinds: str = ""
     # Mamba-2 mixer sizes (one group of B/C): d_inner = ssm_heads *
     # ssm_head_dim; the state a slot carries is [ssm_heads, ssm_head_dim,
     # ssm_state] float32 a layer plus ssm_conv - 1 columns of the
@@ -190,9 +197,20 @@ class ModelConfig:
         return self.n_layers - self.n_dense_layers if self.n_experts else 0
 
     @property
-    def n_attn_layers(self) -> int:
+    def n_window_layers(self) -> int:
+        return self.layer_kinds.count("w")
+
+    @property
+    def n_full_layers(self) -> int:
+        """Layers whose keys and values are kept at every position: the
+        rows of the cache."""
         return (self.layer_kinds.count("A") if self.layer_kinds
                 else self.n_layers)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers with attention's projections, full or window."""
+        return self.n_full_layers + self.n_window_layers
 
     @property
     def ssm_inner(self) -> int:
@@ -214,6 +232,14 @@ class ModelConfig:
             + self.n_conv_layers * (self.conv_kernel - 1) * self.dim)
 
     @property
+    def window_ring_bytes(self) -> int:
+        """Keys and values of the window layers' rings one sequence
+        carries, as the TPU's default cache keeps them: int8 codes and a
+        float32 scale a head a position."""
+        return (2 * self.n_window_layers * self.n_kv_heads
+                * self.sliding_window * (self.head_dim + 4))
+
+    @property
     def rotary_dim(self) -> int:
         rd = int(self.head_dim * self.rotary_pct)
         return rd - rd % 2
@@ -232,7 +258,10 @@ class ModelConfig:
             ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_state
                         + self.ssm_heads) + self.ssm_inner * d)
             conv = d * 3 * d + d * d
-            dense = 3 * d * self.dense_ffn_dim
+            # the leading dense layers' MLP, at its own width and in the
+            # stack's own form
+            dense = (3 if self.mlp_type == "gated" else 2) \
+                * d * self.dense_ffn_dim
             return (self.n_attn_layers * attn + self.n_ssm_layers * ssm
                     + self.n_conv_layers * conv
                     + self.n_dense_layers * dense
@@ -276,7 +305,8 @@ class ModelConfig:
             assert len(self.layer_kinds) == self.n_layers, (
                 f"layer_kinds names {len(self.layer_kinds)} layers, "
                 f"n_layers is {self.n_layers}")
-            assert set(self.layer_kinds) <= {"m", "c", "A"}, self.layer_kinds
+            assert set(self.layer_kinds) <= {"m", "c", "w", "A"}, (
+                self.layer_kinds)
             assert "A" in self.layer_kinds, "no attention layer to cache"
             assert not ("m" in self.layer_kinds and "c" in self.layer_kinds), (
                 "one recurrent kind a stack")
@@ -284,9 +314,23 @@ class ModelConfig:
                 assert self.ssm_heads > 0 and self.ssm_conv >= 2
             if "c" in self.layer_kinds:
                 assert self.conv_kernel >= 2
-            assert not (self.parallel_block or self.post_norms
-                        or self.altern_sliding or self.sliding_window), (
-                "hybrid stacks run the plain pre-norm block")
+            for field in ("parallel_block", "post_norms", "altern_sliding"):
+                assert not getattr(self, field), (
+                    f"hybrid stacks run the plain pre-norm block: {field} "
+                    "is set")
+            if "w" in self.layer_kinds:
+                assert not set(self.layer_kinds) & {"m", "c"}, (
+                    "window layers stand beside full attention alone: "
+                    "no recurrent kind in their stack")
+                assert self.sliding_window > 0, (
+                    "window layers (\"w\") need sliding_window > 0")
+            else:
+                assert not self.sliding_window, (
+                    "sliding_window in a hybrid stack belongs to its "
+                    "window layers: layer_kinds has no \"w\"")
+        assert set(self.rope_kinds) <= {"A", "w"} and (
+            not self.rope_kinds or self.layer_kinds), (
+            "rope_kinds names attention kinds of a hybrid stack")
         if self.n_dense_layers:
             assert self.layer_kinds and self.n_experts, (
                 "leading dense layers stand before a hybrid stack's "
@@ -502,6 +546,37 @@ PRESETS = {
         dense_ffn_dim=96, layer_kinds="ccAcccAc", conv_kernel=3,
         qk_norm=True, rope_theta=1000000.0, tie_embeddings=True,
         max_seq_len=256),
+    # K-EXAONE-236B-A23B (exaone_moe), ONE CHIP'S SHARE of a stated
+    # deployment: each layer shared by 8 chips (expert parallel: this is
+    # chip 0, routed experts 0-15 of 128, rows 0-19,199 of the untied
+    # embedding and head; attention and the shared expert on every chip)
+    # and the first pipeline stage of 8 of the 48 layers, w w w A w w w A
+    # (two whole periods: layer 0 with its dense MLP, seven routed ones).
+    # Every width is the published one: hidden 6144, GQA 64/8 at head_dim
+    # 128 with q/k norms, window 128, dense width 18432, sigmoid router
+    # 128 / 8 a token scaled 2.5, expert and shared-expert width 2048.
+    # Window layers rotate at theta 1e6, full layers carry no position.
+    # benchmark/configs/k-exaone-236b-a23b.json states the cut and what
+    # the published config leaves to assumption.
+    "k-exaone-236b-a23b": _mk(
+        arch="exaonemoe", vocab_size=19200, dim=6144, n_layers=8,
+        n_heads=64, n_kv_heads=8, head_dim=128, ffn_dim=2048,
+        n_experts=128, n_experts_used=8, n_experts_held=16, expert_first=0,
+        n_shared_ffn=2048, shared_gate=False, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=2.5,
+        n_dense_layers=1, dense_ffn_dim=18432, layer_kinds="wwwAwwwA",
+        sliding_window=128, rope_kinds="w", qk_norm=True,
+        rope_theta=1000000.0, norm_eps=1e-5, max_seq_len=262144),
+    # the same shape at toy widths (tests, --rehearse): two periods
+    "tiny-exaone": _mk(
+        arch="exaonemoe", vocab_size=256, dim=64, n_layers=8, n_heads=4,
+        n_kv_heads=2, head_dim=16, ffn_dim=32, n_experts=8,
+        n_experts_used=3, n_experts_held=4, expert_first=0,
+        n_shared_ffn=32, shared_gate=False, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=2.5,
+        n_dense_layers=1, dense_ffn_dim=96, layer_kinds="wwwAwwwA",
+        sliding_window=8, rope_kinds="w", qk_norm=True,
+        rope_theta=1000000.0, max_seq_len=256),
     "dolphin-mixtral": _mk(arch="llama", vocab_size=32002, dim=4096,
                            n_layers=32, n_heads=32, n_kv_heads=8,
                            head_dim=128, ffn_dim=14336, n_experts=8,

@@ -42,6 +42,8 @@ Params pytree layout (all leaves jnp arrays; layer leaves stacked on axis 0):
     ssm_out [Lm, di, D]     (di = H * P, C = di + 2 * N)
     or, where the recurrent mixer is a gated short convolution ("c", lfm2),
     conv_in [Lc, D, 3D]  conv_w [Lc, K, D]  conv_out [Lc, D, D]
+    Window layers ("w", exaone_moe) have attention's projections: La counts
+    them with the "A" layers, in layer order
     cfg.n_dense_layers leading layers of such a stack have one dense MLP,
     w_gate/w_up [Ld, D, Fd]  w_down [Ld, Fd, D], and the router's leaves
     are stacked over the Lr = L - Ld layers after them (router_bias
@@ -668,7 +670,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
     """
     from ..ops.quant_cache import is_quantized_cache
     B, T = tokens.shape
-    k_cache, v_cache, ssm, conv = split_state(k_cache, v_cache)
+    k_cache, v_cache, state = split_state(k_cache, v_cache)
     kc_arr = k_cache["q"] if is_quantized_cache(k_cache) else k_cache
     L, _, _, S, _ = kc_arr.shape
     A = S if attn_len is None else min(attn_len, S)
@@ -681,7 +683,10 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
     k_pos = jnp.arange(A, dtype=jnp.int32)[None, None, :]
     q_pos = positions[:, :, None]
 
-    mask = _causal_window_mask(k_pos, q_pos, cfg.sliding_window)
+    # a hybrid stack's window belongs to its "w" layers, which attend over
+    # rings of their own (``_ring_attend``); its "A" layers see every key
+    mask = _causal_window_mask(
+        k_pos, q_pos, 0 if cfg.layer_kinds else cfg.sliding_window)
     m_full = (_causal_window_mask(k_pos, q_pos, 0)
               if cfg.altern_sliding else None)
 
@@ -702,7 +707,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return lax.dynamic_slice(c, (i,) + (0,) * (len(sizes) - 1),
                                  (1,) + sizes[1:])[0]
 
-    def attend(lp, h, kc, vc, i, mask_l, cos_i, sin_i):
+    def attend(lp, h, kc, vc, i, mask_l, cos_i, sin_i, cfg=cfg):
         """One layer's attention mixer against layer ``i`` of the cache:
         project, write the new keys and values, attend, project out."""
         q, k, v = _qkv(cfg, lp, h, cos_i, sin_i)
@@ -743,14 +748,22 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
     load = (None if route_live is None
             else jnp.zeros((cfg.n_experts,), jnp.int32))
     if cfg.layer_kinds:
-        x, k_cache, v_cache, ssm, conv, load = _hybrid_layers(
-            params, cfg, x, k_cache, v_cache, ssm, conv, _valid_rows(
-                n_valid, B, T),
+        nv = _valid_rows(n_valid, B, T)
+        cfg_a, cfg_w = _kind_cfgs(cfg)
+
+        def attend_win(ap, h, win, row):
+            q, k, v = _qkv(cfg_w, ap, h, cos, sin)
+            out, win = _ring_attend(cfg_w, q, k, v, win, row, lengths, nv,
+                                    scale)
+            return _proj_out(cfg, ap, out, B, T), win
+
+        x, k_cache, v_cache, state, load = _hybrid_layers(
+            params, cfg, x, k_cache, v_cache, state, nv,
             lambda ap, h, kc, vc, row: attend(ap, h, kc, vc, row, mask,
-                                              cos, sin), route_live, load)
+                                              cos, sin, cfg_a),
+            attend_win, route_live, load)
         logits = _unembed(cfg, params, _last_real(x, n_valid))
-        return (logits, *join_state(k_cache, v_cache, ssm, conv),
-                *_given(load))
+        return (logits, *join_state(k_cache, v_cache, state), *_given(load))
 
     def body(carry, layer_in):
         x, kc, vc, load = carry
@@ -810,6 +823,16 @@ def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load):
 # decode batch, leaves it exactly as it was. It travels inside the two
 # cache trees (``join_state``), so the engine's programs hand it on as they
 # hand on the cache.
+#
+# A stack of window and full attention ("w" beside "A", exaone_moe) has no
+# recurrent mixer: both kinds are attention over ONE stack of projections
+# and differ in mask, rotary embedding (cfg.rope_kinds) and cache. An "A"
+# layer's keys and values are a full-length row of the cache; a "w" layer's
+# are a RING of cfg.sliding_window positions a slot, position p at p % W,
+# so slot j holds the newest position congruent to j. The rings are state
+# in the sense above: they ride in the cache trees ({"win"} beside the
+# keys' and the values' leaves), ``n_valid`` guards every write, and they
+# can be advanced and not cut back.
 
 _ATTN_STACK = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
                "q_norm_w", "k_norm_w")
@@ -818,52 +841,179 @@ _DENSE_FFN = ("w_gate", "w_up", "w_down", "b_up", "b_down")
 
 
 def split_state(k_cache, v_cache):
-    """(k_cache, v_cache, ssm, conv): the recurrent state taken out of the
-    two cache trees; (k_cache, v_cache, None, None) for trees without, and
-    ``ssm`` None for a stack whose state is a convolution's alone."""
-    if not (isinstance(v_cache, dict) and "conv" in v_cache):
-        return k_cache, v_cache, None, None
-    kc = {k: v for k, v in k_cache.items() if k != "ssm"}
-    vc = {k: v for k, v in v_cache.items() if k != "conv"}
+    """(k_cache, v_cache, state): what a slot carries beside its full-length
+    keys and values, taken out of the two cache trees. ``state`` is None for
+    trees without, else (ssm, conv, win): a Mamba stack's (ssm, conv, None),
+    a short-convolution stack's (None, conv, None), a window stack's (None,
+    None, (k rings, v rings)), each ring array [Lw, B, KvH, W, hd] or its
+    int8 form {"q", "s" [Lw, B, KvH, W]}."""
+    if not (isinstance(v_cache, dict)
+            and ("conv" in v_cache or "win" in v_cache)):
+        return k_cache, v_cache, None
+    kc = {k: v for k, v in k_cache.items() if k not in ("ssm", "win")}
+    vc = {k: v for k, v in v_cache.items() if k not in ("conv", "win")}
     if "kv" in kc:
         kc, vc = kc["kv"], vc["kv"]
-    return kc, vc, k_cache.get("ssm"), v_cache["conv"]
+    win = (k_cache["win"], v_cache["win"]) if "win" in v_cache else None
+    return kc, vc, (k_cache.get("ssm"), v_cache.get("conv"), win)
 
 
-def join_state(k_cache, v_cache, ssm, conv):
-    """Inverse of ``split_state``: {"ssm"} beside the keys' leaves,
-    {"conv"} beside the values' (a plain array cache goes under "kv")."""
-    if conv is None:
+def join_state(k_cache, v_cache, state):
+    """Inverse of ``split_state``: {"ssm"} / {"win"} beside the keys'
+    leaves, {"conv"} / {"win"} beside the values' (a plain array cache goes
+    under "kv")."""
+    if state is None:
         return k_cache, v_cache
+    ssm, conv, win = state
     if not isinstance(k_cache, dict):
         k_cache, v_cache = {"kv": k_cache}, {"kv": v_cache}
     if ssm is not None:
         k_cache = {**k_cache, "ssm": ssm}
-    return k_cache, {**v_cache, "conv": conv}
+    if conv is not None:
+        v_cache = {**v_cache, "conv": conv}
+    if win is not None:
+        k_cache, v_cache = ({**k_cache, "win": win[0]},
+                            {**v_cache, "win": win[1]})
+    return k_cache, v_cache
 
 
-def empty_state(cfg: ModelConfig, B: int):
-    """(ssm [Lm, B, H, P, N], conv [Lm, B, K-1, C]) float32 zeros: what a
-    sequence carries before its first position; (None, conv [Lc, B, K-1,
-    D]) for a stack of short convolutions."""
+def empty_state(cfg: ModelConfig, B: int, kv_dtype=jnp.float32):
+    """What a sequence carries before its first position, zeros: (ssm [Lm,
+    B, H, P, N], conv [Lm, B, K-1, C], None) float32; (None, conv [Lc, B,
+    K-1, D], None) for a stack of short convolutions; (None, None, (k, v))
+    for a stack of window layers, rings [Lw, B, KvH, W, hd] of ``kv_dtype``
+    (int8: codes and float32 scales, as the cache keeps its rows)."""
+    if cfg.n_window_layers:
+        shape = (cfg.n_window_layers, B, cfg.n_kv_heads, cfg.sliding_window,
+                 cfg.head_dim)
+
+        def ring():
+            if jnp.dtype(kv_dtype) == jnp.int8:
+                return {"q": jnp.zeros(shape, jnp.int8),
+                        "s": jnp.zeros(shape[:-1], jnp.float32)}
+            return jnp.zeros(shape, kv_dtype)
+        return None, None, (ring(), ring())
     if cfg.n_conv_layers:
         return None, jnp.zeros((cfg.n_conv_layers, B, cfg.conv_kernel - 1,
-                                cfg.dim), jnp.float32)
+                                cfg.dim), jnp.float32), None
     Lm = cfg.n_ssm_layers
     return (jnp.zeros((Lm, B, cfg.ssm_heads, cfg.ssm_head_dim,
                        cfg.ssm_state), jnp.float32),
             jnp.zeros((Lm, B, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
-                      jnp.float32))
+                      jnp.float32), None)
+
+
+def quantize_rings(state):
+    """``state`` with a prefill's float rings as the int8 cache keeps them
+    (an admission's, before it goes into the slot's rows)."""
+    from ..ops.quant_cache import quantize_kv
+    ssm, conv, win = state
+    if win is not None:
+        win = tuple(dict(zip("qs", quantize_kv(r))) for r in win)
+    return ssm, conv, win
 
 
 def _hybrid_rows(cfg: ModelConfig):
-    """(is_attn [L] bool, row [L]): each layer's kind and its row in its
-    own mixer's stack; host lists, for the scans to cut."""
-    rows, n = [], {"A": 0, "m": 0, "c": 0}
+    """(is_attn [L] bool, row [L], wrow): each layer's kind ("A" or the
+    stack's other one), its row among the layers of its own kind (the
+    state's or the cache's row) and its row in its weights' stack; host
+    lists, for the scans to cut. ``wrow`` is None where the two are the
+    same: only "w" layers, which share the "A" layers' stack of
+    projections, count their weights' rows over both kinds."""
+    rows, wrows, n = [], [], {"A": 0, "m": 0, "c": 0, "w": 0}
     for c in cfg.layer_kinds:
         rows.append(n[c])
+        wrows.append(n["A"] + n["w"] if c in "Aw" else n[c])
         n[c] += 1
-    return [c == "A" for c in cfg.layer_kinds], rows
+    return ([c == "A" for c in cfg.layer_kinds], rows,
+            wrows if n["w"] else None)
+
+
+def _kind_cfgs(cfg: ModelConfig):
+    """(cfg of the "A" layers, cfg of the "w" layers): the stack's config
+    with the window and the rotary embedding as each kind has them."""
+    def rotates(kind):
+        return cfg.rope and (not cfg.rope_kinds or kind in cfg.rope_kinds)
+    return (dataclasses.replace(cfg, sliding_window=0, rope=rotates("A")),
+            dataclasses.replace(cfg, rope=rotates("w")))
+
+
+def _ring_pos(last, W: int):
+    """[B, W] int32: the position slot j of a ring holds once position
+    ``last`` [B] is written, the newest one <= last congruent to j mod W;
+    negative where no position has reached the slot."""
+    j = jnp.arange(W, dtype=jnp.int32)[None, :]
+    return last[:, None] - (last[:, None] - j) % W
+
+
+def _ring_merge(old, new, lengths, n_valid):
+    """A ring advanced over a block: old [B, KvH, W, ...] as position
+    lengths - 1 left it, new [B, KvH, T, ...] the block's positions, of
+    which the first n_valid [B] are real. Slot j takes the block's newest
+    real position congruent to j where there is one and keeps its own
+    otherwise, so a row with nothing real keeps its very bits."""
+    W, T = old.shape[2], new.shape[2]
+    t = _ring_pos(lengths + n_valid - 1, W) - lengths[:, None]     # [B, W]
+    at = jnp.clip(t, 0, T - 1)[:, None, :]
+    fresh = t[:, None, :] >= 0
+    if old.ndim == 4:
+        at, fresh = at[..., None], fresh[..., None]
+    if T > 1:       # one new position broadcasts to the slot that takes it
+        new = jnp.take_along_axis(new, at, axis=2)
+    return jnp.where(fresh, new, old)
+
+
+@device_scope("attn.window")
+def _ring_attend(cfg: ModelConfig, q, k, v, win, row, lengths, n_valid,
+                 scale):
+    """Window attention of one layer over its ring, and the ring advanced.
+    q [B, T, H, hd], k and v [B, T, KvH, hd] at positions lengths + t (as
+    ``_qkv`` leaves them); win = (k rings, v rings) [Lw, B, KvH, W, hd] or
+    int8 {"q", "s"}, of which this layer reads and writes row ``row``;
+    n_valid [B]: positions at or past it write nothing. A key is visible
+    iff its position is >= 0, <= the query's and > the query's - W. The
+    ring takes the block's last W real positions (``_ring_merge``: a select
+    over the whole ring, with which this scope is 0.55 ms of a decode step
+    of six layers at 64 slots; a scatter of one row a slot and head made it
+    1.56, 0.67 of that the scales' scatter alone: my chip runs, PR 39). One new
+    position (the decode step) attends over the ring with itself in it,
+    which then IS its window; more attend over the ring as it stood plus
+    the new block. Returns (out [B, T, H, hd], win)."""
+    from ..ops import quant_cache as QC
+    T, W = q.shape[1], cfg.sliding_window
+    quant = QC.is_quantized_cache(win[0])
+    k = k.transpose(0, 2, 1, 3)                       # [B, KvH, T, hd]
+    v = v.transpose(0, 2, 1, 3)
+    tree_map = jax.tree_util.tree_map
+
+    def stored(x, like):
+        if quant:
+            return dict(zip("qs", QC.quantize_kv(x)))
+        return x.astype(like.dtype)
+
+    def attend(ks, vs, k_pos, q_pos):
+        k_pos, q_pos = k_pos[:, None, :], q_pos[:, :, None]
+        ok = (k_pos >= 0) & (k_pos <= q_pos) & (k_pos > q_pos - W)
+        mask = jnp.where(ok, 0.0, -1e30).astype(jnp.float32)[:, None]
+        if quant:
+            return QC.attend_hf_q(q, ks, vs, mask, scale, cfg.attn_softcap)
+        return attend_hf(q, ks.astype(q.dtype), vs.astype(q.dtype), mask,
+                         scale, cfg.attn_softcap)
+
+    new = (stored(k, win[0]), stored(v, win[1]))
+    q_pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    old = tree_map(
+        lambda ring: lax.dynamic_index_in_dim(ring, row, 0, False), win)
+    merged = tree_map(lambda a, b: _ring_merge(a, b, lengths, n_valid),
+                      old, new)
+    win = tree_map(
+        lambda ring, x: lax.dynamic_update_index_in_dim(ring, x, row, 0),
+        win, merged)
+    if T == 1:
+        return attend(*merged, _ring_pos(lengths, W), q_pos), win
+    both = tree_map(lambda a, b: jnp.concatenate([a, b], axis=2), old, new)
+    return attend(*both, jnp.concatenate(
+        [_ring_pos(lengths - 1, W), q_pos], axis=1), q_pos), win
 
 
 def _valid_rows(n_valid, B: int, T: int):
@@ -1020,19 +1170,25 @@ def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
     return out, ssm, conv
 
 
-def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, ssm, conv,
-                   n_valid, attend, live=None, load=None):
+def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
+                   n_valid, attend, attend_win=None, live=None, load=None):
     """The layer scans of a hybrid stack. ``attend(ap, h, kc, vc, row) ->
     (out, kc, vc)`` is the caller's attention mixer against row ``row`` of
-    its keys and values (a fresh chunk's or the cache's). Everything a
-    layer may write rides the carry, and each mixer hands the other's
-    through untouched. ``load`` [E] int32 (or None) is advanced by what
-    each router kept for the ``live`` rows (``_residual_counting``)."""
+    its keys and values (a fresh chunk's or the cache's), ``attend_win(ap,
+    h, win, row) -> (out, win)`` its window mixer against row ``row`` of
+    the rings. ``state`` is ``split_state``'s. Everything a layer may write
+    rides the carry, and each mixer hands the others' through untouched.
+    ``load`` [E] int32 (or None) is advanced by what each router kept for
+    the ``live`` rows (``_residual_counting``). Returns (x, kc, vc, state,
+    load)."""
     layers = params["layers"]
-    # the recurrent mixer's leaves, by their names' prefix
-    prefix = "ssm_" if cfg.n_ssm_layers else "conv_"
+    # the other mixer's leaves, by their names' prefix; window layers have
+    # none of their own
+    prefix = ("ssm_" if cfg.n_ssm_layers else
+              "conv_" if cfg.n_conv_layers else None)
     attn_stack = {k: v for k, v in layers.items() if k in _ATTN_STACK}
-    rec_stack = {k: v for k, v in layers.items() if k.startswith(prefix)}
+    rec_stack = {k: v for k, v in layers.items()
+                 if prefix and k.startswith(prefix)}
     shared = {k: v for k, v in layers.items()
               if k not in attn_stack and k not in rec_stack}
 
@@ -1042,35 +1198,39 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, ssm, conv,
             stack)
 
     def body(cfg, carry, layer_in):
-        x, kc, vc, ssm, conv, load = carry
-        lp, is_attn, row = layer_in
+        x, kc, vc, ssm, conv, win, load = carry
+        lp, is_attn, row, wrow = layer_in
+        wrow = row if wrow is None else wrow
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
 
-        def attn_mixer(h, kc, vc, ssm, conv):
-            out, kc, vc = attend(take(attn_stack, row), h, kc, vc, row)
-            return out, kc, vc, ssm, conv
+        def attn_mixer(h, kc, vc, ssm, conv, win):
+            out, kc, vc = attend(take(attn_stack, wrow), h, kc, vc, row)
+            return out, kc, vc, ssm, conv, win
 
-        def rec_mixer(h, kc, vc, ssm, conv):
+        def other_mixer(h, kc, vc, ssm, conv, win):
+            if win is not None:
+                out, win = attend_win(take(attn_stack, wrow), h, win, row)
+                return out, kc, vc, ssm, conv, win
             rp = take(rec_stack, row)
             if ssm is None:
                 out, conv = _conv_mixer(cfg, rp, h, conv, row, n_valid)
             else:
                 out, ssm, conv = _ssm_mixer(cfg, rp, h, ssm, conv, row,
                                             n_valid)
-            return out, kc, vc, ssm, conv
+            return out, kc, vc, ssm, conv, win
 
-        # the recurrent mixer is the TRUE branch on purpose: with the
+        # the stack's other mixer is the TRUE branch on purpose: with the
         # branches the other way round the TPU compiler hands the whole
         # state through the attention layer's branch by a copy (1.24 GB of
         # Mamba state at 32 slots, 2.9 ms of a 24.8 ms decode step: my chip
         # run, PR 29), this way round both branches update or pass their
         # buffers in place
-        out, kc, vc, ssm, conv = lax.cond(~is_attn, rec_mixer, attn_mixer,
-                                          h, kc, vc, ssm, conv)
+        out, kc, vc, ssm, conv, win = lax.cond(
+            ~is_attn, other_mixer, attn_mixer, h, kc, vc, ssm, conv, win)
         x, load = _residual_counting(cfg, lp, x, h, out, live, load)
-        return (x, kc, vc, ssm, conv, load), None
+        return (x, kc, vc, ssm, conv, win, load), None
 
-    is_attn, rows = _hybrid_rows(cfg)
+    is_attn, rows, wrows = _hybrid_rows(cfg)
     L, Ld = cfg.n_layers, cfg.n_dense_layers
     spans = [(0, L, cfg, shared)]
     if Ld:
@@ -1086,22 +1246,29 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, ssm, conv,
                   {**{k: v[:Ld] for k, v in norms.items()}, **dense}),
                  (Ld, L, cfg,
                   {**{k: v[Ld:] for k, v in norms.items()}, **routed})]
-    carry = (x, kc, vc, ssm, conv, load)
+    carry = (x, kc, vc, *state, load)
     for lo, hi, cfg_l, xs in spans:
         carry, _ = lax.scan(
             functools.partial(body, cfg_l), carry,
             (xs, jnp.asarray(is_attn[lo:hi]),
-             jnp.asarray(rows[lo:hi], jnp.int32)))
-    return carry
+             jnp.asarray(rows[lo:hi], jnp.int32),
+             None if wrows is None else jnp.asarray(wrows[lo:hi],
+                                                    jnp.int32)))
+    x, kc, vc, *state, load = carry
+    return x, kc, vc, tuple(state), load
 
 
 def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
                     inputs_embeds, mesh):
     """``prefill_chunk`` of a hybrid stack: a fresh chunk from the empty
-    state. Keys and values of the attention layers come back [La, B, KvH,
-    T, hd]; the state as each row's position n_valid - 1 left it."""
+    state. Keys and values of the full-attention layers come back [La, B,
+    KvH, T, hd]; the state as each row's position n_valid - 1 left it (a
+    window layer's ring holds the last W real positions of the chunk, in
+    the chunk's own type: the engine quantizes them as it does the
+    rows)."""
     B, T = tokens.shape
     scale = _attn_scale(cfg)
+    cfg_a, cfg_w = _kind_cfgs(cfg)
     mask = jnp.broadcast_to(causal_mask(T, T, 0), (B, 1, T, T))
     cos = sin = None
     if cfg.rope:
@@ -1113,22 +1280,40 @@ def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
         x = _embed(cfg, params, tokens)
 
     def attend(ap, h, kc, vc, row):
-        q, k, v = _qkv(cfg, ap, h, cos, sin)
+        q, k, v = _qkv(cfg_a, ap, h, cos, sin)
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
         with device_scope("attn.core"):
-            attn = chunk_attention(cfg, q, k, v, mask, scale, mesh=mesh)
+            attn = chunk_attention(cfg_a, q, k, v, mask, scale, mesh=mesh)
         kc = lax.dynamic_update_index_in_dim(kc, k, row, 0)
         vc = lax.dynamic_update_index_in_dim(vc, v, row, 0)
         return _proj_out(cfg, ap, attn, B, T), kc, vc
 
-    kv0 = jnp.zeros((cfg.n_attn_layers, B, cfg.n_kv_heads, T, cfg.head_dim),
+    def attend_win(ap, h, win, row):
+        q, k, v = _qkv(cfg_w, ap, h, cos, sin)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        with device_scope("attn.window"):
+            attn = chunk_attention(cfg_w, q, k, v, mask_w, scale, mesh=mesh)
+            # the ring as the chunk's last real position leaves it
+            zero = jnp.zeros((B,), jnp.int32)
+            win = tuple(lax.dynamic_update_index_in_dim(
+                ring, _ring_merge(jnp.zeros_like(ring[0]), new, zero, nv),
+                row, 0) for ring, new in zip(win, (k, v)))
+        return _proj_out(cfg, ap, attn, B, T), win
+
+    kv0 = jnp.zeros((cfg.n_full_layers, B, cfg.n_kv_heads, T, cfg.head_dim),
                     x.dtype)
-    x, ks, vs, ssm, conv, _ = _hybrid_layers(
-        params, cfg, x, kv0, kv0, *empty_state(cfg, B),
-        _valid_rows(n_valid, B, T), attend)
+    state = empty_state(cfg, B, x.dtype)
+    nv = _valid_rows(n_valid, B, T)
+    if cfg.n_window_layers:
+        mask_w = jnp.broadcast_to(
+            causal_mask(T, T, 0, sliding_window=cfg.sliding_window),
+            (B, 1, T, T))
+    x, ks, vs, state, _ = _hybrid_layers(
+        params, cfg, x, kv0, kv0, state, nv, attend, attend_win)
     logits = _unembed(cfg, params, _last_real(x, n_valid))
-    return (logits, *join_state({"kv": ks}, {"kv": vs}, ssm, conv))
+    return (logits, *join_state({"kv": ks}, {"kv": vs}, state))
 
 
 # --------------------------------------------------------------------------
@@ -1659,7 +1844,10 @@ def forward_with_cache_paged(params: Params, cfg: ModelConfig,
     k_pos = jnp.arange(S_attn, dtype=jnp.int32)[None, None, :]
     q_pos = positions[:, :, None]
 
-    mask = _causal_window_mask(k_pos, q_pos, cfg.sliding_window)
+    # a hybrid stack's window belongs to its "w" layers, which attend over
+    # rings of their own (``_ring_attend``); its "A" layers see every key
+    mask = _causal_window_mask(
+        k_pos, q_pos, 0 if cfg.layer_kinds else cfg.sliding_window)
     m_full = (_causal_window_mask(k_pos, q_pos, 0)
               if cfg.altern_sliding else None)
 

@@ -124,7 +124,11 @@ def detect_peak_flops() -> Tuple[float, str]:
 
 def _layer_split(cfg: ModelConfig) -> Tuple[int, int]:
     """(full_attention_layers, sliding_window_layers)."""
-    L = cfg.n_attn_layers       # a hybrid stack's Mamba layers attend nothing
+    if cfg.layer_kinds:
+        # a hybrid stack says which is which; its recurrent layers attend
+        # nothing
+        return cfg.n_full_layers, cfg.n_window_layers
+    L = cfg.n_layers
     if cfg.sliding_window <= 0:
         return L, 0
     if cfg.altern_sliding:

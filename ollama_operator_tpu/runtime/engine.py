@@ -168,18 +168,21 @@ def resolve_serving_defaults(ecfg: "EngineConfig", cfg: ModelConfig,
 def _recurrent_slots(cfg: ModelConfig) -> int:
     """Slots of a hybrid stack's contiguous cache on the TPU, from the
     model alone. Its decode step is bound by the weights of the experts it
-    holds, so the batch is what fills them: the smallest power of two that
-    gives every expert four tokens a step (8 slots x 10 picks over
-    granite's 72 is 1.1 a step: a latency test, not a serving batch), while
-    the slots' recurrent state (what grows with the batch here: granite's
-    38.7 MB a slot against 8 MB of int8 keys and values at 4096 positions;
-    a short-convolution stack's is 196 KB) stays under an eighth of a v5e
-    chip's 16 GB."""
+    holds, so the batch is what fills them: the smallest power of two, 64
+    at most, that gives every expert of the router four tokens a step (8
+    slots x 10 picks over granite's 72 is 1.1 a step: a latency test, not
+    a serving batch), while what a slot carries beside its full-length
+    keys and values stays under an eighth of a v5e chip's 16 GB: the
+    recurrent state (granite's 38.7 MB a slot against 8 MB of int8 keys
+    and values at 4096 positions; a short-convolution stack's 196 KB) and
+    the window layers' rings (K-EXAONE's 1.6 MB). The full-length rows are
+    not counted: they grow with the context the server is started at."""
     want = 4 * cfg.n_experts / cfg.n_experts_used if cfg.n_experts else 8
     slots = 8
     while slots < min(want, 64):
         slots *= 2
-    while slots > 8 and slots * cfg.ssm_state_bytes > (2 << 30):
+    carried = cfg.ssm_state_bytes + cfg.window_ring_bytes
+    while slots > 8 and slots * carried > (2 << 30):
         slots //= 2
     return slots
 
@@ -472,12 +475,15 @@ class Engine:
         self.mesh = mesh
         B, S = ecfg.max_slots, min(ecfg.max_seq_len, cfg.max_seq_len)
         self.n_slots, self.max_seq = B, S
-        # layers that keep keys and values: all, but for a hybrid stack
-        L, KvH, hd = cfg.n_attn_layers, cfg.n_kv_heads, cfg.head_dim
+        # layers that keep keys and values at every position: all, but for
+        # a hybrid stack (its window layers keep rings, part of the state)
+        L, KvH, hd = cfg.n_full_layers, cfg.n_kv_heads, cfg.head_dim
         V = cfg.vocab_size
-        # a hybrid stack's slots carry a recurrent state beside their keys
-        # and values (models/decoder.py, hybrid section); it rides inside
-        # the two cache trees, so every program hands it on with them
+        # a hybrid stack's slots carry a state beside their full-length
+        # keys and values (models/decoder.py, hybrid section: a recurrent
+        # mixer's, or window layers' rings); it rides inside the two cache
+        # trees, so every program hands it on with them, and it can be
+        # advanced and not cut back
         self.recurrent = bool(cfg.layer_kinds)
         if cfg.n_experts:
             seed_expert_tokens(cfg.n_experts)
@@ -692,9 +698,21 @@ class Engine:
             cache_shape = (L, B, KvH, S, hd)  # head-first: (S, hd) tiles
             self.k_cache = zeros(cache_shape, ecfg.cache_dtype, cache_sh)
             self.v_cache = zeros(cache_shape, ecfg.cache_dtype, cache_sh)
+        full = self.kv_bytes
         if self.recurrent:
             self.k_cache, self.v_cache = decoder.join_state(
-                self.k_cache, self.v_cache, *decoder.empty_state(cfg, B))
+                self.k_cache, self.v_cache,
+                decoder.empty_state(cfg, B, ecfg.cache_dtype))
+        *carried, win = (decoder.split_state(self.k_cache, self.v_cache)[2]
+                         or (None, None, None))
+
+        def nbytes(tree):
+            return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
+        # device bytes of the cache by what holds them
+        # (tpu_model_cache_bytes{kind}): full-length rows or pages, the
+        # window layers' rings, recurrent state
+        self.cache_bytes = {"full": full, "window": nbytes(win),
+                            "state": nbytes(carried)}
         self.lengths = zeros((B,), jnp.int32, slot_sh)
         self.counts = zeros((B, V), jnp.int32, slot_sh2)
         # penalty ring: the last repeat_last_n token ids per slot (sentinel
@@ -902,8 +920,7 @@ class Engine:
 
         def slot_rows(state, slot):
             """One slot's rows [L, 1, ...] of each leaf [L, B, ...] of the
-            recurrent state (ssm, conv); ssm is None for a stack whose
-            state is a convolution's alone."""
+            state (``decoder.split_state``'s: recurrent state or rings)."""
             return jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_slice(
                     a, (0, slot) + (0,) * (a.ndim - 2),
@@ -912,7 +929,8 @@ class Engine:
         def put_rows(state, rows, slot):
             return jax.tree_util.tree_map(
                 lambda a, r: jax.lax.dynamic_update_slice(
-                    a, r, (0, slot) + (0,) * (a.ndim - 2)), state, rows)
+                    a, r.astype(a.dtype), (0, slot) + (0,) * (a.ndim - 2)),
+                state, rows)
 
         def real(n):
             """How many positions of a row are real, for the forward pass
@@ -986,14 +1004,14 @@ class Engine:
             positions carry id == vocab_size, which the scatter-add drops —
             image tokens never enter the counts), sample, and install
             chunk K/V + slot state."""
-            k_cache, v_cache, ssm, conv = decoder.split_state(k_cache,
-                                                              v_cache)
-            if conv is not None:
+            k_cache, v_cache, state = decoder.split_state(k_cache, v_cache)
+            if state is not None:
                 # the state the prompt ends in goes over whatever the
                 # slot's last tenant left: release zeroes nothing
-                ssm, conv = put_rows((ssm, conv),
-                                     (ks.get("ssm"), vs["conv"]), slot)
-                ks, vs = ks["kv"], vs["kv"]
+                ks, vs, ended = decoder.split_state(ks, vs)
+                if self.quant_cache:
+                    ended = decoder.quantize_rings(ended)
+                state = put_rows(state, ended, slot)
             last = last_row(logits, n_valid)
             # ring of the last rln prompt tokens: absolute positions
             # n_valid-rln .. n_valid-1 land in slots pos % rln (each slot
@@ -1044,8 +1062,7 @@ class Engine:
                     v_cache = jax.lax.dynamic_update_slice(
                         v_cache, vs.astype(v_cache.dtype),
                         (0, slot, 0, 0, 0))
-            k_cache, v_cache = decoder.join_state(k_cache, v_cache, ssm,
-                                                  conv)
+            k_cache, v_cache = decoder.join_state(k_cache, v_cache, state)
             return (tok, *pin(k_cache, v_cache, lengths, counts,
                               last_tokens, pring, mu))
 
@@ -1431,8 +1448,8 @@ class Engine:
                         mask_row, cflag, rln):
                 dsl = jax.lax.dynamic_slice
                 dus = jax.lax.dynamic_update_slice
-                k_cache, v_cache, ssm, conv = decoder.split_state(
-                    k_cache, v_cache)
+                k_cache, v_cache, state = decoder.split_state(k_cache,
+                                                              v_cache)
                 if self.quant_cache:
                     Lq, _, KvH, _S, hd = k_cache["q"].shape
                     def slice5(c):
@@ -1452,22 +1469,21 @@ class Engine:
                     def write5(c, cs):
                         return dus(c, cs, (0, slot, 0, 0, 0))
                 kc_s, vc_s = slice5(k_cache), slice5(v_cache)
-                if conv is not None:
+                if state is not None:
                     # the slot's state, read where the last piece left it
                     # and advanced over the tail's real positions only
                     kc_s, vc_s = decoder.join_state(
-                        kc_s, vc_s, *slot_rows((ssm, conv), slot))
+                        kc_s, vc_s, slot_rows(state, slot))
                 logits, kc_s, vc_s = fwd(
                     params, cfg, tokens, kc_s, vc_s, start[None],
                     mesh=self.mesh, **real(n_new[None]))
-                if conv is not None:
-                    kc_s, vc_s, ssm_s, conv_s = decoder.split_state(kc_s,
-                                                                    vc_s)
-                    ssm, conv = put_rows((ssm, conv), (ssm_s, conv_s), slot)
+                if state is not None:
+                    kc_s, vc_s, state_s = decoder.split_state(kc_s, vc_s)
+                    state = put_rows(state, state_s, slot)
                 k_cache = write5(k_cache, kc_s)
                 v_cache = write5(v_cache, vc_s)
                 k_cache, v_cache = decoder.join_state(k_cache, v_cache,
-                                                      ssm, conv)
+                                                      state)
                 last = last_row(logits, n_new)
                 (tok, lengths, counts, last_tokens, pring,
                  mu) = _sample_install(
@@ -3486,7 +3502,7 @@ class Engine:
 
     @property
     def state_bytes(self) -> int:
-        """Bytes of the slots' recurrent state (0 for a stack that has
-        none); part of ``kv_bytes``, whose trees it rides in."""
-        state = decoder.split_state(self.k_cache, self.v_cache)[2:]
-        return sum(a.nbytes for a in state if a is not None)
+        """Bytes of what the slots carry beside full-length keys and
+        values, recurrent state and rings (0 for a stack that has
+        neither); part of ``kv_bytes``, whose trees it rides in."""
+        return self.cache_bytes["state"] + self.cache_bytes["window"]

@@ -306,10 +306,11 @@ class Scheduler:
         self.spec_drafted = 0
         self.spec_accepted = 0
         # stall-free chunked prefill (Sarathi-style): prompts longer than
-        # one piece admit bucket-by-bucket through Engine.extend, one
-        # piece per scheduler step, so the worst-case stall a DECODING
-        # slot sees is one piece's prefill, not one prompt's. 0 disables;
-        # unset derives from decode_chunk (rounded up to a real bucket).
+        # one piece admit bucket-by-bucket through Engine.extend, a
+        # budget of pieces per scheduler step (_piece_tokens), so the
+        # worst-case stall a DECODING slot sees is that budget's
+        # prefill, not a prompt's. 0 disables; unset derives from
+        # decode_chunk (rounded up to a real bucket).
         if prefill_chunk is None:
             pc_env = os.environ.get("TPU_PREFILL_CHUNK", "")
             prefill_chunk = (int(pc_env) if pc_env
@@ -317,6 +318,16 @@ class Scheduler:
         self.prefill_chunk = (
             engine.bucket_for(min(int(prefill_chunk), engine.max_seq))
             if prefill_chunk and engine.supports_extend else 0)
+        # the prompt tokens the pieces of one scheduler step may hold
+        # together: what the chunk launched behind them decodes (every
+        # slot, decode_chunk steps), and one piece at the least. So the
+        # stall a decoding slot sees from chunked prompts stays about one
+        # chunk's own time, and a mix whose prompts are no longer than
+        # its answers never queues jobs behind one another. _step refills
+        # it; an awaited piece spends all of it (_dispatch_piece).
+        self._piece_tokens = max(
+            self.prefill_chunk, engine.n_slots * engine.ecfg.decode_chunk)
+        self._piece_budget = self._piece_tokens
         # double-buffered async dispatch: launch decode dispatch N+1
         # before materialising N's tokens, so host fan-out/detokenise
         # overlaps device compute (JAX async dispatch). The only
@@ -349,10 +360,13 @@ class Scheduler:
         # when the handle materialises
         self._pending = None
         # the admissions this pass launched and has not collected, oldest
-        # first: (AdmitHandle, [(slot, request, reuse_len)]). Their slots
-        # have owners already (_own), so the chunk launched next carries
-        # them; _land collects the first tokens behind that launch. Empty
-        # whenever _step returns.
+        # first: (AdmitHandle, [(slot, request, start, end, of)]): the
+        # dispatch prefilled [start, end) of a prompt of ``of`` tokens,
+        # and its token is the request's first iff end == of (anything
+        # less is a chunked admission's piece). A finished admission's
+        # slot has its owner already (_own), so the chunk launched next
+        # carries it; _land collects the first tokens behind that
+        # launch. Empty whenever _step returns.
         self._launched: List[tuple] = []
         # device-grammar escape bookkeeping: slot → request whose
         # ALREADY-LAUNCHED next dispatch ran with the slot frozen
@@ -1248,7 +1262,8 @@ class Scheduler:
             return True
         if launch:
             self._own(slot, req, "launched")
-            self._launched.append((out, [(slot, req, reuse_len)]))
+            n = len(req.admit_ids)
+            self._launched.append((out, [(slot, req, reuse_len, n, n)]))
             return True
         kind = "extend" if reuse_len else "admit"
         dur = self._note_prefill(kind)
@@ -1297,62 +1312,113 @@ class Scheduler:
             except WatchdogTimeout:
                 raise
             except Exception as e:  # noqa: BLE001 — the owners' error frame
-                for slot, req, _ in items:
+                for slot, req, *_ in items:
                     if self._running[slot] is req:
                         # the frame first: if the release raises too, the
                         # supervisor must not find this owner again
                         self._running[slot] = None
+                        self._prefilling.pop(slot, None)
                         req.slot = None
                         self._request_error(req, str(e))
                         self.engine.release(slot)
                 continue
             dur = self._note_prefill(handle.kind, sp.dur)
             m = len(items)
-            for (slot, req, reuse_len), tok in zip(items, toks):
-                n_new = len(req.admit_ids) - reuse_len
+            for (slot, req, start, end, of), tok in zip(items, toks):
+                n_new = end - start
                 # a batched dispatch's time is split evenly, so the ring's
                 # busy_s doesn't count the dispatch m times
-                self.acct.on_prefill(dur / m, reuse_len, n_new,
+                self.acct.on_prefill(dur / m, start, n_new,
                                      self.engine.bucket_for(n_new))
                 # from the launch, so that the request's queue stage ends
                 # and its prefill stage begins there (trace.fold_stages)
+                since = round((handle.t_done - handle.t_launch) * 1e3, 3)
+                if end < of:
+                    # a chunked admission's piece: its token is no one's
+                    req.trace.event("prefill_piece", kind=handle.kind,
+                                    done=end, of=of, dur_ms=since)
+                    continue
                 req.trace.event(
                     "prefill", kind=handle.kind, n_tokens=n_new,
-                    dur_ms=round((handle.t_done - handle.t_launch) * 1e3, 3),
-                    **({"batched": m} if m > 1 else {}))
+                    dur_ms=since, **({"batched": m} if m > 1 else {}))
                 if self._running[slot] is req:
                     self._post_admit(slot, req, tok, launched=True)
+
+    def _dispatch_piece(self, slot: int, req: Request, start: int,
+                        end: int):
+        """One piece of a chunked admission: prefill ``[start, end)`` of
+        the request's prompt into ``slot``, launched or awaited as a
+        one-shot admission is (_launches), and charge it to the step's
+        budget. A piece short of the prompt's end runs with default
+        options and leaves the slot parked: cache and lengths stay, the
+        slot goes engine-inactive so decode dispatches skip it. The final
+        piece runs with the request's real options/grammar mask and
+        samples its TTFT token (PRNG-seed-identical to a one-shot
+        admission: the seed derives from (slot, full prompt length)); it
+        ends the job. Whatever the engine raises is the caller's."""
+        ids = req.admit_ids
+        final = end == len(ids)
+        launch = self._launches(req)
+        eng = self.engine
+        kw = {}
+        if final:
+            kw = dict(opts=req.opts,
+                      mask_row=(req.constraint.mask_row()
+                                if req.constraint is not None else None))
+        if start:
+            out = (eng.extend_launch if launch else eng.extend)(
+                slot, ids[:end], start, **kw)
+        else:
+            out = (eng.admit_launch if launch else eng.admit)(
+                slot, ids[:end], **kw)
+        METRICS.inc("tpu_model_prefill_chunks_total")
+        if final:
+            self._prefilling.pop(slot, None)
+        else:
+            eng.release(slot, park=True)
+        if launch:
+            self._piece_budget -= end - start
+            if final:
+                self._own(slot, req, "launched")
+            self._launched.append(
+                (out, [(slot, req, start, end, len(ids))]))
+            return
+        # an awaited piece held this thread: no second one this step
+        self._piece_budget = 0
+        kind = "extend" if start else "admit"
+        dur = self._note_prefill(kind)
+        self.acct.on_prefill(dur, start, end - start,
+                             self.engine.bucket_for(end - start))
+        req.trace.event("prefill_piece", kind=kind, done=end,
+                        of=len(ids), dur_ms=round(dur * 1e3, 3))
+        if final:
+            self._post_admit(slot, req, out)
 
     def _start_chunked(self, slot: int, req: Request,
                        reuse_len: int) -> bool:
         """First piece of a chunked admission: prefill one
-        prefill_chunk-sized bucket, park the slot, and register the job —
-        the remaining pieces interleave with decode dispatches
-        (_advance_prefill). Returns False when the paged pool ran dry and
-        the request was requeued."""
+        prefill_chunk-sized bucket, park the slot, and register the job;
+        the remaining pieces follow while the step's budget lasts and
+        otherwise interleave with decode dispatches (_advance_prefill).
+        Returns False when the paged pool ran dry and the request was
+        requeued."""
         if self._expired_at_admission(req):
             return True
         ids = req.admit_ids
-        end = reuse_len + self.prefill_chunk
         try:
             try:
-                if reuse_len:
-                    self.engine.extend(slot, ids[:end], reuse_len)
-                else:
-                    self.engine.admit(slot, ids[:end])
+                self._dispatch_piece(slot, req, reuse_len,
+                                     reuse_len + self.prefill_chunk)
             except PagesExhausted:
                 if not (reuse_len and self._use_radix):
                     raise
                 # stitched first piece ran dry mid-COW/tail: cold-start
                 # the chunked prefill once (stitch/extend rolled the
                 # shared mappings back)
-                reuse_len, end = 0, self.prefill_chunk
+                reuse_len = 0
                 req._tier_stitch = None
-                self.engine.admit(slot, ids[:end])
+                self._dispatch_piece(slot, req, 0, self.prefill_chunk)
             req.stats.n_reused = reuse_len
-            # park between pieces: cache and lengths stay, the slot goes
-            # engine-inactive so decode dispatches skip it
-            self.engine.release(slot, park=True)
         except PagesExhausted as e:
             if not self.engine.admissible(len(ids)):
                 self._request_error(
@@ -1370,18 +1436,11 @@ class Scheduler:
         except Exception as e:
             self._request_error(req, str(e))
             return True
-        METRICS.inc("tpu_model_prefill_chunks_total")
-        kind = "extend" if reuse_len else "admit"
-        dur = self._note_prefill(kind)
-        n_new = end - reuse_len
-        self.acct.on_prefill(dur, reuse_len, n_new,
-                             self.engine.bucket_for(n_new))
-        req.trace.event("prefill_piece", kind=kind, done=end,
-                        of=len(ids), dur_ms=round(dur * 1e3, 3))
         req.slot = slot
         self._running[slot] = req
-        self._prefilling[slot] = _PrefillJob(req, end)
-        return True
+        self._prefilling[slot] = _PrefillJob(
+            req, reuse_len + self.prefill_chunk)
+        return self._advance_job(slot)
 
     def _abort_prefill(self, slot: int, reason: str):
         job = self._prefilling.pop(slot)
@@ -1394,69 +1453,58 @@ class Scheduler:
         req.out.put(("done", reason))
 
     def _advance_prefill(self):
-        """One prefill piece for the oldest chunked-admission job — at
-        most one per scheduler step, so decoding slots never stall more
-        than one piece per dispatch. The final piece runs with the
-        request's real options/grammar mask and samples its TTFT token
-        (PRNG-seed-identical to a one-shot admission: the seed derives
-        from (slot, full prompt length))."""
-        if not self._prefilling:
-            return
-        slot = next(iter(self._prefilling))
-        job = self._prefilling[slot]
-        req = job.req
-        if req.cancelled.is_set():
-            self._abort_prefill(slot, "cancelled")
-            return
-        if req.deadline is not None and time.monotonic() > req.deadline:
-            if req.resume_ids is None:
-                # no token ever reached the client: this is a shed
-                # (503 + Retry-After), not a mid-generation timeout
-                self._prefilling.pop(slot)
+        """The next pieces of the chunked-admission jobs, oldest job
+        first, while the step's budget of prompt tokens lasts
+        (_piece_tokens): decoding slots never stall for more than that
+        per dispatch, and no job waits for another's last piece."""
+        for slot in list(self._prefilling):
+            if self._piece_budget <= 0 or not self._advance_job(slot):
+                return
+
+    def _advance_job(self, slot: int) -> bool:
+        """Pieces for the job in ``slot`` until its prompt is in or the
+        step's budget is spent. Returns False when the paged pool ran dry
+        and the request was requeued."""
+        while self._piece_budget > 0 and slot in self._prefilling:
+            job = self._prefilling[slot]
+            req = job.req
+            if req.cancelled.is_set():
+                self._abort_prefill(slot, "cancelled")
+                return True
+            if req.deadline is not None and time.monotonic() > req.deadline:
+                if req.resume_ids is None:
+                    # no token ever reached the client: this is a shed
+                    # (503 + Retry-After), not a mid-generation timeout
+                    self._prefilling.pop(slot)
+                    self._running[slot] = None
+                    req.slot = None
+                    self.engine.release(slot)
+                    self._shed(req)
+                else:
+                    METRICS.inc("tpu_model_request_timeouts_total")
+                    self._abort_prefill(slot, "timeout")
+                return True
+            ids = req.admit_ids
+            end = min(job.done + self.prefill_chunk, len(ids))
+            try:
+                self._dispatch_piece(slot, req, job.done, end)
+            except PagesExhausted:
+                # mid-prefill pool pressure: back out and requeue; the
+                # re-admission restarts the prompt (no tokens were
+                # emitted)
+                self._prefilling.pop(slot, None)
                 self._running[slot] = None
                 req.slot = None
                 self.engine.release(slot)
-                self._shed(req)
-            else:
-                METRICS.inc("tpu_model_request_timeouts_total")
-                self._abort_prefill(slot, "timeout")
-            return
-        ids = req.admit_ids
-        start = job.done
-        end = min(job.done + self.prefill_chunk, len(ids))
-        final = end == len(ids)
-        try:
-            if final:
-                mask_row = (req.constraint.mask_row()
-                            if req.constraint is not None else None)
-                first = self.engine.extend(slot, ids, job.done, req.opts,
-                                           mask_row=mask_row)
-            else:
-                self.engine.extend(slot, ids[:end], job.done)
-                self.engine.release(slot, park=True)
-                job.done = end
-        except PagesExhausted:
-            # mid-prefill pool pressure: back out and requeue; the
-            # re-admission restarts the prompt (no tokens were emitted)
-            self._prefilling.pop(slot, None)
-            self._running[slot] = None
-            req.slot = None
-            self.engine.release(slot)
-            self._evict_one_parked(self._pages_for(len(ids)))
-            self._preempted.insert(0, req)
-            return
-        # any other engine failure propagates to the supervisor, which
-        # errors every running request (this one included) exactly once
-        # and restarts — _fail_running clears _prefilling
-        METRICS.inc("tpu_model_prefill_chunks_total")
-        dur = self._note_prefill("extend")
-        self.acct.on_prefill(dur, start, end - start,
-                             self.engine.bucket_for(end - start))
-        req.trace.event("prefill_piece", kind="extend", done=end,
-                        of=len(ids), dur_ms=round(dur * 1e3, 3))
-        if final:
-            self._prefilling.pop(slot, None)
-            self._post_admit(slot, req, first)
+                self._evict_one_parked(self._pages_for(len(ids)))
+                self._preempted.insert(0, req)
+                return False
+            # any other engine failure propagates to the supervisor,
+            # which errors every running request (this one included)
+            # exactly once and restarts — _fail_running clears
+            # _prefilling
+            job.done = end
+        return True
 
     def _flush_admit_batch(self, batch: dict):
         """Admit the same-bucket groups collected this pass: groups of 4
@@ -1496,7 +1544,8 @@ class Scheduler:
                     for s, r in group:
                         self._own(s, r, "launched")
                     self._launched.append(
-                        (out, [(s, r, 0) for s, r in group]))
+                        (out, [(s, r, 0, len(r.admit_ids), len(r.admit_ids))
+                               for s, r in group]))
                     continue
                 dur = self._note_prefill("admit")
                 # one batched dispatch: split its wall time evenly so the
@@ -1575,7 +1624,8 @@ class Scheduler:
                 if (piece and len(ids) - reuse_len > piece
                         and req.embeds is None
                         and len(ids) + piece <= self.engine.max_seq):
-                    # long prompt: admit piecewise, one piece per step
+                    # long prompt: admit piecewise, inside the step's
+                    # budget of pieces
                     if not self._start_chunked(slot, req, reuse_len):
                         return
                     continue
@@ -2254,6 +2304,7 @@ class Scheduler:
             self._shed_expired()
             self._throttle_over_limit()
             self._preempt_for_priority()
+        self._piece_budget = self._piece_tokens
         if self._prefilling:
             with span("sched.prefill"):
                 self._advance_prefill()

@@ -296,11 +296,11 @@ class LoadedModel:
             METRICS.gauge_fn("tpu_model_radix_pages",
                              lambda: (lm := wself()) is not None
                              and lm.engine.radix_pages or 0)
-        if getattr(self.engine, "recurrent", False):
-            # what the slots of a hybrid stack hold beside keys and values
-            METRICS.gauge_fn("tpu_model_recurrent_state_bytes",
-                             lambda: (lm := wself()) is not None
-                             and lm.engine.state_bytes or 0)
+        for kind in getattr(self.engine, "cache_bytes", ()):
+            METRICS.gauge_fn("tpu_model_cache_bytes",
+                             lambda kind=kind: (lm := wself()) is not None
+                             and lm.engine.cache_bytes[kind] or 0,
+                             f'{{kind="{kind}"}}')
         if getattr(self.engine, "host_cache_enabled", False):
             # tier-1 host-arena occupancy: bytes and whole KV pages the
             # spilled radix subtrees hold in pinned host RAM (the spill /
@@ -909,8 +909,9 @@ class LoadedModel:
         if getattr(self.engine, "radix_enabled", False):
             METRICS.remove_gauge("tpu_model_radix_nodes")
             METRICS.remove_gauge("tpu_model_radix_pages")
-        if getattr(self.engine, "recurrent", False):
-            METRICS.remove_gauge("tpu_model_recurrent_state_bytes")
+        for kind in getattr(self.engine, "cache_bytes", ()):
+            METRICS.remove_gauge("tpu_model_cache_bytes",
+                                 f'{{kind="{kind}"}}')
         if getattr(self.engine, "host_cache_enabled", False):
             METRICS.remove_gauge("tpu_model_host_cache_bytes")
             METRICS.remove_gauge("tpu_model_host_cache_pages")

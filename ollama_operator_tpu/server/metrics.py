@@ -212,12 +212,12 @@ GLOBAL.describe("tpu_model_admissions_total",
                 "prefill was dispatched without a host sync and its token "
                 "collected behind the next decode chunk's launch; awaited "
                 "= the loop waited for it where the admission was made (a "
-                "synchronous loop, speculation on, a constrained request, "
-                "the last piece of a chunked prefill)")
+                "synchronous loop, speculation on, a constrained request)")
 GLOBAL.describe("tpu_model_prefill_chunks_total",
                 "Chunked-prefill pieces dispatched (stall-free admission "
-                "of long prompts, one bucket-sized piece per scheduler "
-                "step)")
+                "of long prompts in bucket-sized pieces, as many a "
+                "scheduler step as hold the prompt tokens its decode chunk "
+                "advances)")
 GLOBAL.describe("tpu_model_prefix_hit_tokens_total",
                 "Prompt tokens served from the prefix cache at admission "
                 "(radix page stitch or parked-slot extend) instead of "
@@ -257,10 +257,12 @@ GLOBAL.describe("tpu_model_host_cache_bytes",
 GLOBAL.describe("tpu_model_host_cache_pages",
                 "Spilled KV pages resident in the tier-1 host arena "
                 "(live gauge)")
-GLOBAL.describe("tpu_model_recurrent_state_bytes",
-                "Device bytes of the slots' recurrent state, for a model "
-                "with state-space or short-convolution layers (live gauge; "
-                "absent for a model that keeps keys and values only)")
+GLOBAL.describe("tpu_model_cache_bytes",
+                "Device bytes of the loaded model's cache as allocated, by "
+                "what holds them (kind=full|window|state): full-length "
+                "rows or pages of keys and values; the rings of "
+                "sliding_window positions a slot that window-attention "
+                "layers keep instead of a full row; recurrent state")
 GLOBAL.describe("tpu_model_async_fallback_total",
                 "Decode dispatches that fell back to synchronous while "
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "

@@ -17,7 +17,11 @@ The invariants under test:
   else; a wedged device at collect goes to the supervisor, which errors (or
   replays) every owner exactly once;
 - constrained requests and a loop that drafts take the awaited form, and
-  `tpu_model_admissions_total{mode}` says which form each request took.
+  `tpu_model_admissions_total{mode}` says which form each request took;
+- a prompt past one prefill piece launches its pieces too: the streams are
+  the awaited pieces' and the one-shot admission's, every job gets pieces
+  while the step's budget of prompt tokens lasts (the oldest first), and
+  nothing is fetched between the pieces and the chunk launched behind them.
 """
 
 import dataclasses
@@ -39,6 +43,7 @@ from ollama_operator_tpu.server.metrics import GLOBAL as METRICS
 
 TINY = dataclasses.replace(PRESETS["tiny"], kernels="xla")
 HYBRID = PRESETS["tiny-hybrid"]
+RINGS = PRESETS["tiny-exaone"]
 GREEDY = SlotOptions(temperature=0.0, repeat_penalty=1.0)
 SEEDED = SlotOptions(temperature=0.9, top_k=40, seed=1234)
 ECFG = EngineConfig(max_slots=4, max_seq_len=128, cache_dtype=jnp.int8,
@@ -48,6 +53,9 @@ KINDS = {
     "contiguous_int8": (TINY, ECFG),
     "hybrid_int8": (HYBRID, ECFG),
 }
+# the kinds a prompt past one piece is tried on: a window layer's ring is
+# one more state that only advances (the piece is two windows long)
+PIECE_KINDS = dict(KINDS, rings_int8=(RINGS, ECFG))
 
 
 @pytest.fixture(scope="module")
@@ -57,7 +65,7 @@ def engines():
 
     def get(kind):
         if kind not in built:
-            cfg, ecfg = KINDS[kind]
+            cfg, ecfg = PIECE_KINDS[kind]
             params = decoder.init_params(cfg, jax.random.PRNGKey(0),
                                          dtype=jnp.float32)
             built[kind] = Engine(cfg, params, ecfg=ecfg)
@@ -127,6 +135,30 @@ def modes():
 
 def moved(before):
     return {m: v - before[m] for m, v in modes().items()}
+
+
+def spy_on(eng, sched, monkeypatch):
+    """The engine's dispatches, its fetches and the fan-outs, in order."""
+    log = []
+    fetch = eng._fetch
+
+    def spy(name):
+        real = getattr(eng, name)
+
+        def call(*a, **kw):
+            log.append(name)
+            return real(*a, **kw)
+        monkeypatch.setattr(eng, name, call)
+
+    for name in ("admit_launch", "admit_many_launch", "extend_launch",
+                 "decode_n_launch", "admit", "admit_many", "extend"):
+        spy(name)
+    monkeypatch.setattr(
+        eng, "_fetch", lambda x: (log.append("fetch"), fetch(x))[1])
+    fanout = sched._fanout
+    monkeypatch.setattr(sched, "_fanout", lambda *a, **kw: (
+        log.append("fanout"), fanout(*a, **kw))[1])
+    return log
 
 
 def serve(eng, prompts, opts, *, launched, max_tokens=11, monkeypatch=None,
@@ -204,26 +236,8 @@ def test_no_host_fetch_between_admission_launch_and_decode_launch(
     the chunk launched behind it fetch nothing from the device; the first
     tokens are fetched after that launch, oldest first, and before the
     fan-out of the chunk that was in flight."""
-    log = []
-    fetch = eng._fetch
-
-    def spy(name):
-        real = getattr(eng, name)
-
-        def call(*a, **kw):
-            log.append(name)
-            return real(*a, **kw)
-        monkeypatch.setattr(eng, name, call)
-
-    for name in ("admit_launch", "admit_many_launch", "extend_launch",
-                 "decode_n_launch", "admit", "admit_many", "extend"):
-        spy(name)
-    monkeypatch.setattr(
-        eng, "_fetch", lambda x: (log.append("fetch"), fetch(x))[1])
     sched = manual(Scheduler(eng, prefill_chunk=0, async_dispatch=True))
-    fanout = sched._fanout
-    monkeypatch.setattr(sched, "_fanout", lambda *a, **kw: (
-        log.append("fanout"), fanout(*a, **kw))[1])
+    log = spy_on(eng, sched, monkeypatch)
     try:
         r0 = sched.submit(prompt(9), GREEDY, max_tokens=40)
         sched._step()
@@ -597,6 +611,162 @@ def test_a_loop_that_drafts_awaits(monkeypatch):
         assert moved(before) == {"launched": 0, "awaited": 1}
     finally:
         sched.shutdown()
+
+
+# ------------------------------------------------- a prompt past one piece
+
+def serve_pieces(eng, prompts, opts, *, piece, launched, budget=None,
+                 monkeypatch=None, max_tokens=7):
+    """``serve`` with prompts admitted in pieces of ``piece`` tokens (0:
+    whole), ``budget`` prompt tokens of pieces a step."""
+    sched = manual(Scheduler(eng, prefill_chunk=piece, async_dispatch=True))
+    if budget is not None:
+        sched._piece_tokens = budget
+    if not launched:
+        monkeypatch.setattr(sched, "_launches", lambda req=None: False)
+    try:
+        reqs = [sched.submit(p, opts, max_tokens=max_tokens)
+                for p in prompts]
+        run_steps(sched)
+        return [frames(r) for r in reqs]
+    finally:
+        sched.shutdown()
+        clean(eng)
+
+
+@pytest.mark.parametrize("budget", [16, 64])
+@pytest.mark.parametrize("opts", [GREEDY, SEEDED], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("kind", list(PIECE_KINDS))
+def test_launched_pieces_give_the_awaited_and_the_one_shot_stream(
+        engines, monkeypatch, kind, opts, budget):
+    """Three prompts of two and three pieces and one of one piece, all
+    waiting at the first pass: each stream is what awaited pieces give, at
+    a budget of one piece a step and at one that takes a whole prompt in a
+    step; greedy, also what an admission in one piece gives (a piece
+    attends over its predecessors' int8 keys where a whole prompt attends
+    over its own unrounded ones: a sampler at temperature 0.9 can tell)."""
+    eng = engines(kind)
+    prompts = [prompt(40, 2), prompt(21, 9), prompt(12, 5), prompt(33, 17)]
+    whole = serve_pieces(eng, prompts, opts, piece=0, launched=True)
+    before = modes()
+    want = serve_pieces(eng, prompts, opts, piece=16, launched=False,
+                        monkeypatch=monkeypatch)
+    assert moved(before) == {"launched": 0, "awaited": 4}
+    before = modes()
+    c0 = METRICS.get("tpu_model_prefill_chunks_total")
+    got = serve_pieces(eng, prompts, opts, piece=16, launched=True,
+                       budget=budget)
+    assert moved(before) == {"launched": 4, "awaited": 0}
+    assert METRICS.get("tpu_model_prefill_chunks_total") - c0 == 3 + 2 + 3
+    assert got == want
+    if opts is GREEDY:
+        assert got == whole
+    for fr in got:
+        assert len(tokens_of(fr)) == 7 and fr[-1] == ("done", "length")
+
+
+def test_no_job_waits_for_anothers_pieces(eng, monkeypatch):
+    """With a chunk in flight, two prompts of two pieces are both in
+    after ONE pass: four prefill launches and the chunk behind them with
+    no fetch between, then the chunk in flight, the four collects and the
+    fan-out; both first tokens are out and both slots ride the chunk."""
+    sched = manual(Scheduler(eng, prefill_chunk=16, async_dispatch=True))
+    sched._piece_tokens = 64
+    log = spy_on(eng, sched, monkeypatch)
+    try:
+        r0 = sched.submit(prompt(9), GREEDY, max_tokens=40)
+        sched._step()
+        assert sched._pending is not None       # a chunk is in flight
+        del log[:]
+        rs = [sched.submit(p, GREEDY, max_tokens=40)
+              for p in (prompt(20, 5), prompt(27, 9))]
+        before = modes()
+        sched._step()
+        assert log == ["admit_launch", "extend_launch",
+                       "admit_launch", "extend_launch",
+                       "decode_n_launch",
+                       "fetch",                 # the chunk in flight
+                       "fetch", "fetch", "fetch", "fetch",
+                       "fanout"]
+        assert moved(before) == {"launched": 2, "awaited": 0}
+        assert not sched._launched and not sched._prefilling
+        assert all(len(tokens_of(frames(r))) == 1 for r in rs)
+        assert sorted(sched._pending[1]) == [0, 1, 2]      # all ride it
+        for r in [r0] + rs:
+            r.cancel()
+        run_steps(sched)
+    finally:
+        sched.shutdown()
+        clean(eng)
+
+
+def test_a_steps_pieces_stay_inside_its_budget_oldest_job_first(
+        eng, monkeypatch):
+    """Two prompts of three pieces (16, 16, 8) at a budget of 32 tokens a
+    step: the pass that admits them gives the older one two pieces and the
+    younger its first (a new job's first piece always goes); the next step
+    ends the older one, then spends what is left on the younger."""
+    sched = manual(Scheduler(eng, prefill_chunk=16, async_dispatch=True))
+    assert sched._piece_tokens == 16    # 4 slots x 4 steps: one piece
+    sched._piece_tokens = 32
+    log = spy_on(eng, sched, monkeypatch)
+    try:
+        a, b = (sched.submit(p, GREEDY, max_tokens=5)
+                for p in (prompt(40, 2), prompt(40, 11)))
+        sched._step()
+        assert [x for x in log if x.endswith("_launch")] == [
+            "admit_launch", "extend_launch", "admit_launch"]
+        assert {s: j.done for s, j in sched._prefilling.items()} == {
+            a.slot: 32, b.slot: 16}
+        assert not tokens_of(frames(a)) and not sched._decoding()
+        del log[:]
+        sched._step()
+        assert [x for x in log if x.endswith("_launch")] == [
+            "extend_launch", "extend_launch", "extend_launch",
+            "decode_n_launch"]
+        assert not sched._prefilling
+        assert sorted(sched._pending[1]) == sorted([a.slot, b.slot])
+        run_steps(sched)
+    finally:
+        sched.shutdown()
+        clean(eng)
+
+
+def test_a_piece_that_fails_at_collect_errors_its_owner_once(eng,
+                                                             monkeypatch):
+    """A launched piece whose fetch raises: the job's owner gets one error
+    frame, its slot is free and no job is left; the other request, and
+    the next one on that slot, are served."""
+    sched = manual(Scheduler(eng, prefill_chunk=16, async_dispatch=True))
+    launch = eng.admit_launch
+    hit = {}
+
+    def poisoned(slot, ids, *a, **kw):
+        h = launch(slot, ids, *a, **kw)
+        if len(ids) == 16 and not hit:
+            hit["slot"] = slot
+
+            def wait():
+                raise RuntimeError("device said no")
+            h = type("H", (), {"wait": staticmethod(wait), "kind": "admit",
+                               "slots": (slot,)})()
+        return h
+
+    monkeypatch.setattr(eng, "admit_launch", poisoned)
+    try:
+        bad = sched.submit(prompt(40, 2), GREEDY, max_tokens=4)
+        ok = sched.submit(prompt(9, 7), GREEDY, max_tokens=4)
+        run_steps(sched)
+        assert frames(bad) == [("error", "device said no")]
+        assert len(tokens_of(frames(ok))) == 4
+        assert not sched._prefilling
+        assert sched._running[hit["slot"]] is None
+        nxt = sched.submit(prompt(40, 2), GREEDY, max_tokens=4)
+        run_steps(sched)
+        assert len(tokens_of(frames(nxt))) == 4
+    finally:
+        sched.shutdown()
+        clean(eng)
 
 
 # ------------------------------------------------------------- the engine

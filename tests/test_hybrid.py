@@ -68,7 +68,7 @@ def make_engine(params, slots=4, cache=jnp.float32, **kw):
 
 def state_of(eng, slot):
     """(ssm, conv) of one slot as host arrays."""
-    _, _, ssm, conv = decoder.split_state(eng.k_cache, eng.v_cache)
+    _, _, (ssm, conv, _) = decoder.split_state(eng.k_cache, eng.v_cache)
     return np.asarray(ssm[:, slot]), np.asarray(conv[:, slot])
 
 
@@ -147,7 +147,7 @@ def test_prefill_then_decode_against_the_reference(ref, params, cache):
         kc, vc = (kc.at[:, :, :, :24].set(ks["kv"]),
                   kc.at[:, :, :, :24].set(vs["kv"]))
         tol = 2e-4
-    K, V = decoder.join_state(kc, vc, ks["ssm"], vs["conv"])
+    K, V = decoder.join_state(kc, vc, (ks["ssm"], vs["conv"], None))
     step = jax.jit(lambda p, t, K, V, n: decoder.forward_with_cache(
         p, CFG, t, K, V, n))
     for i in range(24, 40):
@@ -229,7 +229,7 @@ def test_prefill_in_pieces_equals_one_piece(params, pieces):
     want_l, ks, vs = jax.jit(
         lambda p, t: decoder.prefill_chunk(p, CFG, t))(params, toks[None])
     kc = jnp.zeros((1, 1, CFG.n_kv_heads, 64, CFG.head_dim))
-    K, V = decoder.join_state(kc, kc, *decoder.empty_state(CFG, 1))
+    K, V = decoder.join_state(kc, kc, decoder.empty_state(CFG, 1))
     at = 0
     for n in pieces:
         lg, K, V = decoder.forward_with_cache(
@@ -554,12 +554,12 @@ def test_state_gauge_and_ps_details(params, monkeypatch):
         assert lm.engine.state_bytes == want
         assert lm.engine.kv_bytes > want
         text = METRICS.render()
-        assert f"tpu_model_recurrent_state_bytes {want}" in text.replace(
-            ".0", "")
+        assert f'tpu_model_cache_bytes{{kind="state"}} {want}' in \
+            text.replace(".0", "")
     finally:
         lm.unload()
     # the gauge went with the model: no sample line is left
-    assert not re.search(r"^tpu_model_recurrent_state_bytes \d",
+    assert not re.search(r"^tpu_model_cache_bytes\S* \d",
                          METRICS.render(), re.M)
 
 

@@ -75,7 +75,7 @@ def make_engine(params, slots=4, cache=jnp.float32, **kw):
 
 def state_of(eng, slot):
     """The convolution inputs one slot carries, as a host array."""
-    _, _, ssm, conv = decoder.split_state(eng.k_cache, eng.v_cache)
+    _, _, (ssm, conv, _) = decoder.split_state(eng.k_cache, eng.v_cache)
     assert ssm is None
     return np.asarray(conv[:, slot])
 
@@ -143,7 +143,7 @@ def test_prefill_then_decode_against_the_reference(ref, params, cache):
         kc, vc = (kc.at[:, :, :, :24].set(ks["kv"]),
                   kc.at[:, :, :, :24].set(vs["kv"]))
         tol = 2e-4
-    K, V = decoder.join_state(kc, vc, None, vs["conv"])
+    K, V = decoder.join_state(kc, vc, (None, vs["conv"], None))
     step = jax.jit(lambda p, t, K, V, n: decoder.forward_with_cache(
         p, CFG, t, K, V, n))
     for i in range(24, 40):
@@ -246,7 +246,7 @@ def test_lowered_programs_carry_the_new_scopes(params, program):
     else:
         kc = jnp.zeros((CFG.n_attn_layers, 2, CFG.n_kv_heads, 32,
                         CFG.head_dim))
-        K, V = decoder.join_state(kc, kc, *decoder.empty_state(CFG, 2))
+        K, V = decoder.join_state(kc, kc, decoder.empty_state(CFG, 2))
         low = jax.jit(lambda p, t, K, V, n: decoder.forward_with_cache(
             p, CFG, t, K, V, n, route_live=n)).lower(
             params, tokens(2)[:, None], K, V, jnp.array([3, 0], jnp.int32))
@@ -345,7 +345,7 @@ def test_prefill_in_pieces_equals_one_piece(params, pieces):
     want_l, ks, vs = jax.jit(
         lambda p, t: decoder.prefill_chunk(p, CFG, t))(params, toks[None])
     kc = jnp.zeros((CFG.n_attn_layers, 1, CFG.n_kv_heads, 64, CFG.head_dim))
-    K, V = decoder.join_state(kc, kc, *decoder.empty_state(CFG, 1))
+    K, V = decoder.join_state(kc, kc, decoder.empty_state(CFG, 1))
     at = 0
     for n in pieces:
         lg, K, V = decoder.forward_with_cache(
@@ -561,11 +561,11 @@ def test_state_gauge_and_ps_details(params, monkeypatch):
         assert want == 2 * 6 * 2 * 64 * 4
         assert lm.engine.state_bytes == want
         assert lm.engine.kv_bytes > want
-        assert f"tpu_model_recurrent_state_bytes {want}" in \
+        assert f'tpu_model_cache_bytes{{kind="state"}} {want}' in \
             METRICS.render().replace(".0", "")
     finally:
         lm.unload()
-    assert not re.search(r"^tpu_model_recurrent_state_bytes \d",
+    assert not re.search(r"^tpu_model_cache_bytes\S* \d",
                          METRICS.render(), re.M)
 
 
