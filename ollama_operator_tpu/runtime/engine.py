@@ -364,7 +364,8 @@ class DecodeHandle:
     never wait) keep bit-identical host lengths."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "budgets",
-                 "accepted", "t_done", "t_begin", "sampler", "_load")
+                 "accepted", "t_done", "t_begin", "t_queued", "sampler",
+                 "_load")
 
     def __init__(self, engine: "Engine", toks, t0: float, epoch: int = 0,
                  budgets: Optional[np.ndarray] = None,
@@ -376,6 +377,10 @@ class DecodeHandle:
         self.sampler = sampler
         self._toks = toks
         self._t0 = t0
+        # perf_counter() when the program had been handed to the runtime
+        # (the launch's host staging done): from then on the device has
+        # it queued
+        self.t_queued = time.perf_counter()
         self._out: Optional[np.ndarray] = None
         self.epoch = epoch
         self.budgets = budgets
@@ -393,6 +398,11 @@ class DecodeHandle:
     def t_launch(self) -> float:
         """perf_counter() at launch time (set by decode_n_launch)."""
         return self._t0
+
+    def ready(self) -> bool:
+        """Whether the device has finished the program, so that wait()
+        would not block. Asks the runtime; syncs and fetches nothing."""
+        return self._toks is None or self._toks.is_ready()
 
     def wait(self) -> np.ndarray:
         if self._out is None:
@@ -425,13 +435,14 @@ class AdmitHandle:
     Followers replay the launch and never wait."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "kind", "slots",
-                 "t_done", "t_begin")
+                 "t_done", "t_begin", "t_queued")
 
     def __init__(self, engine: "Engine", toks, t0: float, kind: str,
                  slots: Sequence[int]):
         self._engine = engine
         self._toks = toks
         self._t0 = t0
+        self.t_queued = time.perf_counter()      # as DecodeHandle's
         self._out: Optional[List[int]] = None
         # the key of Engine.dispatch_ms this admission reports under
         self.kind = kind
@@ -443,6 +454,10 @@ class AdmitHandle:
     def t_launch(self) -> float:
         """perf_counter() when the launch began (host staging included)."""
         return self._t0
+
+    def ready(self) -> bool:
+        """As DecodeHandle.ready: the prefill has run; nothing is synced."""
+        return self._toks is None or self._toks.is_ready()
 
     def wait(self) -> List[int]:
         if self._out is None:

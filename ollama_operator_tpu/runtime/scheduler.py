@@ -15,6 +15,7 @@ the slot is released on the next loop iteration.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import queue
@@ -43,6 +44,10 @@ from .trace import FLIGHT, TRACER, fold_stages, span
 
 # tpu_model_admissions_total's label values (Scheduler._own)
 _ADMIT_MODE = {m: f'{{mode="{m}"}}' for m in ("launched", "awaited")}
+# tpu_model_pass_holds_total's and tpu_model_decode_launches_total's
+# (Scheduler._hold_pass, _step)
+_HOLD_END = {e: f'{{end="{e}"}}' for e in ("filled", "deadline", "none")}
+_LAUNCH_TIMING = {t: f'{{timing="{t}"}}' for t in ("ahead", "late", "empty")}
 
 
 class SchedulerBusy(RuntimeError):
@@ -245,6 +250,9 @@ class Scheduler:
     # ceiling on the supervised-restart backoff (it doubles per
     # consecutive failure starting from restart_backoff)
     RESTART_BACKOFF_CAP = 2.0
+    # the clock _hold_pass reads its deadline on: the engine's and the
+    # spans' (a test puts its own here, and a _wake whose wait() moves it)
+    _now = staticmethod(time.perf_counter)
 
     def __init__(self, engine: Engine, max_queue: int = 256,
                  max_restarts: Optional[int] = None,
@@ -400,6 +408,23 @@ class Scheduler:
         # waiting queue — they already hold a place in the line
         self._preempted: List[Request] = []
         self.n_preemptions = 0
+        # what the host took, in seconds, from the start of a step's
+        # housekeeping until the step's FIRST program was handed to the
+        # runtime (t_queued of the pass's first prefill, else of the decode
+        # launch): the largest of the recent double-buffered steps, each
+        # older one counting a tenth less. _hold_pass ends its hold this
+        # long before the chunk in flight lands: from then on the device's
+        # queue is never empty, and the rest of the pass runs in the shadow
+        # of what it has queued. Not the whole pass: the runtime blocks a
+        # launch while 32 programs are in flight (an admission is nine),
+        # so a pass of four admissions ends only after the chunk in flight
+        # has (PERF.md, PR 40). None until a step has measured one.
+        self._lead_s: Optional[float] = None
+        # what the last two decode chunks took, in seconds, as
+        # _wait_handle accounts them (Engine._landed's interval): the hold
+        # takes the chunk in flight for the shorter of the two, so one
+        # chunk that held a compile or a stall lengthens no hold
+        self._chunk_s: collections.deque = collections.deque(maxlen=2)
         # slot vacancy: (perf_counter() of the previous _step, slots its
         # admission pass left free, whether a request still waited then) —
         # the state that holds until the next iteration's admission pass
@@ -1561,10 +1586,14 @@ class Scheduler:
             for s, r in items:
                 self._admit_one(s, r, 0)
 
-    def _admit_waiting(self):
-        # slots mid-chunked-prefill are engine-inactive but TAKEN
-        free = [s for s in self.engine.free_slots()
+    def _free_slots(self) -> List[int]:
+        """The slots an admission pass may fill: those mid-chunked-prefill
+        are engine-inactive but TAKEN."""
+        return [s for s in self.engine.free_slots()
                 if s not in self._prefilling]
+
+    def _admit_waiting(self):
+        free = self._free_slots()
         batch: dict = {}   # prefill bucket → [(slot, req)] to batch-admit
         try:
             while free:
@@ -2170,6 +2199,8 @@ class Scheduler:
                if handle.t_done is not None else 0.0)
         METRICS.observe("tpu_model_dispatch_seconds", dur,
                         f'{{kind="{kind}"}}')
+        if kind == "decode":
+            self._chunk_s.append(dur)
         if self.acct.enabled:
             # goodput/FLOPs split of the dispatch grid: active slots'
             # host-mirrored lengths as contexts, the full slot batch as
@@ -2291,7 +2322,84 @@ class Scheduler:
         METRICS.inc("tpu_model_slot_vacant_seconds_total", free * dt,
                     '{queue="waiting"}' if waiting else '{queue="empty"}')
 
+    def _host_masked(self, decoding: dict) -> bool:
+        """Whether a slot of ``decoding`` needs a fresh HOST grammar mask
+        for every token (device-grammar slots advance their automaton on
+        the device): such a step empties the pipeline first."""
+        gdev = self.engine._gdev_mode
+        return any(r.constraint is not None and not gdev[s]
+                   for s, r in decoding.items())
+
+    def _unfilled(self) -> int:
+        """Free slots beyond the requests that wait for one. The waiters
+        that were cancelled or have expired are swept out first (and get
+        their frames): they will fill no slot."""
+        self._shed_expired()
+        waiting = (len(self._admission)
+                   + sum(not r.cancelled.is_set() for r in self._preempted))
+        return len(self._free_slots()) - waiting
+
+    def _hold_until(self) -> Optional[float]:
+        """The _now() at which a hold before this step's pass must end so
+        that the launch behind the pass still reaches the device before
+        the chunk in flight lands; None where the step holds nothing.
+        Measured, not set: the chunk in flight began when it was launched
+        or, if later, when its predecessor's tokens reached the host
+        (Engine._landed's rule) and takes what the shorter of the last
+        two chunks took (_chunk_s); the step's first program takes the
+        host what recent ones took (_lead_s). No hold before both have been
+        measured, nor in a step that will not double-buffer: a loop that
+        drafts or awaits its admissions (_launches), a host-masked slot
+        (the synchronous branch)."""
+        if (self._lead_s is None or len(self._chunk_s) < 2
+                or not self._launches()
+                or self._host_masked(self._decoding())):
+            return None
+        began = max(self._pending[0].t_launch, self.engine._t_landed)
+        return began + min(self._chunk_s) - self._lead_s
+
+    def _hold_pass(self):
+        """Before the step's pass, with a chunk in flight: sleep on _wake
+        while free slots outnumber the requests waiting and the hold's
+        deadline (_hold_until) has not come. The device needs the next
+        chunk queued only by the time the one in flight ends, and a pass
+        made a chunk early finds nobody: a finisher's successor arrives
+        milliseconds after the fan-out that freed its slot and would wait
+        a whole further chunk for the next pass. submit() sets _wake, so
+        an arrival ends the sleep and the condition is read again; ONE
+        pass then admits everyone who came (same-bucket arrivals share an
+        admit_many). Nothing configures it. How it ended is counted in
+        tpu_model_pass_holds_total: every free slot got its waiter
+        (filled), the deadline came first, also where it had passed
+        before the hold began (deadline), or there was nothing to hold
+        for (none). An exclusive task or a shutdown ends it as the
+        deadline does. The sleep is time this thread stood waiting on the
+        dispatch in flight (acct.on_wait), as the wait it shortens is."""
+        if self._pending is None:
+            return
+        end = "none"
+        until = self._hold_until()
+        # cleared before the waiters are counted: whoever submits after
+        # the count sets it again and ends the first sleep
+        self._wake.clear()
+        if until is not None and self._unfilled() > 0:
+            with span("sched.hold") as sp:
+                while True:
+                    left = until - self._now()
+                    if left <= 0 or self._tasks or self._stop.is_set():
+                        end = "deadline"
+                        break
+                    self._wake.wait(left)
+                    self._wake.clear()
+                    if self._unfilled() <= 0:
+                        end = "filled"
+                        break
+                sp.set(end=end)
+            self.acct.on_wait(sp.dur, sp.t1)
+        METRICS.inc("tpu_model_pass_holds_total", 1.0, _HOLD_END[end])
+
     def _step(self):
+        self._hold_pass()
         if self._tasks:
             # exclusive tasks see a quiet pipeline: land any in-flight
             # dispatch first so a KV import's cache upload never races a
@@ -2380,9 +2488,7 @@ class Scheduler:
         # only HOST-masked grammar slots force the pipeline empty (fresh
         # PDA mask per token); device-grammar slots advance their
         # automaton on device and ride async like everyone else
-        gdev = self.engine._gdev_mode
-        constrained = any(r.constraint is not None and not gdev[s]
-                          for s, r in decoding.items())
+        constrained = self._host_masked(decoding)
         if not self.async_dispatch or constrained:
             # synchronous path: grammar needs a fresh host PDA mask
             # between dispatches, so the pipeline must be empty before
@@ -2473,7 +2579,9 @@ class Scheduler:
                 self._fanout(toks_prev, prev_snapshot,
                              chunked=prev_drafted is None)
             return
-        # double-buffered async dispatch: launch dispatch N+1 FIRST,
+        # double-buffered async dispatch: launch dispatch N+1 FIRST (the
+        # step began by holding the pass, and so this launch, until N was
+        # about to land: _hold_pass),
         # then materialise dispatch N, collect the first tokens of the
         # admissions this pass launched between the two, and fan N out —
         # the pass's host work and the detokenise/queue work overlap
@@ -2494,6 +2602,19 @@ class Scheduler:
             self._drain_pending()
             raise
         prev, self._pending = self._pending, (handle, decoding, None)
+        # whether the device's queue ran dry before this launch: asked of
+        # the program queued last before it (the pass's last prefill, else
+        # the chunk in flight), which syncs nothing
+        if prev is None:
+            timing = "empty"
+        else:
+            before = self._launched[-1][0] if self._launched else prev[0]
+            timing = "late" if before.ready() else "ahead"
+        METRICS.inc("tpu_model_decode_launches_total", 1.0,
+                    _LAUNCH_TIMING[timing])
+        first = self._launched[0][0] if self._launched else handle
+        self._lead_s = max(first.t_queued - t_step,
+                           0.9 * (self._lead_s or 0.0))
         self._land(prev)
 
     def _fanout(self, toks_n, snapshot: dict, chunked: bool = True):

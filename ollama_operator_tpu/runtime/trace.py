@@ -272,6 +272,9 @@ SPAN_TABLE = (
      "request read to scheduler.submit: parse, template, tokenize"),
     ("http.flush", "HTTP server",
      "_StreamCoalescer.flush: frame assembled to socket write returned"),
+    ("sched.hold", "scheduler",
+     "_hold_pass: asleep on _wake before the pass, a chunk in flight and "
+     "free slots with no request waiting yet (field end: filled|deadline)"),
     ("sched.housekeep", "scheduler",
      "shed expired, throttle, priority preemption, pool pressure relief"),
     ("sched.prefill", "scheduler", "_advance_prefill: one chunked piece"),

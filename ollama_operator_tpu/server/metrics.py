@@ -213,6 +213,24 @@ GLOBAL.describe("tpu_model_admissions_total",
                 "collected behind the next decode chunk's launch; awaited "
                 "= the loop waited for it where the admission was made (a "
                 "synchronous loop, speculation on, a constrained request)")
+GLOBAL.describe("tpu_model_pass_holds_total",
+                "Admission passes made with a decode chunk in flight, by "
+                "how the hold before the pass ended (end=filled|deadline|"
+                "none): filled = the scheduler held the pass until every "
+                "free slot had a request waiting; deadline = it held "
+                "until the chunk in flight was about to land (less what a "
+                "step takes the host to hand its first program to the "
+                "runtime) with slots still free; none = nothing to hold for (no free slot without a "
+                "waiter, no measured chunk yet, a loop that drafts)")
+GLOBAL.describe("tpu_model_decode_launches_total",
+                "Decode chunks launched by the double-buffered loop, by "
+                "what the device's queue held when the launch returned "
+                "(timing=ahead|late|empty): ahead = the program queued "
+                "before it (the chunk in flight, or the pass's last "
+                "prefill) had not finished; late = it had, so the device "
+                "stood dry until this launch; empty = no chunk was in "
+                "flight at all (the first chunk, or after a drain for "
+                "pages)")
 GLOBAL.describe("tpu_model_prefill_chunks_total",
                 "Chunked-prefill pieces dispatched (stall-free admission "
                 "of long prompts in bucket-sized pieces, as many a "
@@ -639,6 +657,11 @@ for _sampler in ("argmax", "candidates"):
                f'{{sampler="{_sampler}"}}')
 for _mode in ("launched", "awaited"):
     GLOBAL.inc("tpu_model_admissions_total", 0.0, f'{{mode="{_mode}"}}')
+for _end in ("filled", "deadline", "none"):
+    GLOBAL.inc("tpu_model_pass_holds_total", 0.0, f'{{end="{_end}"}}')
+for _timing in ("ahead", "late", "empty"):
+    GLOBAL.inc("tpu_model_decode_launches_total", 0.0,
+               f'{{timing="{_timing}"}}')
 GLOBAL.inc("tpu_model_model_flops_total", 0.0)
 
 

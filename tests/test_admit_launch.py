@@ -353,6 +353,10 @@ class _FailsAtWait:
 
     def __init__(self, exc):
         self._exc = exc
+        self.t_queued = time.perf_counter()
+
+    def ready(self):
+        return False
 
     def wait(self):
         raise self._exc
@@ -749,7 +753,8 @@ def test_a_piece_that_fails_at_collect_errors_its_owner_once(eng,
             def wait():
                 raise RuntimeError("device said no")
             h = type("H", (), {"wait": staticmethod(wait), "kind": "admit",
-                               "slots": (slot,)})()
+                               "slots": (slot,), "t_queued": h.t_queued,
+                               "ready": staticmethod(lambda: False)})()
         return h
 
     monkeypatch.setattr(eng, "admit_launch", poisoned)
