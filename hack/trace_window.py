@@ -11,7 +11,8 @@ prints for it (per module: seconds by scope and the costliest operations; the
 idle by host span) to ``chiprun_out/<tag>.trace.json``; the run's result line
 goes to ``chiprun_out/<tag>.result.json``; and ``TIMELINE_S`` seconds from the
 middle of the trace, laid out on one clock, go to
-``chiprun_out/<tag>.timeline.json``: every run of a device module and every
+``chiprun_out/<tag>.timeline.json`` (the environment's ``TIMELINE_S`` where
+set, 1.5 otherwise): every run of a device module and every
 span of the scheduler's thread as ``[start ms, end ms, name]``, so that one
 cycle of the loop can be read beside what the device did meanwhile. The exit
 code is the run's. The builder's tool for a chip call, not part of the
@@ -30,7 +31,7 @@ os.makedirs(out_dir, exist_ok=True)
 os.chdir(tree)
 sys.path.insert(0, tree)
 rmtree = shutil.rmtree
-TIMELINE_S = 1.5
+TIMELINE_S = float(os.environ.get("TIMELINE_S", "1.5"))
 
 
 def timeline(trace_spans, path):

@@ -426,6 +426,26 @@ class DecodeHandle:
         return self._out
 
 
+# tpu_model_admit_dispatch_seconds' label values (AdmitHandle.wait)
+_ADMIT_PART = {p: f'{{part="{p}"}}' for p in ("launch", "behind", "run")}
+
+
+def admit_parts(t_launch: float, t_queued: float, t_begin: float,
+                t_done: float) -> Tuple[float, float, float]:
+    """One admission dispatch by part, from the four instants its handle
+    stamps, in seconds: (launch, behind, run). launch: the host's staging
+    and the call into the runtime, blocked or not (to t_queued); behind:
+    queued on the device behind what was launched before it (to t_begin,
+    nothing where its predecessor had landed already); run: what the
+    dispatch itself took, by Engine._landed's rule. They add up to
+    t_done - t_launch. The stamps are host fetch times, so run also
+    holds the time the token lay on the device until the host came for
+    it (the first dispatch a pass collects waits for the chunk before
+    it to be fetched)."""
+    return (t_queued - t_launch, max(t_begin - t_queued, 0.0),
+            t_done - max(t_begin, t_queued))
+
+
 class AdmitHandle:
     """An admission launched and not awaited: what DecodeHandle is to
     ``decode_n``. The prefill program is dispatched and the slot already
@@ -464,6 +484,11 @@ class AdmitHandle:
             toks = self._engine._fetch(self._toks)
             self.t_begin, self.t_done = self._engine._landed(self.kind,
                                                              self._t0)
+            # once a dispatch, a batched one too, awaited or launched
+            for part, dur in zip(_ADMIT_PART.values(), admit_parts(
+                    self._t0, self.t_queued, self.t_begin, self.t_done)):
+                METRICS.observe("tpu_model_admit_dispatch_seconds", dur,
+                                part)
             self._out = [int(t) for t in toks.reshape(-1)]
             self._toks = None
         return self._out
@@ -829,6 +854,11 @@ class Engine:
         other processes own, so build a global array from the (identical)
         host value via make_array_from_callback."""
         if sharding is None:
+            if np.ndim(x) == 0:
+                # a scalar is no plain transfer: jnp.asarray makes it a
+                # program of its own (jit_convert_element_type, four of an
+                # admission's nine), which queues, and is held, like one
+                return self._enqueue("scalar_upload", jnp.asarray, x)
             return jnp.asarray(x)
         if not self._multi:
             return jax.device_put(x, sharding)
@@ -853,6 +883,19 @@ class Engine:
                             out_shardings=self._repl_sh)(0)
             self._dummy_key_val = k
         return k
+
+    @staticmethod
+    def _enqueue(program: str, exe, *args):
+        """Hand one compiled program to the runtime, inside the span
+        engine.enqueue: the call's own time, and the wait where the
+        runtime holds a launch because its queue is full (it takes 32
+        programs in flight; an admission is about nine: its prefill, its
+        key install and the uploads of its scalars, _g). The arguments
+        are staged before the call, so an upload among them lies
+        outside: an array's in the launch's own span, a scalar's in an
+        engine.enqueue of its own."""
+        with span("engine.enqueue", program=program):
+            return exe(*args)
 
     def _landed(self, kind: str, t_launch: float) -> Tuple[float, float]:
         """A handle's tokens just reached the host: (t_begin, t_done) of
@@ -1725,7 +1768,8 @@ class Engine:
                 else (slot * 1000003 + seq_len * 7919 + 12345)
                 & 0x7FFFFFFF)
         with span("engine.install_key"):
-            self.keys, key = self._install_key_fn(
+            self.keys, key = self._enqueue(
+                "install_key", self._install_key_fn,
                 self.keys, self._gr(np.int32(slot)),
                 self._gr(np.int32(seed)))
         if mask_row is not None:
@@ -1810,7 +1854,8 @@ class Engine:
             emb[0, :n] = embeds
             (tok, self.k_cache, self.v_cache, self.lengths, self.counts,
              self.last_tokens, self.pring,
-             self.mu) = self._admit_embeds_fn(
+             self.mu) = self._enqueue(
+                "admit", self._admit_embeds_fn,
                 self.params, self.k_cache, self.v_cache, self.lengths,
                 self.counts, self.last_tokens, self.pring, self.mu,
                 self._gr(tokens), self._gr(emb), self._gr(np.int32(slot)),
@@ -1820,7 +1865,8 @@ class Engine:
         else:
             (tok, self.k_cache, self.v_cache, self.lengths, self.counts,
              self.last_tokens, self.pring,
-             self.mu) = self._admit_exec(bucket)(
+             self.mu) = self._enqueue(
+                "admit", self._admit_exec(bucket),
                 self.params, self.k_cache, self.v_cache, self.lengths,
                 self.counts, self.last_tokens, self.pring, self.mu,
                 self._gr(tokens), self._gr(np.int32(slot)),
@@ -2008,7 +2054,8 @@ class Engine:
             sp_rows, keys_m = self._sp_many(opts_list), self._stack_keys(keys)
         (toks, self.k_cache, self.v_cache, self.lengths, self.counts,
          self.last_tokens, self.pring, self.mu) = \
-            self._admit_many_exec(m, bucket)(
+            self._enqueue(
+                "admit_many", self._admit_many_exec(m, bucket),
                 self.params, self.k_cache, self.v_cache, self.lengths,
                 self.counts, self.last_tokens, self.pring, self.mu,
                 self._gr(tokens), gi(list(slots)), gi(ns),
@@ -2181,7 +2228,8 @@ class Engine:
                  self._gr(np.int32(rln))]
         (tok, self.k_cache, self.v_cache, self.lengths, self.counts,
          self.last_tokens, self.pring, self.mu) = \
-            self._extend_exec(bucket, attn_a)(*args)
+            self._enqueue("extend", self._extend_exec(bucket, attn_a),
+                          *args)
         self._commit_slot(slot, n_total, opts)
         return AdmitHandle(self, tok, t0, "extend", (slot,))
 
@@ -3343,7 +3391,8 @@ class Engine:
         budgets = self.step_budgets(n)
         (toks_n, self.k_cache, self.v_cache, self.lengths, self.counts,
          self.last_tokens, self.pring, self.mu, self.keys,
-         self._gstate, load) = exe(
+         self._gstate, load) = self._enqueue(
+            "decode", exe,
             self.params, self.k_cache, self.v_cache, self.lengths,
             self.counts, self.last_tokens, self.pring, self.mu, self.sp,
             self.keys, self._active_dev, self.mask_bits, self._constr_dev,
@@ -3432,7 +3481,8 @@ class Engine:
         exe = self._spec_exec(k, self._attn_bucket(n))
         (toks, self.k_cache, self.v_cache, self.lengths, self.counts,
          self.last_tokens, self.pring, self.mu, self.keys,
-         self._gstate) = exe(
+         self._gstate) = self._enqueue(
+            "spec", exe,
             self.params, self.k_cache, self.v_cache, self.lengths,
             self.counts, self.last_tokens, self.pring, self.mu, self.sp,
             self.keys, self._active_dev, self.mask_bits, self._constr_dev,
@@ -3498,7 +3548,8 @@ class Engine:
         self._repeat_n[slot] = max(1, self.ecfg.repeat_last_n)
         self._rln_dev = self._g(self._repeat_n, self._slot_sh)
         (self.lengths, self.counts, self.last_tokens, self.pring,
-         self.mu) = self._release_fn(
+         self.mu) = self._enqueue(
+            "release", self._release_fn,
             self.lengths, self.counts, self.last_tokens, self.pring,
             self.mu, self._gr(np.int32(slot)))
 

@@ -48,6 +48,11 @@ _ADMIT_MODE = {m: f'{{mode="{m}"}}' for m in ("launched", "awaited")}
 # (Scheduler._hold_pass, _step)
 _HOLD_END = {e: f'{{end="{e}"}}' for e in ("filled", "deadline", "none")}
 _LAUNCH_TIMING = {t: f'{{timing="{t}"}}' for t in ("ahead", "late", "empty")}
+# tpu_model_page_stalls_total's and tpu_model_admission_passes_total's
+# (Scheduler._stall_for_pages, _admit_waiting)
+_STALL_CAUSE = {c: f'{{cause="{c}"}}' for c in (
+    "pool_dry_admit", "pool_dry_stitch", "pool_dry_decode")}
+_PASS_STALLED = {False: '{stalled="no"}', True: '{stalled="yes"}'}
 
 
 class SchedulerBusy(RuntimeError):
@@ -376,6 +381,9 @@ class Scheduler:
         # carries it; _land collects the first tokens behind that
         # launch. Empty whenever _step returns.
         self._launched: List[tuple] = []
+        # whether a stall for pages opened since the admission pass began
+        # (_stall_for_pages sets it, _admit_waiting clears and reads it)
+        self._pass_stalled = False
         # device-grammar escape bookkeeping: slot → request whose
         # ALREADY-LAUNCHED next dispatch ran with the slot frozen
         # (its automaton escaped the device table mid-chunk); that
@@ -954,6 +962,22 @@ class Scheduler:
                           quarantined=n_q, freed=freed)
         return freed
 
+    def _stall_for_pages(self, cause: str) -> None:
+        """The pool is dry with a chunk in flight or pages fenced behind
+        one: land and fan out what is in flight and unfence, before
+        anything else is dispatched. The device runs dry meanwhile, so
+        the stall is a span of its own (sched.stall; the wait, the
+        collects, the fan-out and its releases nest inside it) and is
+        counted by cause in tpu_model_page_stalls_total; the pass it
+        interrupts counts as stalled (_admit_waiting). Evicting cached
+        pages with nothing in flight frees them at once and is no
+        stall."""
+        self._pass_stalled = True
+        METRICS.inc("tpu_model_page_stalls_total", 1.0, _STALL_CAUSE[cause])
+        with span("sched.stall", cause=cause):
+            self._drain_pending()
+            self._quiesce(cause)
+
     def _next_waiting(self) -> Optional[Request]:
         """Priority-aware head of the waiting line. Preempted requests
         still re-admit ahead of queued ones OF THE SAME CLASS (they
@@ -1020,8 +1044,7 @@ class Scheduler:
         except PagesExhausted:
             if self._pending is not None or self.engine.quarantined_pages:
                 # likely fenced, not dry: unfence instead of evicting
-                self._drain_pending()
-                self._quiesce("pool_dry_stitch")
+                self._stall_for_pages("pool_dry_stitch")
             else:
                 self._evict_one_parked()
             return 0
@@ -1272,8 +1295,7 @@ class Scheduler:
                          f"has: {e}")
                 return True
             if self._pending is not None or self.engine.quarantined_pages:
-                self._drain_pending()
-                self._quiesce("pool_dry_admit")
+                self._stall_for_pages("pool_dry_admit")
                 reclaimed = True
             else:
                 reclaimed = self._evict_one_parked(
@@ -1452,8 +1474,7 @@ class Scheduler:
                 return True
             if self._pending is not None or self.engine.quarantined_pages:
                 # fenced, not dry (see _admit_one): unfence, don't evict
-                self._drain_pending()
-                self._quiesce("pool_dry_admit")
+                self._stall_for_pages("pool_dry_admit")
             else:
                 self._evict_one_parked(self._pages_for(len(ids)))
             self._preempted.insert(0, req)
@@ -1593,13 +1614,20 @@ class Scheduler:
                 if s not in self._prefilling]
 
     def _admit_waiting(self):
+        """One admission pass: waiting requests into free slots, in the
+        waiting line's order, until either runs out or the pool does. A
+        pass that took at least one request off the line is counted in
+        tpu_model_admission_passes_total, stalled="yes" where it had to
+        stall for pages (_stall_for_pages) on the way."""
         free = self._free_slots()
         batch: dict = {}   # prefill bucket → [(slot, req)] to batch-admit
+        took = self._pass_stalled = False
         try:
             while free:
                 req = self._next_waiting()
                 if req is None:
                     return
+                took = True
                 if req.cancelled.is_set():
                     req.out.put(("done", "cancelled"))
                     continue
@@ -1670,6 +1698,9 @@ class Scheduler:
                     return
         finally:
             self._flush_admit_batch(batch)
+            if took:
+                METRICS.inc("tpu_model_admission_passes_total", 1.0,
+                            _PASS_STALLED[self._pass_stalled])
 
     def _loop(self):
         while not self._stop.is_set():
@@ -1879,8 +1910,7 @@ class Scheduler:
             # anyone's cache or preempting a generation. One stall per
             # pool-dry event, vs a re-prefill per needless preemption.
             if self._pending is not None or self.engine.quarantined_pages:
-                self._drain_pending()
-                self._quiesce("pool_dry_decode")
+                self._stall_for_pages("pool_dry_decode")
                 continue
             if self._evict_one_parked():
                 continue

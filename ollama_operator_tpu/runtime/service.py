@@ -312,23 +312,20 @@ class LoadedModel:
             METRICS.gauge_fn("tpu_model_host_cache_pages",
                              lambda: (lm := wself()) is not None
                              and lm.engine.host_cache_pages or 0)
-        # utilization gauges (runtime/accounting.py): 60s-window MFU,
-        # occupancy, goodput and waste read from the scheduler's
-        # accounting snapshot; None (no peak known / idle) renders 0
-        def _util(field):
+        # utilization gauge (runtime/accounting.py): 60s-window MFU read
+        # from the scheduler's accounting snapshot; None (no peak known /
+        # idle) renders 0. Occupancy, goodput and waste are rates over
+        # tpu_model_useful_tokens_total / tpu_model_padded_tokens_total
+        # (and fields of /debug/utilization), not gauges
+        def _mfu():
             lm = wself()
             if lm is None or lm.scheduler is None:
                 return 0.0
             acct = getattr(lm.scheduler, "acct", None)
             if acct is None or not acct.enabled:
                 return 0.0
-            return float(acct.snapshot().get(field) or 0.0)
-        METRICS.gauge_fn("tpu_model_mfu", lambda: _util("mfu"))
-        METRICS.gauge_fn("tpu_model_occupancy", lambda: _util("occupancy"))
-        METRICS.gauge_fn("tpu_model_goodput_tokens_per_second",
-                         lambda: _util("goodput_tok_s"))
-        METRICS.gauge_fn("tpu_model_padding_waste_pct",
-                         lambda: _util("waste_pct"))
+            return float(acct.snapshot().get("mfu") or 0.0)
+        METRICS.gauge_fn("tpu_model_mfu", _mfu)
 
     # ------------------------------------------------------------------
     # warm-snapshot (scale-to-zero fast cold-start): the AOT warm-bucket
@@ -915,10 +912,7 @@ class LoadedModel:
         if getattr(self.engine, "host_cache_enabled", False):
             METRICS.remove_gauge("tpu_model_host_cache_bytes")
             METRICS.remove_gauge("tpu_model_host_cache_pages")
-        for _g in ("tpu_model_mfu", "tpu_model_occupancy",
-                   "tpu_model_goodput_tokens_per_second",
-                   "tpu_model_padding_waste_pct"):
-            METRICS.remove_gauge(_g)
+        METRICS.remove_gauge("tpu_model_mfu")
 
 
 class _IdleScheduler:

@@ -279,6 +279,10 @@ SPAN_TABLE = (
      "shed expired, throttle, priority preemption, pool pressure relief"),
     ("sched.prefill", "scheduler", "_advance_prefill: one chunked piece"),
     ("sched.admit", "admission", "_admit_waiting (field n: admitted)"),
+    ("sched.stall", "admission",
+     "a pass or a decode step found the pool dry with pages fenced: the "
+     "chunk in flight is landed and fanned out and the fence quiesced "
+     "before anything else is dispatched (field cause)"),
     ("sched.launch", "engine dispatch",
      "engine.decode_n_launch as called from _step"),
     ("sched.wait", "engine dispatch",
@@ -304,6 +308,12 @@ SPAN_TABLE = (
      "host-to-device staging of slot state (the unnamed jit_convert_"
      "element_type / jit__lambda programs): sampling rows, active mask, "
      "block tables, stacked keys"),
+    ("engine.enqueue", "engine dispatch",
+     "the call that hands one compiled program to the runtime, from the "
+     "call to its return: the runtime's own time, and all of the wait "
+     "where it holds the launch because its queue is full (field "
+     "program; a scalar's upload on one device is such a program too, "
+     "jit_convert_element_type: program=scalar_upload)"),
 )
 SPANS: Dict[str, str] = {name: layer for name, layer, _ in SPAN_TABLE}
 _SPAN_LABELS = {name: f'{{span="{name}"}}' for name in SPANS}

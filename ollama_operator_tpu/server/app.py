@@ -2113,8 +2113,6 @@ def serve(manager: ModelManager, host: str = "0.0.0.0", port: int = 11434
     METRICS.gauge_fn("tpu_model_hbm_bytes_in_use", _hbm_bytes_in_use)
     METRICS.gauge_fn("tpu_model_flight_recorder_events",
                      lambda: float(FLIGHT.seq))
-    METRICS.gauge_fn("tpu_model_flight_recorder_dumps",
-                     lambda: float(FLIGHT.dumps))
     t = threading.Thread(target=httpd.serve_forever, daemon=True,
                          name="http-server")
     t.start()

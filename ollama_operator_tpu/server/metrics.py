@@ -221,7 +221,29 @@ GLOBAL.describe("tpu_model_pass_holds_total",
                 "until the chunk in flight was about to land (less what a "
                 "step takes the host to hand its first program to the "
                 "runtime) with slots still free; none = nothing to hold for (no free slot without a "
-                "waiter, no measured chunk yet, a loop that drafts)")
+                "waiter, no measured chunk yet, a loop that drafts). A "
+                "pass that begins with no chunk in flight is not counted "
+                "here (after a drain for pages: the passes of a paged "
+                "pool that is always full); "
+                "tpu_model_admission_passes_total counts every pass")
+GLOBAL.describe("tpu_model_admission_passes_total",
+                "Admission passes that took at least one request off the "
+                "waiting line, by whether the pass had to stall for pages "
+                "on the way (stalled=yes|no): yes = it found the paged "
+                "pool dry with a chunk in flight or pages fenced behind "
+                "one, and landed, fanned out and unfenced before it "
+                "admitted (span sched.stall); a contiguous cache counts "
+                "no only")
+GLOBAL.describe("tpu_model_page_stalls_total",
+                "Stalls for pages (span sched.stall), by what found the "
+                "pool dry (cause=pool_dry_admit|pool_dry_stitch|"
+                "pool_dry_decode): an admission, a radix stitch's "
+                "copy-on-write, the next decode chunk's pages. Each "
+                "drains the chunk in flight with the device running dry "
+                "behind it; eviction of cached pages with nothing in "
+                "flight is not counted. Not "
+                "tpu_model_admission_stall_ms_total, which is the "
+                "scheduler thread blocked on prefill work")
 GLOBAL.describe("tpu_model_decode_launches_total",
                 "Decode chunks launched by the double-buffered loop, by "
                 "what the device's queue held when the launch returned "
@@ -351,9 +373,6 @@ GLOBAL.describe("tpu_model_flight_recorder_events",
                 "Structured events recorded into the flight-recorder "
                 "ring so far (runtime/trace.py); the ring keeps only "
                 "the last TPU_FLIGHT_EVENTS of them")
-GLOBAL.describe("tpu_model_flight_recorder_dumps",
-                "Flight-recorder dumps written to stderr (supervised "
-                "restarts and chaos-drill post-mortems)")
 GLOBAL.describe("tpu_model_replayed_requests_total",
                 "In-flight streams recovered across a supervised engine "
                 "restart by replay (re-prefill of prompt+generated, "
@@ -424,16 +443,6 @@ GLOBAL.describe("tpu_model_mfu",
                 "Achieved model-FLOPs utilization vs device peak over "
                 "the last 60s (0..1; 0 when no peak is known — CPU "
                 "without TPU_PEAK_FLOPS)")
-GLOBAL.describe("tpu_model_occupancy",
-                "Useful fraction of issued token positions over the "
-                "last 60s (active slots / padded grid, Orca-style "
-                "continuous-batching efficiency)")
-GLOBAL.describe("tpu_model_goodput_tokens_per_second",
-                "Useful tokens per second over the last 60s (decode + "
-                "prefill + accepted speculative)")
-GLOBAL.describe("tpu_model_padding_waste_pct",
-                "Percent of issued token positions that were padding "
-                "over the last 60s (100 - 100*occupancy)")
 GLOBAL.describe("tpu_model_autoscale_decisions_total",
                 "Autoscaler scale actions taken, by action "
                 "(action=up|down|to_zero|wake): each is one damped "
@@ -561,6 +570,18 @@ GLOBAL.describe("tpu_model_request_stage_seconds",
                 "RequestTrace when the response ends — off with "
                 "TPU_TRACE=0",
                 buckets=STAGE_BUCKETS)
+GLOBAL.describe("tpu_model_admit_dispatch_seconds",
+                "One admission dispatch (admit, admit_many, extend; a "
+                "batched one once) by part, observed when its first "
+                "token reaches the host (part=launch: host staging and "
+                "the call into the runtime, held there or not; behind: "
+                "queued on the device behind the chunk in flight and the "
+                "pass's earlier prefills; run: what the dispatch itself "
+                "took, the time its token lay unfetched included). The "
+                "parts add up to the prefill event's dur_ms, which "
+                "tpu_model_request_stage_seconds{stage=\"prefill\"} "
+                "reads; on with TPU_TRACE=0 too",
+                buckets=STAGE_BUCKETS)
 GLOBAL.describe("tpu_model_slot_vacant_seconds_total",
                 "Slot-seconds a decode slot stood free, split by whether "
                 "a request was waiting for admission meanwhile "
@@ -662,6 +683,14 @@ for _end in ("filled", "deadline", "none"):
 for _timing in ("ahead", "late", "empty"):
     GLOBAL.inc("tpu_model_decode_launches_total", 0.0,
                f'{{timing="{_timing}"}}')
+for _stalled in ("yes", "no"):
+    GLOBAL.inc("tpu_model_admission_passes_total", 0.0,
+               f'{{stalled="{_stalled}"}}')
+for _cause in ("pool_dry_admit", "pool_dry_stitch", "pool_dry_decode"):
+    GLOBAL.inc("tpu_model_page_stalls_total", 0.0, f'{{cause="{_cause}"}}')
+for _part in ("launch", "behind", "run"):
+    GLOBAL.seed_histogram("tpu_model_admit_dispatch_seconds",
+                          f'{{part="{_part}"}}')
 GLOBAL.inc("tpu_model_model_flops_total", 0.0)
 
 

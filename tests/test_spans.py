@@ -438,6 +438,425 @@ def test_admit_launched_share_reads_the_schedulers_own_count(admitted, want):
     assert got == (None if want is None else pytest.approx(want))
 
 
+# -- the stall for pages, the runtime's launch call, a dispatch by part --
+
+NEW_COUNTERS = [("tpu_model_admission_passes_total", "stalled", v)
+                for v in ("yes", "no")] + [
+    ("tpu_model_page_stalls_total", "cause", v)
+    for v in ("pool_dry_admit", "pool_dry_stitch", "pool_dry_decode")]
+ADMIT_PARTS = ("launch", "behind", "run")
+NEW_READERS = ("pass_stalled_share", "page_stall_ms_per_pass",
+               "stall_idle_share", "sched_enqueue_share", "admit_launch_ms",
+               "admit_behind_ms", "admit_run_ms")
+
+
+def test_the_table_has_twenty_rows_and_the_two_new_ones_their_layers():
+    assert len(SPAN_TABLE) == 20
+    assert SPANS["sched.stall"] == "admission"
+    assert SPANS["engine.enqueue"] == "engine dispatch"
+
+
+@pytest.mark.parametrize("family,key,value", NEW_COUNTERS)
+def test_stall_and_pass_counters_are_described_and_preseeded(family, key,
+                                                             value):
+    text = METRICS.render()
+    assert f"# HELP {family} " in text
+    assert re.search(rf'^{family}\{{{key}="{value}"\}} [0-9.]+$', text,
+                     re.M), f"{key}={value} absent from an idle scrape"
+
+
+@pytest.mark.parametrize("part", ADMIT_PARTS)
+def test_admit_dispatch_parts_are_described_and_preseeded(part):
+    text = METRICS.render()
+    assert "# HELP tpu_model_admit_dispatch_seconds " in text
+    assert (f'tpu_model_admit_dispatch_seconds_count{{part="{part}"}}'
+            in text)
+    bounds, _ = METRICS.hist_buckets("tpu_model_admit_dispatch_seconds",
+                                     f'{{part="{part}"}}')
+    assert bounds == STAGE_BUCKETS
+
+
+def test_the_pass_holds_help_says_which_passes_it_leaves_out():
+    text = METRICS.render()
+    (help_,) = re.findall(r"^# HELP tpu_model_pass_holds_total (.*)$", text,
+                          re.M)
+    assert "no chunk in flight" in help_
+    assert "tpu_model_admission_passes_total" in help_
+
+
+def _stall_counts():
+    get = METRICS.get
+    return dict(
+        stall=_series("tpu_model_span_seconds", '{span="sched.stall"}')[0],
+        **{f"{key}={v}": get(family, f'{{{key}="{v}"}}')
+           for family, key, v in NEW_COUNTERS})
+
+
+def _moved(before):
+    return {k: v - before[k] for k, v in _stall_counts().items() if
+            v != before[k]}
+
+
+def test_a_pass_that_stalls_for_pages_says_so_once():
+    """The pool holds one request at a time and the second arrives while
+    the first's last chunk is in flight (test_admit_launch's scene): its
+    pass finds the pool dry, and that is one sched.stall span, one stall
+    counted under its cause, one pass counted as stalled, the flight
+    recorder's event as before, and the tokens a pool with room gives."""
+    import dataclasses
+
+    import test_admit_launch as tal
+    from ollama_operator_tpu.models import decoder
+    from ollama_operator_tpu.runtime.scheduler import Scheduler
+    from ollama_operator_tpu.runtime.trace import FLIGHT
+    cfg, ecfg = tal.KINDS["paged_int8"]
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jax.numpy.float32)
+    eng = Engine(cfg, params, ecfg=dataclasses.replace(
+        ecfg, max_slots=2, n_pages=3))
+    pa, pb = tal.prompt(20), tal.prompt(20, base=11)
+    (ref,) = tal.serve(eng, [pb], tal.GREEDY, launched=True, max_tokens=9)
+    sched = tal.manual(Scheduler(eng, prefill_chunk=0, async_dispatch=True))
+    try:
+        before = _stall_counts()
+        ra = sched.submit(pa, tal.GREEDY, max_tokens=5)
+        sched._step()                   # first token; 4 more in flight
+        assert _moved(before) == {"stalled=no": 1}
+        assert sched._pending is not None
+        rb = sched.submit(pb, tal.GREEDY, max_tokens=9)
+        seq = FLIGHT.seq
+        sched._step()
+        assert _moved(before) == {"stalled=no": 1, "stalled=yes": 1,
+                                  "cause=pool_dry_admit": 1, "stall": 1}
+        events = [e for e in FLIGHT.snapshot() if e["seq"] > seq
+                  and e["kind"] == "fence_quiesce"]
+        assert [e["cause"] for e in events] == ["pool_dry_admit"]
+        assert tal.frames(ra)[-1] == ("done", "length")
+        tal.run_steps(sched)            # steps that admit nobody: no pass
+        assert _moved(before) == {"stalled=no": 1, "stalled=yes": 1,
+                                  "cause=pool_dry_admit": 1, "stall": 1}
+        assert tal.frames(rb) == ref
+    finally:
+        sched.shutdown()
+        tal.clean(eng)
+
+
+def _record_spans(monkeypatch, module):
+    """Every span ``module`` opens from now on, in order of opening."""
+    made = []
+
+    class Rec(span):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(self)
+    monkeypatch.setattr(module, "span", Rec)
+    return made
+
+
+def test_the_stall_span_holds_the_drain_and_the_fanout(monkeypatch):
+    """sched.stall is the parent of what the stall runs: the wait for the
+    chunk in flight and its fan-out nest inside it, so its self time is
+    what is left (the quiesce)."""
+    from ollama_operator_tpu.runtime import scheduler as sched_mod
+    cfg, params, eng, sched = make_stack(slots=2)
+    sched._stop.set()
+    sched._wake.set()
+    sched._thread.join(timeout=5)
+    made = _record_spans(monkeypatch, sched_mod)
+    try:
+        sched.submit(np.array([1, 2, 3], np.int32), GREEDY, max_tokens=9)
+        sched._step()
+        assert sched._pending is not None
+        made.clear()
+        sched._stall_for_pages("pool_dry_decode")
+        assert sched._pending is None
+        names = [s.name for s in made]
+        assert names[0] == "sched.stall"
+        assert {"sched.wait", "sched.fanout"} <= set(names[1:])
+        stall = made[0]
+        assert stall._fields == {"cause": "pool_dry_decode"}
+        assert stall.self_s == pytest.approx(
+            stall.dur - sum(s.dur for s in made[1:]
+                            if s._parent is stall))
+    finally:
+        sched.shutdown()
+
+
+def test_a_contiguous_cache_counts_its_passes_and_never_a_stall():
+    cfg, params, eng, sched = make_stack(slots=2)
+    try:
+        before = _stall_counts()
+        reqs = [sched.submit(np.array([i + 1, i + 2, i + 3], np.int32),
+                             GREEDY, max_tokens=4) for i in range(5)]
+        assert all(len(list(r.tokens())) == 4 for r in reqs)
+        moved = _moved(before)
+        assert set(moved) == {"stalled=no"}
+        assert 1 <= moved["stalled=no"] <= len(reqs)
+    finally:
+        sched.shutdown()
+
+
+def test_enqueue_is_observed_once_an_executable_call(monkeypatch):
+    """One engine.enqueue a compiled program handed to the runtime (an
+    admission: the key install, the prefill and their scalars; a chunk; a
+    release), each named, and the launch's own span does not count it as
+    its self time: a prefill call that stands 30 ms stands in
+    engine.enqueue."""
+    from ollama_operator_tpu.runtime import engine as engine_mod
+    cfg, params, eng, sched = make_stack(slots=2)
+    sched.shutdown()
+    p = np.array([1, 2, 3, 4], np.int32)
+    eng.admit(0, p, GREEDY)                       # compiled before the spy
+    eng.decode_n(4)
+    eng.release(0)
+    made = _record_spans(monkeypatch, engine_mod)
+    real = eng._admit_exec
+
+    def slow(bucket):
+        exe = real(bucket)
+        return lambda *a: (time.sleep(0.03), exe(*a))[1]
+    monkeypatch.setattr(eng, "_admit_exec", slow)
+    n0, _ = _series("tpu_model_span_seconds", '{span="engine.enqueue"}')
+    eng.admit_launch(0, p, GREEDY).wait()
+    eng.decode_n_launch(4).wait()
+    eng.release(0)
+    n1, _ = _series("tpu_model_span_seconds", '{span="engine.enqueue"}')
+    every = [s for s in made if s.name == "engine.enqueue"]
+    assert n1 - n0 == len(every)
+    # on one device a scalar's upload is a program too, and is enqueued
+    # like one: the slot and the seed before the key install, the
+    # constraint's flag, the slot, the length and the window before the
+    # prefill, the slot before the release
+    assert [s._fields["program"] for s in every] == (
+        ["scalar_upload"] * 2 + ["install_key"] + ["scalar_upload"] * 4
+        + ["admit", "decode", "scalar_upload", "release"])
+    enq = [s for s in every if s._fields["program"] != "scalar_upload"]
+    by = {s.name: s for s in made}
+    admit, prefill = by["engine.admit"], enq[1]
+    assert prefill._parent is admit and prefill.dur >= 0.03
+    assert enq[0]._parent is by["engine.install_key"]
+    assert enq[2]._parent is by["engine.decode_n"]
+    assert enq[3]._parent is by["engine.release"]
+    assert admit.self_s == pytest.approx(
+        admit.dur - sum(s.dur for s in made if s._parent is admit))
+    assert admit.self_s < admit.dur - 0.03
+
+
+@pytest.mark.parametrize("stamps,want", [
+    # launched behind a chunk in flight: staged 0.2, queued 0.3, ran 0.1
+    ((10.0, 10.2, 10.5, 10.6), (0.2, 0.3, 0.1)),
+    # an empty device (t_begin is the launch): nothing stood before it
+    ((10.0, 10.2, 10.0, 10.6), (0.2, 0.0, 0.4)),
+    # its predecessor landed while the launch still stood in the runtime
+    ((10.0, 10.6, 10.5, 10.7), (0.6, 0.0, 0.1)),
+])
+def test_an_admission_dispatch_by_part_worked_by_hand(stamps, want):
+    from ollama_operator_tpu.runtime.engine import admit_parts
+    got = admit_parts(*stamps)
+    assert got == pytest.approx(want)
+    assert sum(got) == pytest.approx(stamps[3] - stamps[0])   # done - launch
+
+
+def _part_series():
+    return {p: _series("tpu_model_admit_dispatch_seconds",
+                       f'{{part="{p}"}}') for p in ADMIT_PARTS}
+
+
+@pytest.mark.parametrize("form", ["awaited", "launched", "batched"])
+def test_a_dispatch_observes_its_parts_once_and_they_add_up(form,
+                                                            monkeypatch):
+    """An awaited admission, a launched one and an admit_many of two are
+    one observation of each part, whose sum is t_done - t_launch; with
+    TPU_TRACE off too; on an empty device nothing stood before it."""
+    monkeypatch.setattr(trace_mod, "TRACE_ENABLED", False)
+    cfg, params, eng, sched = make_stack(slots=2)
+    sched.shutdown()
+    p = np.array([1, 2, 3, 4], np.int32)
+    before = _part_series()
+    if form == "batched":
+        h = eng.admit_many_launch([0, 1], [p, p + 1], [GREEDY, GREEDY])
+        assert len(h.wait()) == 2
+    elif form == "launched":
+        h = eng.admit_launch(0, p, GREEDY)
+        h.wait()
+    else:
+        eng.admit(0, p, GREEDY)
+        h = None
+    h is None or h.wait()                         # a second wait: no more
+    after = _part_series()
+    assert all(after[q][0] == before[q][0] + 1 for q in ADMIT_PARTS)
+    moved = {q: after[q][1] - before[q][1] for q in ADMIT_PARTS}
+    assert moved["behind"] == 0.0                 # the device stood empty
+    if h is not None:
+        assert sum(moved.values()) == pytest.approx(h.t_done - h.t_launch,
+                                                    abs=1e-9)
+        assert moved["launch"] == pytest.approx(h.t_queued - h.t_launch,
+                                                abs=1e-9)
+
+
+def _scrapes(fill):
+    """(before, after): two scrapes of a registry of its own around
+    ``fill(reg)``, each family of ``fill`` present (at 1) in the first."""
+    from benchmark import prom
+    from ollama_operator_tpu.server.metrics import Metrics
+    reg = Metrics()
+    reg.inc("tpu_model_generated_tokens_total", 5.0)
+    fill(reg, 1.0)
+    before = prom.parse(reg.render())
+    fill(reg, None)
+    return before, prom.parse(reg.render())
+
+
+def _ctx(fill, **kw):
+    import types
+    before, after = _scrapes(fill)
+    return types.SimpleNamespace(before=before, after=after, notes={},
+                                 trace=None, **kw)
+
+
+def _fill_passes(yes, no, stall_s=0.0, causes=()):
+    def fill(reg, first):
+        for lab, n in (("yes", yes), ("no", no)):
+            reg.inc("tpu_model_admission_passes_total",
+                    first or float(n), f'{{stalled="{lab}"}}')
+        reg.observe("tpu_model_span_seconds", first or stall_s,
+                    '{span="sched.stall"}')
+        for cause in causes:
+            reg.inc("tpu_model_page_stalls_total", 1.0,
+                    f'{{cause="{cause}"}}')
+    return fill
+
+
+def _fill_parts(n, launch, behind, run, passes=0):
+    def fill(reg, first):
+        for part, s in (("launch", launch), ("behind", behind),
+                        ("run", run)):
+            for _ in range(1 if first else n):
+                reg.observe("tpu_model_admit_dispatch_seconds",
+                            first or s / n, f'{{part="{part}"}}')
+        if passes:
+            reg.inc("tpu_model_admission_passes_total",
+                    first or float(passes), '{stalled="no"}')
+            reg.inc("tpu_model_admission_passes_total", first or 0.0,
+                    '{stalled="yes"}')
+    return fill
+
+
+def _fill_enqueue(enqueue_s, phases):
+    def fill(reg, first):
+        reg.observe("tpu_model_span_seconds", first or enqueue_s,
+                    '{span="engine.enqueue"}')
+        for phase, s in phases.items():
+            reg.inc("tpu_model_breakdown_seconds_total", first or s,
+                    f'{{phase="{phase}"}}')
+    return fill
+
+
+@pytest.mark.parametrize("name,fill,want", [
+    ("pass_stalled_share", _fill_passes(9, 1), 90.0),
+    ("pass_stalled_share", _fill_passes(0, 12), 0.0),
+    ("pass_stalled_share", _fill_passes(0, 0), None),     # nobody admitted
+    ("page_stall_ms_per_pass",
+     _fill_passes(9, 1, 3.0, ["pool_dry_admit"]), 300.0),
+    ("page_stall_ms_per_pass", _fill_passes(0, 0, 0.5), None),
+    ("sched_enqueue_share", _fill_enqueue(
+        2.0, dict(host=3.0, dispatch_wait=6.0, idle=1.0)), 20.0),
+    ("sched_enqueue_share", _fill_enqueue(0.0, {}), None),
+    ("admit_launch_ms", _fill_parts(5, 0.5, 2.0, 0.1), 100.0),
+    ("admit_behind_ms", _fill_parts(5, 0.5, 2.0, 0.1), 400.0),
+    ("admit_run_ms", _fill_parts(5, 0.5, 2.0, 0.1, passes=2), 20.0),
+    ("admit_run_ms", _fill_parts(0, 0.0, 0.0, 0.0), None),  # none landed
+])
+def test_the_new_readers_read_the_programs_own_counts(name, fill, want):
+    """Each reader over two scrapes of a real registry's text: what the
+    window added, over what the window added."""
+    from benchmark import run
+    ctx = _ctx(fill)
+    got = run.layer_reader(name).read(ctx)
+    assert got == (None if want is None else pytest.approx(want))
+    if name == "page_stall_ms_per_pass" and want:
+        assert ctx.notes["page_stalls"]["by_cause"] == {
+            "pool_dry_admit": 1, "pool_dry_stitch": None,
+            "pool_dry_decode": None}
+    if name == "admit_run_ms" and want:
+        assert ctx.notes["admit_dispatch"]["dispatches_per_pass"] == 2.5
+
+
+def _hand_made_planes(stall):
+    """A device that ran 0-100 and 200-300 (ps), and a scheduler thread
+    with a pass open all along and ``stall`` (start, end) inside it."""
+    names = {1: ("fusion.1", ""), 2: ("sched.admit", ""),
+             3: ("sched.stall", ""), 4: ("sched.wait", "")}
+    host = [(0, 400, 2)]
+    if stall:
+        host += [(stall[0], stall[1], 3), (stall[0], stall[1] - 10, 4)]
+    return [{"name": "/device:TPU:0", "meta": names, "lines": [
+                {"name": "XLA Ops", "events": [(0, 100, 1), (200, 300, 1)]},
+                {"name": "XLA Modules", "events": []}]},
+            {"name": "/host:CPU", "meta": names, "lines": [
+                {"name": "scheduler", "events": host}]}]
+
+
+@pytest.mark.parametrize("stall,want_ps", [
+    ((150, 400), 50),         # the idle 100-200 half inside the stall
+    ((90, 210), 100),         # all of it, and busy time is not idle
+    ((210, 290), 0),          # a stall while the device ran
+    (None, 0),                # the span in the vocabulary, none in the trace
+])
+def test_stall_idle_share_on_hand_made_planes(monkeypatch, stall, want_ps):
+    from benchmark import run, trace_spans
+    planes = _hand_made_planes(stall)
+    monkeypatch.setattr(trace_spans, "find_trace", lambda where=None: "x")
+    monkeypatch.setattr(trace_spans, "read_planes", lambda path: planes)
+    monkeypatch.setattr(trace_spans, "reduce",
+                        lambda where=None: trace_spans.reduce_planes(planes))
+    ctx = _ctx(_fill_passes(1, 0, 0.1))
+    ctx.trace = {"window_s": 300e-12, "busy_s": 200e-12}
+    got = run.layer_reader("stall_idle_share").read(ctx)
+    assert got == pytest.approx(100.0 * want_ps / 300)
+    assert got <= 100.0 * (1 - 200 / 300) + 1e-9          # the idle share
+    note = ctx.notes["stall_idle"]
+    assert note["idle_in_stall_s"] == pytest.approx(want_ps * 1e-12)
+    assert note["stalls"] == (1 if stall else 0)
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_program_without_the_families_reads_nothing(name, monkeypatch):
+    """The parent under this PR's benchmark files: every accepted family
+    is there, the new ones are not; traced or not, the reader returns
+    None and does not raise."""
+    from benchmark import run, trace_spans
+    planes = _hand_made_planes(None)
+    monkeypatch.setattr(trace_spans, "find_trace", lambda where=None: "x")
+    monkeypatch.setattr(trace_spans, "read_planes", lambda path: planes)
+    monkeypatch.setattr(trace_spans, "reduce",
+                        lambda where=None: trace_spans.reduce_planes(planes))
+
+    def parent(reg, first):
+        reg.observe("tpu_model_span_seconds", 0.25,
+                    '{span="engine.admit"}')
+        reg.inc("tpu_model_breakdown_seconds_total", 4.0, '{phase="host"}')
+        reg.inc("tpu_model_pass_holds_total", 3.0, '{end="filled"}')
+    for trace in (None, {"window_s": 300e-12, "busy_s": 200e-12}):
+        ctx = _ctx(parent)
+        ctx.trace = trace
+        assert run.layer_reader(name).read(ctx) is None
+        assert ctx.notes == {}
+
+
+def test_the_benchmark_lists_the_new_readers_where_they_read():
+    import json
+    from pathlib import Path
+    bench = json.loads((Path(__file__).resolve().parents[1]
+                        / "BENCHMARK.json").read_text())
+    by = {m["name"]: m for m in bench["per_layer"]}
+    paged = ["starcoder2-3b.decode-saturated", "phi-2.decode-saturated"]
+    for name in NEW_READERS:
+        dense_only = name in NEW_READERS[:3]
+        assert by[name].get("workloads") == (paged if dense_only else None)
+    assert [m["name"] for m in bench["per_layer"]][-7:] == list(NEW_READERS)
+
+
 # -- device scopes in every path's lowered program ---------------------
 
 def _lowered_texts(monkeypatch, **ecfg_kw):
