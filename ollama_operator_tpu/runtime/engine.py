@@ -364,8 +364,8 @@ class DecodeHandle:
     never wait) keep bit-identical host lengths."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "budgets",
-                 "accepted", "t_done", "t_begin", "t_queued", "sampler",
-                 "_load")
+                 "accepted", "t_done", "t_begin", "t_queued", "enqueue_s",
+                 "sampler", "_load")
 
     def __init__(self, engine: "Engine", toks, t0: float, epoch: int = 0,
                  budgets: Optional[np.ndarray] = None,
@@ -379,8 +379,10 @@ class DecodeHandle:
         self._t0 = t0
         # perf_counter() when the program had been handed to the runtime
         # (the launch's host staging done): from then on the device has
-        # it queued
+        # it queued; enqueue_s is the engine's clock of the runtime's
+        # launch calls at that moment (Engine._enqueue)
         self.t_queued = time.perf_counter()
+        self.enqueue_s = engine.enqueue_s
         self._out: Optional[np.ndarray] = None
         self.epoch = epoch
         self.budgets = budgets
@@ -428,6 +430,9 @@ class DecodeHandle:
 
 # tpu_model_admit_dispatch_seconds' label values (AdmitHandle.wait)
 _ADMIT_PART = {p: f'{{part="{p}"}}' for p in ("launch", "behind", "run")}
+# tpu_model_radix_evicted_pages_total's, by what PageTable.unpin returned
+# (Engine._unpin_evicted)
+_EVICT_FENCE = {True: '{fence="free"}', False: '{fence="fenced"}'}
 
 
 def admit_parts(t_launch: float, t_queued: float, t_begin: float,
@@ -455,7 +460,7 @@ class AdmitHandle:
     Followers replay the launch and never wait."""
 
     __slots__ = ("_engine", "_toks", "_t0", "_out", "kind", "slots",
-                 "t_done", "t_begin", "t_queued")
+                 "t_done", "t_begin", "t_queued", "enqueue_s")
 
     def __init__(self, engine: "Engine", toks, t0: float, kind: str,
                  slots: Sequence[int]):
@@ -463,6 +468,7 @@ class AdmitHandle:
         self._toks = toks
         self._t0 = t0
         self.t_queued = time.perf_counter()      # as DecodeHandle's
+        self.enqueue_s = engine.enqueue_s
         self._out: Optional[List[int]] = None
         # the key of Engine.dispatch_ms this admission reports under
         self.kind = kind
@@ -513,6 +519,8 @@ class Engine:
         self.cfg = cfg
         self.ecfg = ecfg
         self.mesh = mesh
+        # seconds inside the runtime's launch call, all told (_enqueue)
+        self.enqueue_s = 0.0
         B, S = ecfg.max_slots, min(ecfg.max_seq_len, cfg.max_seq_len)
         self.n_slots, self.max_seq = B, S
         # layers that keep keys and values at every position: all, but for
@@ -884,8 +892,7 @@ class Engine:
             self._dummy_key_val = k
         return k
 
-    @staticmethod
-    def _enqueue(program: str, exe, *args):
+    def _enqueue(self, program: str, exe, *args):
         """Hand one compiled program to the runtime, inside the span
         engine.enqueue: the call's own time, and the wait where the
         runtime holds a launch because its queue is full (it takes 32
@@ -893,9 +900,13 @@ class Engine:
         key install and the uploads of its scalars, _g). The arguments
         are staged before the call, so an upload among them lies
         outside: an array's in the launch's own span, a scalar's in an
-        engine.enqueue of its own."""
-        with span("engine.enqueue", program=program):
-            return exe(*args)
+        engine.enqueue of its own. ``enqueue_s`` adds the calls up: a
+        clock of the time that was the runtime's and not the host's
+        (Scheduler._step takes it out of its lead)."""
+        with span("engine.enqueue", program=program) as sp:
+            out = exe(*args)
+        self.enqueue_s += sp.dur
+        return out
 
     def _landed(self, kind: str, t_launch: float) -> Tuple[float, float]:
         """A handle's tokens just reached the host: (t_begin, t_done) of
@@ -1889,14 +1900,27 @@ class Engine:
         # very next chunk must preempt would thrash prefill work.
         # free_for(slot): on a dp mesh each slot allocates only from its
         # own shard's sub-pool
-        ahead = min(n + self.ecfg.decode_chunk, self.max_seq)
-        if (self._pt.blocks_for(ahead) > self._pt.free_for(slot)
+        need = self._pt.blocks_for(
+            min(n + self.ecfg.decode_chunk, self.max_seq))
+        self._make_room(slot, need)
+        if (need > self._pt.free_for(slot)
                 or not self._pt.grow(slot, n)):
             raise PagesExhausted(
                 f"prompt of {n} tokens (+1 chunk headroom) needs "
-                f"{self._pt.blocks_for(ahead)} pages; "
-                f"{self._pt.free_for(slot)} free")
+                f"{need} pages; {self._pt.free_for(slot)} free")
         return self._table_row_dev(slot)
+
+    def _make_room(self, slot: int, pages: int):
+        """Before an allocation of ``pages`` for ``slot`` declares the
+        pool dry: evict, oldest first, as many cached leaves as are
+        missing, of those the fence lets go AT ONCE (no slot maps them
+        and the last one that did went at a retired epoch:
+        runtime/paged.py). A leaf that would only move to the quarantine
+        stays in the tree: the stall that would free it unfences it as
+        well. Pure function of mirrored state, inside mirrored calls."""
+        short = pages - self._pt.free_for(slot)
+        if short > 0 and self._radix is not None:
+            self.radix_evict(short, at_once=True)
 
     def _table_row_dev(self, slot: int):
         """The admission program's table argument: the slot's row [NBLK]
@@ -2207,6 +2231,7 @@ class Engine:
             ahead = min(n_total + self.ecfg.decode_chunk, self.max_seq)
             deficit = (self._pt.blocks_for(ahead)
                        - self._pt.owned_blocks(slot))
+            self._make_room(slot, deficit)
             if deficit > self._pt.free_for(slot) \
                     or not self._pt.grow(slot, n_total):
                 # the scheduler already popped this slot from its parked
@@ -2750,10 +2775,16 @@ class Engine:
         # clamp at max_seq: a slot finishing its context within the chunk
         # over-decodes into its last page (same as the dense cache's
         # over-decode-then-release semantics), never past the table
-        victims = [s for s in order
-                   if not self._pt.grow(
-                       s, min(int(self._host_lengths[s]) + n,  # lint: allow(host-sync-hot-path): host shadow of slot lengths
-                              self.max_seq))]
+        victims = []
+        for s in order:
+            upto = min(int(self._host_lengths[s]) + n,  # lint: allow(host-sync-hot-path): host shadow of slot lengths
+                       self.max_seq)
+            # a dry pool first takes the cached pages that are free at
+            # once (_make_room); only then is the slot a victim
+            self._make_room(s, self._pt.blocks_for(upto)
+                            - self._pt.owned_blocks(s))
+            if not self._pt.grow(s, upto):
+                victims.append(s)
         victims.reverse()
         return victims
 
@@ -2934,16 +2965,16 @@ class Engine:
             for n in t1run:
                 ls["skip2" if n.host.snapshot else "skip1"] += ps
             t1run = []
-        # make room for the planned uploads BEFORE enqueuing any of them:
-        # at this point no restitch program is in flight, so eviction can
-        # still spill victims to the host tier (mid-stitch the epoch has
-        # advanced and a dry pool would plainly free them instead). The
-        # probe just bumped the matched path MRU, so LRU victims are
-        # other prefixes — never the run being restitched.
-        need = len(t1run) + (1 if part is not None and q > 0
-                             and not skipped else 0)
-        if need > self._pt.n_free:
-            self.radix_evict(need - self._pt.n_free)
+        # make room for the planned uploads and the copy-on-write page
+        # BEFORE enqueuing any of them: at this point no restitch program
+        # is in flight, so eviction can still spill victims to the host
+        # tier (mid-stitch the epoch has advanced and a dry pool would
+        # plainly free them instead). Only leaves that are free at once
+        # (_make_room). The probe just bumped the matched path MRU, so
+        # LRU victims are other prefixes — never the run being
+        # restitched.
+        self._make_room(slot, len(t1run) + (1 if part is not None and q > 0
+                                            and not skipped else 0))
         try:
             for node in t1run:
                 was_snap = node.host.snapshot
@@ -3035,11 +3066,20 @@ class Engine:
         self.release(slot)
         return k * ps
 
-    def radix_evict(self, n_pages: int = 1) -> int:
+    def radix_evict(self, n_pages: int = 1, at_once: bool = False) -> int:
         """Evict up to ``n_pages`` least-recently-used radix leaves whose
         pages no slot currently maps, page-by-page (children before
         parents), returning their pages to the pool. Replaces the
-        all-or-nothing parked-slot eviction. Returns pages freed.
+        all-or-nothing parked-slot eviction. Returns pages evicted.
+
+        A page whose last slot mapping went at a retired epoch reaches
+        the free list at once, with a chunk in flight too: no program in
+        flight holds it in a block table (runtime/paged.py: the fence's
+        stamp is taken at the unmap, not at this unpin). Any other goes
+        to the quarantine. ``at_once`` passes those over: what an
+        allocation asks for that needs its pages now (_make_room).
+        tpu_model_radix_evicted_pages_total counts a page either way
+        (fence=free|fenced).
 
         With the host arena on (TPU_HOST_CACHE_GB > 0) an evicted page
         is SPILLED to the host tier first — but only while the epoch
@@ -3053,12 +3093,12 @@ class Engine:
             return 0
 
         def evictable(pg):
-            return self._pt.shared_refs(pg) == 0
+            return (self._pt.shared_refs(pg) == 0
+                    and not (at_once and self._pt.fenced(pg)))
 
         if self._arena is None:
             pages = self._radix.evict(n_pages, evictable)
-            for pg in pages:
-                self._pt.unpin(pg)
+            self._unpin_evicted(pages)
             return len(pages)
         freed = 0
         while freed < n_pages:
@@ -3070,10 +3110,16 @@ class Engine:
                 continue
             pages, hosts = self._radix.remove(node)
             self._arena.free_all(hosts)
-            for pg in pages:
-                self._pt.unpin(pg)
+            self._unpin_evicted(pages)
             freed += len(pages)
         return freed
+
+    def _unpin_evicted(self, pages):
+        """Drop the tree's pin on pages it just evicted, counting each by
+        whether the fence let it reach the free list at once."""
+        for pg in pages:
+            METRICS.inc("tpu_model_radix_evicted_pages_total", 1.0,
+                        _EVICT_FENCE[self._pt.unpin(pg)])
 
     def _spill_node(self, node) -> bool:
         """Move one radix node's page into the host arena (tier 0 → 1).
@@ -3093,9 +3139,10 @@ class Engine:
             return False
         kp, vp = self._gather_page_fn(self.k_cache, self.v_cache,
                                       self._gr(np.int32(node.page)))
+        # lint: allow(host-sync-hot-path): only while the fence is quiescent — no dispatch is in flight for the copy to stall
         kv = jax.device_get((kp, vp))
         pg = self._radix.mark_spilled(node, self._arena.store(kv))
-        self._pt.unpin(pg)
+        self._unpin_evicted([pg])
         self.n_spilled_pages += 1
         METRICS.inc("tpu_model_spilled_pages_total")
         return True
@@ -3310,6 +3357,17 @@ class Engine:
             return 0
         jax.block_until_ready(self.lengths)
         return self._pt.drain_quarantine()
+
+    def fence_retire(self, epoch: int):
+        """Tell the page table that the decode dispatch launched at
+        ``epoch`` has been materialised, as ``decode_n_launch(retire=)``
+        does, without launching anything: the scheduler calls it at the
+        beginning of a pass, so the pass's allocations find unfenced
+        what the handle it waited a step ago lets go. Host state only;
+        MIRRORED (followers never wait a handle), so call it at a fixed
+        place of the call stream. Dense engines: no-op."""
+        if self.paged:
+            self._pt.retire_epoch(epoch)
 
     def decode_n(self, n: Optional[int] = None) -> np.ndarray:
         """n decode steps in one device program; returns tokens [n, B].

@@ -269,7 +269,11 @@ class MirroredEngine:
                 # same call-stream position, every host's free list
                 # stays bit-identical (DecodeHandle.wait, which followers
                 # never run, deliberately does NOT retire epochs)
-                "fence_quiesce")
+                "fence_quiesce",
+                # fence_retire moves the retired epoch as a launch's
+                # retire= does, with no launch: host state alone, at a
+                # fixed place of the leader's step
+                "fence_retire")
 
     def __init__(self, inner, cp: ControlPlane):
         object.__setattr__(self, "_inner", inner)

@@ -20,23 +20,50 @@ A page returns to the free list exactly when its refcount hits zero —
 ``check()`` asserts that accounting invariant and the test suite runs it
 after every test (autouse fixture in conftest.py).
 
-**Epoch-fenced reclamation** (ISSUE 5): double-buffered async dispatch
-launches decode program N+1 before materialising N's tokens, so a page
-freed between the two launches may still be read (or written, for the
-slot's new positions) by the in-flight program through the block table it
-captured at launch. The table therefore carries a monotonic dispatch
-epoch: ``advance_epoch()`` stamps each ``decode_n_launch``; while any
-launched epoch is un-retired, a page whose refcount hits zero goes to a
-FIFO **quarantine** stamped with the current epoch instead of the free
-list, and becomes allocatable only once ``retire_epoch(e)`` certifies the
-program launched at its stamp has been materialised (vLLM's deferred
-block reclamation / SGLang's radix fencing, host-side). Retirement is
-driven by CALLERS at deterministic call-stream positions (the scheduler
-after waiting a handle, supervised restart via ``drain_quarantine``) so
-multi-host follower replay — which never materialises tokens — keeps
-byte-identical free lists. When no dispatch is outstanding
-(epoch == retired, the synchronous path) frees hit the pool directly,
-exactly as before.
+**Epoch-fenced reclamation** (ISSUE 5; the stamp's event is ISSUE 42's):
+double-buffered async dispatch launches decode program N+1 before
+materialising N's tokens, so a page freed between the two launches may
+still be read (or written, for the slot's new positions) by the in-flight
+program through the block table it captured at launch. The table therefore
+carries a monotonic dispatch epoch: ``advance_epoch()`` stamps each
+``decode_n_launch``, and ``retire_epoch(e)`` certifies that the program
+launched at ``e`` (and every earlier one) has been materialised.
+
+The rule: **a page is not handed out again while a program launched when
+a slot mapped it is un-materialised.** A program reaches a pool page only
+through the block-table rows it captured at launch, and a row holds a
+page only while a SLOT maps it: the radix tree's pin keeps a page
+resident and puts it in no row. So each page carries the epoch current
+when its last slot mapping was dropped (``release``: ``_unmapped``; a page
+the tree allocated for itself, ``alloc_pinned``, counts as mapped at the
+epoch it was allocated). If that was epoch E, every program that can hold
+the page was launched at or before E, decode or not: admissions and
+releases launched between two chunks run in order behind the earlier
+chunk and are collected by the scheduler's ``_land`` that waits it,
+before any retire names that epoch. Once E is retired nobody holds the
+page, whether the tree still pinned it or not. When a page's refcount
+hits zero (``_reclaim``) it therefore goes straight to the free list if
+its stamp is retired, whatever the current epoch is: a cached page whose
+slots went cycles ago is free the moment the tree evicts it, with a chunk
+in flight. Every other page goes to a FIFO **quarantine** stamped with
+the CURRENT epoch (never earlier than its own stamp, so the FIFO's stamps
+stay sorted) and becomes allocatable when that epoch retires (vLLM's
+deferred block reclamation / SGLang's radix fencing, host-side). Until
+ISSUE 42 the stamp was taken when the LAST REFERENCE went, the tree's pin
+included, which is never earlier than the last unmap: every page that
+rule freed this one frees too, and the only pages freed sooner are
+tree-only pages whose slots went at a retired epoch. A page stitched into
+a slot (``map_shared``) and released again carries the later stamp.
+``check()`` holds the rule as an invariant: no page on the free list has
+a stamp above the retired epoch.
+
+Retirement is driven by CALLERS at deterministic call-stream positions
+(the scheduler at the beginning of a pass and with each launch, after it
+waited a handle; supervised restart via ``drain_quarantine``) so
+multi-host follower replay, which never materialises tokens, keeps
+byte-identical free lists. When no dispatch is outstanding (epoch ==
+retired, the synchronous path) every stamp is retired and frees hit the
+pool directly, exactly as before.
 
 Fused speculative decoding needs NO states beyond these: a spec dispatch
 maps pages for its worst case (k+1 positions) via the same
@@ -105,6 +132,9 @@ class PageTable:
         self._epoch = 0
         self._retired = 0
         self._quarantine: List[tuple] = []
+        # per page, the epoch current when its last SLOT mapping was
+        # dropped: what the fence holds against the retired epoch
+        self._unmapped = np.zeros((n_pages,), np.int64)
         _LIVE.add(self)
 
     @property
@@ -159,15 +189,24 @@ class PageTable:
             self.tables[slot, len(owned)] = pg
             owned.append(pg)
 
-    def _reclaim(self, pg: int):
-        """A page's refcount just hit zero: return it to the pool — via
-        the epoch quarantine while a launched dispatch is un-retired (its
-        captured block table may still reference the page), directly
-        otherwise (synchronous flow, today's semantics)."""
-        if self._epoch > self._retired:
+    def fenced(self, pg: int) -> bool:
+        """Whether a program launched while a slot mapped ``pg`` may be
+        un-materialised: its last unmap came after the retired epoch.
+        A page that is not fenced is free the moment its last reference
+        goes (module docstring)."""
+        return bool(self._unmapped[pg] > self._retired)
+
+    def _reclaim(self, pg: int) -> bool:
+        """A page's refcount just hit zero: return it to the pool.
+        Directly where no un-retired program was launched while a slot
+        mapped it (always so in the synchronous flow); else via the
+        epoch quarantine, since a captured block table may still
+        reference the page. True where it is allocatable at once."""
+        if self.fenced(pg):
             self._quarantine.append((self._epoch, pg))
-        else:
-            self._free.append(pg)
+            return False
+        self._free.append(pg)
+        return True
 
     def release(self, slot: int):
         """Drop all of ``slot``'s page mappings (table row resets to
@@ -175,6 +214,7 @@ class PageTable:
         (through the epoch fence while a dispatch is in flight)."""
         owned = self._owned[slot]
         for pg in owned:
+            self._unmapped[pg] = self._epoch
             self._rc[pg] -= 1
             assert self._rc[pg] >= 0, f"double free of page {pg}"
             if self._rc[pg] == 0:
@@ -194,6 +234,9 @@ class PageTable:
         assert self._rc[pg] == 0, f"free page {pg} had rc {self._rc[pg]}"
         self._rc[pg] = 1
         self._pins[pg] = 1
+        # no slot will ever unmap it: the programs that can have reached
+        # it (as whatever it was before) were launched by now
+        self._unmapped[pg] = self._epoch
         return pg
 
     def pin(self, pg: int):
@@ -204,15 +247,16 @@ class PageTable:
         self._rc[pg] += 1
         self._pins[pg] += 1
 
-    def unpin(self, pg: int):
-        """Drop a radix-tree reference; frees the page at rc zero
-        (through the epoch fence while a dispatch is in flight — radix
-        eviction must not recycle a page an in-flight program reads)."""
+    def unpin(self, pg: int) -> bool:
+        """Drop a radix-tree reference; frees the page at rc zero: at
+        once where its last slot mapping went at a retired epoch, through
+        the quarantine otherwise (radix eviction must not recycle a page
+        an in-flight program reads). True where the page reached the
+        free list now."""
         assert self._pins[pg] >= 1, f"page {pg} is not pinned"
         self._pins[pg] -= 1
         self._rc[pg] -= 1
-        if self._rc[pg] == 0:
-            self._reclaim(pg)
+        return bool(self._rc[pg] == 0) and self._reclaim(pg)
 
     # ------------------------------------------------------------------
     # dispatch-epoch fence (async double-buffering; module docstring)
@@ -268,7 +312,7 @@ class PageTable:
     def shared_refs(self, pg: int) -> int:
         """Slot mappings of ``pg`` beyond the tree's pins — a pinned page
         with shared_refs == 0 is referenced only by the radix tree and is
-        safe to evict (unpin frees it immediately)."""
+        safe to evict (unpin frees it: at once unless ``fenced``)."""
         return int(self._rc[pg]) - int(self._pins[pg])
 
     def slot_pages(self, slot: int) -> List[int]:
@@ -295,8 +339,10 @@ class PageTable:
         page is dead to every slot and to the radix tree, merely not yet
         reallocatable), or referenced with rc == slot mappings + pins ≥ 1.
         Nothing leaked, nothing double freed, block-table rows consistent
-        with the ownership lists, quarantine stamps sane. Debug/test hook
-        (an autouse fixture runs it after every test)."""
+        with the ownership lists, quarantine stamps sane, and the fence's
+        rule: no page on the free list was unmapped after the retired
+        epoch. Debug/test hook (an autouse fixture runs it after every
+        test)."""
         free = Counter(self._free)
         quar = Counter(pg for _, pg in self._quarantine)
         mapped: Counter = Counter()
@@ -322,6 +368,9 @@ class PageTable:
                 assert rc == 0 and m == 0 and p == 0, (
                     f"page {pg} {'free' if f else 'quarantined'} but "
                     f"referenced (rc={rc}, mapped={m}, pins={p})")
+                assert not (f and self.fenced(pg)), (
+                    f"page {pg} free though unmapped at epoch "
+                    f"{int(self._unmapped[pg])}, retired {self._retired}")
             else:
                 assert rc == m + p and rc >= 1, (
                     f"page {pg} leaked or miscounted "

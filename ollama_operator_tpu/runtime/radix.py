@@ -39,11 +39,14 @@ so multi-host replays stay deterministic.
 
 Epoch-fence interplay (ISSUE 5): the tree itself never frees a page —
 eviction hands page ids back to the engine, whose ``unpin`` routes any
-refcount-zero page through the PageTable's epoch fence. Under async
-dispatch an evicted page therefore sits in quarantine until the decode
-dispatch whose block tables captured it materialises, so LRU eviction is
-safe to run with a program in flight; under sync dispatch the fence is
-pass-through and eviction frees immediately, exactly as before.
+refcount-zero page through the PageTable's epoch fence. The fence holds
+a page by when its last SLOT mapping went (the tree's pin puts a page in
+no block table): a leaf no slot has mapped since the retired epoch is
+free the moment it is evicted, with a program in flight too, and any
+other sits in quarantine until the decode dispatch whose block tables
+captured it materialises, so LRU eviction is safe to run with a program
+in flight; under sync dispatch the fence is pass-through and eviction
+frees immediately, exactly as before.
 Spilling is stricter: the engine only gathers a page's bytes while the
 fence is fully quiescent (no launched dispatch un-retired), so the
 host copy can never capture a page an in-flight program still writes.

@@ -419,7 +419,10 @@ class Scheduler:
         # what the host took, in seconds, from the start of a step's
         # housekeeping until the step's FIRST program was handed to the
         # runtime (t_queued of the pass's first prefill, else of the decode
-        # launch): the largest of the recent double-buffered steps, each
+        # launch), less the time inside the runtime's launch call
+        # (Engine.enqueue_s: where a full queue holds a launch): the
+        # largest of the recent steps that had a chunk in flight all
+        # through (one that drained for pages measures the drain), each
         # older one counting a tenth less. _hold_pass ends its hold this
         # long before the chunk in flight lands: from then on the device's
         # queue is never empty, and the rest of the pass runs in the shadow
@@ -964,14 +967,19 @@ class Scheduler:
 
     def _stall_for_pages(self, cause: str) -> None:
         """The pool is dry with a chunk in flight or pages fenced behind
-        one: land and fan out what is in flight and unfence, before
-        anything else is dispatched. The device runs dry meanwhile, so
-        the stall is a span of its own (sched.stall; the wait, the
+        one, and the engine found no cached page that is free at once
+        (Engine._make_room evicts those before it raises: a leaf no slot
+        has mapped since the retired epoch is in no block table a
+        program in flight captured): land and fan out what is in flight
+        and unfence, before anything else is dispatched. What is left to
+        stall for is pages whose slots went with the chunk in flight
+        still holding their rows: a tree too young to have older leaves,
+        a pool the live sequences fill. The device runs dry meanwhile,
+        so the stall is a span of its own (sched.stall; the wait, the
         collects, the fan-out and its releases nest inside it) and is
         counted by cause in tpu_model_page_stalls_total; the pass it
         interrupts counts as stalled (_admit_waiting). Evicting cached
-        pages with nothing in flight frees them at once and is no
-        stall."""
+        pages that are free at once is no stall."""
         self._pass_stalled = True
         METRICS.inc("tpu_model_page_stalls_total", 1.0, _STALL_CAUSE[cause])
         with span("sched.stall", cause=cause):
@@ -1245,7 +1253,9 @@ class Scheduler:
         """One admission (fresh or prefix-reusing), launched or awaited
         (_launches). Returns False when the paged pool ran dry and the
         request was requeued — the caller should stop admitting this
-        pass. ``tries``: a try that finds the pool dry reclaims pages
+        pass. The engine has by then evicted every cached page that the
+        fence lets go at once and still found too few (_make_room), so
+        ``tries``: a try that finds the pool dry reclaims pages
         (unfences, else evicts) and a request that came cold is tried
         again where it stands: at most once after each."""
         if self._expired_at_admission(req):
@@ -1277,11 +1287,14 @@ class Scheduler:
                             embeds=req.embeds, mask_row=mask_row)
             req.stats.n_reused = reuse_len
         except PagesExhausted as e:
-            # paged pool dry: under async dispatch first drain the
-            # pipeline and unfence quarantined pages (they may merely be
-            # fenced behind the in-flight dispatch, not truly gone), else
-            # evict cached pages (nothing is in flight then: they are
-            # free at once). A request that came cold is then tried again
+            # paged pool dry, the cached pages that were free at once
+            # taken already (Engine._make_room): under async dispatch
+            # first drain the pipeline and unfence (what is missing is
+            # fenced behind the in-flight dispatch: quarantined pages,
+            # leaves whose slots went while it flew), else evict cached
+            # pages (nothing is in flight then: they are free at once,
+            # or become so by the stall the next try takes). A request
+            # that came cold is then tried again
             # where it stands: the pass has paid for the stall, and a
             # request sent to the next pass waits a whole cycle beside
             # pages that are free. One that came with a prefix goes to
@@ -2436,6 +2449,15 @@ class Scheduler:
             # decode reading the same buffers
             self._drain_pending()
             self._run_tasks()
+        if self._pending is not None and self.engine.paged:
+            # the chunk _fence_ack names was waited by the previous
+            # step's _land, which also collected every admission launched
+            # behind it, and nothing is materialised between here and
+            # this step's launch, whose retire= says the same a pass
+            # later: the pass's allocations find unfenced (and its
+            # evictions free at once) what that chunk held
+            self.engine.fence_retire(self._fence_ack)
+        enqueue_s = self.engine.enqueue_s
         with span("sched.housekeep") as sp:
             t_step = sp.t0
             self._count_vacancy(t_step)
@@ -2642,9 +2664,16 @@ class Scheduler:
             timing = "late" if before.ready() else "ahead"
         METRICS.inc("tpu_model_decode_launches_total", 1.0,
                     _LAUNCH_TIMING[timing])
-        first = self._launched[0][0] if self._launched else handle
-        self._lead_s = max(first.t_queued - t_step,
-                           0.9 * (self._lead_s or 0.0))
+        if prev is not None:
+            # what the host took until the step's first program was handed
+            # to the runtime. Not a step that drained for pages on its way
+            # (prev is None: it waited a chunk out), and not the time
+            # inside the runtime's launch call, where a launch is held
+            # while 32 programs are in flight: t_queued is stamped after
+            # that wait, and a lead that holds it ends every hold at once
+            first = self._launched[0][0] if self._launched else handle
+            lead = first.t_queued - t_step - (first.enqueue_s - enqueue_s)
+            self._lead_s = max(lead, 0.9 * (self._lead_s or 0.0))
         self._land(prev)
 
     def _fanout(self, toks_n, snapshot: dict, chunked: bool = True):

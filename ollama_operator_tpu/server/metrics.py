@@ -244,6 +244,18 @@ GLOBAL.describe("tpu_model_page_stalls_total",
                 "flight is not counted. Not "
                 "tpu_model_admission_stall_ms_total, which is the "
                 "scheduler thread blocked on prefill work")
+GLOBAL.describe("tpu_model_radix_evicted_pages_total",
+                "Pages the radix prefix cache evicted, by whether the "
+                "page table's epoch fence let the page reach the free "
+                "list at once (fence=free|fenced): free = no slot had "
+                "mapped it since the last retired epoch, so no program "
+                "in flight can hold it in a block table (also every "
+                "eviction with nothing in flight, and a spill to the "
+                "host tier); fenced = it went to the quarantine until "
+                "the chunk in flight lands. An allocation that runs dry "
+                "evicts only pages that are free at once, so on a paged "
+                "pool that is always full free / (free + fenced) says "
+                "how often a pass got its pages without a stall")
 GLOBAL.describe("tpu_model_decode_launches_total",
                 "Decode chunks launched by the double-buffered loop, by "
                 "what the device's queue held when the launch returned "
@@ -688,6 +700,9 @@ for _stalled in ("yes", "no"):
                f'{{stalled="{_stalled}"}}')
 for _cause in ("pool_dry_admit", "pool_dry_stitch", "pool_dry_decode"):
     GLOBAL.inc("tpu_model_page_stalls_total", 0.0, f'{{cause="{_cause}"}}')
+for _fence in ("free", "fenced"):
+    GLOBAL.inc("tpu_model_radix_evicted_pages_total", 0.0,
+               f'{{fence="{_fence}"}}')
 for _part in ("launch", "behind", "run"):
     GLOBAL.seed_histogram("tpu_model_admit_dispatch_seconds",
                           f'{{part="{_part}"}}')

@@ -351,9 +351,10 @@ class _FailsAtWait:
     went through, the fetch raises."""
     kind = "admit"
 
-    def __init__(self, exc):
+    def __init__(self, exc, launched):
         self._exc = exc
-        self.t_queued = time.perf_counter()
+        self.t_queued = launched.t_queued
+        self.enqueue_s = launched.enqueue_s
 
     def ready(self):
         return False
@@ -369,7 +370,7 @@ def _fail_second_admission(eng, monkeypatch, exc):
     def launch(*a, **kw):
         handle = real(*a, **kw)
         n.append(handle)
-        return _FailsAtWait(exc) if len(n) == 2 else handle
+        return _FailsAtWait(exc, handle) if len(n) == 2 else handle
     monkeypatch.setattr(eng, "admit_launch", launch)
 
 

@@ -13,6 +13,7 @@ drains the quarantine, accounting stays clean).
 
 import dataclasses
 import queue as queue_mod
+import threading
 import time
 
 import jax
@@ -105,22 +106,113 @@ def test_drain_quarantine_reclaims_everything():
     pt.check()
 
 
-def test_unpin_routes_through_the_fence():
-    """Radix eviction frees via unpin: with a dispatch in flight the page
-    must quarantine, not return to the pool."""
+@pytest.mark.parametrize("unmapped", ["at_a_retired_epoch", "in_flight"])
+def test_unpin_routes_through_the_fence(unmapped):
+    """Radix eviction frees via unpin, and the fence holds a page by when
+    its last SLOT mapping went, not by when the tree lets go. A tree-only
+    page whose slot went before the dispatch in flight was launched is in
+    no block table that dispatch captured: free at once. One whose slot
+    went while the dispatch was in flight must quarantine until that
+    dispatch retires."""
     pt = PageTable(n_slots=2, n_pages=6, page_size=8, max_blocks=8)
     assert pt.grow(0, 8)
     pg = pt.slot_pages(0)[0]
     pt.pin(pg)                                 # the tree adopts it
-    pt.release(0)
+    if unmapped == "in_flight":
+        e = pt.advance_epoch()                 # a dispatch is in flight
+        pt.release(0)                          # ... and holds the row
+    else:
+        pt.release(0)
+        e = pt.advance_epoch()                 # launched without the page
     assert pt.n_free == 4                      # pinned: stays resident
-    pt.advance_epoch()                         # a dispatch is in flight
-    pt.unpin(pg)                               # LRU eviction
-    assert pt.quarantined == 1 and pt.n_free == 4
+    assert pt.fenced(pg) is (unmapped == "in_flight")
+    at_once = pt.unpin(pg)                     # LRU eviction
+    if unmapped == "in_flight":
+        assert not at_once
+        assert pt.quarantined == 1 and pt.n_free == 4
+        pt.check()
+        pt.retire_epoch(e)
+    else:
+        assert at_once
+    assert pt.quarantined == 0 and pt.n_free == 5
     pt.check()
     pt.drain_quarantine()
-    assert pt.n_free == 5
     pt.check()
+
+
+def test_a_stitched_page_carries_its_last_unmap():
+    """A cached page stitched into a second slot and released again while
+    a dispatch is in flight was in that dispatch's block table: the later
+    unmap is the stamp, whatever the first one was."""
+    pt = PageTable(n_slots=2, n_pages=6, page_size=8, max_blocks=8)
+    assert pt.grow(0, 8)
+    pg = pt.slot_pages(0)[0]
+    pt.pin(pg)
+    pt.release(0)                              # unmapped at epoch 0
+    assert not pt.fenced(pg)
+    pt.map_shared(1, [pg])                     # a radix hit stitches it
+    e1 = pt.advance_epoch()                    # launched with slot 1's row
+    assert not pt.fenced(pg)                   # mapped: the stamp is old
+    pt.release(1)                              # unmapped in flight
+    assert pt.fenced(pg)
+    e2 = pt.advance_epoch()
+    assert not pt.unpin(pg)                    # evicted: must quarantine
+    assert pt.quarantined == 1
+    pt.check()
+    pt.retire_epoch(e1)                        # the FIFO's stamp is e2's
+    assert pt.quarantined == 1
+    pt.retire_epoch(e2)
+    assert pt.quarantined == 0 and pt.n_free == 5
+    pt.check()
+
+
+def test_a_page_the_tree_allocated_counts_as_mapped_then():
+    """alloc_pinned: no slot ever maps the page, so it is held as if its
+    last unmap were the allocation."""
+    pt = PageTable(n_slots=1, n_pages=4, page_size=8, max_blocks=4)
+    e = pt.advance_epoch()
+    pg = pt.alloc_pinned()
+    assert pt.fenced(pg)
+    assert not pt.unpin(pg)
+    assert pt.quarantined == 1
+    pt.retire_epoch(e)
+    assert pt.quarantined == 0 and pt.n_free == 3
+    pg = pt.alloc_pinned()                     # nothing in flight now
+    assert not pt.fenced(pg) and pt.unpin(pg)
+    pt.check()
+
+
+def test_check_refuses_a_free_page_unmapped_after_the_retired_epoch():
+    pt = PageTable(n_slots=1, n_pages=4, page_size=8, max_blocks=4)
+    assert pt.grow(0, 8)
+    pg = pt.slot_pages(0)[0]
+    e = pt.advance_epoch()
+    pt.release(0)
+    assert pt.quarantined == 1
+    pt._quarantine.clear()                     # corrupt: skip the fence
+    pt._free.append(pg)
+    with pytest.raises(AssertionError, match="unmapped at epoch"):
+        pt.check()
+    pt.retire_epoch(e)                         # now the rule allows it
+    pt.check()
+
+
+def test_sharded_table_delegates_the_stamp():
+    """Each dp shard's table stamps its own pages; epochs are global."""
+    from ollama_operator_tpu.runtime.paged import ShardedPageTable
+    spt = ShardedPageTable(n_slots=4, dp=2, pages_per_shard=3,
+                           page_size=8, max_blocks=4)
+    assert spt.grow(0, 8) and spt.grow(2, 8)   # one slot a shard
+    spt.release(0)                             # nothing in flight: free
+    assert spt.quarantined == 0 and spt.n_free == 5
+    e = spt.advance_epoch()
+    spt.release(2)                             # in flight: the other shard
+    assert spt.quarantined == 1
+    assert [pt.quarantined for pt in spt._pts] == [0, 1]
+    spt.check()
+    spt.retire_epoch(e)
+    assert spt.quarantined == 0 and spt.n_free == 6
+    spt.check()
 
 
 def test_check_catches_free_and_quarantined():
@@ -282,6 +374,162 @@ def test_preempt_under_pressure_in_flight_converges(params):
         eng._pt.check()
     finally:
         sched.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a pool the radix tree keeps full: every pass must evict
+# ---------------------------------------------------------------------------
+
+CHURN = dataclasses.replace(PAGED, decode_chunk=4)
+N_CLIENTS, N_ROUNDS = 2, 7
+
+
+def _churn_prompt(i):
+    return ((np.arange(9 + 2 * (i % 5)) + 7 * i) % 50 + 3).astype(np.int32)
+
+
+def _evict_counts():
+    out = {f: METRICS.get("tpu_model_radix_evicted_pages_total",
+                          f'{{fence="{f}"}}') for f in ("free", "fenced")}
+    out["stalls"] = sum(
+        METRICS.get("tpu_model_page_stalls_total", f'{{cause="{c}"}}')
+        for c in ("pool_dry_admit", "pool_dry_stitch", "pool_dry_decode"))
+    return out
+
+
+def _churn(params, n_pages, async_on, calls=None):
+    """Two clients, each sending its next request when its last one is
+    done, through a scheduler stepped by hand: fourteen distinct greedy
+    requests of 21-29 positions (each donates two or three pages when it
+    ends). Returns (streams by request, what the counters moved by, the
+    most radix pages seen, the free list at rest). ``calls`` wraps the
+    engine in a mirror that records the call stream."""
+    from test_admit_launch import frames, manual, tokens_of
+    eng = Engine(XLA, params, ecfg=dataclasses.replace(CHURN,
+                                                       n_pages=n_pages))
+    served = eng
+    if calls is not None:
+        from ollama_operator_tpu.runtime.follower import MirroredEngine
+
+        class Tape:
+            dispatch_lock = threading.Lock()
+
+            def broadcast(self, msg):
+                calls.append(msg)
+        served = MirroredEngine(eng, Tape())
+    sched = manual(Scheduler(served, prefill_chunk=0,
+                             async_dispatch=async_on))
+    before = _evict_counts()
+    streams, live, sent, most = {}, {}, 0, 0
+    try:
+        for _ in range(600):
+            for c in range(N_CLIENTS):
+                req = live.get(c)
+                if req is not None and not req.stats.t_done:
+                    continue
+                if req is not None:
+                    assert req.error is None
+                    live[c] = None
+                if sent < N_CLIENTS * N_ROUNDS:
+                    live[c] = sched.submit(_churn_prompt(sent), GREEDY,
+                                           max_tokens=12)
+                    streams[sent] = live[c]
+                    sent += 1
+            if sent == N_CLIENTS * N_ROUNDS and not any(live.values()) \
+                    and sched._pending is None:
+                break
+            sched._step()
+            most = max(most, eng.radix_pages)
+            eng._pt.check()
+        else:
+            raise AssertionError("the clients did not finish")
+        out = {i: tokens_of(frames(r)) for i, r in streams.items()}
+        assert all(len(t) == 12 for t in out.values())
+        if eng.quarantined_pages:
+            sched._step()                      # the idle step unfences
+        now = _evict_counts()
+        return (out, {k: now[k] - before[k] for k in now}, most,
+                list(eng._pt._free))
+    finally:
+        sched.shutdown()
+
+
+def test_a_full_pool_evicts_at_once_and_streams_stay(params):
+    """(a) the streams of a pool that must evict in every pass are those
+    of a pool that never evicts and of the synchronous loop; (b) with a
+    chunk in flight the pass takes its pages from leaves whose slots went
+    at a retired epoch: no stall for pages, every eviction free at once,
+    none into the quarantine."""
+    tight, moved, most, _ = _churn(params, 14, True)
+    roomy, moved_roomy, most_roomy, _ = _churn(params, 200, True)
+    sync, moved_sync, _, _ = _churn(params, 14, False)
+    assert tight == roomy == sync
+    assert moved_roomy == {"free": 0, "fenced": 0, "stalls": 0}
+    assert most_roomy > 14                     # more than the tight pool is
+    assert moved["stalls"] == 0 and moved["fenced"] == 0
+    assert moved["free"] >= 10                 # a page or more a request
+    assert moved_sync["stalls"] == 0 and moved_sync["fenced"] == 0
+    assert moved_sync["free"] >= 10
+
+
+def test_leaves_donated_in_flight_fall_back_to_the_stall(params):
+    """(c) the tree's only leaves went with the chunk in flight still
+    holding their rows: nothing is free at once, so the pass stalls as it
+    always did (lands the chunk, unfences), evicts then, and admits in
+    the same step. Nothing is evicted into the quarantine on the way."""
+    from test_admit_launch import frames, manual, run_steps, tokens_of
+    eng = Engine(XLA, params, ecfg=dataclasses.replace(
+        CHURN, max_slots=2, n_pages=7))         # seven pages of eight
+    sched = manual(Scheduler(eng, prefill_chunk=0, async_dispatch=True))
+    try:
+        ra = sched.submit(_churn_prompt(0), GREEDY, max_tokens=5)   # 9+5
+        rb = sched.submit(_churn_prompt(3), GREEDY, max_tokens=9)   # 15+9
+        sched._step()                          # both admitted, chunk 1
+        sched._step()                          # chunk 2 launched, 1 landed:
+        assert ra.stats.t_done                 # a ended, donated in flight
+        assert sched._pending is not None and eng.radix_pages == 1
+        assert all(eng._pt.fenced(pg) for pg in range(1, 8)
+                   if eng._pt._pins[pg])
+        # b maps three pages, the tree pins one, a's other two wait in the
+        # quarantine: one page is free, and a prompt of four pages (five
+        # with a chunk's headroom) finds the only leaf fenced
+        before = _evict_counts()
+        rc = sched.submit(np.arange(60, 90, dtype=np.int32), GREEDY,
+                          max_tokens=4)
+        assert eng.free_pages == 1 and eng.quarantined_pages == 2
+        sched._step()
+        moved = {k: v - before[k] for k, v in _evict_counts().items()}
+        assert moved["stalls"] == 1 and moved["fenced"] == 0
+        assert moved["free"] == 1              # after the unfence, at once
+        assert rb.stats.t_done                 # the stall landed b's last
+        assert rc.slot is not None and sched._running[rc.slot] is rc
+        run_steps(sched)
+        assert len(tokens_of(frames(rc))) == 4
+        assert len(tokens_of(frames(rb))) == 9
+        eng._pt.check()
+    finally:
+        sched.shutdown()
+
+
+def test_fence_retire_is_mirrored_and_a_replay_keeps_the_free_list(params):
+    """(d) the retire at the pass is a mirrored call; a follower that
+    replays the leader's call stream (and never waits a handle) ends with
+    the leader's free list, eviction for eviction."""
+    from ollama_operator_tpu.runtime.follower import MirroredEngine
+    assert "fence_retire" in MirroredEngine.MIRRORED
+    calls = []
+    _, moved, _, free = _churn(params, 14, True, calls=calls)
+    assert moved["free"] >= 10
+    names = [c[1] for c in calls]
+    assert "fence_retire" in names and "decode_n_launch" in names
+    assert "radix_evict" not in names          # evicted inside the calls
+    follower = Engine(XLA, params, ecfg=dataclasses.replace(CHURN,
+                                                            n_pages=14))
+    for _, name, a, kw in calls:
+        getattr(follower, name)(*a, **kw)
+    follower._pt.check()
+    assert list(follower._pt._free) == free
+    assert follower.radix_pages > 0
 
 
 # ---------------------------------------------------------------------------

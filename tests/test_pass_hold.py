@@ -358,10 +358,11 @@ def test_the_lead_is_the_largest_of_recent_steps(eng, monkeypatch):
         sched._step()
         assert sched._lead_s == pytest.approx(90.0)
         sched._lead_s = 1e-9
+        t0 = time.perf_counter()
         sched._step()
         handle = sched._pending[0]
         # this step's own reading: housekeeping to the launch's hand-over
-        assert sched._lead_s >= handle.t_queued - handle.t_launch > 0
+        assert 1e-9 < sched._lead_s <= handle.t_queued - t0
         # a pass that admits: its FIRST prefill's hand-over is the reading,
         # not the second's nor the decode launch's behind them
         real = eng.admit_launch
@@ -379,6 +380,52 @@ def test_the_lead_is_the_largest_of_recent_steps(eng, monkeypatch):
         sched._step()
         assert len(firsts) == 2 and firsts[0] < firsts[1]
         assert 1e-9 < sched._lead_s <= firsts[0] - t0
+    finally:
+        done(sched, eng)
+
+
+def test_the_lead_leaves_out_the_runtimes_launch_call(eng, monkeypatch):
+    """The runtime holds a launch while 32 programs are in flight, and
+    t_queued is stamped after that wait: a lead that held it would end
+    every later hold at once. What a step spends inside the runtime's
+    call (Engine.enqueue_s) is not the host's."""
+    sched, _, _, _ = in_flight(eng, monkeypatch, running=2)
+    try:
+        held = 0.05
+        enqueue = eng._enqueue
+
+        def blocked(program, exe, *args):
+            return enqueue(program,
+                           lambda *a: (time.sleep(held), exe(*a))[1], *args)
+        monkeypatch.setattr(eng, "_enqueue", blocked)
+        sched._lead_s = 1e-9
+        t0, clock0 = time.perf_counter(), eng.enqueue_s
+        sched._step()
+        handle = sched._pending[0]
+        assert eng.enqueue_s - clock0 >= held
+        assert handle.t_queued - t0 >= held
+        assert 1e-9 < sched._lead_s <= handle.t_queued - t0 - held
+    finally:
+        done(sched, eng)
+
+
+def test_the_lead_does_not_learn_from_a_step_that_drained(eng, monkeypatch):
+    """A step that stalled for pages waited the chunk in flight out before
+    its first launch (_stall_for_pages drains _pending): what it took is
+    the drain, not the host's lead. 0.3 s learnt there would keep the
+    hold off for some 27 steps."""
+    sched, _, log, _ = in_flight(eng, monkeypatch, running=2)
+    try:
+        relieve = sched._relieve_pressure
+
+        def dry_once(n_steps):
+            sched._stall_for_pages("pool_dry_decode")
+            return relieve(n_steps)
+        monkeypatch.setattr(sched, "_relieve_pressure", dry_once)
+        sched._lead_s = 0.5
+        sched._step()
+        assert log == ["wait", "launch"]         # drained, then launched
+        assert sched._lead_s == 0.5              # not even decayed
     finally:
         done(sched, eng)
 

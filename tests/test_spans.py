@@ -854,7 +854,51 @@ def test_the_benchmark_lists_the_new_readers_where_they_read():
     for name in NEW_READERS:
         dense_only = name in NEW_READERS[:3]
         assert by[name].get("workloads") == (paged if dense_only else None)
-    assert [m["name"] for m in bench["per_layer"]][-7:] == list(NEW_READERS)
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-8:-1] == list(NEW_READERS)
+    # the fence's reader came after them, for the paged cells alone
+    assert names[-1] == "evict_free_share"
+    assert by["evict_free_share"] == dict(
+        name="evict_free_share", unit="%", better="higher",
+        source="program_counter", layer="admission", moves="out_tok_s",
+        workloads=paged)
+
+
+# -- an evicted page by whether the fence let it go at once ------------
+
+@pytest.mark.parametrize("fence", ["free", "fenced"])
+def test_the_evicted_pages_counter_is_described_and_preseeded(fence):
+    family = "tpu_model_radix_evicted_pages_total"
+    text = METRICS.render()
+    assert f"# HELP {family} " in text
+    assert re.search(rf'^{family}\{{fence="{fence}"\}} [0-9.]+$', text,
+                     re.M), f"fence={fence} absent from an idle scrape"
+
+
+def _fill_evicted(free, fenced):
+    def fill(reg, first):
+        for lab, n in (("free", free), ("fenced", fenced)):
+            reg.inc("tpu_model_radix_evicted_pages_total",
+                    first or float(n), f'{{fence="{lab}"}}')
+    return fill
+
+
+@pytest.mark.parametrize("fill,want", [
+    (_fill_evicted(30, 10), 75.0),
+    (_fill_evicted(12, 0), 100.0),
+    (_fill_evicted(0, 7), 0.0),
+    (_fill_evicted(0, 0), None),          # a contiguous cache: seeded, still
+    (lambda reg, first: None, None),      # the parent: no such family
+])
+def test_evict_free_share_reads_the_windows_evictions(fill, want):
+    from benchmark import run
+    ctx = _ctx(fill)
+    got = run.layer_reader("evict_free_share").read(ctx)
+    assert got == (None if want is None else pytest.approx(want))
+    if want is None:
+        assert ctx.notes == {}
+    else:
+        assert sum(ctx.notes["evicted_pages"].values()) > 0
 
 
 # -- device scopes in every path's lowered program ---------------------
