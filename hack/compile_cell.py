@@ -5,11 +5,14 @@ reference's ``forward_chosen`` / ``forward_rounded``, from the tree given as
 argv[1], and prints each one's ``memory_analysis()``:
 
     JAX_PLATFORMS=cpu python hack/compile_cell.py /root/repo lfm2-8b-a1b \
-        [decode] [admit] [admit_many] [extend] [ref]
+        [decode] [admit] [admit_many] [extend] [ref] [probe]
 
 What the TPU compiler refuses it raises here, at no chip time. It counts one
 program at a time, not what else the process keeps on the device (the
-probe's two engines hold a cache each). ~4 min for a 16-layer routed model."""
+probe's two engines hold a cache each). ``probe`` (a contiguous cache only)
+is the logits program of ``server_child.probe``: it does not donate the
+cache, so its temporaries hold a copy of every cache leaf a decode step
+writes, beside the probe engine's own. ~4 min for a 16-layer routed model."""
 import os
 import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -63,13 +66,33 @@ if "admit_many" in what:
     eng._admit_many_exec(4, 256)
 if "extend" in what:
     eng._extend_exec(256, 4096)
+if "probe" in what:
+    from ollama_operator_tpu.models import decoder
+    T = min(sc.PROBE_TOKENS, ecfg.max_seq_len // 2)
+
+    def logits_fn(p, kc, vc, tokens, step_tokens, lengths):
+        pre, _ks, _vs = decoder.prefill_chunk(p, eng.cfg, tokens)
+        dec, _kc, _vc = decoder.forward_with_cache(
+            p, eng.cfg, step_tokens, kc, vc, lengths,
+            attn_len=eng._attn_bucket(1))
+        return pre[0, T - 1], dec[0, 0]
+    spy(eng, "probe", "logits_fn", jax.jit(logits_fn), eng.params,
+        eng.k_cache, eng.v_cache, jnp.zeros((1, T), jnp.int32),
+        jnp.zeros((eng.n_slots, 1), jnp.int32), eng.lengths)
 if "ref" in what:
     ref = sc.load_reference(conf)
+    p = jax.tree_util.tree_map(sds, params)
+if "ref" in what and not hasattr(ref, "forward_chosen"):
+    # a model that makes no choice: the probe runs ``forward`` alone
+    t = jax.ShapeDtypeStruct((257,), jnp.int32, sharding=one)
+    c = jax.jit(lambda p, t: ref.forward(p, conf, t)[-2:]).lower(p, t).compile()
+    m = c.memory_analysis()
+    print(f"reference.forward: args {m.argument_size_in_bytes/GB:.3f} temp {m.temp_size_in_bytes/GB:.3f} out {m.output_size_in_bytes/GB:.3f}", flush=True)
+elif "ref" in what:
     T = 257
     k = conf["num_experts_per_tok"]
     Lr = conf["num_hidden_layers"] - conf.get(
         "num_dense_layers", conf.get("first_k_dense_replace", 0))
-    p = jax.tree_util.tree_map(sds, params)
     t = jax.ShapeDtypeStruct((T,), jnp.int32, sharding=one)
     ch = {"moe.route": jax.ShapeDtypeStruct((Lr, T, k), jnp.int32, sharding=one)}
     c = jax.jit(lambda p, t, c: ref.forward_chosen(p, conf, t, c)).lower(p, t, ch).compile()

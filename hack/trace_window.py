@@ -8,7 +8,9 @@ part (PERF.md section 7, "prefill by part").
 runs ``<tree>/benchmark/run.py`` with the arguments (give ``--trace 1``), and
 before the trace is removed writes what ``python -m benchmark.trace_spans``
 prints for it (per module: seconds by scope and the costliest operations; the
-idle by host span) to ``chiprun_out/<tag>.trace.json``; the run's result line
+idle by host span) to ``chiprun_out/<tag>.trace.json``; the device's costliest
+operations with their whole scope path (``tf_op``: whichever reader lists the
+scope) to ``chiprun_out/<tag>.ops.json``; the run's result line
 goes to ``chiprun_out/<tag>.result.json``; and ``TIMELINE_S`` seconds from the
 middle of the trace, laid out on one clock, go to
 ``chiprun_out/<tag>.timeline.json`` (the environment's ``TIMELINE_S`` where
@@ -63,6 +65,32 @@ def timeline(trace_spans, path):
     return {"seconds": TIMELINE_S, "modules": cut(mods), "spans": cut(spans)}
 
 
+def costliest_ops(trace_spans, path, n=60):
+    """The device's ``n`` costliest operations by self time over the whole
+    trace, each with its count and its full ``tf_op`` (every scope the
+    program gave it, whichever reader lists it): ``[seconds, count, name,
+    tf_op]``."""
+    found = trace_spans.find_trace(path)
+    if found is None:
+        return []
+    total = {}
+    for plane in trace_spans.read_planes(found):
+        if not plane["name"].startswith(trace_spans.DEVICE_PREFIX):
+            continue
+        for ln in plane["lines"]:
+            if ln["name"] != trace_spans.OPS_LINE:
+                continue
+            for _s, _e, mid, self_ps in trace_spans.self_times(ln["events"]):
+                name, tf_op = plane["meta"].get(mid, ("?", ""))
+                row = total.setdefault((name.split(" = ")[0], tf_op), [0, 0])
+                row[0] += self_ps
+                row[1] += 1
+    rows = sorted(((ps * 1e-12, cnt, name, tf_op)
+                   for (name, tf_op), (ps, cnt) in total.items()),
+                  reverse=True)
+    return [list(r) for r in rows[:n]]
+
+
 def keep_then_remove(path, *a, **kw):
     if os.path.basename(path).startswith("bench-trace-"):
         from benchmark import trace_spans
@@ -71,6 +99,8 @@ def keep_then_remove(path, *a, **kw):
             trace_spans.main(["trace_spans", path])
         with open(os.path.join(out_dir, tag + ".trace.json"), "w") as f:
             f.write(buf.getvalue())
+        with open(os.path.join(out_dir, tag + ".ops.json"), "w") as f:
+            json.dump(costliest_ops(trace_spans, path), f, indent=0)
         tl = timeline(trace_spans, path)
         if tl is not None:
             with open(os.path.join(out_dir, tag + ".timeline.json"), "w") as f:

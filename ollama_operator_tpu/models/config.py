@@ -132,13 +132,14 @@ class ModelConfig:
     n_dense_layers: int = 0
     dense_ffn_dim: int = 0
     # hybrid stacks: one letter a layer, "m" a Mamba-2 mixer
-    # (granitemoehybrid), "c" a gated short convolution (lfm2), "A"
-    # attention over every earlier position, "w" attention over the last
+    # (granitemoehybrid), "c" a gated short convolution (lfm2), "d" a
+    # gated delta-rule linear-attention mixer (olmo_hybrid), "A" attention
+    # over every earlier position, "w" attention over the last
     # sliding_window positions (exaone_moe), whose keys and values are a
     # ring of that many positions a slot; "" = every layer attends. A
     # stack has ONE kind beside "A" (no published stack mixes two of "m",
-    # "c" and "w"). "A" and "w" share one stack of projections. A string,
-    # so the config stays hashable and arrives whole from JSON.
+    # "c", "d" and "w"). "A" and "w" share one stack of projections. A
+    # string, so the config stays hashable and arrives whole from JSON.
     layer_kinds: str = ""
     # the attention kinds of a hybrid stack whose q and k rotate, where
     # cfg.rope: "" = both; "w" = the window layers alone, the full layers
@@ -158,6 +159,20 @@ class ModelConfig:
     # activation; out = (C * conv) W_out. A slot carries the last
     # conv_kernel - 1 values of u, [conv_kernel - 1, dim] float32 a layer
     conv_kernel: int = 3               # lfm2 conv_L_cache
+    # gated delta rule ("d", models/decoder._delta_mixer): delta_heads
+    # heads of delta_key_dim (q, k) and delta_value_dim (v); a depthwise
+    # causal convolution of delta_conv taps and SiLU over [q, k, v]. A slot
+    # carries, a layer, the state S [heads, key_dim, value_dim] float32,
+    # updated by a rank-one correction of itself, and the convolution's
+    # last delta_conv - 1 inputs. delta_neg_eigval doubles beta, so the
+    # eigenvalue of I - beta k k^T along k lies in [-1, 1]
+    # (linear_allow_neg_eigval). delta_chunk: the blocked prefill's block
+    delta_heads: int = 0
+    delta_key_dim: int = 96
+    delta_value_dim: int = 192
+    delta_conv: int = 4
+    delta_neg_eigval: bool = False
+    delta_chunk: int = 64
     rope: bool = True                  # False = no positional embedding
                                        # (position_embedding_type "nope")
     kernels: str = "auto"              # attention impl: auto|pallas|xla|interpret
@@ -193,6 +208,16 @@ class ModelConfig:
         return self.layer_kinds.count("c")
 
     @property
+    def n_delta_layers(self) -> int:
+        return self.layer_kinds.count("d")
+
+    @property
+    def delta_conv_dim(self) -> int:
+        """Channels the delta mixer's convolution runs over: q, k and v."""
+        return self.delta_heads * (2 * self.delta_key_dim
+                                   + self.delta_value_dim)
+
+    @property
     def n_routed_layers(self) -> int:
         return self.n_layers - self.n_dense_layers if self.n_experts else 0
 
@@ -224,12 +249,15 @@ class ModelConfig:
     @property
     def ssm_state_bytes(self) -> int:
         """Recurrent state one sequence carries, all layers, float32: a
-        Mamba layer's state and convolution inputs, a short convolution's
-        inputs alone."""
+        Mamba layer's or a delta layer's state and convolution inputs, a
+        short convolution's inputs alone."""
         return 4 * (self.n_ssm_layers * (
             self.ssm_inner * self.ssm_state
             + (self.ssm_conv - 1) * self.ssm_conv_dim)
-            + self.n_conv_layers * (self.conv_kernel - 1) * self.dim)
+            + self.n_conv_layers * (self.conv_kernel - 1) * self.dim
+            + self.n_delta_layers * (
+                self.delta_heads * self.delta_key_dim * self.delta_value_dim
+                + (self.delta_conv - 1) * self.delta_conv_dim))
 
     @property
     def window_ring_bytes(self) -> int:
@@ -258,12 +286,17 @@ class ModelConfig:
             ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_state
                         + self.ssm_heads) + self.ssm_inner * d)
             conv = d * 3 * d + d * d
+            # q, k, v, the output gate, the two per-head gates, out
+            dv = self.delta_heads * self.delta_value_dim
+            delta = (d * (self.delta_conv_dim + dv + 2 * self.delta_heads)
+                     + dv * d)
             # the leading dense layers' MLP, at its own width and in the
             # stack's own form
             dense = (3 if self.mlp_type == "gated" else 2) \
                 * d * self.dense_ffn_dim
             return (self.n_attn_layers * attn + self.n_ssm_layers * ssm
                     + self.n_conv_layers * conv
+                    + self.n_delta_layers * delta
                     + self.n_dense_layers * dense
                     + (l - self.n_dense_layers) * mlp + emb)
         return l * (attn + mlp) + emb
@@ -305,21 +338,24 @@ class ModelConfig:
             assert len(self.layer_kinds) == self.n_layers, (
                 f"layer_kinds names {len(self.layer_kinds)} layers, "
                 f"n_layers is {self.n_layers}")
-            assert set(self.layer_kinds) <= {"m", "c", "w", "A"}, (
+            assert set(self.layer_kinds) <= {"m", "c", "d", "w", "A"}, (
                 self.layer_kinds)
             assert "A" in self.layer_kinds, "no attention layer to cache"
-            assert not ("m" in self.layer_kinds and "c" in self.layer_kinds), (
+            assert len(set(self.layer_kinds) & {"m", "c", "d"}) <= 1, (
                 "one recurrent kind a stack")
             if "m" in self.layer_kinds:
                 assert self.ssm_heads > 0 and self.ssm_conv >= 2
             if "c" in self.layer_kinds:
                 assert self.conv_kernel >= 2
+            if "d" in self.layer_kinds:
+                assert self.delta_heads > 0 and self.delta_conv >= 2
+                assert self.delta_chunk > 0
             for field in ("parallel_block", "post_norms", "altern_sliding"):
                 assert not getattr(self, field), (
                     f"hybrid stacks run the plain pre-norm block: {field} "
                     "is set")
             if "w" in self.layer_kinds:
-                assert not set(self.layer_kinds) & {"m", "c"}, (
+                assert not set(self.layer_kinds) & {"m", "c", "d"}, (
                     "window layers stand beside full attention alone: "
                     "no recurrent kind in their stack")
                 assert self.sliding_window > 0, (
@@ -577,6 +613,29 @@ PRESETS = {
         n_dense_layers=1, dense_ffn_dim=96, layer_kinds="wwwAwwwA",
         sliding_window=8, rope_kinds="w", qk_norm=True,
         rope_theta=1000000.0, max_seq_len=256),
+    # Olmo-Hybrid-7B (olmo_hybrid), cut in DEPTH alone: the first three
+    # whole periods of the 32 layers, d d d A (layers 0-11; the others would
+    # lie on further chips as pipeline stages). Every width, every head
+    # and the whole vocabulary are the published ones: hidden 3840, gated
+    # delta-rule layers of 30 heads (keys 96, values 192, convolution of 4
+    # taps, negative eigenvalues allowed), MHA 30/30 at head_dim 128
+    # without positional embedding, SwiGLU MLP of 11008, untied head over
+    # 100,352 rows, no experts. benchmark/configs/olmo-hybrid-7b.json
+    # states the cut and what the published config leaves to assumption.
+    "olmo-hybrid-7b": _mk(
+        arch="olmohybrid", vocab_size=100352, dim=3840, n_layers=12,
+        n_heads=30, n_kv_heads=30, head_dim=128, ffn_dim=11008,
+        layer_kinds="dddAdddAdddA", delta_heads=30, delta_key_dim=96,
+        delta_value_dim=192, delta_conv=4, delta_neg_eigval=True,
+        delta_chunk=64, rope=False, tie_embeddings=False, norm_eps=1e-6,
+        max_seq_len=65536),
+    # the same shape at toy widths (tests, --rehearse): two periods
+    "tiny-olmo-hybrid": _mk(
+        arch="olmohybrid", vocab_size=256, dim=64, n_layers=8, n_heads=4,
+        n_kv_heads=4, head_dim=16, ffn_dim=128, layer_kinds="dddAdddA",
+        delta_heads=4, delta_key_dim=8, delta_value_dim=16, delta_conv=4,
+        delta_neg_eigval=True, delta_chunk=8, rope=False,
+        tie_embeddings=False, norm_eps=1e-6, max_seq_len=256),
     "dolphin-mixtral": _mk(arch="llama", vocab_size=32002, dim=4096,
                            n_layers=32, n_heads=32, n_kv_heads=8,
                            head_dim=128, ffn_dim=14336, n_experts=8,

@@ -83,7 +83,16 @@ def _mm(cfg: ModelConfig, x, w, out_dtype=None):
     int4 the fused kernel throughout. An "auto" that nobody resolved (a
     forward pass outside any engine) is XLA, and an explicit
     kernels="pallas"/"interpret" config still routes everything through
-    kernels."""
+    kernels.
+
+    A float32 activation against a bfloat16 matrix (the residual stream and
+    the sublayers of a delta stack, ``_hybrid_layers``) goes into the MXU
+    as bfloat16 and comes out float32, unrounded: ``x @ w`` would promote
+    the MATRIX to float32 first."""
+    if (not Q.is_quantized(w) and x.dtype == jnp.float32
+            and w.dtype == jnp.bfloat16):
+        return jnp.matmul(x.astype(w.dtype), w,
+                          preferred_element_type=out_dtype or jnp.float32)
     return Q.matmul(x, w, out_dtype, kernels=_mm_mode(cfg))
 
 
@@ -160,6 +169,21 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         layers["ssm_d"] = jnp.ones((Lm, H), dtype)
         layers["ssm_norm_w"] = jnp.ones((Lm, di), dtype)
         layers["ssm_out"] = w(next(keys), (Lm, di, D))
+    if cfg.n_delta_layers:
+        Ld, H = cfg.n_delta_layers, cfg.delta_heads
+        C, dv = cfg.delta_conv_dim, cfg.delta_heads * cfg.delta_value_dim
+        layers["delta_qkv"] = w(next(keys), (Ld, D, C))
+        layers["delta_ab"] = w(next(keys), (Ld, D, 2 * H), 0.2)
+        layers["delta_z"] = w(next(keys), (Ld, D, dv))
+        layers["delta_conv_w"] = w(next(keys), (Ld, cfg.delta_conv, C), 0.4)
+        layers["delta_dt_bias"] = w(next(keys), (Ld, H), 0.5)
+        # alpha = exp(-exp(a_log) softplus(a + dt_bias)): decays of 1 to
+        # 16 a unit of softplus, as Gated DeltaNet draws them
+        layers["delta_a_log"] = jnp.broadcast_to(
+            jnp.log(1.0 + jnp.arange(H, dtype=jnp.float32) % 16),
+            (Ld, H)).astype(dtype)
+        layers["delta_norm_w"] = jnp.ones((Ld, cfg.delta_value_dim), dtype)
+        layers["delta_out"] = w(next(keys), (Ld, dv, D))
     if cfg.n_dense_layers:
         # the leading dense layers' MLP; the routed leaves below are stacked
         # over the layers after them
@@ -563,7 +587,7 @@ def _unembed(cfg: ModelConfig, params: Params, x):
         logits = _mm(cfg, x, params["lm_head"], out_dtype=jnp.float32)
     else:
         head = params["tok_emb"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("btd,dv->btv", x, head,
+        logits = jnp.einsum("btd,dv->btv", x.astype(head.dtype), head,
                             preferred_element_type=jnp.float32)
     if "lm_head_b" in params:
         logits = logits + params["lm_head_b"].astype(jnp.float32)
@@ -804,8 +828,10 @@ def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load):
 # Every layer is  h = x + rm * mixer(norm(x));  h + rm * ffn(norm(h)), and
 # the mixer is attention ("A") or the stack's recurrent one by
 # cfg.layer_kinds: a Mamba-2 block ("m", granitemoehybrid, whose attention
-# has no rotary embedding) or a gated short convolution ("c", lfm2, whose
-# attention norms q and k and rotates them). The layers run as ONE lax.scan
+# has no rotary embedding), a gated short convolution ("c", lfm2, whose
+# attention norms q and k and rotates them) or a gated delta-rule
+# linear-attention mixer ("d", olmo_hybrid, in a stack without experts whose
+# attention has no rotary embedding). The layers run as ONE lax.scan
 # whose body traces the shared half (norms, router, experts, residuals)
 # once and picks the mixer with lax.cond; each mixer's weights are stacked
 # over their own layers only and a layer finds its row through the static
@@ -817,7 +843,10 @@ def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load):
 # What a sequence carries besides keys and values is, per Mamba layer, the
 # state S [H, P, N] float32 and the last K-1 inputs of the causal
 # convolution [K-1, C] float32; per short-convolution layer the last K-1
-# inputs alone [K-1, D]. It cannot be cut back to a prefix, only advanced,
+# inputs alone [K-1, D]; per delta layer the state S [H, dk, dv] float32 (a
+# matrix a head, updated by a rank-one correction of itself) and the last K-1
+# inputs of the convolution over [q, k, v], in the same two leaves as a
+# Mamba layer's. It cannot be cut back to a prefix, only advanced,
 # so every entry point says how many of a row's positions are real
 # (``n_valid``): a padded prefill position, a slot that sits inactive in a
 # decode batch, leaves it exactly as it was. It travels inside the two
@@ -843,10 +872,10 @@ _DENSE_FFN = ("w_gate", "w_up", "w_down", "b_up", "b_down")
 def split_state(k_cache, v_cache):
     """(k_cache, v_cache, state): what a slot carries beside its full-length
     keys and values, taken out of the two cache trees. ``state`` is None for
-    trees without, else (ssm, conv, win): a Mamba stack's (ssm, conv, None),
-    a short-convolution stack's (None, conv, None), a window stack's (None,
-    None, (k rings, v rings)), each ring array [Lw, B, KvH, W, hd] or its
-    int8 form {"q", "s" [Lw, B, KvH, W]}."""
+    trees without, else (ssm, conv, win): a Mamba or a delta stack's (ssm,
+    conv, None), a short-convolution stack's (None, conv, None), a window
+    stack's (None, None, (k rings, v rings)), each ring array [Lw, B, KvH, W,
+    hd] or its int8 form {"q", "s" [Lw, B, KvH, W]}."""
     if not (isinstance(v_cache, dict)
             and ("conv" in v_cache or "win" in v_cache)):
         return k_cache, v_cache, None
@@ -879,10 +908,12 @@ def join_state(k_cache, v_cache, state):
 
 def empty_state(cfg: ModelConfig, B: int, kv_dtype=jnp.float32):
     """What a sequence carries before its first position, zeros: (ssm [Lm,
-    B, H, P, N], conv [Lm, B, K-1, C], None) float32; (None, conv [Lc, B,
-    K-1, D], None) for a stack of short convolutions; (None, None, (k, v))
-    for a stack of window layers, rings [Lw, B, KvH, W, hd] of ``kv_dtype``
-    (int8: codes and float32 scales, as the cache keeps its rows)."""
+    B, H, P, N], conv [Lm, B, K-1, C], None) float32; (ssm [Ld, B, H, dk,
+    dv], conv [Ld, B, K-1, 2 H dk + H dv], None) for a stack of delta
+    layers; (None, conv [Lc, B, K-1, D], None) for a stack of short
+    convolutions; (None, None, (k, v)) for a stack of window layers, rings
+    [Lw, B, KvH, W, hd] of ``kv_dtype`` (int8: codes and float32 scales, as
+    the cache keeps its rows)."""
     if cfg.n_window_layers:
         shape = (cfg.n_window_layers, B, cfg.n_kv_heads, cfg.sliding_window,
                  cfg.head_dim)
@@ -896,6 +927,12 @@ def empty_state(cfg: ModelConfig, B: int, kv_dtype=jnp.float32):
     if cfg.n_conv_layers:
         return None, jnp.zeros((cfg.n_conv_layers, B, cfg.conv_kernel - 1,
                                 cfg.dim), jnp.float32), None
+    if cfg.n_delta_layers:
+        Ld = cfg.n_delta_layers
+        return (jnp.zeros((Ld, B, cfg.delta_heads, cfg.delta_key_dim,
+                           cfg.delta_value_dim), jnp.float32),
+                jnp.zeros((Ld, B, cfg.delta_conv - 1, cfg.delta_conv_dim),
+                          jnp.float32), None)
     Lm = cfg.n_ssm_layers
     return (jnp.zeros((Lm, B, cfg.ssm_heads, cfg.ssm_head_dim,
                        cfg.ssm_state), jnp.float32),
@@ -920,7 +957,7 @@ def _hybrid_rows(cfg: ModelConfig):
     lists, for the scans to cut. ``wrow`` is None where the two are the
     same: only "w" layers, which share the "A" layers' stack of
     projections, count their weights' rows over both kinds."""
-    rows, wrows, n = [], [], {"A": 0, "m": 0, "c": 0, "w": 0}
+    rows, wrows, n = [], [], {"A": 0, "m": 0, "c": 0, "d": 0, "w": 0}
     for c in cfg.layer_kinds:
         rows.append(n[c])
         wrows.append(n["A"] + n["w"] if c in "Aw" else n[c])
@@ -1170,6 +1207,138 @@ def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
     return out, ssm, conv
 
 
+def _delta_rule(cfg: ModelConfig, S0, q, k, v, g, beta):
+    """The gated delta rule over T positions from state S0, exact:
+    S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t);
+    S_t = S' + k_t u_t^T;  o_t = S_t^T q_t.
+
+    S0 [B, H, dk, dv]; q, k [B, T, H, dk]; v [B, T, H, dv]; g <= 0 and beta
+    [B, T, H], both 0 where a position is not real: then S_t = S_{t-1}. All
+    float32. One position is the recurrence as written, on the vector unit
+    (the compiler reads the state three times and writes it once: 4.9 ms a
+    step of nine layers at 32 slots, where both read-outs from S0 in one
+    MXU pass took 3.8 and the bytes alone would take 1.6: my chip run, PR
+    44). More go block by block (cfg.delta_chunk,
+    Gated DeltaNet's form): with c the running sum of g inside a block, the
+    block's u solve (I + A) U = beta (V - exp(c) K S0), A[t, s] = beta_t
+    exp(c_t - c_s) (k_t . k_s) for s < t, a unit lower triangular system
+    solved by forward substitution (its inverse row by row, every block at
+    once: it does not hang on the state); then o_t = exp(c_t) S0^T q_t +
+    sum_{s <= t} exp(c_t - c_s) (k_s . q_t) u_s, and between blocks the
+    state. Returns (o [B, T, H, dv], S_T)."""
+    B, T, H, dk = q.shape
+    hi = lax.Precision.HIGHEST
+    if T == 1:
+        k1, q1 = k[:, 0], q[:, 0]
+        Sp = jnp.exp(g[:, 0])[..., None, None] * S0
+        u = beta[:, 0, :, None] * (v[:, 0] - (Sp * k1[..., None]).sum(2))
+        S1 = Sp + k1[..., None] * u[:, :, None, :]
+        return (S1 * q1[..., None]).sum(2)[:, None], S1
+    C = min(cfg.delta_chunk, T)
+    pad = -T % C
+    if pad:
+        # g = 0 and beta = 0 there: the state passes through, the outputs
+        # are cut off
+        q, k, v, g, beta = (jnp.pad(x, [(0, 0), (0, pad)]
+                                    + [(0, 0)] * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+    nC = (T + pad) // C
+
+    def blocks(x):                  # [B, nC*C, H, ...] -> [B, nC, H, C, ...]
+        return jnp.moveaxis(x.reshape(B, nC, C, *x.shape[2:]), 3, 2)
+
+    q, k, v, g, beta = (blocks(x) for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=-1)                            # [B, nC, H, C]
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    # decay from position s to position t >= s of the block
+    seg = jnp.exp(jnp.where(tri, cum[..., :, None] - cum[..., None, :],
+                            -jnp.inf))                      # [.., t, s]
+    kk = jnp.einsum("bnhtd,bnhsd->bnhts", k, k, precision=hi)
+    A = jnp.where(jnp.tril(tri, -1), beta[..., None] * kk * seg, 0.0)
+
+    def solve_row(t, inv):
+        # row t of (I + A)^-1: e_t - sum_{s < t} A[t, s] inv[s], the rows
+        # before it final, the rows from it on still the identity's
+        a_t = lax.dynamic_index_in_dim(A, t, 3, keepdims=False)
+        row = lax.dynamic_index_in_dim(inv, t, 3, keepdims=False) \
+            - jnp.einsum("bnhs,bnhsj->bnhj", a_t, inv, precision=hi)
+        return lax.dynamic_update_index_in_dim(inv, row, t, 3)
+
+    inv = lax.fori_loop(1, C, solve_row, jnp.broadcast_to(
+        jnp.eye(C, dtype=jnp.float32), A.shape))
+    W = jnp.einsum("bnhts,bnhsd->bnhtd", inv,
+                   (beta * jnp.exp(cum))[..., None] * k, precision=hi)
+    Vt = jnp.einsum("bnhts,bnhsd->bnhtd", inv, beta[..., None] * v,
+                    precision=hi)
+    qk = jnp.einsum("bnhtd,bnhsd->bnhts", q, k, precision=hi) * seg
+
+    def block(S, xs):
+        qb, kb, Wb, Vb, qkb, cb = xs
+        U = Vb - jnp.einsum("bhtd,bhdv->bhtv", Wb, S, precision=hi)
+        o = jnp.exp(cb)[..., None] * jnp.einsum(
+            "bhtd,bhdv->bhtv", qb, S, precision=hi) \
+            + jnp.einsum("bhts,bhsv->bhtv", qkb, U, precision=hi)
+        rest = jnp.exp(cb[..., -1:] - cb)                   # [B, H, C]
+        S = jnp.exp(cb[..., -1])[..., None, None] * S + jnp.einsum(
+            "bhs,bhsd,bhsv->bhdv", rest, kb, U, precision=hi)
+        return S, o
+
+    S, os_ = lax.scan(block, S0, tuple(jnp.moveaxis(x, 1, 0) for x in
+                                       (q, k, W, Vt, qk, cum)))
+    # [nC, B, H, C, dv] -> [B, nC*C, H, dv]
+    o = jnp.transpose(os_, (1, 0, 3, 2, 4)).reshape(B, nC * C, H, -1)
+    return o[:, :T], S
+
+
+def _delta_mixer(cfg: ModelConfig, dp, u, ssm, conv, row, n_valid):
+    """Gated delta-rule mixer of one layer (olmo_hybrid's linear attention;
+    Gated DeltaNet). [q, k, v] = silu(causal_conv(u W_qkv)) (K taps, no
+    bias); a head: q = q / |q| / sqrt(dk), k = k / |k| (L2 over dk, eps
+    1e-6 under the root); beta = sigmoid(u W_b), doubled where
+    cfg.delta_neg_eigval; g = -exp(A_log) softplus(u W_a + dt_bias); the
+    state by ``_delta_rule``; y = RMSNorm_dv(o; w) * silu(u W_z) a head;
+    out = y W_o. u [B, T, D] (normed); ssm [Ld, B, H, dk, dv] and conv [Ld,
+    B, K-1, C] float32, of which this layer reads and writes row ``row``;
+    n_valid [B]: positions >= n_valid[b] change neither. Returns (out [B,
+    T, D], ssm, conv)."""
+    B, T, _ = u.shape
+    H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    f32 = jnp.float32
+    valid = (jnp.arange(T)[None, :] < n_valid[:, None])[..., None]
+    with device_scope("delta.in_proj"):
+        qkv = _mm(cfg, u, dp["delta_qkv"])
+        ab = _mm(cfg, u, dp["delta_ab"]).astype(f32)
+        z = _mm(cfg, u, dp["delta_z"])
+    with device_scope("delta.conv"):
+        qkv, conv = _causal_conv(conv, row, qkv, dp["delta_conv_w"], n_valid,
+                                 act=jax.nn.silu)
+    with device_scope("delta.update"):
+        q = qkv[..., :H * dk].reshape(B, T, H, dk)
+        k = qkv[..., H * dk:2 * H * dk].reshape(B, T, H, dk)
+        v = qkv[..., 2 * H * dk:].reshape(B, T, H, dv)
+        q = q * lax.rsqrt((q * q).sum(-1, keepdims=True) + 1e-6) * dk ** -0.5
+        k = k * lax.rsqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+        beta = jax.nn.sigmoid(ab[..., H:]) * (2.0 if cfg.delta_neg_eigval
+                                              else 1.0)
+        g = -jnp.exp(dp["delta_a_log"].astype(f32)) * jax.nn.softplus(
+            ab[..., :H] + dp["delta_dt_bias"].astype(f32))
+        S0 = lax.dynamic_index_in_dim(ssm, row, 0, keepdims=False)
+        o, S1 = _delta_rule(cfg, S0, q, k, v, jnp.where(valid, g, 0.0),
+                            jnp.where(valid, beta, 0.0))
+        # g = 0 and beta = 0 already leave S where it was up to rounding;
+        # a row with nothing real keeps its very bits
+        S1 = jnp.where((n_valid > 0)[:, None, None, None], S1, S0)
+        ssm = lax.dynamic_update_index_in_dim(ssm, S1, row, 0)
+    with device_scope("delta.gate_norm"):
+        o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * dp["delta_norm_w"].astype(f32)
+        y = o * jax.nn.silu(z.astype(f32).reshape(B, T, H, dv))
+    with device_scope("delta.out"):
+        out = _mm(cfg, y.reshape(B, T, H * dv).astype(u.dtype),
+                  dp["delta_out"])
+    return out, ssm, conv
+
+
 def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
                    n_valid, attend, attend_win=None, live=None, load=None):
     """The layer scans of a hybrid stack. ``attend(ap, h, kc, vc, row) ->
@@ -1182,10 +1351,24 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
     the ``live`` rows (``_residual_counting``). Returns (x, kc, vc, state,
     load)."""
     layers = params["layers"]
+    # A delta stack carries its residual stream, and what its delta mixers
+    # and MLPs hand on between their matmuls, float32: bfloat16 goes into the
+    # MXU (``_mm``) and into attention only. Its sublayers have no router's
+    # mean and no multiplier under 1 before the residual add: at the
+    # published widths each passes a perturbation of its input on LARGER
+    # (seeded weights of 0.02 give the MLP a gain of 0.02 sqrt(3840) x 0.02
+    # sqrt(11008) = 2.6), so with every handed-on value rounded to bfloat16
+    # the logits twelve layers on lay 2.6-3.6% of the largest from a float32
+    # reference's (the other stacks: 1.0-2.2%); unrounded, 1.6-2.6% (my chip
+    # runs, PR 44, 8 and 20 readings)
+    act_dtype = x.dtype
+    if cfg.n_delta_layers:
+        x = x.astype(jnp.float32)
     # the other mixer's leaves, by their names' prefix; window layers have
     # none of their own
     prefix = ("ssm_" if cfg.n_ssm_layers else
-              "conv_" if cfg.n_conv_layers else None)
+              "conv_" if cfg.n_conv_layers else
+              "delta_" if cfg.n_delta_layers else None)
     attn_stack = {k: v for k, v in layers.items() if k in _ATTN_STACK}
     rec_stack = {k: v for k, v in layers.items()
                  if prefix and k.startswith(prefix)}
@@ -1204,8 +1387,9 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
 
         def attn_mixer(h, kc, vc, ssm, conv, win):
-            out, kc, vc = attend(take(attn_stack, wrow), h, kc, vc, row)
-            return out, kc, vc, ssm, conv, win
+            out, kc, vc = attend(take(attn_stack, wrow), h.astype(act_dtype),
+                                 kc, vc, row)
+            return out.astype(h.dtype), kc, vc, ssm, conv, win
 
         def other_mixer(h, kc, vc, ssm, conv, win):
             if win is not None:
@@ -1214,6 +1398,9 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
             rp = take(rec_stack, row)
             if ssm is None:
                 out, conv = _conv_mixer(cfg, rp, h, conv, row, n_valid)
+            elif cfg.n_delta_layers:
+                out, ssm, conv = _delta_mixer(cfg, rp, h, ssm, conv, row,
+                                              n_valid)
             else:
                 out, ssm, conv = _ssm_mixer(cfg, rp, h, ssm, conv, row,
                                             n_valid)

@@ -182,9 +182,20 @@ def per_token_flops(cfg: ModelConfig) -> float:
         # a gated short convolution: in-projection to 3 d, two gating
         # products, K taps, out-projection
         conv = 2 * d * 3 * d + 2 * d * d + 2 * (cfg.conv_kernel + 2) * d
+        # a gated delta-rule mixer: q, k, v, the output gate and the two
+        # per-head gates in, the out-projection, the convolution's K taps,
+        # and the state's decay, rank-one correction and read-out (S' = a
+        # S, S'^T k, S' + k u^T, S^T q: about 7 operations an element of
+        # [H, dk, dv], which a decode step also reads and writes once:
+        # cfg.ssm_state_bytes each way a slot)
+        hv = cfg.delta_heads * cfg.delta_value_dim
+        delta = (2 * d * (cfg.delta_conv_dim + hv + 2 * cfg.delta_heads)
+                 + 2 * hv * d + 2 * cfg.delta_conv * cfg.delta_conv_dim
+                 + 7 * cfg.delta_key_dim * hv)
         dense = 6 * d * cfg.dense_ffn_dim
         return float(cfg.n_attn_layers * proj + cfg.n_ssm_layers * ssm
                      + cfg.n_conv_layers * conv
+                     + cfg.n_delta_layers * delta
                      + cfg.n_dense_layers * dense
                      + (L - cfg.n_dense_layers) * mlp + head)
     return float(L * (proj + mlp) + head)

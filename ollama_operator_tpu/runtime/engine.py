@@ -23,6 +23,7 @@ server loop that the reference delegates to the ollama image
 from __future__ import annotations
 
 import dataclasses
+import gc
 import math
 import os
 import sys
@@ -167,17 +168,21 @@ def resolve_serving_defaults(ecfg: "EngineConfig", cfg: ModelConfig,
 
 def _recurrent_slots(cfg: ModelConfig) -> int:
     """Slots of a hybrid stack's contiguous cache on the TPU, from the
-    model alone. Its decode step is bound by the weights of the experts it
-    holds, so the batch is what fills them: the smallest power of two, 64
-    at most, that gives every expert of the router four tokens a step (8
-    slots x 10 picks over granite's 72 is 1.1 a step: a latency test, not
-    a serving batch), while what a slot carries beside its full-length
-    keys and values stays under an eighth of a v5e chip's 16 GB: the
-    recurrent state (granite's 38.7 MB a slot against 8 MB of int8 keys
-    and values at 4096 positions; a short-convolution stack's 196 KB) and
-    the window layers' rings (K-EXAONE's 1.6 MB). The full-length rows are
-    not counted: they grow with the context the server is started at."""
-    want = 4 * cfg.n_experts / cfg.n_experts_used if cfg.n_experts else 8
+    model alone. With experts, its decode step is bound by the weights of
+    the experts it holds, so the batch is what fills them: the smallest
+    power of two, 64 at most, that gives every expert of the router four
+    tokens a step (8 slots x 10 picks over granite's 72 is 1.1 a step: a
+    latency test, not a serving batch). Without experts a step reads every
+    weight whatever the batch, and the stack wants the 32 slots the paged
+    default gives an MHA model (``resolve_serving_defaults``: "MHA keeps
+    32"). Either way what a slot carries beside its full-length keys and
+    values stays under an eighth of a v5e chip's 16 GB: the recurrent
+    state (granite's 38.7 MB a slot against 8 MB of int8 keys and values
+    at 4096 positions; a delta stack's 21.2 MB over nine layers; a
+    short-convolution stack's 196 KB) and the window layers' rings
+    (K-EXAONE's 1.6 MB). The full-length rows are not counted: they grow
+    with the context the server is started at."""
+    want = 4 * cfg.n_experts / cfg.n_experts_used if cfg.n_experts else 32
     slots = 8
     while slots < min(want, 64):
         slots *= 2
@@ -247,13 +252,19 @@ def resolve_engine_dtype(cfg: ModelConfig, backend: str) -> str:
     that by doubling streamed bytes), and 7B+ needs int4 to leave HBM room
     for the KV pool (mistral-7B int4 = the r4 flagship; bf16 7B does not
     fit at all). MoE expert stacks serve dense bf16 (quantized expert
-    matmuls are an unmeasured path). CPU serves f32 — XLA's CPU thunk
+    matmuls are an unmeasured path), and so does every hybrid stack
+    (``cfg.layer_kinds``), with experts or without: quantized matmuls of a
+    recurrent mixer are as unmeasured, ``ops/quant.QUANT_LAYER_KEYS`` names
+    none of a mixer's leaves (a "quantized" hybrid model would be half
+    bfloat16 by accident), and a hybrid stack of 4e9 parameters would
+    otherwise land on int4, whose warm plan has never compiled on the chip
+    (ROADMAP R1). CPU serves f32 — XLA's CPU thunk
     runtime has no bf16 dots and the quantized matmuls are pallas/TPU
     paths. An explicit spec/env/flag always wins (callers only consult
     this when theirs is unset)."""
     if backend != "tpu":
         return "float32"
-    if cfg.n_experts:
+    if cfg.n_experts or cfg.layer_kinds:
         return "bfloat16"
     return "int4" if cfg.n_params >= 4e9 else "int8"
 
@@ -547,6 +558,14 @@ class Engine:
                     f"no sharding of its state is defined; got a mesh of "
                     f"{dict(mesh.shape)}")
 
+        # an engine's compiled closures refer back to it, so an engine
+        # nobody holds any more (a model just unloaded, the probe's first
+        # engine) keeps its caches on the device until the cycle collector
+        # runs: run it before asking for this one's (``LoadedModel`` does
+        # so on every backend; here it costs 0.1 s an engine, and host
+        # memory needs no such help)
+        if jax.default_backend() != "cpu":
+            gc.collect()
         cache_dtype = resolve_cache_dtype(ecfg.cache_dtype)
         if cache_dtype is not ecfg.cache_dtype:
             ecfg = dataclasses.replace(ecfg, cache_dtype=cache_dtype)

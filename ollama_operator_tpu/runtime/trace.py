@@ -329,7 +329,9 @@ DEVICE_SCOPES = ("embed", "attn.qkv", "attn.kv_write", "attn.core",
                  "attn.window", "attn.out", "mlp", "moe.route",
                  "moe.experts", "ssm.in_proj",
                  "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out",
-                 "conv.in_proj", "conv.conv", "conv.out", "lm_head", "sample")
+                 "conv.in_proj", "conv.conv", "conv.out", "delta.in_proj",
+                 "delta.conv", "delta.update", "delta.gate_norm", "delta.out",
+                 "lm_head", "sample")
 
 # Request stages folded into tpu_model_request_stage_seconds{stage=...}
 STAGES = ("ingress", "queue", "prefill", "first_flush", "decode")

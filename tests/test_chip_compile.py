@@ -414,3 +414,34 @@ def test_flash_prefill_compiles_inside_manual_shard_map(topo):
         return chunk_attention(CFG, q, k, v, None, SCALE, mesh=mesh)
 
     assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv)
+
+
+@pytest.mark.parametrize("B,T", [(32, 1), (4, 256)],
+                         ids=["decode-32-slots", "admit_many-4x256"])
+def test_delta_mixer_compiles_at_published_widths(one_chip, B, T):
+    """The gated delta-rule mixer of one layer as the served programs run it
+    (state and convolution inputs donated), at Olmo-Hybrid-7B's widths: the
+    decode step (the recurrence as written) updates the nine layers' state
+    in place, its temporaries far below one layer's state, and the blocked
+    form of a batched admission compiles with its triangular solve."""
+    cfg = PRESETS["olmo-hybrid-7b"]
+    Ld = cfg.n_delta_layers
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    layers = jax.eval_shape(lambda k: decoder.init_params(cfg, k),
+                            jax.random.key(0))["layers"]
+    dp = {k: sds(v.shape[1:], v.dtype) for k, v in layers.items()
+          if k.startswith("delta_")}
+    ssm = sds((Ld, B, cfg.delta_heads, cfg.delta_key_dim,
+               cfg.delta_value_dim), jnp.float32)
+    conv = sds((Ld, B, cfg.delta_conv - 1, cfg.delta_conv_dim), jnp.float32)
+    compiled = jax.jit(
+        lambda dp, u, ssm, conv, row, nv: decoder._delta_mixer(
+            cfg, dp, u, ssm, conv, row, nv), donate_argnums=(2, 3)).lower(
+        dp, sds((B, T, cfg.dim), jnp.bfloat16), ssm, conv,
+        sds((), jnp.int32), sds((B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    one_layer = ssm.size * 4 // Ld
+    assert mem.alias_size_in_bytes >= ssm.size * 4      # both leaves in place
+    if T == 1:
+        assert mem.temp_size_in_bytes < one_layer // 16

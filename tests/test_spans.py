@@ -26,7 +26,7 @@ from test_scheduler import GREEDY, make_stack
 SPAN_NAMES = [row[0] for row in SPAN_TABLE]
 # the scopes a dense (no MoE) model's step must carry, on every path
 DENSE_SCOPES = [s for s in DEVICE_SCOPES
-                if not s.startswith(("moe.", "ssm.", "conv."))
+                if not s.startswith(("moe.", "ssm.", "conv.", "delta."))
                 and s != "attn.window"]
 
 
@@ -103,11 +103,12 @@ def test_stage_buckets_step_by_at_most_a_quarter_from_1ms_to_60s():
 
 
 def test_the_benchmark_reads_the_programs_vocabulary():
-    from benchmark import conv_spans, ssm_spans, trace_spans, window_spans
+    from benchmark import (conv_spans, delta_spans, ssm_spans, trace_spans,
+                           window_spans)
     # the accepted reader knows the scopes the dense cells carry; each
     # hybrid stack's mixer is read by the reader that came with it
     lists = (trace_spans.SCOPES, ssm_spans.SCOPES, conv_spans.SCOPES,
-             window_spans.SCOPES)
+             window_spans.SCOPES, delta_spans.SCOPES)
     assert set().union(*lists) == set(DEVICE_SCOPES)
     assert sum(map(len, lists)) == len(DEVICE_SCOPES)     # no scope twice
     assert all(n.startswith(trace_spans.SPAN_PREFIXES) for n in SPANS)
@@ -855,9 +856,10 @@ def test_the_benchmark_lists_the_new_readers_where_they_read():
         dense_only = name in NEW_READERS[:3]
         assert by[name].get("workloads") == (paged if dense_only else None)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-8:-1] == list(NEW_READERS)
-    # the fence's reader came after them, for the paged cells alone
-    assert names[-1] == "evict_free_share"
+    # the fence's reader came after them, for the paged cells alone (later
+    # PRs' metrics follow it: entries are only ever appended)
+    at = names.index("evict_free_share")
+    assert names[at - 7:at] == list(NEW_READERS)
     assert by["evict_free_share"] == dict(
         name="evict_free_share", unit="%", better="higher",
         source="program_counter", layer="admission", moves="out_tok_s",
