@@ -909,8 +909,9 @@ def join_state(k_cache, v_cache, state):
 def empty_state(cfg: ModelConfig, B: int, kv_dtype=jnp.float32):
     """What a sequence carries before its first position, zeros: (ssm [Lm,
     B, H, P, N], conv [Lm, B, K-1, C], None) float32; (ssm [Ld, B, H, dk,
-    dv], conv [Ld, B, K-1, 2 H dk + H dv], None) for a stack of delta
-    layers; (None, conv [Lc, B, K-1, D], None) for a stack of short
+    dv] or, where ``_delta_pack`` says 2, [Ld, B, H/2, dk, 2 dv], conv [Ld,
+    B, K-1, 2 H dk + H dv], None) for a stack of delta layers; (None, conv
+    [Lc, B, K-1, D], None) for a stack of short
     convolutions; (None, None, (k, v)) for a stack of window layers, rings
     [Lw, B, KvH, W, hd] of ``kv_dtype`` (int8: codes and float32 scales, as
     the cache keeps its rows)."""
@@ -928,9 +929,9 @@ def empty_state(cfg: ModelConfig, B: int, kv_dtype=jnp.float32):
         return None, jnp.zeros((cfg.n_conv_layers, B, cfg.conv_kernel - 1,
                                 cfg.dim), jnp.float32), None
     if cfg.n_delta_layers:
-        Ld = cfg.n_delta_layers
-        return (jnp.zeros((Ld, B, cfg.delta_heads, cfg.delta_key_dim,
-                           cfg.delta_value_dim), jnp.float32),
+        Ld, P = cfg.n_delta_layers, _delta_pack(cfg)
+        return (jnp.zeros((Ld, B, cfg.delta_heads // P, cfg.delta_key_dim,
+                           P * cfg.delta_value_dim), jnp.float32),
                 jnp.zeros((Ld, B, cfg.delta_conv - 1, cfg.delta_conv_dim),
                           jnp.float32), None)
     Lm = cfg.n_ssm_layers
@@ -1207,6 +1208,39 @@ def _ssm_mixer(cfg: ModelConfig, sp, u, ssm, conv, row, n_valid):
     return out, ssm, conv
 
 
+def _delta_pack(cfg: ModelConfig) -> int:
+    """How many heads of a delta layer's state lie side by side along the
+    last axis of the ``ssm`` leaf, [Ld, B, H / P, dk, P dv]: 2 where a head's
+    row of dv values would lie padded in 128-lane tiles and two heads' rows
+    fill whole ones, else 1. The published head (dv 192) takes 256 lanes
+    alone and shares 384 with its neighbour: the decode step's pass over
+    1.35 GB of state moved 1.80 GB, 2.64 ms for 1.99 (my chip run, PR 45).
+    Only ``empty_state``, ``_delta_mixer`` and the kernel know: everything
+    else sees a leaf by its first two axes and its bytes."""
+    dv = cfg.delta_value_dim
+    return 2 if (dv % 128 and not 2 * dv % 128
+                 and cfg.delta_heads % 2 == 0) else 1
+
+
+def _delta_packed(S, P: int):
+    """[B, H, dk, dv] -> [B, H / P, dk, P dv]: head P g + p into lanes [p dv,
+    (p + 1) dv) of group g."""
+    if P == 1:
+        return S
+    B, H, dk, dv = S.shape
+    return S.reshape(B, H // P, P, dk, dv).transpose(0, 1, 3, 2, 4).reshape(
+        B, H // P, dk, P * dv)
+
+
+def _delta_unpacked(S, P: int):
+    """``_delta_packed``'s inverse."""
+    if P == 1:
+        return S
+    B, G, dk, W = S.shape
+    return S.reshape(B, G, dk, P, W // P).transpose(0, 1, 3, 2, 4).reshape(
+        B, G * P, dk, W // P)
+
+
 def _delta_rule(cfg: ModelConfig, S0, q, k, v, g, beta):
     """The gated delta rule over T positions from state S0, exact:
     S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t);
@@ -1214,11 +1248,14 @@ def _delta_rule(cfg: ModelConfig, S0, q, k, v, g, beta):
 
     S0 [B, H, dk, dv]; q, k [B, T, H, dk]; v [B, T, H, dv]; g <= 0 and beta
     [B, T, H], both 0 where a position is not real: then S_t = S_{t-1}. All
-    float32. One position is the recurrence as written, on the vector unit
-    (the compiler reads the state three times and writes it once: 4.9 ms a
-    step of nine layers at 32 slots, where both read-outs from S0 in one
-    MXU pass took 3.8 and the bytes alone would take 1.6: my chip run, PR
-    44). More go block by block (cfg.delta_chunk,
+    float32. One position is the recurrence as written, on the vector unit:
+    the compiler's form, four passes that read the state three times and
+    write it once. The decode step takes it only where ``_delta_step``'s
+    kernel, which passes over the state once, does not engage (4.87 ms a
+    step of nine layers at 32 slots for the kernel's 1.99, where both
+    read-outs from S0 in one MXU pass took 3.88 and the bytes alone would
+    take 1.65: hack/delta_microbench.py, my chip run, PR 45). More go block
+    by block (cfg.delta_chunk,
     Gated DeltaNet's form): with c the running sum of g inside a block, the
     block's u solve (I + A) U = beta (V - exp(c) K S0), A[t, s] = beta_t
     exp(c_t - c_s) (k_t . k_s) for s < t, a unit lower triangular system
@@ -1290,6 +1327,30 @@ def _delta_rule(cfg: ModelConfig, S0, q, k, v, g, beta):
     return o[:, :T], S
 
 
+def _delta_step(cfg: ModelConfig, ssm, row, q, k, v, g, beta, n_valid):
+    """One position of the gated delta rule on row ``row`` of the carried
+    leaf ``ssm`` (``empty_state``'s, in either layout), through the kernel
+    that passes over the state once (ops/pallas/delta.py), where
+    ``cfg.kernels`` resolves to one and the heads tile; q, k, v, g, beta as
+    ``_delta_rule`` takes them with T = 1, n_valid [B]. Returns (o [B, 1, H,
+    dv], ssm) or None: the caller then takes ``_delta_rule``'s four passes."""
+    from ..ops.attention import resolve_kernels
+    mode = resolve_kernels(cfg.kernels)
+    if mode not in ("pallas", "interpret"):
+        note_kernel("delta.update", "xla_recurrence")
+        return None
+    from ..ops.pallas.delta import delta_update
+    out = delta_update(ssm, row, q[:, 0], k[:, 0], v[:, 0],
+                       jnp.exp(g[:, 0]), beta[:, 0], n_valid,
+                       interpret=mode == "interpret")
+    if out is None:
+        note_kernel("delta.update", "xla_recurrence", fell_back=True)
+        return None
+    note_kernel("delta.update", "delta_update")
+    o, ssm = out
+    return o[:, None], ssm
+
+
 def _delta_mixer(cfg: ModelConfig, dp, u, ssm, conv, row, n_valid):
     """Gated delta-rule mixer of one layer (olmo_hybrid's linear attention;
     Gated DeltaNet). [q, k, v] = silu(causal_conv(u W_qkv)) (K taps, no
@@ -1297,8 +1358,9 @@ def _delta_mixer(cfg: ModelConfig, dp, u, ssm, conv, row, n_valid):
     1e-6 under the root); beta = sigmoid(u W_b), doubled where
     cfg.delta_neg_eigval; g = -exp(A_log) softplus(u W_a + dt_bias); the
     state by ``_delta_rule``; y = RMSNorm_dv(o; w) * silu(u W_z) a head;
-    out = y W_o. u [B, T, D] (normed); ssm [Ld, B, H, dk, dv] and conv [Ld,
-    B, K-1, C] float32, of which this layer reads and writes row ``row``;
+    out = y W_o. u [B, T, D] (normed); ssm (``empty_state``'s: [Ld, B, H /
+    P, dk, P dv], P ``_delta_pack``'s) and conv [Ld, B, K-1, C] float32, of
+    which this layer reads and writes row ``row``;
     n_valid [B]: positions >= n_valid[b] change neither. Returns (out [B,
     T, D], ssm, conv)."""
     B, T, _ = u.shape
@@ -1322,13 +1384,22 @@ def _delta_mixer(cfg: ModelConfig, dp, u, ssm, conv, row, n_valid):
                                               else 1.0)
         g = -jnp.exp(dp["delta_a_log"].astype(f32)) * jax.nn.softplus(
             ab[..., :H] + dp["delta_dt_bias"].astype(f32))
-        S0 = lax.dynamic_index_in_dim(ssm, row, 0, keepdims=False)
-        o, S1 = _delta_rule(cfg, S0, q, k, v, jnp.where(valid, g, 0.0),
-                            jnp.where(valid, beta, 0.0))
-        # g = 0 and beta = 0 already leave S where it was up to rounding;
-        # a row with nothing real keeps its very bits
-        S1 = jnp.where((n_valid > 0)[:, None, None, None], S1, S0)
-        ssm = lax.dynamic_update_index_in_dim(ssm, S1, row, 0)
+        g, beta = jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+        step = _delta_step(cfg, ssm, row, q, k, v, g, beta, n_valid) \
+            if T == 1 else None
+        if step is not None:
+            o, ssm = step
+        else:
+            # the rule takes a head's matrix by itself: the leaf's layout
+            # ends here
+            P = _delta_pack(cfg)
+            S0 = lax.dynamic_index_in_dim(ssm, row, 0, keepdims=False)
+            o, S1 = _delta_rule(cfg, _delta_unpacked(S0, P), q, k, v, g, beta)
+            # g = 0 and beta = 0 already leave S where it was up to
+            # rounding; a row with nothing real keeps its very bits
+            S1 = jnp.where((n_valid > 0)[:, None, None, None],
+                           _delta_packed(S1, P), S0)
+            ssm = lax.dynamic_update_index_in_dim(ssm, S1, row, 0)
     with device_scope("delta.gate_norm"):
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                           + cfg.norm_eps) * dp["delta_norm_w"].astype(f32)

@@ -421,10 +421,11 @@ def test_flash_prefill_compiles_inside_manual_shard_map(topo):
 def test_delta_mixer_compiles_at_published_widths(one_chip, B, T):
     """The gated delta-rule mixer of one layer as the served programs run it
     (state and convolution inputs donated), at Olmo-Hybrid-7B's widths: the
-    decode step (the recurrence as written) updates the nine layers' state
-    in place, its temporaries far below one layer's state, and the blocked
-    form of a batched admission compiles with its triangular solve."""
-    cfg = PRESETS["olmo-hybrid-7b"]
+    decode step holds the kernel that passes over the state once (PR 45) and
+    updates the nine layers' state in place, its temporaries far below one
+    layer's state; the blocked form of a batched admission compiles with its
+    triangular solve and no kernel."""
+    cfg = dataclasses.replace(PRESETS["olmo-hybrid-7b"], kernels="pallas")
     Ld = cfg.n_delta_layers
     sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
         shape, dt, sharding=one_chip)
@@ -432,9 +433,9 @@ def test_delta_mixer_compiles_at_published_widths(one_chip, B, T):
                             jax.random.key(0))["layers"]
     dp = {k: sds(v.shape[1:], v.dtype) for k, v in layers.items()
           if k.startswith("delta_")}
-    ssm = sds((Ld, B, cfg.delta_heads, cfg.delta_key_dim,
-               cfg.delta_value_dim), jnp.float32)
-    conv = sds((Ld, B, cfg.delta_conv - 1, cfg.delta_conv_dim), jnp.float32)
+    ssm, conv, _ = (x and sds(x.shape, x.dtype) for x in jax.eval_shape(
+        lambda: decoder.empty_state(cfg, B)))
+    assert ssm.shape == (Ld, B, 15, 96, 384)    # two heads a row: no padding
     compiled = jax.jit(
         lambda dp, u, ssm, conv, row, nv: decoder._delta_mixer(
             cfg, dp, u, ssm, conv, row, nv), donate_argnums=(2, 3)).lower(
@@ -443,5 +444,6 @@ def test_delta_mixer_compiles_at_published_widths(one_chip, B, T):
     mem = compiled.memory_analysis()
     one_layer = ssm.size * 4 // Ld
     assert mem.alias_size_in_bytes >= ssm.size * 4      # both leaves in place
+    assert ("delta_update" in compiled.as_text()) == (T == 1)
     if T == 1:
         assert mem.temp_size_in_bytes < one_layer // 16

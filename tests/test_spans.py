@@ -960,6 +960,61 @@ def test_lowered_program_carries_every_scope(lowered, path, program):
     assert dotted <= set(DEVICE_SCOPES)
 
 
+def test_every_kernel_site_is_named_from_the_vocabulary():
+    """Each ``pl.pallas_call`` under ops/pallas/ passes ``name=``, so a trace
+    shows the kernel under a name that outlives a refactor, and the names
+    are ``KERNEL_NAMES``: a literal at the site, or (the fused matmul's one
+    site) a parameter whose callers pass literals of the list."""
+    import ast
+    import glob
+    import os
+
+    from ollama_operator_tpu.ops import pallas
+    from ollama_operator_tpu.runtime.trace import KERNEL_NAMES
+    sites, named = 0, set()
+    for path in sorted(glob.glob(os.path.join(
+            os.path.dirname(pallas.__file__), "*.py"))):
+        tree = ast.parse(open(path).read())
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call)
+                    and ast.unparse(node.func) == "pl.pallas_call"):
+                continue
+            sites += 1
+            name = next(kw.value for kw in node.keywords if kw.arg == "name")
+            if isinstance(name, ast.Constant):
+                named.add(name.value)
+                continue
+            # a parameter of the enclosing function: its callers' literals
+            assert isinstance(name, ast.Name), path
+            named |= {c.args[0].value for c in ast.walk(tree)
+                      if isinstance(c, ast.Call) and c.args
+                      and isinstance(c.args[0], ast.Constant)
+                      and ast.unparse(c.func) == "_fused"}
+    assert sites == 6
+    assert named == set(KERNEL_NAMES)
+    assert len(set(KERNEL_NAMES)) == len(KERNEL_NAMES)
+
+
+def test_the_delta_kernel_runs_under_the_scope_its_metric_reads():
+    """``benchmark/delta_spans.py`` reads ``delta.update``: the decode
+    program's kernel call lies under that scope, by its name."""
+    import dataclasses
+
+    from ollama_operator_tpu.models import decoder
+    cfg = dataclasses.replace(cfglib.PRESETS["tiny-olmo-hybrid"],
+                              kernels="interpret")
+    ssm, conv, _ = decoder.empty_state(cfg, 2)
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jax.numpy.float32)
+    dp = {k: v[0] for k, v in params["layers"].items()
+          if k.startswith("delta_")}
+    u = jax.numpy.ones((2, 1, cfg.dim))
+    text = jax.jit(lambda *a: decoder._delta_mixer(cfg, *a)).lower(
+        dp, u, ssm, conv, jax.numpy.int32(1),
+        jax.numpy.ones((2,), jax.numpy.int32)).as_text(debug_info=True)
+    assert re.search(r'delta\.update/[^"]*delta_update', text)
+
+
 def test_moe_scopes_nest_under_mlp():
     import dataclasses
 
