@@ -5,14 +5,20 @@ reference's ``forward_chosen`` / ``forward_rounded``, from the tree given as
 argv[1], and prints each one's ``memory_analysis()``:
 
     JAX_PLATFORMS=cpu python hack/compile_cell.py /root/repo lfm2-8b-a1b \
-        [decode] [admit] [admit_many] [extend] [ref] [probe]
+        [decode] [admit] [admit_many] [extend] [ref] [probe] [plan]
 
 What the TPU compiler refuses it raises here, at no chip time. It counts one
 program at a time, not what else the process keeps on the device (the
 probe's two engines hold a cache each). ``probe`` (a contiguous cache only)
 is the logits program of ``server_child.probe``: it does not donate the
 cache, so its temporaries hold a copy of every cache leaf a decode step
-writes, beside the probe engine's own. ~4 min for a 16-layer routed model."""
+writes, beside the probe engine's own. ``plan`` compiles EVERY program of the
+warm plan (each attended and prefill bucket: 56 for a contiguous cell) and
+goes on past one the compiler refuses, printing ``FAIL``: one shape of 56 can
+fail alone (``glm-5``'s ``decode.(32, 1024)`` did, in VMEM, PR 46), and the
+chip's warm plan would find it 15 minutes into a run. Several at once:
+``... plan:decode``, ``plan:admit``, ``plan:admit_many``, ``plan:extend``.
+~4 min for a 16-layer routed model."""
 import os
 import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -34,7 +40,13 @@ def sds(a):
 GB = 1e9
 def spy(self, kind, key, jit_fn, *args):
     args = jax.tree_util.tree_map(sds, args)
-    c = jit_fn.lower(*args).compile()
+    try:
+        c = jit_fn.lower(*args).compile()
+    except Exception as e:      # noqa: BLE001 — the plan goes on past it
+        if not any(w.startswith("plan") for w in sys.argv[3:]):
+            raise
+        print(f"FAIL {kind}.{key}: {str(e)[:400]!r}", flush=True)
+        return None
     m = c.memory_analysis()
     print(f"{kind}.{key}: args {m.argument_size_in_bytes/GB:.3f} out {m.output_size_in_bytes/GB:.3f} "
           f"alias {m.alias_size_in_bytes/GB:.3f} temp {m.temp_size_in_bytes/GB:.3f} "
@@ -56,6 +68,25 @@ print("weights_program temp", m.temp_size_in_bytes/GB, "out",
       m.output_size_in_bytes/GB, flush=True)
 eng = E.Engine(cfg, params, mesh=None, ecfg=ecfg)
 what = sys.argv[3:] or ["decode", "admit", "admit_many", "extend", "ref"]
+plan = {w.partition(":")[2] or "all" for w in what if w.startswith("plan")}
+if plan:
+    every = eng._buckets
+    for kind in ("decode", "admit", "admit_many", "extend"):
+        if not plan & {"all", kind}:
+            continue
+        for b in every:
+            if kind == "decode":
+                eng._decode_n_exec(ecfg.decode_chunk, b)
+                eng._decode_n_exec(1, b)
+            elif kind == "admit":
+                eng._admit_exec(b)
+            elif kind == "admit_many":
+                eng._admit_many_exec(2, b)
+                eng._admit_many_exec(4, b)
+            else:
+                for a in every:
+                    if a > b:
+                        eng._extend_exec(b, a)
 if "decode" in what:
     eng._decode_n_exec(ecfg.decode_chunk, 512)
     eng._decode_n_exec(ecfg.decode_chunk, 4096)

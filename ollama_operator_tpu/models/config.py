@@ -134,7 +134,8 @@ class ModelConfig:
     # hybrid stacks: one letter a layer, "m" a Mamba-2 mixer
     # (granitemoehybrid), "c" a gated short convolution (lfm2), "d" a
     # gated delta-rule linear-attention mixer (olmo_hybrid), "A" attention
-    # over every earlier position, "w" attention over the last
+    # over every earlier position (latent attention over the positions its
+    # indexer keeps where kv_latent_dim, below), "w" attention over the last
     # sliding_window positions (exaone_moe), whose keys and values are a
     # ring of that many positions a slot; "" = every layer attends. A
     # stack has ONE kind beside "A" (no published stack mixes two of "m",
@@ -173,6 +174,28 @@ class ModelConfig:
     delta_conv: int = 4
     delta_neg_eigval: bool = False
     delta_chunk: int = 64
+    # latent attention (glm_moe_dsa, models/decoder.py "latent attention"):
+    # kv_latent_dim > 0 turns the "A" layers of a hybrid stack into it. The
+    # query goes through a normed latent of q_latent_dim to n_heads heads of
+    # [qk_nope_dim | qk_rope_dim]; keys and values of every head are
+    # expanded from ONE normed latent of kv_latent_dim a position, beside
+    # ONE rotated key of qk_rope_dim all heads share; values are v_head_dim
+    # wide. The cache holds that row, kv_latent_dim + qk_rope_dim channels a
+    # position a layer, and nothing a head. Beside it an indexer of
+    # index_heads heads of index_head_dim scores every cached position
+    # against the query (its key, index_head_dim channels a position a
+    # layer, is the cache's second row) and attention reads the index_topk
+    # positions of largest score. rope_interleave: rotated channels pair
+    # (2i, 2i + 1), as the checkpoint stores them, in attention and indexer
+    kv_latent_dim: int = 0             # kv_lora_rank
+    q_latent_dim: int = 0              # q_lora_rank
+    qk_nope_dim: int = 0               # qk_nope_head_dim
+    qk_rope_dim: int = 0               # qk_rope_head_dim
+    v_head_dim: int = 0
+    index_heads: int = 0               # index_n_heads
+    index_head_dim: int = 0
+    index_topk: int = 0
+    rope_interleave: bool = False
     rope: bool = True                  # False = no positional embedding
                                        # (position_embedding_type "nope")
     kernels: str = "auto"              # attention impl: auto|pallas|xla|interpret
@@ -238,6 +261,32 @@ class ModelConfig:
         return self.n_full_layers + self.n_window_layers
 
     @property
+    def cache_row_dims(self) -> Tuple[int, int, int]:
+        """(heads, channels of the keys' row, channels of the values' row)
+        a position of a full layer's cache: a head's keys and values, or
+        latent attention's one row [latent | rotated key] and the indexer's
+        key, which ride where keys and values do."""
+        if self.kv_latent_dim:
+            return (1, self.kv_latent_dim + self.qk_rope_dim
+                    + self.latent_row_pad, self.index_head_dim)
+        return self.n_kv_heads, self.head_dim, self.head_dim
+
+    @property
+    def latent_row_pad(self) -> int:
+        """Zero channels behind a cached latent row's rotated key: where the
+        latent fills whole 128-lane tiles (the published 512) the key's
+        part is rounded up to whole ones too (64 -> 128; the device's tiles
+        hold the row at that width anyway), so that the compiler keeps the
+        cache as it is handed over: at 576 channels it turned the whole
+        cache position-minor inside every decode chunk and copied each
+        layer's attended bucket back, 3.2 ms of a 26.8 ms step (my chip
+        run, PR 46, call 3). Read from the shape; a toy's row is as it
+        is."""
+        if self.kv_latent_dim % 128:
+            return 0
+        return -self.qk_rope_dim % 128
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
@@ -273,10 +322,26 @@ class ModelConfig:
         return rd - rd % 2
 
     @property
+    def attn_params(self) -> int:
+        """Matrix elements of one attention layer's projections (latent
+        attention's: its indexer's with them)."""
+        d = self.dim
+        if not self.kv_latent_dim:
+            return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        h, c, rq = self.n_heads, self.kv_latent_dim, self.q_latent_dim
+        return (d * rq + rq * h * (self.qk_nope_dim + self.qk_rope_dim)
+                + d * (c + self.qk_rope_dim)
+                + c * h * (self.qk_nope_dim + self.v_head_dim)
+                + h * self.v_head_dim * d
+                # the indexer: its queries, its key, its heads' weights
+                + rq * self.index_heads * self.index_head_dim
+                + d * self.index_head_dim + d * self.index_heads)
+
+    @property
     def n_params(self) -> int:
         """Approximate parameter count (for sizing / logs)."""
         d, f, l, v = self.dim, self.ffn_dim, self.n_layers, self.vocab_size
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attn = self.attn_params
         mlp = 3 * d * f if self.mlp_type == "gated" else 2 * d * f
         if self.n_experts:
             mlp = (self.experts_held * mlp + d * self.n_experts
@@ -341,6 +406,27 @@ class ModelConfig:
             assert set(self.layer_kinds) <= {"m", "c", "d", "w", "A"}, (
                 self.layer_kinds)
             assert "A" in self.layer_kinds, "no attention layer to cache"
+            if self.kv_latent_dim:
+                assert set(self.layer_kinds) == {"A"}, (
+                    "latent attention stands in a stack of its own: no "
+                    "recurrent or window kind beside it has been served")
+                for field in ("q_latent_dim", "qk_nope_dim", "qk_rope_dim",
+                              "v_head_dim", "index_heads", "index_head_dim",
+                              "index_topk"):
+                    assert getattr(self, field) > 0, (
+                        f"latent attention needs {field} > 0")
+                assert (self.qk_rope_dim % 2 == 0
+                        and self.qk_rope_dim <= self.index_head_dim), (
+                    "the rotated channels pair up, and the indexer rotates "
+                    "as many of its own")
+                assert (self.rope and self.rope_scaling_type == "none"
+                        and self.rope_scaling == 1.0 and not self.rope_kinds
+                        and not self.rope_freq_factors), (
+                    "latent attention rotates at rope_theta alone")
+                for field in ("attn_bias", "qk_norm", "attn_softcap",
+                              "attn_scale", "attn_scale_mult"):
+                    assert not getattr(self, field), (
+                        f"latent attention has no {field}")
             assert len(set(self.layer_kinds) & {"m", "c", "d"}) <= 1, (
                 "one recurrent kind a stack")
             if "m" in self.layer_kinds:
@@ -364,6 +450,13 @@ class ModelConfig:
                 assert not self.sliding_window, (
                     "sliding_window in a hybrid stack belongs to its "
                     "window layers: layer_kinds has no \"w\"")
+        if not (self.kv_latent_dim and self.layer_kinds):
+            for field in ("kv_latent_dim", "q_latent_dim", "qk_nope_dim",
+                          "qk_rope_dim", "v_head_dim", "index_heads",
+                          "index_head_dim", "index_topk", "rope_interleave"):
+                assert not getattr(self, field), (
+                    f"{field} belongs to latent attention: a hybrid stack "
+                    "(layer_kinds) with kv_latent_dim > 0")
         assert set(self.rope_kinds) <= {"A", "w"} and (
             not self.rope_kinds or self.layer_kinds), (
             "rope_kinds names attention kinds of a hybrid stack")
@@ -636,6 +729,42 @@ PRESETS = {
         delta_heads=4, delta_key_dim=8, delta_value_dim=16, delta_conv=4,
         delta_neg_eigval=True, delta_chunk=8, rope=False,
         tie_embeddings=False, norm_eps=1e-6, max_seq_len=256),
+    # GLM-5 (glm_moe_dsa, 744B-A40B), ONE CHIP'S SHARE of a stated
+    # deployment: each layer shared by 16 chips (expert parallel: this is
+    # chip 0, routed experts 0-15 of 256, rows 0-19,359 of the untied
+    # embedding and head; attention, the indexer and the shared expert on
+    # every chip) and the first pipeline stage of 7 of the 78 layers: layer
+    # 0 (one of the three leading dense layers: they count once) and six
+    # routed ones. Every width is the published one: hidden 6144, latent
+    # attention of 64 heads (query latent 2048, key/value latent 512, 192
+    # un-rotated + 64 rotated query channels, values 256), the indexer of
+    # 32 heads of 128 keeping 2048 positions a query, dense width 12288,
+    # sigmoid router 256 / 8 a token scaled 2.5, expert and shared-expert
+    # width 2048. benchmark/configs/glm-5.json states the cut and what the
+    # published config leaves to assumption.
+    "glm-5": _mk(
+        arch="glmmoedsa", vocab_size=19360, dim=6144, n_layers=7,
+        n_heads=64, n_kv_heads=64, head_dim=64, ffn_dim=2048,
+        n_experts=256, n_experts_used=8, n_experts_held=16, expert_first=0,
+        n_shared_ffn=2048, shared_gate=False, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=2.5,
+        n_dense_layers=1, dense_ffn_dim=12288, layer_kinds="AAAAAAA",
+        kv_latent_dim=512, q_latent_dim=2048, qk_nope_dim=192,
+        qk_rope_dim=64, v_head_dim=256, index_heads=32, index_head_dim=128,
+        index_topk=2048, rope_interleave=True, rope_theta=1000000.0,
+        norm_eps=1e-5, max_seq_len=202752),
+    # the same shape at toy widths (tests, --rehearse): the indexer keeps
+    # 16 positions a query, so a prompt of a few dozen tokens chooses
+    "tiny-glm5": _mk(
+        arch="glmmoedsa", vocab_size=256, dim=64, n_layers=4, n_heads=4,
+        n_kv_heads=4, head_dim=8, ffn_dim=32, n_experts=16,
+        n_experts_used=3, n_experts_held=4, expert_first=0,
+        n_shared_ffn=32, shared_gate=False, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=2.5,
+        n_dense_layers=1, dense_ffn_dim=96, layer_kinds="AAAA",
+        kv_latent_dim=32, q_latent_dim=48, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=24, index_heads=4, index_head_dim=16, index_topk=16,
+        rope_interleave=True, rope_theta=1000000.0, max_seq_len=256),
     "dolphin-mixtral": _mk(arch="llama", vocab_size=32002, dim=4096,
                            n_layers=32, n_heads=32, n_kv_heads=8,
                            head_dim=128, ffn_dim=14336, n_experts=8,

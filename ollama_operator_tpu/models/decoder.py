@@ -44,6 +44,15 @@ Params pytree layout (all leaves jnp arrays; layer leaves stacked on axis 0):
     conv_in [Lc, D, 3D]  conv_w [Lc, K, D]  conv_out [Lc, D, D]
     Window layers ("w", exaone_moe) have attention's projections: La counts
     them with the "A" layers, in layer order
+    Latent attention (cfg.kv_latent_dim, glm_moe_dsa) has, in place of
+    wq/wk/wv, over its La layers
+    wq_a [La, D, Rq]  q_a_norm_w [La, Rq]  wq_b [La, Rq, H*(dn+dr)]
+    wkv_a [La, D, C+dr]  kv_a_norm_w [La, C]
+    w_uk [La, H, dn, C]  w_uv [La, H, C, dv]  wo [La, H*dv, D]
+    (the published kv_b_proj [C, H*(dn+dv)] a head at a time, keys' part
+    transposed: the layout the absorbed decode step multiplies by) and the
+    indexer's idx_wq [La, Rq, Hi*di]  idx_wk [La, D, di]
+    idx_k_norm_w / idx_k_norm_b [La, di]  idx_w [La, D, Hi]
     cfg.n_dense_layers leading layers of such a stack have one dense MLP,
     w_gate/w_up [Ld, D, Fd]  w_down [Ld, Fd, D], and the router's leaves
     are stacked over the Lr = L - Ld layers after them (router_bias
@@ -65,7 +74,7 @@ from ..ops import quant as Q
 from ..ops.attention import (attend_hf, cached_attention, causal_mask,
                              chunk_attention, note_kernel)
 from ..ops.norms import layer_norm, rms_norm
-from ..ops.rope import apply_rope, rope_angles_cfg
+from ..ops.rope import apply_rope, rope_angles, rope_angles_cfg
 from ..runtime.trace import device_scope
 from .config import ModelConfig
 
@@ -142,13 +151,31 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     La = cfg.n_attn_layers          # L unless the stack is a hybrid one
-    layers: Dict[str, jax.Array] = {
-        "attn_norm_w": jnp.ones((L, D), dtype),
-        "wq": w(next(keys), (La, D, cfg.q_dim)),
-        "wk": w(next(keys), (La, D, cfg.kv_dim)),
-        "wv": w(next(keys), (La, D, cfg.kv_dim)),
-        "wo": w(next(keys), (La, cfg.q_dim, D)),
-    }
+    layers: Dict[str, jax.Array] = {"attn_norm_w": jnp.ones((L, D), dtype)}
+    if cfg.kv_latent_dim:
+        H, C, Rq = cfg.n_heads, cfg.kv_latent_dim, cfg.q_latent_dim
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        Hi, di = cfg.index_heads, cfg.index_head_dim
+        layers.update(
+            wq_a=w(next(keys), (La, D, Rq)),
+            q_a_norm_w=jnp.ones((La, Rq), dtype),
+            wq_b=w(next(keys), (La, Rq, H * (dn + dr))),
+            wkv_a=w(next(keys), (La, D, C + dr)),
+            kv_a_norm_w=jnp.ones((La, C), dtype),
+            w_uk=w(next(keys), (La, H, dn, C)),
+            w_uv=w(next(keys), (La, H, C, dv)),
+            wo=w(next(keys), (La, H * dv, D)),
+            idx_wq=w(next(keys), (La, Rq, Hi * di)),
+            idx_wk=w(next(keys), (La, D, di)),
+            idx_k_norm_w=jnp.ones((La, di), dtype),
+            idx_k_norm_b=jnp.zeros((La, di), dtype),
+            idx_w=w(next(keys), (La, D, Hi)))
+    else:
+        layers.update(
+            wq=w(next(keys), (La, D, cfg.q_dim)),
+            wk=w(next(keys), (La, D, cfg.kv_dim)),
+            wv=w(next(keys), (La, D, cfg.kv_dim)),
+            wo=w(next(keys), (La, cfg.q_dim, D)))
     if cfg.n_conv_layers:
         Lc = cfg.n_conv_layers
         layers["conv_in"] = w(next(keys), (Lc, D, 3 * D))
@@ -291,7 +318,6 @@ def _layer_rope(cfg: ModelConfig, i, cos, sin, cos_l, sin_l):
 def _rope_pair(positions, cfg: ModelConfig):
     """(cos, sin, cos_l, sin_l): the global rope table plus, for dual-rope
     archs (cfg.rope_local_theta — gemma3), the local-theta table."""
-    from ..ops.rope import rope_angles
     cos, sin = rope_angles_cfg(positions, cfg)
     if not cfg.rope_local_theta:
         return cos, sin, None, None
@@ -397,6 +423,11 @@ def _moe_experts(cfg: ModelConfig, lp, xf, gates):
         # a token's other kept experts would add is another chip's to add
         gates = lax.slice_in_dim(gates, cfg.expert_first,
                                  cfg.expert_first + cfg.experts_held, axis=1)
+    if (xf.dtype == jnp.float32 and not Q.is_quantized(lp["we_gate"])
+            and lp["we_gate"].dtype == jnp.bfloat16):
+        # a stack that carries its stream float32 (``_hybrid_layers``): into
+        # the MXU as bfloat16, as ``_mm`` does; the router read it unrounded
+        xf = xf.astype(jnp.bfloat16)
     impl = cfg.moe_impl
     if impl == "auto":
         impl = "einsum" if N <= 256 else "scan"
@@ -781,10 +812,19 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                     scale)
             return _proj_out(cfg, ap, out, B, T), win
 
+        if cfg.kv_latent_dim:
+            cos, sin = rope_angles(positions, cfg.qk_rope_dim,
+                                   cfg.rope_theta)
+
+            def attend_full(ap, h, kc, vc, row):
+                return _latent_cached(cfg, ap, h, kc, vc, row, positions,
+                                      nv, A, cos, sin)
+        else:
+            def attend_full(ap, h, kc, vc, row):
+                return attend(ap, h, kc, vc, row, mask, cos, sin, cfg_a)
+
         x, k_cache, v_cache, state, load = _hybrid_layers(
-            params, cfg, x, k_cache, v_cache, state, nv,
-            lambda ap, h, kc, vc, row: attend(ap, h, kc, vc, row, mask,
-                                              cos, sin, cfg_a),
+            params, cfg, x, k_cache, v_cache, state, nv, attend_full,
             attend_win, route_live, load)
         logits = _unembed(cfg, params, _last_real(x, n_valid))
         return (logits, *join_state(k_cache, v_cache, state), *_given(load))
@@ -864,7 +904,11 @@ def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load):
 # can be advanced and not cut back.
 
 _ATTN_STACK = ("wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
-               "q_norm_w", "k_norm_w")
+               "q_norm_w", "k_norm_w",
+               # latent attention's and its indexer's
+               "wq_a", "q_a_norm_w", "wq_b", "wkv_a", "kv_a_norm_w", "w_uk",
+               "w_uv", "idx_wq", "idx_wk", "idx_k_norm_w", "idx_k_norm_b",
+               "idx_w")
 _LAYER_NORMS = ("attn_norm_w", "attn_norm_b", "mlp_norm_w", "mlp_norm_b")
 _DENSE_FFN = ("w_gate", "w_up", "w_down", "b_up", "b_down")
 
@@ -891,7 +935,7 @@ def join_state(k_cache, v_cache, state):
     """Inverse of ``split_state``: {"ssm"} / {"win"} beside the keys'
     leaves, {"conv"} / {"win"} beside the values' (a plain array cache goes
     under "kv")."""
-    if state is None:
+    if state is None or all(x is None for x in state):
         return k_cache, v_cache
     ssm, conv, win = state
     if not isinstance(k_cache, dict):
@@ -914,7 +958,10 @@ def empty_state(cfg: ModelConfig, B: int, kv_dtype=jnp.float32):
     [Lc, B, K-1, D], None) for a stack of short
     convolutions; (None, None, (k, v)) for a stack of window layers, rings
     [Lw, B, KvH, W, hd] of ``kv_dtype`` (int8: codes and float32 scales, as
-    the cache keeps its rows)."""
+    the cache keeps its rows). None for a stack of attention alone (latent
+    attention's: what it keeps, it keeps at every position)."""
+    if set(cfg.layer_kinds) == {"A"}:
+        return None
     if cfg.n_window_layers:
         shape = (cfg.n_window_layers, B, cfg.n_kv_heads, cfg.sliding_window,
                  cfg.head_dim)
@@ -1432,8 +1479,14 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
     # the logits twelve layers on lay 2.6-3.6% of the largest from a float32
     # reference's (the other stacks: 1.0-2.2%); unrounded, 1.6-2.6% (my chip
     # runs, PR 44, 8 and 20 readings)
+    # A latent-attention stack does the same: every layer's attention reads
+    # ONE int8 latent for all its heads' keys and values, whose rounding
+    # (0.8% of a layer's attention output, the same for every head: it does
+    # not average out) comes on top, and with the stream rounded as well the
+    # decode step's logits lay 1.9-2.7% from the reference's on six seeds
+    # (my chip run, PR 46, call 1)
     act_dtype = x.dtype
-    if cfg.n_delta_layers:
+    if cfg.n_delta_layers or cfg.kv_latent_dim:
         x = x.astype(jnp.float32)
     # the other mixer's leaves, by their names' prefix; window layers have
     # none of their own
@@ -1483,8 +1536,13 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
         # Mamba state at 32 slots, 2.9 ms of a 24.8 ms decode step: my chip
         # run, PR 29), this way round both branches update or pass their
         # buffers in place
-        out, kc, vc, ssm, conv, win = lax.cond(
-            ~is_attn, other_mixer, attn_mixer, h, kc, vc, ssm, conv, win)
+        if prefix is None and win is None:
+            # a stack of attention alone (latent attention): no branch
+            mixed = attn_mixer(h, kc, vc, ssm, conv, win)
+        else:
+            mixed = lax.cond(~is_attn, other_mixer, attn_mixer, h, kc, vc,
+                             ssm, conv, win)
+        out, kc, vc, ssm, conv, win = mixed
         x, load = _residual_counting(cfg, lp, x, h, out, live, load)
         return (x, kc, vc, ssm, conv, win, load), None
 
@@ -1504,7 +1562,7 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
                   {**{k: v[:Ld] for k, v in norms.items()}, **dense}),
                  (Ld, L, cfg,
                   {**{k: v[Ld:] for k, v in norms.items()}, **routed})]
-    carry = (x, kc, vc, *state, load)
+    carry = (x, kc, vc, *(state or (None, None, None)), load)
     for lo, hi, cfg_l, xs in spans:
         carry, _ = lax.scan(
             functools.partial(body, cfg_l), carry,
@@ -1514,6 +1572,317 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
                                                     jnp.int32)))
     x, kc, vc, *state, load = carry
     return x, kc, vc, tuple(state), load
+
+
+# --------------------------------------------------------------------------
+# latent attention (glm_moe_dsa): one row a position, an indexer beside it
+# --------------------------------------------------------------------------
+#
+# With u_t the normed input of position t:
+#   cQ_t = RMSNorm(u_t W_qa);  [q_nope | q_rope] = cQ_t W_qb, a head;
+#   [cKV_t | kR_t] = u_t W_kva, cKV_t = RMSNorm(cKV_t);  rotary on q_rope and
+#   on the ONE kR_t every head shares. Head i: k_nope = W_uk,i cKV_s,
+#   v = W_uv,i^T cKV_s; scores (q_nope . k_nope + q_rope . kR_s) / sqrt(dn +
+#   dr); out = [sum_s a v] W_o.
+# The cache holds [cKV_s | kR_s] and nothing a head: it rides where a full
+# layer's keys do, [La, B, 1, S, C + dr] (int8: one float32 scale for the
+# latent part and one for the rotated key, "s" [La, B, 2, S]: a normed latent
+# and a raw projection differ in size). A fresh chunk expands keys and values
+# a head (``_latent_expanded``); everything that reads the cache runs
+# ABSORBED (``_latent_absorbed``): q~ = W_uk,i^T q_nope [C], scores q~ . cKV_s
+# + q_rope . kR_s, out = W_uv,i^T (sum_s a cKV_s): the row is read as it
+# lies and no key or value a head is ever made. The two are one function.
+# The indexer: qI = cQ_t W_iq (Hi heads of di), kI_s = LayerNorm(u_s W_ik),
+# rotary on the first dr channels of both, w_t = u_t W_iw / sqrt(Hi di);
+# I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI_s); attention reads the
+# cfg.index_topk positions s <= t of largest I[t, s], all of them while there
+# are no more. kI rides where a full layer's values do, [La, B, 1, S, di].
+# The published inference code rotates qI and kI by a Hadamard matrix and
+# keeps them float8: a rotation leaves every dot product as it was and the
+# storage type here is the cache's, so neither is reproduced. The rows not
+# kept are masked, not skipped: a program reads its whole attended bucket.
+
+_LATENT_Q_BLOCK = 128       # queries, over the batch, a pass of a long prefill
+
+
+def _rope_pairs(cfg: ModelConfig, x, cos, sin):
+    """Rotary embedding over the first cfg.qk_rope_dim channels of x [B, T,
+    d] or [B, T, H, d]; cos, sin [B, T, dr / 2]. ``cfg.rope_interleave``:
+    channels (2i, 2i + 1) are a pair, and come out de-interleaved (first
+    halves, then second halves: the same order for queries and keys, so
+    every dot product is the interleaved rotation's)."""
+    dr = cfg.qk_rope_dim
+    if cfg.rope_interleave:
+        x = jnp.concatenate([x[..., 0:dr:2], x[..., 1:dr:2], x[..., dr:]],
+                            axis=-1)
+    if x.ndim == 3:                 # no head axis: lend it one
+        return apply_rope(x[:, :, None], cos, sin, dr)[:, :, 0]
+    return apply_rope(x, cos, sin, dr)
+
+
+def _latent_project(cfg: ModelConfig, ap, h, cos, sin):
+    """Latent attention's projections of the normed input h [B, T, D]:
+    (q_nope [B, T, H, dn], q_rope [B, T, H, dr] rotated, row [B, T, C + dr]
+    = [normed latent | rotated shared key], cq [B, T, Rq] the normed query
+    latent, which the indexer reads too)."""
+    B, T, _ = h.shape
+    H, C = cfg.n_heads, cfg.kv_latent_dim
+    dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
+    with device_scope("attn.qkv"):
+        cq = rms_norm(_mm(cfg, h, ap["wq_a"]), ap["q_a_norm_w"],
+                      cfg.norm_eps)
+        q = _mm(cfg, cq, ap["wq_b"]).reshape(B, T, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        kv = _mm(cfg, h, ap["wkv_a"])
+        ckv = rms_norm(kv[..., :C], ap["kv_a_norm_w"], cfg.norm_eps)
+        q_rope = _rope_pairs(cfg, q_rope, cos, sin)
+        kr = _rope_pairs(cfg, kv[..., C:], cos, sin)
+        row = jnp.concatenate([ckv, kr.astype(ckv.dtype)], axis=-1)
+    return q_nope, q_rope, row, cq
+
+
+def _index_project(cfg: ModelConfig, ap, h, cq, cos, sin):
+    """The indexer's projections: (qi [B, T, Hi, di] and ki [B, T, di],
+    their first dr channels rotated, w [B, T, Hi] float32, the heads' weights
+    with both scales folded in)."""
+    B, T, _ = h.shape
+    Hi, di = cfg.index_heads, cfg.index_head_dim
+    qi = _mm(cfg, cq, ap["idx_wq"]).reshape(B, T, Hi, di)
+    ki = layer_norm(_mm(cfg, h, ap["idx_wk"]), ap["idx_k_norm_w"],
+                    ap["idx_k_norm_b"], 1e-6)
+    qi = _rope_pairs(cfg, qi, cos, sin)
+    ki = _rope_pairs(cfg, ki, cos, sin)
+    w = _mm(cfg, h, ap["idx_w"]).astype(jnp.float32) * (Hi * di) ** -0.5
+    return qi, ki, w
+
+
+def _index_keep(cfg: ModelConfig, score, visible):
+    """The positions a query's attention may read: ``visible`` [B, T, A]
+    where there are at most cfg.index_topk of them, else the index_topk
+    visible ones of largest ``score`` [B, T, A] float32, a tie on the last
+    place going to the earlier position. bool [B, T, A]. Called only where
+    the attended length A passes index_topk: below it nothing is chosen
+    and the scores are not computed."""
+    score = jnp.where(visible, score, -jnp.inf)
+    # lax.top_k puts the lower index first among equal scores, so its last
+    # place names both the k-th score and the last tied position kept
+    vals, idx = lax.top_k(score, cfg.index_topk)
+    kth, last = vals[..., -1:], idx[..., -1:]
+    pos = jnp.arange(score.shape[-1], dtype=idx.dtype)
+    return ((score > kth) | ((score == kth) & (pos <= last))) & visible
+
+
+def _index_mask(cfg: ModelConfig, qi, w, ki_rows, q_pos):
+    """bool [B, T, A]: the positions each query at q_pos [B, T] may read of
+    the A that ki_rows [B, A, di] holds (or int8 {"q", "s" [B, A]}: a
+    position's scale is positive and comes out of the ReLU): every position
+    up to its own and, where A passes cfg.index_topk, of those the ones
+    ``_index_keep`` keeps by the indexer's scores of queries qi [B, T, Hi,
+    di], w [B, T, Hi]."""
+    from ..ops.quant_cache import is_quantized_cache
+    quant = is_quantized_cache(ki_rows)
+    kq = ki_rows["q"] if quant else ki_rows
+    A = kq.shape[1]
+    visible = (jnp.arange(A, dtype=jnp.int32)[None, None, :]
+               <= q_pos[:, :, None])
+    if A <= cfg.index_topk:
+        return visible
+    with device_scope("attn.index"):
+        dots = jnp.einsum("btjd,bsd->btjs", qi, kq.astype(qi.dtype),
+                          preferred_element_type=jnp.float32)
+        score = jnp.einsum("btjs,btj->bts", jax.nn.relu(dots), w)
+        if quant:
+            score = score * ki_rows["s"][:, None, :]
+        return _index_keep(cfg, score, visible)
+
+
+def _by_query_blocks(fn, T: int, *per_query):
+    """``fn(*blocks)`` over blocks of queries (axis 1 of every array of
+    ``per_query``), results joined along axis 1: a long chunk's scores, [B,
+    H, T, A] float32 whole, stay a block's. A block is _LATENT_Q_BLOCK
+    queries over the batch, 16 a row at least: a batched admission of four
+    rows of 4,096 positions at 256 queries a row needs 4.4 GB of
+    temporaries beside 12.4 GB of arguments and does not compile for a chip
+    of 15.75 GB; at 32 a row it needs 3.5 (``hack/compile_cell.py``'s way,
+    PR 46)."""
+    Q = max(_LATENT_Q_BLOCK // per_query[0].shape[0], 16)
+    if T <= Q or T % Q:
+        return fn(*per_query)
+    blocks = tuple(jnp.moveaxis(a.reshape(a.shape[0], T // Q, Q,
+                                          *a.shape[2:]), 1, 0)
+                   for a in per_query)
+    out = lax.map(lambda xs: fn(*xs), blocks)
+    return jnp.moveaxis(out, 0, 1).reshape(out.shape[1], T, *out.shape[3:])
+
+
+def _latent_scale(cfg: ModelConfig) -> float:
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+
+
+def _latent_expanded(cfg: ModelConfig, ap, q_nope, q_rope, row, qi, w, ki,
+                     q_pos):
+    """Attention of a fresh chunk over itself, keys and values expanded a
+    head from the chunk's own rows: row [B, T, C + dr], ki [B, T, di], both
+    as computed (not yet as the cache stores them); q_pos [B, T]. -> [B, T,
+    H * dv]."""
+    B, T, H, _ = q_nope.shape
+    C = cfg.kv_latent_dim
+    ckv, kr = row[..., :C], row[..., C:]
+    with device_scope("attn.core"):
+        k_nope = jnp.einsum("bsc,hnc->bshn", ckv, ap["w_uk"])
+        v = jnp.einsum("bsc,hcv->bshv", ckv, ap["w_uv"])
+
+    def block(q_nope, q_rope, qi, w, q_pos):
+        ok = _index_mask(cfg, qi, w, ki, q_pos)
+        with device_scope("attn.core"):
+            s = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bthr,bsr->bhts", q_rope, kr,
+                              preferred_element_type=jnp.float32))
+            s = jnp.where(ok[:, None], s * _latent_scale(cfg), -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+            return jnp.einsum("bhts,bshv->bthv", p, v)
+
+    note_kernel("prefill", "einsum")
+    out = _by_query_blocks(block, T, q_nope, q_rope, qi, w, q_pos)
+    return out.reshape(B, T, -1)
+
+
+def _latent_absorbed(cfg: ModelConfig, ap, q_nope, q_rope, rows, qi, w,
+                     ki_rows, q_pos):
+    """Attention over the first A cached positions, absorbed: rows [B, A, C
+    + dr] and ki_rows [B, A, di] as the cache keeps them (int8: {"q", "s"},
+    the rows' "s" [B, 2, A], latent part and rotated key), the new
+    positions already written. q_pos [B, T]. -> [B, T, H * dv]."""
+    from ..ops.quant_cache import is_quantized_cache
+    B, T, H, _ = q_nope.shape
+    C = cfg.kv_latent_dim
+    quant = is_quantized_cache(rows)
+    codes = rows["q"] if quant else rows
+    dt = q_nope.dtype
+    lat = codes[..., :C].astype(dt)
+    kr = codes[..., C:C + cfg.qk_rope_dim].astype(dt)   # zeros may follow
+    with device_scope("attn.core"):
+        q_abs = jnp.einsum("bthn,hnc->bthc", q_nope, ap["w_uk"])
+
+    def block(q_abs, q_rope, qi, w, q_pos):
+        ok = _index_mask(cfg, qi, w, ki_rows, q_pos)
+        with device_scope("attn.core"):
+            s_lat = jnp.einsum("bthc,bsc->bhts", q_abs, lat,
+                               preferred_element_type=jnp.float32)
+            s_rot = jnp.einsum("bthr,bsr->bhts", q_rope, kr,
+                               preferred_element_type=jnp.float32)
+            if quant:
+                s_lat = s_lat * rows["s"][:, 0, None, None, :]
+                s_rot = s_rot * rows["s"][:, 1, None, None, :]
+            s = jnp.where(ok[:, None], (s_lat + s_rot) * _latent_scale(cfg),
+                          -1e30)
+            # the softmax by hand: the latent's scale goes into the
+            # normaliser's pass, one pass over [B, H, T, A] fewer
+            e = jnp.exp(s - s.max(axis=-1, keepdims=True))
+            norm = 1.0 / e.sum(axis=-1, keepdims=True)
+            if quant:
+                norm = norm * rows["s"][:, 0, None, None, :]
+            return jnp.einsum("bhts,bsc->bthc", (e * norm).astype(dt), lat)
+
+    note_kernel("decode", "einsum")
+    o_lat = _by_query_blocks(block, T, q_abs, q_rope, qi, w, q_pos)
+    with device_scope("attn.core"):
+        out = jnp.einsum("bthc,hcv->bthv", o_lat, ap["w_uv"])
+    return out.reshape(B, T, -1)
+
+
+def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
+                   n_valid, A: int, cos, sin):
+    """One latent-attention layer against row ``row_i`` of the cache: kc
+    the rows [La, B, 1, S, C + dr], vc the indexer's keys [La, B, 1, S, di]
+    (either int8 {"q", "s"}). Writes the new positions' rows and keys
+    (those at or past n_valid [B] write nothing: a padded position, an
+    inactive slot leave the cache's bits alone), attends over the first A
+    positions. -> (out [B, T, D], kc, vc)."""
+    from ..ops import quant_cache as QC
+    B, T, _ = h.shape
+    C = cfg.kv_latent_dim
+    q_nope, q_rope, row, cq = _latent_project(cfg, ap, h, cos, sin)
+    with device_scope("attn.index"):
+        qi, ki, w = _index_project(cfg, ap, h, cq, cos, sin)
+    quant = QC.is_quantized_cache(kc)
+    pad = [(0, 0), (0, 0), (0, cfg.latent_row_pad)]
+    S = (kc["q"] if quant else kc).shape[3]
+    real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None]
+    bidx = jnp.arange(B)[:, None]
+    # a position that is not real rewrites what lies there (the engine keeps
+    # lengths + T <= S, so a row's positions are its own). A scatter that
+    # DROPS them instead (an index past the end) is the same program but for
+    # one shape: ``decode.(32, 1024)`` at the published widths then fails to
+    # compile for the v5e ("Used 173.00M of 128.00M vmem", an empty heap:
+    # hack/compile_cell.py's way, PR 46)
+    pidx = jnp.minimum(positions, S - 1)
+
+    def put(c, x):
+        old = c[row_i, bidx, 0, pidx]
+        keep = real if old.ndim == 2 else real[..., None]
+        return c.at[row_i, bidx, 0, pidx].set(
+            jnp.where(keep, x.astype(c.dtype), old))
+
+    def window(c, n):
+        lead = (1, B, 1, A)
+        return lax.dynamic_slice(c, (row_i,) + (0,) * (c.ndim - 1),
+                                 lead[:n] + c.shape[n:])
+
+    if quant:
+        with device_scope("attn.kv_write"):
+            rq, rs = QC.quantize_latent(row, C)     # [B,T,C+dr], [B,T,2]
+            kc = {"q": put(kc["q"], jnp.pad(rq, pad)),
+                  "s": kc["s"].at[row_i, bidx, :, pidx].set(jnp.where(
+                      real[..., None], rs, kc["s"][row_i, bidx, :, pidx]))}
+        with device_scope("attn.index"):
+            iq, is_ = QC.quantize_kv(ki)
+            vc = {"q": put(vc["q"], iq), "s": put(vc["s"], is_)}
+        rows = {"q": window(kc["q"], 4)[0, :, 0],
+                "s": lax.dynamic_slice(
+                    kc["s"], (row_i, 0, 0, 0), (1, B, 2, A))[0]}
+        ki_rows = {"q": window(vc["q"], 4)[0, :, 0],
+                   "s": window(vc["s"], 4)[0, :, 0]}
+    else:
+        with device_scope("attn.kv_write"):
+            kc = put(kc, jnp.pad(row, pad))
+        with device_scope("attn.index"):
+            vc = put(vc, ki)
+        rows, ki_rows = window(kc, 4)[0, :, 0], window(vc, 4)[0, :, 0]
+    out = _latent_absorbed(cfg, ap, q_nope, q_rope, rows, qi, w, ki_rows,
+                           positions)
+    return _proj_out(cfg, ap, out, B, T), kc, vc
+
+
+def _latent_prefill(params: Params, cfg: ModelConfig, x, n_valid):
+    """``prefill_chunk`` of a latent-attention stack from the embedded chunk
+    x [B, T, D]: -> (logits, rows [La, B, 1, T, C + dr (+ the cache's
+    padding, zeros)], indexer keys [La, B, 1, T, di]), both as computed (the
+    engine quantizes them as it does keys and values); nothing else is
+    carried."""
+    B, T, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    cos, sin = rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
+
+    def attend(ap, h, kc, vc, row_i):
+        q_nope, q_rope, row, cq = _latent_project(cfg, ap, h, cos, sin)
+        with device_scope("attn.index"):
+            qi, ki, w = _index_project(cfg, ap, h, cq, cos, sin)
+        out = _latent_expanded(cfg, ap, q_nope, q_rope, row, qi, w, ki,
+                               positions)
+        kc = lax.dynamic_update_index_in_dim(kc, row[:, None], row_i, 0)
+        vc = lax.dynamic_update_index_in_dim(
+            vc, ki[:, None].astype(vc.dtype), row_i, 0)
+        return _proj_out(cfg, ap, out, B, T), kc, vc
+
+    _, kd, vd = cfg.cache_row_dims
+    La = cfg.n_full_layers
+    x, ks, vs, _, _ = _hybrid_layers(
+        params, cfg, x, jnp.zeros((La, B, 1, T, kd), x.dtype),
+        jnp.zeros((La, B, 1, T, vd), x.dtype), None,
+        _valid_rows(n_valid, B, T), attend)
+    return _unembed(cfg, params, _last_real(x, n_valid)), ks, vs
 
 
 def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
@@ -1536,6 +1905,8 @@ def _hybrid_prefill(params: Params, cfg: ModelConfig, tokens, n_valid,
         x = inputs_embeds.astype(params["tok_emb"].dtype)
     else:
         x = _embed(cfg, params, tokens)
+    if cfg.kv_latent_dim:
+        return _latent_prefill(params, cfg, x, n_valid)
 
     def attend(ap, h, kc, vc, row):
         q, k, v = _qkv(cfg_a, ap, h, cos, sin)

@@ -9,6 +9,10 @@ Layout mirrors the bf16 cache, plus a scale array one axis short:
 
     q [.., KvH, S, hd] int8      s [.., KvH, S] f32
 
+(latent attention's one row a position, models/decoder.py, has two parts of
+two sizes and a scale for each: q [.., 1, S, C + dr], s [.., 2, S],
+``quantize_latent``)
+
 The arithmetic stays exact-shaped with the dense path (ops/attention.py
 ``attend_hf``): scores pick up the key scale AFTER the q·k dot (the scale
 is per key position, so it factors out), and the value scale folds into
@@ -48,6 +52,17 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     s = amax / 127.0
     q = jnp.round(x.astype(jnp.float32) / jnp.maximum(s[..., None], 1e-30))
     return jnp.clip(q, -127, 127).astype(jnp.int8), s
+
+
+def quantize_latent(row: jax.Array, split: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """A latent-attention row [..., C + dr] = [normed latent | rotated key]
+    -> (int8 [..., C + dr], f32 scales [..., 2]): the two parts differ in
+    size (a norm's output against a raw projection), so each has its own
+    scale, the latent's first; ``split`` = C."""
+    lq, ls = quantize_kv(row[..., :split])
+    rq, rs = quantize_kv(row[..., split:])
+    return jnp.concatenate([lq, rq], axis=-1), jnp.stack([ls, rs], axis=-1)
 
 
 def attend_hf_q(q, kc: Dict, vc: Dict, mask, scale: float,

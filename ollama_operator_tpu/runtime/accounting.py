@@ -160,8 +160,7 @@ def per_token_flops(cfg: ModelConfig) -> float:
     really computes logits for the whole padded step, and on the tiny CI
     configs it dominates; the docs carry the caveat."""
     d, f, L, v = cfg.dim, cfg.ffn_dim, cfg.n_layers, cfg.vocab_size
-    q, kv = cfg.q_dim, cfg.kv_dim
-    proj = 2 * (d * q + 2 * d * kv + q * d)
+    proj = 2 * cfg.attn_params
     mlp_mult = 6 if cfg.mlp_type == "gated" else 4
     mlp = mlp_mult * d * f
     if cfg.n_experts:
@@ -205,6 +204,16 @@ def attn_span_flops(cfg: ModelConfig, start: int, n: int) -> float:
     """Attention score+value matmul FLOPs (4·q_dim per attended key) for
     positions [start, start+n), respecting sliding windows per layer."""
     full, sliding = _layer_split(cfg)
+    if cfg.kv_latent_dim:
+        # absorbed: a kept position costs a head a dot over the row and a
+        # sum over its latent part; every position before the query costs
+        # the indexer its heads' dots
+        c, dr = cfg.kv_latent_dim, cfg.qk_rope_dim
+        return full * (
+            2.0 * cfg.n_heads * (2 * c + dr)
+            * _ctx_sum(start, n, cfg.index_topk)
+            + 2.0 * cfg.index_heads * cfg.index_head_dim
+            * _ctx_sum(start, n))
     tot = full * _ctx_sum(start, n)
     if sliding:
         tot += sliding * _ctx_sum(start, n, cfg.sliding_window)

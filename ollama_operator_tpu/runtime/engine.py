@@ -45,7 +45,8 @@ from .faults import FAULTS
 from .trace import FLIGHT, device_scope, span
 from ..parallel.sharding import (kv_cache_pspec, params_sharding_tree,
                                  resolve_moe_impl)
-from ..server.metrics import GLOBAL as METRICS, seed_expert_tokens
+from ..server.metrics import (GLOBAL as METRICS, seed_expert_tokens,
+                              seed_index_positions)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -536,7 +537,11 @@ class Engine:
         self.n_slots, self.max_seq = B, S
         # layers that keep keys and values at every position: all, but for
         # a hybrid stack (its window layers keep rings, part of the state)
-        L, KvH, hd = cfg.n_full_layers, cfg.n_kv_heads, cfg.head_dim
+        L, hd = cfg.n_full_layers, cfg.head_dim
+        # a position's two rows in a full layer's cache: a head's keys and
+        # values, or latent attention's [latent | rotated key] and the
+        # indexer's key (one "head", two widths)
+        KvH, k_dim, v_dim = cfg.cache_row_dims
         V = cfg.vocab_size
         # a hybrid stack's slots carry a state beside their full-length
         # keys and values (models/decoder.py, hybrid section: a recurrent
@@ -546,6 +551,17 @@ class Engine:
         self.recurrent = bool(cfg.layer_kinds)
         if cfg.n_experts:
             seed_expert_tokens(cfg.n_experts)
+        if cfg.kv_latent_dim:
+            from .host_cache import host_cache_bytes
+            seed_index_positions()
+            if ecfg.paged:
+                self._refuse_for_latent_rows("a page pool")
+            if mesh is not None and mesh.size > 1:
+                self._refuse_for_latent_rows(
+                    f"a mesh of {dict(mesh.shape)}")
+            if host_cache_bytes() > 0:
+                self._refuse_for_latent_rows(
+                    "the host tier (TPU_HOST_CACHE_GB)")
         if self.recurrent:
             if ecfg.paged:
                 raise ValueError(
@@ -752,19 +768,26 @@ class Engine:
         elif self.quant_cache:
             from ..ops.quant_cache import empty_cache
 
-            def qzeros(sh):
-                c = empty_cache(L, B, KvH, S, hd)
+            def qzeros(sh, dim, scales=KvH):
+                c = empty_cache(L, B, KvH, S, dim)
+                if scales != KvH:
+                    c["s"] = jnp.zeros((L, B, scales, S), jnp.float32)
                 if sh is None:
                     return c
                 return jax.tree_util.tree_map(self._g, c, sh)
             cache_sh = self._quant_cache_sharding(cache_sh)
             self._cache_sh = cache_sh
-            self.k_cache = qzeros(cache_sh)
-            self.v_cache = qzeros(cache_sh)
+            # a latent row has two scales a position: its latent part's
+            # and its rotated key's (ops/quant_cache.quantize_latent)
+            self.k_cache = qzeros(cache_sh, k_dim,
+                                  2 if cfg.kv_latent_dim else KvH)
+            self.v_cache = qzeros(cache_sh, v_dim)
         else:
-            cache_shape = (L, B, KvH, S, hd)  # head-first: (S, hd) tiles
-            self.k_cache = zeros(cache_shape, ecfg.cache_dtype, cache_sh)
-            self.v_cache = zeros(cache_shape, ecfg.cache_dtype, cache_sh)
+            # head-first: (S, hd) tiles
+            self.k_cache = zeros((L, B, KvH, S, k_dim), ecfg.cache_dtype,
+                                 cache_sh)
+            self.v_cache = zeros((L, B, KvH, S, v_dim), ecfg.cache_dtype,
+                                 cache_sh)
         full = self.kv_bytes
         if self.recurrent:
             self.k_cache, self.v_cache = decoder.join_state(
@@ -777,9 +800,14 @@ class Engine:
             return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
         # device bytes of the cache by what holds them
         # (tpu_model_cache_bytes{kind}): full-length rows or pages, the
-        # window layers' rings, recurrent state
+        # window layers' rings, recurrent state; where attention is
+        # latent, its rows are the full-length ones and its indexer's keys
+        # a kind of their own
         self.cache_bytes = {"full": full, "window": nbytes(win),
                             "state": nbytes(carried)}
+        if cfg.kv_latent_dim:
+            index = nbytes(self.v_cache)
+            self.cache_bytes.update(full=full - index, index=index)
         self.lengths = zeros((B,), jnp.int32, slot_sh)
         self.counts = zeros((B, V), jnp.int32, slot_sh2)
         # penalty ring: the last repeat_last_n token ids per slot (sentinel
@@ -949,6 +977,16 @@ class Engine:
         if getattr(x, "is_fully_addressable", True):
             return np.asarray(x)
         return np.asarray(x.addressable_data(0))
+
+    def _refuse_for_latent_rows(self, what: str) -> None:
+        """Latent attention's cache has one home so far, the contiguous
+        cache of one device; everything else says so by name."""
+        if self.cfg.kv_latent_dim:
+            raise ValueError(
+                f"{what}: no form yet for latent rows (one row [latent | "
+                "rotated key] and one indexer key a position, no keys or "
+                "values a head); they serve from the contiguous cache of "
+                "one device, without speculation, export or a host tier")
 
     @staticmethod
     def _quant_cache_sharding(cache_sh):
@@ -1131,9 +1169,14 @@ class Engine:
                 k_cache, v_cache = decoder.paged_insert(
                     cfg, k_cache, v_cache, ks, vs, table_row, n_valid)
             elif self.quant_cache:
-                from ..ops.quant_cache import quantize_kv
+                from ..ops.quant_cache import quantize_kv, quantize_latent
                 with device_scope("attn.kv_write"):
-                    kq, ksc = quantize_kv(ks)          # [L,1,KvH,T,hd]
+                    if cfg.kv_latent_dim:
+                        # [L,1,1,T,C+dr]; its two scales to [L,1,2,T]
+                        kq, ksc = quantize_latent(ks, cfg.kv_latent_dim)
+                        ksc = jnp.moveaxis(ksc[:, :, 0], -1, 2)
+                    else:
+                        kq, ksc = quantize_kv(ks)      # [L,1,KvH,T,hd]
                     vq, vsc = quantize_kv(vs)
                     dus = jax.lax.dynamic_update_slice
                     k_cache = {
@@ -1538,24 +1581,20 @@ class Engine:
                 dus = jax.lax.dynamic_update_slice
                 k_cache, v_cache, state = decoder.split_state(k_cache,
                                                               v_cache)
-                if self.quant_cache:
-                    Lq, _, KvH, _S, hd = k_cache["q"].shape
-                    def slice5(c):
-                        return {"q": dsl(c["q"], (0, slot, 0, 0, 0),
-                                         (Lq, 1, KvH, A, hd)),
-                                "s": dsl(c["s"], (0, slot, 0, 0),
-                                         (Lq, 1, KvH, A))}
-                    def write5(c, cs):
-                        return {"q": dus(c["q"], cs["q"],
-                                         (0, slot, 0, 0, 0)),
-                                "s": dus(c["s"], cs["s"], (0, slot, 0, 0))}
-                else:
-                    Lq, _, KvH, _S, hd = k_cache.shape
-                    def slice5(c):
-                        return dsl(c, (0, slot, 0, 0, 0),
-                                   (Lq, 1, KvH, A, hd))
-                    def write5(c, cs):
-                        return dus(c, cs, (0, slot, 0, 0, 0))
+                # one slot's first A positions of every leaf [L, B, KvH,
+                # S, ...]: codes and scales alike, keys' and values' rows
+                # each at their own width
+                def slice5(c):
+                    return jax.tree_util.tree_map(
+                        lambda a: dsl(a, (0, slot) + (0,) * (a.ndim - 2),
+                                      (a.shape[0], 1, a.shape[2], A)
+                                      + a.shape[4:]), c)
+
+                def write5(c, cs):
+                    return jax.tree_util.tree_map(
+                        lambda a, x: dus(a, x,
+                                         (0, slot) + (0,) * (a.ndim - 2)),
+                        c, cs)
                 kc_s, vc_s = slice5(k_cache), slice5(v_cache)
                 if state is not None:
                     # the slot's state, read where the last piece left it
@@ -2412,6 +2451,23 @@ class Engine:
                             '{sampler="%s"}' % (
                                 "candidates" if needed else "argmax"))
         return "candidates" if first else "argmax"
+
+    def _count_index_positions(self, budgets: np.ndarray) -> None:
+        """A launched chunk's decode steps into
+        ``tpu_model_index_positions_total``: step j of an active slot of
+        n cached positions has n + j + 1 before it (its own counted) and
+        its attention reads at most ``index_topk`` of them; the same in
+        every layer, so a layer is counted. From the host's lengths, before
+        the launch advances them."""
+        n0 = self._host_lengths[self.active].astype(np.int64)
+        b = budgets[self.active]
+        steps = np.arange(b.max(initial=0))[None, :]
+        n = np.where(steps < b[:, None], n0[:, None] + 1 + steps, 0)
+        seen, kept = n.sum(), np.minimum(n, self.cfg.index_topk).sum()
+        METRICS.inc("tpu_model_index_positions_total", float(seen),
+                    '{what="seen"}')
+        METRICS.inc("tpu_model_index_positions_total", float(kept),
+                    '{what="kept"}')
 
     @staticmethod
     def _count_expert_tokens(load: np.ndarray) -> None:
@@ -3281,6 +3337,7 @@ class Engine:
         cut (never skips) so the shipped chain stays rooted. None when
         the radix cache is off or holds nothing for these ids.
         Leader-side only — callers gate on single-host serving."""
+        self._refuse_for_latent_rows("export_request_kv")
         if self._radix is None:
             return None
         FAULTS.check("pages.export")
@@ -3476,6 +3533,8 @@ class Engine:
             self._rln_dev, self._gstate, self._gmask_dev,
             self._gtrans_dev, self._tables_dev(),
             self._g(budgets, self._slot_sh))
+        if self.cfg.kv_latent_dim:
+            self._count_index_positions(budgets)
         self._host_lengths[self.active] += budgets[self.active]
         # stamp AFTER the successful launch: a raise above leaves the
         # epoch untouched, so later frees aren't fenced behind a program
@@ -3540,6 +3599,7 @@ class Engine:
         stream like ``retire`` does."""
         assert self.sp_size == 1, \
             "speculative decode: bucketed caches only (no sp meshes)"
+        self._refuse_for_latent_rows("speculative decoding")
         assert not self.recurrent, (
             "speculative decode rolls rejected drafts back by length; a "
             "recurrent state has no such rollback")

@@ -326,7 +326,7 @@ _SPAN_EVENT = {"http.flush": "http_flush"}
 # Compile-time only; the profiler shows them in each device operation's
 # `tf_op` stat (benchmark/trace_spans.py reads that).
 DEVICE_SCOPES = ("embed", "attn.qkv", "attn.kv_write", "attn.core",
-                 "attn.window", "attn.out", "mlp", "moe.route",
+                 "attn.window", "attn.index", "attn.out", "mlp", "moe.route",
                  "moe.experts", "ssm.in_proj",
                  "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out",
                  "conv.in_proj", "conv.conv", "conv.out", "delta.in_proj",

@@ -311,10 +311,13 @@ GLOBAL.describe("tpu_model_host_cache_pages",
                 "(live gauge)")
 GLOBAL.describe("tpu_model_cache_bytes",
                 "Device bytes of the loaded model's cache as allocated, by "
-                "what holds them (kind=full|window|state): full-length "
-                "rows or pages of keys and values; the rings of "
-                "sliding_window positions a slot that window-attention "
-                "layers keep instead of a full row; recurrent state")
+                "what holds them (kind=full|window|state|index): "
+                "full-length rows or pages of keys and values (latent "
+                "attention's: one row [latent | rotated key] a position); "
+                "the rings of sliding_window positions a slot that "
+                "window-attention layers keep instead of a full row; "
+                "recurrent state; the keys of latent attention's indexer "
+                "(index: only where the model has one)")
 GLOBAL.describe("tpu_model_async_fallback_total",
                 "Decode dispatches that fell back to synchronous while "
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "
@@ -443,6 +446,16 @@ GLOBAL.describe("tpu_model_moe_expert_tokens_total",
                 "to the host once a decode chunk beside the tokens; "
                 "seeded when the engine of such a model is built "
                 "(seed_expert_tokens), absent for a model without a router")
+GLOBAL.describe("tpu_model_index_positions_total",
+                "Cached positions the decode steps of a model with latent "
+                "attention's indexer had before them (what=seen: a step of "
+                "a sequence of n positions, its new one counted, sees n) "
+                "and the positions its attention read of them (what=kept: "
+                "min(n, index_topk)), a layer, over the active slots: "
+                "from the host's lengths once a decode chunk, no device "
+                "work; kept = seen says the selection slept. Seeded when "
+                "the engine of such a model is built "
+                "(seed_index_positions), absent for other models")
 GLOBAL.describe("tpu_model_model_flops_total",
                 "Analytic model FLOPs issued for active slots (matmul "
                 "terms only, MFU convention of Chowdhery et al.); rate() "
@@ -715,6 +728,14 @@ def seed_expert_tokens(n_experts: int) -> None:
     for _e in range(n_experts):
         GLOBAL.inc("tpu_model_moe_expert_tokens_total", 0.0,
                    f'{{expert="{_e}"}}')
+
+
+def seed_index_positions() -> None:
+    """Both series at 0: the engine of a model with an indexer seeds them
+    when it is built."""
+    for _what in ("seen", "kept"):
+        GLOBAL.inc("tpu_model_index_positions_total", 0.0,
+                   f'{{what="{_what}"}}')
 
 
 for _queue in ("waiting", "empty"):

@@ -447,3 +447,43 @@ def test_delta_mixer_compiles_at_published_widths(one_chip, B, T):
     assert ("delta_update" in compiled.as_text()) == (T == 1)
     if T == 1:
         assert mem.temp_size_in_bytes < one_layer // 16
+
+
+@pytest.mark.parametrize("B,T,A", [(64, 1, 4096), (64, 1, 1024),
+                                   (1, 256, 4096)],
+                         ids=["decode-deep", "decode-shallow", "extend"])
+def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A):
+    """One latent-attention layer against the int8 cache as the served
+    programs run it (both caches donated), at GLM-5's widths: the decode
+    step at the bucket where the indexer chooses (a top-k of 2,048 of 4,096)
+    and at one where it does not, and an extend piece; the rows and the
+    indexer's keys are written in place."""
+    cfg = PRESETS["glm-5"]
+    La = 2
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    layers = jax.eval_shape(lambda k: decoder.init_params(cfg, k),
+                            jax.random.key(0))["layers"]
+    ap = {k: sds(v.shape[1:], v.dtype) for k, v in layers.items()
+          if k in decoder._ATTN_STACK}
+    _, kd, vd = cfg.cache_row_dims
+    kc = {"q": sds((La, B, 1, 4096, kd), jnp.int8),
+          "s": sds((La, B, 2, 4096), jnp.float32)}
+    vc = {"q": sds((La, B, 1, 4096, vd), jnp.int8),
+          "s": sds((La, B, 1, 4096), jnp.float32)}
+
+    def layer(ap, h, kc, vc, row, lengths, nv):
+        pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        cos, sin = decoder.rope_angles(pos, cfg.qk_rope_dim, cfg.rope_theta)
+        return decoder._latent_cached(cfg, ap, h, kc, vc, row, pos, nv, A,
+                                      cos, sin)
+
+    compiled = jax.jit(layer, donate_argnums=(2, 3)).lower(
+        ap, sds((B, T, cfg.dim), jnp.bfloat16), kc, vc, sds((), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    cache = La * B * 4096 * (kd + vd + 12)
+    assert mem.alias_size_in_bytes >= cache             # all four leaves
+    # the top-k is a sort of the bucket, there only where the indexer chooses
+    import re
+    assert bool(re.search(r"\bsort\(", compiled.as_text())) == (A > 2048)

@@ -27,7 +27,7 @@ SPAN_NAMES = [row[0] for row in SPAN_TABLE]
 # the scopes a dense (no MoE) model's step must carry, on every path
 DENSE_SCOPES = [s for s in DEVICE_SCOPES
                 if not s.startswith(("moe.", "ssm.", "conv.", "delta."))
-                and s != "attn.window"]
+                and s not in ("attn.window", "attn.index")]
 
 
 def _series(name, labels):
@@ -103,12 +103,13 @@ def test_stage_buckets_step_by_at_most_a_quarter_from_1ms_to_60s():
 
 
 def test_the_benchmark_reads_the_programs_vocabulary():
-    from benchmark import (conv_spans, delta_spans, ssm_spans, trace_spans,
-                           window_spans)
+    from benchmark import (conv_spans, delta_spans, index_spans, ssm_spans,
+                           trace_spans, window_spans)
     # the accepted reader knows the scopes the dense cells carry; each
-    # hybrid stack's mixer is read by the reader that came with it
+    # hybrid stack's mixer, and latent attention's indexer, is read by the
+    # reader that came with it
     lists = (trace_spans.SCOPES, ssm_spans.SCOPES, conv_spans.SCOPES,
-             window_spans.SCOPES, delta_spans.SCOPES)
+             window_spans.SCOPES, delta_spans.SCOPES, index_spans.SCOPES)
     assert set().union(*lists) == set(DEVICE_SCOPES)
     assert sum(map(len, lists)) == len(DEVICE_SCOPES)     # no scope twice
     assert all(n.startswith(trace_spans.SPAN_PREFIXES) for n in SPANS)
