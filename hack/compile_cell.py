@@ -18,7 +18,10 @@ goes on past one the compiler refuses, printing ``FAIL``: one shape of 56 can
 fail alone (``glm-5``'s ``decode.(32, 1024)`` did, in VMEM, PR 46), and the
 chip's warm plan would find it 15 minutes into a run. Several at once:
 ``... plan:decode``, ``plan:admit``, ``plan:admit_many``, ``plan:extend``.
-~4 min for a 16-layer routed model."""
+~4 min for a 16-layer routed model. With ``HLO_DIR`` in the environment each
+engine program's optimized HLO is written there (``<config>.<kind>.<key>.hlo.txt``:
+``hack/hlo_copies.py`` lists the loops and re-laying copies the chip will
+run, by the computation they run in)."""
 import os
 import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -47,6 +50,11 @@ def spy(self, kind, key, jit_fn, *args):
             raise
         print(f"FAIL {kind}.{key}: {str(e)[:400]!r}", flush=True)
         return None
+    if os.environ.get("HLO_DIR"):
+        os.makedirs(os.environ["HLO_DIR"], exist_ok=True)
+        with open(os.path.join(os.environ["HLO_DIR"],
+                               f"{name}.{kind}.{key}.hlo.txt"), "w") as f:
+            f.write(c.as_text())
     m = c.memory_analysis()
     print(f"{kind}.{key}: args {m.argument_size_in_bytes/GB:.3f} out {m.output_size_in_bytes/GB:.3f} "
           f"alias {m.alias_size_in_bytes/GB:.3f} temp {m.temp_size_in_bytes/GB:.3f} "

@@ -10,7 +10,8 @@ before the trace is removed writes what ``python -m benchmark.trace_spans``
 prints for it (per module: seconds by scope and the costliest operations; the
 idle by host span) to ``chiprun_out/<tag>.trace.json``; the device's costliest
 operations with their whole scope path (``tf_op``: whichever reader lists the
-scope) to ``chiprun_out/<tag>.ops.json``; the run's result line
+scope; the environment's ``OPS_N`` of them where set, 60 otherwise) to
+``chiprun_out/<tag>.ops.json``; the run's result line
 goes to ``chiprun_out/<tag>.result.json``; and ``TIMELINE_S`` seconds from the
 middle of the trace, laid out on one clock, go to
 ``chiprun_out/<tag>.timeline.json`` (the environment's ``TIMELINE_S`` where
@@ -34,6 +35,7 @@ os.chdir(tree)
 sys.path.insert(0, tree)
 rmtree = shutil.rmtree
 TIMELINE_S = float(os.environ.get("TIMELINE_S", "1.5"))
+OPS_N = int(os.environ.get("OPS_N", "60"))
 
 
 def timeline(trace_spans, path):
@@ -65,7 +67,7 @@ def timeline(trace_spans, path):
     return {"seconds": TIMELINE_S, "modules": cut(mods), "spans": cut(spans)}
 
 
-def costliest_ops(trace_spans, path, n=60):
+def costliest_ops(trace_spans, path, n=OPS_N):
     """The device's ``n`` costliest operations by self time over the whole
     trace, each with its count and its full ``tf_op`` (every scope the
     program gave it, whichever reader lists it): ``[seconds, count, name,

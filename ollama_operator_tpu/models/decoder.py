@@ -1185,7 +1185,8 @@ def _causal_conv(conv, row, new, w, n_valid, bias=None, act=None):
     act(bias + sum_j w[j] * in[t-K+1+j]), conv)."""
     K, T, f32 = w.shape[0], new.shape[1], jnp.float32
     prev = lax.dynamic_index_in_dim(conv, row, 0, keepdims=False)
-    cat = jnp.concatenate([prev, new.astype(f32)], axis=1)      # [B,T+K-1,C]
+    new = new.astype(f32)
+    cat = jnp.concatenate([prev, new], axis=1)                  # [B,T+K-1,C]
     w = w.astype(f32)
     out = None if bias is None else bias.astype(f32)
     for j in range(K):
@@ -1195,8 +1196,23 @@ def _causal_conv(conv, row, new, w, n_valid, bias=None, act=None):
         out = act(out)
     # the K-1 inputs before position n_valid: what the next call's
     # first positions look back on
-    prev = jax.vmap(lambda c, n: lax.dynamic_slice_in_dim(c, n, K - 1, 0)
-                    )(cat, n_valid)
+    if T == 1:
+        # a decode step: a row that takes the position keeps its inputs
+        # shifted by one, a row that sits out keeps them: a select over
+        # whole arrays, the same bits as the gather, which the compiler
+        # runs as a loop of one-row updates over the slots (2.10 ms of
+        # olmo's 17.73 ms step). Written position-major, the order the
+        # leaf lies in on the device: as ``where(.., cat[:, 1:], prev)``
+        # the compiler carried the leaf in another layout and re-laid a
+        # 398 MB weight stack in every delta layer (+11 ms a step: my chip
+        # run, PR 47; PERF.md section 6 has the forms tried)
+        held = jnp.swapaxes(prev, 0, 1)                         # [K-1, B, C]
+        shifted = jnp.concatenate([held[1:], jnp.swapaxes(new, 0, 1)], axis=0)
+        prev = jnp.swapaxes(
+            jnp.where((n_valid > 0)[None, :, None], shifted, held), 0, 1)
+    else:
+        prev = jax.vmap(lambda c, n: lax.dynamic_slice_in_dim(c, n, K - 1, 0)
+                        )(cat, n_valid)
     return out, lax.dynamic_update_index_in_dim(conv, prev, row, 0)
 
 

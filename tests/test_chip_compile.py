@@ -487,3 +487,30 @@ def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A):
     # the top-k is a sort of the bucket, there only where the indexer chooses
     import re
     assert bool(re.search(r"\bsort\(", compiled.as_text())) == (A > 2048)
+
+
+@pytest.mark.parametrize("name", ["granite-4.0-h-small", "lfm2-8b-a1b",
+                                  "olmo-hybrid-7b"])
+def test_a_decode_steps_convolution_loops_over_no_slots(one_chip, name):
+    """``decoder._causal_conv`` with one new position, at the three hybrid
+    cells' widths and 32 slots, the leaf donated: the inputs it carries are
+    advanced by a select over whole arrays, in place, and the compiled
+    program holds no ``while``: the gather a row, which a prefill piece
+    keeps, compiled at Olmo-Hybrid-7B's 11,520 channels to a loop of 32
+    one-row ``dynamic-update-slice``s a layer, the decode step's largest
+    single operation (2.10 ms of 17.73: PERF.md section 6, PR 47)."""
+    cfg = PRESETS[name]
+    _, conv, _ = jax.eval_shape(lambda: decoder.empty_state(cfg, 32))
+    L, B, Km1, C = conv.shape
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda conv, row, new, w, nv: decoder._causal_conv(
+            conv, row, new, w, nv, act=jax.nn.silu), donate_argnums=(0,)
+    ).lower(sds(conv.shape, conv.dtype), sds((), jnp.int32),
+            sds((B, 1, C), jnp.bfloat16), sds((Km1 + 1, C), jnp.bfloat16),
+            sds((B,), jnp.int32)).compile()
+    assert " while(" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= conv.size * 4         # in place
+    assert mem.temp_size_in_bytes < conv.size * 4 // L * 4  # a few rows
