@@ -9,11 +9,18 @@ of the shapes it takes (:func:`paged_decode_tileable`). It is the
 (ledger, PRs 25-30); a shape it refuses is served by gather + einsum
 (``models/decoder._paged_attend``) and flagged ``kernel_fallback``.
 
-- **One grid step per slot, a walk over its live pages.** Tables, per-slot
-  lengths and the layer index are prefetched scalars
+- **One grid step per slot, ONE walk over the batch's live pages.** Tables,
+  per-slot lengths and the layer index are prefetched scalars
   (``PrefetchScalarGridSpec``); the pools stay in HBM and the kernel copies
-  ``pool[layer, table[b, i]]`` into VMEM itself (``make_async_copy``), page
-  ``i + 1`` in flight while page ``i`` is scored. A 100-token slot in a
+  ``pool[layer, table[b, i]]`` into VMEM itself (``make_async_copy``), up
+  to three pages in flight while page ``i`` is scored (``_walk_depth``: as
+  many buffers as the page's bytes leave room for). The walk does not
+  drain at a slot's end: after slot ``b``'s last page the next copy is
+  slot ``b + 1``'s first live page, started in ``b``'s grid step and waited
+  for in the next, so buffers, semaphores and the walk's four words of
+  state are scratch that outlives a grid step: a batch of short slots
+  (starcoder2's cell: 1,920 slot visits a step, two pages each) never
+  waits for a first page with nothing in flight. A 100-token slot in a
   4096-token bucket reads two pages, not the bucket, and its dead blocks
   cost no grid step. The full ``[L, ...]`` pool is the operand: no
   per-layer slice is ever materialised.
@@ -75,9 +82,21 @@ def _pool_arrs(k_pool, v_pool):
     return quant, quant4, k_arr, v_arr
 
 
-# pages in flight ahead of the flash update: the classic double buffer
-# (BASELINE.md r5 measured 4 neutral, at twice the page buffers in VMEM)
-_DEPTH = 2
+# What the walk keeps in VMEM, read from the shapes alone: as many (k, v,
+# scales) page sets as _PAGE_BUFFER_BYTES hold, between the double buffer
+# and _MAX_DEPTH. depth - 1 pages are in flight ahead of the flash update,
+# ACROSS slots (BASELINE.md r5's "4 neutral" was of a deeper queue inside
+# one slot, where a slot of one or two pages has nothing more to fetch).
+# PERF.md section 6, PR 48: four buffers are worth 14% of phi-2's visit over
+# two (557 KB a set) and 5% of starcoder2's (66 KB); three read slower than
+# two there (a remainder by 3 in the page loop), and no small page gets three.
+_PAGE_BUFFER_BYTES = 4 << 20
+_MAX_DEPTH = 4
+
+
+def _walk_depth(page_bytes: int) -> int:
+    """Buffers for a page set of ``page_bytes`` (k + v + scales, as stored)."""
+    return max(2, min(_MAX_DEPTH, _PAGE_BUFFER_BYTES // page_bytes))
 
 
 def paged_decode_tileable(H: int, k_pool, interpret: bool) -> bool:
@@ -140,95 +159,114 @@ def _flash_page_update(qv, kb, vb, ksc, vsc, m_ref, l_ref, acc_ref, *,
 def _paged_kernel(lay_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, *rest,
                   scale: float, softcap: float, window: int,
                   ps: int, kvh: int, gp: int, cdt,
-                  quant: bool, quant4: bool):
-    """One grid step per SLOT: walk the slot's LIVE pages (those inside
-    the window, if any) with a double-buffered manual DMA, one
-    :func:`_flash_page_update` a page.
+                  quant: bool, quant4: bool, depth: int):
+    """One grid step per SLOT, one walk over the LIVE pages (those inside
+    the window, if any) of the whole batch: the copies run ``depth - 1``
+    pages ahead of the :func:`_flash_page_update` that scores them and do
+    not stop at a slot's last page: the next page in flight is then slot
+    ``b + 1``'s first, started in this grid step and waited for in the next.
 
     Refs (in order): prefetched lay/len/tbl scalars; q [1, KvH, Gp, hd]
     VMEM block; k/v pools ([L, P, KvH, ps, hd], HBM, copied by hand);
     with ``quant`` the k/v scale pools ([L, P, KvH, 1, sp] f32, HBM); the
-    output block; then scratch: kbuf/vbuf [2, KvH, ps, hd], (ksbuf/vsbuf
-    [2, KvH, 1, sp],) acc [KvH, Gp, hd] f32, m/l [KvH, Gp, 1] f32, sem.
+    output block; then scratch, all of which outlives a grid step:
+    kbuf/vbuf [depth, KvH, ps, hd], (ksbuf/vsbuf [depth, KvH, 1, sp],)
+    acc [KvH, Gp, hd] f32, m/l [KvH, Gp, 1] f32, sem, and the walk's state
+    in SMEM: slot and page of the next copy to start, that slot's last
+    page + 1, and the count of pages scored so far, whose remainder by
+    ``depth`` is the buffer in turn.
     """
     if quant:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf,
-         acc_ref, m_ref, l_ref, sem) = rest
+         acc_ref, m_ref, l_ref, sem, walk) = rest
     else:
-        o_ref, kbuf, vbuf, acc_ref, m_ref, l_ref, sem = rest
+        o_ref, kbuf, vbuf, acc_ref, m_ref, l_ref, sem, walk = rest
         ks_hbm = vs_hbm = ksbuf = vsbuf = None
     b = pl.program_id(0)
+    nslots = pl.num_programs(0)
     lay = lay_ref[0]
-    qp = len_ref[b]                          # query's absolute position
-    nlive = qp // ps + 1                     # pages covering [0, qp]
-    start = jnp.int32(0)
-    if window:
-        # first block holding a key inside the window (older positions in
-        # that block are masked off below)
-        start = jnp.maximum(start, (qp - window + 1) // ps)
 
-    def start_dma(i, slot):
-        pg = tbl_ref[b, i]
-        pltpu.make_async_copy(k_hbm.at[lay, pg], kbuf.at[slot],
-                              sem.at[0, slot]).start()
-        pltpu.make_async_copy(v_hbm.at[lay, pg], vbuf.at[slot],
-                              sem.at[1, slot]).start()
+    def live(slot):
+        """(first, one past the last) live page of ``slot``: the pages
+        covering [0, qp], from the first block that holds a key inside the
+        window (older positions in that block are masked off below).
+        Positions are not negative, so the truncating ``lax.div`` is the
+        floor, at a twentieth of ``//``'s scalar operations: the walk
+        computes this once a page."""
+        qp = len_ref[slot]                   # query's absolute position
+        first = jnp.int32(0)
+        if window:
+            first = jax.lax.div(jnp.maximum(qp - window + 1, 0), ps)
+        return first, jax.lax.div(qp, ps) + 1
+
+    def copies(pg, buf):
+        yield pltpu.make_async_copy(k_hbm.at[lay, pg], kbuf.at[buf],
+                                    sem.at[0, buf])
+        yield pltpu.make_async_copy(v_hbm.at[lay, pg], vbuf.at[buf],
+                                    sem.at[1, buf])
         if quant:
-            pltpu.make_async_copy(ks_hbm.at[lay, pg], ksbuf.at[slot],
-                                  sem.at[2, slot]).start()
-            pltpu.make_async_copy(vs_hbm.at[lay, pg], vsbuf.at[slot],
-                                  sem.at[3, slot]).start()
+            yield pltpu.make_async_copy(ks_hbm.at[lay, pg], ksbuf.at[buf],
+                                        sem.at[2, buf])
+            yield pltpu.make_async_copy(vs_hbm.at[lay, pg], vsbuf.at[buf],
+                                        sem.at[3, buf])
 
-    def wait_dma(i, slot):
-        pg = tbl_ref[b, i]
-        pltpu.make_async_copy(k_hbm.at[lay, pg], kbuf.at[slot],
-                              sem.at[0, slot]).wait()
-        pltpu.make_async_copy(v_hbm.at[lay, pg], vbuf.at[slot],
-                              sem.at[1, slot]).wait()
-        if quant:
-            pltpu.make_async_copy(ks_hbm.at[lay, pg], ksbuf.at[slot],
-                                  sem.at[2, slot]).wait()
-            pltpu.make_async_copy(vs_hbm.at[lay, pg], vsbuf.at[slot],
-                                  sem.at[3, slot]).wait()
+    def fetch(fb, fi, fend, buf):
+        """Start the copies of slot ``fb``'s page ``fi`` (nothing once the
+        batch's last page is on its way) and step to the pair after it:
+        every slot has a live page, so a slot's last (``fend - 1``) is
+        followed by the next slot's first."""
+        @pl.when(fb < nslots)
+        def _start():
+            for c in copies(tbl_ref[fb, fi], buf):
+                c.start()
 
+        nb = fb + 1
+        first, nlive = live(jnp.minimum(nb, nslots - 1))   # rows end there
+        last = fi + 1 >= fend
+        return (jnp.where(last, nb, fb), jnp.where(last, first, fi + 1),
+                jnp.where(last, nlive, fend))
+
+    @pl.when(b == 0)
+    def _prime():
+        # depth - 1 pages in flight before the first wait, once a call
+        state = (jnp.int32(0), *live(0))
+        for j in range(depth - 1):
+            state = fetch(*state, j)
+        walk[0], walk[1], walk[2], walk[3] = *state, jnp.int32(0)
+
+    qp = len_ref[b]
+    first, nlive = live(b)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
-    # prologue: _DEPTH - 1 pages in flight before the first wait
-    for j in range(_DEPTH - 1):
-        @pl.when(start + j < nlive)
-        def _prime(j=j):
-            start_dma(start + j, (start + j) % _DEPTH)
-
     qv = q_ref[0]                            # [KvH, Gp, hd]
 
-    def body(i, _):
-        slot = i % _DEPTH
-
-        @pl.when(i + _DEPTH - 1 < nlive)
-        def _prefetch():
-            start_dma(i + _DEPTH - 1, (i + _DEPTH - 1) % _DEPTH)
-
-        wait_dma(i, slot)
-        # scale buffers are 4-D [2, KvH, 1, sp] (a 3-D buffer's
+    def body(i, carry):
+        *ahead, n = carry
+        ahead = fetch(*ahead, jax.lax.rem(n + depth - 1, depth))
+        buf = jax.lax.rem(n, depth)
+        for c in copies(0, buf):             # a wait reads shape and sem
+            c.wait()
+        # scale buffers are 4-D [depth, KvH, 1, sp] (a 3-D buffer's
         # dynamic-slot load lowers as an unsupported gather) and
         # lane-padded to sp >= ps (Mosaic DMA tile rule); the unit axis
         # is the broadcast axis and only the live ps lanes multiply
-        kb, vb = kbuf[slot], vbuf[slot]
+        kb, vb = kbuf[buf], vbuf[buf]
         if quant4:
             # pages land nibble-packed [KvH, ps//2, hd]; unpack after the
             # (half-width) DMA so HBM traffic stays at int4
             kb, vb = _unpack4(kb), _unpack4(vb)
         _flash_page_update(
             qv, kb, vb,
-            ksbuf[slot][:, :, :ps] if quant else None,
-            vsbuf[slot][:, :, :ps] if quant else None,
+            ksbuf[buf][:, :, :ps] if quant else None,
+            vsbuf[buf][:, :, :ps] if quant else None,
             m_ref, l_ref, acc_ref,
             k_start=i * ps, qp=qp, scale=scale, softcap=softcap,
             window=window, ps=ps, kvh=kvh, gp=gp, cdt=cdt)
-        return 0
+        return (*ahead, n + 1)
 
-    jax.lax.fori_loop(start, nlive, body, 0)
+    walk[0], walk[1], walk[2], walk[3] = jax.lax.fori_loop(
+        first, nlive, body, (walk[0], walk[1], walk[2], walk[3]))
     out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
     o_ref[0] = out.astype(o_ref.dtype)   # [KvH, Gp, hd] — caller reshapes
 
@@ -269,6 +307,13 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
         # inert in the score dot and the pad outputs are sliced off below)
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, hd - hd_q)))
 
+    page_bytes = KvH * psq * hd * (k_arr.dtype.itemsize
+                                   + v_arr.dtype.itemsize)
+    if quant:
+        sp = k_pool["s"].shape[-1]
+        page_bytes += 2 * KvH * sp * 4
+    depth = _walk_depth(page_bytes)
+
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     in_specs = [
         pl.BlockSpec((1, KvH, Gp, hd), lambda b, *pref: (b, 0, 0, 0)),
@@ -276,26 +321,27 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     ]
     args = [qg, k_arr, v_arr]
     scratch = [
-        pltpu.VMEM((_DEPTH, KvH, psq, hd), k_arr.dtype),
-        pltpu.VMEM((_DEPTH, KvH, psq, hd), v_arr.dtype),
+        pltpu.VMEM((depth, KvH, psq, hd), k_arr.dtype),
+        pltpu.VMEM((depth, KvH, psq, hd), v_arr.dtype),
     ]
     if quant:
-        sp = k_pool["s"].shape[-1]
         in_specs += [hbm, hbm]
         args += [k_pool["s"].reshape(L, P, KvH, 1, -1).astype(jnp.float32),
                  v_pool["s"].reshape(L, P, KvH, 1, -1).astype(jnp.float32)]
-        scratch += [pltpu.VMEM((_DEPTH, KvH, 1, sp), jnp.float32),
-                    pltpu.VMEM((_DEPTH, KvH, 1, sp), jnp.float32)]
+        scratch += [pltpu.VMEM((depth, KvH, 1, sp), jnp.float32),
+                    pltpu.VMEM((depth, KvH, 1, sp), jnp.float32)]
     scratch += [
         pltpu.VMEM((KvH, Gp, hd), jnp.float32),
         pltpu.VMEM((KvH, Gp, 1), jnp.float32),
         pltpu.VMEM((KvH, Gp, 1), jnp.float32),
-        pltpu.SemaphoreType.DMA((4 if quant else 2, _DEPTH)),
+        pltpu.SemaphoreType.DMA((4 if quant else 2, depth)),
+        pltpu.SMEM((4,), jnp.int32),
     ]
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, softcap=softcap, window=sliding_window,
-        ps=ps, kvh=KvH, gp=Gp, cdt=cdt, quant=quant, quant4=quant4)
+        ps=ps, kvh=KvH, gp=Gp, cdt=cdt, quant=quant, quant4=quant4,
+        depth=depth)
     out = pl.pallas_call(
         kernel,
         name="paged_v3",       # the ledger's name for it since PR 25
@@ -308,6 +354,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KvH, Gp, hd), q.dtype),
+        # sequential: copies, semaphores and the walk's state cross steps
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,

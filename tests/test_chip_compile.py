@@ -294,6 +294,34 @@ def _cell_pool(one_chip, name):
 
 
 @pytest.mark.parametrize("name", sorted(CELL_POOLS))
+def test_paged_decode_kernel_compiles_at_the_cells_pools(one_chip, name):
+    """The walk alone at each dense cell's resolved pool, the model's own
+    window (starcoder2's 4096 compiles the branch that starts a slot's
+    walk late): its page buffers, as deep as the page's bytes make them
+    (phi-2's page set is 557 KB, starcoder2's 66 KB), fit the kernel's
+    VMEM, and copies that cross a grid step are Mosaic's to take."""
+    from ollama_operator_tpu.ops.pallas.paged import _walk_depth
+    cfg, B, ps, pool, _ = _cell_pool(one_chip, name)
+    nblk = 2048 // ps
+    KvH = cfg.n_kv_heads
+    assert _walk_depth(2 * KvH * ps * 128 + 2 * KvH * 128 * 4) == 4
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    q = sds((B, 1, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+
+    def fn(q, kp, vp, layer, tables, lengths):
+        out = paged_decode_attention(
+            q, kp, vp, layer, tables, lengths, 1.0, cfg.attn_softcap,
+            cfg.sliding_window, nblk=nblk)
+        assert out is not None, "refused the cell's own pool layout"
+        return out
+
+    txt = _compiled_text(fn, q, pool, pool, sds((), jnp.int32),
+                         sds((B, nblk), jnp.int32), sds((B,), jnp.int32))
+    assert "paged_v3" in txt
+
+
+@pytest.mark.parametrize("name", sorted(CELL_POOLS))
 def test_decode_write_in_the_layer_scan_copies_no_pool(one_chip, name):
     """The decode program's writer as the step runs it: inside the layer
     scan, the pools donated, the v3 attention reading them in the same

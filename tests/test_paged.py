@@ -409,6 +409,118 @@ def test_paged_kernel_window_over_an_int8_pool():
                                    rtol=2e-5, atol=2e-5, err_msg=f"win={win}")
 
 
+# the walk's seams (PR 48): the kernel's copies run ahead of the scoring
+# across slots and across grid steps, so what follows what matters ---------
+
+# query positions of 12 slots over 8-token pages, four pages a slot at most
+_SEAMS = {
+    "one-page-slots-around-many-page-slots":
+        [3, 30, 2, 31, 5, 29, 1, 25, 7, 7, 31, 0],
+    "a-pages-last-and-first-position":
+        [7, 8, 15, 16, 23, 24, 31, 0, 7, 8, 15, 16],
+    "free-slots-between-live-ones":
+        [9, 0, 17, 0, 0, 25, 0, 31, 0, 0, 0, 12],
+    "the-grids-last-slot-is-the-longest":
+        [0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 31],
+    "every-slot-one-page": [0, 1, 2, 3, 4, 5, 6, 7, 0, 3, 5, 7],
+    "every-slot-every-page": [24, 31, 25, 30, 26, 29, 27, 28, 31, 24, 31, 24],
+    # under a window of 11 the walk starts past page 0 (start > 0) in the
+    # slots at 20 and more, and at page 0 in their neighbours
+    "the-window-starts-late-in-every-other-slot":
+        [4, 26, 9, 31, 2, 20, 10, 23, 0, 30, 5, 27],
+}
+_SEAM_POOLS = {"plain": False, "int8": True, "int4": 4}
+
+
+_SEAM_FNS = {}
+
+
+def _seam_fns(pool, kvh, h, window, B, depth=None):
+    """(kernel, reference, page size) for one pool kind and head layout,
+    compiled once and fed every seam's lengths: ``B`` slots of up to four
+    8-token (int4: 16-token) pages, distinct pages in a shuffled table;
+    ``window`` counts in 8-token pages' positions, as ``_SEAMS`` does.
+    ``depth`` overrules the kernel's own rule while it is traced."""
+    from ollama_operator_tpu.ops.pallas import paged as PG
+    key = (pool, kvh, h, window, B, depth)
+    if key not in _SEAM_FNS:
+        quant = _SEAM_POOLS[pool]
+        L, nblk, hd = 2, 4, 128
+        ps = 16 if quant == 4 else 8
+        window *= ps // 8
+        kp, vp = _rand_pool(jax.random.key(20), L, B * nblk + 1, kvh, ps, hd,
+                            quant)
+        q = jax.random.normal(jax.random.key(21), (B, 1, h, hd), jnp.float32)
+        tables = jnp.asarray(
+            np.random.default_rng(1).permutation(np.arange(1, B * nblk + 1))
+            .reshape(B, nblk), jnp.int32)
+
+        def kern(layer, lengths):
+            with pytest.MonkeyPatch.context() as mp:
+                if depth is not None:
+                    mp.setattr(PG, "_walk_depth", lambda page_bytes: depth)
+                return PG.paged_decode_attention(
+                    q, kp, vp, layer, tables, lengths, scale=0.3,
+                    sliding_window=window, nblk=nblk, interpret=True)
+
+        def ref(layer, lengths):
+            return _gather_einsum(q, kp, vp, layer, tables, lengths, 0.3,
+                                  nblk, window=window)
+        _SEAM_FNS[key] = jax.jit(kern), jax.jit(ref), ps
+    return _SEAM_FNS[key]
+
+
+def _seam_lengths(seam, ps, B=12):
+    return jnp.asarray(_SEAMS[seam][:B], jnp.int32) * (ps // 8)
+
+
+@pytest.mark.parametrize("seam", sorted(_SEAMS))
+@pytest.mark.parametrize("kvh,h", [(2, 8), (4, 4)])   # GQA and MHA
+@pytest.mark.parametrize("pool", sorted(_SEAM_POOLS))
+def test_paged_kernel_walk_crosses_slots_and_grid_steps(pool, kvh, h, seam):
+    """One pipeline over the batch: a slot's first pages are fetched in its
+    predecessors' grid steps, while those are scored. Every seam, every
+    pool kind, both head layouts, with and without a window."""
+    window = 11 if "window" in seam else 0
+    kern, ref, ps = _seam_fns(pool, kvh, h, window, 12)
+    lengths = _seam_lengths(seam, ps)
+    layer = jnp.asarray([1], jnp.int32)
+    np.testing.assert_allclose(np.asarray(kern(layer, lengths)),
+                               np.asarray(ref(layer, lengths)),
+                               rtol=2e-5, atol=2e-5)
+
+
+# (slots in the batch, buffers): one slot alone; the double buffer, three
+# and four buffers (the copies then run up to three SLOTS ahead here)
+@pytest.mark.parametrize("B,depth", [(1, None), (2, 4), (11, 2), (12, 2),
+                                     (12, 3), (12, 4)])
+@pytest.mark.parametrize("seam", ["one-page-slots-around-many-page-slots",
+                                  "the-window-starts-late-in-every-other-slot"])
+def test_paged_kernel_walk_at_every_depth(seam, B, depth):
+    window = 11 if "window" in seam else 0
+    kern, ref, ps = _seam_fns("int8", 2, 8, window, B, depth)
+    lengths = _seam_lengths(seam, ps, B)
+    layer = jnp.asarray([0], jnp.int32)
+    np.testing.assert_allclose(np.asarray(kern(layer, lengths)),
+                               np.asarray(ref(layer, lengths)),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_kernel_twice_in_one_program_shares_nothing():
+    """Two layers of one jitted step: the second call's walk starts from
+    its own first page with every semaphore at rest, whatever the first
+    call's last slot left in the buffers."""
+    kern, ref, ps = _seam_fns("int8", 2, 8, 0, 12)
+    a = _seam_lengths("the-grids-last-slot-is-the-longest", ps)
+    b = _seam_lengths("one-page-slots-around-many-page-slots", ps)
+    l0, l1 = jnp.asarray([0], jnp.int32), jnp.asarray([1], jnp.int32)
+    both = jax.jit(lambda: (kern(l0, a), kern(l1, b), kern(l0, b)))()
+    for got, (layer, lengths) in zip(both, [(l0, a), (l1, b), (l0, b)]):
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(ref(layer, lengths)),
+                                   rtol=2e-5, atol=2e-5)
+
+
 # shapes Mosaic's copies cannot take, as a chip would meet them (the mode is
 # "pallas": the refusal comes before anything is lowered, so this runs here)
 _REFUSED = {
