@@ -46,11 +46,10 @@ bench:  ## headline decode-throughput benchmark (one JSON line)
 # BENCH_XLA_CACHE=0: the CPU-backend persistent-cache deserialization
 # path is unstable on some hosts (wrong tokens, then a native crash) —
 # tiny smoke programs recompile in seconds anyway
-bench-smoke:  ## seconds-scale CPU bench: engine + HTTP + mixed + prefix + spec + overload + restart + coldstart + fused-paged + disagg arms
+bench-smoke:  ## seconds-scale CPU bench: engine + HTTP + mixed + prefix + overload + restart + coldstart + fused-paged + disagg arms
 	JAX_PLATFORMS=cpu BENCH_HTTP=1 BENCH_MIXED_ARM=1 \
 	  BENCH_PREFIX_ARM=1 BENCH_TIER_ARMS=1 \
 	  BENCH_PAGED_ASYNC_ARM=1 BENCH_PAGED_FUSED_ARM=1 \
-	  BENCH_SPEC_ARM=1 \
 	  BENCH_OVERLOAD_ARM=1 BENCH_RESTART_ARM=1 BENCH_COLDSTART_ARM=1 \
 	  BENCH_DISAGG_ARM=1 BENCH_ASSERT_DISAGG=1 \
 	  BENCH_ASSERT_COLDSTART=1 BENCH_XLA_CACHE=0 \

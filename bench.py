@@ -428,226 +428,6 @@ def measure(jax, *, model: str, dtype: str, slots: int, steps: int,
     return rec
 
 
-def measure_spec(jax, *, model: str, dtype: str, slots: int, steps: int,
-                 seq: int, prompt_len: int, paged: bool, mixed: bool,
-                 chunk: int, page_size: int, n_pages: int | None,
-                 platform: str, params_cache: dict | None = None,
-                 env: dict | None = None, spec_k: int = 4) -> dict:
-    """Fused speculative-decoding arm (ISSUE 6): greedy slots driven
-    through the ONE production dispatch surface —
-    ``decode_n_launch(drafts=)`` + ``wait`` + ``spec_ack`` — on a
-    repetition-heavy workload, in three sub-arms:
-
-      lookup     — real prompt-lookup drafts (runtime/drafter.py), the
-                   number the serving default is decided from
-      accept_all — oracle drafts replayed from the recorded baseline
-                   continuation: the scheme's ceiling
-      reject_all — garbage drafts: its floor, pure dispatch overhead
-
-    A chunk dispatch advances `chunk` steps sequentially; a spec dispatch
-    scores k+1 positions in ONE forward, so ms_per_dispatch vs the
-    baseline dispatch separates "the spec program is slow" from "the
-    model forward dominates" — the CI gate asserts the lookup arm stays
-    within 1.2x of the baseline dispatch AND beats its tok/s."""
-    import gc
-
-    from ollama_operator_tpu.models.config import get_config
-    from ollama_operator_tpu.runtime import drafter
-    from ollama_operator_tpu.runtime.engine import (Engine, EngineConfig,
-                                                    SlotOptions,
-                                                    resolve_cache_dtype)
-
-    on_cpu = platform == "cpu"
-    if on_cpu:
-        dtype = "float32"
-    kv_dtype = resolve_cache_dtype(
-        os.environ.get("BENCH_KV_DTYPE", "float32" if on_cpu else "int8"))
-    cfg = get_config(model)
-    log(f"bench: SPEC capture model={model} dtype={dtype} slots={slots} "
-        f"k={spec_k}")
-    params, param_bytes, dtype = _bench_params(
-        jax, cfg, model, dtype, on_cpu, params_cache)
-    eng = Engine(cfg, params,
-                 ecfg=EngineConfig(max_slots=slots, max_seq_len=seq,
-                                   decode_chunk=chunk,
-                                   cache_dtype=kv_dtype))
-    greedy = SlotOptions(temperature=0.0, repeat_penalty=1.0)
-    k = spec_k
-    prompt_len = min(prompt_len, eng.max_seq // 2)
-    calls = max(1, steps // chunk)
-    # the whole run must fit the context: prompt + first token + warm
-    # chunk + measured steps + the transient k+1 launch over-advance
-    if prompt_len + 1 + chunk + calls * chunk + k + 2 > eng.max_seq:
-        steps = max(chunk, (eng.max_seq - prompt_len - chunk - k - 3)
-                    // chunk * chunk)
-        calls = max(1, steps // chunk)
-        log(f"bench: clamping spec steps to {steps} to fit context "
-            f"{eng.max_seq}")
-    n_steps = calls * chunk
-    rng = np.random.default_rng(0)
-    # repetition-heavy workload — the regime prompt-lookup targets
-    # (code, JSON, summarisation): each slot's prompt cycles a short
-    # random pattern, so the drafter finds its first match immediately
-    # and greedy continuations stay periodic
-    pats = [rng.integers(1, cfg.vocab_size, size=8,
-                         endpoint=False).astype(np.int32)
-            for _ in range(slots)]
-    prompts = [np.tile(p, prompt_len // len(p) + 1)[:prompt_len]
-               for p in pats]
-
-    def admit_all():
-        return [int(eng.admit(s, prompts[s], greedy))
-                for s in range(slots)]
-
-    firsts = admit_all()
-    # warm every program the timed loops can touch: chunk programs for
-    # the reachable buckets, and the spec verify program per bucket —
-    # a bucket crossing mid-run must swap executables, never compile
-    # (the BENCH_r05 623ms/spec-dispatch anomaly)
-    ctx_lo, ctx_hi = prompt_len, prompt_len + 1 + chunk + n_steps + k + 2
-    eng.warm_buckets(ctx_lo=ctx_lo, ctx_hi=ctx_hi, full=False)
-    if eng._bucketed_attn:
-        lo = eng.bucket_for(min(ctx_lo + chunk, eng.max_seq))
-        hi = eng.bucket_for(min(ctx_hi, eng.max_seq))
-        spec_buckets = [b for b in eng._buckets if lo <= b <= hi] or [hi]
-    else:
-        spec_buckets = [eng.max_seq]
-    for b in spec_buckets:
-        eng._spec_exec(k, b)
-    # record the true greedy continuation — the accept_all draft oracle —
-    # and time the plain decode_n baseline on the same work
-    eng.decode_n()                      # first-dispatch runtime setup
-    t0 = time.perf_counter()
-    recs = [eng.decode_n() for _ in range(calls)]
-    base_dt = time.perf_counter() - t0
-    base_tok_s = n_steps * slots / base_dt
-    # continuation per slot, starting right after the warm chunk
-    cont = np.concatenate(recs, axis=0).T          # [B, n_steps]
-
-    def run_spec(make_arm, label):
-        for s in range(slots):
-            eng.release(s)
-        first = admit_all()
-        warm = eng.decode_n()           # same warm chunk → positions align
-        draft_fn, feed = make_arm(first, warm)
-        pos = np.zeros(slots, np.int64)
-        drafted_tot = accepted_tot = dispatches = 0
-        t0 = time.perf_counter()
-        while pos.min() < n_steps and dispatches < 4 * n_steps:
-            drafts, drafted = draft_fn(pos)
-            h = eng.decode_n_launch(drafts=drafts)
-            toks = h.wait()                        # [k+1, B]
-            rollback = np.maximum(h.budgets - h.accepted, 0)
-            if rollback.any():
-                eng.spec_ack(rollback)
-            emit = h.accepted.astype(np.int64)     # tokens emitted/slot
-            pos += emit
-            drafted_tot += int(drafted.sum())
-            accepted_tot += int(np.minimum(np.maximum(emit - 1, 0),
-                                           drafted).sum())
-            if feed is not None:
-                feed(toks)
-            dispatches += 1
-        dt = time.perf_counter() - t0
-        emitted = int(pos.sum())
-        # utilization: every spec dispatch runs all slots over k+1
-        # positions; useful = tokens that advanced streams, the rest of
-        # the issued grid (rejected drafts) is waste. FLOPs estimated at
-        # the mid-run context (exact would need per-dispatch ctx capture)
-        from ollama_operator_tpu.runtime.accounting import spec_verify_flops
-        issued = float(dispatches * slots * (k + 1))
-        ctx_mid = int(prompt_len + 1 + chunk + emitted / (2 * slots))
-        flops = dispatches * slots * spec_verify_flops(cfg, ctx_mid, k)
-        rec = {"label": label, "tok_s": round(emitted / dt, 2),
-               "dispatches": dispatches,
-               "ms_per_dispatch": round(dt / max(dispatches, 1) * 1e3, 2),
-               "tokens_per_dispatch": round(emitted / max(dispatches, 1),
-                                            2),
-               "acceptance_rate": round(accepted_tot / drafted_tot, 4)
-               if drafted_tot else 0.0,
-               "utilization": _analytic_utilization(
-                   cfg, dt_s=dt, flops=flops, useful=float(emitted),
-                   issued=issued)}
-        log(f"bench: spec {label}: {json.dumps(rec)}")
-        return rec
-
-    def lookup_arm(first, warm):
-        # per-slot incremental bigram index over prompt + emitted stream,
-        # exactly what Scheduler._lookup_draft maintains per request
-        hists = [list(map(int, prompts[s])) + [first[s]]
-                 + [int(t) for t in warm[:, s]] for s in range(slots)]
-        idxs = [{} for _ in range(slots)]
-        upto = [0] * slots
-
-        def draft_fn(pos):
-            d = np.zeros((slots, k), np.int32)
-            dr = np.zeros(slots, np.int32)
-            for b in range(slots):
-                prop, upto[b] = drafter.propose(hists[b], idxs[b],
-                                                upto[b], k)
-                if prop:
-                    d[b, :len(prop)] = prop
-                    dr[b] = len(prop)
-            return d, dr
-
-        def feed(toks):
-            for b in range(slots):
-                hists[b] += [int(t) for t in toks[:, b]
-                             if int(t) < cfg.vocab_size]
-        return draft_fn, feed
-
-    def oracle_arm(first, warm):
-        def draft_fn(pos):
-            d = np.zeros((slots, k), np.int32)
-            for b in range(slots):
-                seg = cont[b, int(pos[b]):int(pos[b]) + k]
-                d[b, :len(seg)] = seg
-            return d, np.full(slots, k, np.int32)
-        return draft_fn, None
-
-    def junk_arm(first, warm):
-        def draft_fn(pos):
-            return (np.full((slots, k), cfg.vocab_size - 1, np.int32),
-                    np.full(slots, k, np.int32))
-        return draft_fn, None
-
-    lookup = run_spec(lookup_arm, "lookup")
-    best = run_spec(oracle_arm, "accept_all")
-    worst = run_spec(junk_arm, "reject_all")
-    base_ms_per_dispatch = round(base_dt / calls * 1e3, 2)
-    dispatch_ratio = round(
-        lookup["ms_per_dispatch"] / max(base_ms_per_dispatch, 1e-9), 3)
-    rec = {
-        "model": model,
-        "mode": f"spec_fused_k{k}",
-        "tok_s": lookup["tok_s"],          # headline: the REAL drafter
-        "baseline_tok_s": round(base_tok_s, 2),
-        "baseline_ms_per_dispatch": base_ms_per_dispatch,
-        "lookup": lookup,
-        "accept_all": best,
-        "reject_all": worst,
-        "spec_acceptance": lookup["acceptance_rate"],
-        "speedup": round(lookup["tok_s"] / base_tok_s, 3),
-        "speedup_ceiling": round(best["tok_s"] / base_tok_s, 3),
-        "overhead_floor": round(worst["tok_s"] / base_tok_s, 3),
-        # per-dispatch: a spec verify (ONE forward over k+1 positions)
-        # vs a chunk dispatch (`chunk` sequential forwards) — must stay
-        # near or below 1.0; >= 2.0 means launch overhead, not compute
-        "dispatch_ratio": dispatch_ratio,
-        # headline utilization follows the headline arm (the real drafter)
-        "utilization": lookup.get("utilization"),
-        "slots": slots, "steps": n_steps, "dtype": dtype,
-        "decode_chunk": chunk, "spec_k": k,
-        "prompt_len": prompt_len,
-    }
-    if env:
-        rec["env"] = dict(env)
-    log(f"bench: spec capture done: {json.dumps(rec)}")
-    del eng, params
-    gc.collect()
-    return rec
-
-
 def _bench_tokenizer(vocab_size: int):
     """A byte-fallback llama tokenizer over a synthetic vocab: any prompt
     text encodes (one byte token per char), so the HTTP capture's prompt
@@ -3289,16 +3069,6 @@ def main() -> None:
             # to the unified references, real KV pages moved, and
             # async_fallback_total 0. BENCH_ASSERT_DISAGG=1 gates on it
             plan.append({**smoke, "disagg_arm": True, "slots": 2})
-        if os.environ.get("BENCH_SPEC_ARM", "") == "1":
-            # fused prompt-lookup speculation (ISSUE 6): lookup /
-            # accept_all / reject_all sub-arms on a repetition-heavy
-            # workload vs the chunked-decode baseline — the summary's
-            # spec_* ratios gate per-dispatch cost and tok/s speedup.
-            # The arm needs enough steps that the drafter's warm-up miss
-            # phase (before the greedy stream settles into its loop)
-            # amortises — short runs under-report the steady-state win.
-            plan.append({**smoke, "spec": True,
-                         "steps": max(96, envi("BENCH_STEPS", 32))})
     else:
         # the full TPU suite, deadline-ordered so a cut run still records
         # the strongest evidence (VERDICT r4 #1/#2): the round-comparable
@@ -3340,11 +3110,6 @@ def main() -> None:
             # scheduler + tokenize overhead
             dict(model="phi", dtype="int8", slots=8, steps=64, seq=1024,
                  prompt_len=128, paged=False, mixed=False, http=True),
-            # speculative-decoding envelope BEFORE the int4 arm so the
-            # (phi, int8) params cache survives into it (the int4 entry
-            # evicts the single-model cache)
-            dict(model="phi", dtype="int8", slots=8, steps=64, seq=1024,
-                 prompt_len=128, paged=False, mixed=False, spec=True),
             # int4 A/B vs capture 1: packed nibbles through the fused
             # pallas qmm (capacity feature; bandwidth parity tracked)
             dict(model="phi", dtype="int4", slots=8, steps=64, seq=1024,
@@ -3412,7 +3177,6 @@ def main() -> None:
         saved_env = {k: os.environ.get(k) for k in cap_env}
         os.environ.update(cap_env)
         http = cap.pop("http", False)
-        spec = cap.pop("spec", False)
         mixed_arm = cap.pop("mixed_arm", False)
         prefix_arm = cap.pop("prefix_arm", False)
         overload_arm = cap.pop("overload_arm", False)
@@ -3430,8 +3194,7 @@ def main() -> None:
                   else measure_overload if overload_arm
                   else measure_prefix if prefix_arm
                   else measure_mixed if mixed_arm
-                  else measure_http if http
-                  else measure_spec if spec else measure)
+                  else measure_http if http else measure)
             # plan-level keys override the global knobs (a capture may pin
             # its own page_size/n_pages — e.g. the shipped-default arm)
             captures.append(fn(jax, **{**common, **cap}))
@@ -3518,16 +3281,6 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
     for c in captures:
         if c.get("mode") == "prefix":
             paged_async_ttft_ratio = c.get("paged_async_ttft_ratio")
-            break
-    # fused prompt-lookup speculation (ISSUE 6 acceptance: the REAL
-    # lookup arm's per-dispatch cost <= 1.2x a baseline chunk dispatch,
-    # tok/s speedup > 1 on the repetition-heavy workload)
-    spec_tok_s_ratio = spec_dispatch_ratio = spec_acceptance = None
-    for c in captures:
-        if str(c.get("mode", "")).startswith("spec_fused"):
-            spec_tok_s_ratio = c.get("speedup")
-            spec_dispatch_ratio = c.get("dispatch_ratio")
-            spec_acceptance = c.get("spec_acceptance")
             break
     # overload discipline (ISSUE 8 acceptance: high p99 TTFT ratio <= 2
     # at 5x load, best_effort shed > 0 while shed{class=high} stays 0,
@@ -3626,7 +3379,7 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
     async_fallbacks = {
         cause: int(METRICS.get("tpu_model_async_fallback_total",
                                f'{{cause="{cause}"}}'))
-        for cause in ("grammar", "paged_dp", "spec")}
+        for cause in ("grammar", "paged_dp")}
     return json.dumps({
         "metric": metric,
         "value": head.get("tok_s"),
@@ -3634,7 +3387,7 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
         "vs_baseline": round(vs, 3),
         # which BENCH_r*.json the ratio resolved against (earliest recorded)
         "baseline_round": baseline[1] if baseline else None,
-        # surface-level captures (http/spec) don't carry every
+        # surface-level captures (http) don't carry every
         # engine-capture field — the headline is normally capture 0
         # (engine), but a pinned BENCH_HTTP run must still assemble
         "ttft_p50_ms": head.get("ttft_p50_ms"),
@@ -3651,9 +3404,6 @@ def assemble(captures: list, platform: str, n_devices: int) -> str:
         "tier_fleet_warm_hit": tier_fleet_warm_hit,
         "paged_async_itl_ratio": paged_async_itl_ratio,
         "paged_async_ttft_ratio": paged_async_ttft_ratio,
-        "spec_tok_s_ratio": spec_tok_s_ratio,
-        "spec_dispatch_ratio": spec_dispatch_ratio,
-        "spec_acceptance": spec_acceptance,
         "overload_high_p99_ttft_ratio": overload_high_ratio,
         "overload_best_effort_shed": overload_be_shed,
         "overload_high_shed": overload_high_shed,

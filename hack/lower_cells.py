@@ -63,8 +63,6 @@ for name in names:
     eng._admit_exec(256)
     eng._admit_many_exec(2, 128)
     eng._extend_exec(128, 512)
-    if not eng.recurrent:
-        eng._spec_exec(4, 512)
     for k, t in texts.items():
         open(os.path.join(out, f"{name}.{k}.txt"), "w").write(t)
         print(" ", k, len(t), flush=True)

@@ -264,34 +264,3 @@ def _candidates(logits, greedy, sp: SamplingParams, key, mu):
     mu2 = mu - sp.mirostat_eta * (e_obs - sp.mirostat_tau)
     live = (sp.mirostat > 0) & (sp.temperature > 0.0)
     return toks, jnp.where(live, mu2, mu)
-
-
-def spec_accept(drafts, greedy, ok, sampled, vocab_size):
-    """Vectorized accept/rollback for speculative verification.
-
-    ``drafts`` [B, k] are the proposed continuations, ``greedy`` [B, k+1]
-    the verify pass's argmax at each scored position, ``ok`` [B] bool
-    marks slots where raw-argmax acceptance is exact (greedy, neutral
-    penalties, unconstrained, active), and ``sampled`` [B] is the
-    decode-identical single token for every other slot.
-
-    Returns ``(n_acc, out)``: per-slot accepted-draft counts [B] and the
-    emission matrix [B, k+1] — row b holds its accepted draft prefix,
-    then the bonus token (``greedy[b, n_acc]`` for accepting slots,
-    ``sampled[b]`` otherwise), then ``vocab_size`` sentinel padding.
-    Rejection is thereby only a mask: positions at or beyond the first
-    draft/argmax mismatch pad to the sentinel and the caller rolls slot
-    lengths forward by the accepted count alone — no second dispatch, no
-    KV copy (rejected positions sit above the advanced length and are
-    never attended)."""
-    B, k = drafts.shape
-    match = (drafts == greedy[:, :-1]).astype(jnp.int32)
-    n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
-    n_acc = jnp.where(ok, n_acc, 0)
-    bi = jnp.arange(B)
-    bonus = jnp.where(ok, greedy[bi, n_acc], sampled)
-    t_idx = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
-    dpad = jnp.concatenate([drafts, jnp.zeros((B, 1), jnp.int32)], axis=1)
-    out = jnp.where(t_idx < n_acc[:, None], dpad, jnp.int32(vocab_size))
-    out = out.at[bi, n_acc].set(bonus)
-    return n_acc, out

@@ -16,9 +16,9 @@ multi-host follower replay invariant is untouched):
    `bench.py measure_mixed` enforces (`acct_tok_s_ratio`).
 
 2. **Goodput split**: every dispatch's slot·step grid is divided into
-   useful tokens (active slots, accepted drafts, real prompt positions) vs
-   bucket-padding waste (empty batch slots, prefill positions beyond the
-   prompt chunk, rejected speculative drafts).  Occupancy is the
+   useful tokens (active slots, real prompt positions) vs bucket-padding
+   waste (empty batch slots, prefill positions beyond the prompt
+   chunk).  Occupancy is the
    token-weighted useful fraction — the continuous-batching efficiency
    measure in the tradition of Yu et al. (Orca).
 
@@ -27,9 +27,8 @@ multi-host follower replay invariant is untouched):
    work queued), and host overhead (everything else — detok, HTTP, Python).
 
 MFU convention notes (also in docs/en/guide/tpu-serving.md):
-- The numerator counts FLOPs issued for *active* slots only, including
-  speculative positions that are later rejected (the device really ran
-  them); padded batch slots and padded prefill positions are excluded.
+- The numerator counts FLOPs issued for *active* slots only; padded
+  batch slots and padded prefill positions are excluded.
   So MFU answers "useful-work FLOPs vs peak" and `waste_pct` separately
   answers "how much of the issued grid was padding".
 - Peak FLOPs comes from the detected TPU generation (bf16 dense peak per
@@ -233,16 +232,9 @@ def decode_flops(cfg: ModelConfig, ctx: int, n_steps: int = 1) -> float:
             + attn_span_flops(cfg, ctx - 1, n_steps))
 
 
-def spec_verify_flops(cfg: ModelConfig, ctx: int, k: int) -> float:
-    """One speculative verify dispatch for one slot: k drafts + 1 bonus
-    position, contexts ctx..ctx+k — identical math to a (k+1)-token
-    prefill chunk starting at position ctx-1."""
-    return prefill_flops(cfg, ctx - 1, k + 1)
-
-
 # --- accumulator ------------------------------------------------------------
 
-_KINDS = ("decode", "prefill", "spec")
+_KINDS = ("decode", "prefill")
 
 
 class UtilizationAccounting:
@@ -324,24 +316,6 @@ class UtilizationAccounting:
         useful = float(n_active * n_steps)
         padded = float(max(0, capacity - n_active) * n_steps)
         self._bump("decode", flops, useful, padded, dur_s)
-
-    def on_spec(self, dur_s: float, ctxs: Iterable[int], k: int,
-                emitted: float, capacity: int) -> None:
-        """One speculative verify dispatch: every slot in the bucket runs
-        k+1 positions; `emitted` is the number of tokens that actually
-        advanced streams (accepted drafts + bonus).  FLOPs count the
-        active slots' full verify windows (rejected drafts were really
-        computed); waste = the issued grid minus emitted."""
-        if self.cfg is None:
-            return
-        flops = 0.0
-        n_active = 0
-        for c in ctxs:
-            n_active += 1
-            flops += spec_verify_flops(self.cfg, c, k)
-        issued = float(capacity * (k + 1))
-        useful = float(min(emitted, issued))
-        self._bump("spec", flops, useful, max(0.0, issued - useful), dur_s)
 
     def on_prefill(self, dur_s: float, start: int, n_new: int,
                    bucket: int) -> None:
@@ -456,9 +430,6 @@ class _NullAccounting:
     model_flops = 0.0
 
     def on_decode(self, *a: Any, **kw: Any) -> None:
-        pass
-
-    def on_spec(self, *a: Any, **kw: Any) -> None:
         pass
 
     def on_prefill(self, *a: Any, **kw: Any) -> None:

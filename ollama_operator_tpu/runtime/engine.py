@@ -363,24 +363,12 @@ class DecodeHandle:
     ``decode_n_launch(retire=...)`` to unfence pages quarantined up to
     it — wait() itself must NOT retire, because multi-host followers
     replay launches without ever waiting and the free-list order has to
-    stay bit-identical across hosts (runtime/paged.py docstring).
+    stay bit-identical across hosts (runtime/paged.py docstring)."""
 
-    A speculative launch (``decode_n_launch(drafts=...)``) additionally
-    sets ``budgets`` — the per-slot host-length advance taken at launch,
-    an upper bound since accept counts are still device-side futures —
-    and wait() fills ``accepted`` (tokens actually emitted per slot) and
-    returns rows transposed to [k+1, B] so fan-out sees the same
-    row-major layout as a chunked dispatch. The caller acks the
-    overshoot back with ``Engine.spec_ack(budgets - accepted)``; the ack
-    rides the broadcast call stream, which is what lets followers (who
-    never wait) keep bit-identical host lengths."""
-
-    __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "budgets",
-                 "accepted", "t_done", "t_begin", "t_queued", "enqueue_s",
-                 "sampler", "_load")
+    __slots__ = ("_engine", "_toks", "_t0", "_out", "epoch", "t_done",
+                 "t_begin", "t_queued", "enqueue_s", "sampler", "_load")
 
     def __init__(self, engine: "Engine", toks, t0: float, epoch: int = 0,
-                 budgets: Optional[np.ndarray] = None,
                  sampler: str = "argmax", load=None):
         self._engine = engine
         # a routed model's chunk: [E] picks per expert, still on the device
@@ -397,8 +385,6 @@ class DecodeHandle:
         self.enqueue_s = engine.enqueue_s
         self._out: Optional[np.ndarray] = None
         self.epoch = epoch
-        self.budgets = budgets
-        self.accepted: Optional[np.ndarray] = None
         # perf_counter() when wait() materialised the tokens; with
         # t_launch this makes the async launch→materialize overlap
         # visible to the tracing layer (runtime/trace.py)
@@ -421,14 +407,8 @@ class DecodeHandle:
     def wait(self) -> np.ndarray:
         if self._out is None:
             toks = self._engine._fetch(self._toks)
-            kind = "spec" if self.budgets is not None else "decode"
-            self.t_begin, self.t_done = self._engine._landed(kind, self._t0)
-            if self.budgets is not None:
-                # [B, k+1] sentinel-padded: valid entries per row are the
-                # accepted draft prefix + bonus token, in order
-                self.accepted = (
-                    toks < self._engine.cfg.vocab_size).sum(axis=1)
-                toks = toks.T
+            self.t_begin, self.t_done = self._engine._landed(
+                "decode", self._t0)
             self._out = toks
             self._toks = None
             if self._load is not None:
@@ -854,30 +834,21 @@ class Engine:
         self._host_lengths = np.zeros((B,), np.int64)
         # last observed wall-clock per dispatch kind (launch→tokens-on-
         # host), exported as gauges — gives dispatch-dominated regressions
-        # a number. The BENCH_r05 623ms/spec-dispatch anomaly was exactly
-        # this gauge catching mid-serving XLA compiles: spec executables
-        # were only warmed for one attention bucket, so every bucket
-        # crossing recompiled inside a timed dispatch. warm_buckets now
-        # compiles every (k, bucket) spec program AND pre-seeds
-        # dispatch_ms["spec"] from a no-op dispatch over the empty batch,
-        # so the first real request pays neither compile nor first-run
-        # setup.
-        self.dispatch_ms = {"decode": 0.0, "admit": 0.0, "extend": 0.0,
-                            "spec": 0.0}
+        # a number
+        self.dispatch_ms = {"decode": 0.0, "admit": 0.0, "extend": 0.0}
         # perf_counter() when the newest dispatch's tokens reached the host
         # (_landed): a dispatch launched before then began no earlier
         self._t_landed = 0.0
         # mid-serving recompile detector: warm_buckets registers every
         # AOT-warmed executable signature; an executable-cache miss
         # outside warming is an XLA compile inside a timed dispatch —
-        # counted per program kind (the BENCH_r05 incident as a counter)
+        # counted per program kind
         self._warming = False
         self._warmed_sigs: set = set()
         # (kind, key) -> the "site=kernel" choices that program traced
         self.program_kernels: Dict[Any, tuple] = {}
         self.recompiles: Dict[str, int] = {
-            "decode": 0, "admit": 0, "admit_many": 0, "extend": 0,
-            "spec": 0}
+            "decode": 0, "admit": 0, "admit_many": 0, "extend": 0}
 
         # per-slot sampling params, host mirror + device arrays
         self._opts: Dict[int, SlotOptions] = {}
@@ -986,7 +957,7 @@ class Engine:
                 f"{what}: no form yet for latent rows (one row [latent | "
                 "rotated key] and one indexer key a position, no keys or "
                 "values a head); they serve from the contiguous cache of "
-                "one device, without speculation, export or a host tier")
+                "one device, without export or a host tier")
 
     @staticmethod
     def _quant_cache_sharding(cache_sh):
@@ -1394,98 +1365,6 @@ class Engine:
             return (toks_n, k_cache, v_cache, lengths, counts, last_tokens,
                     pring, mu, keys, gstate, load)
 
-        def _spec_verify(params, k_cache, v_cache, lengths, counts,
-                         last_tokens, pring, mu, sp, keys, active,
-                         mask_bits, constrained, rln, gstate, gmask,
-                         gtrans, is_greedy, drafts, attn_len,
-                         tables=None):
-            """Speculative verify step (one dispatch): run the cached
-            forward over [last_token, draft_0..draft_{k-1}] per slot,
-            greedy-accept the longest matching draft prefix (greedy
-            slots only — temperature-0 acceptance is exact), and emit
-            accepted drafts + one model token per slot. Rejected
-            positions\' K/V are garbage above the advanced length and are
-            never attended; the next write overwrites them. Non-greedy
-            slots sample their single token exactly like _decode_body, so
-            a k=0-accepting batch degrades to one normal decode step."""
-            B, kk = drafts.shape
-            V = cfg.vocab_size
-            # escaped device-grammar slots freeze exactly as in decode
-            active = active * (gstate != -2).astype(active.dtype)
-            tokens_in = jnp.concatenate([last_tokens[:, None], drafts], 1)
-            kw = {"attn_len": attn_len} if self._bucketed_attn else {}
-            if self.paged:
-                ps = self.ecfg.page_size
-                nblk = -(-attn_len // ps)
-                logits, k_cache, v_cache = \
-                    decoder.forward_with_cache_paged(
-                        params, cfg, tokens_in, k_cache, v_cache,
-                        tables, lengths, nblk, mesh=self.mesh)
-            else:
-                logits, k_cache, v_cache = step_impl(
-                    params, tokens=tokens_in, k_cache=k_cache,
-                    v_cache=v_cache, lengths=lengths, **kw)
-            ok = (active == 1) & (is_greedy == 1)
-            bi = jnp.arange(B)
-            gdev = gstate >= 0
-            gi = jnp.clip(gstate, 0, gmask.shape[0] - 1)
-            with device_scope("sample"):
-                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                step_keys = jax.vmap(jax.random.fold_in)(keys, lengths)
-                l0 = logits[:, 0]
-                eff_bits = jnp.where(gdev[:, None], gmask[gi], mask_bits)
-                allowed = unpack_mask(eff_bits, V)
-                l0 = jnp.where((constrained == 1)[:, None] & ~allowed,
-                               sampling.NEG_INF, l0)
-                sampled0, mu_new = sampling.sample(l0, counts, sp,
-                                                   step_keys, mu,
-                                                   live=active)
-            # greedy (accepting) slots never run mirostat; only the
-            # sampled path's slots absorb the surprise update
-            mu = jnp.where((active == 1) & ~ok, mu_new, mu)
-            # vectorized accept/rollback (ops/sampling.spec_accept):
-            # accepted draft prefix + bonus token per row, sentinel
-            # padding at and beyond the first mismatch
-            n_acc, out = sampling.spec_accept(drafts, greedy, ok,
-                                              sampled0, V)
-            out = jnp.where((active == 1)[:, None], out, jnp.int32(V))
-            # constrained slots are spec-ineligible (_spec_flags), so they
-            # emit exactly out[:, 0] == sampled0 — advance the device
-            # automaton by that single token
-            tok0 = out[:, 0]
-            ns = gtrans[gi, jnp.clip(tok0, 0, V - 1)]
-            ns = jnp.where(ns < 0, jnp.int32(-2), ns)
-            gstate = jnp.where(gdev & (active == 1) & (tok0 < V),
-                               ns, gstate)
-            if slot_sh is not None:
-                gstate = jax.lax.with_sharding_constraint(gstate, slot_sh)
-
-            def push(carry, t):
-                lengths, counts, last_tokens, pring = carry
-                tok_t = out[:, t]
-                act_t = ((active == 1) & (t <= n_acc)
-                         & (tok_t < V)).astype(jnp.int32)
-                rmod = jnp.maximum(rln, 1)
-                slot_pos = (lengths + 1) % rmod
-                evict = pring[bi, slot_pos]
-                evict = jnp.where(act_t == 1, evict, jnp.int32(V))
-                live = (act_t == 1) & (rln > 0)
-                new = jnp.where(live, tok_t, jnp.int32(V))
-                counts2 = counts.at[bi, evict].add(-1, mode="drop")
-                counts2 = counts2.at[bi, new].add(1, mode="drop")
-                pring2 = jnp.where(live[:, None],
-                                   pring.at[bi, slot_pos].set(tok_t),
-                                   pring)
-                lengths2 = lengths + act_t
-                last2 = jnp.where(act_t == 1, tok_t, last_tokens)
-                return (lengths2, counts2, last2, pring2), None
-
-            (lengths, counts, last_tokens, pring), _ = jax.lax.scan(
-                push, (lengths, counts, last_tokens, pring),
-                jnp.arange(kk + 1, dtype=jnp.int32))
-            return (out, *pin(k_cache, v_cache, lengths, counts,
-                              last_tokens, pring, mu), keys, gstate)
-
         def _make_extend_paged(A):
             """Paged prefix-cache continuation, attending only the first
             ``A`` positions (the live-prefix bucket): the reused prefix
@@ -1701,11 +1580,6 @@ class Engine:
                                outs=dec_outs)
         self._decode_n_fn = _jit(_decode_n, (1, 2, 3, 4, 5, 6, 7, 9, 14),
                                  static=(17, 18), outs=decn_outs)
-        spec_outs = (((slot_sh2,) + state_outs + (slot_sh, slot_sh))
-                     if state_outs else None)
-        self._spec_fn = _jit(_spec_verify, (1, 2, 3, 4, 5, 6, 7, 9, 14),
-                             static=(19,), outs=spec_outs)
-        self._spec_execs: Dict[Any, Any] = {}
         self._release_fn = _jit(
             _release, (0, 1, 2, 3, 4),
             outs=((slot_sh, slot_sh2, slot_sh, slot_sh2, slot_sh)
@@ -2607,7 +2481,7 @@ class Engine:
         attention buckets (smallest covering ctx_lo+n .. smallest covering
         ctx_hi) — the bench uses this so a capture doesn't pay compiles for
         buckets it never decodes in. ``full=False`` additionally skips the
-        single-step, admission, spec, and extend warms (lazy compile covers
+        single-step, admission and extend warms (lazy compile covers
         a first use; a server must never take that hit mid-request, a bench
         capture may)."""
         n = n or self.ecfg.decode_chunk
@@ -2635,29 +2509,6 @@ class Engine:
                 for m in (2, 4):
                     if m <= self.n_slots:
                         self._admit_many_exec(m, b)
-        spec_k = self._spec_warm_k()
-        if spec_k > 0:
-            # speculative verify programs per attention bucket — a bucket
-            # crossing must swap programs, never recompile mid-serving
-            # (the BENCH_r05 623ms/spec-dispatch anomaly was exactly this
-            # warm missing: one warmed bucket, compiles on every cross)
-            for b in buckets:
-                self._spec_exec(spec_k, b)
-            if not self.active.any():
-                # pre-seed dispatch_ms["spec"] from a no-op dispatch
-                # over the empty batch (every slot inactive → the push
-                # scan advances nothing and inactive-slot KV writes land
-                # above/outside attended lengths): the gauge starts at
-                # steady-state launch cost instead of 0, and the first
-                # REAL spec dispatch pays neither compile nor first-run
-                # executable setup. Bypasses decode_n_launch so warm
-                # never consumes an armed engine.step fault.
-                h = self._spec_launch(
-                    np.zeros((self.n_slots, spec_k), np.int32), None,
-                    time.perf_counter())
-                h.wait()
-                if self.paged:
-                    self._pt.retire_epoch(h.epoch)
         if self.supports_extend:
             # (tail, attended) bucket pairs; the max_seq tail bucket is
             # unreachable (extend requires start >= 1 and start + bucket
@@ -2673,14 +2524,6 @@ class Engine:
                 for a in attns:
                     self._extend_exec(b, a)
 
-    def _spec_warm_k(self) -> int:
-        """The draft length whose verify programs this engine warms: 0
-        when speculation is off or this mesh cannot run it."""
-        if (self.sp_size > 1 or (self.paged and self._paged_dp > 1)
-                or self.recurrent):
-            return 0
-        return int(os.environ.get("TPU_SPEC_DECODE", "0") or "0")
-
     # --- warm-snapshot (scale-to-zero fast cold-start) -----------------
     def _exec_cache_items(self):
         """Yield ((kind, key), executable) over every AOT exec cache —
@@ -2693,8 +2536,6 @@ class Engine:
             yield ("admit_many", k), exe
         for k, exe in self._extend_execs.items():
             yield ("extend", k), exe
-        for k, exe in self._spec_execs.items():
-            yield ("spec", k), exe
 
     def _install_exec(self, sig, exe) -> bool:
         kind, key = sig
@@ -2706,8 +2547,6 @@ class Engine:
             self._admit_many_execs[key] = exe
         elif kind == "extend":
             self._extend_execs[key] = exe
-        elif kind == "spec":
-            self._spec_execs[key] = exe
         else:
             return False
         return True
@@ -2725,12 +2564,11 @@ class Engine:
             self._admit_many_exec(*key)
         elif kind == "extend":
             self._extend_exec(*key)
-        elif kind == "spec" and self._spec_warm_k() == key[0]:
-            self._spec_exec(*key)
         else:
-            # a signature this configuration disallows (speculation off
-            # or at another k, batched admission on a mesh that has
-            # none) is skipped; a compile that FAILS is not caught here
+            # a signature this configuration disallows (batched admission
+            # on a mesh that has none) or a kind this build no longer has
+            # (an older snapshot's) is skipped; a compile that FAILS is
+            # not caught here
             return False
         return True
 
@@ -3470,26 +3308,13 @@ class Engine:
         return toks
 
     def decode_n_launch(self, n: Optional[int] = None,
-                        retire: Optional[int] = None,
-                        drafts: Optional[np.ndarray] = None
-                        ) -> DecodeHandle:
-        """Launch one decode dispatch WITHOUT materialising its tokens:
-        slot state (host lengths included) advances immediately; the
-        returned handle's wait() fetches [n, B]. Double-buffering
-        callers launch dispatch N+1 before waiting on N so fan-out work
-        overlaps device compute (see DecodeHandle).
-
-        ``drafts`` [B, k] switches the dispatch to the fused speculative
-        draft+verify program (prompt-lookup decoding): ONE dispatch
-        scores k+1 positions per slot, greedy-accepts each eligible
-        slot's longest matching draft prefix plus a bonus token, and
-        advances every other slot exactly one decode-identical token —
-        rejection costs a sentinel mask and a host-length rollback
-        (``spec_ack``), never a second dispatch or a KV copy. wait()
-        then returns [k+1, B] sentinel-padded rows and fills the
-        handle's ``accepted`` counts. Zeros are fine for slots with
-        nothing to propose; this is the ONLY speculative entry point
-        (the standalone decode_spec surface is gone).
+                        retire: Optional[int] = None) -> DecodeHandle:
+        """Launch one chunk of ``n`` decode steps (``ecfg.decode_chunk``
+        where None) WITHOUT materialising its tokens: slot state (host
+        lengths included) advances immediately; the returned handle's
+        wait() fetches [n, B]. Double-buffering callers launch dispatch
+        N+1 before waiting on N so fan-out work overlaps device compute
+        (see DecodeHandle).
 
         Paged mode: each successful launch advances the page-table
         dispatch epoch; ``retire`` (the ``.epoch`` of the newest handle
@@ -3497,18 +3322,10 @@ class Engine:
         quarantined at or before that epoch, making them allocatable for
         this very launch. The kwarg rides the multi-host mirror
         broadcast, so followers retire at the identical call-stream
-        position without ever waiting on a handle themselves.
-        Speculative launches need no extra fence states: draft tokens
-        write into pages already mapped by prepare_decode, and the
-        accept mask only moves ``lengths``."""
+        position without ever waiting on a handle themselves."""
         FAULTS.check("engine.step")
         with span("engine.decode_n") as sp:
-            if drafts is not None:
-                # lint: allow(host-sync-hot-path): draft tokens are host ints
-                handle = self._spec_launch(np.asarray(drafts, np.int32),
-                                           retire, sp.t0)
-            else:
-                handle = self._launch(n, retire, sp.t0)
+            handle = self._launch(n, retire, sp.t0)
             sp.set(sampler=handle.sampler)
             return handle
 
@@ -3544,109 +3361,16 @@ class Engine:
                             sampler=self._count_sampler_steps(n, budgets),
                             load=load)
 
-    def _spec_exec(self, k: int, attn_len: int):
-        key = (k, attn_len)
-        exe = self._spec_execs.get(key)
-        if exe is None:
-            drafts = self._g(np.zeros((self.n_slots, k), np.int32),
-                             self._slot_sh2)
-            flags = self._g(np.zeros((self.n_slots,), np.int32),
-                            self._slot_sh)
-            exe = self._compile(
-                "spec", key, self._spec_fn,
-                self.params, self.k_cache, self.v_cache, self.lengths,
-                self.counts, self.last_tokens, self.pring, self.mu,
-                self.sp, self.keys, self._active_dev, self.mask_bits,
-                self._constr_dev, self._rln_dev, self._gstate,
-                self._gmask_dev, self._gtrans_dev, flags, drafts,
-                attn_len, self._tables_dev())
-            self._spec_execs[key] = exe
-        return exe
-
-    def _spec_flags(self) -> np.ndarray:
-        """Per-slot eligibility for exact speculative acceptance:
-        acceptance compares raw argmax, so it is exact ONLY for active,
-        unconstrained, greedy slots with neutral penalties (sample()
-        would otherwise adjust logits by the evolving counts); everyone
-        else takes the single-token sampled path inside the same
-        dispatch. Derived from host-mirrored slot state alone, so every
-        host computes identical flags at the same call-stream
-        position."""
-        flags = np.zeros((self.n_slots,), np.int32)
-        for s in range(self.n_slots):
-            if not self.active[s] or self._constrained[s]:
-                continue
-            o = self._opts.get(s, SlotOptions())
-            if (o.temperature <= 0.0 and o.repeat_penalty == 1.0
-                    and o.presence_penalty == 0.0
-                    and o.frequency_penalty == 0.0):
-                flags[s] = 1
-        return flags
-
-    def _spec_launch(self, drafts: np.ndarray, retire: Optional[int],
-                     t0: float) -> DecodeHandle:
-        """Fused speculative dispatch body (see decode_n_launch).
-
-        Host lengths advance by each slot's UPPER BOUND (k+1 for
-        eligible slots, 1 for the rest) at launch — the accept counts
-        are still device-side futures, and followers replay launches
-        without waiting, so the advance must be deterministic from the
-        call args alone. Over-estimation is safe everywhere host
-        lengths are read (attention buckets grow monotonically with
-        them; prepare_decode maps at most one page early); the caller
-        reconciles to the exact value by passing the waited handle's
-        overshoot back through ``spec_ack``, which rides the broadcast
-        stream like ``retire`` does."""
-        assert self.sp_size == 1, \
-            "speculative decode: bucketed caches only (no sp meshes)"
-        self._refuse_for_latent_rows("speculative decoding")
-        assert not self.recurrent, (
-            "speculative decode rolls rejected drafts back by length; a "
-            "recurrent state has no such rollback")
-        assert not (self.paged and self._paged_dp > 1), \
-            "speculative decode: the paged dp-manual region is T=1 only"
-        k = int(drafts.shape[1])  # lint: allow(host-sync-hot-path): shape read of a host array
-        assert k >= 1, "need at least one draft column"
-        n = k + 1
-        if self.paged and retire is not None:
-            self._pt.retire_epoch(retire)
-        victims = self.prepare_decode(n)
-        if victims:
-            from .paged import PagesExhausted
-            raise PagesExhausted(f"pool dry; victims {victims}")
-        flags = self._spec_flags()
-        exe = self._spec_exec(k, self._attn_bucket(n))
-        (toks, self.k_cache, self.v_cache, self.lengths, self.counts,
-         self.last_tokens, self.pring, self.mu, self.keys,
-         self._gstate) = self._enqueue(
-            "spec", exe,
-            self.params, self.k_cache, self.v_cache, self.lengths,
-            self.counts, self.last_tokens, self.pring, self.mu, self.sp,
-            self.keys, self._active_dev, self.mask_bits, self._constr_dev,
-            self._rln_dev, self._gstate, self._gmask_dev,
-            self._gtrans_dev, self._g(flags, self._slot_sh),
-            self._g(drafts, self._slot_sh2), self._tables_dev())
-        # inactive slots get budget 0, not 1: they neither advance at
-        # launch nor emit, so their rollback is exactly zero — a slot
-        # that goes inactive AND is re-admitted between launch and ack
-        # must never absorb the old occupant's overshoot
-        budgets = np.where(self.active,
-                           np.where(flags == 1, n, 1), 0).astype(np.int32)
-        self._host_lengths[self.active] += budgets[self.active]
-        epoch = self._pt.advance_epoch() if self.paged else 0
-        return DecodeHandle(self, toks, t0, epoch, budgets=budgets,
-                            sampler=self._count_sampler_steps(1))
-
-    def spec_ack(self, rollback: np.ndarray) -> None:
-        """Reconcile host lengths after a speculative dispatch
-        materialises: subtract the per-slot overshoot (launch budget
-        minus tokens actually emitted — the rejected draft tail). Called
-        by the scheduler right after wait() and BEFORE any release/admit
-        can reuse a slot; MIRRORED, so followers roll back at the same
-        call-stream position without ever waiting themselves. Slots
-        released since launch are masked out (their lengths were already
-        reset), and the clamp keeps a stale ack from ever driving a
-        length negative."""
+    def rollback_lengths(self, rollback: np.ndarray) -> None:
+        """Subtract a per-slot overshoot [B] from the host lengths: the
+        steps a launch advanced them by (``step_budgets``) that the
+        device did not take, because a device-grammar slot's automaton
+        left its table mid-chunk and froze. Called by the scheduler as
+        it fans the chunk out (``Scheduler._grammar_ack``); MIRRORED, so
+        followers roll back at the same call-stream position without
+        ever waiting themselves. Slots released since launch are masked
+        out (their lengths were already reset), and the clamp keeps a
+        stale rollback from ever driving a length negative."""
         rb = np.asarray(rollback, np.int64)  # lint: allow(host-sync-hot-path): rollback vector is host numpy
         rb = np.minimum(np.where(self.active, rb, 0), self._host_lengths)
         self._host_lengths -= rb
@@ -3657,7 +3381,7 @@ class Engine:
         refreshes on the host between dispatches); device-grammar slots
         and everyone else take the full chunk — the device table refreshes
         their mask per step, and an on-device escape freezes the slot so
-        the overshoot rolls back through spec_ack."""
+        the overshoot rolls back through rollback_lengths."""
         host_masked = self._constrained & ~self._gdev_mode
         return np.where(host_masked, 1, n).astype(np.int32)
 

@@ -244,16 +244,13 @@ class MirroredEngine:
                 # replay launches and never wait, so a handle the leader
                 # collects later keeps every host's call stream the same
                 "admit_launch", "admit_many_launch", "extend_launch",
-                # decode_n_launch is the ONE decode dispatch surface —
-                # its drafts= kwarg covers fused speculative dispatches
-                # (the standalone decode_spec op is gone); spec_ack
-                # reconciles speculative host-length overshoot at the
-                # exact call-stream position the leader waited, so
-                # followers never need to wait a handle to stay
-                # bit-identical
-                "decode_n_launch", "spec_ack", "release", "set_mask",
-                "clear_mask", "install_grammar", "warm_buckets",
-                "free_slot_pages", "prepare_decode",
+                # rollback_lengths takes a frozen device-grammar slot's
+                # host-length overshoot back at the call-stream position
+                # the leader fanned the chunk out, so followers never
+                # need to wait a handle to stay bit-identical
+                "decode_n_launch", "rollback_lengths", "release",
+                "set_mask", "clear_mask", "install_grammar",
+                "warm_buckets", "free_slot_pages", "prepare_decode",
                 # radix prefix cache: stitching/donation/eviction mutate
                 # page refcounts and (for COW) dispatch a page copy, so
                 # every host must replay them in order; prefix_probe is

@@ -81,8 +81,6 @@ declare("TPU_DECODE_CHUNK", "int", 0, "engine",
 declare("TPU_MIN_PREFILL_BUCKET", "int", 0, "engine",
         "floor for the padded prefill bucket ladder; 0 = engine-config "
         "default")
-declare("TPU_SPEC_DECODE", "int", 0, "engine",
-        "speculative-decoding draft length k; 0 disables")
 declare("TPU_WARM_SNAPSHOT_EXECS", "bool", None, "engine",
         "0 skips serialising warm executables into the snapshot; unset = "
         "backend default")

@@ -65,14 +65,6 @@ byte-identical free lists. When no dispatch is outstanding (epoch ==
 retired, the synchronous path) every stamp is retired and frees hit the
 pool directly, exactly as before.
 
-Fused speculative decoding needs NO states beyond these: a spec dispatch
-maps pages for its worst case (k+1 positions) via the same
-``prepare_decode`` growth path, draft tokens write into those
-already-mapped pages, and rejection just moves ``lengths`` back
-(``Engine.spec_ack``) — the rejected positions sit above the advanced
-length, are never attended, and are overwritten by the next dispatch.
-Nothing is freed on rejection, so nothing new can race the fence.
-
 Design notes vs the reference: llama.cpp's unified KV cell pool inside the
 delegated `ollama/ollama` image plays this role
 (/root/reference/pkg/model/pod.go:11); here the allocator is explicit so

@@ -30,7 +30,6 @@ import numpy as np
 from ..ops.constrain import GrammarTable
 from ..server.metrics import GLOBAL as METRICS
 from . import accounting
-from . import drafter
 from .admission import (DEFAULT_TENANT, PRIORITY_RANK, AdmissionQueue,
                         TenantRateLimited, TenantRateLimiter,
                         observed_throughput_tps, predict_queue_wait_s,
@@ -187,11 +186,6 @@ class Request:
         # every sampled token (incl. EOG), for parking the slot's KV as a
         # reusable prefix after the request finishes
         self.all_tokens: List[int] = []
-        # prompt-lookup drafting index: final-bigram → position of its
-        # continuation in (prompt + generated), maintained incrementally
-        # so drafting stays O(k) per step on long contexts
-        self._bigram_idx: dict = {}
-        self._indexed_upto = 0
         # set when the request is preempted (paged pool pressure): the
         # full prompt + tokens generated so far; re-admission prefills
         # from here and generation continues seamlessly on the same
@@ -289,35 +283,6 @@ class Scheduler:
             else float(os.environ.get("TPU_ENGINE_RESTART_BACKOFF_S",
                                       "0.05")))
         self.n_restarts = 0
-        # fused prompt-lookup speculative decoding (TPU_SPEC_DECODE=k):
-        # draft up to k tokens PER SLOT from bigram matches in that
-        # slot's own prompt+generated history (runtime/drafter.py); ONE
-        # bucketed dispatch (engine.decode_n_launch(drafts=...)) then
-        # verifies every draft and advances every slot — greedy
-        # penalty-free slots accept their matching prefix + a bonus
-        # token, everyone else steps exactly one decode-identical token
-        # inside the same program. Rejection costs a sentinel mask and a
-        # host-length ack (engine.spec_ack), never a second dispatch,
-        # and the path double-buffers like dense/paged decode — no
-        # cause="spec" sync fallback remains. The old standalone
-        # decode_spec surface (623 ms/dispatch in BENCH_r05, compiling
-        # per bucket crossing mid-request) is gone; its anomaly is now a
-        # warm-pass concern (engine.warm_buckets pre-compiles every
-        # (k, bucket) spec program). Opt-in: acceptance is workload-
-        # dependent — watch the spec block in /api/ps and keep it
-        # enabled only when the acceptance rate holds (docs give
-        # guidance).
-        self.spec_k = int(os.environ.get("TPU_SPEC_DECODE", "0") or "0")
-        if self.spec_k and getattr(engine, "recurrent", False):
-            # a rejected draft rolls back by length alone; a recurrent
-            # state that has run over it cannot: refused, not served wrong
-            FLIGHT.record("spec_refused", cause="recurrent_state",
-                          k=self.spec_k)
-            self.spec_k = 0
-        # drafted/accepted running totals back the /api/ps acceptance-
-        # rate block (counters also exported via metrics)
-        self.spec_drafted = 0
-        self.spec_accepted = 0
         # stall-free chunked prefill (Sarathi-style): prompts longer than
         # one piece admit bucket-by-bucket through Engine.extend, a
         # budget of pieces per scheduler step (_piece_tokens), so the
@@ -346,13 +311,11 @@ class Scheduler:
         # overlaps device compute (JAX async dispatch). The only
         # remaining sync fallback is HOST-masked grammar (a fresh host
         # PDA mask per token — device-table grammar slots ride async,
-        # see _fanout); fused speculation double-buffers with its
-        # stages reordered — see the spec branch in _step. Paged mode
-        # double-buffers too, dp-sharded pools included: the page
-        # table's epoch fence quarantines freed pages until the
-        # dispatch that captured their block table materialises
-        # (ShardedPageTable delegates the fence per shard), so
-        # recycling can never corrupt an in-flight program's reads
+        # see _fanout). Paged mode double-buffers too, dp-sharded pools
+        # included: the page table's epoch fence quarantines freed pages
+        # until the dispatch that captured their block table
+        # materialises (ShardedPageTable delegates the fence per shard),
+        # so recycling can never corrupt an in-flight program's reads
         # (runtime/paged.py).
         if async_dispatch is None:
             async_dispatch = os.environ.get(
@@ -367,10 +330,8 @@ class Scheduler:
         # is engine-inactive between pieces; without this map
         # free_slots() would hand it to someone else)
         self._prefilling: dict = {}
-        # (DecodeHandle, {slot: request-at-launch}, per-slot drafted
-        # counts or None) of the in-flight decode dispatch, when
-        # double-buffering — drafted counts feed the acceptance metrics
-        # when the handle materialises
+        # (DecodeHandle, {slot: request-at-launch}) of the in-flight
+        # decode dispatch, when double-buffering
         self._pending = None
         # the admissions this pass launched and has not collected, oldest
         # first: (AdmitHandle, [(slot, request, start, end, of)]): the
@@ -1240,12 +1201,10 @@ class Scheduler:
         """Whether an admission is launched and its first token collected
         behind the next chunk's launch, or awaited where it is made.
         Launched wherever nothing needs the host between a prefill and the
-        first decode step. Awaited: a synchronous loop; a pass while
-        drafts are built (they extend each slot's true tip, first token
-        included); a constrained request (its first token advances the
-        automaton, and the mask of its first decode step follows from
-        that)."""
-        return (self.async_dispatch and self.spec_k == 0
+        first decode step. Awaited: a synchronous loop; a constrained
+        request (its first token advances the automaton, and the mask of
+        its first decode step follows from that)."""
+        return (self.async_dispatch
                 and (req is None or req.constraint is None))
 
     def _admit_one(self, slot: int, req: Request, reuse_len: int,
@@ -2035,59 +1994,6 @@ class Scheduler:
                           delay_ms=round(delay * 1e3, 1))
             self._preempt_slot(slot, cause="throttle", resume_delay=delay)
 
-    def _build_drafts(self, k: int, tails: Optional[dict] = None):
-        """Prompt-lookup drafts [B, k] (zero-padded past each slot's
-        proposal) plus per-slot drafted counts [B], or (None, None) when
-        no eligible slot found an n-gram match — the loop then takes the
-        normal chunked path. Per-slot: only greedy penalty-free
-        unconstrained slots draft (device acceptance is exact there);
-        every other active slot still advances one decode-identical
-        token inside the same fused dispatch, and an eligible slot with
-        no match drafts nothing and costs nothing. ``tails`` carries
-        tokens from a dispatch that has materialised but not yet fanned
-        out (async spec pipelining), so drafts always extend the slot's
-        true tip."""
-        drafts = np.zeros((self.engine.n_slots, k), np.int32)
-        drafted = np.zeros((self.engine.n_slots,), np.int32)
-        n_drafting = 0
-        for slot, req in enumerate(self._running):
-            if req is None or slot in self._prefilling:
-                continue
-            if req.constraint is not None:
-                continue
-            o = req.opts
-            if (o.temperature > 0.0 or o.repeat_penalty != 1.0
-                    or o.presence_penalty != 0.0
-                    or o.frequency_penalty != 0.0):
-                continue
-            extra = tails.get(slot) if tails else None
-            d = self._lookup_draft(req, k, extra=extra)
-            if d:
-                drafts[slot, :len(d)] = d
-                drafted[slot] = len(d)
-                n_drafting += 1
-        if n_drafting == 0:
-            return None, None
-        return drafts, drafted
-
-    @staticmethod
-    def _lookup_draft(req: Request, k: int, ngram: int = drafter.NGRAM,
-                      extra: Optional[Sequence[int]] = None):
-        """Latest earlier occurrence of the context's final n-gram → the
-        k tokens that followed it (runtime/drafter.py; llama.cpp-style
-        lookup decoding, no draft model needed). The n-gram →
-        continuation-position index is maintained incrementally on the
-        request, so a step costs O(new tokens + k), not O(context).
-        ``extra`` appends tokens a materialised-but-unfanned dispatch
-        already produced — the index positions it creates stay valid
-        because _fanout appends exactly those tokens to all_tokens."""
-        hist = list(req.prompt_ids) + req.all_tokens
-        if extra:
-            hist += [int(t) for t in extra]
-        d, req._indexed_upto = drafter.propose(
-            hist, req._bigram_idx, req._indexed_upto, k, ngram=ngram)
-        return d
-
     def _watchdog_timeout_s(self) -> float:
         """Dispatch-wait budget in seconds; 0 disables the watchdog.
 
@@ -2201,27 +2107,19 @@ class Scheduler:
 
     def _grammar_ack(self, slot: int, over: int):
         """Roll back a device-grammar slot's launch-time host-length
-        over-advance (the frozen steps after an on-device escape) —
-        same mirrored reconciliation path fused speculation uses."""
+        over-advance (the frozen steps after an on-device escape),
+        mirrored so followers reconcile at the same call position."""
         if over <= 0:
             return
         rb = np.zeros((self.engine.n_slots,), np.int64)
         rb[slot] = over
-        self.engine.spec_ack(rb)
+        self.engine.rollback_lengths(rb)
 
-    def _wait_handle(self, handle, snapshot=None,
-                     drafted=None) -> np.ndarray:
+    def _wait_handle(self, handle, snapshot=None) -> np.ndarray:
         """Materialise a launched dispatch and reconcile host state: the
-        paged fence ack, and — for speculative dispatches — the
-        spec_ack rollback of the launch-time length over-advance
-        (budgets − accepted), broadcast so followers reconcile at the
-        identical call-stream position. The rollback is masked by
-        ``snapshot`` occupancy IDENTITY: a slot whose occupant finished
-        and was replaced between launch and wait must not have the old
-        occupant's overshoot subtracted from the new request's fresh
-        length (a parked/donated predecessor's length was already
-        reset or is repaired at reuse). Folds per-slot drafted/accepted
-        counts into the acceptance metrics."""
+        paged fence ack, the dispatch's latency and goodput, and a
+        ``dispatch`` span on every request of ``snapshot`` that still
+        owns its slot."""
         with span("sched.wait") as sp:
             toks_n = self._watched(handle.wait)
         # breakdown: only the time the scheduler actually BLOCKED here is
@@ -2230,20 +2128,18 @@ class Scheduler:
         self.acct.on_wait(sp.dur, sp.t1)
         self._fence_ack = handle.epoch
         self._consecutive_failures = 0
-        # dispatch latency, per program kind: what THIS dispatch took,
-        # from the later of its launch and its predecessor's tokens
-        # reaching the host (Engine._landed) to its own; a chunk launched
-        # behind one that still ran does not count that one's remainder.
-        # The trace event below is anchored at the launch, which makes
-        # async overlap visible (a launch far before its materialize =
-        # host work hidden behind device compute).
-        kind = "spec" if handle.budgets is not None else "decode"
+        # dispatch latency: what THIS dispatch took, from the later of
+        # its launch and its predecessor's tokens reaching the host
+        # (Engine._landed) to its own; a chunk launched behind one that
+        # still ran does not count that one's remainder. The trace event
+        # below is anchored at the launch, which makes async overlap
+        # visible (a launch far before its materialize = host work
+        # hidden behind device compute).
         dur = ((handle.t_done - handle.t_begin)
                if handle.t_done is not None else 0.0)
         METRICS.observe("tpu_model_dispatch_seconds", dur,
-                        f'{{kind="{kind}"}}')
-        if kind == "decode":
-            self._chunk_s.append(dur)
+                        '{kind="decode"}')
+        self._chunk_s.append(dur)
         if self.acct.enabled:
             # goodput/FLOPs split of the dispatch grid: active slots'
             # host-mirrored lengths as contexts, the full slot batch as
@@ -2251,70 +2147,14 @@ class Scheduler:
             hl, act = self.engine._host_lengths, self.engine.active
             ctxs = [int(hl[s]) for s in range(len(act)) if act[s]]
             n_rows = int(np.asarray(toks_n).shape[0])
-            if kind == "spec":
-                emitted = (float(np.asarray(handle.accepted).sum())
-                           if handle.accepted is not None else 0.0)
-                self.acct.on_spec(dur, ctxs, max(0, n_rows - 1), emitted,
-                                  self.engine.n_slots)
-            else:
-                self.acct.on_decode(dur, ctxs, n_rows,
-                                    self.engine.n_slots)
+            self.acct.on_decode(dur, ctxs, n_rows, self.engine.n_slots)
         if snapshot is not None:
             for s, r in snapshot.items():
-                if self._running[s] is not r:
-                    continue
-                acc = (int(handle.accepted[s])
-                       if handle.accepted is not None else None)
-                if acc is not None:
+                if self._running[s] is r:
                     r.trace.event_at(handle.t_launch, "dispatch",
-                                     kind=kind, epoch=handle.epoch,
-                                     dur_ms=round(dur * 1e3, 3),
-                                     accepted=acc)
-                else:
-                    r.trace.event_at(handle.t_launch, "dispatch",
-                                     kind=kind, epoch=handle.epoch,
+                                     kind="decode", epoch=handle.epoch,
                                      dur_ms=round(dur * 1e3, 3))
-        if handle.budgets is not None:
-            rollback = np.maximum(handle.budgets - handle.accepted, 0)
-            if snapshot is not None:
-                stable = np.zeros((self.engine.n_slots,), bool)
-                for s, r in snapshot.items():
-                    stable[s] = (self._running[s] is r
-                                 and s not in self._prefilling)
-                rollback = np.where(stable, rollback, 0)
-            if rollback.any():
-                self.engine.spec_ack(rollback)
-            if drafted is not None:
-                # a slot emits its accepted draft prefix + 1 bonus (or
-                # ordinary) token, so accepted drafts = emitted − 1;
-                # clamping by drafted keeps zero-pad columns that
-                # happened to match the argmax out of the rate
-                acc = np.minimum(
-                    np.maximum(handle.accepted - 1, 0), drafted)
-                d, a = int(drafted.sum()), int(acc.sum())
-                if d:
-                    self.spec_drafted += d
-                    self.spec_accepted += a
-                    METRICS.inc("tpu_model_spec_drafted_tokens_total",
-                                float(d))
-                    METRICS.inc("tpu_model_spec_accepted_tokens_total",
-                                float(a))
         return toks_n
-
-    def _pending_tails(self, toks_n, snapshot: dict) -> dict:
-        """slot → token tail of a materialised-but-not-yet-fanned-out
-        dispatch, for drafting the NEXT dispatch before _fanout runs.
-        Only identity-stable slots count (same occupant, not back in
-        prefill); sentinel columns (spec padding past the accepted
-        prefix) are dropped."""
-        vocab = self.engine.cfg.vocab_size
-        tails: dict = {}
-        for slot, req in snapshot.items():
-            if self._running[slot] is not req or slot in self._prefilling:
-                continue
-            tails[slot] = [int(t) for t in np.asarray(toks_n)[:, slot]
-                           if int(t) < vocab]
-        return tails
 
     def _drain_pending(self):
         """Materialise and fan out the in-flight async dispatch, if any,
@@ -2326,21 +2166,21 @@ class Scheduler:
 
     def _land(self, prev):
         """Bring to the host, in the order the device runs them, the
-        dispatch ``prev`` (a _pending triple, or None) and the admissions
+        dispatch ``prev`` (a _pending pair, or None) and the admissions
         launched behind it; then fan ``prev`` out. The first tokens are
         collected BEFORE the fan-out: a first token is not held behind a
         chunk's worth of queue puts."""
         toks_n = None
         if prev is not None:
-            handle, snapshot, drafted = prev
-            toks_n = self._wait_handle(handle, snapshot, drafted)
+            handle, snapshot = prev
+            toks_n = self._wait_handle(handle, snapshot)
         try:
             self._collect_launched()
         finally:
             # whatever the collect raised, prev's tokens are on the host:
             # deliver them before the supervisor errors whoever is left
             if prev is not None:
-                self._fanout(toks_n, snapshot, chunked=drafted is None)
+                self._fanout(toks_n, snapshot)
 
     def _decoding(self) -> dict:
         """slot → request for every slot the NEXT decode dispatch will
@@ -2392,8 +2232,8 @@ class Scheduler:
         two chunks took (_chunk_s); the step's first program takes the
         host what recent ones took (_lead_s). No hold before both have been
         measured, nor in a step that will not double-buffer: a loop that
-        drafts or awaits its admissions (_launches), a host-masked slot
-        (the synchronous branch)."""
+        awaits its admissions (_launches), a host-masked slot (the
+        synchronous branch)."""
         if (self._lead_s is None or len(self._chunk_s) < 2
                 or not self._launches()
                 or self._host_masked(self._decoding())):
@@ -2521,18 +2361,8 @@ class Scheduler:
         n_steps = (1 if all(r.constraint is not None and not gdev[s]
                             for s, r in decoding.items())
                    else None)
-        spec_usable = (self.spec_k > 0 and self.engine.sp_size == 1
-                       and not (self.engine.paged
-                                and self.engine._paged_dp > 1)
-                       and n_steps is None)
-        # drafts are built AFTER the in-flight dispatch lands (they must
-        # extend each slot's true tip), so pressure relief sizes for the
-        # worst case the coming dispatch could need: spec_k+1 mapped
-        # positions for a spec dispatch, decode_chunk for a chunked one
         with span("sched.housekeep"):
-            self._relieve_pressure(
-                max(self.engine.ecfg.decode_chunk, self.spec_k + 1)
-                if spec_usable else n_steps)
+            self._relieve_pressure(n_steps)
         decoding = self._decoding()
         if not decoding:
             self._drain_pending()
@@ -2544,104 +2374,46 @@ class Scheduler:
         if not self.async_dispatch or constrained:
             # synchronous path: grammar needs a fresh host PDA mask
             # between dispatches, so the pipeline must be empty before
-            # this one dispatches. Fused speculation still works here —
-            # the spec program advances constrained slots exactly one
-            # (masked) token while drafting slots verify k+1. (In paged
-            # mode decode_n self-retires its epoch and the spec launch
-            # threads retire=, so sync dispatches also drain any
-            # quarantine the async stretch left behind.)
+            # this one dispatches. (In paged mode decode_n self-retires
+            # its epoch, so sync dispatches also drain any quarantine
+            # the async stretch left behind.)
             if self.async_dispatch:
                 METRICS.inc("tpu_model_async_fallback_total", 1.0,
                             '{cause="grammar"}')
                 FLIGHT.record("async_fallback", cause="grammar")
             self._drain_pending()
-            drafts = drafted = None
-            if spec_usable:
-                drafts, drafted = self._build_drafts(self.spec_k)
-            if drafts is not None:
-                with span("sched.launch"):
-                    handle = self.engine.decode_n_launch(
-                        retire=(self._fence_ack if self.engine.paged
-                                else None),
-                        drafts=drafts)
-                toks_n = self._wait_handle(handle, decoding,
-                                           drafted)         # [k+1, B]
-            else:
-                with span("sched.wait") as sp:
-                    toks_n = self._watched(
-                        lambda: self.engine.decode_n(n_steps))
-                self._consecutive_failures = 0
-                t0, dur = sp.t0, sp.dur
-                METRICS.observe("tpu_model_dispatch_seconds", dur,
-                                '{kind="decode"}')
-                self.acct.on_wait(dur, sp.t1)
-                if self.acct.enabled:
-                    hl = self.engine._host_lengths
-                    self.acct.on_decode(
-                        dur, [int(hl[s]) for s in decoding],
-                        int(np.asarray(toks_n).shape[0]),
-                        self.engine.n_slots)
-                for s, r in decoding.items():
-                    if self._running[s] is r:
-                        r.trace.event_at(t0, "dispatch", kind="decode",
-                                         sync=True,
-                                         dur_ms=round(dur * 1e3, 3))
-            self._fanout(toks_n, decoding, chunked=drafts is None)
-            return
-        if spec_usable:
-            # fused speculation double-buffers with the stages
-            # REORDERED: drafts for dispatch N+1 must extend dispatch
-            # N's tokens, so the loop waits N first (spec_ack
-            # reconciling the launch-time length over-advance), drafts
-            # from the just-landed tails, launches N+1, and only then
-            # fans N out — detokenise/queue host work still overlaps
-            # N+1's device compute, which is the half of
-            # double-buffering that pays. No cause="spec" sync fallback
-            # remains.
-            prev, self._pending = self._pending, None
-            toks_prev = tails = prev_snapshot = None
-            if prev is not None:
-                prev_handle, prev_snapshot, prev_drafted = prev
-                toks_prev = self._wait_handle(prev_handle, prev_snapshot,
-                                              prev_drafted)
-                tails = self._pending_tails(toks_prev, prev_snapshot)
-            drafts, drafted = self._build_drafts(self.spec_k, tails)
-            try:
-                with span("sched.launch"):
-                    if drafts is not None:
-                        handle = self.engine.decode_n_launch(
-                            retire=(self._fence_ack if self.engine.paged
-                                    else None),
-                            drafts=drafts)
-                    else:   # no slot found a match this round: full chunk
-                        handle = (self.engine.decode_n_launch(
-                                      retire=self._fence_ack)
-                                  if self.engine.paged
-                                  else self.engine.decode_n_launch())
-            except Exception:
-                # dispatch N's tokens were already materialised —
-                # deliver them before the supervisor errors whoever is
-                # left
-                if toks_prev is not None:
-                    self._fanout(toks_prev, prev_snapshot,
-                                 chunked=prev_drafted is None)
-                raise
-            self._pending = (handle, decoding, drafted)
-            if toks_prev is not None:
-                self._fanout(toks_prev, prev_snapshot,
-                             chunked=prev_drafted is None)
+            with span("sched.wait") as sp:
+                toks_n = self._watched(
+                    lambda: self.engine.decode_n(n_steps))
+            self._consecutive_failures = 0
+            t0, dur = sp.t0, sp.dur
+            METRICS.observe("tpu_model_dispatch_seconds", dur,
+                            '{kind="decode"}')
+            self.acct.on_wait(dur, sp.t1)
+            if self.acct.enabled:
+                hl = self.engine._host_lengths
+                self.acct.on_decode(
+                    dur, [int(hl[s]) for s in decoding],
+                    int(np.asarray(toks_n).shape[0]),
+                    self.engine.n_slots)
+            for s, r in decoding.items():
+                if self._running[s] is r:
+                    r.trace.event_at(t0, "dispatch", kind="decode",
+                                     sync=True,
+                                     dur_ms=round(dur * 1e3, 3))
+            self._fanout(toks_n, decoding)
             return
         # double-buffered async dispatch: launch dispatch N+1 FIRST (the
         # step began by holding the pass, and so this launch, until N was
-        # about to land: _hold_pass),
-        # then materialise dispatch N, collect the first tokens of the
-        # admissions this pass launched between the two, and fan N out —
-        # the pass's host work and the detokenise/queue work overlap
-        # device compute, and the device's queue holds the chunk in
-        # flight, the pass's prefills and the next chunk. Device programs
-        # stay ordered through their donated-state data dependencies. The
-        # retire= ack unfences pages freed behind dispatches we have
-        # already materialised (paged mode; no-op dense).
+        # about to land: _hold_pass), then materialise dispatch N, collect
+        # the first tokens of the admissions this pass launched between
+        # the two, and fan N out — the pass's host work and the
+        # detokenise/queue work overlap device compute, and the device's
+        # queue holds the chunk in flight, the pass's prefills and the
+        # next chunk. Device programs stay ordered through their
+        # donated-state data dependencies. The retire= ack unfences pages
+        # freed behind dispatches we have already materialised (paged
+        # mode; no-op dense).
         try:
             with span("sched.launch"):
                 handle = (self.engine.decode_n_launch(
@@ -2653,7 +2425,7 @@ class Scheduler:
             # before the supervisor errors whoever is left
             self._drain_pending()
             raise
-        prev, self._pending = self._pending, (handle, decoding, None)
+        prev, self._pending = self._pending, (handle, decoding)
         # whether the device's queue ran dry before this launch: asked of
         # the program queued last before it (the pass's last prefill, else
         # the chunk in flight), which syncs nothing
@@ -2676,11 +2448,11 @@ class Scheduler:
             self._lead_s = max(lead, 0.9 * (self._lead_s or 0.0))
         self._land(prev)
 
-    def _fanout(self, toks_n, snapshot: dict, chunked: bool = True):
+    def _fanout(self, toks_n, snapshot: dict):
         with span("sched.fanout"):
-            self._fanout_rows(toks_n, snapshot, chunked)
+            self._fanout_rows(toks_n, snapshot)
 
-    def _fanout_rows(self, toks_n, snapshot: dict, chunked: bool):
+    def _fanout_rows(self, toks_n, snapshot: dict):
         """Deliver one dispatch's token rows [n, B] to the requests in
         ``snapshot`` (slot → request AT LAUNCH time). Under
         double-buffering a slot may have finished, been preempted, or
@@ -2704,9 +2476,7 @@ class Scheduler:
         (re-entering device mode when that state is back in the table),
         and the ALREADY-LAUNCHED next dispatch — which ran with the slot
         still frozen — is marked in _gdiscard so its rows are dropped and
-        its budget acked when IT fans out. ``chunked`` distinguishes full-
-        chunk dispatches from fused-spec ones (budget 1 per constrained
-        slot, reconciled by _wait_handle already — no grammar ack)."""
+        its budget acked when IT fans out."""
         pend: dict = {}
         # lint: allow(host-sync-hot-path): toks_n was fetched by DecodeHandle.wait — shape read of a host array
         n_rows = int(np.asarray(toks_n).shape[0])
@@ -2742,12 +2512,9 @@ class Scheduler:
             marked = self._gdiscard.pop(slot, None)
             if marked is req:
                 # this dispatch launched while the slot sat frozen after
-                # an escape: every row is garbage, and (full-chunk
-                # dispatch) its whole launch budget is overshoot. Spec
-                # dispatches emitted all-sentinel rows for the frozen
-                # slot and _wait_handle already rolled their budget back.
-                if chunked:
-                    self._grammar_ack(slot, n_rows)
+                # an escape: every row is garbage, and its whole launch
+                # budget is overshoot
+                self._grammar_ack(slot, n_rows)
                 return [None, -1]
             if not self.engine._gdev_mode[slot]:
                 return None
@@ -2756,8 +2523,7 @@ class Scheduler:
                 return None
             st = gt.state_id(req.constraint.state)
             if st < 0:   # host/device bookkeeping diverged: recover
-                if chunked:
-                    self._grammar_ack(slot, n_rows)
+                self._grammar_ack(slot, n_rows)
                 self._refresh_mask(slot, req)
                 return [gt, -1]
             return [gt, st]
@@ -2781,16 +2547,13 @@ class Scheduler:
                     elif walk[1] < 0:
                         continue  # device walk ended: rows are garbage
                 tid = int(row[slot])  # lint: allow(host-sync-hot-path): row is a host array post-wait
-                if tid >= self.engine.cfg.vocab_size:
-                    continue   # sentinel padding past the slot's
-                               # accepted prefix (fused spec verify)
                 # grammar check BEFORE emitting: a dead-end state (empty
                 # mask → uniform sampling over -inf logits) must not leak
                 # an illegal token into the client's JSON stream
                 if (req.constraint is not None
                         and tid not in req.eog_ids
                         and not req.constraint.advance(tid)):
-                    if walk is not None and chunked:
+                    if walk is not None:
                         self._grammar_ack(slot, n_rows - (row_idx + 1))
                     _flush(slot, req)
                     self._finish(slot, req, "stop")
@@ -2799,7 +2562,7 @@ class Scheduler:
                     req.stats.t_first_token = time.monotonic()
                 req.all_tokens.append(tid)  # EOG incl.: it's in the cache
                 if tid in req.eog_ids:
-                    if walk is not None and chunked:
+                    if walk is not None:
                         # EOG transitions escape on device: the slot
                         # advanced this row then froze — reconcile the
                         # chunk's remaining budget before release
@@ -2838,8 +2601,7 @@ class Scheduler:
                     # in the table), and mark the already-in-flight next
                     # dispatch, which ran with the slot still frozen
                     walk[1] = -1
-                    if chunked:
-                        self._grammar_ack(slot, n_rows - (row_idx + 1))
+                    self._grammar_ack(slot, n_rows - (row_idx + 1))
                     if (self._pending is not None
                             and self._pending[1].get(slot) is req):
                         self._gdiscard[slot] = req

@@ -335,15 +335,12 @@ class LoadedModel:
     def warm_snapshot_key(self) -> str:
         """Serving-identity hash the snapshot is keyed by: a snapshot is
         only valid for the exact digest + engine geometry + jax backend
-        that produced it (the warm plan itself also varies with
-        TPU_SPEC_DECODE, so that rides along)."""
+        that produced it."""
         import hashlib
-        import os as _os
         import jax
         payload = "|".join([
             self.digest or self.name, repr(self.ecfg), jax.__version__,
-            jax.default_backend(),
-            _os.environ.get("TPU_SPEC_DECODE", "0") or "0"])
+            jax.default_backend()])
         return hashlib.sha256(payload.encode()).hexdigest()[:24]
 
     def _restore_warm_snapshot(self) -> bool:
@@ -930,9 +927,6 @@ class _IdleScheduler:
     # /api/ps reads these off every resident model's scheduler; an
     # encoder has no decode loop, so they are permanently "off"
     async_dispatch = False
-    spec_k = 0
-    spec_drafted = 0
-    spec_accepted = 0
     n_throttles = 0
     draining = False
     n_replays = 0

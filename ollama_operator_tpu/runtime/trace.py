@@ -8,7 +8,7 @@ control plane).  Three instruments:
 1. **Per-request span timelines** (`RequestTrace`): every request carries a
    lock-cheap append-only event list stamping its path through the stack —
    queued → admit/stitch → each chunked-prefill piece → each decode dispatch
-   (with epoch/bucket/spec-acceptance and launch-vs-materialize split) →
+   (with epoch and launch-vs-materialize split) →
    detok → HTTP flush.  Appends are a single `list.append` of a tuple (
    GIL-atomic, no lock), so tracing rides the hot decode path at well under
    the 2% tok/s budget `bench.py measure_mixed` enforces.
@@ -294,7 +294,7 @@ SPAN_TABLE = (
      "_fanout: grammar walk, per-request queue put"),
     ("sched.idle", "scheduler", "the _wake.wait of an idle scheduler"),
     ("engine.decode_n", "engine dispatch",
-     "host time to launch one decode chunk (spec launch included)"),
+     "host time to launch one decode chunk"),
     ("engine.admit", "engine dispatch",
      "one-shot prefill + insert, to the program's dispatch (the wait for "
      "the first token lies outside: sched.collect where it is launched)"),

@@ -695,8 +695,7 @@ class ModelManager:
                     # whether decode double-buffers (false = forced sync:
                     # TPU_ASYNC_DISPATCH=0 or paged dp>1; the per-dispatch
                     # grammar fallback counts in
-                    # tpu_model_async_fallback_total, not here — fused
-                    # speculation double-buffers and never falls back)
+                    # tpu_model_async_fallback_total, not here)
                     "async": bool(lm.scheduler.async_dispatch),
                     "dispatch_ms": (dict(lm.engine.dispatch_ms)
                                     if getattr(lm, "engine", None)
@@ -742,23 +741,6 @@ class ModelManager:
                             if getattr(lm, "engine", None)
                             is not None else 0),
                     },
-                },
-                # fused prompt-lookup speculation: process-lifetime
-                # drafted/accepted token counters (same series /metrics
-                # exports) and the rate operators tune TPU_SPEC_DECODE
-                # by — a rate holding under ~0.3 means lookup misses are
-                # paying dispatch overhead for nothing, switch it off
-                "spec": {
-                    "enabled": lm.scheduler.spec_k > 0,
-                    "k": lm.scheduler.spec_k,
-                    "drafted_tokens": int(METRICS.get(
-                        "tpu_model_spec_drafted_tokens_total")),
-                    "accepted_tokens": int(METRICS.get(
-                        "tpu_model_spec_accepted_tokens_total")),
-                    "acceptance_rate": (
-                        round(lm.scheduler.spec_accepted
-                              / lm.scheduler.spec_drafted, 4)
-                        if lm.scheduler.spec_drafted else 0.0),
                 },
                 # overload discipline: live admission-policy snapshot —
                 # per-class queue depth / token backlog, WDRR tenant

@@ -212,7 +212,7 @@ GLOBAL.describe("tpu_model_admissions_total",
                 "prefill was dispatched without a host sync and its token "
                 "collected behind the next decode chunk's launch; awaited "
                 "= the loop waited for it where the admission was made (a "
-                "synchronous loop, speculation on, a constrained request)")
+                "synchronous loop, a constrained request)")
 GLOBAL.describe("tpu_model_pass_holds_total",
                 "Admission passes made with a decode chunk in flight, by "
                 "how the hold before the pass ended (end=filled|deadline|"
@@ -221,7 +221,7 @@ GLOBAL.describe("tpu_model_pass_holds_total",
                 "until the chunk in flight was about to land (less what a "
                 "step takes the host to hand its first program to the "
                 "runtime) with slots still free; none = nothing to hold for (no free slot without a "
-                "waiter, no measured chunk yet, a loop that drafts). A "
+                "waiter, no measured chunk yet). A "
                 "pass that begins with no chunk in flight is not counted "
                 "here (after a drain for pages: the passes of a paged "
                 "pool that is always full); "
@@ -323,18 +323,7 @@ GLOBAL.describe("tpu_model_async_fallback_total",
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "
                 "(host PDA mask between dispatches), once at startup for "
                 "paged_dp (dp-sharded page pools stay sync); a "
-                "silently-sync deployment shows here. cause=\"spec\" is "
-                "retired — fused speculation double-buffers — and kept "
-                "pre-seeded at 0 to prove it stays that way")
-GLOBAL.describe("tpu_model_spec_drafted_tokens_total",
-                "Prompt-lookup draft tokens submitted to fused "
-                "speculative verification (TPU_SPEC_DECODE=k); divide "
-                "accepted by drafted for the acceptance rate")
-GLOBAL.describe("tpu_model_spec_accepted_tokens_total",
-                "Draft tokens accepted by speculative verification — "
-                "each one is an output token that skipped a decode "
-                "dispatch; accepted/drafted below ~0.3 means lookup "
-                "misses are paying dispatch overhead for nothing")
+                "silently-sync deployment shows here")
 GLOBAL.describe("tpu_model_prefix_reused_tokens_total",
                 "Prompt tokens served from a parked prefix cache on the "
                 "request's FIRST admission (per-request view of the "
@@ -371,7 +360,7 @@ GLOBAL.describe("tpu_model_tenant_decode_tokens_total",
                 "series behind WDRR fairness dashboards")
 GLOBAL.describe("tpu_model_dispatch_seconds",
                 "Device dispatch latency histogram by program kind "
-                "(kind=decode|admit|extend|spec): what the dispatch "
+                "(kind=decode|admit|extend): what the dispatch "
                 "took, from the later of its launch and its predecessor's "
                 "tokens reaching the host to its own tokens on the host "
                 "(a dispatch queued behind one that still runs does not "
@@ -416,21 +405,19 @@ GLOBAL.describe("tpu_model_watchdog_fires_total",
                 "replay")
 GLOBAL.describe("tpu_model_recompiles_total",
                 "Mid-serving XLA compiles, by program kind (kind=decode|"
-                "admit|admit_many|extend|spec): an executable-cache miss "
+                "admit|admit_many|extend): an executable-cache miss "
                 "OUTSIDE warm_buckets, paid inside a timed dispatch. "
                 "Nonzero after warmup means the warm plan missed a "
-                "signature (the BENCH_r05 623ms spec-dispatch incident "
-                "as a counter)")
+                "signature")
 GLOBAL.describe("tpu_model_useful_tokens_total",
                 "Useful token positions computed per dispatch kind "
-                "(kind=decode|prefill|spec): active slots' steps, real "
-                "prompt positions, emitted speculative tokens — the "
-                "goodput numerator (runtime/accounting.py)")
+                "(kind=decode|prefill): active slots' steps, real "
+                "prompt positions — the goodput numerator "
+                "(runtime/accounting.py)")
 GLOBAL.describe("tpu_model_padded_tokens_total",
                 "Padding-waste token positions per dispatch kind: empty "
                 "batch slots x steps, prefill bucket positions past the "
-                "prompt chunk, rejected speculative drafts — the waste "
-                "half of the goodput split")
+                "prompt chunk — the waste half of the goodput split")
 GLOBAL.describe("tpu_model_decode_steps_total",
                 "Decode steps launched, by the sampler each took on the "
                 "device (sampler=argmax|candidates): argmax where no "
@@ -629,8 +616,6 @@ for _name in ("tpu_model_engine_restarts_total",
               "tpu_model_prefix_hit_tokens_total",
               "tpu_model_prefix_miss_tokens_total",
               "tpu_model_spilled_pages_total",
-              "tpu_model_spec_drafted_tokens_total",
-              "tpu_model_spec_accepted_tokens_total",
               # traffic counters: an idle (or freshly-restarted) server
               # must scrape 0, not absent — a dashboard rate() over an
               # absent series renders "no data" exactly when someone is
@@ -673,7 +658,7 @@ for _tier in ("0", "1", "2"):
 # the restitch histogram likewise: a latency dashboard over a server
 # that has never restitched must read empty buckets, not "no data"
 GLOBAL.seed_histogram("tpu_model_restitch_seconds")
-for _cause in ("grammar", "spec", "paged_dp"):
+for _cause in ("grammar", "paged_dp"):
     GLOBAL.inc("tpu_model_async_fallback_total", 0.0,
                f'{{cause="{_cause}"}}')
 # admission-control counters: every class × cause combination pre-seeded
@@ -693,9 +678,9 @@ GLOBAL.inc("tpu_model_tenant_decode_tokens_total", 0.0,
 # the goodput/waste dashboards must read 0, not absent, from the first
 # scrape — a recompile series that first appears AT the first mid-serving
 # compile hides exactly the event it exists to expose
-for _kind in ("decode", "admit", "admit_many", "extend", "spec"):
+for _kind in ("decode", "admit", "admit_many", "extend"):
     GLOBAL.inc("tpu_model_recompiles_total", 0.0, f'{{kind="{_kind}"}}')
-for _kind in ("decode", "prefill", "spec"):
+for _kind in ("decode", "prefill"):
     GLOBAL.inc("tpu_model_useful_tokens_total", 0.0, f'{{kind="{_kind}"}}')
     GLOBAL.inc("tpu_model_padded_tokens_total", 0.0, f'{{kind="{_kind}"}}')
 for _sampler in ("argmax", "candidates"):

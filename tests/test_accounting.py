@@ -25,7 +25,6 @@ from ollama_operator_tpu.runtime.accounting import (NULL_ACCOUNTING,
                                                     make_accounting,
                                                     per_token_flops,
                                                     prefill_flops,
-                                                    spec_verify_flops,
                                                     _ctx_sum)
 from ollama_operator_tpu.server.metrics import GLOBAL as METRICS
 
@@ -84,11 +83,6 @@ def test_decode_flops_continues_the_series():
     assert decode_flops(TINY, 10, 2) == 2 * 180224 + 4 * 64 * (2 * 21)
     # decode IS a width-n prefill starting one position back
     assert decode_flops(TINY, 10, 2) == prefill_flops(TINY, 9, 2)
-
-
-def test_spec_verify_is_a_k_plus_1_prefill():
-    assert spec_verify_flops(TINY, 10, 3) == prefill_flops(TINY, 9, 4)
-    assert spec_verify_flops(LLAMA2, 100, 4) == prefill_flops(LLAMA2, 99, 5)
 
 
 def test_sliding_window_layers_split_and_cap():
@@ -154,16 +148,6 @@ def test_decode_goodput_counts_padded_slots():
     assert acct.padded_tokens["decode"] == 8      # 2 empty slots x 4
     expect = (4 * per_token_flops(TINY) + attn_span_flops(TINY, 4, 4)
               + 4 * per_token_flops(TINY) + attn_span_flops(TINY, 8, 4))
-    assert acct.model_flops == pytest.approx(expect)
-
-
-def test_spec_goodput_counts_rejected_drafts_as_waste():
-    acct = make_acct()
-    # 2-slot bucket, k=3 → 8 issued positions; only 3 tokens advanced
-    acct.on_spec(0.01, ctxs=[10, 12], k=3, emitted=3.0, capacity=2)
-    assert acct.useful_tokens["spec"] == 3
-    assert acct.padded_tokens["spec"] == 5
-    expect = spec_verify_flops(TINY, 10, 3) + spec_verify_flops(TINY, 12, 3)
     assert acct.model_flops == pytest.approx(expect)
 
 
@@ -256,7 +240,6 @@ def test_accounting_without_cfg_is_safe():
     acct = UtilizationAccounting(None, peak_flops=1e12)
     acct.on_decode(0.01, ctxs=[5], n_steps=1, capacity=1)
     acct.on_prefill(0.01, 0, 4, 16)
-    acct.on_spec(0.01, ctxs=[5], k=2, emitted=1, capacity=1)
     assert acct.model_flops == 0.0
 
 

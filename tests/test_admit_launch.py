@@ -16,7 +16,7 @@ The invariants under test:
 - a fault at collect errors that admission's owner exactly once and nobody
   else; a wedged device at collect goes to the supervisor, which errors (or
   replays) every owner exactly once;
-- constrained requests and a loop that drafts take the awaited form, and
+- constrained requests take the awaited form, and
   `tpu_model_admissions_total{mode}` says which form each request took;
 - a prompt past one prefill piece launches its pieces too: the streams are
   the awaited pieces' and the one-shot admission's, every job gets pieces
@@ -595,25 +595,6 @@ def test_a_constrained_request_is_awaited(monkeypatch, device_grammar):
         assert advance_bytes(INITIAL_STATE,
                              b"".join(PIECES[t] for t in toks)) is not None
         assert len(tokens_of(frames(rp))) == 5
-    finally:
-        sched.shutdown()
-
-
-def test_a_loop_that_drafts_awaits(monkeypatch):
-    """``spec_k > 0``: drafts extend each slot's true tip, the first token
-    included, so the pass waits for it."""
-    monkeypatch.setenv("TPU_SPEC_DECODE", "2")
-    params = decoder.init_params(TINY, jax.random.key(0), jnp.float32)
-    eng = Engine(TINY, params, ecfg=dataclasses.replace(
-        ECFG, cache_dtype=jnp.float32))
-    sched = manual(Scheduler(eng, prefill_chunk=0, async_dispatch=True))
-    try:
-        assert sched.spec_k == 2
-        before = modes()
-        r = sched.submit(prompt(9), GREEDY, max_tokens=7)
-        run_steps(sched)
-        assert len(tokens_of(frames(r))) == 7
-        assert moved(before) == {"launched": 0, "awaited": 1}
     finally:
         sched.shutdown()
 

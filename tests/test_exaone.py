@@ -534,17 +534,15 @@ def test_chunked_prefill_through_the_scheduler(shared_engine):
         sched.shutdown()
 
 
-def test_the_rules_of_a_state_that_only_advances_apply(shared_engine, params,
-                                                       monkeypatch):
-    """No page pool, no mesh, no speculation, a parked prefix reused only
-    whole: the rules a recurrent stack has, unchanged."""
+def test_the_rules_of_a_state_that_only_advances_apply(shared_engine, params):
+    """No page pool, no mesh, a parked prefix reused only whole: the rules
+    a recurrent stack has, unchanged."""
     with pytest.raises(ValueError, match="contiguous cache"):
         make_engine(params, paged=True, page_size=16)
-    monkeypatch.setenv("TPU_SPEC_DECODE", "4")
     eng, sched = make_stack(shared_engine)
     manual(sched)
     try:
-        assert eng.recurrent and sched.spec_k == 0
+        assert eng.recurrent
         base = [int(t) for t in tokens(24, seed=12)]
         req = type("R", (), {})()
         req.embeds = None

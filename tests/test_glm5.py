@@ -715,7 +715,6 @@ def test_the_index_counter_counts_seen_and_kept(params):
     ("a page pool", dict(paged=True, page_size=16)),
     ("a mesh", dict(mesh=True)),
     ("the host tier", dict(env=("TPU_HOST_CACHE_GB", "1"))),
-    ("speculative decoding", dict(call="spec")),
     ("export_request_kv", dict(call="export")),
 ])
 def test_what_latent_rows_cannot_do_yet_is_refused_by_name(params, what, kw,
@@ -733,26 +732,18 @@ def test_what_latent_rows_cannot_do_yet_is_refused_by_name(params, what, kw,
         eng = Engine(CFG, params, mesh=mesh, ecfg=EngineConfig(
             max_slots=2, max_seq_len=64, cache_dtype=jnp.float32,
             decode_chunk=4, min_prefill_bucket=16, **kw))
-        if call == "spec":
-            eng.admit(0, tokens(8), GREEDY)
-            eng.decode_n_launch(drafts=np.zeros((2, 2), np.int32))
-        elif call == "export":
+        if call == "export":
             eng.export_request_kv(tokens(20))
         else:
             raise AssertionError("the engine was built")
     assert what.split(" (")[0] in str(err.value)
 
 
-def test_the_rules_of_a_stack_with_layer_kinds_apply(shared_engine, params,
-                                                     monkeypatch):
-    """No speculation through the scheduler, a parked prefix reused only
-    whole: the rules every contiguous stack with ``layer_kinds`` has."""
-    monkeypatch.setenv("TPU_SPEC_DECODE", "4")
-    eng, sched = make_stack(shared_engine)
-    try:
-        assert eng.recurrent and sched.spec_k == 0
-    finally:
-        sched.shutdown()
+def test_the_rules_of_a_stack_with_layer_kinds_apply(shared_engine):
+    """A parked prefix reused only whole: the rule every contiguous stack
+    with ``layer_kinds`` has."""
+    eng = shared_engine
+    assert eng.recurrent
     eng.admit(0, tokens(20), GREEDY)
     eng.release(0, park=True)
     with pytest.raises(ValueError, match="cannot be cut back"):

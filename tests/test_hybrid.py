@@ -445,24 +445,6 @@ def test_a_conversation_continues_correctly_after_parking(shared_engine):
     assert got == uninterrupted(shared_engine, cont, GREEDY, 6)
 
 
-def test_speculative_decoding_is_refused(shared_engine, monkeypatch):
-    monkeypatch.setenv("TPU_SPEC_DECODE", "4")
-    want = uninterrupted(shared_engine, tokens(8), GREEDY, 10)
-    n0 = len([e for e in FLIGHT.snapshot() if e["kind"] == "spec_refused"])
-    eng, sched = make_stack(shared_engine)
-    try:
-        assert sched.spec_k == 0 and eng._spec_warm_k() == 0
-        evs = [e for e in FLIGHT.snapshot() if e["kind"] == "spec_refused"]
-        assert len(evs) == n0 + 1
-        assert evs[-1]["cause"] == "recurrent_state"
-        assert list(sched.submit(tokens(8), GREEDY,
-                                 max_tokens=10).tokens()) == want
-        with pytest.raises(AssertionError, match="recurrent"):
-            eng._spec_launch(np.zeros((eng.n_slots, 4), np.int32), None, 0.0)
-    finally:
-        sched.shutdown()
-
-
 # -- the chip's share of the expert layer -------------------------------
 
 @pytest.mark.parametrize("who", ["program", "reference"])

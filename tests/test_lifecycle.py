@@ -2,7 +2,7 @@
 hung-dispatch watchdog (the PR 9 lifecycle layer in runtime/scheduler.py).
 
 The replay chaos drills here are the zero-error counterparts of the
-exactly-once error drills in test_faults/test_paged_async/test_spec_decode
+exactly-once error drills in test_faults/test_paged_async/test_stall_free
 (which pin the fallback path with TPU_RESTART_REPLAY_MAX=0): with replay
 ON, an engine failure mid-stream must be INVISIBLE to a deterministic
 client — same tokens, same queue, no error frame — because the rebuilt

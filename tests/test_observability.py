@@ -224,8 +224,7 @@ def test_scheduler_observes_latency_histograms():
         sched.shutdown()
     assert _hist_count("tpu_model_queue_wait_seconds") > q0
     text = METRICS.render()
-    assert 'tpu_model_dispatch_seconds_bucket{kind="decode"' in text \
-        or 'tpu_model_dispatch_seconds_bucket{kind="spec"' in text
+    assert 'tpu_model_dispatch_seconds_bucket{kind="decode"' in text
     assert re.search(r'tpu_model_dispatch_seconds_bucket\{kind="(admit|'
                      r'extend)"', text)
 
@@ -328,11 +327,11 @@ def test_utilization_metric_families_preseeded():
     reads as a counter reset (same discipline as the shed matrix)."""
     text = METRICS.render()
     series = ([f'tpu_model_recompiles_total{{kind="{k}"}}'
-               for k in ("decode", "admit", "admit_many", "extend", "spec")]
+               for k in ("decode", "admit", "admit_many", "extend")]
               + [f'tpu_model_useful_tokens_total{{kind="{k}"}}'
-                 for k in ("decode", "prefill", "spec")]
+                 for k in ("decode", "prefill")]
               + [f'tpu_model_padded_tokens_total{{kind="{k}"}}'
-                 for k in ("decode", "prefill", "spec")]
+                 for k in ("decode", "prefill")]
               + [f'tpu_model_breakdown_seconds_total{{phase="{p}"}}'
                  for p in ("dispatch_wait", "host", "idle")]
               + [f'tpu_model_decode_steps_total{{sampler="{s}"}}'
@@ -351,7 +350,6 @@ def test_utilization_series_pass_strict_validator():
                                  device_kind="unit")
     acct.on_decode(0.01, ctxs=[4, 6], n_steps=2, capacity=4)
     acct.on_prefill(0.01, 0, 5, 16)
-    acct.on_spec(0.01, ctxs=[8], k=2, emitted=1.0, capacity=1)
     acct.on_wait(0.005)
     acct.on_idle(0.005)
     validate_prometheus_text(METRICS.render())

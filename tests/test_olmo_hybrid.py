@@ -582,16 +582,11 @@ def test_chunked_prefill_through_the_scheduler(shared_engine):
         sched.shutdown()
 
 
-def test_speculation_and_paging_are_refused_as_for_any_recurrent_stack(
-        shared_engine, params, monkeypatch):
+def test_paging_is_refused_as_for_any_recurrent_stack(shared_engine,
+                                                      params):
+    assert shared_engine.recurrent
     with pytest.raises(ValueError, match="contiguous cache"):
         make_engine(params, paged=True, page_size=16)
-    monkeypatch.setenv("TPU_SPEC_DECODE", "4")
-    eng, sched = make_stack(shared_engine)
-    try:
-        assert eng.recurrent and sched.spec_k == 0
-    finally:
-        sched.shutdown()
 
 
 # -- serving defaults, accounting, metrics ------------------------------

@@ -541,7 +541,7 @@ def test_async_fallback_counter_preseeded():
     rules rate() over these and a series that first appears AT the first
     fallback hides it."""
     text = METRICS.render()
-    for cause in ("grammar", "spec", "paged_dp"):
+    for cause in ("grammar", "paged_dp"):
         assert f'tpu_model_async_fallback_total{{cause="{cause}"}}' in text
 
 

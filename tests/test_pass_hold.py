@@ -17,8 +17,7 @@ The invariants under test:
 - arrivals of one bucket that came during one hold share one admit_many;
 - a cancelled or expired waiter is no waiter;
 - nothing is held with no chunk in flight, no free slot, no measured chunk
-  or pass yet, in a synchronous loop, beside a host-masked slot, or in a
-  loop that drafts;
+  or pass yet, in a synchronous loop, or beside a host-masked slot;
 - tpu_model_pass_holds_total{end} and
   tpu_model_decode_launches_total{timing} count as their help says.
 """
@@ -141,8 +140,8 @@ def in_flight(eng, monkeypatch, running, **sched_kw):
     sched._now, sched._wake = clock.now, clock
     sched._stop.clear()       # manual() set it; a shutdown ends a hold
     if sched._pending is not None:
-        handle, snapshot, drafted = sched._pending
-        sched._pending = (Held(handle, log), snapshot, drafted)
+        handle, snapshot = sched._pending
+        sched._pending = (Held(handle, log), snapshot)
     eng._t_landed = clock.t0
     sched._chunk_s.extend([CHUNK, CHUNK])
     sched._lead_s = COST
@@ -264,10 +263,6 @@ def _no_measured_pass(sched, eng):
     sched._lead_s = None
 
 
-def _drafting(sched, eng):
-    sched.spec_k = 2
-
-
 def _host_masked(sched, eng):
     # a device-grammar slot whose automaton left the device's table while
     # its next chunk was already launched: host-masked from now on
@@ -278,10 +273,9 @@ def _host_masked(sched, eng):
 @pytest.mark.parametrize("why,counted", [
     (_no_chunk, {}), (_no_measured_chunk, {"none": 1}),
     (_one_measured_chunk, {"none": 1}),
-    (_no_measured_pass, {"none": 1}), (_drafting, {"none": 1}),
-    (_host_masked, {"none": 1})],
+    (_no_measured_pass, {"none": 1}), (_host_masked, {"none": 1})],
     ids=["no_chunk_in_flight", "no_measured_chunk", "one_measured_chunk",
-         "no_measured_pass", "a_loop_that_drafts", "a_host_masked_slot"])
+         "no_measured_pass", "a_host_masked_slot"])
 def test_nothing_is_held_without_its_conditions(eng, monkeypatch, why,
                                                 counted):
     """Two slots free and nobody waiting, which would hold, but for one

@@ -299,7 +299,7 @@ def test_max_tokens_one_finishes_with_length(eng):
 
 
 def test_dispatch_latency_gauges_populate(eng):
-    assert set(eng.dispatch_ms) == {"decode", "admit", "extend", "spec"}
+    assert set(eng.dispatch_ms) == {"decode", "admit", "extend"}
     run_one(eng, prompt(20), prefill_chunk=16, async_dispatch=True,
             max_tokens=4)
     assert eng.dispatch_ms["decode"] > 0.0

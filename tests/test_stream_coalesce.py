@@ -17,6 +17,7 @@ from ollama_operator_tpu.runtime.engine import Engine, EngineConfig, SlotOptions
 from ollama_operator_tpu.runtime.scheduler import RequestStats, Scheduler
 from ollama_operator_tpu.runtime.service import StopMatcher
 from ollama_operator_tpu.runtime import service as svc
+from ollama_operator_tpu.runtime.trace import NULL_TRACE
 from ollama_operator_tpu.server.app import (_StreamCoalescer,
                                             resolve_stream_flush,
                                             STREAM_FLUSH_TOKENS)
@@ -112,6 +113,10 @@ def test_stream_truncates_stop_split_across_chunks():
             self.cancelled = False
             self.stats = RequestStats(n_prompt=2)
             self.stats.n_generated = sum(len(c) for c in chunks)
+            # what a Request carries beside its stream: an id, and the
+            # trace it has at TPU_TRACE=0
+            self.id = 1
+            self.trace = NULL_TRACE
 
         def chunks(self):
             for c in chunks:

@@ -70,7 +70,7 @@ def _sigs_only(monkeypatch):
 
 def _recompile_total():
     return sum(METRICS.get("tpu_model_recompiles_total", f'{{kind="{k}"}}')
-               for k in ("decode", "admit", "admit_many", "extend", "spec"))
+               for k in ("decode", "admit", "admit_many", "extend"))
 
 
 class TestEngineSnapshot:
@@ -120,6 +120,24 @@ class TestEngineSnapshot:
         assert out["restored"] == 0
         assert out["compiled"] == 2
         assert len(eng._warmed_sigs) == 2
+        assert all(v == 0 for v in eng.recompiles.values())
+
+    def test_a_kind_this_build_no_longer_has_is_skipped(
+            self, model, donor_blob, monkeypatch):
+        """A snapshot saved by a build that still warmed speculative
+        verify programs lists ("spec", (k, bucket)) entries, payloads
+        included: the restore skips them and warms the rest."""
+        cfg, params = model
+        _, blob = donor_blob
+        snap = pickle.loads(blob)
+        snap["sigs"] = snap["sigs"][:2] + [("spec", (3, 16))]
+        snap["execs"] = {("spec", (3, 16)): (b"gone", pickle.dumps((0, 0)))}
+        eng = Engine(cfg, params, ecfg=ECFG)
+        assert not eng._install_exec(("spec", (3, 16)), object())
+        out = eng.restore_warm(pickle.dumps(snap))
+        assert out == {"restored": 0, "compiled": 2}
+        assert {s[0] for s in eng._warmed_sigs} <= {"decode", "admit",
+                                                    "admit_many", "extend"}
         assert all(v == 0 for v in eng.recompiles.values())
 
     def test_unknown_snapshot_version_rejected(self, model):
