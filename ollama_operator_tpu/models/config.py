@@ -60,7 +60,8 @@ class ModelConfig:
                                        # NO norm biases
     norm_weight_offset: float = 0.0    # gemma: weight stored as (w - 1)
     mlp_type: str = "gated"            # "gated" (silu/gelu gate*up) | "plain"
-    act: str = "silu"                  # "silu" | "gelu" | "gelu_tanh"
+    act: str = "silu"                  # "silu" | "relu" | "gelu" |
+                                       # "gelu_tanh"
     parallel_block: bool = False       # phi-2: attn and mlp share the input LN
     attn_bias: bool = False            # qwen2/phi-2: bias on q/k/v
     out_bias: bool = False             # phi-2: bias on o/mlp projections
@@ -126,6 +127,12 @@ class ModelConfig:
     moe_score: str = "softmax"
     moe_select_bias: bool = False
     moe_scale: float = 1.0             # routed_scaling_factor
+    # the stream the router reads, a property of the architecture: "mlp" =
+    # the normed input of the feed-forward, after the mixer's residual add
+    # (every routed stack but one); "block" = the layer's own input,
+    # un-normed, ahead of the mixer (smallthinker: the gates are a function
+    # of what enters the layer); hybrid stacks only
+    moe_router_input: str = "mlp"
     # leading layers whose feed-forward is one dense gated MLP of width
     # dense_ffn_dim in a stack whose other layers are routed (lfm2_moe
     # num_dense_layers / intermediate_size); hybrid stacks only
@@ -384,11 +391,16 @@ class ModelConfig:
                 "rope_orig_ctx")
         assert self.norm_type in ("rmsnorm", "layernorm")
         assert self.mlp_type in ("gated", "plain")
-        assert self.act in ("silu", "gelu", "gelu_tanh")
+        assert self.act in ("silu", "relu", "gelu", "gelu_tanh")
         assert self.kernels in ("auto", "pallas", "xla", "interpret")
         assert self.mm_kernels in ("auto", "pallas", "xla", "interpret")
         assert self.moe_impl in ("auto", "einsum", "scan")
         assert self.moe_score in ("softmax", "sigmoid")
+        assert self.moe_router_input in ("mlp", "block")
+        if self.moe_router_input == "block":
+            assert self.layer_kinds and self.n_experts, (
+                "a router that reads the block's input is routed ahead of "
+                "the mixer by the hybrid stacks' scan")
         if self.moe_select_bias or self.moe_scale != 1.0:
             assert self.moe_score == "sigmoid", (
                 "a selection bias and a scaling factor belong to the "
@@ -706,6 +718,32 @@ PRESETS = {
         n_dense_layers=1, dense_ffn_dim=96, layer_kinds="wwwAwwwA",
         sliding_window=8, rope_kinds="w", qk_norm=True,
         rope_theta=1000000.0, max_seq_len=256),
+    # SmallThinker-21BA3B-Instruct (smallthinker), cut in DEPTH alone:
+    # pipeline stage 0 of seven, layers 0-7 of 52, two whole periods A w w w
+    # (a full layer without positional embedding, then three window layers
+    # of 4,096 positions that rotate at theta 1.5e6), every expert and the
+    # whole untied vocabulary. Every width is the published one: hidden
+    # 2560, GQA 28/4 at head_dim 128 without q/k norms, a softmax router
+    # over 64 ReLU-gated experts of 768 keeping 6 a token, which reads the
+    # layer's un-normed INPUT ahead of attention (moe_router_input), no
+    # shared expert, no dense layer. benchmark/configs/
+    # smallthinker-21b-a3b.json states the cut and what the published config
+    # leaves to assumption.
+    "smallthinker-21b-a3b": _mk(
+        arch="smallthinker", vocab_size=151936, dim=2560, n_layers=8,
+        n_heads=28, n_kv_heads=4, head_dim=128, ffn_dim=768, n_experts=64,
+        n_experts_used=6, moe_score="softmax", moe_renorm=True, act="relu",
+        moe_router_input="block", layer_kinds="AwwwAwww",
+        sliding_window=4096, rope_kinds="w", rope_theta=1500000.0,
+        tie_embeddings=False, norm_eps=1e-6, max_seq_len=16384),
+    # the same shape at toy widths (tests, --rehearse): two periods
+    "tiny-smallthinker": _mk(
+        arch="smallthinker", vocab_size=512, dim=64, n_layers=8, n_heads=4,
+        n_kv_heads=2, head_dim=16, ffn_dim=32, n_experts=8,
+        n_experts_used=3, moe_score="softmax", moe_renorm=True, act="relu",
+        moe_router_input="block", layer_kinds="AwwwAwww", sliding_window=8,
+        rope_kinds="w", rope_theta=1500000.0, tie_embeddings=False,
+        norm_eps=1e-6, max_seq_len=2048),
     # Olmo-Hybrid-7B (olmo_hybrid), cut in DEPTH alone: the first three
     # whole periods of the 32 layers, d d d A (layers 0-11; the others would
     # lie on further chips as pipeline stages). Every width, every head

@@ -344,6 +344,8 @@ def _norm(cfg: ModelConfig, x, w, b=None):
 def _act(cfg: ModelConfig, x):
     if cfg.act == "silu":
         return jax.nn.silu(x)
+    if cfg.act == "relu":
+        return jax.nn.relu(x)
     if cfg.act == "gelu":
         return jax.nn.gelu(x, approximate=False)
     return jax.nn.gelu(x, approximate=True)
@@ -381,10 +383,14 @@ def _moe_gates(cfg: ModelConfig, lp, xf):
     return gates.at[jnp.arange(N)[:, None], topi].set(topw)
 
 
-def _moe_mlp(cfg: ModelConfig, lp, x, tap=None):
+def _moe_mlp(cfg: ModelConfig, lp, x, tap=None, gates=None):
     """Sparse-MoE gated MLP (mixtral family), exact (no token dropping).
     ``tap``: a list the router's gates [N, E] are appended to, for a
     caller that counts what was routed where (``_expert_load``).
+    ``gates``: the router's gates where the caller already has them (a
+    router that reads the layer's input, ``cfg.moe_router_input``
+    "block": ``_hybrid_layers`` routes before it mixes); routed here from
+    ``x`` otherwise.
 
     Every expert computes over all tokens and the combine applies the gate
     (zero for unselected) — on TPU decode this costs nothing extra where it
@@ -404,8 +410,9 @@ def _moe_mlp(cfg: ModelConfig, lp, x, tap=None):
     """
     B, T, D = x.shape
     xf = x.reshape(B * T, D)
-    with device_scope("moe.route"):
-        gates = _moe_gates(cfg, lp, xf)                      # [N, E] fp32
+    if gates is None:
+        with device_scope("moe.route"):
+            gates = _moe_gates(cfg, lp, xf)                  # [N, E] fp32
     if tap is not None:
         tap.append(gates)
     with device_scope("moe.experts"):
@@ -466,9 +473,9 @@ def _expert_load(gates, live):
 
 
 @device_scope("mlp")
-def _mlp(cfg: ModelConfig, lp, x, tap=None):
+def _mlp(cfg: ModelConfig, lp, x, tap=None, gates=None):
     if cfg.n_experts:
-        return _moe_mlp(cfg, lp, x, tap)
+        return _moe_mlp(cfg, lp, x, tap, gates)
     if cfg.mlp_type == "gated":
         g = _act(cfg, _mm(cfg, x, lp["w_gate"]))
         u = _mm(cfg, x, lp["w_up"])
@@ -514,7 +521,7 @@ def _proj_out(cfg, lp, attn_out, B, T):
     return o
 
 
-def _residual(cfg: ModelConfig, lp, x, h, attn, tap=None):
+def _residual(cfg: ModelConfig, lp, x, h, attn, tap=None, gates=None):
     rm = cfg.residual_multiplier or 1.0   # granite: scaled residual adds
     if cfg.post_norms:
         # gemma2 sandwich norms: attn/mlp OUTPUTS normed before the adds
@@ -530,7 +537,7 @@ def _residual(cfg: ModelConfig, lp, x, h, attn, tap=None):
     with device_scope("attn.out"):
         x = x + rm * attn
     h2 = _norm(cfg, x, lp["mlp_norm_w"], lp.get("mlp_norm_b"))
-    m = _mlp(cfg, lp, h2, tap)
+    m = _mlp(cfg, lp, h2, tap, gates)
     if cfg.post_norms:
         m = _norm(cfg, m, lp["post_ffw_norm_w"])
     with device_scope("mlp"):
@@ -809,7 +816,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         def attend_win(ap, h, win, row):
             q, k, v = _qkv(cfg_w, ap, h, cos, sin)
             out, win = _ring_attend(cfg_w, q, k, v, win, row, lengths, nv,
-                                    scale)
+                                    scale, A)
             return _proj_out(cfg, ap, out, B, T), win
 
         if cfg.kv_latent_dim:
@@ -850,14 +857,15 @@ def _given(load):
     return () if load is None else (load,)
 
 
-def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load):
+def _residual_counting(cfg: ModelConfig, lp, x, h, attn, live, load,
+                       gates=None):
     """``_residual``, and ``load`` [E] advanced by what this layer's router
     kept for the ``live`` rows (``load`` None, or a layer without a router:
-    handed back as it came)."""
+    handed back as it came). ``gates``: as ``_moe_mlp`` takes them."""
     if load is None:
-        return _residual(cfg, lp, x, h, attn), None
+        return _residual(cfg, lp, x, h, attn, gates=gates), None
     tap = []
-    x = _residual(cfg, lp, x, h, attn, tap)
+    x = _residual(cfg, lp, x, h, attn, tap, gates)
     return x, (load + _expert_load(tap[0], live) if tap else load)
 
 
@@ -1048,24 +1056,56 @@ def _ring_merge(old, new, lengths, n_valid):
     return jnp.where(fresh, new, old)
 
 
+# A decode step puts its one new position into a ring either way. The
+# select over the whole ring (``_ring_merge``) reads and writes every slot of
+# the layer's ring: 2 x B x KvH x W x (hd + 4) bytes of int8 codes and scales,
+# twice. The row write addresses one (slot, head) at a time, as a full
+# layer's ``attn.kv_write`` does, whatever W is. A ring of at most this many
+# positions takes the select, a longer one the row (PERF.md section 6, PR 50,
+# has both forms' times at W = 128 and W = 4,096 on the chip).
+_RING_SELECT_MAX = 512
+
+
+def _ring_put(ring, row, new, at, live):
+    """The ring stack [Lw, B, KvH, W, ...] with new [B, KvH, 1, ...] at ring
+    slot ``at`` [B] of layer ``row``, for the rows ``live`` [B] marks; the
+    others' slot is written back as it was read, so they keep their very
+    bits."""
+    B, KvH = new.shape[:2]
+    idx = (row, jnp.arange(B)[:, None, None], jnp.arange(KvH)[None, :, None],
+           at[:, None, None])
+    keep = (live != 0).reshape((B,) + (1,) * (new.ndim - 1))
+    return ring.at[idx].set(jnp.where(keep, new, ring[idx]))
+
+
 @device_scope("attn.window")
 def _ring_attend(cfg: ModelConfig, q, k, v, win, row, lengths, n_valid,
-                 scale):
+                 scale, depth: Optional[int] = None):
     """Window attention of one layer over its ring, and the ring advanced.
     q [B, T, H, hd], k and v [B, T, KvH, hd] at positions lengths + t (as
     ``_qkv`` leaves them); win = (k rings, v rings) [Lw, B, KvH, W, hd] or
     int8 {"q", "s"}, of which this layer reads and writes row ``row``;
     n_valid [B]: positions at or past it write nothing. A key is visible
-    iff its position is >= 0, <= the query's and > the query's - W. The
-    ring takes the block's last W real positions (``_ring_merge``: a select
-    over the whole ring, with which this scope is 0.55 ms of a decode step
-    of six layers at 64 slots; a scatter of one row a slot and head made it
-    1.56, 0.67 of that the scales' scatter alone: my chip runs, PR 39). One new
-    position (the decode step) attends over the ring with itself in it,
-    which then IS its window; more attend over the ring as it stood plus
-    the new block. Returns (out [B, T, H, hd], win)."""
+    iff its position is >= 0, <= the query's and > the query's - W.
+
+    ``depth`` (static) is the caller's attended prefix, as a full layer's
+    ``attn_len``: every row's lengths + T <= depth. The ring is read that
+    deep and no deeper: below W no ring has wrapped, so slot j IS position j
+    and the first ``depth`` slots hold every key there is; at W or past it
+    the whole ring is the window. A step's ring traffic so grows with the
+    live contexts, not with W.
+
+    One new position (the decode step) is written a row a slot where the
+    ring is long (``_ring_put``) and by a select over the whole ring where
+    it is short (``_RING_SELECT_MAX``), then attends over the ring with
+    itself in it, which IS its window. Several (a prefill piece, one slot's)
+    attend over the ring as it stood plus the new block, and the ring takes
+    the block's last W real positions by the select (``_ring_merge``: a
+    block may wrap the ring several times over). Returns (out [B, T, H,
+    hd], win)."""
     from ..ops import quant_cache as QC
     T, W = q.shape[1], cfg.sliding_window
+    A = W if depth is None else min(depth, W)
     quant = QC.is_quantized_cache(win[0])
     k = k.transpose(0, 2, 1, 3)                       # [B, KvH, T, hd]
     v = v.transpose(0, 2, 1, 3)
@@ -1085,20 +1125,40 @@ def _ring_attend(cfg: ModelConfig, q, k, v, win, row, lengths, n_valid,
         return attend_hf(q, ks.astype(q.dtype), vs.astype(q.dtype), mask,
                          scale, cfg.attn_softcap)
 
+    def layer(ring, n):
+        """The first n slots of this layer's ring, [B, KvH, n, ...] (the
+        whole ring as PR 39 took it, so that a short ring's programs stay
+        the ones the ledger measured)."""
+        if n == W:
+            return lax.dynamic_index_in_dim(ring, row, 0, keepdims=False)
+        return lax.squeeze(lax.dynamic_slice(
+            ring, (row,) + (0,) * (ring.ndim - 1),
+            (1,) + ring.shape[1:3] + (n,) + ring.shape[4:]), (0,))
+
     new = (stored(k, win[0]), stored(v, win[1]))
     q_pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    old = tree_map(
-        lambda ring: lax.dynamic_index_in_dim(ring, row, 0, False), win)
+    if T == 1 and W > _RING_SELECT_MAX:
+        win = tree_map(
+            lambda ring, x: _ring_put(ring, row, x, lengths % W, n_valid),
+            win, new)
+        cur = tree_map(lambda ring: layer(ring, A), win)
+        return attend(*cur, _ring_pos(lengths, W)[:, :A], q_pos), win
+    old = tree_map(lambda ring: layer(ring, W), win)
     merged = tree_map(lambda a, b: _ring_merge(a, b, lengths, n_valid),
                       old, new)
     win = tree_map(
         lambda ring, x: lax.dynamic_update_index_in_dim(ring, x, row, 0),
         win, merged)
+
+    def head(x):
+        return x if A == W else tree_map(lambda a: a[:, :, :A], x)
     if T == 1:
-        return attend(*merged, _ring_pos(lengths, W), q_pos), win
-    both = tree_map(lambda a, b: jnp.concatenate([a, b], axis=2), old, new)
+        return attend(*head(merged), _ring_pos(lengths, W)[:, :A],
+                      q_pos), win
+    both = tree_map(lambda a, b: jnp.concatenate([a, b], axis=2), head(old),
+                    new)
     return attend(*both, jnp.concatenate(
-        [_ring_pos(lengths - 1, W), q_pos], axis=1), q_pos), win
+        [_ring_pos(lengths - 1, W)[:, :A], q_pos], axis=1), q_pos), win
 
 
 def _valid_rows(n_valid, B: int, T: int):
@@ -1524,6 +1584,13 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
         x, kc, vc, ssm, conv, win, load = carry
         lp, is_attn, row, wrow = layer_in
         wrow = row if wrow is None else wrow
+        gates = None
+        if cfg.n_experts and cfg.moe_router_input == "block":
+            # a router that reads the layer's INPUT, un-normed, ahead of
+            # the mixer (smallthinker): its gates are ready before
+            # attention starts, and the experts take them as given
+            with device_scope("moe.route"):
+                gates = _moe_gates(cfg, lp, x.reshape(-1, x.shape[-1]))
         h = _norm(cfg, x, lp["attn_norm_w"], lp.get("attn_norm_b"))
 
         def attn_mixer(h, kc, vc, ssm, conv, win):
@@ -1559,7 +1626,7 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
             mixed = lax.cond(~is_attn, other_mixer, attn_mixer, h, kc, vc,
                              ssm, conv, win)
         out, kc, vc, ssm, conv, win = mixed
-        x, load = _residual_counting(cfg, lp, x, h, out, live, load)
+        x, load = _residual_counting(cfg, lp, x, h, out, live, load, gates)
         return (x, kc, vc, ssm, conv, win, load), None
 
     is_attn, rows, wrows = _hybrid_rows(cfg)

@@ -508,12 +508,17 @@ class Engine:
         # the quantized matmuls' path hangs on the mesh and the backend,
         # both known here and nowhere below
         cfg = resolve_mm_kernels(cfg, mesh)
+        B, S = ecfg.max_slots, min(ecfg.max_seq_len, cfg.max_seq_len)
+        if cfg.n_window_layers and cfg.sliding_window > S:
+            # a ring is never longer than the context the server was
+            # started at: no position a request can reach leaves a window
+            # that long, so the shorter ring drops the same keys (none)
+            cfg = dataclasses.replace(cfg, sliding_window=S)
         self.cfg = cfg
         self.ecfg = ecfg
         self.mesh = mesh
         # seconds inside the runtime's launch call, all told (_enqueue)
         self.enqueue_s = 0.0
-        B, S = ecfg.max_slots, min(ecfg.max_seq_len, cfg.max_seq_len)
         self.n_slots, self.max_seq = B, S
         # layers that keep keys and values at every position: all, but for
         # a hybrid stack (its window layers keep rings, part of the state)
@@ -3426,6 +3431,19 @@ class Engine:
     def kv_bytes(self) -> int:
         leaves = jax.tree_util.tree_leaves((self.k_cache, self.v_cache))
         return sum(l.size * l.dtype.itemsize for l in leaves)
+
+    @property
+    def ring_positions(self) -> Dict[str, int]:
+        """{"live", "allocated"}: positions the window layers' rings hold
+        against what they could (tpu_model_ring_positions{what}); empty for
+        a model without window layers. A slot's ring holds min(its
+        length, W) positions a window layer; from the host's mirror of the
+        lengths, every launched step counted: no device work."""
+        Lw, W = self.cfg.n_window_layers, self.cfg.sliding_window
+        if not Lw:
+            return {}
+        return {"live": Lw * int(np.minimum(self._host_lengths, W).sum()),
+                "allocated": Lw * self.n_slots * W}
 
     @property
     def state_bytes(self) -> int:

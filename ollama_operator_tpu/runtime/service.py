@@ -301,6 +301,11 @@ class LoadedModel:
                              lambda kind=kind: (lm := wself()) is not None
                              and lm.engine.cache_bytes[kind] or 0,
                              f'{{kind="{kind}"}}')
+        for what in getattr(self.engine, "ring_positions", ()):
+            METRICS.gauge_fn("tpu_model_ring_positions",
+                             lambda what=what: (lm := wself()) is not None
+                             and lm.engine.ring_positions[what] or 0,
+                             f'{{what="{what}"}}')
         if getattr(self.engine, "host_cache_enabled", False):
             # tier-1 host-arena occupancy: bytes and whole KV pages the
             # spilled radix subtrees hold in pinned host RAM (the spill /
@@ -906,6 +911,9 @@ class LoadedModel:
         for kind in getattr(self.engine, "cache_bytes", ()):
             METRICS.remove_gauge("tpu_model_cache_bytes",
                                  f'{{kind="{kind}"}}')
+        for what in getattr(self.engine, "ring_positions", ()):
+            METRICS.remove_gauge("tpu_model_ring_positions",
+                                 f'{{what="{what}"}}')
         if getattr(self.engine, "host_cache_enabled", False):
             METRICS.remove_gauge("tpu_model_host_cache_bytes")
             METRICS.remove_gauge("tpu_model_host_cache_pages")

@@ -318,6 +318,13 @@ GLOBAL.describe("tpu_model_cache_bytes",
                 "window-attention layers keep instead of a full row; "
                 "recurrent state; the keys of latent attention's indexer "
                 "(index: only where the model has one)")
+GLOBAL.describe("tpu_model_ring_positions",
+                "Positions the window-attention layers' rings of the "
+                "loaded model hold (what=live: min(a slot's length, the "
+                "ring's length) a slot a window layer, from the host's "
+                "mirror of the lengths) against the positions they were "
+                "allocated (what=allocated); only where the model has "
+                "window layers")
 GLOBAL.describe("tpu_model_async_fallback_total",
                 "Decode dispatches that fell back to synchronous while "
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "

@@ -517,6 +517,42 @@ def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A):
     assert bool(re.search(r"\bsort\(", compiled.as_text())) == (A > 2048)
 
 
+@pytest.mark.parametrize("name,B,T,A", [
+    ("smallthinker-21b-a3b", 64, 1, 1024), ("smallthinker-21b-a3b", 64, 1, 4096),
+    ("smallthinker-21b-a3b", 1, 256, 4096), ("k-exaone-236b-a23b", 64, 1, 1024)],
+    ids=["rows-shallow", "rows-deep", "extend", "select-128"])
+def test_ring_attention_compiles_at_published_widths(one_chip, name, B, T, A):
+    """One window layer against the int8 rings as the served programs run it
+    (the rings donated), at SmallThinker's widths (rings of 4,096: a decode
+    step writes a row a slot and its temporaries stay under one layer's
+    ring, at either attended depth; an extend piece merges one slot's ring)
+    and at K-EXAONE's (rings of 128: the select): every leaf of the rings is
+    updated in place."""
+    cfg = PRESETS[name]
+    cfg_w = decoder._kind_cfgs(cfg)[1]
+    Lw, W = 2, cfg.sliding_window
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    ring = {"q": sds((Lw, B, cfg.n_kv_heads, W, cfg.head_dim), jnp.int8),
+            "s": sds((Lw, B, cfg.n_kv_heads, W), jnp.float32)}
+
+    def layer(q, k, v, kr, vr, row, lengths, nv):
+        return decoder._ring_attend(cfg_w, q, k, v, (kr, vr), row, lengths,
+                                    nv, 0.088, A)
+
+    compiled = jax.jit(layer, donate_argnums=(3, 4)).lower(
+        sds((B, T, cfg.n_heads, cfg.head_dim), jnp.bfloat16),
+        sds((B, T, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16),
+        sds((B, T, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16),
+        ring, ring, sds((), jnp.int32), sds((B,), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    one_layer = B * cfg.n_kv_heads * W * (cfg.head_dim + 4)
+    assert mem.alias_size_in_bytes >= 2 * Lw * one_layer    # all four leaves
+    if T == 1:
+        assert mem.temp_size_in_bytes < one_layer   # no copy of a ring
+
+
 @pytest.mark.parametrize("name", ["granite-4.0-h-small", "lfm2-8b-a1b",
                                   "olmo-hybrid-7b"])
 def test_a_decode_steps_convolution_loops_over_no_slots(one_chip, name):
