@@ -825,7 +825,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
             def attend_full(ap, h, kc, vc, row):
                 return _latent_cached(cfg, ap, h, kc, vc, row, positions,
-                                      nv, A, cos, sin)
+                                      nv, A, cos, sin, mesh)
         else:
             def attend_full(ap, h, kc, vc, row):
                 return attend(ap, h, kc, vc, row, mask, cos, sin, cfg_a)
@@ -1683,7 +1683,10 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
 # The published inference code rotates qI and kI by a Hadamard matrix and
 # keeps them float8: a rotation leaves every dot product as it was and the
 # storage type here is the cache's, so neither is reproduced. The rows not
-# kept are masked, not skipped: a program reads its whole attended bucket.
+# kept are masked, not skipped. The einsum form reads its whole attended
+# bucket for every slot; the one-position step on a chip is a kernel that
+# walks each slot's own rows in the cache leaf itself (``_latent_kernel``,
+# ops/pallas/latent.py).
 
 _LATENT_Q_BLOCK = 128       # queries, over the batch, a pass of a long prefill
 
@@ -1832,19 +1835,22 @@ def _latent_expanded(cfg: ModelConfig, ap, q_nope, q_rope, row, qi, w, ki,
 
 
 def _latent_absorbed(cfg: ModelConfig, ap, q_nope, q_rope, rows, qi, w,
-                     ki_rows, q_pos):
+                     ki_rows, q_pos, kernel=None):
     """Attention over the first A cached positions, absorbed: rows [B, A, C
     + dr] and ki_rows [B, A, di] as the cache keeps them (int8: {"q", "s"},
     the rows' "s" [B, 2, A], latent part and rotated key), the new
-    positions already written. q_pos [B, T]. -> [B, T, H * dv]."""
+    positions already written. q_pos [B, T]. -> [B, T, H * dv]. With
+    ``kernel`` (``_latent_kernel``'s, T == 1) the scores, softmax and sum
+    are its walk over the cache itself and ``rows`` is not read."""
     from ..ops.quant_cache import is_quantized_cache
     B, T, H, _ = q_nope.shape
     C = cfg.kv_latent_dim
-    quant = is_quantized_cache(rows)
-    codes = rows["q"] if quant else rows
-    dt = q_nope.dtype
-    lat = codes[..., :C].astype(dt)
-    kr = codes[..., C:C + cfg.qk_rope_dim].astype(dt)   # zeros may follow
+    if kernel is None:
+        quant = is_quantized_cache(rows)
+        codes = rows["q"] if quant else rows
+        dt = q_nope.dtype
+        lat = codes[..., :C].astype(dt)
+        kr = codes[..., C:C + cfg.qk_rope_dim].astype(dt)  # zeros may follow
     with device_scope("attn.core"):
         q_abs = jnp.einsum("bthn,hnc->bthc", q_nope, ap["w_uk"])
 
@@ -1868,15 +1874,52 @@ def _latent_absorbed(cfg: ModelConfig, ap, q_nope, q_rope, rows, qi, w,
                 norm = norm * rows["s"][:, 0, None, None, :]
             return jnp.einsum("bhts,bsc->bthc", (e * norm).astype(dt), lat)
 
-    note_kernel("decode", "einsum")
-    o_lat = _by_query_blocks(block, T, q_abs, q_rope, qi, w, q_pos)
+    if kernel is not None:
+        # below index_topk positions nothing is chosen: the kernel's own
+        # test of visibility is the whole mask
+        A = (ki_rows["q"] if is_quantized_cache(ki_rows) else ki_rows).shape[1]
+        keep = (_index_mask(cfg, qi, w, ki_rows, q_pos)[:, 0]
+                if A > cfg.index_topk else None)
+        with device_scope("attn.core"):
+            o_lat = kernel(q_abs[:, 0], q_rope[:, 0], keep)[:, None]
+    else:
+        o_lat = _by_query_blocks(block, T, q_abs, q_rope, qi, w, q_pos)
     with device_scope("attn.core"):
         out = jnp.einsum("bthc,hcv->bthv", o_lat, ap["w_uv"])
     return out.reshape(B, T, -1)
 
 
+def _latent_kernel(cfg: ModelConfig, mesh, T: int, kc, row_i, q_pos,
+                   n_valid):
+    """The one place that decides how a latent layer's cached rows are
+    attended: ``ops/pallas/latent.latent_decode`` over layer ``row_i`` of the
+    leaf ``kc`` itself, each slot to its own length, for the one-position
+    step on one device where ``cfg.kernels`` resolves to a kernel and the
+    widths tile (``latent_decode_tileable``); else None, and the caller reads
+    a window of the leaf through the einsum form: by design for T > 1 (an
+    ``extend`` piece, the probe's prefill), flagged ``kernel_fallback`` where
+    the kernel was wanted. Returns (q_abs [B, H, C], q_rope [B, H, dr], keep
+    [B, A] or None) -> o_lat [B, H, C]."""
+    from ..ops.attention import resolve_kernels
+    from ..ops.pallas.latent import latent_decode, latent_decode_tileable
+    from ..ops.quant_cache import is_quantized_cache
+    mode = resolve_kernels(cfg.kernels)
+    wanted = T == 1 and mode in ("pallas", "interpret")
+    interp = mode == "interpret"
+    S, W = (kc["q"] if is_quantized_cache(kc) else kc).shape[-2:]
+    if not (wanted and (mesh is None or mesh.size == 1)
+            and latent_decode_tileable(cfg.n_heads, cfg.kv_latent_dim, W, S,
+                                       interp)):
+        note_kernel("decode", "einsum", fell_back=wanted)
+        return None
+    note_kernel("decode", "latent_decode")
+    return lambda q_abs, q_rope, keep: latent_decode(
+        kc, row_i, q_abs, q_rope, q_pos[:, 0], n_valid, keep,
+        _latent_scale(cfg), interpret=interp)
+
+
 def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
-                   n_valid, A: int, cos, sin):
+                   n_valid, A: int, cos, sin, mesh=None):
     """One latent-attention layer against row ``row_i`` of the cache: kc
     the rows [La, B, 1, S, C + dr], vc the indexer's keys [La, B, 1, S, di]
     (either int8 {"q", "s"}). Writes the new positions' rows and keys
@@ -1922,9 +1965,6 @@ def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
         with device_scope("attn.index"):
             iq, is_ = QC.quantize_kv(ki)
             vc = {"q": put(vc["q"], iq), "s": put(vc["s"], is_)}
-        rows = {"q": window(kc["q"], 4)[0, :, 0],
-                "s": lax.dynamic_slice(
-                    kc["s"], (row_i, 0, 0, 0), (1, B, 2, A))[0]}
         ki_rows = {"q": window(vc["q"], 4)[0, :, 0],
                    "s": window(vc["s"], 4)[0, :, 0]}
     else:
@@ -1932,9 +1972,16 @@ def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
             kc = put(kc, jnp.pad(row, pad))
         with device_scope("attn.index"):
             vc = put(vc, ki)
-        rows, ki_rows = window(kc, 4)[0, :, 0], window(vc, 4)[0, :, 0]
+        ki_rows = window(vc, 4)[0, :, 0]
+    kernel = _latent_kernel(cfg, mesh, T, kc, row_i, positions, n_valid)
+    rows = None                 # the kernel reads the leaf itself
+    if kernel is None:
+        rows = ({"q": window(kc["q"], 4)[0, :, 0],
+                 "s": lax.dynamic_slice(
+                     kc["s"], (row_i, 0, 0, 0), (1, B, 2, A))[0]}
+                if quant else window(kc, 4)[0, :, 0])
     out = _latent_absorbed(cfg, ap, q_nope, q_rope, rows, qi, w, ki_rows,
-                           positions)
+                           positions, kernel)
     return _proj_out(cfg, ap, out, B, T), kc, vc
 
 

@@ -477,16 +477,25 @@ def test_delta_mixer_compiles_at_published_widths(one_chip, B, T):
         assert mem.temp_size_in_bytes < one_layer // 16
 
 
-@pytest.mark.parametrize("B,T,A", [(64, 1, 4096), (64, 1, 1024),
-                                   (1, 256, 4096)],
-                         ids=["decode-deep", "decode-shallow", "extend"])
-def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A):
+@pytest.mark.parametrize("B,T,A,kernels", [
+    (64, 1, 4096, "xla"), (64, 1, 1024, "xla"), (1, 256, 4096, "xla"),
+    (64, 1, 1024, "pallas"), (64, 1, 2048, "pallas"), (64, 1, 4096, "pallas"),
+    (1, 256, 4096, "pallas")],
+    ids=["decode-deep", "decode-shallow", "extend", "kernel-1024",
+         "kernel-2048", "kernel-4096", "kernel-extend"])
+def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A,
+                                                       kernels):
     """One latent-attention layer against the int8 cache as the served
     programs run it (both caches donated), at GLM-5's widths: the decode
     step at the bucket where the indexer chooses (a top-k of 2,048 of 4,096)
-    and at one where it does not, and an extend piece; the rows and the
-    indexer's keys are written in place."""
-    cfg = PRESETS["glm-5"]
+    and at ones where it does not, and an extend piece; the rows and the
+    indexer's keys are written in place. In the einsum form (``xla``, and
+    any T > 1) and through the kernel that walks each slot's own rows
+    (``pallas`` at T == 1, PR 51: ``latent_decode`` in the optimized program,
+    which then holds no copy of the layer's attended window of rows, [64, A,
+    640] int8, the einsum form's 3.77 ms a step)."""
+    import re
+    cfg = dataclasses.replace(PRESETS["glm-5"], kernels=kernels)
     La = 2
     sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
         shape, dt, sharding=one_chip)
@@ -510,11 +519,16 @@ def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A):
         ap, sds((B, T, cfg.dim), jnp.bfloat16), kc, vc, sds((), jnp.int32),
         sds((B,), jnp.int32), sds((B,), jnp.int32)).compile()
     mem = compiled.memory_analysis()
+    text = compiled.as_text()
     cache = La * B * 4096 * (kd + vd + 12)
     assert mem.alias_size_in_bytes >= cache             # all four leaves
     # the top-k is a sort of the bucket, there only where the indexer chooses
-    import re
-    assert bool(re.search(r"\bsort\(", compiled.as_text())) == (A > 2048)
+    assert bool(re.search(r"\bsort\(", text)) == (A > 2048)
+    kernel = kernels == "pallas" and T == 1
+    assert ("latent_decode" in text) == kernel
+    if kernel:
+        assert "tpu_custom_call" in text
+        assert not re.search(rf"s8\[{B},{A},{kd}\]", text)
 
 
 @pytest.mark.parametrize("name,B,T,A", [

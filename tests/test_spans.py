@@ -991,7 +991,7 @@ def test_every_kernel_site_is_named_from_the_vocabulary():
                       if isinstance(c, ast.Call) and c.args
                       and isinstance(c.args[0], ast.Constant)
                       and ast.unparse(c.func) == "_fused"}
-    assert sites == 6
+    assert sites == 7
     assert named == set(KERNEL_NAMES)
     assert len(set(KERNEL_NAMES)) == len(KERNEL_NAMES)
 
@@ -1014,6 +1014,27 @@ def test_the_delta_kernel_runs_under_the_scope_its_metric_reads():
         dp, u, ssm, conv, jax.numpy.int32(1),
         jax.numpy.ones((2,), jax.numpy.int32)).as_text(debug_info=True)
     assert re.search(r'delta\.update/[^"]*delta_update', text)
+
+
+def test_the_latent_kernel_runs_under_the_scope_its_metrics_read():
+    """``decode_attn_ms_per_step`` and ``paged_attn_roofline`` read
+    ``attn.core``: the decode step's kernel call lies under that scope, by
+    its name, with the two einsums around it."""
+    import dataclasses
+
+    from ollama_operator_tpu.models import decoder
+    cfg = dataclasses.replace(cfglib.PRESETS["tiny-glm5"],
+                              kernels="interpret")
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jax.numpy.float32)
+    _, kd, vd = cfg.cache_row_dims
+    zeros = jax.numpy.zeros
+    text = jax.jit(lambda *a: decoder.forward_with_cache(
+        params, cfg, *a, attn_len=32)).lower(
+        zeros((2, 1), jax.numpy.int32), zeros((4, 2, 1, 64, kd)),
+        zeros((4, 2, 1, 64, vd)), jax.numpy.array([3, 20], jax.numpy.int32)
+    ).as_text(debug_info=True)
+    assert re.search(r'attn\.core/[^"]*latent_decode', text)
 
 
 def test_moe_scopes_nest_under_mlp():
