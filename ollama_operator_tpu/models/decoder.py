@@ -816,7 +816,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
         def attend_win(ap, h, win, row):
             q, k, v = _qkv(cfg_w, ap, h, cos, sin)
             out, win = _ring_attend(cfg_w, q, k, v, win, row, lengths, nv,
-                                    scale, A)
+                                    scale, A, mesh)
             return _proj_out(cfg, ap, out, B, T), win
 
         if cfg.kv_latent_dim:
@@ -1062,7 +1062,11 @@ def _ring_merge(old, new, lengths, n_valid):
 # twice. The row write addresses one (slot, head) at a time, as a full
 # layer's ``attn.kv_write`` does, whatever W is. A ring of at most this many
 # positions takes the select, a longer one the row (PERF.md section 6, PR 50,
-# has both forms' times at W = 128 and W = 4,096 on the chip).
+# has both forms' times at W = 128 and W = 4,096 on the chip). The line is
+# also where the read changes: a short ring is attended whole through the
+# einsum form, a long one on a chip through ``ops/pallas/ring.ring_decode``,
+# each slot to its own live depth (``_ring_kernel``; PERF.md section 6,
+# PR 52).
 _RING_SELECT_MAX = 512
 
 
@@ -1078,9 +1082,38 @@ def _ring_put(ring, row, new, at, live):
     return ring.at[idx].set(jnp.where(keep, new, ring[idx]))
 
 
+def _ring_kernel(cfg: ModelConfig, mesh, q, row, lengths, n_valid, scale):
+    """The one place that decides how a window layer's ring is attended:
+    ``ops/pallas/ring.ring_decode`` over layer ``row`` of the rings
+    themselves, each slot to its own live depth, for one new position (T ==
+    1) against a ring longer than ``_RING_SELECT_MAX``, on one device, where
+    ``cfg.kernels`` resolves to a kernel and the shapes tile
+    (``ring_decode_tileable``); else None, and the caller reads the ring
+    through the einsum form: by design for a short ring and for T > 1 (an
+    ``extend`` piece, an admission, the probe's prefill), flagged
+    ``kernel_fallback`` where a long ring wanted the kernel. Returns win (the
+    rings, the new position written) -> out [B, 1, H, hd]."""
+    from ..ops.attention import resolve_kernels
+    from ..ops.pallas.ring import ring_decode, ring_decode_tileable
+    B, T, H, hd = q.shape
+    W = cfg.sliding_window
+    mode = resolve_kernels(cfg.kernels)
+    wanted = (T == 1 and W > _RING_SELECT_MAX
+              and mode in ("pallas", "interpret"))
+    interp = mode == "interpret"
+    if not (wanted and (mesh is None or mesh.size == 1)
+            and ring_decode_tileable(B, H, cfg.n_kv_heads, hd, W, interp)):
+        note_kernel("window", "einsum", fell_back=wanted)
+        return None
+    note_kernel("window", "ring_decode")
+    return lambda win: ring_decode(
+        win[0], win[1], row, q[:, 0], lengths, n_valid, scale,
+        cfg.attn_softcap, interpret=interp)[:, None]
+
+
 @device_scope("attn.window")
 def _ring_attend(cfg: ModelConfig, q, k, v, win, row, lengths, n_valid,
-                 scale, depth: Optional[int] = None):
+                 scale, depth: Optional[int] = None, mesh=None):
     """Window attention of one layer over its ring, and the ring advanced.
     q [B, T, H, hd], k and v [B, T, KvH, hd] at positions lengths + t (as
     ``_qkv`` leaves them); win = (k rings, v rings) [Lw, B, KvH, W, hd] or
@@ -1089,18 +1122,22 @@ def _ring_attend(cfg: ModelConfig, q, k, v, win, row, lengths, n_valid,
     iff its position is >= 0, <= the query's and > the query's - W.
 
     ``depth`` (static) is the caller's attended prefix, as a full layer's
-    ``attn_len``: every row's lengths + T <= depth. The ring is read that
-    deep and no deeper: below W no ring has wrapped, so slot j IS position j
-    and the first ``depth`` slots hold every key there is; at W or past it
-    the whole ring is the window. A step's ring traffic so grows with the
-    live contexts, not with W.
+    ``attn_len``: every row's lengths + T <= depth. The einsum form reads the
+    ring that deep and no deeper: below W no ring has wrapped, so slot j IS
+    position j and the first ``depth`` slots hold every key there is; at W
+    or past it the whole ring is the window.
 
     One new position (the decode step) is written a row a slot where the
     ring is long (``_ring_put``) and by a select over the whole ring where
     it is short (``_RING_SELECT_MAX``), then attends over the ring with
-    itself in it, which IS its window. Several (a prefill piece, one slot's)
-    attend over the ring as it stood plus the new block, and the ring takes
-    the block's last W real positions by the select (``_ring_merge``: a
+    itself in it, which IS its window. A long ring on a chip is read by
+    ``ring_decode`` (``_ring_kernel``), each slot's own min(lengths + 1, W)
+    ring slots where they lie: the bucket, which the longest live context
+    sets, no longer bounds what a slot reads, and a step's ring traffic is
+    the live positions'. Elsewhere the einsum form reads ``depth`` slots of
+    every ring. Several positions (a prefill piece, one slot's) attend over
+    the ring as it stood plus the new block, and the ring takes the block's
+    last W real positions by the select (``_ring_merge``: a
     block may wrap the ring several times over). Returns (out [B, T, H,
     hd], win)."""
     from ..ops import quant_cache as QC
@@ -1137,10 +1174,13 @@ def _ring_attend(cfg: ModelConfig, q, k, v, win, row, lengths, n_valid,
 
     new = (stored(k, win[0]), stored(v, win[1]))
     q_pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    kernel = _ring_kernel(cfg, mesh, q, row, lengths, n_valid, scale)
     if T == 1 and W > _RING_SELECT_MAX:
         win = tree_map(
             lambda ring, x: _ring_put(ring, row, x, lengths % W, n_valid),
             win, new)
+        if kernel is not None:
+            return kernel(win), win
         cur = tree_map(lambda ring: layer(ring, A), win)
         return attend(*cur, _ring_pos(lengths, W)[:, :A], q_pos), win
     old = tree_map(lambda ring: layer(ring, W), win)

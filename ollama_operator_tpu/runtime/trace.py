@@ -333,14 +333,15 @@ DEVICE_SCOPES = ("embed", "attn.qkv", "attn.kv_write", "attn.core",
                  "delta.conv", "delta.update", "delta.gate_norm", "delta.out",
                  "lm_head", "sample")
 
-# Kernels: the `name=` of every `pl.pallas_call` site under ops/pallas/ (seven
+# Kernels: the `name=` of every `pl.pallas_call` site under ops/pallas/ (eight
 # sites; the fused matmul's one site serves two names), which is what a device
 # trace calls the kernel's operation, under whichever scope above it ran
 # (`delta_update` under delta.update, PR 45; `latent_decode` under attn.core,
-# PR 51). tests/test_spans.py holds the sites to this list.
+# PR 51; `ring_decode` under attn.window, PR 52). tests/test_spans.py holds
+# the sites to this list.
 KERNEL_NAMES = ("flash_prefill", "decode_attention", "paged_v3",
                 "paged_kv_write", "qmm_pallas", "qmm4_pallas", "delta_update",
-                "latent_decode")
+                "latent_decode", "ring_decode")
 
 # Request stages folded into tpu_model_request_stage_seconds{stage=...}
 STAGES = ("ingress", "queue", "prefill", "first_flush", "decode")

@@ -531,18 +531,27 @@ def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A,
         assert not re.search(rf"s8\[{B},{A},{kd}\]", text)
 
 
-@pytest.mark.parametrize("name,B,T,A", [
-    ("smallthinker-21b-a3b", 64, 1, 1024), ("smallthinker-21b-a3b", 64, 1, 4096),
-    ("smallthinker-21b-a3b", 1, 256, 4096), ("k-exaone-236b-a23b", 64, 1, 1024)],
-    ids=["rows-shallow", "rows-deep", "extend", "select-128"])
-def test_ring_attention_compiles_at_published_widths(one_chip, name, B, T, A):
+@pytest.mark.parametrize("name,B,T,A,kernels", [
+    ("smallthinker-21b-a3b", 64, 1, 1024, "xla"),
+    ("smallthinker-21b-a3b", 64, 1, 4096, "xla"),
+    ("smallthinker-21b-a3b", 1, 256, 4096, "xla"),
+    ("k-exaone-236b-a23b", 64, 1, 1024, "xla"),
+    ("smallthinker-21b-a3b", 64, 1, 4096, "pallas"),
+    ("smallthinker-21b-a3b", 1, 256, 4096, "pallas"),
+    ("k-exaone-236b-a23b", 64, 1, 1024, "pallas")],
+    ids=["rows-shallow", "rows-deep", "extend", "select-128", "kernel-deep",
+         "kernel-extend", "kernel-select-128"])
+def test_ring_attention_compiles_at_published_widths(one_chip, name, B, T, A,
+                                                     kernels):
     """One window layer against the int8 rings as the served programs run it
     (the rings donated), at SmallThinker's widths (rings of 4,096: a decode
     step writes a row a slot and its temporaries stay under one layer's
     ring, at either attended depth; an extend piece merges one slot's ring)
     and at K-EXAONE's (rings of 128: the select): every leaf of the rings is
-    updated in place."""
-    cfg = PRESETS[name]
+    updated in place. In the einsum form (``xla``) and where the kernels are
+    on (``pallas``): a long ring's decode step reads through ``ring_decode``
+    (PR 52), a piece and a short ring keep the einsum form."""
+    cfg = dataclasses.replace(PRESETS[name], kernels=kernels)
     cfg_w = decoder._kind_cfgs(cfg)[1]
     Lw, W = 2, cfg.sliding_window
     sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
@@ -565,6 +574,8 @@ def test_ring_attention_compiles_at_published_widths(one_chip, name, B, T, A):
     assert mem.alias_size_in_bytes >= 2 * Lw * one_layer    # all four leaves
     if T == 1:
         assert mem.temp_size_in_bytes < one_layer   # no copy of a ring
+    kernel = kernels == "pallas" and T == 1 and W > decoder._RING_SELECT_MAX
+    assert ("ring_decode" in compiled.as_text()) == kernel
 
 
 @pytest.mark.parametrize("name", ["granite-4.0-h-small", "lfm2-8b-a1b",
@@ -592,3 +603,53 @@ def test_a_decode_steps_convolution_loops_over_no_slots(one_chip, name):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= conv.size * 4         # in place
     assert mem.temp_size_in_bytes < conv.size * 4 // L * 4  # a few rows
+
+
+def test_smallthinkers_deepest_decode_program_holds_the_ring_kernel(
+        one_chip, monkeypatch):
+    """``decode.(32, 4096)`` of the smallthinker cell as the benchmark's
+    child resolves it (bfloat16 weights as shapes, int8 cache, 64 slots,
+    rings of 4,096), compiled for the described v5e: the window layers' read
+    is ``ring_decode`` (PR 52), said so and flagged nothing, and no ring leaf
+    is re-laid outside the program's entry: the scales' leaves reach the
+    kernel as the row write's scatter leaves them (a kernel that takes them
+    as declared has both copied whole, 2 x 25 MB, in front of every window
+    layer's call)."""
+    import os
+    import re
+
+    from benchmark import server_child as sc
+    from ollama_operator_tpu.runtime import engine as E
+    name = "smallthinker-21b-a3b"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    conf = sc.load_conf(os.path.join(os.path.dirname(sc.__file__), "configs",
+                                     name + ".json"), False)
+    cfg = sc.model_config(conf, False)
+    dtype, ecfg = sc.resolve(cfg, "tpu", False)
+    assert (dtype, ecfg.max_slots, ecfg.decode_chunk) == ("bfloat16", 64, 32)
+    params = jax.eval_shape(
+        sc.weights_program(cfg, 0, jnp.bfloat16, ()), jax.random.key(0))
+    texts = {}
+
+    def spy(self, kind, key, jit_fn, *args):
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+        with E.record_kernels() as picked:
+            texts[kind, key] = jit_fn.lower(*args).compile().as_text()
+        texts["picked"] = sorted(picked)
+    monkeypatch.setattr(E.Engine, "_compile", spy)
+    eng = E.Engine(cfg, params, mesh=None, ecfg=ecfg)
+    eng._decode_n_exec(ecfg.decode_chunk, 4096)
+    text = texts["decode", (32, 4096)]
+    assert ("window", "ring_decode", False) in texts["picked"]
+    assert not any(fell_back for _, _, fell_back in texts["picked"])
+    assert "ring_decode" in text and "tpu_custom_call" in text
+    W, KvH, hd = eng.cfg.sliding_window, cfg.n_kv_heads, cfg.head_dim
+    leaf = rf"(?:f32\[6,64,{KvH},{W}\]|s8\[6,64,{KvH},{W},{hd}\])"
+    entry = False
+    for line in text.splitlines():
+        if re.match(r"^(ENTRY )?%?[\w.\-]+ \(", line):
+            entry = line.startswith("ENTRY")
+        assert entry or not re.search(
+            rf"= {leaf}\S* (?:copy|copy-start)\(", line), line[:200]

@@ -991,7 +991,7 @@ def test_every_kernel_site_is_named_from_the_vocabulary():
                       if isinstance(c, ast.Call) and c.args
                       and isinstance(c.args[0], ast.Constant)
                       and ast.unparse(c.func) == "_fused"}
-    assert sites == 7
+    assert sites == 8
     assert named == set(KERNEL_NAMES)
     assert len(set(KERNEL_NAMES)) == len(KERNEL_NAMES)
 
@@ -1035,6 +1035,29 @@ def test_the_latent_kernel_runs_under_the_scope_its_metrics_read():
         zeros((4, 2, 1, 64, vd)), jax.numpy.array([3, 20], jax.numpy.int32)
     ).as_text(debug_info=True)
     assert re.search(r'attn\.core/[^"]*latent_decode', text)
+
+
+def test_the_ring_kernel_runs_under_the_scope_its_metrics_read(monkeypatch):
+    """``decode_window_attn_ms_per_step`` and ``ring_attn_roofline`` read
+    ``attn.window``: a long ring's decode step lies under that scope whole,
+    the row write and the kernel's call, by its name."""
+    import dataclasses
+
+    from ollama_operator_tpu.models import decoder
+    monkeypatch.setattr(decoder, "_RING_SELECT_MAX", 0)
+    cfg = dataclasses.replace(cfglib.PRESETS["tiny-smallthinker"],
+                              kernels="interpret")
+    params = decoder.init_params(cfg, jax.random.PRNGKey(0),
+                                 dtype=jax.numpy.float32)
+    zeros = jax.numpy.zeros
+    kc = zeros((cfg.n_full_layers, 2, cfg.n_kv_heads, 64, cfg.head_dim))
+    K, V = decoder.join_state(kc, kc, decoder.empty_state(cfg, 2))
+    text = jax.jit(lambda *a: decoder.forward_with_cache(
+        params, cfg, *a, attn_len=32)).lower(
+        zeros((2, 1), jax.numpy.int32), K, V,
+        jax.numpy.array([3, 20], jax.numpy.int32)).as_text(debug_info=True)
+    assert re.search(r'attn\.window/[^"]*ring_decode', text)
+    assert re.search(r'attn\.window/[^"]*scatter', text)
 
 
 def test_moe_scopes_nest_under_mlp():
