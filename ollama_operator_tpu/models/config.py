@@ -47,6 +47,14 @@ class ModelConfig:
     rope_high_freq_factor: float = 4.0
     rope_yarn_beta_fast: float = 32.0  # yarn correction-dim betas
     rope_yarn_beta_slow: float = 1.0
+    # yarn's two magnitudes where the config states them (rope_scaling
+    # mscale / mscale_all_dim, the DeepSeek-V3 convention): with m(s) =
+    # 0.1 s ln(factor) + 1, cos/sin scale by m(mscale) / m(mscale_all_dim)
+    # and the softmax scale by m(mscale_all_dim)^2. Both 0 = the Llama
+    # convention above (cos/sin by rope_attn_factor or 0.1 ln(factor) + 1,
+    # the softmax scale untouched)
+    rope_yarn_mscale: float = 0.0
+    rope_yarn_mscale_all_dim: float = 0.0
     # per-frequency factors from a GGUF rope_freqs.weight tensor
     # (llama3.1-family conversions bake their scheme into this); tuple so
     # the config stays hashable for jit static args
@@ -188,11 +196,13 @@ class ModelConfig:
     # expanded from ONE normed latent of kv_latent_dim a position, beside
     # ONE rotated key of qk_rope_dim all heads share; values are v_head_dim
     # wide. The cache holds that row, kv_latent_dim + qk_rope_dim channels a
-    # position a layer, and nothing a head. Beside it an indexer of
-    # index_heads heads of index_head_dim scores every cached position
-    # against the query (its key, index_head_dim channels a position a
-    # layer, is the cache's second row) and attention reads the index_topk
-    # positions of largest score. rope_interleave: rotated channels pair
+    # position a layer, and nothing a head. Beside it (glm_moe_dsa) an
+    # indexer of index_heads heads of index_head_dim scores every cached
+    # position against the query (its key, index_head_dim channels a
+    # position a layer, is the cache's second row) and attention reads the
+    # index_topk positions of largest score; the three index_* fields all 0
+    # (kimi_k2) = no indexer: attention reads every earlier position and
+    # the cache has the one row. rope_interleave: rotated channels pair
     # (2i, 2i + 1), as the checkpoint stores them, in attention and indexer
     kv_latent_dim: int = 0             # kv_lora_rank
     q_latent_dim: int = 0              # q_lora_rank
@@ -272,7 +282,8 @@ class ModelConfig:
         """(heads, channels of the keys' row, channels of the values' row)
         a position of a full layer's cache: a head's keys and values, or
         latent attention's one row [latent | rotated key] and the indexer's
-        key, which ride where keys and values do."""
+        key, which ride where keys and values do (0 channels where the
+        model has no indexer: nothing rides there)."""
         if self.kv_latent_dim:
             return (1, self.kv_latent_dim + self.qk_rope_dim
                     + self.latent_row_pad, self.index_head_dim)
@@ -292,6 +303,28 @@ class ModelConfig:
         if self.kv_latent_dim % 128:
             return 0
         return -self.qk_rope_dim % 128
+
+    @property
+    def latent_key_residual(self) -> int:
+        """Channels of the rotated key's SECOND code in a cached latent row
+        kept int8 (``ops/quant_cache.quantize_latent``): 0, a code a channel,
+        unless the softmax's scale is scaled up (YaRN's m(mscale_all_dim)^2
+        under the DeepSeek-V3 convention): every rounding of a key then
+        moves each attention weight by that factor more, and at the
+        published widths under seeded weights, where the rotated key is nine
+        tenths of the scores' spread, the decode step read 2.1-3.3% of the
+        reference's largest logit through plain int8 rows and 1.3-1.8
+        through bfloat16 ones (my chip runs, PR 53). Such a row keeps, in the
+        padding behind the rotated key, a second code a channel for what
+        the first rounded away: the same bytes a position, the same two
+        scales. Read from the widths: a row without that padding stays as
+        it is."""
+        dr = self.qk_rope_dim
+        if (self.rope_scaling_type == "yarn" and self.rope_scaling > 1.0
+                and self.rope_yarn_mscale_all_dim > 0
+                and self.kv_latent_dim and self.latent_row_pad >= dr):
+            return dr
+        return 0
 
     @property
     def ssm_inner(self) -> int:
@@ -389,6 +422,15 @@ class ModelConfig:
             assert self.rope_orig_ctx > 0, (
                 f"{self.rope_scaling_type} rope scaling requires "
                 "rope_orig_ctx")
+        if self.rope_yarn_mscale or self.rope_yarn_mscale_all_dim:
+            assert self.rope_scaling_type == "yarn", (
+                "mscale and mscale_all_dim are yarn's")
+            # the softmax's share of them is applied where latent attention
+            # scales its scores (decoder._latent_scale) and nowhere else:
+            # ordinary attention would run at a wrong magnitude unsaid
+            assert self.kv_latent_dim > 0, (
+                "mscale and mscale_all_dim (the DeepSeek-V3 convention of "
+                "yarn) are served under latent attention alone")
         assert self.norm_type in ("rmsnorm", "layernorm")
         assert self.mlp_type in ("gated", "plain")
         assert self.act in ("silu", "relu", "gelu", "gelu_tanh")
@@ -423,18 +465,26 @@ class ModelConfig:
                     "latent attention stands in a stack of its own: no "
                     "recurrent or window kind beside it has been served")
                 for field in ("q_latent_dim", "qk_nope_dim", "qk_rope_dim",
-                              "v_head_dim", "index_heads", "index_head_dim",
-                              "index_topk"):
+                              "v_head_dim"):
                     assert getattr(self, field) > 0, (
                         f"latent attention needs {field} > 0")
+                index = (self.index_heads, self.index_head_dim,
+                         self.index_topk)
+                assert all(x > 0 for x in index) or not any(index), (
+                    "an indexer has heads, a head size and a top-k, or "
+                    f"there is none: {index}")
                 assert (self.qk_rope_dim % 2 == 0
-                        and self.qk_rope_dim <= self.index_head_dim), (
+                        and (not self.index_heads
+                             or self.qk_rope_dim <= self.index_head_dim)), (
                     "the rotated channels pair up, and the indexer rotates "
                     "as many of its own")
-                assert (self.rope and self.rope_scaling_type == "none"
-                        and self.rope_scaling == 1.0 and not self.rope_kinds
-                        and not self.rope_freq_factors), (
-                    "latent attention rotates at rope_theta alone")
+                assert (self.rope and not self.rope_kinds
+                        and not self.rope_freq_factors
+                        and (self.rope_scaling_type == "yarn"
+                             or (self.rope_scaling_type == "none"
+                                 and self.rope_scaling == 1.0))), (
+                    "latent attention rotates at rope_theta alone or "
+                    "under yarn")
                 for field in ("attn_bias", "qk_norm", "attn_softcap",
                               "attn_scale", "attn_scale_mult"):
                     assert not getattr(self, field), (
@@ -803,6 +853,49 @@ PRESETS = {
         kv_latent_dim=32, q_latent_dim=48, qk_nope_dim=16, qk_rope_dim=8,
         v_head_dim=24, index_heads=4, index_head_dim=16, index_topk=16,
         rope_interleave=True, rope_theta=1000000.0, max_seq_len=256),
+    # Kimi-K2.7-Code (kimi_k2, 1.04T-A32B), ONE CHIP'S SHARE of a stated
+    # deployment: each layer shared by 32 chips (expert parallel: this is
+    # chip 0, routed experts 0-11 of 384, rows 0-20,479 of the untied
+    # embedding and head; attention and the shared expert on every chip)
+    # and the first pipeline stage of 8 of the 61 layers: layer 0 (dense)
+    # and seven routed ones. Every width is the published one: hidden 7168,
+    # latent attention of 64 heads (query latent 1536, key/value latent 512,
+    # 128 un-rotated + 64 rotated query channels, values 128) with NO
+    # indexer, rotary under YaRN (factor 64 over 4,096 positions at theta
+    # 50,000, mscale = mscale_all_dim = 1: cos/sin as they are, the softmax
+    # scale times (0.1 ln 64 + 1)^2), dense width 18432, sigmoid router 384
+    # / 8 a token scaled 2.827, expert and shared-expert width 2048.
+    # benchmark/configs/kimi-k2.7-code.json states the cut and what the
+    # published config leaves to assumption.
+    "kimi-k2.7-code": _mk(
+        arch="kimik2", vocab_size=20480, dim=7168, n_layers=8, n_heads=64,
+        n_kv_heads=64, head_dim=64, ffn_dim=2048, n_experts=384,
+        n_experts_used=8, n_experts_held=12, expert_first=0,
+        n_shared_ffn=2048, shared_gate=False, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=2.827,
+        n_dense_layers=1, dense_ffn_dim=18432, layer_kinds="AAAAAAAA",
+        kv_latent_dim=512, q_latent_dim=1536, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, rope_interleave=True,
+        rope_theta=50000.0, rope_scaling_type="yarn", rope_scaling=64.0,
+        rope_orig_ctx=4096, rope_yarn_beta_fast=32.0,
+        rope_yarn_beta_slow=1.0, rope_yarn_mscale=1.0,
+        rope_yarn_mscale_all_dim=1.0, norm_eps=1e-5, max_seq_len=262144),
+    # the same shape at toy widths (tests, --rehearse): four shares of a
+    # 16-wide router; YaRN over 32 positions, so a prompt of a few dozen
+    # tokens passes the original context
+    "tiny-kimi-k2": _mk(
+        arch="kimik2", vocab_size=256, dim=64, n_layers=4, n_heads=4,
+        n_kv_heads=4, head_dim=8, ffn_dim=32, n_experts=16,
+        n_experts_used=3, n_experts_held=4, expert_first=0,
+        n_shared_ffn=32, shared_gate=False, moe_score="sigmoid",
+        moe_select_bias=True, moe_renorm=True, moe_scale=2.827,
+        n_dense_layers=1, dense_ffn_dim=96, layer_kinds="AAAA",
+        kv_latent_dim=32, q_latent_dim=48, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, rope_interleave=True, rope_theta=50000.0,
+        rope_scaling_type="yarn", rope_scaling=8.0, rope_orig_ctx=32,
+        rope_yarn_beta_fast=32.0, rope_yarn_beta_slow=1.0,
+        rope_yarn_mscale=1.0, rope_yarn_mscale_all_dim=1.0,
+        max_seq_len=256),
     "dolphin-mixtral": _mk(arch="llama", vocab_size=32002, dim=4096,
                            n_layers=32, n_heads=32, n_kv_heads=8,
                            head_dim=128, ffn_dim=14336, n_experts=8,

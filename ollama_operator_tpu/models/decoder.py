@@ -74,7 +74,8 @@ from ..ops import quant as Q
 from ..ops.attention import (attend_hf, cached_attention, causal_mask,
                              chunk_attention, note_kernel)
 from ..ops.norms import layer_norm, rms_norm
-from ..ops.rope import apply_rope, rope_angles, rope_angles_cfg
+from ..ops.rope import (apply_rope, rope_angles, rope_angles_cfg,
+                        yarn_softmax_factor)
 from ..runtime.trace import device_scope
 from .config import ModelConfig
 
@@ -164,12 +165,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             kv_a_norm_w=jnp.ones((La, C), dtype),
             w_uk=w(next(keys), (La, H, dn, C)),
             w_uv=w(next(keys), (La, H, C, dv)),
-            wo=w(next(keys), (La, H * dv, D)),
-            idx_wq=w(next(keys), (La, Rq, Hi * di)),
-            idx_wk=w(next(keys), (La, D, di)),
-            idx_k_norm_w=jnp.ones((La, di), dtype),
-            idx_k_norm_b=jnp.zeros((La, di), dtype),
-            idx_w=w(next(keys), (La, D, Hi)))
+            wo=w(next(keys), (La, H * dv, D)))
+        if Hi:
+            layers.update(
+                idx_wq=w(next(keys), (La, Rq, Hi * di)),
+                idx_wk=w(next(keys), (La, D, di)),
+                idx_k_norm_w=jnp.ones((La, di), dtype),
+                idx_k_norm_b=jnp.zeros((La, di), dtype),
+                idx_w=w(next(keys), (La, D, Hi)))
     else:
         layers.update(
             wq=w(next(keys), (La, D, cfg.q_dim)),
@@ -820,8 +823,7 @@ def forward_with_cache(params: Params, cfg: ModelConfig, tokens: jax.Array,
             return _proj_out(cfg, ap, out, B, T), win
 
         if cfg.kv_latent_dim:
-            cos, sin = rope_angles(positions, cfg.qk_rope_dim,
-                                   cfg.rope_theta)
+            cos, sin = _latent_rope(cfg, positions)
 
             def attend_full(ap, h, kc, vc, row):
                 return _latent_cached(cfg, ap, h, kc, vc, row, positions,
@@ -1727,6 +1729,14 @@ def _hybrid_layers(params: Params, cfg: ModelConfig, x, kc, vc, state,
 # bucket for every slot; the one-position step on a chip is a kernel that
 # walks each slot's own rows in the cache leaf itself (``_latent_kernel``,
 # ops/pallas/latent.py).
+# A model without an indexer (kimi_k2: cfg.index_topk == 0) has none of the
+# indexer's leaves, calls or scope: attention reads every earlier position,
+# the cache has the one row and nothing rides where values do (vc is None).
+# Its rotary may be YaRN's (``_latent_rope``), whose DeepSeek-V3 convention
+# leaves cos / sin alone and scales the scores (``_latent_scale``); under a
+# scaled-up softmax an int8 row keeps the rotated key's second code in its
+# padding (cfg.latent_key_residual), which the query's [q_rope | q_rope /
+# RESIDUAL_STEPS] scores in the same dot.
 
 _LATENT_Q_BLOCK = 128       # queries, over the batch, a pass of a long prefill
 
@@ -1798,21 +1808,20 @@ def _index_keep(cfg: ModelConfig, score, visible):
     return ((score > kth) | ((score == kth) & (pos <= last))) & visible
 
 
-def _index_mask(cfg: ModelConfig, qi, w, ki_rows, q_pos):
+def _index_mask(cfg: ModelConfig, qi, w, ki_rows, q_pos, A: int):
     """bool [B, T, A]: the positions each query at q_pos [B, T] may read of
-    the A that ki_rows [B, A, di] holds (or int8 {"q", "s" [B, A]}: a
-    position's scale is positive and comes out of the ReLU): every position
-    up to its own and, where A passes cfg.index_topk, of those the ones
-    ``_index_keep`` keeps by the indexer's scores of queries qi [B, T, Hi,
-    di], w [B, T, Hi]."""
+    the first A: every position up to its own and, where the model has an
+    indexer and A passes cfg.index_topk, of those the ones ``_index_keep``
+    keeps by the indexer's scores of queries qi [B, T, Hi, di], w [B, T, Hi]
+    against the keys ki_rows [B, A, di] (or int8 {"q", "s" [B, A]}: a
+    position's scale is positive and comes out of the ReLU)."""
     from ..ops.quant_cache import is_quantized_cache
-    quant = is_quantized_cache(ki_rows)
-    kq = ki_rows["q"] if quant else ki_rows
-    A = kq.shape[1]
     visible = (jnp.arange(A, dtype=jnp.int32)[None, None, :]
                <= q_pos[:, :, None])
-    if A <= cfg.index_topk:
+    if A <= cfg.index_topk or not cfg.index_topk:
         return visible
+    quant = is_quantized_cache(ki_rows)
+    kq = ki_rows["q"] if quant else ki_rows
     with device_scope("attn.index"):
         dots = jnp.einsum("btjd,bsd->btjs", qi, kq.astype(qi.dtype),
                           preferred_element_type=jnp.float32)
@@ -1842,15 +1851,31 @@ def _by_query_blocks(fn, T: int, *per_query):
 
 
 def _latent_scale(cfg: ModelConfig) -> float:
-    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    """What latent attention's scores are multiplied by: 1 / sqrt(dn + dr),
+    times YaRN's m(mscale_all_dim)^2 where the config states it."""
+    return ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+            * yarn_softmax_factor(cfg))
+
+
+def _latent_rope(cfg: ModelConfig, positions):
+    """cos, sin [B, T, dr / 2] of latent attention's rotated channels: at
+    rope_theta alone (the call there was: float32 frequencies made on the
+    device, where ``scaled_inv_freq`` rounds float64 ones, so an unscaled
+    stack's programs stay what they were), or with the scaling's
+    per-channel frequencies and cos / sin magnitude
+    (``ops/rope.scaled_inv_freq``)."""
+    if cfg.rope_scaling_type == "none":
+        return rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    return rope_angles_cfg(positions, cfg, cfg.qk_rope_dim)
+
 
 
 def _latent_expanded(cfg: ModelConfig, ap, q_nope, q_rope, row, qi, w, ki,
                      q_pos):
     """Attention of a fresh chunk over itself, keys and values expanded a
     head from the chunk's own rows: row [B, T, C + dr], ki [B, T, di], both
-    as computed (not yet as the cache stores them); q_pos [B, T]. -> [B, T,
-    H * dv]."""
+    as computed (not yet as the cache stores them); q_pos [B, T]; qi, w and
+    ki None for a model without an indexer. -> [B, T, H * dv]."""
     B, T, H, _ = q_nope.shape
     C = cfg.kv_latent_dim
     ckv, kr = row[..., :C], row[..., C:]
@@ -1858,8 +1883,10 @@ def _latent_expanded(cfg: ModelConfig, ap, q_nope, q_rope, row, qi, w, ki,
         k_nope = jnp.einsum("bsc,hnc->bshn", ckv, ap["w_uk"])
         v = jnp.einsum("bsc,hcv->bshv", ckv, ap["w_uv"])
 
-    def block(q_nope, q_rope, qi, w, q_pos):
-        ok = _index_mask(cfg, qi, w, ki, q_pos)
+    def block(q_nope, q_rope, *index_and_pos):
+        *index, q_pos = index_and_pos
+        qi, w = index or (None, None)       # no indexer: nothing to cut
+        ok = _index_mask(cfg, qi, w, ki, q_pos, T)
         with device_scope("attn.core"):
             s = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
                             preferred_element_type=jnp.float32)
@@ -1870,7 +1897,8 @@ def _latent_expanded(cfg: ModelConfig, ap, q_nope, q_rope, row, qi, w, ki,
             return jnp.einsum("bhts,bshv->bthv", p, v)
 
     note_kernel("prefill", "einsum")
-    out = _by_query_blocks(block, T, q_nope, q_rope, qi, w, q_pos)
+    index = (qi, w) if cfg.index_topk else ()
+    out = _by_query_blocks(block, T, q_nope, q_rope, *index, q_pos)
     return out.reshape(B, T, -1)
 
 
@@ -1879,23 +1907,29 @@ def _latent_absorbed(cfg: ModelConfig, ap, q_nope, q_rope, rows, qi, w,
     """Attention over the first A cached positions, absorbed: rows [B, A, C
     + dr] and ki_rows [B, A, di] as the cache keeps them (int8: {"q", "s"},
     the rows' "s" [B, 2, A], latent part and rotated key), the new
-    positions already written. q_pos [B, T]. -> [B, T, H * dv]. With
-    ``kernel`` (``_latent_kernel``'s, T == 1) the scores, softmax and sum
-    are its walk over the cache itself and ``rows`` is not read."""
+    positions already written; q_rope [B, T, H, dr], or 2 dr wide where
+    the rows hold the rotated key's second code (``_latent_cached``). q_pos
+    [B, T]; qi, w and ki_rows None for a model without an indexer. -> [B, T,
+    H * dv]. With ``kernel`` (``_latent_kernel``'s, T == 1) the scores,
+    softmax and sum are its walk over the cache itself and ``rows`` is not
+    read."""
     from ..ops.quant_cache import is_quantized_cache
     B, T, H, _ = q_nope.shape
     C = cfg.kv_latent_dim
     if kernel is None:
         quant = is_quantized_cache(rows)
         codes = rows["q"] if quant else rows
+        A = codes.shape[1]
         dt = q_nope.dtype
         lat = codes[..., :C].astype(dt)
-        kr = codes[..., C:C + cfg.qk_rope_dim].astype(dt)  # zeros may follow
+        kr = codes[..., C:C + q_rope.shape[-1]].astype(dt)  # zeros may follow
     with device_scope("attn.core"):
         q_abs = jnp.einsum("bthn,hnc->bthc", q_nope, ap["w_uk"])
 
-    def block(q_abs, q_rope, qi, w, q_pos):
-        ok = _index_mask(cfg, qi, w, ki_rows, q_pos)
+    def block(q_abs, q_rope, *index_and_pos):
+        *index, q_pos = index_and_pos
+        qi, w = index or (None, None)       # no indexer: nothing to cut
+        ok = _index_mask(cfg, qi, w, ki_rows, q_pos, A)
         with device_scope("attn.core"):
             s_lat = jnp.einsum("bthc,bsc->bhts", q_abs, lat,
                                preferred_element_type=jnp.float32)
@@ -1915,15 +1949,19 @@ def _latent_absorbed(cfg: ModelConfig, ap, q_nope, q_rope, rows, qi, w,
             return jnp.einsum("bhts,bsc->bthc", (e * norm).astype(dt), lat)
 
     if kernel is not None:
-        # below index_topk positions nothing is chosen: the kernel's own
-        # test of visibility is the whole mask
-        A = (ki_rows["q"] if is_quantized_cache(ki_rows) else ki_rows).shape[1]
-        keep = (_index_mask(cfg, qi, w, ki_rows, q_pos)[:, 0]
-                if A > cfg.index_topk else None)
+        # without an indexer, and below index_topk positions, nothing is
+        # chosen: the kernel's own test of visibility is the whole mask
+        keep = None
+        if cfg.index_topk:
+            A = (ki_rows["q"] if is_quantized_cache(ki_rows)
+                 else ki_rows).shape[1]
+            if A > cfg.index_topk:
+                keep = _index_mask(cfg, qi, w, ki_rows, q_pos, A)[:, 0]
         with device_scope("attn.core"):
             o_lat = kernel(q_abs[:, 0], q_rope[:, 0], keep)[:, None]
     else:
-        o_lat = _by_query_blocks(block, T, q_abs, q_rope, qi, w, q_pos)
+        index = (qi, w) if cfg.index_topk else ()
+        o_lat = _by_query_blocks(block, T, q_abs, q_rope, *index, q_pos)
     with device_scope("attn.core"):
         out = jnp.einsum("bthc,hcv->bthv", o_lat, ap["w_uv"])
     return out.reshape(B, T, -1)
@@ -1962,18 +2000,25 @@ def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
                    n_valid, A: int, cos, sin, mesh=None):
     """One latent-attention layer against row ``row_i`` of the cache: kc
     the rows [La, B, 1, S, C + dr], vc the indexer's keys [La, B, 1, S, di]
-    (either int8 {"q", "s"}). Writes the new positions' rows and keys
-    (those at or past n_valid [B] write nothing: a padded position, an
-    inactive slot leave the cache's bits alone), attends over the first A
-    positions. -> (out [B, T, D], kc, vc)."""
+    (either int8 {"q", "s"}; vc None for a model without an indexer).
+    Writes the new positions' rows and keys (those at or past n_valid [B]
+    write nothing: a padded position, an inactive slot leave the cache's
+    bits alone), attends over the first A positions. -> (out [B, T, D], kc,
+    vc)."""
     from ..ops import quant_cache as QC
     B, T, _ = h.shape
     C = cfg.kv_latent_dim
     q_nope, q_rope, row, cq = _latent_project(cfg, ap, h, cos, sin)
-    with device_scope("attn.index"):
-        qi, ki, w = _index_project(cfg, ap, h, cq, cos, sin)
+    qi = ki = w = ki_rows = None
+    if cfg.index_topk:
+        with device_scope("attn.index"):
+            qi, ki, w = _index_project(cfg, ap, h, cq, cos, sin)
     quant = QC.is_quantized_cache(kc)
-    pad = [(0, 0), (0, 0), (0, cfg.latent_row_pad)]
+    residual = cfg.latent_key_residual if quant else 0
+    pad = [(0, 0), (0, 0), (0, cfg.latent_row_pad - residual)]
+    if residual:        # the query's share of the key's second code
+        q_rope = jnp.concatenate(
+            [q_rope, q_rope * (1.0 / QC.RESIDUAL_STEPS)], axis=-1)
     S = (kc["q"] if quant else kc).shape[3]
     real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None]
     bidx = jnp.arange(B)[:, None]
@@ -1998,21 +2043,24 @@ def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
 
     if quant:
         with device_scope("attn.kv_write"):
-            rq, rs = QC.quantize_latent(row, C)     # [B,T,C+dr], [B,T,2]
+            # [B,T,C+dr] (or C+2dr with the key's second code), [B,T,2]
+            rq, rs = QC.quantize_latent(row, C, residual)
             kc = {"q": put(kc["q"], jnp.pad(rq, pad)),
                   "s": kc["s"].at[row_i, bidx, :, pidx].set(jnp.where(
                       real[..., None], rs, kc["s"][row_i, bidx, :, pidx]))}
-        with device_scope("attn.index"):
-            iq, is_ = QC.quantize_kv(ki)
-            vc = {"q": put(vc["q"], iq), "s": put(vc["s"], is_)}
-        ki_rows = {"q": window(vc["q"], 4)[0, :, 0],
-                   "s": window(vc["s"], 4)[0, :, 0]}
+        if cfg.index_topk:
+            with device_scope("attn.index"):
+                iq, is_ = QC.quantize_kv(ki)
+                vc = {"q": put(vc["q"], iq), "s": put(vc["s"], is_)}
+            ki_rows = {"q": window(vc["q"], 4)[0, :, 0],
+                       "s": window(vc["s"], 4)[0, :, 0]}
     else:
         with device_scope("attn.kv_write"):
             kc = put(kc, jnp.pad(row, pad))
-        with device_scope("attn.index"):
-            vc = put(vc, ki)
-        ki_rows = window(vc, 4)[0, :, 0]
+        if cfg.index_topk:
+            with device_scope("attn.index"):
+                vc = put(vc, ki)
+            ki_rows = window(vc, 4)[0, :, 0]
     kernel = _latent_kernel(cfg, mesh, T, kc, row_i, positions, n_valid)
     rows = None                 # the kernel reads the leaf itself
     if kernel is None:
@@ -2028,29 +2076,32 @@ def _latent_cached(cfg: ModelConfig, ap, h, kc, vc, row_i, positions,
 def _latent_prefill(params: Params, cfg: ModelConfig, x, n_valid):
     """``prefill_chunk`` of a latent-attention stack from the embedded chunk
     x [B, T, D]: -> (logits, rows [La, B, 1, T, C + dr (+ the cache's
-    padding, zeros)], indexer keys [La, B, 1, T, di]), both as computed (the
-    engine quantizes them as it does keys and values); nothing else is
-    carried."""
+    padding, zeros)], indexer keys [La, B, 1, T, di] or None where the model
+    has no indexer), both as computed (the engine quantizes them as it does
+    keys and values); nothing else is carried."""
     B, T, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    cos, sin = rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    cos, sin = _latent_rope(cfg, positions)
 
     def attend(ap, h, kc, vc, row_i):
         q_nope, q_rope, row, cq = _latent_project(cfg, ap, h, cos, sin)
-        with device_scope("attn.index"):
-            qi, ki, w = _index_project(cfg, ap, h, cq, cos, sin)
+        qi = ki = w = None
+        if cfg.index_topk:
+            with device_scope("attn.index"):
+                qi, ki, w = _index_project(cfg, ap, h, cq, cos, sin)
         out = _latent_expanded(cfg, ap, q_nope, q_rope, row, qi, w, ki,
                                positions)
         kc = lax.dynamic_update_index_in_dim(kc, row[:, None], row_i, 0)
-        vc = lax.dynamic_update_index_in_dim(
-            vc, ki[:, None].astype(vc.dtype), row_i, 0)
+        if cfg.index_topk:
+            vc = lax.dynamic_update_index_in_dim(
+                vc, ki[:, None].astype(vc.dtype), row_i, 0)
         return _proj_out(cfg, ap, out, B, T), kc, vc
 
     _, kd, vd = cfg.cache_row_dims
     La = cfg.n_full_layers
     x, ks, vs, _, _ = _hybrid_layers(
         params, cfg, x, jnp.zeros((La, B, 1, T, kd), x.dtype),
-        jnp.zeros((La, B, 1, T, vd), x.dtype), None,
+        jnp.zeros((La, B, 1, T, vd), x.dtype) if vd else None, None,
         _valid_rows(n_valid, B, T), attend)
     return _unembed(cfg, params, _last_real(x, n_valid)), ks, vs
 

@@ -204,7 +204,10 @@ def latent_decode(rows, row, q_abs, q_rope, q_pos, live, keep, scale: float,
             {"q": that, "s": [La, B, 2, S] float32: a position's scales for
             the latent part and the rotated key}. Read, never written.
     row     int32 scalar, traced or not.
-    q_abs   [B, H, C], the queries through ``w_uk``; q_rope [B, H, dr].
+    q_abs   [B, H, C], the queries through ``w_uk``; q_rope [B, H, dr], or
+            as many channels of the row past C as the query scores (the
+            rotated key's second code, ``quantize_latent``: the caller's
+            query then holds its share of it).
     q_pos   [B] int32, each query's position: slot b reads positions [0,
             q_pos[b]], its own row already written.
     live    [B]: a slot with 0 reads nothing and returns zeros.

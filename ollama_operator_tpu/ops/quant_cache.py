@@ -11,7 +11,7 @@ Layout mirrors the bf16 cache, plus a scale array one axis short:
 
 (latent attention's one row a position, models/decoder.py, has two parts of
 two sizes and a scale for each: q [.., 1, S, C + dr], s [.., 2, S],
-``quantize_latent``)
+``quantize_latent``, which can keep the key's rounding in a second code)
 
 The arithmetic stays exact-shaped with the dense path (ops/attention.py
 ``attend_hf``): scores pick up the key scale AFTER the q·k dot (the scale
@@ -46,6 +46,11 @@ import jax.numpy as jnp
 from .attention import NEG_INF, softcap_scores
 
 
+# what a second code of a latent row's rotated key counts in: the first code's
+# rounding lies in [-1/2, 1/2] of its step, 254 of these steps
+RESIDUAL_STEPS = 254
+
+
 def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """[..., hd] float → (int8 [..., hd], f32 scale [...])."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
@@ -54,15 +59,32 @@ def quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return jnp.clip(q, -127, 127).astype(jnp.int8), s
 
 
-def quantize_latent(row: jax.Array, split: int
-                     ) -> Tuple[jax.Array, jax.Array]:
+def quantize_latent(row: jax.Array, split: int, residual: int = 0
+                    ) -> Tuple[jax.Array, jax.Array]:
     """A latent-attention row [..., C + dr] = [normed latent | rotated key]
     -> (int8 [..., C + dr], f32 scales [..., 2]): the two parts differ in
     size (a norm's output against a raw projection), so each has its own
-    scale, the latent's first; ``split`` = C."""
+    scale, the latent's first; ``split`` = C.
+
+    With ``residual`` = dr (``ModelConfig.latent_key_residual``: rows whose
+    rounding the softmax's scale amplifies) the key's dr codes are followed
+    by as many again that hold what the first rounded away, in 1 /
+    ``RESIDUAL_STEPS`` of the first's step: key = scale x (code + code' /
+    RESIDUAL_STEPS), so a query [q | q / RESIDUAL_STEPS] scores both in the
+    one dot. -> int8 [..., C + 2 dr], then whatever channels the row had
+    past them (a cache's padding, handed on)."""
     lq, ls = quantize_kv(row[..., :split])
-    rq, rs = quantize_kv(row[..., split:])
-    return jnp.concatenate([lq, rq], axis=-1), jnp.stack([ls, rs], axis=-1)
+    if not residual:
+        rq, rs = quantize_kv(row[..., split:])
+        return jnp.concatenate([lq, rq], axis=-1), jnp.stack([ls, rs], axis=-1)
+    key = row[..., split:split + residual].astype(jnp.float32)
+    rq, rs = quantize_kv(key)
+    left = key / jnp.maximum(rs[..., None], 1e-30) - rq
+    fine = jnp.clip(jnp.round(left * RESIDUAL_STEPS), -127, 127)
+    codes = jnp.concatenate(
+        [lq, rq, fine.astype(jnp.int8),
+         row[..., split + 2 * residual:].astype(jnp.int8)], axis=-1)
+    return codes, jnp.stack([ls, rs], axis=-1)
 
 
 def attend_hf_q(q, kc: Dict, vc: Dict, mask, scale: float,

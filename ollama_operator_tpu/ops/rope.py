@@ -38,6 +38,7 @@ def scaled_inv_freq(rotary_dim: int, theta: float, *,
                     high_freq_factor: float = 4.0, attn_factor: float = 0.0,
                     beta_fast: float = 32.0, beta_slow: float = 1.0,
                     freq_factors: Optional[Tuple[float, ...]] = None,
+                    yarn_mscale: float = 0.0, yarn_mscale_all_dim: float = 0.0,
                     ) -> Tuple[Tuple[float, ...], float]:
     """The per-frequency rotation rates after context-extension scaling.
 
@@ -56,7 +57,10 @@ def scaled_inv_freq(rotary_dim: int, theta: float, *,
       original window are untouched, long wavelengths interpolate by
       ``factor``, with a linear ramp between the ``beta_fast``/``beta_slow``
       correction dims; cos/sin scale by ``attn_factor`` (default
-      ``0.1·ln(factor)+1``).
+      ``0.1·ln(factor)+1``). Where the config states ``yarn_mscale`` /
+      ``yarn_mscale_all_dim`` (the DeepSeek-V3 convention) cos/sin scale by
+      ``yarn_magnitude`` of the one over that of the other, and the softmax
+      scale carries the rest (``yarn_softmax_factor``).
     - ``llama3`` — low/high-frequency interpolation: wavelengths beyond
       ``orig_ctx/low_freq_factor`` divide by ``factor``, those inside
       ``orig_ctx/high_freq_factor`` are untouched, smooth blend between.
@@ -111,12 +115,31 @@ def scaled_inv_freq(rotary_dim: int, theta: float, *,
                        / (high - low), 0.0, 1.0)
         extrap = 1.0 - ramp          # 1 at high-freq dims: keep original
         inv_freq = (inv_freq / factor) * (1.0 - extrap) + inv_freq * extrap
-        mscale = attn_factor if attn_factor > 0 else (
-            0.1 * math.log(factor) + 1.0 if factor > 1.0 else 1.0)
+        if yarn_mscale or yarn_mscale_all_dim:
+            mscale = (yarn_magnitude(factor, yarn_mscale)
+                      / yarn_magnitude(factor, yarn_mscale_all_dim))
+        else:
+            mscale = attn_factor if attn_factor > 0 else yarn_magnitude(
+                factor)
     elif scaling_type != "none":
         raise ValueError(f"unknown rope scaling type {scaling_type!r}")
 
     return tuple(np.asarray(inv_freq, np.float32).tolist()), float(mscale)
+
+
+def yarn_magnitude(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's m(s) = 0.1 s ln(factor) + 1 (1 where nothing is extended)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_softmax_factor(cfg) -> float:
+    """What the softmax scale is multiplied by under the DeepSeek-V3
+    convention of YaRN, m(mscale_all_dim)^2 (the queries' and the keys'
+    share, which that convention takes out of cos/sin); 1.0 for every other
+    scheme, the Llama convention of YaRN among them."""
+    if cfg.rope_scaling_type != "yarn" or not cfg.rope_yarn_mscale_all_dim:
+        return 1.0
+    return yarn_magnitude(cfg.rope_scaling, cfg.rope_yarn_mscale_all_dim) ** 2
 
 
 def rope_angles(positions, rotary_dim: int, theta: float,
@@ -141,11 +164,14 @@ def rope_angles(positions, rotary_dim: int, theta: float,
     return cos, sin
 
 
-def rope_angles_cfg(positions, cfg):
+def rope_angles_cfg(positions, cfg, rotary_dim: Optional[int] = None):
     """cfg-driven rope_angles: applies the model's full scaling scheme
-    (ModelConfig.rope_scaling_type & friends, gguf/transcode.py)."""
+    (ModelConfig.rope_scaling_type & friends, gguf/transcode.py) over
+    ``rotary_dim`` channels (cfg.rotary_dim where not given: latent
+    attention rotates its qk_rope_dim)."""
+    rotary_dim = rotary_dim or cfg.rotary_dim
     inv_freq, mscale = scaled_inv_freq(
-        cfg.rotary_dim, cfg.rope_theta,
+        rotary_dim, cfg.rope_theta,
         scaling_type=cfg.rope_scaling_type, factor=cfg.rope_scaling,
         orig_ctx=cfg.rope_orig_ctx,
         low_freq_factor=cfg.rope_low_freq_factor,
@@ -153,8 +179,10 @@ def rope_angles_cfg(positions, cfg):
         attn_factor=cfg.rope_attn_factor,
         beta_fast=cfg.rope_yarn_beta_fast,
         beta_slow=cfg.rope_yarn_beta_slow,
-        freq_factors=cfg.rope_freq_factors)
-    return rope_angles(positions, cfg.rotary_dim, cfg.rope_theta,
+        freq_factors=cfg.rope_freq_factors,
+        yarn_mscale=cfg.rope_yarn_mscale,
+        yarn_mscale_all_dim=cfg.rope_yarn_mscale_all_dim)
+    return rope_angles(positions, rotary_dim, cfg.rope_theta,
                        inv_freq=inv_freq, mscale=mscale)
 
 

@@ -525,7 +525,8 @@ class Engine:
         L, hd = cfg.n_full_layers, cfg.head_dim
         # a position's two rows in a full layer's cache: a head's keys and
         # values, or latent attention's [latent | rotated key] and the
-        # indexer's key (one "head", two widths)
+        # indexer's key (one "head", two widths; without an indexer v_dim
+        # is 0 and there is no second tree: v_cache is None)
         KvH, k_dim, v_dim = cfg.cache_row_dims
         V = cfg.vocab_size
         # a hybrid stack's slots carry a state beside their full-length
@@ -538,7 +539,8 @@ class Engine:
             seed_expert_tokens(cfg.n_experts)
         if cfg.kv_latent_dim:
             from .host_cache import host_cache_bytes
-            seed_index_positions()
+            if cfg.index_topk:
+                seed_index_positions()
             if ecfg.paged:
                 self._refuse_for_latent_rows("a page pool")
             if mesh is not None and mesh.size > 1:
@@ -766,13 +768,13 @@ class Engine:
             # and its rotated key's (ops/quant_cache.quantize_latent)
             self.k_cache = qzeros(cache_sh, k_dim,
                                   2 if cfg.kv_latent_dim else KvH)
-            self.v_cache = qzeros(cache_sh, v_dim)
+            self.v_cache = qzeros(cache_sh, v_dim) if v_dim else None
         else:
             # head-first: (S, hd) tiles
             self.k_cache = zeros((L, B, KvH, S, k_dim), ecfg.cache_dtype,
                                  cache_sh)
-            self.v_cache = zeros((L, B, KvH, S, v_dim), ecfg.cache_dtype,
-                                 cache_sh)
+            self.v_cache = (zeros((L, B, KvH, S, v_dim), ecfg.cache_dtype,
+                                  cache_sh) if v_dim else None)
         full = self.kv_bytes
         if self.recurrent:
             self.k_cache, self.v_cache = decoder.join_state(
@@ -786,11 +788,11 @@ class Engine:
         # device bytes of the cache by what holds them
         # (tpu_model_cache_bytes{kind}): full-length rows or pages, the
         # window layers' rings, recurrent state; where attention is
-        # latent, its rows are the full-length ones and its indexer's keys
-        # a kind of their own
+        # latent, its rows are the full-length ones and its indexer's keys,
+        # where it has one, a kind of their own
         self.cache_bytes = {"full": full, "window": nbytes(win),
                             "state": nbytes(carried)}
-        if cfg.kv_latent_dim:
+        if cfg.index_topk:
             index = nbytes(self.v_cache)
             self.cache_bytes.update(full=full - index, index=index)
         self.lengths = zeros((B,), jnp.int32, slot_sh)
@@ -960,9 +962,9 @@ class Engine:
         if self.cfg.kv_latent_dim:
             raise ValueError(
                 f"{what}: no form yet for latent rows (one row [latent | "
-                "rotated key] and one indexer key a position, no keys or "
-                "values a head); they serve from the contiguous cache of "
-                "one device, without export or a host tier")
+                "rotated key] and at most one indexer key a position, no "
+                "keys or values a head); they serve from the contiguous "
+                "cache of one device, without export or a host tier")
 
     @staticmethod
     def _quant_cache_sharding(cache_sh):
@@ -1148,27 +1150,31 @@ class Engine:
                 from ..ops.quant_cache import quantize_kv, quantize_latent
                 with device_scope("attn.kv_write"):
                     if cfg.kv_latent_dim:
-                        # [L,1,1,T,C+dr]; its two scales to [L,1,2,T]
-                        kq, ksc = quantize_latent(ks, cfg.kv_latent_dim)
+                        # [L,1,1,T,C+dr+pad]; its two scales to [L,1,2,T]
+                        kq, ksc = quantize_latent(ks, cfg.kv_latent_dim,
+                                                  cfg.latent_key_residual)
                         ksc = jnp.moveaxis(ksc[:, :, 0], -1, 2)
                     else:
                         kq, ksc = quantize_kv(ks)      # [L,1,KvH,T,hd]
-                    vq, vsc = quantize_kv(vs)
+                    if vs is not None:      # a second row to keep
+                        vq, vsc = quantize_kv(vs)
                     dus = jax.lax.dynamic_update_slice
                     k_cache = {
                         "q": dus(k_cache["q"], kq, (0, slot, 0, 0, 0)),
                         "s": dus(k_cache["s"], ksc, (0, slot, 0, 0))}
-                    v_cache = {
-                        "q": dus(v_cache["q"], vq, (0, slot, 0, 0, 0)),
-                        "s": dus(v_cache["s"], vsc, (0, slot, 0, 0))}
+                    if vs is not None:
+                        v_cache = {
+                            "q": dus(v_cache["q"], vq, (0, slot, 0, 0, 0)),
+                            "s": dus(v_cache["s"], vsc, (0, slot, 0, 0))}
             else:
                 with device_scope("attn.kv_write"):
                     k_cache = jax.lax.dynamic_update_slice(
                         k_cache, ks.astype(k_cache.dtype),
                         (0, slot, 0, 0, 0))
-                    v_cache = jax.lax.dynamic_update_slice(
-                        v_cache, vs.astype(v_cache.dtype),
-                        (0, slot, 0, 0, 0))
+                    if vs is not None:
+                        v_cache = jax.lax.dynamic_update_slice(
+                            v_cache, vs.astype(v_cache.dtype),
+                            (0, slot, 0, 0, 0))
             k_cache, v_cache = decoder.join_state(k_cache, v_cache, state)
             return (tok, *pin(k_cache, v_cache, lengths, counts,
                               last_tokens, pring, mu))
@@ -3355,7 +3361,7 @@ class Engine:
             self._rln_dev, self._gstate, self._gmask_dev,
             self._gtrans_dev, self._tables_dev(),
             self._g(budgets, self._slot_sh))
-        if self.cfg.kv_latent_dim:
+        if self.cfg.index_topk:
             self._count_index_positions(budgets)
         self._host_lengths[self.active] += budgets[self.active]
         # stamp AFTER the successful launch: a raise above leaves the
@@ -3444,6 +3450,21 @@ class Engine:
             return {}
         return {"live": Lw * int(np.minimum(self._host_lengths, W).sum()),
                 "allocated": Lw * self.n_slots * W}
+
+    @property
+    def latent_positions(self) -> Dict[str, int]:
+        """{"live", "allocated"}: cached positions latent attention's rows
+        hold against what they were allocated
+        (tpu_model_latent_positions{what}); empty for a model without
+        latent attention. An active slot holds its length a latent layer (a
+        parked one's rows lie there and no decode step reads them); from the
+        host's mirror of the lengths, every launched step counted: no
+        device work."""
+        if not self.cfg.kv_latent_dim:
+            return {}
+        La = self.cfg.n_full_layers
+        return {"live": La * int(self._host_lengths[self.active].sum()),
+                "allocated": La * self.n_slots * self.max_seq}
 
     @property
     def state_bytes(self) -> int:

@@ -306,6 +306,11 @@ class LoadedModel:
                              lambda what=what: (lm := wself()) is not None
                              and lm.engine.ring_positions[what] or 0,
                              f'{{what="{what}"}}')
+        for what in getattr(self.engine, "latent_positions", ()):
+            METRICS.gauge_fn("tpu_model_latent_positions",
+                             lambda what=what: (lm := wself()) is not None
+                             and lm.engine.latent_positions[what] or 0,
+                             f'{{what="{what}"}}')
         if getattr(self.engine, "host_cache_enabled", False):
             # tier-1 host-arena occupancy: bytes and whole KV pages the
             # spilled radix subtrees hold in pinned host RAM (the spill /
@@ -913,6 +918,9 @@ class LoadedModel:
                                  f'{{kind="{kind}"}}')
         for what in getattr(self.engine, "ring_positions", ()):
             METRICS.remove_gauge("tpu_model_ring_positions",
+                                 f'{{what="{what}"}}')
+        for what in getattr(self.engine, "latent_positions", ()):
+            METRICS.remove_gauge("tpu_model_latent_positions",
                                  f'{{what="{what}"}}')
         if getattr(self.engine, "host_cache_enabled", False):
             METRICS.remove_gauge("tpu_model_host_cache_bytes")

@@ -343,6 +343,17 @@ KERNEL_NAMES = ("flash_prefill", "decode_attention", "paged_v3",
                 "paged_kv_write", "qmm_pallas", "qmm4_pallas", "delta_update",
                 "latent_decode", "ring_decode")
 
+# Gauges of the loaded model's cache, registered by LoadedModel from the
+# engine's property of the same stem (cache_bytes, ring_positions,
+# latent_positions: host arithmetic, no device work) and read by a per-layer
+# metric of the benchmark: label key and the values it may take. A gauge is
+# there only where the model has the thing it counts.
+CACHE_GAUGES = {
+    "tpu_model_cache_bytes": ("kind", ("full", "window", "state", "index")),
+    "tpu_model_ring_positions": ("what", ("live", "allocated")),
+    "tpu_model_latent_positions": ("what", ("live", "allocated")),
+}
+
 # Request stages folded into tpu_model_request_stage_seconds{stage=...}
 STAGES = ("ingress", "queue", "prefill", "first_flush", "decode")
 _STAGE_LABELS = {st: f'{{stage="{st}"}}' for st in STAGES}

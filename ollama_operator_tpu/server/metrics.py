@@ -317,7 +317,7 @@ GLOBAL.describe("tpu_model_cache_bytes",
                 "the rings of sliding_window positions a slot that "
                 "window-attention layers keep instead of a full row; "
                 "recurrent state; the keys of latent attention's indexer "
-                "(index: only where the model has one)")
+                "(index: only where the model has an indexer)")
 GLOBAL.describe("tpu_model_ring_positions",
                 "Positions the window-attention layers' rings of the "
                 "loaded model hold (what=live: min(a slot's length, the "
@@ -325,6 +325,13 @@ GLOBAL.describe("tpu_model_ring_positions",
                 "mirror of the lengths) against the positions they were "
                 "allocated (what=allocated); only where the model has "
                 "window layers")
+GLOBAL.describe("tpu_model_latent_positions",
+                "Cached positions latent attention's rows of the loaded "
+                "model hold (what=live: an active slot's length a latent layer, "
+                "from the host's mirror of the lengths) against the "
+                "positions they were allocated (what=allocated: slots x "
+                "the served context a latent layer); only where the model "
+                "has latent attention")
 GLOBAL.describe("tpu_model_async_fallback_total",
                 "Decode dispatches that fell back to synchronous while "
                 "TPU_ASYNC_DISPATCH was on: per-dispatch for grammar "

@@ -531,6 +531,56 @@ def test_latent_attention_compiles_at_published_widths(one_chip, B, T, A,
         assert not re.search(rf"s8\[{B},{A},{kd}\]", text)
 
 
+@pytest.mark.parametrize("B,T,A,kernels", [
+    (64, 1, 4096, "xla"), (64, 1, 1024, "pallas"), (64, 1, 4096, "pallas"),
+    (1, 256, 4096, "pallas")],
+    ids=["decode-deep", "kernel-1024", "kernel-4096", "kernel-extend"])
+def test_latent_attention_without_an_indexer_compiles_at_published_widths(
+        one_chip, B, T, A, kernels):
+    """One latent-attention layer at Kimi-K2.7-Code's widths (64 heads of 128
+    + 64 query channels, values 128, the row 576 -> 640) against the int8
+    rows as the served programs run it: NOTHING rides where values do, the
+    rows' two leaves are written in place (the key's second code where the
+    padding was), the rotated channels turn at YaRN's frequencies, no sort
+    at any depth, and the decode step reads through ``latent_decode`` with
+    no keep mask (PR 53)."""
+    import re
+    cfg = dataclasses.replace(PRESETS["kimi-k2.7-code"], kernels=kernels)
+    La = 2
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=one_chip)
+    layers = jax.eval_shape(lambda k: decoder.init_params(cfg, k),
+                            jax.random.key(0))["layers"]
+    ap = {k: sds(v.shape[1:], v.dtype) for k, v in layers.items()
+          if k in decoder._ATTN_STACK}
+    assert not [k for k in ap if k.startswith("idx_")]
+    _, kd, vd = cfg.cache_row_dims
+    assert (kd, vd) == (640, 0) and cfg.latent_key_residual == 64
+    kc = {"q": sds((La, B, 1, 4096, kd), jnp.int8),
+          "s": sds((La, B, 2, 4096), jnp.float32)}
+
+    def layer(ap, h, kc, row, lengths, nv):
+        pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        cos, sin = decoder._latent_rope(cfg, pos)
+        out, kc, vc = decoder._latent_cached(cfg, ap, h, kc, None, row, pos,
+                                             nv, A, cos, sin)
+        assert vc is None
+        return out, kc
+
+    compiled = jax.jit(layer, donate_argnums=(2,)).lower(
+        ap, sds((B, T, cfg.dim), jnp.bfloat16), kc, sds((), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert mem.alias_size_in_bytes >= La * B * 4096 * (kd + 8)
+    assert not re.search(r"\bsort\(", text)
+    kernel = kernels == "pallas" and T == 1
+    assert ("latent_decode" in text) == kernel
+    if kernel:
+        assert "tpu_custom_call" in text
+        assert not re.search(rf"s8\[{B},{A},{kd}\]", text)
+
+
 @pytest.mark.parametrize("name,B,T,A,kernels", [
     ("smallthinker-21b-a3b", 64, 1, 1024, "xla"),
     ("smallthinker-21b-a3b", 64, 1, 4096, "xla"),

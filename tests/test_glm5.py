@@ -179,8 +179,8 @@ def test_validate_accepts_and_refuses():
     for bad, msg in (
             (dict(layer_kinds="AAwA", sliding_window=8), "stack of its own"),
             (dict(layer_kinds="AAcA"), "stack of its own"),
-            (dict(index_topk=0), "needs index_topk"),
-            (dict(index_heads=0), "needs index_heads"),
+            (dict(index_topk=0), "or there is none"),
+            (dict(index_heads=0), "or there is none"),
             (dict(q_latent_dim=0), "needs q_latent_dim"),
             (dict(qk_rope_dim=7), "pair up"),
             (dict(qk_rope_dim=32), "pair up"),
@@ -198,6 +198,24 @@ def test_validate_accepts_and_refuses():
     with pytest.raises(AssertionError, match="belongs to latent attention"):
         dataclasses.replace(cfglib.PRESETS["tiny"],
                             kv_latent_dim=32).validate()
+
+
+def test_yarns_two_magnitudes_at_their_defaults_change_nothing():
+    """``rope_yarn_mscale`` / ``rope_yarn_mscale_all_dim`` are 0 on both
+    presets: cos / sin are the plain rotary's bit for bit (the call that
+    makes them is the one there was) and the softmax scale is 1 / sqrt(dn +
+    dr) to the last bit."""
+    from ollama_operator_tpu.ops import rope
+    for cfg in (CFG, BIG):
+        assert (cfg.rope_yarn_mscale, cfg.rope_yarn_mscale_all_dim) == (0, 0)
+        assert rope.yarn_softmax_factor(cfg) == 1.0
+        assert decoder._latent_scale(cfg) == (
+            cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+        pos = jnp.array([[0, 1, 17, 4095, 150000]], jnp.int32)
+        cos, sin = decoder._latent_rope(cfg, pos)
+        c0, s0 = rope.rope_angles(pos, cfg.qk_rope_dim, cfg.rope_theta)
+        assert np.array_equal(cos, c0) and np.array_equal(sin, s0)
+    assert BIG.cache_row_dims == (1, 640, 128)
 
 
 # -- the model against the reference -----------------------------------

@@ -9,6 +9,8 @@ ROPE_INIT_FUNCTIONS (the ecosystem-canonical math, matching llama.cpp) and
 cover the GGUF metadata → ModelConfig plumbing.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -108,6 +110,62 @@ def test_yarn_explicit_attention_factor():
     _, m = scaled_inv_freq(64, 10000.0, scaling_type="yarn", factor=4.0,
                            orig_ctx=2048, attn_factor=1.5)
     assert m == pytest.approx(ref_m) == pytest.approx(1.5)
+
+
+def test_yarns_two_magnitudes_at_their_defaults_are_the_llama_convention():
+    """The DeepSeek-V3 convention's fields (ModelConfig.rope_yarn_mscale /
+    rope_yarn_mscale_all_dim) at 0, their default: the GGUF families' YaRN
+    keeps its cos / sin magnitude (0.1 ln(factor) + 1, or the explicit
+    attn_factor) and its frequencies bit for bit, and no softmax factor."""
+    from ollama_operator_tpu.ops.rope import yarn_softmax_factor
+    for kw, want_m in ((dict(), 0.1 * np.log(4.0) + 1.0),
+                       (dict(attn_factor=1.5), 1.5)):
+        old = scaled_inv_freq(64, 10000.0, scaling_type="yarn", factor=4.0,
+                              orig_ctx=2048, **kw)
+        new = scaled_inv_freq(64, 10000.0, scaling_type="yarn", factor=4.0,
+                              orig_ctx=2048, yarn_mscale=0.0,
+                              yarn_mscale_all_dim=0.0, **kw)
+        assert old == new and old[1] == want_m
+    cfg = ModelConfig(rope_scaling_type="yarn", rope_scaling=4.0,
+                      rope_orig_ctx=2048).validate()
+    assert (cfg.rope_yarn_mscale, cfg.rope_yarn_mscale_all_dim) == (0.0, 0.0)
+    assert yarn_softmax_factor(cfg) == 1.0
+    pos = jnp.arange(0, 9000, 977, dtype=jnp.int32)[None]
+    cos, sin = rope_angles_cfg(pos, cfg)
+    inv, m = scaled_inv_freq(128, 10000.0, scaling_type="yarn", factor=4.0,
+                             orig_ctx=2048)
+    c0, s0 = rope_angles(pos, 128, 10000.0, inv_freq=inv, mscale=m)
+    assert np.array_equal(cos, c0) and np.array_equal(sin, s0)
+    # stated, the two move the magnitude to the scores: cos / sin by their
+    # ratio, the softmax by the second one squared
+    _, m = scaled_inv_freq(64, 10000.0, scaling_type="yarn", factor=4.0,
+                           orig_ctx=2048, yarn_mscale=1.0,
+                           yarn_mscale_all_dim=1.0)
+    assert m == 1.0
+    stated = ModelConfig(rope_scaling_type="yarn", rope_scaling=4.0,
+                         rope_orig_ctx=2048, rope_yarn_mscale=1.0,
+                         rope_yarn_mscale_all_dim=1.0)
+    assert yarn_softmax_factor(stated) == pytest.approx(
+        (0.1 * np.log(4.0) + 1.0) ** 2)
+
+
+@pytest.mark.parametrize("fields", [dict(rope_yarn_mscale=1.0),
+                                    dict(rope_yarn_mscale_all_dim=1.0),
+                                    dict(rope_yarn_mscale=1.0,
+                                         rope_yarn_mscale_all_dim=1.0)],
+                         ids=lambda f: "+".join(f))
+def test_yarns_two_magnitudes_are_refused_outside_latent_attention(fields):
+    """The softmax's share of mscale / mscale_all_dim is applied where
+    latent attention scales its scores and nowhere else: an ordinary yarn
+    model that states either would run at a wrong attention magnitude with
+    nothing said, so ``validate`` refuses it; a latent stack takes them."""
+    from ollama_operator_tpu.models.config import PRESETS
+    with pytest.raises(AssertionError, match="latent attention alone"):
+        ModelConfig(rope_scaling_type="yarn", rope_scaling=4.0,
+                    rope_orig_ctx=2048, **fields).validate()
+    with pytest.raises(AssertionError, match="are yarn's"):
+        ModelConfig(**fields).validate()
+    dataclasses.replace(PRESETS["tiny-kimi-k2"], **fields).validate()
 
 
 def test_presets_llama31_32_scaled():

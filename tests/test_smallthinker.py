@@ -630,7 +630,10 @@ def test_the_cell_and_its_metrics_are_in_the_benchmark():
                  "moe_expert_load_spread", "decode_kv_write_ms_per_step",
                  "decode_window_attn_ms_per_step", "kv_cache_mb_per_slot",
                  "pass_filled_share", "late_launch_share"):
-        assert by[name]["workloads"][-1] == CELL
+        # appended in PR 50's turn: only what PR 53 appended stands behind it
+        cells = by[name]["workloads"]
+        assert cells[cells.index(CELL) + 1:] in (
+            [], ["kimi-k2.7-code.decode-deep"])
     # whole rings by batch would read over 100% where a ring is read to the
     # live depth: this cell is not on that list, and its file has no hook
     assert CELL not in by["window_attn_roofline"]["workloads"]

@@ -118,6 +118,37 @@ def test_the_benchmark_reads_the_programs_vocabulary():
     assert len(grouped) == len(set(grouped))
 
 
+def test_the_caches_gauges_are_one_vocabulary():
+    """``CACHE_GAUGES``: each gauge of the loaded model's cache is described
+    for the scrape, registered and removed by ``LoadedModel`` from the
+    engine's property of the same stem, and read by a per-layer metric of the
+    benchmark under the label the vocabulary gives."""
+    import glob
+    import inspect
+    import os
+
+    from benchmark import trace_spans
+    from ollama_operator_tpu.runtime import service
+    from ollama_operator_tpu.runtime.engine import Engine
+    from ollama_operator_tpu.runtime.trace import CACHE_GAUGES
+    from ollama_operator_tpu.server import metrics
+    served = inspect.getsource(service)
+    described = inspect.getsource(metrics)
+    readers = "".join(open(p).read() for p in glob.glob(os.path.join(
+        os.path.dirname(trace_spans.__file__), "layer_metrics", "*.py")))
+    assert set(CACHE_GAUGES) == set(re.findall(
+        r'"(tpu_model_(?:cache_bytes|[a-z]+_positions))"', served))
+    for name, (key, values) in CACHE_GAUGES.items():
+        stem = name[len("tpu_model_"):]
+        assert isinstance(getattr(Engine, stem, None), property) or (
+            stem == "cache_bytes")
+        assert served.count(f'"{name}"') == 2          # on and off
+        assert f'GLOBAL.describe("{name}"' in described
+        assert f'"{name}' in readers
+        assert f'{{{{{key}="{{{key}}}"}}}}' in served
+        assert len(set(values)) == len(values)
+
+
 # -- the span primitive ------------------------------------------------
 
 def test_a_span_observes_its_duration_once():
